@@ -1,7 +1,6 @@
 """Speculative-decoding smoke bench: spec-on vs spec-off on deterministic
-CPU traces (the bankable evidence that self-drafting pays before a TPU
-window is available; `bench.py --spec-k` / `tpu_capture.py --spec-k` carry
-the same knob for the on-chip number).
+CPU traces (counts of accepted drafts and dispatches, not device speed;
+`bench.py --spec-k` carries the same knob for the on-chip number).
 
 Two workloads, both greedy and fully deterministic:
 

@@ -142,11 +142,14 @@ class FabricServer:
         self._replicas.clear()
         if self._server is not None:
             self._server.close()
-            await self._server.wait_closed()
+        # sever the live connections BEFORE awaiting wait_closed(): since
+        # Python 3.12 it waits for every connection handler to finish
         for w in list(self._conn_writers):
             with contextlib.suppress(Exception):
                 w.close()
         self._conn_writers.clear()
+        if self._server is not None:
+            await self._server.wait_closed()
         await self.state.close()
 
     # -------------------------------------------------------- replication

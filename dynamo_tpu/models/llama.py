@@ -437,13 +437,10 @@ def _use_overlap_tail(cfg, layer, mesh) -> bool:
     )
 
 
-def _fused_interpret(cfg) -> bool:
-    """Interpret the fused kernels off-TPU (CPU tests/benches) or when the
-    model is pinned to the interpret attention impl."""
-    return (
-        cfg.attn_impl == "pallas_interpret"
-        or jax.default_backend() != "tpu"
-    )
+def _fused_interpret() -> bool:
+    """Interpret the fused kernels off-TPU (CPU tests/benches); never on
+    a TPU."""
+    return jax.default_backend() != "tpu"
 
 
 def _fused_qkv_dispatch(x, layer, cfg, inv_freqs, positions, mesh):
@@ -451,7 +448,7 @@ def _fused_qkv_dispatch(x, layer, cfg, inv_freqs, positions, mesh):
     (ops/collective.py) and direct otherwise. cos/sin are computed
     exactly as apply_rope's angle formula; the rotation itself runs
     inside the fused program."""
-    interp = _fused_interpret(cfg)
+    interp = _fused_interpret()
     angles = positions[..., None].astype(jnp.float32) * inv_freqs
     kwargs = dict(
         eps=cfg.rms_eps,
@@ -483,10 +480,10 @@ def _fused_out_dispatch(attn_flat, layer, cfg, x, mesh):
 
         return fused_attn_out_residual_meshed(
             mesh, attn_flat, layer["wo"], x,
-            interpret=_fused_interpret(cfg),
+            interpret=_fused_interpret(),
         )
     return fused_attn_out_residual(
-        attn_flat, layer["wo"], x, interpret=_fused_interpret(cfg)
+        attn_flat, layer["wo"], x, interpret=_fused_interpret()
     )
 
 
@@ -516,7 +513,7 @@ def _attn_decode(x, layer, cfg, inv_freqs, positions, k_cache_l, v_cache_l, bloc
                 mesh, attn_flat, layer["wo"], x, layer["mlp_norm"],
                 layer["wg"], layer["wu"], layer["wd"],
                 eps=cfg.rms_eps, mlp_act=cfg.mlp_act,
-                interpret=_fused_interpret(cfg),
+                interpret=_fused_interpret(),
             )
         else:
             out = _fused_out_dispatch(attn_flat, layer, cfg, x, mesh)

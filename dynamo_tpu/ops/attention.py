@@ -124,10 +124,8 @@ def causal_prefill_attention(
 
             interp = impl == "pallas_interpret"
             if mesh is not None and head_axis is not None:
-                from jax.experimental.shard_map import shard_map
-
                 hs = PSpec(None, head_axis, None)
-                fn = shard_map(
+                fn = jax.shard_map(
                     lambda q_, k_, v_, vl_: flash_prefill_attention_pallas(
                         q_, k_, v_, vl_, block_q=bq, block_k=bq,
                         window=window, scale=scale,
@@ -137,7 +135,7 @@ def causal_prefill_attention(
                     mesh=mesh,
                     in_specs=(hs, hs, hs, PSpec()),
                     out_specs=hs,
-                    check_rep=False,
+                    check_vma=False,
                 )
                 return fn(q, k, v, jnp.asarray(valid_len, jnp.int32))
             return flash_prefill_attention_pallas(
@@ -263,8 +261,6 @@ def paged_decode_attention(
         ks = k_cache["s"] if quant else None
         vs = v_cache["s"] if quant else None
         if mesh is not None and head_axis is not None:
-            from jax.experimental.shard_map import shard_map
-
             cache_spec = PSpec(head_axis, None, None, None)
             in_specs = [
                 PSpec(None, head_axis, None),  # q [B, Hq, D]
@@ -284,12 +280,12 @@ def paged_decode_attention(
                     logit_softcap=logit_softcap, interpret=interp,
                 )
 
-            fn = shard_map(
+            fn = jax.shard_map(
                 _kern,
                 mesh=mesh,
                 in_specs=tuple(in_specs),
                 out_specs=PSpec(None, head_axis, None),
-                check_rep=False,
+                check_vma=False,
             )
             args = (q, kq, vq, block_tables, context_lens)
             if quant:
@@ -386,8 +382,6 @@ def paged_verify_attention(
         ks = k_cache["s"] if quant else None
         vs = v_cache["s"] if quant else None
         if mesh is not None and head_axis is not None:
-            from jax.experimental.shard_map import shard_map
-
             in_specs = [
                 PSpec(None, None, head_axis, None),  # q [B, S, Hq, D]
                 PSpec(head_axis, None, None, None),  # k cache
@@ -406,12 +400,12 @@ def paged_verify_attention(
                     logit_softcap=logit_softcap, interpret=interp,
                 )
 
-            fn = shard_map(
+            fn = jax.shard_map(
                 _kern,
                 mesh=mesh,
                 in_specs=tuple(in_specs),
                 out_specs=PSpec(None, None, head_axis, None),
-                check_rep=False,
+                check_vma=False,
             )
             args = (q, kq, vq, block_tables, positions)
             if quant:

@@ -41,7 +41,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as PSpec
 
 from dynamo_tpu.ops.basics import swiglu
@@ -128,7 +128,7 @@ def fused_qkv_rope_meshed(
     head_spec = PSpec(None, axis, None)
     return shard_map(
         _body, mesh=mesh, in_specs=tuple(specs),
-        out_specs=(head_spec, head_spec, head_spec), check_rep=False,
+        out_specs=(head_spec, head_spec, head_spec), check_vma=False,
     )(*args)
 
 
@@ -172,7 +172,7 @@ def fused_attn_out_residual_meshed(
 
     return shard_map(
         _body, mesh=mesh, in_specs=tuple(specs),
-        out_specs=PSpec(None, None), check_rep=False,
+        out_specs=PSpec(None, None), check_vma=False,
     )(*args)
 
 
@@ -329,5 +329,5 @@ def fused_tail_overlap(
 
     return shard_map(
         _body, mesh=mesh, in_specs=tuple(specs),
-        out_specs=PSpec(None, None), check_rep=False,
+        out_specs=PSpec(None, None), check_vma=False,
     )(*args)

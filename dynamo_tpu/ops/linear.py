@@ -26,8 +26,9 @@ int8->f32 dequant happens on the weight tiles in VMEM, and the [B, hidden]
 activations never round-trip HBM between the fused ops. The kernels follow
 the SAME op/precision sequence as the unfused path (rms_norm -> matmul
 f32-accum -> scale -> bf16 cast -> bias -> rope-in-f32), so with a single
-contraction tile (the default; `block_in` enables tiling for big models on
-real TPU) fused and unfused decode are bit-identical.
+contraction tile fused and unfused decode are bit-identical. The tile is
+the whole contraction dim while the weights fit VMEM and a divisor of it
+otherwise (`_contraction_tile`; a 7B model's projections do not fit).
 """
 
 from __future__ import annotations
@@ -135,6 +136,26 @@ def _rope_rotate(y, cos, sin, heads, head_dim, dtype):
     ).astype(dtype)
 
 
+# One contraction tile of a fused kernel's weights may take this much
+# VMEM (Mosaic double-buffers it): 16 MB of VMEM on a v5e must also hold
+# the activations and the f32 accumulators.
+_WEIGHT_TILE_BYTES = 4 * 2**20
+
+
+def _contraction_tile(k_dim: int, row_bytes: int) -> int:
+    """Rows of the contraction dim per grid step: all of them when the
+    weights fit `_WEIGHT_TILE_BYTES` (one tile — bit-identical to the
+    unfused path), else the largest lane-aligned divisor that does. The
+    whole [4096, 6144] int8 QKV block of a 7B model is 24 MB and cannot
+    be resident at once."""
+    if k_dim * row_bytes <= _WEIGHT_TILE_BYTES:
+        return k_dim
+    for blk in range(k_dim - k_dim % 128, 0, -128):
+        if k_dim % blk == 0 and blk * row_bytes <= _WEIGHT_TILE_BYTES:
+            return blk
+    return k_dim
+
+
 def _fused_qkv_kernel(
     *refs,
     eps: float,
@@ -217,24 +238,28 @@ def fused_qkv_rope(
     bq: Optional[jax.Array] = None,
     bk: Optional[jax.Array] = None,
     bv: Optional[jax.Array] = None,
-    block_in: Optional[int] = None,  # contraction tile; None = whole hidden
+    block_in: Optional[int] = None,  # contraction tile; None = sized to VMEM
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """RMSNorm + QKV projections (+bias) + RoPE in ONE pallas program.
 
     Returns (q [B, Hq, D], k [B, Hkv, D], v [B, Hkv, D]) — exactly what
     ops/layers.qkv_head produces for non-qk-norm models, bit-identical
-    when block_in covers the whole hidden dim (the default)."""
+    when one tile covers the whole hidden dim (`_contraction_tile`)."""
     FUSED_KERNEL_ENTRIES["qkv_rope"] += 1
     B, H = x.shape
     q_dim = num_heads * head_dim
     kv_dim = num_kv_heads * head_dim
-    blk = H if block_in is None else min(block_in, H)
-    assert H % blk == 0, (H, blk)
-    n_tiles = H // blk
     wq_q, wq_s = _wq_parts(wq)
     wk_q, wk_s = _wq_parts(wk)
     wv_q, wv_s = _wq_parts(wv)
+    blk = (
+        _contraction_tile(H, (q_dim + 2 * kv_dim) * wq_q.dtype.itemsize)
+        if block_in is None
+        else min(block_in, H)
+    )
+    assert H % blk == 0, (H, blk)
+    n_tiles = H // blk
     quantized = wq_s is not None
     has_bias = bq is not None
 
@@ -353,7 +378,11 @@ def fused_attn_out_residual(
     B, q_dim = attn.shape
     wo_q, wo_s = _wq_parts(wo)
     H = wo_q.shape[1]
-    blk = q_dim if block_in is None else min(block_in, q_dim)
+    blk = (
+        _contraction_tile(q_dim, H * wo_q.dtype.itemsize)
+        if block_in is None
+        else min(block_in, q_dim)
+    )
     assert q_dim % blk == 0, (q_dim, blk)
     n_tiles = q_dim // blk
     quantized = wo_s is not None and not partial_out

@@ -37,7 +37,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from dynamo_tpu.ops.basics import rms_norm, swiglu
@@ -296,7 +296,7 @@ def moe_ffn_shard_map(
             P(ep_axis, tp_axis, None),
         ),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(x, router_w, wg, wu, wd)
 
@@ -424,6 +424,6 @@ def moe_ffn_ep_a2a(
             P(ep_axis, tp_axis, None),
         ),
         out_specs=P(ep_axis, None),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(x, router_w, wg, wu, wd)

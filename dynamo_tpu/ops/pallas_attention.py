@@ -58,11 +58,17 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
-# jax renamed TPUCompilerParams -> CompilerParams around 0.5; accept both so
-# the kernels run on every toolchain the fleet has deployed
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
+def _page_scale_row(vals: list, block_size: int) -> jax.Array:
+    """[1, W*block_size] f32 row holding vals[i] over page i's columns.
+    Built from a lane iota and selects: Mosaic cannot lower the
+    [W, bs] -> [W*bs, 1] reshape a `jnp.repeat(...)[:, None]` needs."""
+    col = jax.lax.broadcasted_iota(
+        jnp.int32, (1, len(vals) * block_size), dimension=1
+    )
+    row = jnp.full(col.shape, vals[0], jnp.float32)
+    for i in range(1, len(vals)):
+        row = jnp.where(col >= i * block_size, vals[i], row)
+    return row
 
 
 def _apply_softcap(s: jax.Array, softcap: Optional[float]) -> jax.Array:
@@ -180,8 +186,11 @@ def _decode_kernel(
             k = k_buf[slot].astype(jnp.float32)  # [W*bs, D]
             v = v_buf[slot].astype(jnp.float32)
             if quantized:
-                # in-kernel dequant: one SMEM scale per fetched page,
-                # expanded to a per-row column over the [W*bs, D] tile
+                # in-kernel dequant: one SMEM scale per fetched page. A
+                # page's scale is constant over its rows, so it factors
+                # out of both dots: q.(k*s) = (q.k)*s and p.(v*s) = (p*s).v
+                # — applied to the [G, W*bs] scores/probabilities, not
+                # the [W*bs, D] tiles
                 kvals = []
                 vvals = []
                 for i in range(W):
@@ -190,14 +199,14 @@ def _decode_kernel(
                     ]
                     kvals.append(ks_ref[h, page])
                     vvals.append(vs_ref[h, page])
-                krow = jnp.repeat(jnp.stack(kvals), block_size)[:, None]
-                vrow = jnp.repeat(jnp.stack(vvals), block_size)[:, None]
-                k = k * krow
-                v = v * vrow
+                kcol = _page_scale_row(kvals, block_size)
+                vcol = _page_scale_row(vvals, block_size)
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             ) * scale  # [G, W*bs]
+            if quantized:
+                s = s * kcol
             s = _apply_softcap(s, softcap)
             pos = c * chunk_tokens + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, dimension=1
@@ -214,7 +223,7 @@ def _decode_kernel(
             p = jnp.exp(s - m_new)
             l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
             acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-                p, v, (((1,), (0,)), ((), ())),
+                p * vcol if quantized else p, v, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
             m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
@@ -270,8 +279,8 @@ def paged_decode_attention_pallas(
         grid=(B, Hkv),
         in_specs=[
             pl.BlockSpec((1, 1, G, D), q_index),
-            pl.BlockSpec(memory_space=pltpu.ANY),  # K cache stays in HBM
-            pl.BlockSpec(memory_space=pltpu.ANY),  # V cache stays in HBM
+            pl.BlockSpec(memory_space=pl.ANY),  # K cache stays in HBM
+            pl.BlockSpec(memory_space=pl.ANY),  # V cache stays in HBM
         ],
         out_specs=pl.BlockSpec((1, 1, G, D), o_index),
         scratch_shapes=[
@@ -295,7 +304,7 @@ def paged_decode_attention_pallas(
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -407,14 +416,14 @@ def _verify_kernel(
                     ]
                     kvals.append(ks_ref[h, page])
                     vvals.append(vs_ref[h, page])
-                krow = jnp.repeat(jnp.stack(kvals), block_size)[:, None]
-                vrow = jnp.repeat(jnp.stack(vvals), block_size)[:, None]
-                k = k * krow
-                v = v * vrow
+                kcol = _page_scale_row(kvals, block_size)
+                vcol = _page_scale_row(vvals, block_size)
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             ) * scale  # [S*G, W*bs]
+            if quantized:
+                s = s * kcol
             s = _apply_softcap(s, softcap)
             # row r is draft position r // G at true position base + r//G
             qpos = base + jax.lax.broadcasted_iota(
@@ -435,7 +444,7 @@ def _verify_kernel(
             p = jnp.exp(s - m_new)
             l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
             acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-                p, v, (((1,), (0,)), ((), ())),
+                p * vcol if quantized else p, v, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
             m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
@@ -493,8 +502,8 @@ def paged_verify_attention_pallas(
         grid=(B, Hkv),
         in_specs=[
             pl.BlockSpec((1, 1, S * G, D), q_index),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((1, 1, S * G, D), o_index),
         scratch_shapes=[
@@ -521,7 +530,7 @@ def paged_verify_attention_pallas(
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, S * G, D), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -691,7 +700,7 @@ def flash_prefill_attention_pallas(
         kernel_body,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Hkv, P, G, D), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,

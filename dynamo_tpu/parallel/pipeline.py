@@ -32,7 +32,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dynamo_tpu.ops.attention import NEG_INF
@@ -235,7 +235,7 @@ def prefill_pp(
         mesh=mesh,
         in_specs=(pp_spec, P(), P(), P(), pp_spec, pp_spec),
         out_specs=(P(), pp_spec, pp_spec),
-        check_rep=False,
+        check_vma=False,
     )
     lm_head = params.get("lm_head")
     if lm_head is None:
@@ -355,7 +355,7 @@ def decode_pp(
         mesh=mesh,
         in_specs=(pp_spec, P(), P(), P(), pp_spec, pp_spec),
         out_specs=(P(), pp_spec, pp_spec),
-        check_rep=False,
+        check_vma=False,
     )
     lm_head = params.get("lm_head")
     if lm_head is None:

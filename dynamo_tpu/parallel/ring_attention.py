@@ -29,7 +29,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 NEG_INF = -1e30
@@ -163,6 +163,6 @@ def ring_prefill_attention(
         mesh=mesh,
         in_specs=(spec, spec, spec, P()),
         out_specs=spec,
-        check_rep=False,
+        check_vma=False,
     )
     return fn(q, k, v, jnp.asarray(valid_len, jnp.int32))

@@ -95,9 +95,28 @@ async def amain(args: argparse.Namespace) -> None:
     # DYN_LOGGING_JSONL even when an early import already initialized
     # logging (serve.py children tighten per-service log levels this way)
     dlog.init(force=True)
+    name = args.model_name or (args.model_path or "echo-model")
+    jax_engine = None
+    if args.out_opt == "jax":
+        # Built BEFORE the runtime exists: importing jax, reaching the
+        # chip and making 7B of weights stall this process for longer than
+        # the lease TTL, and a runtime that cannot send keepalives loses
+        # its primary lease and self-fences before the first request.
+        from dynamo_tpu.engine.jax_engine.factory import build_jax_engine
+
+        if not args.model_path:
+            raise SystemExit("out=jax requires a --model-path (HF dir)")
+        jax_engine = await build_jax_engine(
+            args.model_path,
+            name,
+            kv_block_size=args.kv_block_size,
+            context_length=args.context_length,
+            tensor_parallel_size=args.tensor_parallel_size,
+            num_blocks=args.num_blocks,
+            max_batch=args.max_batch,
+        )
     drt = await DistributedRuntime.from_settings()
     try:
-        name = args.model_name or (args.model_path or "echo-model")
         if args.out_opt == "dyn":
             from dynamo_tpu.kv_router.scheduler import KvRouterConfig
 
@@ -142,29 +161,8 @@ async def amain(args: argparse.Namespace) -> None:
                 )
             )
             config = EngineConfig.static_(engine, mdc)
-        elif args.out_opt == "jax":
-            from dynamo_tpu.engine.jax_engine.factory import build_jax_engine
-            from dynamo_tpu.runtime.config import (
-                default_jax_cache_dir,
-                setup_jax_compilation_cache,
-            )
-
-            if not args.model_path:
-                raise SystemExit("out=jax requires a --model-path (HF dir)")
-            # persistent XLA compile cache (DYN_JAX_CACHE_DIR overrides;
-            # "off" disables): a restarted server skips the ~46.6 s cold
-            # compile of the engine program set
-            setup_jax_compilation_cache(default_jax_cache_dir())
-            engine, mdc = await build_jax_engine(
-                args.model_path,
-                name,
-                kv_block_size=args.kv_block_size,
-                context_length=args.context_length,
-                tensor_parallel_size=args.tensor_parallel_size,
-                num_blocks=args.num_blocks,
-                max_batch=args.max_batch,
-            )
-            config = EngineConfig.static_(engine, mdc)
+        elif jax_engine is not None:
+            config = EngineConfig.static_(*jax_engine)
         else:
             raise SystemExit(f"unknown out={args.out_opt}")
         if args.request_template:

@@ -7,11 +7,7 @@ Role-equivalent of the reference's Figment-based RuntimeConfig/WorkerConfig
 from __future__ import annotations
 
 import os
-
-try:
-    import tomllib  # Python 3.11+
-except ImportError:  # Python 3.10: tomli is the same parser, different name
-    import tomli as tomllib
+import tomllib
 from dataclasses import dataclass, field, fields
 from typing import Any, Optional
 
@@ -32,62 +28,42 @@ def _env_int(name: str, default: int) -> int:
     return int(v) if v is not None else default
 
 
-# set by setup_jax_compilation_cache so repeated calls (run.py CLI, then
-# factory.build_jax_engine in the same process) configure jax only once
-_jax_cache_configured: Optional[str] = None
-
-
-def setup_jax_compilation_cache(
-    default_dir: Optional[str] = None,
-) -> Optional[str]:
-    """Point jax at a persistent compilation cache directory, so serving
-    processes stop paying the cold-compile bill (~46.6 s for the TPU
-    engine's program set) on every restart — bench.py has always done
-    this; this is the serve.py/run.py wiring.
-
-    Resolution order: DYN_JAX_CACHE_DIR env var, then JAX_COMPILATION_CACHE_DIR
-    (jax's own knob — respected, not overridden), then `default_dir` from
-    the caller. DYN_JAX_CACHE_DIR set to "" / "0" / "off" disables even
-    the default. Returns the directory in effect, or None when disabled.
-    Idempotent per process; never raises (a broken cache dir must not
-    block serving).
-    """
-    global _jax_cache_configured
-    if _jax_cache_configured is not None:
-        return _jax_cache_configured or None
-    raw = os.environ.get("DYN_JAX_CACHE_DIR")
-    if raw is not None and raw.strip().lower() in ("", "0", "off", "none"):
-        _jax_cache_configured = ""
-        return None
-    cache_dir = (
-        raw
-        or os.environ.get("JAX_COMPILATION_CACHE_DIR")
-        or default_dir
+def jax_cache_dir() -> str:
+    """The persistent XLA compilation cache directory of every JAX process
+    of this program (server, graph workers, bench, smoke, prebake, tests):
+    `JAX_COMPILATION_CACHE_DIR` where the environment sets it, else
+    `<checkout>/.jax_cache`. The path is part of the cache key, so one
+    fixed default is what makes a second start a hit."""
+    outside = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if outside:
+        return outside
+    checkout = os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     )
-    if not cache_dir:
-        _jax_cache_configured = ""
-        return None
-    cache_dir = os.path.abspath(os.path.expanduser(cache_dir))
-    try:
-        import jax
+    return os.path.join(checkout, ".jax_cache")
 
+
+def setup_jax_compilation_cache() -> str:
+    """Turn the persistent compilation cache on at `jax_cache_dir()`, so a
+    restarted process skips the cold compile of the engine's program set.
+    Where `JAX_COMPILATION_CACHE_DIR` is set jax has already read it and
+    this sets no other directory. Idempotent; returns the directory."""
+    import jax
+
+    cache_dir = jax_cache_dir()
+    if jax.config.jax_compilation_cache_dir != cache_dir:
         os.makedirs(cache_dir, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", cache_dir)
-        # cache every program: the engine compiles few, large programs, so
-        # there is no small-entry flood to guard against
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    except Exception:  # noqa: BLE001 — cache is an optimization only
-        _jax_cache_configured = ""
-        return None
-    _jax_cache_configured = cache_dir
+    # cache every program: the engine compiles few, large programs, so
+    # there is no small-entry flood to guard against
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    # A Mosaic kernel's serialized body carries the source location of every
+    # frame above it and is part of the cache key: with full tracebacks, an
+    # edit that moves a line anywhere on the call path made every program
+    # holding a Pallas kernel a cold compile (minutes at 7B; PERF.md PR 21).
+    # One frame — the kernel's own line — keeps the key to what was compiled.
+    jax.config.update("jax_include_full_tracebacks_in_locations", False)
     return cache_dir
-
-
-def default_jax_cache_dir() -> str:
-    """Default persistent-cache location for the CLI entrypoints."""
-    return os.path.join(
-        os.path.expanduser("~"), ".cache", "dynamo_tpu", "jax_cache"
-    )
 
 
 @dataclass
@@ -109,10 +85,6 @@ class RuntimeConfig:
                             drain writes the KV offload tiers + prefix
                             index as checksummed KVB2 pages; boot
                             restores them so restarts rejoin warm
-      DYN_JAX_CACHE_DIR     persistent XLA compilation cache directory for
-                            every jax-running process (serve.py/run.py/
-                            factory; "" or "off" disables) — see
-                            setup_jax_compilation_cache
     """
 
     fabric_addr: str = ""

@@ -19,7 +19,6 @@ import signal
 import socket
 from typing import Optional
 
-from dynamo_tpu.runtime.config import default_jax_cache_dir
 from dynamo_tpu.runtime.logging import get_logger
 from dynamo_tpu.sdk import Supervisor, load_graph
 
@@ -110,15 +109,11 @@ async def serve_graph(
                 f"{spec.name}-{r}",
                 "dynamo_tpu.sdk.runner",
                 spec.target,
+                # children inherit the environment, so an outside
+                # JAX_COMPILATION_CACHE_DIR reaches every jax-running
+                # service untouched (runtime.config.jax_cache_dir)
                 env={
                     "DYN_FABRIC_ADDR": addr,
-                    # every jax-running service shares one persistent XLA
-                    # compile cache across restarts (DYN_JAX_CACHE_DIR
-                    # overrides, "off" disables) — a respawned worker
-                    # skips the ~46.6 s cold compile of its program set
-                    "DYN_JAX_CACHE_DIR": os.environ.get(
-                        "DYN_JAX_CACHE_DIR", default_jax_cache_dir()
-                    ),
                     **spec.env,
                     **(extra_env or {}),
                 },

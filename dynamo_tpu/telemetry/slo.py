@@ -35,17 +35,13 @@ from __future__ import annotations
 import os
 import random
 import threading
+import tomllib
 from collections import OrderedDict, deque
 from dataclasses import dataclass
 from typing import Any, Callable, Optional
 
 from dynamo_tpu.runtime import clock as dclock
 from dynamo_tpu.telemetry.histogram import PhaseHistogram, PhaseHistograms
-
-try:
-    import tomllib  # Python 3.11+
-except ImportError:  # Python 3.10: tomli is the same parser
-    import tomli as tomllib  # type: ignore[no-redef]
 
 # Namespace event subject for SLO state transitions (ok/burning/breached).
 SLO_STATUS_SUBJECT = "slo-status"

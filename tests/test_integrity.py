@@ -627,6 +627,8 @@ async def test_skewed_peer_fails_handshake_with_friendly_error():
         writer.write(wire.pack([1, "ok", 42], version=9))
         with contextlib.suppress(Exception):
             await writer.drain()
+        # Server.wait_closed() (3.12+) waits for every connection
+        writer.close()
 
     server = await asyncio.start_server(skewed_server, "127.0.0.1", 0)
     port = server.sockets[0].getsockname()[1]

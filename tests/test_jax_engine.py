@@ -225,6 +225,7 @@ def make_chunked_engine(chunk_tokens, mixed_step=False, **kw):
             max_model_len=64, watermark_blocks=2,
             mixed_step=mixed_step,
             chunk_budget=kw.get("chunk_budget", 0),
+            decode_horizon=kw.get("decode_horizon", 1),
         ),
     )
 

@@ -88,6 +88,82 @@ def test_mixed_step_token_identical_to_phase_separated():
     assert gp.mixed_decode_tokens > 0
 
 
+def _spy_programs(engine):
+    """Record which step programs the packer dispatched: the chunk count
+    of every mixed step, and (H, longest lane tail) of every horizon."""
+    mixed_calls, horizons = [], []
+    orig_mixed = engine.runner.mixed_step
+    orig_pick = engine._horizon_for
+
+    def mixed_spy(chunks, *a, **k):
+        mixed_calls.append(len(chunks))
+        return orig_mixed(chunks, *a, **k)
+
+    def pick_spy(active):
+        H = orig_pick(active)
+        horizons.append(
+            (H, max(engine._lane_remaining(s) for s in active))
+        )
+        return H
+
+    engine.runner.mixed_step = mixed_spy
+    engine._horizon_for = pick_spy
+    return mixed_calls, horizons
+
+
+async def _short_tails(engine, make_long):
+    """Four lanes admitted together whose budgets leave every lane 2 or 3
+    tokens after the first full horizon (and a last token after the
+    second): the tails the one H program now serves."""
+    reqs = [
+        greedy_request([1, 2, 3], 7),
+        _seeded_request([4, 5, 6], 8, seed=11),
+        greedy_request([7, 8, 9, 10], 8),
+        make_long([11, 12, 13]),
+    ]
+    outs = await asyncio.gather(*(collect(engine, r) for r in reqs))
+    await engine.close()
+    return outs
+
+
+@pytest.mark.parametrize("sampling", ["greedy", "seeded"])
+@pytest.mark.parametrize("workload", ["prefill_mid_decode", "short_tails"])
+def test_mixed_step_at_horizon_4_token_identical(workload, sampling):
+    """The TPU default: decode horizon 4 WITH mixed steps. A prefill
+    arriving mid-decode interleaves mixed steps with decode_multi@H4, and
+    a batch whose lanes all have 2-3 tokens left runs the full H program.
+    Both must stream exactly what horizon 1 with mixed steps off streams,
+    greedy and seeded-temperature — and both programs must have run."""
+    run = {
+        "prefill_mid_decode": _overlapped_run,
+        "short_tails": _short_tails,
+    }[workload]
+
+    def make_long(p):
+        if sampling == "greedy":
+            return greedy_request(p, 7)
+        return _seeded_request(p, 7, seed=77)
+
+    ref_engine = make_chunked_engine(8, mixed_step=False, decode_horizon=1)
+    ref = asyncio.run(run(ref_engine, make_long))
+
+    engine = make_chunked_engine(8, mixed_step=True, decode_horizon=4)
+    mixed_calls, horizons = _spy_programs(engine)
+    got = asyncio.run(run(engine, make_long))
+
+    for (toks_ref, r_ref), (toks, r) in zip(ref, got):
+        assert r == r_ref
+        assert toks == toks_ref, "H=4 + mixed diverged from H=1 unmixed"
+    assert {H for H, _ in horizons} <= {1, 4}, horizons
+    assert any(H == 4 for H, _ in horizons), "horizon never dispatched"
+    if workload == "prefill_mid_decode":
+        assert mixed_calls, "mixed stepper never engaged at horizon 4"
+    else:
+        assert any(H == 4 and 1 < tail < 4 for H, tail in horizons), (
+            "no 2..3-token tail reached the H program", horizons
+        )
+
+
 def test_mixed_step_budget_packs_multiple_chunks():
     """chunk_budget=16 with 8-token chunks allows two chunk slots per
     step: the same 40-token prompt finishes in fewer mixed steps, still

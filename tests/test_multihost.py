@@ -66,6 +66,27 @@ def test_two_process_engine_serves_horizon_decode(tmp_path):
     _two_process_engine_serves(tmp_path, {"DYN_DECODE_HORIZON": "3"})
 
 
+def _spawn_worker(tmp_path, env, rank, *args):
+    """One rank of tests/multihost_worker.py. Its stderr goes to a file,
+    not a pipe: XLA logs a long line for every compilation-cache load, no
+    test reads a rank's stderr before that rank has exited, and a rank
+    blocked on a full pipe never reaches its barrier."""
+    worker = os.path.join(REPO, "tests", "multihost_worker.py")
+    with open(tmp_path / f"rank{rank}.err", "w") as err:
+        return subprocess.Popen(
+            [sys.executable, worker, str(rank), *args],
+            cwd="/tmp",
+            stdout=subprocess.PIPE,
+            stderr=err,
+            env=env,
+            text=True,
+        )
+
+
+def _stderr_tail(tmp_path, rank, n=3000):
+    return (tmp_path / f"rank{rank}.err").read_text(errors="replace")[-n:]
+
+
 def _two_process_engine_serves(tmp_path, extra_env):
     model_dir = _tiny_model_dir(tmp_path)
     port = _free_port()
@@ -88,22 +109,18 @@ def _two_process_engine_serves(tmp_path, extra_env):
     procs = []
     try:
         time.sleep(1.0)  # fabric server bind
-        worker = os.path.join(REPO, "tests", "multihost_worker.py")
         for rank in (1, 0):
             procs.append(
-                subprocess.Popen(
-                    [sys.executable, worker, str(rank), "2", model_dir],
-                    cwd="/tmp",
-                    stdout=subprocess.PIPE,
-                    stderr=subprocess.PIPE,
-                    env=env_base,
-                    text=True,
-                )
+                _spawn_worker(tmp_path, env_base, rank, "2", model_dir)
             )
-        out0, err0 = procs[1].communicate(timeout=240)
-        out1, err1 = procs[0].communicate(timeout=60)
-        assert procs[1].returncode == 0, f"leader failed:\n{err0[-3000:]}"
-        assert procs[0].returncode == 0, f"follower failed:\n{err1[-3000:]}"
+        out0, _ = procs[1].communicate(timeout=240)
+        out1, _ = procs[0].communicate(timeout=60)
+        assert procs[1].returncode == 0, (
+            f"leader failed:\n{_stderr_tail(tmp_path, 0)}"
+        )
+        assert procs[0].returncode == 0, (
+            f"follower failed:\n{_stderr_tail(tmp_path, 1)}"
+        )
         assert "FOLLOWER DONE" in out1
         line = [l for l in out0.splitlines() if l.startswith("TOKENS ")][0]
         t1, t2 = json.loads(line[len("TOKENS "):])
@@ -144,29 +161,25 @@ def test_four_process_dp_tp_mesh(tmp_path):
     procs = []
     try:
         time.sleep(1.0)
-        worker = os.path.join(REPO, "tests", "multihost_worker.py")
         for rank in (3, 2, 1, 0):
             procs.append(
-                subprocess.Popen(
-                    [
-                        sys.executable, worker, str(rank), "4", model_dir,
-                        "2", "2",  # tp=2, dp=2
-                    ],
-                    cwd="/tmp",
-                    stdout=subprocess.PIPE,
-                    stderr=subprocess.PIPE,
-                    env=env_base,
-                    text=True,
+                _spawn_worker(
+                    tmp_path, env_base, rank, "4", model_dir,
+                    "2", "2",  # tp=2, dp=2
                 )
             )
         leader = procs[-1]
-        out0, err0 = leader.communicate(timeout=240)
+        out0, _ = leader.communicate(timeout=240)
         follower_outs = []
-        for p in procs[:-1]:
-            out, err = p.communicate(timeout=60)
-            assert p.returncode == 0, f"follower failed:\n{err[-3000:]}"
+        for rank, p in zip((3, 2, 1), procs[:-1]):
+            out, _ = p.communicate(timeout=60)
+            assert p.returncode == 0, (
+                f"follower failed:\n{_stderr_tail(tmp_path, rank)}"
+            )
             follower_outs.append(out)
-        assert leader.returncode == 0, f"leader failed:\n{err0[-3000:]}"
+        assert leader.returncode == 0, (
+            f"leader failed:\n{_stderr_tail(tmp_path, 0)}"
+        )
         assert all("FOLLOWER DONE" in o for o in follower_outs)
         line = [l for l in out0.splitlines() if l.startswith("TOKENS ")][0]
         t1, t2 = json.loads(line[len("TOKENS "):])
@@ -205,19 +218,11 @@ def test_leader_crash_releases_followers(tmp_path):
     procs = []
     try:
         time.sleep(1.0)
-        worker = os.path.join(REPO, "tests", "multihost_worker.py")
-        for rank, mode in ((1, "leader-hang"), (0, "leader-hang")):
+        for rank in (1, 0):
             procs.append(
-                subprocess.Popen(
-                    [
-                        sys.executable, worker, str(rank), "2", model_dir,
-                        "2", "1", mode,
-                    ],
-                    cwd="/tmp",
-                    stdout=subprocess.PIPE,
-                    stderr=subprocess.PIPE,
-                    env=env_base,
-                    text=True,
+                _spawn_worker(
+                    tmp_path, env_base, rank, "2", model_dir,
+                    "2", "1", "leader-hang",
                 )
             )
         follower, leader = procs
@@ -225,13 +230,16 @@ def test_leader_crash_releases_followers(tmp_path):
         deadline = time.time() + 180
         while time.time() < deadline:
             if leader.poll() is not None:
-                _, err = leader.communicate()
-                pytest.fail(f"leader died during bring-up:\n{err[-3000:]}")
+                pytest.fail(
+                    "leader died during bring-up:\n"
+                    + _stderr_tail(tmp_path, 0)
+                )
             line = leader.stdout.readline()
             if "LEADER HANGING" in line:
                 break
         leader.kill()
-        out, err = follower.communicate(timeout=60)
+        out, _ = follower.communicate(timeout=60)
+        err = _stderr_tail(tmp_path, 1, n=200_000)
         # two legitimate prompt-exit paths, neither of which is a hang:
         #  * rc=3 "LEADER LOST" — our lease watch fired first;
         #  * nonzero rc with jax's coordination-service fatal — the jax

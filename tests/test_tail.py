@@ -598,6 +598,13 @@ async def test_ejection_diverts_traffic_e2e():
         "taileject", _fleet_args(3, slow_idx=1, slow_factor=10.0)
     )
     try:
+        # one unscored request per worker first: a worker's first dispatch
+        # pays one-time costs (connection set-up, lazy imports) that, with
+        # two scored samples per worker, can push a HEALTHY worker's
+        # dispatch signal over the eject band on a loaded host
+        warm = RemoteEngine(PushRouter(client, RouterMode.ROUND_ROBIN))
+        for _ in range(3):
+            await _collect(warm, _req([1, 2, 3, 4], 2))
         clock = _Clock()
         scorer = HealthScorer(
             _cfg(alpha=0.8, eject_intervals=2, probe_every=10**9),

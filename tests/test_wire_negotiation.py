@@ -201,6 +201,9 @@ async def test_disjoint_range_fails_loudly_not_garbage():
             await writer.drain()
         except asyncio.IncompleteReadError:
             pass
+        finally:
+            # Server.wait_closed() (3.12+) waits for every connection
+            writer.close()
 
     srv = await asyncio.start_server(handle, "127.0.0.1", 0)
     host, port = srv.sockets[0].getsockname()[:2]
@@ -286,6 +289,8 @@ async def test_client_ignores_unknown_trailing_response_and_push_fields():
                 await writer.drain()
         except (asyncio.IncompleteReadError, ConnectionResetError):
             pass
+        finally:
+            writer.close()
 
     srv = await asyncio.start_server(handle, "127.0.0.1", 0)
     host, port = srv.sockets[0].getsockname()[:2]

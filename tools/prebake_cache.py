@@ -6,7 +6,7 @@ window compiling the engine's programs on first touch. Those compiles are
 deterministic functions of (model config, serve shape, jax/libtpu
 version) — so bake them at container-BUILD time instead:
 
-    DYN_JAX_CACHE_DIR=/opt/dynamo/jax_cache \
+    JAX_COMPILATION_CACHE_DIR=/opt/dynamo/jax_cache \
         python -m tools.prebake_cache --model-path /models/llama3-8b \
         --max-batch 64 --decode-horizon 4
 
@@ -91,12 +91,9 @@ def _build_runner(args):
 
 
 def prebake(args) -> dict:
-    from dynamo_tpu.runtime.config import (
-        default_jax_cache_dir,
-        setup_jax_compilation_cache,
-    )
+    from dynamo_tpu.runtime.config import setup_jax_compilation_cache
 
-    cache_dir = setup_jax_compilation_cache(default_jax_cache_dir())
+    cache_dir = setup_jax_compilation_cache()
     from dynamo_tpu.ops.sampling import MAX_EOS_IDS
 
     runner = _build_runner(args)
@@ -240,8 +237,9 @@ def prebake(args) -> dict:
 
 def main(argv=None) -> dict:
     ap = argparse.ArgumentParser(
-        description="compile the serve-shape program set into "
-        "DYN_JAX_CACHE_DIR ahead of serving"
+        description="compile the serve-shape program set into the "
+        "compilation cache (JAX_COMPILATION_CACHE_DIR, else "
+        "<checkout>/.jax_cache) ahead of serving"
     )
     ap.add_argument("--model-path", default=None, help="HF model dir")
     ap.add_argument("--tiny", action="store_true",
