@@ -1,0 +1,1 @@
+"""cellbench: the benchmark of record (see BENCHMARK.json and PERF.md)."""
