@@ -20,6 +20,7 @@ from dynamo_tpu.protocols.common import (
     StopConditions,
 )
 from dynamo_tpu.pipeline.nodes import Operator as PipelineOperator
+from dynamo_tpu.telemetry import trace as dtrace
 from dynamo_tpu.tokenizer import TokenizerWrapper
 
 
@@ -80,6 +81,10 @@ class SequenceDecoder:
 
     def step(self, output: LLMEngineOutput) -> StepResult:
         """Fold one engine delta; returns text to emit + finish state."""
+        with dtrace.phase("frontend.detokenize"):
+            return self._fold(output)
+
+    def _fold(self, output: LLMEngineOutput) -> StepResult:
         if self.finished is not None:
             return StepResult(finish_reason=self.finished)
         result = StepResult()

@@ -43,7 +43,6 @@ from dynamo_tpu.protocols.common import (
     PreprocessedRequest,
 )
 from dynamo_tpu.runtime.logging import get_logger
-from dynamo_tpu.telemetry import profile as dprofile
 from dynamo_tpu.telemetry import provenance as dprov
 from dynamo_tpu.telemetry import trace as dtrace
 from dynamo_tpu.telemetry.goodput import (
@@ -537,14 +536,6 @@ class JaxEngine:
         for name in list(seq.spans):
             self._sp_finish(seq, name)
 
-    def _sp_batch_event(self, active: list, label: str, **attrs) -> None:
-        """Mark one batched device dispatch on every member's decode span
-        (bounded per span so long generations can't grow without limit)."""
-        for seq in active:
-            sp = seq.spans.get("decode")
-            if sp is not None and len(sp.events) < 64:
-                sp.event(label, **attrs)
-
     def _observe_stream(self, seq: _Sequence, item: LLMEngineOutput) -> None:
         """Always-on phase histogram recording at the stream edge (what a
         consumer of this worker actually experiences): TTFT, prefill (the
@@ -556,7 +547,9 @@ class JaxEngine:
                 seq.t_first = now
                 ph.observe("ttft", (now - seq.t_arrival) * 1e3)
                 if seq.t_admitted is not None:
-                    ph.observe("prefill", (now - seq.t_admitted) * 1e3)
+                    waited = now - seq.t_admitted
+                    ph.observe("prefill", waited * 1e3)
+                    dtrace.observe_phase("prefill_wait", int(waited * 1e9))
             elif seq.t_last is not None:
                 ph.observe("inter_token", (now - seq.t_last) * 1e3)
             seq.t_last = now
@@ -708,31 +701,39 @@ class JaxEngine:
         lanes: int = 0,
         capacity: int = 0,
         tokens: int = 0,
+        ctx_tokens: int = 0,
+        horizon: int = 1,
     ) -> Any:
         """Run one device dispatch in the executor, visible to the
         stuck-horizon watchdog (and to fault injection). Callers hold
         self._device_lock. `lanes`/`capacity` (decode-family steps) and
-        `tokens` (prefill chunk size) feed the goodput ledger."""
+        `tokens` (prefill chunk size) feed the goodput ledger;
+        `ctx_tokens` (summed context of the live lanes) and `horizon` ride
+        the `loop.dispatch` phase into an open profile window."""
         slow_factor = 1.0
         if faults.active():
             inj = faults.get_injector()
             if inj is not None:
                 await inj.on_dispatch()
                 slow_factor = inj.dispatch_slow_factor()
-        run = fn
-        if dprofile.active():
-            # a profile window is open: name this dispatch on the device
-            # timeline so jax.profiler traces carry the same phase labels
-            # as the request spans
-            def run():
-                with dprofile.annotate(label):
-                    return fn()
+
+        def run():
+            # executor thread: the runner function and its fetch
+            with dtrace.phase("runner.call", label=label):
+                return fn()
 
         loop = asyncio.get_running_loop()
         self._dispatch_info = (label, time.monotonic())
         t0 = self._dispatch_info[1]
         try:
-            result = await loop.run_in_executor(None, run)
+            # event-loop thread: hop to the executor, upload, launch, fetch,
+            # and the wait for the event loop to resume this task
+            with dtrace.phase(
+                "loop.dispatch", label=label, lanes=lanes,
+                ctx_tokens=ctx_tokens, prefill_tokens=tokens,
+                horizon=horizon, first=label not in self._dispatch_ema,
+            ):
+                result = await loop.run_in_executor(None, run)
             if slow_factor > 1.0:
                 # injected gray-worker fault: stretch the dispatch to
                 # FACTOR times its real duration (the device did the work;
@@ -1408,70 +1409,89 @@ class JaxEngine:
     async def _engine_loop(self) -> None:
         loop = asyncio.get_running_loop()
         while not self._closed:
+            # one pass; every part of it is a process-level phase
+            # (telemetry/trace.py::phase), so its host time has a name in
+            # `/debug/goodput` and, while a profile window is open, on the
+            # device timeline
+            with dtrace.phase("loop.iter"):
+                if await self._loop_pass(loop):
+                    return
+
+    async def _stats_and_yield(self, admitted: bool) -> None:
+        with dtrace.phase("loop.stats"):
+            self._update_stats()
+        if not admitted:
+            with dtrace.phase("loop.yield"):
+                await asyncio.sleep(0)  # fairness for producers/consumers
+
+    async def _loop_pass(self, loop) -> bool:
+        """One pass of the engine loop; True when the loop must end."""
+        with dtrace.phase("loop.reap"):
             self._reap_cancelled()
-            self._process_landed()
-            await self._drain_offload()
-            # latch the QoS-degraded chunk size and per-step budget ONCE
-            # per iteration: apply_brownout can land from another task
-            # while a dispatch below is awaited, and a chunk_cap
-            # transition must wait for the next step boundary instead of
-            # re-slicing work already packed this iteration
-            self._step_chunk_tokens = self._chunk_tokens()
-            self._step_chunk_budget = self._chunk_budget()
+        self._process_landed()
+        await self._drain_offload()
+        # latch the QoS-degraded chunk size and per-step budget ONCE
+        # per iteration: apply_brownout can land from another task
+        # while a dispatch below is awaited, and a chunk_cap
+        # transition must wait for the next step boundary instead of
+        # re-slicing work already packed this iteration
+        self._step_chunk_tokens = self._chunk_tokens()
+        self._step_chunk_budget = self._chunk_budget()
+        with dtrace.phase("loop.admit"):
             admitted = await self._admit_phase(loop)
-            if self._prefilling:
-                active = [
-                    s
-                    for s in self.slots
-                    if s is not None
-                    and not s.pending_remote
-                    and not s.prefilling
-                ]
-                if active and self._can_mix(active):
-                    # unified mixed step: every decode lane AND up to
-                    # _step_chunk_budget prefill tokens in ONE device
-                    # program — the alternating-phase bubble disappears
-                    await self._mixed_step_phase(loop, active)
-                    self._update_stats()
-                    if not admitted:
-                        await asyncio.sleep(0)
-                    continue
-            # one chunk of at most one long prefill per iteration, so the
-            # decode step below never waits longer than one chunk
-            chunked = False
-            if self._prefilling:
-                await self._prefill_chunk_step(loop)
-                chunked = True
+        if self._prefilling:
             active = [
                 s
                 for s in self.slots
-                if s is not None and not s.pending_remote and not s.prefilling
+                if s is not None
+                and not s.pending_remote
+                and not s.prefilling
             ]
-            if not active:
-                if chunked:
+            if active and self._can_mix(active):
+                # unified mixed step: every decode lane AND up to
+                # _step_chunk_budget prefill tokens in ONE device
+                # program — the alternating-phase bubble disappears
+                await self._mixed_step_phase(loop, active)
+                await self._stats_and_yield(admitted)
+                return False
+        # one chunk of at most one long prefill per iteration, so the
+        # decode step below never waits longer than one chunk
+        chunked = False
+        if self._prefilling:
+            await self._prefill_chunk_step(loop)
+            chunked = True
+        active = [
+            s
+            for s in self.slots
+            if s is not None and not s.pending_remote and not s.prefilling
+        ]
+        if not active:
+            if chunked:
+                with dtrace.phase("loop.stats"):
                     self._update_stats()
-                    continue
-                pending = any(
-                    s is not None and (s.pending_remote or s.prefilling)
-                    for s in self.slots
-                )
-                if not self.waiting and not pending:
-                    self._wake.clear()
-                    if self._closed:
-                        return
-                    # idle (no work anywhere): the gap to the next
-                    # dispatch is not a phase bubble
-                    self.stats.goodput.mark_idle()
+                return False
+            pending = any(
+                s is not None and (s.pending_remote or s.prefilling)
+                for s in self.slots
+            )
+            if not self.waiting and not pending:
+                self._wake.clear()
+                if self._closed:
+                    return True
+                # idle (no work anywhere): the gap to the next
+                # dispatch is not a phase bubble
+                self.stats.goodput.mark_idle()
+                with dtrace.phase("loop.idle"):
                     await self._wake.wait()
-                else:
-                    # remote prefills in flight (or unadmittable backlog):
-                    # yield without busy-spinning
+            else:
+                # remote prefills in flight (or unadmittable backlog):
+                # yield without busy-spinning
+                with dtrace.phase("loop.yield"):
                     await asyncio.sleep(0.001)
-                continue
-            await self._decode_phase(loop, active)
-            self._update_stats()
-            if not admitted:
-                await asyncio.sleep(0)  # fairness for producers/consumers
+            return False
+        await self._decode_phase(loop, active)
+        await self._stats_and_yield(admitted)
+        return False
 
     def _reap_cancelled(self) -> None:
         for seq in list(self.waiting):
@@ -1560,9 +1580,9 @@ class JaxEngine:
             admitted = True
             if seq.t_admitted is None:  # first admission (not a resume)
                 seq.t_admitted = time.monotonic()
-                self.stats.phase_histograms.observe(
-                    "queue_wait", (seq.t_admitted - seq.t_arrival) * 1e3
-                )
+                waited = seq.t_admitted - seq.t_arrival
+                self.stats.phase_histograms.observe("queue_wait", waited * 1e3)
+                dtrace.observe_phase("queue_wait", int(waited * 1e9))
             if seq.spans:
                 self._sp_finish(seq, "queue_wait")
             # multimodal sequences (vision embeddings in extra["mm"]):
@@ -1705,13 +1725,14 @@ class JaxEngine:
                     ),
                     tokens=len(replay),
                 )
-            # the admission pass may have prebuilt the identical chain for
-            # the prefix lookup — reuse instead of re-hashing the prompt
-            seq.hash_seq = seq.pending_chain or TokenBlockSequence(
-                replay, self.config.block_size
-            )
-            self._emit_stored(seq)
-            self._append_sample(seq, sample)
+            with dtrace.phase("loop.emit"):
+                # the admission pass may have prebuilt the identical chain
+                # for the prefix lookup — reuse instead of re-hashing
+                seq.hash_seq = seq.pending_chain or TokenBlockSequence(
+                    replay, self.config.block_size
+                )
+                self._emit_stored(seq)
+                self._append_sample(seq, sample)
         # flush the packed batches: greedily fill the token budget, one
         # program launch per group (TTFT under many short prompts scales
         # with ceil(total_tokens / budget), not with request count)
@@ -1734,11 +1755,12 @@ class JaxEngine:
         chain is built — the chain keys on token ids only, and two prompts
         with different images share identical placeholder tokens, so
         emitting Stored events would poison prefix routing."""
-        embeds = mm["embeds"]
-        if not hasattr(embeds, "devices"):  # host payload (wire path)
-            embeds = np.asarray(embeds, np.float32)
-        start = int(mm["start"])
-        key_row = self._key_row(seq)
+        with dtrace.phase("loop.pack"):
+            embeds = mm["embeds"]
+            if not hasattr(embeds, "devices"):  # host payload (wire path)
+                embeds = np.asarray(embeds, np.float32)
+            start = int(mm["start"])
+            key_row = self._key_row(seq)
         async with self._device_lock:
             sample = await self._dispatch(
                 "prefill_mm",
@@ -1759,20 +1781,22 @@ class JaxEngine:
                 ),
                 tokens=len(seq.token_ids),
             )
-        self._append_sample(seq, sample)
+        with dtrace.phase("loop.emit"):
+            self._append_sample(seq, sample)
 
     async def _run_packed_prefill(
         self, loop, group: list[_Sequence]
     ) -> None:
-        specs = [
-            (
-                list(s.token_ids), s.block_ids, s.temperature, s.top_p,
-                s.top_k, s.rep_pen, self._key_row(s), s.eos_row,
-                s.needs_eos_suppress,
-            )
-            for s in group
-        ]
-        packed = self.runner.pack_prefill(specs)
+        with dtrace.phase("loop.pack"):
+            specs = [
+                (
+                    list(s.token_ids), s.block_ids, s.temperature, s.top_p,
+                    s.top_k, s.rep_pen, self._key_row(s), s.eos_row,
+                    s.needs_eos_suppress,
+                )
+                for s in group
+            ]
+            packed = self.runner.pack_prefill(specs)
         async with self._device_lock:
             sample = await self._dispatch(
                 "prefill_packed",
@@ -1781,18 +1805,19 @@ class JaxEngine:
                 ),
                 tokens=sum(len(s.token_ids) for s in group),
             )
-        toks, lps, tids, tlps = sample
-        for i, seq in enumerate(group):
-            if seq.slot is None:  # cancelled during the device call
-                continue
-            seq.hash_seq = seq.pending_chain or TokenBlockSequence(
-                list(seq.token_ids), self.config.block_size
-            )
-            self._emit_stored(seq)
-            self._append_token(
-                seq, int(toks[i]), lp=float(lps[i]),
-                top_ids=tids[i], top_lps=tlps[i],
-            )
+        with dtrace.phase("loop.emit"):
+            toks, lps, tids, tlps = sample
+            for i, seq in enumerate(group):
+                if seq.slot is None:  # cancelled during the device call
+                    continue
+                seq.hash_seq = seq.pending_chain or TokenBlockSequence(
+                    list(seq.token_ids), self.config.block_size
+                )
+                self._emit_stored(seq)
+                self._append_token(
+                    seq, int(toks[i]), lp=float(lps[i]),
+                    top_ids=tids[i], top_lps=tlps[i],
+                )
 
     async def _prefill_chunk_step(self, loop) -> None:
         """Run ONE chunk of the oldest in-progress chunked prefill."""
@@ -1801,12 +1826,13 @@ class JaxEngine:
             if seq in self._prefilling:
                 self._prefilling.remove(seq)
             return
-        c = self._step_chunk_tokens
-        start = seq.prefill_pos
-        total = len(seq.token_ids)
-        chunk = seq.token_ids[start : start + c]
-        key_row = self._key_row(seq)
-        final = start + c >= total
+        with dtrace.phase("loop.pack"):
+            c = self._step_chunk_tokens
+            start = seq.prefill_pos
+            total = len(seq.token_ids)
+            chunk = seq.token_ids[start : start + c]
+            key_row = self._key_row(seq)
+            final = start + c >= total
         async with self._device_lock:
             # only the FINAL chunk's sample is consumed; syncing the
             # fetch on intermediate chunks would leave the device idle for
@@ -1826,21 +1852,22 @@ class JaxEngine:
             sample = await self._dispatch(
                 "prefill_chunk", run_chunk, tokens=len(chunk)
             )
-        if seq.spans:
-            sp = seq.spans.get("prefill")
-            if sp is not None and len(sp.events) < 64:
-                sp.event("prefill_chunk", pos=start, tokens=len(chunk))
-        if seq.slot is None:  # cancelled during the device call
-            return
-        seq.prefill_pos = min(start + c, total)
-        if seq.prefill_pos >= total:
-            self._prefilling.remove(seq)
-            seq.prefilling = False
-            seq.hash_seq = seq.pending_chain or TokenBlockSequence(
-                list(seq.token_ids), self.config.block_size
-            )
-            self._emit_stored(seq)
-            self._append_sample(seq, sample)
+        with dtrace.phase("loop.emit"):
+            if seq.spans:
+                sp = seq.spans.get("prefill")
+                if sp is not None and len(sp.events) < 64:
+                    sp.event("prefill_chunk", pos=start, tokens=len(chunk))
+            if seq.slot is None:  # cancelled during the device call
+                return
+            seq.prefill_pos = min(start + c, total)
+            if seq.prefill_pos >= total:
+                self._prefilling.remove(seq)
+                seq.prefilling = False
+                seq.hash_seq = seq.pending_chain or TokenBlockSequence(
+                    list(seq.token_ids), self.config.block_size
+                )
+                self._emit_stored(seq)
+                self._append_sample(seq, sample)
 
     def _can_mix(self, active: list[_Sequence]) -> bool:
         """One mixed program can replace this iteration's prefill-chunk +
@@ -1870,72 +1897,74 @@ class JaxEngine:
         chunks of several prompts, may share a step). A single
         fetch_sample round trip syncs the decode samples together with the
         samples of any chunk that finished its prompt."""
-        C = self._step_chunk_tokens
-        budget = self._step_chunk_budget
-        # -- pack prefill chunks (decode lanes are already committed) ----
-        chunks: list[tuple] = []
-        packed: list[tuple[_Sequence, int, int]] = []  # (seq, start, n)
-        plan: list[tuple[_Sequence, int]] = []  # per-seq total advance
-        for seq in sorted(self._prefilling, key=self._queue_key):
-            if seq.slot is None:  # freed while queued
-                self._prefilling.remove(seq)
-                continue
-            if budget <= 0 or len(chunks) >= self._mixed_max_slots:
-                break
-            total = len(seq.token_ids)
-            pos = seq.prefill_pos
-            advanced = 0
-            key_row = self._key_row(seq)
-            while (
-                pos < total
-                and budget > 0
-                and len(chunks) < self._mixed_max_slots
-            ):
-                n = min(C, total - pos, budget)
-                chunks.append((
-                    seq.token_ids[pos : pos + n], pos, total,
-                    seq.block_ids, seq.temperature, seq.top_p, seq.top_k,
-                    seq.rep_pen, key_row, seq.eos_row,
-                    seq.needs_eos_suppress,
-                ))
-                packed.append((seq, pos, n))
-                pos += n
-                budget -= n
-                advanced += n
-            if advanced:
-                plan.append((seq, advanced))
-        if not chunks:
-            # every in-flight prefill vanished under us; plain decode
-            await self._decode_single_phase(loop, active)
-            return
-        # -- fill the decode lanes (single-step semantics; the eos-mask
-        # variant always runs — neutral rows are a bitwise no-op) --------
-        from dynamo_tpu.ops.sampling import MAX_EOS_IDS
+        with dtrace.phase("loop.pack"):
+            C = self._step_chunk_tokens
+            budget = self._step_chunk_budget
+            # -- pack prefill chunks (decode lanes are already committed) ----
+            chunks: list[tuple] = []
+            packed: list[tuple[_Sequence, int, int]] = []  # (seq, start, n)
+            plan: list[tuple[_Sequence, int]] = []  # per-seq total advance
+            for seq in sorted(self._prefilling, key=self._queue_key):
+                if seq.slot is None:  # freed while queued
+                    self._prefilling.remove(seq)
+                    continue
+                if budget <= 0 or len(chunks) >= self._mixed_max_slots:
+                    break
+                total = len(seq.token_ids)
+                pos = seq.prefill_pos
+                advanced = 0
+                key_row = self._key_row(seq)
+                while (
+                    pos < total
+                    and budget > 0
+                    and len(chunks) < self._mixed_max_slots
+                ):
+                    n = min(C, total - pos, budget)
+                    chunks.append((
+                        seq.token_ids[pos : pos + n], pos, total,
+                        seq.block_ids, seq.temperature, seq.top_p, seq.top_k,
+                        seq.rep_pen, key_row, seq.eos_row,
+                        seq.needs_eos_suppress,
+                    ))
+                    packed.append((seq, pos, n))
+                    pos += n
+                    budget -= n
+                    advanced += n
+                if advanced:
+                    plan.append((seq, advanced))
+            if not chunks:
+                # every in-flight prefill vanished under us; plain decode
+                await self._decode_single_phase(loop, active)
+                return
+            # -- fill the decode lanes (single-step semantics; the eos-mask
+            # variant always runs — neutral rows are a bitwise no-op) --------
+            from dynamo_tpu.ops.sampling import MAX_EOS_IDS
 
-        B = self.config.max_batch
-        self._block_tables.fill(0)
-        self._positions.fill(0)
-        self._slot_indices.fill(0)  # null block slot 0
-        self._temps.fill(0.0)
-        self._top_ps.fill(1.0)
-        self._top_ks.fill(0)
-        bs = self.config.block_size
-        eos_ids = np.full((B, MAX_EOS_IDS), -1, np.int32)
-        eos_sup = np.zeros(B, bool)
-        for seq in active:
-            pos = self._fill_lane(seq)
-            self._slot_indices[seq.slot] = (
-                seq.block_ids[pos // bs] * bs + pos % bs
-            )
-            eos_ids[seq.slot] = seq.eos_row
-            eos_sup[seq.slot] = seq.needs_eos_suppress
-        # chunk slots whose sample is consumed (prompt finishes there)
-        final_slots = [
-            i for i, (seq, start, n) in enumerate(packed)
-            if start + n >= len(seq.token_ids)
-        ]
-        k = len(chunks)
-        tokens_packed = sum(n for _, _, n in packed)
+            B = self.config.max_batch
+            self._block_tables.fill(0)
+            self._positions.fill(0)
+            self._slot_indices.fill(0)  # null block slot 0
+            self._temps.fill(0.0)
+            self._top_ps.fill(1.0)
+            self._top_ks.fill(0)
+            bs = self.config.block_size
+            eos_ids = np.full((B, MAX_EOS_IDS), -1, np.int32)
+            eos_sup = np.zeros(B, bool)
+            for seq in active:
+                pos = self._fill_lane(seq)
+                self._slot_indices[seq.slot] = (
+                    seq.block_ids[pos // bs] * bs + pos % bs
+                )
+                eos_ids[seq.slot] = seq.eos_row
+                eos_sup[seq.slot] = seq.needs_eos_suppress
+            # chunk slots whose sample is consumed (prompt finishes there)
+            final_slots = [
+                i for i, (seq, start, n) in enumerate(packed)
+                if start + n >= len(seq.token_ids)
+            ]
+            k = len(chunks)
+            tokens_packed = sum(n for _, _, n in packed)
+            ctx_tokens = self._ctx_tokens(active)
         async with self._device_lock:
 
             def run_mixed():
@@ -1954,45 +1983,45 @@ class JaxEngine:
             out = await self._dispatch(
                 f"mixed_step@c{k}", run_mixed,
                 lanes=len(active), capacity=B, tokens=tokens_packed,
+                ctx_tokens=ctx_tokens,
             )
-        final_samples = {
-            slot: out[4 * j : 4 * j + 4]
-            for j, slot in enumerate(final_slots)
-        }
-        d_sample = out[4 * len(final_slots) :]
-        # -- prefill bookkeeping (chunk events, advance, finalize) -------
-        for seq, start, n in packed:
-            if seq.spans:
-                sp = seq.spans.get("prefill")
-                if sp is not None and len(sp.events) < 64:
-                    sp.event("prefill_chunk", pos=start, tokens=n)
-        for seq, advanced in plan:
-            if seq.slot is None:  # cancelled during the device call
-                continue
-            total = len(seq.token_ids)
-            seq.prefill_pos = min(seq.prefill_pos + advanced, total)
-            if seq.prefill_pos >= total:
-                self._prefilling.remove(seq)
-                seq.prefilling = False
-                seq.hash_seq = seq.pending_chain or TokenBlockSequence(
-                    list(seq.token_ids), self.config.block_size
+        with dtrace.phase("loop.emit"):
+            final_samples = {
+                slot: out[4 * j : 4 * j + 4]
+                for j, slot in enumerate(final_slots)
+            }
+            d_sample = out[4 * len(final_slots) :]
+            # -- prefill bookkeeping (chunk events, advance, finalize) -------
+            for seq, start, n in packed:
+                if seq.spans:
+                    sp = seq.spans.get("prefill")
+                    if sp is not None and len(sp.events) < 64:
+                        sp.event("prefill_chunk", pos=start, tokens=n)
+            for seq, advanced in plan:
+                if seq.slot is None:  # cancelled during the device call
+                    continue
+                total = len(seq.token_ids)
+                seq.prefill_pos = min(seq.prefill_pos + advanced, total)
+                if seq.prefill_pos >= total:
+                    self._prefilling.remove(seq)
+                    seq.prefilling = False
+                    seq.hash_seq = seq.pending_chain or TokenBlockSequence(
+                        list(seq.token_ids), self.config.block_size
+                    )
+                    self._emit_stored(seq)
+            for i, (seq, start, n) in enumerate(packed):
+                if i in final_samples and seq.slot is not None:
+                    self._append_sample(seq, final_samples[i])
+            # -- decode bookkeeping ------------------------------------------
+            toks, lps, tids, tlps = d_sample
+            for seq in active:
+                if seq.slot is None:
+                    continue  # finished/cancelled concurrently
+                i = seq.slot
+                self._append_token(
+                    seq, int(toks[i]), lp=float(lps[i]),
+                    top_ids=tids[i], top_lps=tlps[i],
                 )
-                self._emit_stored(seq)
-        for i, (seq, start, n) in enumerate(packed):
-            if i in final_samples and seq.slot is not None:
-                self._append_sample(seq, final_samples[i])
-        # -- decode bookkeeping ------------------------------------------
-        if dtrace.enabled():
-            self._sp_batch_event(active, "decode_step", batch=len(active))
-        toks, lps, tids, tlps = d_sample
-        for seq in active:
-            if seq.slot is None:
-                continue  # finished/cancelled concurrently
-            i = seq.slot
-            self._append_token(
-                seq, int(toks[i]), lp=float(lps[i]),
-                top_ids=tids[i], top_lps=tlps[i],
-            )
 
     def _process_landed(self) -> None:
         """Complete landed remote prefills on the engine loop (serialized
@@ -2600,6 +2629,12 @@ class JaxEngine:
         finally:
             self.allocator.free(block_ids)
 
+    @staticmethod
+    def _ctx_tokens(active: list[_Sequence]) -> int:
+        """Summed context of the live lanes: what a decode step's attention
+        reads, carried by the `loop.dispatch` phase."""
+        return sum(len(s.token_ids) for s in active)
+
     def _lane_remaining(self, seq: _Sequence) -> int:
         """Tokens this lane may still emit (max_new and model-length caps)."""
         return max(
@@ -2676,12 +2711,16 @@ class JaxEngine:
     async def _decode_phase(self, loop, active: list[_Sequence]) -> None:
         # brownout >= spec_off pauses drafting: the verify premium and
         # drafter host time go back to real tokens while the SLO burns
-        if self.drafter is not None and not self._spec_paused:
-            drafts = self._collect_drafts(active)
-            if drafts is not None:
-                await self._spec_decode_phase(loop, active, drafts)
-                return
-        H = self._horizon_for(active)
+        with dtrace.phase("loop.pack"):
+            # drafting and the horizon's block preallocation are host work
+            # of this dispatch, like the lane arrays built below
+            drafts = None
+            if self.drafter is not None and not self._spec_paused:
+                drafts = self._collect_drafts(active)
+            H = self._horizon_for(active) if drafts is None else 1
+        if drafts is not None:
+            await self._spec_decode_phase(loop, active, drafts)
+            return
         if H > 1:
             await self._decode_multi_phase(loop, active, H)
             return
@@ -2690,62 +2729,63 @@ class JaxEngine:
     async def _decode_single_phase(
         self, loop, active: list[_Sequence]
     ) -> None:
-        B = self.config.max_batch
-        self._block_tables.fill(0)
-        self._positions.fill(0)
-        self._slot_indices.fill(0)  # null block slot 0
-        self._temps.fill(0.0)
-        self._top_ps.fill(1.0)
-        self._top_ks.fill(0)
-        bs = self.config.block_size
-        for seq in active:
-            pos = self._fill_lane(seq)
-            self._slot_indices[seq.slot] = (
-                seq.block_ids[pos // bs] * bs + pos % bs
-            )
-        penalties = None
-        eos_mask = None
-        any_pen = any(seq.has_penalties for seq in active)
-        any_eos = any(seq.needs_eos_suppress for seq in active)
-        if any_eos and not any_pen:
-            # min_tokens-only batch: EOS masking needs no token history —
-            # skip the [B, L] upload the penalty program pays every step
-            from dynamo_tpu.ops.sampling import MAX_EOS_IDS
-
-            eos_ids = np.full((B, MAX_EOS_IDS), -1, np.int32)
-            eos_sup = np.zeros(B, bool)
+        with dtrace.phase("loop.pack"):
+            B = self.config.max_batch
+            self._block_tables.fill(0)
+            self._positions.fill(0)
+            self._slot_indices.fill(0)  # null block slot 0
+            self._temps.fill(0.0)
+            self._top_ps.fill(1.0)
+            self._top_ks.fill(0)
+            bs = self.config.block_size
             for seq in active:
-                eos_ids[seq.slot] = seq.eos_row
-                eos_sup[seq.slot] = seq.needs_eos_suppress
-            eos_mask = (eos_ids, eos_sup)
-        elif any_pen:
-            # full-history penalties ride a separate (lazily compiled)
-            # program; the plain path never pays the [B, L] input
-            L = self.config.max_model_len
-            hist = np.zeros((B, L), np.int32)
-            hist_len = np.zeros(B, np.int32)
-            prompt_len = np.zeros(B, np.int32)
-            freq = np.zeros(B, np.float32)
-            pres = np.zeros(B, np.float32)
-            rep = np.ones(B, np.float32)
-            from dynamo_tpu.ops.sampling import MAX_EOS_IDS
+                pos = self._fill_lane(seq)
+                self._slot_indices[seq.slot] = (
+                    seq.block_ids[pos // bs] * bs + pos % bs
+                )
+            penalties = None
+            eos_mask = None
+            any_pen = any(seq.has_penalties for seq in active)
+            any_eos = any(seq.needs_eos_suppress for seq in active)
+            if any_eos and not any_pen:
+                # min_tokens-only batch: EOS masking needs no token history —
+                # skip the [B, L] upload the penalty program pays every step
+                from dynamo_tpu.ops.sampling import MAX_EOS_IDS
 
-            eos_ids = np.full((B, MAX_EOS_IDS), -1, np.int32)
-            eos_sup = np.zeros(B, bool)
-            for seq in active:
-                i = seq.slot
-                n = min(len(seq.token_ids), L)
-                hist[i, :n] = seq.token_ids[:n]
-                hist_len[i] = n
-                prompt_len[i] = min(seq.num_prompt, n)
-                freq[i] = seq.freq_pen
-                pres[i] = seq.pres_pen
-                rep[i] = seq.rep_pen
-                eos_ids[i] = seq.eos_row
-                eos_sup[i] = seq.needs_eos_suppress
-            penalties = (
-                hist, hist_len, prompt_len, freq, pres, rep, eos_ids, eos_sup
-            )
+                eos_ids = np.full((B, MAX_EOS_IDS), -1, np.int32)
+                eos_sup = np.zeros(B, bool)
+                for seq in active:
+                    eos_ids[seq.slot] = seq.eos_row
+                    eos_sup[seq.slot] = seq.needs_eos_suppress
+                eos_mask = (eos_ids, eos_sup)
+            elif any_pen:
+                # full-history penalties ride a separate (lazily compiled)
+                # program; the plain path never pays the [B, L] input
+                L = self.config.max_model_len
+                hist = np.zeros((B, L), np.int32)
+                hist_len = np.zeros(B, np.int32)
+                prompt_len = np.zeros(B, np.int32)
+                freq = np.zeros(B, np.float32)
+                pres = np.zeros(B, np.float32)
+                rep = np.ones(B, np.float32)
+                from dynamo_tpu.ops.sampling import MAX_EOS_IDS
+
+                eos_ids = np.full((B, MAX_EOS_IDS), -1, np.int32)
+                eos_sup = np.zeros(B, bool)
+                for seq in active:
+                    i = seq.slot
+                    n = min(len(seq.token_ids), L)
+                    hist[i, :n] = seq.token_ids[:n]
+                    hist_len[i] = n
+                    prompt_len[i] = min(seq.num_prompt, n)
+                    freq[i] = seq.freq_pen
+                    pres[i] = seq.pres_pen
+                    rep[i] = seq.rep_pen
+                    eos_ids[i] = seq.eos_row
+                    eos_sup[i] = seq.needs_eos_suppress
+                penalties = (
+                    hist, hist_len, prompt_len, freq, pres, rep, eos_ids, eos_sup
+                )
         async with self._device_lock:
             sample = await self._dispatch(
                 "decode",
@@ -2765,18 +2805,18 @@ class JaxEngine:
                 ),
                 lanes=len(active),
                 capacity=self.config.max_batch,
+                ctx_tokens=self._ctx_tokens(active),
             )
-        if dtrace.enabled():
-            self._sp_batch_event(active, "decode_step", batch=len(active))
-        toks, lps, tids, tlps = sample
-        for seq in active:
-            if seq.slot is None:
-                continue  # finished/cancelled concurrently
-            i = seq.slot
-            self._append_token(
-                seq, int(toks[i]), lp=float(lps[i]),
-                top_ids=tids[i], top_lps=tlps[i],
-            )
+        with dtrace.phase("loop.emit"):
+            toks, lps, tids, tlps = sample
+            for seq in active:
+                if seq.slot is None:
+                    continue  # finished/cancelled concurrently
+                i = seq.slot
+                self._append_token(
+                    seq, int(toks[i]), lp=float(lps[i]),
+                    top_ids=tids[i], top_lps=tlps[i],
+                )
 
     def _collect_drafts(
         self, active: list[_Sequence]
@@ -2831,81 +2871,82 @@ class JaxEngine:
         draft only decides how many weight reads those tokens cost."""
         from dynamo_tpu.ops.sampling import MAX_EOS_IDS
 
-        B = self.config.max_batch
-        K = self.config.spec_k
-        bs = self.config.block_size
-        any_pen = any(s.has_penalties for s in active)
-        # chained continuation after the verify pass (the RTT-amortizing
-        # horizon): penalty batches run verify-only — the device count
-        # tables can't subtract a rejected draft back out
-        E = 0
-        if self.config.decode_horizon > 1 and not any_pen:
-            if not self.config.lazy_horizon or (
-                hasattr(self.runner, "decode_multi_ready")
-                and self.runner.decode_multi_ready(self.config.decode_horizon)
-            ):
-                E = self.config.decode_horizon - 1
-        # preallocate KV blocks for every potential write this dispatch
-        # (same formula as _horizon_for: the last emitted token is never
-        # fed, so writes cover lane_steps - 1 positions past pos-1)
-        for seq in active:
-            d = drafts.get(seq.seq_id) or []
-            lane_steps = min(len(d) + 1 + E, self._lane_remaining(seq))
-            last_write = (seq.pos - 1) + (lane_steps - 1)
-            need = last_write // bs + 1 - len(seq.block_ids)
-            if need > 0:
-                try:
-                    seq.block_ids.extend(self.allocator.alloc(need))
-                except OutOfBlocks:
-                    # block pressure: fall back to single-step (its
-                    # just-in-time alloc can preempt)
-                    await self._decode_single_phase(loop, active)
-                    return
-        self._block_tables.fill(0)
-        self._positions.fill(0)
-        self._temps.fill(0.0)
-        self._top_ps.fill(1.0)
-        self._top_ks.fill(0)
-        act = np.zeros(B, bool)
-        limit_rem = np.ones(B, np.int32)
-        min_rem = np.zeros(B, np.int32)
-        eos_ids = np.full((B, MAX_EOS_IDS), -1, np.int32)
-        draft_arr = np.full((B, K), -1, np.int32)
-        draft_len = np.zeros(B, np.int32)
-        for seq in active:
-            i = seq.slot
-            self._fill_lane(seq)
-            act[i] = True
-            limit_rem[i] = self._lane_remaining(seq)
-            min_rem[i] = max(0, seq.min_tokens - seq.num_generated)
-            eos_ids[i] = seq.eos_row
-            d = drafts.get(seq.seq_id) or []
-            draft_len[i] = len(d)
-            if d:
-                draft_arr[i, : len(d)] = d
-                self.stats.num_drafts += 1
-                self.stats.num_draft_tokens += len(d)
-        penalties = None
-        if any_pen:
-            # one [B, L] upload per dispatch, scattered to count tables on
-            # device — identical contract to _decode_multi_phase
-            L = self.config.max_model_len
-            hist = np.zeros((B, L), np.int32)
-            hist_len = np.zeros(B, np.int32)
-            prompt_len = np.zeros(B, np.int32)
-            freq = np.zeros(B, np.float32)
-            pres = np.zeros(B, np.float32)
-            rep = np.ones(B, np.float32)
+        with dtrace.phase("loop.pack"):
+            B = self.config.max_batch
+            K = self.config.spec_k
+            bs = self.config.block_size
+            any_pen = any(s.has_penalties for s in active)
+            # chained continuation after the verify pass (the RTT-amortizing
+            # horizon): penalty batches run verify-only — the device count
+            # tables can't subtract a rejected draft back out
+            E = 0
+            if self.config.decode_horizon > 1 and not any_pen:
+                if not self.config.lazy_horizon or (
+                    hasattr(self.runner, "decode_multi_ready")
+                    and self.runner.decode_multi_ready(self.config.decode_horizon)
+                ):
+                    E = self.config.decode_horizon - 1
+            # preallocate KV blocks for every potential write this dispatch
+            # (same formula as _horizon_for: the last emitted token is never
+            # fed, so writes cover lane_steps - 1 positions past pos-1)
+            for seq in active:
+                d = drafts.get(seq.seq_id) or []
+                lane_steps = min(len(d) + 1 + E, self._lane_remaining(seq))
+                last_write = (seq.pos - 1) + (lane_steps - 1)
+                need = last_write // bs + 1 - len(seq.block_ids)
+                if need > 0:
+                    try:
+                        seq.block_ids.extend(self.allocator.alloc(need))
+                    except OutOfBlocks:
+                        # block pressure: fall back to single-step (its
+                        # just-in-time alloc can preempt)
+                        await self._decode_single_phase(loop, active)
+                        return
+            self._block_tables.fill(0)
+            self._positions.fill(0)
+            self._temps.fill(0.0)
+            self._top_ps.fill(1.0)
+            self._top_ks.fill(0)
+            act = np.zeros(B, bool)
+            limit_rem = np.ones(B, np.int32)
+            min_rem = np.zeros(B, np.int32)
+            eos_ids = np.full((B, MAX_EOS_IDS), -1, np.int32)
+            draft_arr = np.full((B, K), -1, np.int32)
+            draft_len = np.zeros(B, np.int32)
             for seq in active:
                 i = seq.slot
-                n = min(len(seq.token_ids), L)
-                hist[i, :n] = seq.token_ids[:n]
-                hist_len[i] = n
-                prompt_len[i] = min(seq.num_prompt, n)
-                freq[i] = seq.freq_pen
-                pres[i] = seq.pres_pen
-                rep[i] = seq.rep_pen
-            penalties = (hist, hist_len, prompt_len, freq, pres, rep)
+                self._fill_lane(seq)
+                act[i] = True
+                limit_rem[i] = self._lane_remaining(seq)
+                min_rem[i] = max(0, seq.min_tokens - seq.num_generated)
+                eos_ids[i] = seq.eos_row
+                d = drafts.get(seq.seq_id) or []
+                draft_len[i] = len(d)
+                if d:
+                    draft_arr[i, : len(d)] = d
+                    self.stats.num_drafts += 1
+                    self.stats.num_draft_tokens += len(d)
+            penalties = None
+            if any_pen:
+                # one [B, L] upload per dispatch, scattered to count tables on
+                # device — identical contract to _decode_multi_phase
+                L = self.config.max_model_len
+                hist = np.zeros((B, L), np.int32)
+                hist_len = np.zeros(B, np.int32)
+                prompt_len = np.zeros(B, np.int32)
+                freq = np.zeros(B, np.float32)
+                pres = np.zeros(B, np.float32)
+                rep = np.ones(B, np.float32)
+                for seq in active:
+                    i = seq.slot
+                    n = min(len(seq.token_ids), L)
+                    hist[i, :n] = seq.token_ids[:n]
+                    hist_len[i] = n
+                    prompt_len[i] = min(seq.num_prompt, n)
+                    freq[i] = seq.freq_pen
+                    pres[i] = seq.pres_pen
+                    rep[i] = seq.rep_pen
+                penalties = (hist, hist_len, prompt_len, freq, pres, rep)
         async with self._device_lock:
             packed = await self._dispatch(
                 "spec_verify",
@@ -2921,73 +2962,72 @@ class JaxEngine:
                 ),
                 lanes=len(active),
                 capacity=self.config.max_batch,
+                ctx_tokens=self._ctx_tokens(active),
+                horizon=1 + E,
             )
-        if dtrace.enabled():
-            self._sp_batch_event(
-                active, "spec_verify", K=K, E=E, batch=len(active)
-            )
-        K2 = (packed.shape[-1] - 2) // 2
-        # verify rows: accept the longest prefix of drafts matching the
-        # model's own tokens, then the bonus token
-        for seq in active:
-            if seq.slot is None:
-                continue
-            i = seq.slot
-            d = drafts.get(seq.seq_id) or []
-            lane_accepted = 0
-            for h in range(len(d) + 1):
-                row = packed[h]
-                tok = int(row[i, 0])
-                if tok < 0:
-                    break  # device marked the position invalid
-                accept = h < len(d) and d[h] == tok
-                self._append_token(
-                    seq, tok,
-                    lp=float(row[i, 1]),
-                    top_ids=row[i, 2:2 + K2].astype(np.int32),
-                    top_lps=row[i, 2 + K2:],
-                )
-                if accept:
-                    lane_accepted += 1
-                    self.stats.num_accepted_tokens += 1
-                    if h < len(self.stats.accepted_per_pos):
-                        self.stats.accepted_per_pos[h] += 1
-                if seq.slot is None or (h < len(d) and not accept):
-                    break
-            if d:
-                # verify premium paid for rejected draft positions: the
-                # device computed len(d)+1 positions but only
-                # lane_accepted drafts landed
-                self.stats.goodput.record_waste(
-                    "spec_rejected", len(d) - lane_accepted
-                )
-                if lane_accepted:
-                    seq.spec_fail = 0
-                else:
-                    # whole draft rejected: history stopped predicting —
-                    # exponentially back off this lane's drafting so the
-                    # verify premium isn't paid dispatch after dispatch
-                    # on low-repetition traffic
-                    seq.spec_fail += 1
-                    seq.spec_backoff = min(1 << seq.spec_fail, 32)
-        # continuation rows: plain chained decode tokens from the accept
-        # point (frozen lanes emit -1; a host-side finish above leaves
-        # slot None and the lane skips its rows)
-        for e in range(E):
-            row = packed[K + 1 + e]
+        with dtrace.phase("loop.emit"):
+            K2 = (packed.shape[-1] - 2) // 2
+            # verify rows: accept the longest prefix of drafts matching the
+            # model's own tokens, then the bonus token
             for seq in active:
                 if seq.slot is None:
                     continue
                 i = seq.slot
-                tok = int(row[i, 0])
-                if tok < 0:
-                    continue
-                self._append_token(
-                    seq, tok,
-                    lp=float(row[i, 1]),
-                    top_ids=row[i, 2:2 + K2].astype(np.int32),
-                    top_lps=row[i, 2 + K2:],
-                )
+                d = drafts.get(seq.seq_id) or []
+                lane_accepted = 0
+                for h in range(len(d) + 1):
+                    row = packed[h]
+                    tok = int(row[i, 0])
+                    if tok < 0:
+                        break  # device marked the position invalid
+                    accept = h < len(d) and d[h] == tok
+                    self._append_token(
+                        seq, tok,
+                        lp=float(row[i, 1]),
+                        top_ids=row[i, 2:2 + K2].astype(np.int32),
+                        top_lps=row[i, 2 + K2:],
+                    )
+                    if accept:
+                        lane_accepted += 1
+                        self.stats.num_accepted_tokens += 1
+                        if h < len(self.stats.accepted_per_pos):
+                            self.stats.accepted_per_pos[h] += 1
+                    if seq.slot is None or (h < len(d) and not accept):
+                        break
+                if d:
+                    # verify premium paid for rejected draft positions: the
+                    # device computed len(d)+1 positions but only
+                    # lane_accepted drafts landed
+                    self.stats.goodput.record_waste(
+                        "spec_rejected", len(d) - lane_accepted
+                    )
+                    if lane_accepted:
+                        seq.spec_fail = 0
+                    else:
+                        # whole draft rejected: history stopped predicting —
+                        # exponentially back off this lane's drafting so the
+                        # verify premium isn't paid dispatch after dispatch
+                        # on low-repetition traffic
+                        seq.spec_fail += 1
+                        seq.spec_backoff = min(1 << seq.spec_fail, 32)
+            # continuation rows: plain chained decode tokens from the accept
+            # point (frozen lanes emit -1; a host-side finish above leaves
+            # slot None and the lane skips its rows)
+            for e in range(E):
+                row = packed[K + 1 + e]
+                for seq in active:
+                    if seq.slot is None:
+                        continue
+                    i = seq.slot
+                    tok = int(row[i, 0])
+                    if tok < 0:
+                        continue
+                    self._append_token(
+                        seq, tok,
+                        lp=float(row[i, 1]),
+                        top_ids=row[i, 2:2 + K2].astype(np.int32),
+                        top_lps=row[i, 2 + K2:],
+                    )
 
     async def _decode_multi_phase(
         self, loop, active: list[_Sequence], H: int
@@ -3001,45 +3041,46 @@ class JaxEngine:
         finish reasons are identical — just H tokens per round trip."""
         from dynamo_tpu.ops.sampling import MAX_EOS_IDS
 
-        B = self.config.max_batch
-        self._block_tables.fill(0)
-        self._positions.fill(0)
-        self._temps.fill(0.0)
-        self._top_ps.fill(1.0)
-        self._top_ks.fill(0)
-        act = np.zeros(B, bool)
-        limit_rem = np.ones(B, np.int32)
-        min_rem = np.zeros(B, np.int32)
-        eos_ids = np.full((B, MAX_EOS_IDS), -1, np.int32)
-        for seq in active:
-            i = seq.slot
-            self._fill_lane(seq)
-            act[i] = True
-            limit_rem[i] = self._lane_remaining(seq)
-            min_rem[i] = max(0, seq.min_tokens - seq.num_generated)
-            eos_ids[i] = seq.eos_row
-        penalties = None
-        if any(seq.has_penalties for seq in active):
-            # one [B, L] upload per HORIZON (not per step): the program
-            # scatters it into count tables and maintains them on device;
-            # plain lanes run freq=0/pres=0/rep=1 (exact pass-through)
-            L = self.config.max_model_len
-            hist = np.zeros((B, L), np.int32)
-            hist_len = np.zeros(B, np.int32)
-            prompt_len = np.zeros(B, np.int32)
-            freq = np.zeros(B, np.float32)
-            pres = np.zeros(B, np.float32)
-            rep = np.ones(B, np.float32)
+        with dtrace.phase("loop.pack"):
+            B = self.config.max_batch
+            self._block_tables.fill(0)
+            self._positions.fill(0)
+            self._temps.fill(0.0)
+            self._top_ps.fill(1.0)
+            self._top_ks.fill(0)
+            act = np.zeros(B, bool)
+            limit_rem = np.ones(B, np.int32)
+            min_rem = np.zeros(B, np.int32)
+            eos_ids = np.full((B, MAX_EOS_IDS), -1, np.int32)
             for seq in active:
                 i = seq.slot
-                n = min(len(seq.token_ids), L)
-                hist[i, :n] = seq.token_ids[:n]
-                hist_len[i] = n
-                prompt_len[i] = min(seq.num_prompt, n)
-                freq[i] = seq.freq_pen
-                pres[i] = seq.pres_pen
-                rep[i] = seq.rep_pen
-            penalties = (hist, hist_len, prompt_len, freq, pres, rep)
+                self._fill_lane(seq)
+                act[i] = True
+                limit_rem[i] = self._lane_remaining(seq)
+                min_rem[i] = max(0, seq.min_tokens - seq.num_generated)
+                eos_ids[i] = seq.eos_row
+            penalties = None
+            if any(seq.has_penalties for seq in active):
+                # one [B, L] upload per HORIZON (not per step): the program
+                # scatters it into count tables and maintains them on device;
+                # plain lanes run freq=0/pres=0/rep=1 (exact pass-through)
+                L = self.config.max_model_len
+                hist = np.zeros((B, L), np.int32)
+                hist_len = np.zeros(B, np.int32)
+                prompt_len = np.zeros(B, np.int32)
+                freq = np.zeros(B, np.float32)
+                pres = np.zeros(B, np.float32)
+                rep = np.ones(B, np.float32)
+                for seq in active:
+                    i = seq.slot
+                    n = min(len(seq.token_ids), L)
+                    hist[i, :n] = seq.token_ids[:n]
+                    hist_len[i] = n
+                    prompt_len[i] = min(seq.num_prompt, n)
+                    freq[i] = seq.freq_pen
+                    pres[i] = seq.pres_pen
+                    rep[i] = seq.rep_pen
+                penalties = (hist, hist_len, prompt_len, freq, pres, rep)
         try:
             async with self._device_lock:
                 packed = await self._dispatch(
@@ -3057,6 +3098,8 @@ class JaxEngine:
                     ),
                     lanes=len(active),
                     capacity=self.config.max_batch,
+                    ctx_tokens=self._ctx_tokens(active),
+                    horizon=H,
                 )
         except Exception:  # noqa: BLE001
             if not self.config.lazy_horizon:
@@ -3079,27 +3122,24 @@ class JaxEngine:
                     if seq.slot is not None and not seq.pending_remote:
                         self._finish(seq, FinishReason.ERROR)
             return
-        if dtrace.enabled():
-            self._sp_batch_event(
-                active, "decode_horizon", H=H, batch=len(active)
-            )
-        K = (packed.shape[-1] - 2) // 2
-        for h in range(H):
-            step = packed[h]
-            for seq in active:
-                if seq.slot is None:
-                    continue  # finished earlier in this horizon
-                i = seq.slot
-                tok = int(step[i, 0])
-                if tok < 0:
-                    continue  # lane was frozen on device
-                self._append_token(
-                    seq,
-                    tok,
-                    lp=float(step[i, 1]),
-                    top_ids=step[i, 2:2 + K].astype(np.int32),
-                    top_lps=step[i, 2 + K:],
-                )
+        with dtrace.phase("loop.emit"):
+            K = (packed.shape[-1] - 2) // 2
+            for h in range(H):
+                step = packed[h]
+                for seq in active:
+                    if seq.slot is None:
+                        continue  # finished earlier in this horizon
+                    i = seq.slot
+                    tok = int(step[i, 0])
+                    if tok < 0:
+                        continue  # lane was frozen on device
+                    self._append_token(
+                        seq,
+                        tok,
+                        lp=float(step[i, 1]),
+                        top_ids=step[i, 2:2 + K].astype(np.int32),
+                        top_lps=step[i, 2 + K:],
+                    )
 
     def _append_sample(
         self, seq: _Sequence, sample: tuple[np.ndarray, ...]
@@ -3277,7 +3317,7 @@ class JaxEngine:
         from dynamo_tpu.engine.jax_engine import perf_model
 
         if active:
-            mean_ctx = sum(len(s.token_ids) for s in active) / len(active)
+            mean_ctx = self._ctx_tokens(active) / len(active)
             params = getattr(self.runner, "params", None)
             quant_w = False
             if isinstance(params, dict):

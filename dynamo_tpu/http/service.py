@@ -472,8 +472,11 @@ class ModelExecution:
                     counters["completion"] += step.tokens_emitted
                     if step.text or step.logprobs:
                         if timer:
-                            timer.on_token(max(step.tokens_emitted, 1))
-                        for chunk in emit_chunk(step, i):
+                            with dtrace.phase("frontend.sse"):
+                                timer.on_token(max(step.tokens_emitted, 1))
+                        with dtrace.phase("frontend.detokenize"):
+                            chunks = emit_chunk(step, i)  # text_chunk objects
+                        for chunk in chunks:
                             queue.put_nowait(("chunk", chunk))
                     if step.finish_reason is not None:
                         if step.finish_reason is FinishReason.ERROR:
@@ -520,7 +523,8 @@ class ModelExecution:
     async def chat_stream(
         self, request: ChatCompletionRequest, ctx: Context, timer: Optional[TokenTimer] = None
     ) -> AsyncIterator[Annotated]:
-        pre, prompt = self.preprocessor.preprocess_chat(request)
+        with dtrace.phase("frontend.preprocess"):
+            pre, prompt = self.preprocessor.preprocess_chat(request)
         pre.extra["echo_text"] = prompt  # feeds echo_full test engines
         qos.stamp_priority(pre, ctx)  # QoS class onto every wire hop
         for ann in self.preprocessor.requested_annotations(pre, prompt):
@@ -579,7 +583,9 @@ class ModelExecution:
                 finish_chat,
                 counters,
             ):
-                yield Annotated.from_data(chunk.model_dump(exclude_none=True))
+                with dtrace.phase("frontend.sse"):
+                    frame = chunk.model_dump(exclude_none=True)
+                yield Annotated.from_data(frame)
         except EngineStreamError as e:
             yield Annotated.from_error(json.dumps(e.payload))
             return
@@ -602,7 +608,8 @@ class ModelExecution:
     async def completion_stream(
         self, request: CompletionRequest, ctx: Context, timer: Optional[TokenTimer] = None
     ) -> AsyncIterator[Annotated]:
-        pre, prompt = self.preprocessor.preprocess_completion(request)
+        with dtrace.phase("frontend.preprocess"):
+            pre, prompt = self.preprocessor.preprocess_completion(request)
         pre.extra["echo_text"] = prompt
         qos.stamp_priority(pre, ctx)  # QoS class onto every wire hop
         gen = CompletionDeltaGenerator(request.model)
@@ -625,7 +632,9 @@ class ModelExecution:
                 lambda reason, i: [gen.finish_chunk(reason, index=i)],
                 counters,
             ):
-                yield Annotated.from_data(chunk.model_dump(exclude_none=True))
+                with dtrace.phase("frontend.sse"):
+                    frame = chunk.model_dump(exclude_none=True)
+                yield Annotated.from_data(frame)
         except EngineStreamError as e:
             yield Annotated.from_error(json.dumps(e.payload))
             return
@@ -1089,7 +1098,8 @@ class HttpService:
                 **self._resp_headers(ctx),
             },
         )
-        await resp.prepare(request)
+        with dtrace.phase("frontend.sse"):
+            await resp.prepare(request)  # status line and headers
         try:
             async for item in annotated_stream:
                 if item.is_error():
@@ -1127,7 +1137,12 @@ class HttpService:
                         ).encode()
                     )
                 elif item.data is not None:
-                    await resp.write(encode_json_event(item.data).encode())
+                    # the frame's bytes and the socket write; the write
+                    # awaits only when the transport pushes back
+                    with dtrace.phase("frontend.sse"):
+                        await resp.write(
+                            encode_json_event(item.data).encode()
+                        )
             await resp.write(encode_done().encode())
         except (ConnectionResetError, asyncio.CancelledError):
             # client went away: kill generation (reference openai.rs:725-811)
@@ -1142,9 +1157,10 @@ class HttpService:
             return self._draining_resp()
         try:
             body = await request.json()
-            if self.template is not None:
-                body = self.template.apply_chat(body)
-            chat_req = ChatCompletionRequest.model_validate(body)
+            with dtrace.phase("frontend.parse"):
+                if self.template is not None:
+                    body = self.template.apply_chat(body)
+                chat_req = ChatCompletionRequest.model_validate(body)
         except Exception as e:  # noqa: BLE001
             return self._error(400, f"invalid request: {e}")
         execution = self.manager.get(chat_req.model)
@@ -1214,9 +1230,10 @@ class HttpService:
             return self._draining_resp()
         try:
             body = await request.json()
-            if self.template is not None:
-                body = self.template.apply_completion(body)
-            comp_req = CompletionRequest.model_validate(body)
+            with dtrace.phase("frontend.parse"):
+                if self.template is not None:
+                    body = self.template.apply_completion(body)
+                comp_req = CompletionRequest.model_validate(body)
         except Exception as e:  # noqa: BLE001
             return self._error(400, f"invalid request: {e}")
         execution = self.manager.get(comp_req.model)
@@ -1504,6 +1521,11 @@ class HttpService:
         if summary is not None and hedge_tokens:
             summary["tokens_wasted"]["hedge_loser"] += hedge_tokens
             summary["tokens_wasted_total"] += hedge_tokens
+        if summary is not None:
+            # this process's phase table (telemetry/trace.py::phase): the
+            # engine loop's and the frontend's host time by name, and the
+            # request phases queue_wait and prefill_wait
+            summary["phases"] = dtrace.phase_summary()
         body: dict[str, Any] = {
             "scope": "frontend",
             "enabled": dgoodput.enabled_from_env(),

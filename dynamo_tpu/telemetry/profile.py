@@ -3,28 +3,30 @@
 Role-equivalent of the reference's `nsys`-oriented profiling hooks, TPU-
 native: `jax.profiler` traces (viewable in TensorBoard / Perfetto) are
 started on demand — `/debug/profile?seconds=N` on the frontend, or
-programmatically — into `DYN_PROFILE_DIR`. While a window is open, engine
-dispatches annotate themselves (`annotate(label)`), so the device timeline
-carries the same phase names as the request traces.
+programmatically — into `DYN_PROFILE_DIR`. While a window is open, every
+process-level phase (`telemetry/trace.py::phase`: the engine loop's passes
+and dispatches, the frontend's synchronous bodies) is also a profiler
+annotation `dyn:<name>`, so the host's phases lie on the device timeline's
+own clock. The window is
+the switch: with none open a phase costs two clock readings.
 
 Everything degrades gracefully without JAX (mocker/echo deployments):
-`start()` reports the error instead of raising, `annotate()` is a no-op.
+`start()` reports the error instead of raising.
 """
 
 from __future__ import annotations
 
 import asyncio
-import contextlib
 import os
 import threading
 import time
-from typing import Any, Iterator, Optional
+from typing import Any, Optional
 
 from dynamo_tpu.runtime.logging import get_logger
 
 logger = get_logger("dynamo_tpu.telemetry.profile")
 
-_active: bool = False  # fast flag for the dispatch-annotation hot path
+_active: bool = False  # fast flag read by trace.phase on its hot path
 _lock = threading.Lock()
 _session: Optional[dict[str, Any]] = None
 
@@ -94,19 +96,3 @@ async def run_window(seconds: float, out_dir: Optional[str] = None) -> dict:
     if "error" not in info:
         await asyncio.sleep(seconds)
     return info
-
-
-@contextlib.contextmanager
-def annotate(label: str) -> Iterator[None]:
-    """Name the current device dispatch on the profiler timeline. No-op
-    unless a profile window is open (one flag check on the hot path)."""
-    if not _active:
-        yield
-        return
-    try:
-        import jax
-
-        with jax.profiler.TraceAnnotation(label):
-            yield
-    except Exception:  # noqa: BLE001 — annotation must never break serving
-        yield

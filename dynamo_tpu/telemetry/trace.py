@@ -30,6 +30,8 @@ import uuid
 from collections import OrderedDict, deque
 from typing import Any, Iterator, Optional
 
+from dynamo_tpu.telemetry import profile as dprofile
+
 # Trace context key inside Context.metadata (rides Context.to_header).
 CTX_KEY = "trace"
 
@@ -618,6 +620,119 @@ def counter(name: str, value: float) -> None:
     if not _enabled:
         return
     tracer().record_counter(name, value)
+
+
+# ---------------------------------------------------- process-level phases
+#
+# Work that belongs to no one request (the engine loop's passes, the
+# frontend's synchronous bodies). Always on: a count and two sums per name
+# in one process-wide table, in the class of the goodput ledger and held to
+# its overhead guard. While a device-profile window is open
+# (telemetry/profile.py) the same interval is also a profiler annotation
+# "dyn:<name>", so it lies in the .xplane.pb on the clock of the device's
+# operations. The table leaves the process in `GET /debug/goodput`.
+
+# name -> [count, total_ns, self_ns]. The hot path takes no lock: under
+# CPython's lock each `slot[i] += n` on integers has no point at which
+# threads switch, and a name's first slot is made under `_phase_lock`.
+_phase_table: dict[str, list[int]] = {}
+_phase_lock = threading.Lock()
+# the enclosing phase of the same TASK (not thread): `loop.dispatch` spans an
+# await during which the same thread runs the frontend's tasks, and a
+# per-thread stack would make those its children
+_current_phase: contextvars.ContextVar[Optional["phase"]] = (
+    contextvars.ContextVar("dyn_trace_phase", default=None)
+)
+_monotonic_ns = time.monotonic_ns
+_phase_set = _current_phase.set
+_phase_reset = _current_phase.reset
+
+
+def _phase_slot(name: str) -> list[int]:
+    with _phase_lock:
+        return _phase_table.setdefault(name, [0, 0, 0])
+
+
+class phase:
+    """Time one process-level phase: `with phase("loop.pack"): ...`.
+    Self time is the duration less the phases opened inside it by the same
+    task. Attributes ride the profiler annotation only."""
+
+    __slots__ = ("name", "attrs", "_t0", "_children_ns", "_token", "_ann")
+
+    def __init__(self, name: str, **attrs: Any) -> None:
+        self.name = name
+        self.attrs = attrs
+
+    def __enter__(self) -> "phase":
+        self._children_ns = 0
+        self._token = _phase_set(self)
+        self._ann = _annotation(self) if dprofile._active else None
+        self._t0 = _monotonic_ns()
+        return self
+
+    def __exit__(self, et: Any, ev: Any, tb: Any) -> bool:
+        dur = _monotonic_ns() - self._t0
+        if self._ann is not None:
+            with contextlib.suppress(Exception):
+                self._ann.__exit__(et, ev, tb)
+        token = self._token
+        parent = token.old_value
+        try:
+            _phase_reset(token)
+        except ValueError:  # closed from another task's context
+            pass
+        if parent.__class__ is self.__class__:  # else None or Token.MISSING
+            parent._children_ns += dur
+        slot = _phase_table.get(self.name) or _phase_slot(self.name)
+        slot[0] += 1
+        slot[1] += dur
+        slot[2] += dur - self._children_ns
+        return False
+
+
+def _annotation(ph: phase) -> Any:
+    """The open profile window's view of the phase: `dyn:<name>` with its
+    attributes as the event's stats."""
+    try:
+        import jax
+
+        ann = jax.profiler.TraceAnnotation("dyn:" + ph.name, **ph.attrs)
+        ann.__enter__()
+        return ann
+    except Exception:  # noqa: BLE001 — annotation must never break serving
+        return None
+
+
+def observe_phase(name: str, dur_ns: int) -> None:
+    """Add one interval that was timed elsewhere to the table: the request
+    phases `queue_wait` and `prefill_wait`, which the phase histograms
+    already time."""
+    slot = _phase_table.get(name) or _phase_slot(name)
+    slot[0] += 1
+    slot[1] += dur_ns
+    slot[2] += dur_ns
+
+
+def phase_summary() -> dict[str, dict[str, Any]]:
+    """{name: {count, ms, self_ms}}, cumulative since process start: the
+    `phases` object of `GET /debug/goodput`."""
+    with _phase_lock:
+        rows = {name: tuple(slot) for name, slot in _phase_table.items()}
+    return {
+        name: {
+            "count": c,
+            "ms": round(total / 1e6, 3),
+            "self_ms": round(own / 1e6, 3),
+        }
+        for name, (c, total, own) in rows.items()
+    }
+
+
+def reset_phases() -> None:
+    """Empty the table (tests)."""
+    with _phase_lock:
+        _phase_table.clear()
 
 
 # -------------------------------------------------------------- W3C interop
