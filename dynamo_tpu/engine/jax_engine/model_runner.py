@@ -61,10 +61,12 @@ def unrolled_steps(step, init, H: int):
 
     A lax.scan would compile the body once, but carrying the multi-GB KV
     caches through a scan makes XLA double-buffer them (the r04 bench OOMed
-    HBM by ~0.9G exactly this way). Unrolled, the cache threads through a
-    straight dynamic-update-slice dataflow that aliases in place. H is
-    small (<=16) and fixed per deployment, so the compile-time cost is
-    bounded and paid once.
+    HBM by ~0.9G exactly this way). Unrolled, each layer's own cache buffer
+    threads through a chain of row scatters that write it in place; that is
+    a property of the compiled program, not of this dataflow, and
+    tests/test_tpu_compile.py holds it there (no slice, copy or update the
+    size of a layer's pool). H is small (<=16) and fixed per deployment, so
+    the compile-time cost is bounded and paid once.
     """
     ys = []
     carry = init
@@ -174,10 +176,10 @@ class ModelRunner:
         self.prefill_buckets = sorted(
             prefill_buckets or default_prefill_buckets(block_size, max_model_len)
         )
-        # head-major layout: each (head, page) is a contiguous [bs, D] tile
-        # (what the pallas kernel streams; TP shards the leading head axis)
-        cache_shape = (
-            config.num_layers,
+        # one array per layer, head-major: each (head, page) is a
+        # contiguous [bs, D] tile (what the pallas kernel streams; TP shards
+        # the leading head axis)
+        layer_shape = (
             config.num_kv_heads,
             num_blocks,
             block_size,
@@ -206,27 +208,21 @@ class ModelRunner:
             if (mesh is not None and global_arrays)
             else None
         )
-        # sharding tree matching the cache container ({"q", "s"} planes
-        # both head-sharded under tp; plain array otherwise)
-        kv_shard_tree = kv_quant.cache_sharding(kv_sharding, self.kv_quantized)
+        # sharding tree matching the cache container: per layer, {"q", "s"}
+        # planes both head-sharded under tp; a plain array otherwise
+        kv_shard_tree = kv_quant.cache_sharding(
+            kv_sharding, config.num_layers, self.kv_quantized
+        )
+        make_zeros = lambda: kv_quant.make_cache(
+            config.num_layers, layer_shape, self.kv_dtype,
+            quantized=self.kv_quantized,
+        )
         if kv_sharding is not None:
             # allocate ON device under the sharding (works single- and
             # multi-controller; never materializes host zeros)
-            make_zeros = jax.jit(
-                lambda: kv_quant.make_cache(
-                    cache_shape, self.kv_dtype, quantized=self.kv_quantized
-                ),
-                out_shardings=kv_shard_tree,
-            )
-            self.k_cache = make_zeros()
-            self.v_cache = make_zeros()
-        else:
-            self.k_cache = kv_quant.make_cache(
-                cache_shape, self.kv_dtype, quantized=self.kv_quantized
-            )
-            self.v_cache = kv_quant.make_cache(
-                cache_shape, self.kv_dtype, quantized=self.kv_quantized
-            )
+            make_zeros = jax.jit(make_zeros, out_shardings=kv_shard_tree)
+        self.k_cache = make_zeros()
+        self.v_cache = make_zeros()
         logger.info(
             "kv cache: %d blocks x %d tokens (%s), %.2f GiB",
             num_blocks,
@@ -379,98 +375,82 @@ class ModelRunner:
             if self._repl is not None
             else {}
         )
+        # The wire format is one [L, Hkv, n, bs, D] array (for the int8
+        # cache a {"q", "s"} pair of such): layers are stacked on the way
+        # out and scattered per layer on the way in.
+        kv_out = (
+            {"out_shardings": (kv_shard_tree, kv_shard_tree)}
+            if kv_sharding is not None
+            else {}
+        )
+
+        def gather(cache, ids):
+            return jax.tree.map(
+                lambda *layers: jnp.stack([a[:, ids] for a in layers]), *cache
+            )
+
+        def scatter(cache, ids, wire):
+            return tuple(
+                jax.tree.map(
+                    lambda c, w: c.at[:, ids].set(w[i].astype(c.dtype)),
+                    layer, wire,
+                )
+                for i, layer in enumerate(cache)
+            )
+
         if self.kv_quantized:
 
-            def _extract(k, v, ids):
-                kd = (
-                    k["q"][:, :, ids].astype(jnp.float32)
-                    * k["s"][:, :, ids][..., None, None]
-                ).astype(self.kv_dtype)
-                vd = (
-                    v["q"][:, :, ids].astype(jnp.float32)
-                    * v["s"][:, :, ids][..., None, None]
-                ).astype(self.kv_dtype)
-                return kd, vd
+            def dense(cache, ids):
+                got = gather(cache, ids)
+                return kv_quant.dequantize(got["q"], got["s"]).astype(
+                    self.kv_dtype
+                )
 
-            self._extract_jit = jax.jit(_extract, **repl_out)
+            self._extract_jit = jax.jit(
+                lambda k, v, ids: (dense(k, ids), dense(v, ids)), **repl_out
+            )
+
+            def _extract_q(k, v, ids):
+                k, v = gather(k, ids), gather(v, ids)
+                return k["q"], k["s"], v["q"], v["s"]
+
             self._extract_q_jit = jax.jit(
-                lambda k, v, ids: (
-                    k["q"][:, :, ids], k["s"][:, :, ids],
-                    v["q"][:, :, ids], v["s"][:, :, ids],
-                ),
+                _extract_q,
                 **(
                     {"out_shardings": (self._repl,) * 4}
                     if self._repl is not None
                     else {}
                 ),
             )
-
-            def _inject(k, v, ids, kb, vb):
-                # whole-block quantize-on-inject: the wire codec's exact
-                # per-(layer, head, block) absmax scheme, on device
-                from dynamo_tpu.ops.kv_quant import (
-                    block_scale,
-                    quantize_with,
-                    scale_inv,
-                )
-
-                out = []
-                for cache, blocks in ((k, kb), (v, vb)):
-                    xf = blocks.astype(jnp.float32)
-                    amax = jnp.max(jnp.abs(xf), axis=(-2, -1))
-                    scale = block_scale(amax)
-                    qv = quantize_with(
-                        xf, scale_inv(scale)[..., None, None]
-                    )
-                    out.append({
-                        "q": cache["q"].at[:, :, ids].set(qv),
-                        "s": cache["s"].at[:, :, ids].set(scale),
-                    })
-                return tuple(out)
-
+            # whole-block quantize-on-inject: the wire codec's exact
+            # per-(layer, head, block) absmax scheme, on device
             self._inject_jit = jax.jit(
-                _inject,
-                donate_argnums=(0, 1),
-                **(
-                    {"out_shardings": (kv_shard_tree, kv_shard_tree)}
-                    if kv_sharding is not None
-                    else {}
+                lambda k, v, ids, kb, vb: (
+                    scatter(k, ids, kv_quant.quantize_blocks(kb)),
+                    scatter(v, ids, kv_quant.quantize_blocks(vb)),
                 ),
+                donate_argnums=(0, 1),
+                **kv_out,
             )
             self._inject_q_jit = jax.jit(
                 lambda k, v, ids, kq, ks, vq, vs: (
-                    {
-                        "q": k["q"].at[:, :, ids].set(kq),
-                        "s": k["s"].at[:, :, ids].set(ks),
-                    },
-                    {
-                        "q": v["q"].at[:, :, ids].set(vq),
-                        "s": v["s"].at[:, :, ids].set(vs),
-                    },
+                    scatter(k, ids, {"q": kq, "s": ks}),
+                    scatter(v, ids, {"q": vq, "s": vs}),
                 ),
                 donate_argnums=(0, 1),
-                **(
-                    {"out_shardings": (kv_shard_tree, kv_shard_tree)}
-                    if kv_sharding is not None
-                    else {}
-                ),
+                **kv_out,
             )
         else:
             self._extract_jit = jax.jit(
-                lambda k, v, ids: (k[:, :, ids], v[:, :, ids]),
+                lambda k, v, ids: (gather(k, ids), gather(v, ids)),
                 **repl_out,
             )
             self._inject_jit = jax.jit(
                 lambda k, v, ids, kb, vb: (
-                    k.at[:, :, ids].set(kb.astype(k.dtype)),
-                    v.at[:, :, ids].set(vb.astype(v.dtype)),
+                    scatter(k, ids, kb), scatter(v, ids, vb)
                 ),
                 donate_argnums=(0, 1),
-                **(
-                    {"out_shardings": (kv_sharding, kv_sharding)}
-                    if kv_sharding is not None
-                    else {}
-                ),
+                **kv_out,
             )
 
     # ------------------------------------------------------------- jitted
@@ -1567,7 +1547,7 @@ class ModelRunner:
                     self.mesh, jax.sharding.PartitionSpec()
                 )
                 if self.mesh is not None
-                else self.k_cache.devices().pop()
+                else jax.tree_util.tree_leaves(self.k_cache)[0].devices().pop()
             )
         )
         k_dev = jax.device_put(k_dev, target)

@@ -33,7 +33,6 @@ from dynamo_tpu.ops.attention import (
     write_prefill_kv,
 )
 from dynamo_tpu.ops.basics import rms_norm, rope_freqs, swiglu
-from dynamo_tpu.ops.kv_quant import cache_layer, cache_set_layer
 from dynamo_tpu.ops.layers import attn_out, qkv_head
 from dynamo_tpu.ops.linear import (
     fused_attn_out_residual,
@@ -592,8 +591,8 @@ def prefill(
     cfg: LlamaConfig,
     tokens: jax.Array,  # [P] int32, padded to a multiple of block_size
     valid_len: jax.Array,  # scalar int32
-    k_cache: jax.Array,  # [L, Hkv, num_blocks, block_size, D]
-    v_cache: jax.Array,
+    k_cache: tuple,  # per layer [Hkv, num_blocks, block_size, D]
+    v_cache: tuple,
     block_table: jax.Array,  # [P // block_size] int32
     *,
     mesh=None,  # with attn_head_axis: run pallas attention under shard_map
@@ -612,8 +611,8 @@ def prefill_mm(
     cfg: LlamaConfig,
     tokens: jax.Array,  # [P] int32, image placeholders pre-expanded
     valid_len: jax.Array,
-    k_cache: jax.Array,
-    v_cache: jax.Array,
+    k_cache: tuple,
+    v_cache: tuple,
     block_table: jax.Array,
     mm_embeds: jax.Array,  # [M, hidden] vision-projector output
     mm_start: jax.Array,  # scalar int32; embeds overwrite [start, start+M)
@@ -642,8 +641,8 @@ def _prefill_from_embeds(
     cfg: LlamaConfig,
     x: jax.Array,  # [P, hidden]
     valid_len: jax.Array,
-    k_cache: jax.Array,
-    v_cache: jax.Array,
+    k_cache: tuple,
+    v_cache: tuple,
     block_table: jax.Array,
     *,
     mesh=None,
@@ -651,17 +650,18 @@ def _prefill_from_embeds(
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     freqs = _rope_pair(cfg)
     positions = jnp.arange(x.shape[0], dtype=jnp.int32)
+    k_out, v_out = [], []  # each layer's own buffer, written in place
     for i, layer in enumerate(params["layers"]):
         x, kc, vc = _attn_prefill(
             x, layer, cfg, _layer_freqs(cfg, i, freqs), positions, valid_len,
-            cache_layer(k_cache, i), cache_layer(v_cache, i), block_table,
+            k_cache[i], v_cache[i], block_table,
             mesh=mesh, head_axis=attn_head_axis, li=i,
         )
-        k_cache = cache_set_layer(k_cache, i, kc)
-        v_cache = cache_set_layer(v_cache, i, vc)
+        k_out.append(kc)
+        v_out.append(vc)
         x = _mlp(x, layer, cfg, mesh)
     logits = _logits(x[valid_len - 1][None, :], params, cfg)[0]
-    return logits, k_cache, v_cache
+    return logits, tuple(k_out), tuple(v_out)
 
 
 def prefill_chunk(
@@ -670,8 +670,8 @@ def prefill_chunk(
     tokens: jax.Array,  # [C] int32 — one chunk (C = fixed chunk size)
     chunk_start: jax.Array,  # scalar int32 — position of tokens[0]
     valid_len: jax.Array,  # scalar int32 — TOTAL prompt length
-    k_cache: jax.Array,  # [L, Hkv, num_blocks, block_size, D]
-    v_cache: jax.Array,
+    k_cache: tuple,  # per layer [Hkv, num_blocks, block_size, D]
+    v_cache: tuple,
     block_table: jax.Array,  # [max_nb] int32 — the whole prompt's blocks
     *,
     mesh=None,  # for MoE dispatch-path selection in _mlp
@@ -689,10 +689,11 @@ def prefill_chunk(
     freqs = _rope_pair(cfg)
     positions = chunk_start + jnp.arange(C, dtype=jnp.int32)
     x = _embed(params, cfg, tokens)
+    k_out, v_out = [], []
     for i, layer in enumerate(params["layers"]):
         q, k, v = _qkv(x, layer, cfg, _layer_freqs(cfg, i, freqs), positions)
         kc, vc = write_chunk_kv(
-            cache_layer(k_cache, i), cache_layer(v_cache, i), k, v,
+            k_cache[i], v_cache[i], k, v,
             block_table, chunk_start,
         )
         attn = chunked_prefill_attention(
@@ -702,11 +703,11 @@ def prefill_chunk(
         )
         x = _attn_out(attn, x, layer, cfg)
         x = _mlp(x, layer, cfg, mesh)
-        k_cache = cache_set_layer(k_cache, i, kc)
-        v_cache = cache_set_layer(v_cache, i, vc)
+        k_out.append(kc)
+        v_out.append(vc)
     idx = jnp.clip(valid_len - 1 - chunk_start, 0, C - 1)
     logits = _logits(x[idx][None, :], params, cfg)[0]
-    return logits, k_cache, v_cache
+    return logits, tuple(k_out), tuple(v_out)
 
 
 def prefill_packed(
@@ -716,8 +717,8 @@ def prefill_packed(
     positions: jax.Array,  # [P] int32 — restart at 0 per segment
     segment_ids: jax.Array,  # [P] int32; -1 marks padding lanes
     slot_indices: jax.Array,  # [P] int32 flat cache slots per token
-    k_cache: jax.Array,  # [L, Hkv, num_blocks, block_size, D]
-    v_cache: jax.Array,
+    k_cache: tuple,  # per layer [Hkv, num_blocks, block_size, D]
+    v_cache: tuple,
     last_idx: jax.Array,  # [N] int32 — index of each prompt's last token
     *,
     mesh=None,  # for MoE dispatch-path selection in _mlp
@@ -735,10 +736,11 @@ def prefill_packed(
     """
     freqs = _rope_pair(cfg)
     x = _embed(params, cfg, tokens)
+    k_out, v_out = [], []
     for i, layer in enumerate(params["layers"]):
         q, k, v = _qkv(x, layer, cfg, _layer_freqs(cfg, i, freqs), positions)
         kc, vc = write_decode_kv(
-            cache_layer(k_cache, i), cache_layer(v_cache, i), k, v,
+            k_cache[i], v_cache[i], k, v,
             slot_indices,
         )
         attn = packed_prefill_attention(
@@ -748,10 +750,10 @@ def prefill_packed(
         )
         x = _attn_out(attn, x, layer, cfg)
         x = _mlp(x, layer, cfg, mesh)
-        k_cache = cache_set_layer(k_cache, i, kc)
-        v_cache = cache_set_layer(v_cache, i, vc)
+        k_out.append(kc)
+        v_out.append(vc)
     logits = _logits(x[last_idx], params, cfg)
-    return logits, k_cache, v_cache
+    return logits, tuple(k_out), tuple(v_out)
 
 
 def prefill_context_parallel(
@@ -762,7 +764,7 @@ def prefill_context_parallel(
     valid_len: jax.Array,  # scalar int32
     *,
     head_axis=None,  # "tp" when kv heads are TP-sharded
-    k_cache=None,  # [L, Hkv, nb, bs, D] — paginate per layer when given
+    k_cache=None,  # per layer [Hkv, nb, bs, D] — paginate when given
     v_cache=None,
     block_table=None,  # [P // bs] int32
 ):
@@ -786,6 +788,7 @@ def prefill_context_parallel(
     positions = jnp.arange(P_len, dtype=jnp.int32)
     x = _embed(params, cfg, tokens)
     k_all, v_all = [], []
+    k_out, v_out = [], []
     for i, layer in enumerate(params["layers"]):
         q, k, v = _qkv(x, layer, cfg, _layer_freqs(cfg, i, freqs), positions)
         # sliding layers ride the same ring; hops whose KV chunk is wholly
@@ -801,17 +804,17 @@ def prefill_context_parallel(
         x = _mlp(x, layer, cfg, mesh)
         if paginate:
             kc, vc = write_prefill_kv(
-                cache_layer(k_cache, i), cache_layer(v_cache, i), k, v,
+                k_cache[i], v_cache[i], k, v,
                 block_table,
             )
-            k_cache = cache_set_layer(k_cache, i, kc)
-            v_cache = cache_set_layer(v_cache, i, vc)
+            k_out.append(kc)
+            v_out.append(vc)
         else:
             k_all.append(k)
             v_all.append(v)
     logits = _logits(x[valid_len - 1][None, :], params, cfg)[0]
     if paginate:
-        return logits, k_cache, v_cache
+        return logits, tuple(k_out), tuple(v_out)
     return logits, jnp.stack(k_all), jnp.stack(v_all)
 
 
@@ -848,8 +851,8 @@ def decode_verify(
     cfg: LlamaConfig,
     tokens: jax.Array,  # [B, S] int32 — last accepted token + draft window
     positions: jax.Array,  # [B, S] int32 true positions
-    k_cache: jax.Array,  # [L, Hkv, num_blocks, block_size, D]
-    v_cache: jax.Array,
+    k_cache: tuple,  # per layer [Hkv, num_blocks, block_size, D]
+    v_cache: tuple,
     block_tables: jax.Array,  # [B, max_blocks] int32
     slot_indices: jax.Array,  # [B, S] int32 flat cache slots (0 = null sink)
     *,
@@ -868,6 +871,7 @@ def decode_verify(
     pos_flat = positions.reshape(-1)
     slots_flat = slot_indices.reshape(-1)
     x = _embed(params, cfg, tokens.reshape(-1))  # [B*S, hidden]
+    k_out, v_out = [], []
     for i, layer in enumerate(params["layers"]):
         fused = _use_fused_decode(cfg, layer, mesh)
         lf = _layer_freqs(cfg, i, freqs)
@@ -879,7 +883,7 @@ def decode_verify(
         else:
             q, k, v = _qkv(x, layer, cfg, lf, pos_flat)
         kc, vc = write_decode_kv(
-            cache_layer(k_cache, i), cache_layer(v_cache, i), k, v,
+            k_cache[i], v_cache[i], k, v,
             slots_flat,
         )
         attn = paged_verify_attention(
@@ -896,9 +900,10 @@ def decode_verify(
         else:
             x = _attn_out(attn.reshape(B * S, cfg.num_heads, cfg.head_dim), x, layer, cfg)
         x = _mlp(x, layer, cfg, mesh)
-        k_cache = cache_set_layer(k_cache, i, kc)
-        v_cache = cache_set_layer(v_cache, i, vc)
-    return _logits(x, params, cfg).reshape(B, S, -1), k_cache, v_cache
+        k_out.append(kc)
+        v_out.append(vc)
+    logits = _logits(x, params, cfg).reshape(B, S, -1)
+    return logits, tuple(k_out), tuple(v_out)
 
 
 def decode(
@@ -906,8 +911,8 @@ def decode(
     cfg: LlamaConfig,
     tokens: jax.Array,  # [B] int32
     positions: jax.Array,  # [B] int32 (0-indexed position of this token)
-    k_cache: jax.Array,  # [L, Hkv, num_blocks, block_size, D]
-    v_cache: jax.Array,
+    k_cache: tuple,  # per layer [Hkv, num_blocks, block_size, D]
+    v_cache: tuple,
     block_tables: jax.Array,  # [B, max_blocks] int32
     slot_indices: jax.Array,  # [B] int32 flat cache slots for the new token
     *,
@@ -917,17 +922,18 @@ def decode(
     """One decode step for a batch; returns (logits [B, V], caches)."""
     freqs = _rope_pair(cfg)
     x = _embed(params, cfg, tokens)
+    k_out, v_out = [], []
     for i, layer in enumerate(params["layers"]):
         overlap = _use_overlap_tail(cfg, layer, mesh)
         x, kc, vc = _attn_decode(
             x, layer, cfg, _layer_freqs(cfg, i, freqs), positions,
-            cache_layer(k_cache, i), cache_layer(v_cache, i),
+            k_cache[i], v_cache[i],
             block_tables, slot_indices,
             mesh=mesh, head_axis=attn_head_axis, li=i,
             overlap_tail=overlap,
         )
-        k_cache = cache_set_layer(k_cache, i, kc)
-        v_cache = cache_set_layer(v_cache, i, vc)
+        k_out.append(kc)
+        v_out.append(vc)
         if not overlap:  # the overlap tail already ran the MLP
             x = _mlp(x, layer, cfg, mesh)
-    return _logits(x, params, cfg), k_cache, v_cache
+    return _logits(x, params, cfg), tuple(k_out), tuple(v_out)

@@ -566,8 +566,8 @@ def write_prefill_kv(
             write_blocks_quant(k_cache, k_blocks, block_table),
             write_blocks_quant(v_cache, v_blocks, block_table),
         )
-    k_cache = k_cache.at[:, block_table].set(k_blocks)
-    v_cache = v_cache.at[:, block_table].set(v_blocks)
+    k_cache = k_cache.at[:, block_table].set(k_blocks.astype(k_cache.dtype))
+    v_cache = v_cache.at[:, block_table].set(v_blocks.astype(v_cache.dtype))
     return k_cache, v_cache
 
 
@@ -583,19 +583,14 @@ def write_decode_kv(
     Int8-resident caches route through write_tokens_quant: appended tokens
     grow the block scale monotonically (rescaling existing mantissas when
     it grows), so decode/verify/packed writes stay duplicate-safe."""
-    if _cache_quantized(k_cache):
-        from dynamo_tpu.ops.kv_quant import write_tokens_quant
+    from dynamo_tpu.ops.kv_quant import scatter_token_rows, write_tokens_quant
 
+    if _cache_quantized(k_cache):
         return (
             write_tokens_quant(k_cache, k_new, slot_indices),
             write_tokens_quant(v_cache, v_new, slot_indices),
         )
-    Hkv, num_blocks, block_size, D = k_cache.shape
-    k_flat = k_cache.reshape(Hkv, num_blocks * block_size, D)
-    v_flat = v_cache.reshape(Hkv, num_blocks * block_size, D)
-    k_flat = k_flat.at[:, slot_indices].set(k_new.transpose(1, 0, 2))
-    v_flat = v_flat.at[:, slot_indices].set(v_new.transpose(1, 0, 2))
     return (
-        k_flat.reshape(Hkv, num_blocks, block_size, D),
-        v_flat.reshape(Hkv, num_blocks, block_size, D),
+        scatter_token_rows(k_cache, k_new, slot_indices),
+        scatter_token_rows(v_cache, v_new, slot_indices),
     )

@@ -7,10 +7,14 @@ per-block scale plane, so every decode step reads ~half the KV bytes from
 HBM and dequantizes inside the attention kernel (pallas) or right after the
 gather (XLA path). bf16 K/V for past tokens never materializes in HBM.
 
-Layout (a plain dict, so it rides every jit/donate/pytree path unchanged):
+Layout: the cache is a tuple of one container per layer (each its own
+device buffer, so a step program writes a layer in place and never slices
+or re-assembles a pool); a layer's container is a plain array, or for the
+int8-resident cache a plain dict, so it rides every jit/donate/pytree path
+unchanged:
 
-    cache = {"q": int8 [L, Hkv, num_blocks, bs, D],
-             "s": f32  [L, Hkv, num_blocks]}
+    cache[i] = {"q": int8 [Hkv, num_blocks, bs, D],
+                "s": f32  [Hkv, num_blocks]}
 
 The scale scheme is EXACTLY the wire codec's (amax/127 per block, inv=0 for
 all-zero blocks), so int8-resident blocks ship verbatim over disagg frames,
@@ -33,29 +37,30 @@ Write semantics:
 
 from __future__ import annotations
 
-from typing import Any, Union
+from typing import Union
 
 import jax
 import jax.numpy as jnp
 
-KVCache = Union[jax.Array, dict]
-
-
-def is_quantized(cache: Any) -> bool:
-    """True for the int8-resident {"q", "s"} cache container."""
-    return isinstance(cache, dict)
+KVLayer = Union[jax.Array, dict]  # one layer: [Hkv, nb, bs, D] or {"q","s"}
+KVCache = tuple  # one KVLayer per model layer
 
 
 def make_cache(
-    shape: tuple[int, ...], dtype, *, quantized: bool
+    num_layers: int, layer_shape: tuple[int, ...], dtype, *, quantized: bool
 ) -> KVCache:
-    """Zero-initialized cache: plain array, or the int8+scale container."""
-    if not quantized:
-        return jnp.zeros(shape, dtype)
-    return {
-        "q": jnp.zeros(shape, jnp.int8),
-        "s": jnp.zeros(shape[:-2], jnp.float32),
-    }
+    """Zero-initialized cache: per layer a plain [Hkv, nb, bs, D] array, or
+    the int8+scale container."""
+
+    def layer() -> KVLayer:
+        if not quantized:
+            return jnp.zeros(layer_shape, dtype)
+        return {
+            "q": jnp.zeros(layer_shape, jnp.int8),
+            "s": jnp.zeros(layer_shape[:-2], jnp.float32),
+        }
+
+    return tuple(layer() for _ in range(num_layers))
 
 
 def cache_zeros_like(cache: KVCache) -> KVCache:
@@ -71,37 +76,22 @@ def cache_nbytes(cache: KVCache) -> int:
     )
 
 
-def cache_layer(cache: KVCache, i: int) -> KVCache:
-    """Layer i's view: [Hkv, nb, bs, D] (+ [Hkv, nb] scales)."""
-    if is_quantized(cache):
-        return {"q": cache["q"][i], "s": cache["s"][i]}
-    return cache[i]
+def cache_sharding(kv_sharding, num_layers: int, quantized: bool):
+    """Sharding pytree matching the cache container, from one layer's
+    [Hkv, nb, bs, D] sharding: the scale plane [Hkv, nb] inherits the
+    layer's leading two axes (the head axis is what TP shards)."""
+    if kv_sharding is None:
+        return None
+    layer = kv_sharding
+    if quantized:
+        from jax.sharding import NamedSharding, PartitionSpec
 
-
-def cache_set_layer(cache: KVCache, i: int, layer: KVCache) -> KVCache:
-    """Write layer i back (functional; aliases in place under donation)."""
-    if is_quantized(cache):
-        return {
-            "q": cache["q"].at[i].set(layer["q"]),
-            "s": cache["s"].at[i].set(layer["s"]),
+        sspec = PartitionSpec(*tuple(kv_sharding.spec)[:2])
+        layer = {
+            "q": kv_sharding,
+            "s": NamedSharding(kv_sharding.mesh, sspec),
         }
-    return cache.at[i].set(layer)
-
-
-def cache_sharding(kv_sharding, quantized: bool):
-    """Sharding pytree matching the cache container: the scale plane
-    [L, Hkv, nb] inherits the cache's leading three axes (the head axis is
-    what TP shards)."""
-    if kv_sharding is None or not quantized:
-        return kv_sharding
-    from jax.sharding import NamedSharding, PartitionSpec
-
-    spec = kv_sharding.spec
-    sspec = PartitionSpec(*tuple(spec)[:3])
-    return {
-        "q": kv_sharding,
-        "s": NamedSharding(kv_sharding.mesh, sspec),
-    }
+    return (layer,) * num_layers
 
 
 # ------------------------------------------------------------ quant math
@@ -137,19 +127,51 @@ def dequantize_layer(layer: dict) -> jax.Array:
 # ---------------------------------------------------------------- writes
 
 
+def scatter_token_rows(
+    pages: jax.Array,  # [Hkv, nb, bs, D]
+    new: jax.Array,  # [T, Hkv, D]
+    slot_indices: jax.Array,  # [T] int32 flat slots (block*bs + offset)
+) -> jax.Array:
+    """Write token t's head h into row h*N + slot[t] of the pages seen as
+    [Hkv*N, D] rows (N = nb*bs).
+
+    The form matters, not only the result: with the indexed dimension
+    major, XLA scatters into the donated buffer where it lies; with slots
+    indexed behind the head axis (`flat.at[:, slots]`) it re-lays the whole
+    layer before and after every write (PERF.md section 6, PR 26)."""
+    Hkv, nb, bs, D = pages.shape
+    N = nb * bs
+    rows = (
+        jnp.arange(Hkv, dtype=jnp.int32)[None, :] * N
+        + slot_indices.astype(jnp.int32)[:, None]
+    ).reshape(-1)  # [T*Hkv], token-major like new.reshape(-1, D)
+    flat = pages.reshape(Hkv * N, D).at[rows].set(
+        new.reshape(-1, D).astype(pages.dtype)
+    )
+    return flat.reshape(Hkv, nb, bs, D)
+
+
+def quantize_blocks(blocks: jax.Array) -> dict:
+    """Whole blocks [..., bs, D] -> {"q": int8 mantissas, "s": f32 [...]}
+    with each block's exact absmax scale (the wire codec's scheme)."""
+    xf = blocks.astype(jnp.float32)
+    scale = block_scale(jnp.max(jnp.abs(xf), axis=(-2, -1)))
+    return {
+        "q": quantize_with(xf, scale_inv(scale)[..., None, None]),
+        "s": scale,
+    }
+
+
 def write_blocks_quant(
     layer: dict,  # {"q": [Hkv, nb, bs, D] int8, "s": [Hkv, nb] f32}
     k_blocks: jax.Array,  # [Hkv, n, bs, D] logical-dtype new blocks
     block_table: jax.Array,  # [n] int32
 ) -> dict:
     """Whole-block write (prefill/chunk): exact per-block absmax scales."""
-    xf = k_blocks.astype(jnp.float32)
-    amax = jnp.max(jnp.abs(xf), axis=(-2, -1))  # [Hkv, n]
-    scale = block_scale(amax)
-    q = quantize_with(xf, scale_inv(scale)[..., None, None])
+    new = quantize_blocks(k_blocks)
     return {
-        "q": layer["q"].at[:, block_table].set(q),
-        "s": layer["s"].at[:, block_table].set(scale),
+        "q": layer["q"].at[:, block_table].set(new["q"]),
+        "s": layer["s"].at[:, block_table].set(new["s"]),
     }
 
 
@@ -199,8 +221,9 @@ def write_tokens_quant(
     q_cache = q_cache.at[:, bids].set(resc)
 
     # insert the new tokens quantized by their block's (possibly grown)
-    # scale, via the flat-slot scatter the bf16 path uses
+    # scale, via the row scatter the bf16 path uses
     tok_q = quantize_with(xf, inv_g[..., None])  # [Hkv, T, D]
-    q_flat = q_cache.reshape(Hkv, nb * bs, D)
-    q_flat = q_flat.at[:, slot_indices].set(tok_q)
-    return {"q": q_flat.reshape(Hkv, nb, bs, D), "s": new_s}
+    q_cache = scatter_token_rows(
+        q_cache, tok_q.transpose(1, 0, 2), slot_indices
+    )
+    return {"q": q_cache, "s": new_s}

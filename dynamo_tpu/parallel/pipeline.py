@@ -6,11 +6,15 @@ Role-equivalent of the reference's --pipeline-parallel-size pass-through
 engine is ours, so PP is implemented in the model math). TPU-first shape:
 
   * per-layer params are STACKED ([L, ...] leading axis) and sharded over
-    the mesh's "pp" axis — each stage holds L/pp layers and scans them
-    with `lax.scan` (one compiled body, no per-layer unrolling);
-  * the paged KV cache's layer axis is sharded over pp the same way, so
-    each stage reads/writes only its own layers' pages — PP divides cache
-    HBM exactly like it divides weight HBM;
+    the mesh's "pp" axis — each stage holds L/pp layers;
+  * the paged KV cache is, like the serial path's, one array per layer a
+    stage walks: a tuple of L/pp arrays [pp, Hkv, nb, bs, D] whose leading
+    STAGE axis is sharded over pp (`make_pp_cache`). Element j holds, on
+    stage s, the pages of layer s*L/pp + j, so each stage reads/writes
+    only its own layers' pages — PP divides cache HBM exactly like it
+    divides weight HBM — and writes each in place in its own buffer. The
+    stage's layers are therefore unrolled, not scanned: a scan would
+    carry the layers stacked and slice one out and back per layer;
   * activations move stage-to-stage with `lax.ppermute` over ICI inside a
     fill/drain microbatch rotation: with M microbatches the schedule runs
     M + pp - 1 ticks, every stage computing every tick once the pipe is
@@ -74,8 +78,8 @@ def shard_stacked_pp(
     mesh: Mesh, stacked: dict
 ) -> tuple[dict, NamedSharding]:
     """Place stacked params: layer axis over pp (non-layer params
-    replicated). Returns (params, kv_cache_sharding) where the cache's
-    LAYER axis is pp-sharded."""
+    replicated). Returns (params, kv_cache_sharding): the sharding of each
+    array of the cache, whose leading STAGE axis is pp-sharded."""
     pp_first = NamedSharding(mesh, P("pp"))
     repl = NamedSharding(mesh, P())
     out = {
@@ -91,8 +95,28 @@ def shard_stacked_pp(
         out["lm_head"] = jax.tree.map(
             lambda v: jax.device_put(v, repl), stacked["lm_head"]
         )
-    kv_sharding = NamedSharding(mesh, P("pp"))  # [L, Hkv, nb, bs, D]
+    kv_sharding = NamedSharding(mesh, P("pp"))  # [pp, Hkv, nb, bs, D]
     return out, kv_sharding
+
+
+def make_pp_cache(
+    kv_sharding: NamedSharding, cfg, num_blocks: int, block_size: int, dtype
+) -> tuple:
+    """A zeroed cache for prefill_pp/decode_pp: one array per layer of a
+    stage, [pp, Hkv, nb, bs, D] with the stage axis over pp."""
+    pp = kv_sharding.mesh.shape["pp"]
+    shape = (pp, cfg.num_kv_heads, num_blocks, block_size, cfg.head_dim)
+    return tuple(
+        jax.device_put(jnp.zeros(shape, dtype), kv_sharding)
+        for _ in range(cfg.num_layers // pp)
+    )
+
+
+def pp_cache_layers(cache: tuple) -> list:
+    """The cache as the model's layers in order, [Hkv, nb, bs, D] each
+    (stage s holds layers s*L/pp .. (s+1)*L/pp - 1)."""
+    pp = cache[0].shape[0]
+    return [c[s] for s in range(pp) for c in cache]
 
 
 # ------------------------------------------------------------ stage math
@@ -123,34 +147,34 @@ def _check_pp_supported(cfg) -> None:
         )
 
 
-def _scan_layers(cfg, layers, x, positions, attend, write_kv, k_cache, v_cache):
-    """Apply this stage's local layer stack with lax.scan.
+def _stage_layers(cfg, layers, x, positions, attend, write_kv, k_cache, v_cache):
+    """Apply this stage's local layers, unrolled.
 
-    `attend(q, k, v, kc, vc)` and `write_kv(kc, vc, k, v)` close over the
-    attention style (prefill in-buffer vs paged decode); kc/vc are one
-    LOCAL layer's cache slices, scanned along axis 0."""
+    `attend(q, kc, vc, k, v)` and `write_kv(kc, vc, k, v)` close over the
+    attention style (prefill in-buffer vs paged decode); k_cache/v_cache
+    are the stage's own arrays, one [1, Hkv, nb, bs, D] per local layer."""
     inv_freqs = rope_freqs(cfg.head_dim, cfg.rope_theta, cfg.rope_scaling)
-    T = x.shape[0]
-
-    def body(x, per_layer):
-        lyr, kc, vc = per_layer
+    k_out, v_out = [], []
+    for j, (kc, vc) in enumerate(zip(k_cache, v_cache)):
+        lyr = jax.tree.map(lambda a: a[j], layers)
         # the SAME projection head as the serial/cp/decode paths
         # (ops/layers.py — handles int8 {"q","s"} weights and qwen2
         # biases); only the attention itself differs per phase
         q, k, v = qkv_head(x, lyr, cfg, inv_freqs, positions)
-        kc, vc = write_kv(kc, vc, k, v)
+        kc, vc = write_kv(kc[0], vc[0], k, v)
         attn = attend(q, kc, vc, k, v)
         x = attn_out(attn, x, lyr, cfg)
         h2 = rms_norm(x, lyr["mlp_norm"], cfg.rms_eps)
         gate = linear(h2, lyr["wg"])
         up = linear(h2, lyr["wu"])
         x = x + linear(swiglu(gate, up), lyr["wd"])
-        return x, (kc, vc)
+        k_out.append(kc[None])
+        v_out.append(vc[None])
+    return x, tuple(k_out), tuple(v_out)
 
-    x, (k_cache, v_cache) = jax.lax.scan(
-        body, x, (layers, k_cache, v_cache)
-    )
-    return x, k_cache, v_cache
+
+def _keep_if(active, new: tuple, old: tuple) -> tuple:
+    return tuple(jnp.where(active, n, o) for n, o in zip(new, old))
 
 
 def prefill_pp(
@@ -159,8 +183,8 @@ def prefill_pp(
     mesh: Mesh,
     tokens: jax.Array,  # [Pl] int32, padded
     valid_len: jax.Array,  # scalar int32
-    k_cache: jax.Array,  # [L, Hkv, nb, bs, D], layer axis pp-sharded
-    v_cache: jax.Array,
+    k_cache: tuple,  # make_pp_cache: L/pp x [pp, Hkv, nb, bs, D]
+    v_cache: tuple,
     block_table: jax.Array,  # [Pl // bs] int32
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Single-prompt prefill through the pipeline: the activation visits
@@ -203,13 +227,13 @@ def prefill_pp(
 
         def tick(t, carry):
             x, k_cache, v_cache = carry
-            y, kc2, vc2 = _scan_layers(
+            y, kc2, vc2 = _stage_layers(
                 cfg, layers, x, positions, attend, write_kv, k_cache, v_cache
             )
             active = stage == t  # stage s works at tick s (one microbatch)
             x = jnp.where(active, y, x)
-            k_cache = jnp.where(active, kc2, k_cache)
-            v_cache = jnp.where(active, vc2, v_cache)
+            k_cache = _keep_if(active, kc2, k_cache)
+            v_cache = _keep_if(active, vc2, v_cache)
             # hand the activation to the next stage
             x = jax.lax.ppermute(
                 x, "pp", [(i, (i + 1) % pp) for i in range(pp)]
@@ -252,8 +276,8 @@ def decode_pp(
     mesh: Mesh,
     tokens: jax.Array,  # [B] int32
     positions: jax.Array,  # [B] int32
-    k_cache: jax.Array,  # [L, Hkv, nb, bs, D], layer axis pp-sharded
-    v_cache: jax.Array,
+    k_cache: tuple,  # make_pp_cache: L/pp x [pp, Hkv, nb, bs, D]
+    v_cache: tuple,
     block_tables: jax.Array,  # [B, max_blocks] int32
     slot_indices: jax.Array,  # [B] int32
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
@@ -323,12 +347,12 @@ def decode_pp(
             bt_mb = block_tables[seq_idx]
             slots_mb = slot_indices[seq_idx]
             attend, write_kv = attend_factory(bt_mb, pos_mb + 1, slots_mb)
-            y, kc2, vc2 = _scan_layers(
+            y, kc2, vc2 = _stage_layers(
                 cfg, layers, buf, pos_mb, attend, write_kv, k_cache, v_cache
             )
             buf = jnp.where(active, y, buf)
-            k_cache = jnp.where(active, kc2, k_cache)
-            v_cache = jnp.where(active, vc2, v_cache)
+            k_cache = _keep_if(active, kc2, k_cache)
+            v_cache = _keep_if(active, vc2, v_cache)
             # last stage emits logits for its finished microbatch
             emit = active & (stage == pp - 1)
             h = rms_norm(buf, final_norm, cfg.rms_eps)

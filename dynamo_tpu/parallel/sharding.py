@@ -13,7 +13,7 @@ Layout:
   wd        [F, E]        -> row parallel
   lm_head   [E, V]        -> vocab-sharded; logits all-gathered (few MB)
   embed, norms            -> replicated
-  kv cache  [L, Hkv, N, Bs, D] -> heads on tp (head-major: each
+  kv cache  per layer [Hkv, N, Bs, D] -> heads on tp (head-major: each
                              (head, page) a contiguous [Bs, D] pallas tile)
 """
 
@@ -63,7 +63,8 @@ def _shard_linear(mesh: Mesh, w: Any, spec_in, spec_out, put=put_local) -> Any:
 def shard_llama(
     mesh: Mesh, config: LlamaConfig, params: dict, put=put_local
 ) -> tuple[dict, NamedSharding]:
-    """Places params onto the mesh; returns (params, kv_cache_sharding).
+    """Places params onto the mesh; returns (params, the sharding of one
+    layer's [Hkv, N, Bs, D] cache array).
 
     `put` is the placement primitive: jax.device_put on one controller,
     put_global under multi-host (every process passes identical host
@@ -118,5 +119,6 @@ def shard_llama(
         out["layers"].append(placed)
     if "lm_head" in params:
         out["lm_head"] = _shard_linear(mesh, params["lm_head"], None, "tp", put)
-    kv_sharding = _ns(mesh, None, "tp", None, None, None)
+    # one layer of the paged cache, [Hkv, nb, bs, D]: heads over tp
+    kv_sharding = _ns(mesh, "tp", None, None, None)
     return out, kv_sharding

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from tests.util import layer_caches
 from dynamo_tpu.models import llama as L
 from dynamo_tpu.ops import pallas_attention as PA
 
@@ -76,8 +77,8 @@ def test_family_never_falls_back_to_xla(family, monkeypatch):
 
     bs, nb, P = 8, 12, 16
     cache_shape = (cfg.num_layers, cfg.num_kv_heads, nb, bs, cfg.head_dim)
-    kc = jnp.zeros(cache_shape, jnp.float32)
-    vc = jnp.zeros(cache_shape, jnp.float32)
+    kc = layer_caches(cache_shape, jnp.float32)
+    vc = layer_caches(cache_shape, jnp.float32)
     tokens = jnp.arange(P, dtype=jnp.int32) % cfg.vocab_size
     table = jnp.arange(1, 1 + P // bs, dtype=jnp.int32)
     logits, kc, vc = L.prefill(params, cfg, tokens, jnp.int32(P), kc, vc, table)

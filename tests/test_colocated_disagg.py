@@ -119,8 +119,8 @@ async def test_colocated_mesh_to_mesh_distinct_devices():
     got = await collect_tokens(decode_engine, prompt)
     assert got == ref
     # every cache array stayed on its own mesh
-    assert {d for d in decode_engine.runner.k_cache.devices()} == set(devs[2:4])
-    assert {d for d in prefill_engine.runner.k_cache.devices()} == set(devs[0:2])
+    assert {d for d in decode_engine.runner.k_cache[0].devices()} == set(devs[2:4])
+    assert {d for d in prefill_engine.runner.k_cache[0].devices()} == set(devs[0:2])
     await decode_engine.close()
     await prefill_engine.close()
 
@@ -152,8 +152,8 @@ async def _assert_asymmetric_matches_local(
     ]
     outs = [await collect_tokens(decode_engine, p) for p in prompts]
     assert outs == refs
-    assert {d for d in decode_engine.runner.k_cache.devices()} == set(d_devs)
-    assert {d for d in prefill_engine.runner.k_cache.devices()} == set(p_devs)
+    assert {d for d in decode_engine.runner.k_cache[0].devices()} == set(d_devs)
+    assert {d for d in prefill_engine.runner.k_cache[0].devices()} == set(p_devs)
     await decode_engine.close()
     await prefill_engine.close()
 

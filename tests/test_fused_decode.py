@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from tests.util import layer_caches
 from dynamo_tpu.models import llama as L
 from dynamo_tpu.ops.basics import rope_freqs
 from dynamo_tpu.ops.layers import attn_out, qkv_head
@@ -82,8 +83,8 @@ def _decode_once(cfg, params, fused):
     c = dataclasses.replace(cfg, fused_decode=fused)
     B, bs, nb = 3, 8, 32
     shape = (c.num_layers, c.num_kv_heads, nb, bs, c.head_dim)
-    kc = jnp.zeros(shape, jnp.bfloat16)
-    vc = jnp.zeros(shape, jnp.bfloat16)
+    kc = layer_caches(shape, jnp.bfloat16)
+    vc = layer_caches(shape, jnp.bfloat16)
     toks = jnp.asarray([5, 6, 7], jnp.int32)
     pos = jnp.asarray([10, 3, 0], jnp.int32)
     bt = jnp.tile(

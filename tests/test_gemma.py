@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from tests.util import layer_caches
 from dynamo_tpu.models import llama as L
 
 
@@ -67,10 +68,9 @@ def test_hf_config_gemma2_and_gemma3():
 
 
 def _logits(cfg, params, toks=8):
-    kc = jnp.zeros(
-        (cfg.num_layers, cfg.num_kv_heads, 16, 4, cfg.head_dim), jnp.bfloat16
-    )
-    vc = jnp.zeros_like(kc)
+    shape = (cfg.num_layers, cfg.num_kv_heads, 16, 4, cfg.head_dim)
+    kc = layer_caches(shape, jnp.bfloat16)
+    vc = layer_caches(shape, jnp.bfloat16)
     tokens = jnp.arange(toks, dtype=jnp.int32) + 2
     out, _, _ = L.prefill(
         params, cfg, tokens, jnp.int32(toks), kc, vc,

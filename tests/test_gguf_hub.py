@@ -183,14 +183,18 @@ def test_gguf_roundtrip_and_forward(tmp_path):
     # and the loaded model computes the same logits
     import jax.numpy as jnp
 
-    kc = jnp.zeros((cfg.num_layers, cfg.num_kv_heads, 8, 4, cfg.head_dim), jnp.bfloat16)
-    vc = jnp.zeros_like(kc)
+    from tests.util import layer_caches
+
+    shape = (cfg.num_layers, cfg.num_kv_heads, 8, 4, cfg.head_dim)
+    kc = layer_caches(shape, jnp.bfloat16)
+    vc = layer_caches(shape, jnp.bfloat16)
     toks = jnp.arange(8, dtype=jnp.int32) + 2
     table = jnp.array([1, 2], jnp.int32)
     ref, _, _ = L.prefill(params, cfg, toks, jnp.int32(8), kc, vc, table)
     got, _, _ = L.prefill(
         params2, cfg2, toks, jnp.int32(8),
-        jnp.zeros_like(kc), jnp.zeros_like(vc), table,
+        layer_caches(shape, jnp.bfloat16), layer_caches(shape, jnp.bfloat16),
+        table,
     )
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=1e-2, atol=1e-2)
     g.close()

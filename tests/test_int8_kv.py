@@ -42,10 +42,8 @@ Hkv, NB, BS, D, Hq = 2, 8, 8, 16, 4
 def _caches(quantized: bool):
     shape = (Hkv, NB, BS, D)
     if quantized:
-        return (
-            kv_quant.make_cache(shape, jnp.bfloat16, quantized=True),
-            kv_quant.make_cache(shape, jnp.bfloat16, quantized=True),
-        )
+        # two layers' containers of one quantized cache: K's and V's
+        return kv_quant.make_cache(2, shape, jnp.bfloat16, quantized=True)
     return jnp.zeros(shape, jnp.bfloat16), jnp.zeros(shape, jnp.bfloat16)
 
 

@@ -7,6 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from tests.util import layer_caches
 from dynamo_tpu.engine.jax_engine.engine import JaxEngine, JaxEngineConfig
 from dynamo_tpu.engine.jax_engine.model_runner import ModelRunner
 from dynamo_tpu.models import llama as L
@@ -70,8 +71,9 @@ async def test_greedy_generation_matches_reference_loop():
     cfg = engine.runner.config
     params = engine.runner.params
     bsz = 4
-    kc = jnp.zeros((cfg.num_layers, cfg.num_kv_heads, 16, bsz, cfg.head_dim), jnp.bfloat16)
-    vc = jnp.zeros_like(kc)
+    shape = (cfg.num_layers, cfg.num_kv_heads, 16, bsz, cfg.head_dim)
+    kc = layer_caches(shape, jnp.bfloat16)
+    vc = layer_caches(shape, jnp.bfloat16)
     table = jnp.array([1, 2], jnp.int32)
     padded = jnp.asarray(np.pad(np.array(prompt, np.int32), (0, 8 - len(prompt))))
     logits, kc, vc = L.prefill(params, cfg, padded, jnp.int32(len(prompt)), kc, vc, table)

@@ -6,6 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from tests.util import layer_caches
 from dynamo_tpu.models import llama as L
 from dynamo_tpu.ops.linear import linear, quantize_int8
 from dynamo_tpu.ops.sampling import sample_tokens
@@ -20,7 +21,7 @@ def tiny_setup():
 
 def _empty_cache(cfg, num_blocks=32, block_size=4):
     shape = (cfg.num_layers, cfg.num_kv_heads, num_blocks, block_size, cfg.head_dim)
-    return jnp.zeros(shape, jnp.bfloat16), jnp.zeros(shape, jnp.bfloat16)
+    return layer_caches(shape, jnp.bfloat16), layer_caches(shape, jnp.bfloat16)
 
 
 def test_prefill_decode_consistency(tiny_setup):
@@ -173,8 +174,8 @@ def test_chunked_prefill_matches_single_shot(tiny_setup):
     )
     # cache contents agree on the used blocks (valid token positions)
     used = np.asarray(table)
-    k_ref = np.asarray(kc_ref[:, :, used], np.float32).reshape(-1, 16, cfg.head_dim)
-    k_new = np.asarray(kc2[:, :, used], np.float32).reshape(-1, 16, cfg.head_dim)
+    k_ref = np.stack(kc_ref)[:, :, used].astype(np.float32).reshape(-1, 16, cfg.head_dim)
+    k_new = np.stack(kc2)[:, :, used].astype(np.float32).reshape(-1, 16, cfg.head_dim)
     np.testing.assert_allclose(k_ref[:, :T], k_new[:, :T], atol=1e-2, rtol=1e-2)
 
 
@@ -210,8 +211,8 @@ def test_chunked_prefill_ragged_table_no_clamp(tiny_setup):
         np.asarray(logits_full), np.asarray(logits_chunk), atol=1e-2, rtol=1e-2
     )
     used = np.asarray(table)
-    k_ref = np.asarray(kc_ref[:, :, used], np.float32).reshape(-1, 12, cfg.head_dim)
-    k_new = np.asarray(kc2[:, :, used], np.float32).reshape(-1, 12, cfg.head_dim)
+    k_ref = np.stack(kc_ref)[:, :, used].astype(np.float32).reshape(-1, 12, cfg.head_dim)
+    k_new = np.stack(kc2)[:, :, used].astype(np.float32).reshape(-1, 12, cfg.head_dim)
     np.testing.assert_allclose(k_ref[:, :T], k_new[:, :T], atol=1e-2, rtol=1e-2)
 
 

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from tests.util import layer_caches
 from dynamo_tpu.models import llama as L
 from dynamo_tpu.ops import linear as lin
 from dynamo_tpu.ops.basics import rope_freqs
@@ -118,8 +119,8 @@ def _mesh_decode_once(cfg, params, mesh, *, fused, overlap=False):
     )
     B, bs, nb = 3, 8, 32
     shape = (c.num_layers, c.num_kv_heads, nb, bs, c.head_dim)
-    kc = jnp.zeros(shape, jnp.bfloat16)
-    vc = jnp.zeros(shape, jnp.bfloat16)
+    kc = layer_caches(shape, jnp.bfloat16)
+    vc = layer_caches(shape, jnp.bfloat16)
     run_params = params
     if mesh is not None:
         run_params, kv_sharding = shard_llama(mesh, c, params)

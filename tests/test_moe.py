@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from tests.util import layer_caches
 from dynamo_tpu.models import mixtral
 from dynamo_tpu.ops.basics import swiglu
 from dynamo_tpu.ops.moe import (
@@ -165,10 +166,9 @@ def test_mixtral_prefill_decode_runs():
     cfg = mixtral.tiny_moe()
     params = mixtral.init_params(cfg, jax.random.PRNGKey(0))
     bs, nb = 16, 8
-    kc = jnp.zeros(
-        (cfg.num_layers, cfg.num_kv_heads, nb, bs, cfg.head_dim), jnp.bfloat16
-    )
-    vc = jnp.zeros_like(kc)
+    shape = (cfg.num_layers, cfg.num_kv_heads, nb, bs, cfg.head_dim)
+    kc = layer_caches(shape, jnp.bfloat16)
+    vc = layer_caches(shape, jnp.bfloat16)
     tokens = jnp.arange(16, dtype=jnp.int32) % cfg.vocab_size
     logits, kc, vc = mixtral.prefill(
         params, cfg, tokens, jnp.int32(16), kc, vc,

@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from tests.util import layer_caches
 from dynamo_tpu.models import llama as L
 from dynamo_tpu.multimodal.processor import (
     expand_image_prompt,
@@ -87,8 +88,8 @@ def test_prefill_mm_matches_embedding_oracle():
         np.random.default_rng(5).normal(size=(M, cfg.hidden_size)),
         jnp.float32,
     )
-    k0 = jnp.zeros(kshape, jnp.float32)
-    v0 = jnp.zeros(kshape, jnp.float32)
+    k0 = layer_caches(kshape, jnp.float32)
+    v0 = layer_caches(kshape, jnp.float32)
     got, _, _ = L.prefill_mm(
         params, cfg, tokens, jnp.int32(P), k0, v0, table, mm, jnp.int32(start)
     )
@@ -96,13 +97,15 @@ def test_prefill_mm_matches_embedding_oracle():
     x = x.at[start : start + M].set(mm.astype(x.dtype))
     want, _, _ = L._prefill_from_embeds(
         params, cfg, x, jnp.int32(P),
-        jnp.zeros(kshape, jnp.float32), jnp.zeros(kshape, jnp.float32), table,
+        layer_caches(kshape, jnp.float32), layer_caches(kshape, jnp.float32),
+        table,
     )
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
     # and the splice actually matters: text-only logits differ
     text, _, _ = L.prefill(
         params, cfg, tokens, jnp.int32(P),
-        jnp.zeros(kshape, jnp.float32), jnp.zeros(kshape, jnp.float32), table,
+        layer_caches(kshape, jnp.float32), layer_caches(kshape, jnp.float32),
+        table,
     )
     assert not np.allclose(np.asarray(got), np.asarray(text), atol=1e-3)
 

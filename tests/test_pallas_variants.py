@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from tests.util import layer_caches
 from dynamo_tpu.ops import attention as A
 from dynamo_tpu.ops import pallas_attention as PA
 
@@ -211,8 +212,8 @@ def test_gemma3_pattern_end_to_end_all_layers_flash(monkeypatch):
     cache_shape = (cfg.num_layers, cfg.num_kv_heads, nb, bs, cfg.head_dim)
 
     def run(impl):
-        kc = jnp.zeros(cache_shape, jnp.float32)
-        vc = jnp.zeros(cache_shape, jnp.float32)
+        kc = layer_caches(cache_shape, jnp.float32)
+        vc = layer_caches(cache_shape, jnp.float32)
         c = dataclasses.replace(cfg, attn_impl=impl)
         tokens = jnp.arange(P, dtype=jnp.int32) % cfg.vocab_size
         table = jnp.arange(1, 1 + P // bs, dtype=jnp.int32)
@@ -258,8 +259,8 @@ def test_gemma3_pattern_verify_all_layers_flash(monkeypatch):
     cache_shape = (cfg.num_layers, cfg.num_kv_heads, nb, bs, cfg.head_dim)
 
     def run(impl):
-        kc = jnp.zeros(cache_shape, jnp.float32)
-        vc = jnp.zeros(cache_shape, jnp.float32)
+        kc = layer_caches(cache_shape, jnp.float32)
+        vc = layer_caches(cache_shape, jnp.float32)
         c = dataclasses.replace(cfg, attn_impl=impl)
         bt = jnp.stack(
             [jnp.arange(1, nb, dtype=jnp.int32),

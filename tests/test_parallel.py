@@ -6,6 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from tests.util import layer_caches
 from dynamo_tpu.models import llama as L
 from dynamo_tpu.parallel.mesh import build_mesh
 from dynamo_tpu.parallel.sharding import shard_llama
@@ -21,8 +22,8 @@ def test_tp_sharded_prefill_matches_single_device():
     toks = jax.random.randint(jax.random.PRNGKey(1), (8,), 0, 64)
     table = jnp.array([1, 2], jnp.int32)
     shape = (cfg.num_layers, cfg.num_kv_heads, 8, 4, cfg.head_dim)
-    kc = jnp.zeros(shape, jnp.bfloat16)
-    vc = jnp.zeros_like(kc)
+    kc = layer_caches(shape, jnp.bfloat16)
+    vc = layer_caches(shape, jnp.bfloat16)
     logits_ref, kc_ref, _ = L.prefill(
         params, cfg, toks, jnp.int32(8), kc, vc, table
     )
@@ -42,13 +43,13 @@ def test_tp_sharded_prefill_matches_single_device():
         np.asarray(logits_ref), np.asarray(logits_sh), atol=3e-2, rtol=3e-2
     )
     # cache kept its tp sharding through the jit
-    assert kc_out.sharding.spec == kv_sharding.spec
+    assert all(c.sharding.spec == kv_sharding.spec for c in kc_out)
     # decode on the sharded state matches too
     bt = jnp.zeros((1, 4), jnp.int32).at[0, :2].set(table)
     slot = jnp.array([1 * 4 + 0], jnp.int32)  # position 8 -> block 2... see map
     logits_d_ref, _, _ = L.decode(
         params, cfg, jnp.array([3], jnp.int32), jnp.array([8], jnp.int32),
-        kc_ref, jnp.zeros_like(kc_ref), bt, slot,
+        kc_ref, layer_caches(shape, jnp.bfloat16), bt, slot,
     )
     decode_jit = jax.jit(L.decode, static_argnums=(1,))
     logits_d_sh, _, _ = decode_jit(
@@ -74,8 +75,8 @@ def test_pallas_shard_map_attention_matches_xla():
     toks = jax.random.randint(jax.random.PRNGKey(1), (8,), 0, 64)
     table = jnp.array([1, 2], jnp.int32)
     shape = (cfg.num_layers, cfg.num_kv_heads, 8, 4, cfg.head_dim)
-    kc = jnp.zeros(shape, jnp.bfloat16)
-    vc = jnp.zeros_like(kc)
+    kc = layer_caches(shape, jnp.bfloat16)
+    vc = layer_caches(shape, jnp.bfloat16)
     logits_ref, kc_ref, vc_ref = L.prefill(
         params, cfg, toks, jnp.int32(8), kc, vc, table
     )
@@ -93,7 +94,7 @@ def test_pallas_shard_map_attention_matches_xla():
     np.testing.assert_allclose(
         np.asarray(logits_ref), np.asarray(logits_pl), atol=3e-2, rtol=3e-2
     )
-    assert kc_pl.sharding.spec == kv_sharding.spec
+    assert all(c.sharding.spec == kv_sharding.spec for c in kc_pl)
 
     # decode step: pallas shard_map vs the unsharded xla reference
     bt = jnp.zeros((1, 4), jnp.int32).at[0, :2].set(table)

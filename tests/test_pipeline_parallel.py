@@ -15,10 +15,13 @@ from dynamo_tpu.models import llama as L
 from dynamo_tpu.parallel.mesh import build_mesh
 from dynamo_tpu.parallel.pipeline import (
     decode_pp,
+    make_pp_cache,
+    pp_cache_layers,
     prefill_pp,
     shard_stacked_pp,
     stack_layer_params,
 )
+from tests.util import layer_caches
 
 BS = 4
 
@@ -48,13 +51,15 @@ def setup(pp=2, num_layers=4, quantize=False, attn_bias=False):
 
 
 def caches(cfg, nb=16, sharding=None):
-    shape = (cfg.num_layers, cfg.num_kv_heads, nb, BS, cfg.head_dim)
-    k = jnp.zeros(shape, jnp.float32)
-    v = jnp.zeros(shape, jnp.float32)
+    """(k, v): per layer for the reference path; with the pp sharding, per
+    layer of a stage with the stage axis sharded."""
     if sharding is not None:
-        k = jax.device_put(k, sharding)
-        v = jax.device_put(v, sharding)
-    return k, v
+        return (
+            make_pp_cache(sharding, cfg, nb, BS, jnp.float32),
+            make_pp_cache(sharding, cfg, nb, BS, jnp.float32),
+        )
+    shape = (cfg.num_layers, cfg.num_kv_heads, nb, BS, cfg.head_dim)
+    return layer_caches(shape, jnp.float32), layer_caches(shape, jnp.float32)
 
 
 def test_stack_rejects_moe():
@@ -99,7 +104,7 @@ def test_prefill_pp_matches_reference():
     )
     # every stage wrote ITS layers' pages: full caches must match
     np.testing.assert_allclose(
-        np.asarray(k_pp), np.asarray(k_ref), rtol=2e-3, atol=2e-3
+        np.stack(pp_cache_layers(k_pp)), np.stack(k_ref), rtol=2e-3, atol=2e-3
     )
 
 
@@ -139,7 +144,7 @@ def test_decode_pp_matches_reference():
         np.asarray(logits_pp), np.asarray(logits_ref), rtol=2e-3, atol=2e-3
     )
     np.testing.assert_allclose(
-        np.asarray(k_pp2), np.asarray(k_ref2), rtol=2e-3, atol=2e-3
+        np.stack(pp_cache_layers(k_pp2)), np.stack(k_ref2), rtol=2e-3, atol=2e-3
     )
 
 
@@ -207,7 +212,7 @@ def test_prefill_decode_pp_int8_matches_reference():
         np.asarray(logits_pp), np.asarray(logits_ref), rtol=2e-3, atol=2e-3
     )
     np.testing.assert_allclose(
-        np.asarray(k_pp2), np.asarray(k_ref2), rtol=2e-3, atol=2e-3
+        np.stack(pp_cache_layers(k_pp2)), np.stack(k_ref2), rtol=2e-3, atol=2e-3
     )
 
 

@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from tests.util import layer_caches
 from dynamo_tpu.models import llama as L
 
 
@@ -32,10 +33,9 @@ def test_hf_config_detection():
 
 
 def _prefill_logits(cfg, params, toks=8):
-    kc = jnp.zeros(
-        (cfg.num_layers, cfg.num_kv_heads, 16, 4, cfg.head_dim), jnp.bfloat16
-    )
-    vc = jnp.zeros_like(kc)
+    shape = (cfg.num_layers, cfg.num_kv_heads, 16, 4, cfg.head_dim)
+    kc = layer_caches(shape, jnp.bfloat16)
+    vc = layer_caches(shape, jnp.bfloat16)
     tokens = jnp.arange(toks, dtype=jnp.int32) + 2
     logits, _, _ = L.prefill(
         params, cfg, tokens, jnp.int32(toks), kc, vc,

@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from tests.util import layer_caches
 from dynamo_tpu.models import llama as L
 from dynamo_tpu.ops.attention import causal_prefill_attention
 from dynamo_tpu.parallel.ring_attention import ring_prefill_attention
@@ -126,13 +127,13 @@ def test_cp_prefill_accepts_sliding_window_model():
     tokens = jnp.arange(P, dtype=jnp.int32) % cfg.vocab_size
     table = jnp.arange(1, 1 + P // bs, dtype=jnp.int32)
 
-    kc = jnp.zeros(cache_shape, jnp.float32)
-    vc = jnp.zeros(cache_shape, jnp.float32)
+    kc = layer_caches(cache_shape, jnp.float32)
+    vc = layer_caches(cache_shape, jnp.float32)
     ref_logits, ref_kc, ref_vc = L.prefill(
         params, cfg, tokens, jnp.int32(P), kc, vc, table
     )
-    kc = jnp.zeros(cache_shape, jnp.float32)
-    vc = jnp.zeros(cache_shape, jnp.float32)
+    kc = layer_caches(cache_shape, jnp.float32)
+    vc = layer_caches(cache_shape, jnp.float32)
     out_logits, out_kc, out_vc = L.prefill_context_parallel(
         params, cfg, mesh, tokens, jnp.int32(P),
         k_cache=kc, v_cache=vc, block_table=table,
@@ -141,10 +142,10 @@ def test_cp_prefill_accepts_sliding_window_model():
         np.asarray(out_logits), np.asarray(ref_logits), atol=2e-4, rtol=2e-4
     )
     np.testing.assert_allclose(
-        np.asarray(out_kc), np.asarray(ref_kc), atol=2e-5, rtol=2e-5
+        np.stack(out_kc), np.stack(ref_kc), atol=2e-5, rtol=2e-5
     )
     np.testing.assert_allclose(
-        np.asarray(out_vc), np.asarray(ref_vc), atol=2e-5, rtol=2e-5
+        np.stack(out_vc), np.stack(ref_vc), atol=2e-5, rtol=2e-5
     )
 
 
@@ -220,11 +221,9 @@ def test_context_parallel_prefill_matches_serial():
     # serial oracle via the paged prefill path
     block_size = 16
     nb = Pn // block_size
-    kc = jnp.zeros(
-        (cfg.num_layers, cfg.num_kv_heads, nb + 1, block_size, cfg.head_dim),
-        jnp.float32,
-    )
-    vc = jnp.zeros_like(kc)
+    shape = (cfg.num_layers, cfg.num_kv_heads, nb + 1, block_size, cfg.head_dim)
+    kc = layer_caches(shape, jnp.float32)
+    vc = layer_caches(shape, jnp.float32)
     table = jnp.arange(1, nb + 1, dtype=jnp.int32)
     logits_ref, kc, vc = L.prefill(
         params, cfg, tokens, jnp.int32(valid), kc, vc, table
@@ -239,7 +238,7 @@ def test_context_parallel_prefill_matches_serial():
     # compare produced K against what the serial path wrote to its cache
     # cache layer i: [Hkv, nb+1, bs, D]; blocks 1..nb hold the prompt
     k_cache_tokens = (
-        np.asarray(kc)[:, :, 1:]
+        np.stack(kc)[:, :, 1:]
         .transpose(0, 2, 3, 1, 4)
         .reshape(cfg.num_layers, Pn, cfg.num_kv_heads, cfg.head_dim)
     )

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from tests.util import layer_caches
 from dynamo_tpu.models import llama as L
 from dynamo_tpu.ops.attention import (
     causal_prefill_attention,
@@ -111,7 +112,7 @@ def _empty_cache(cfg, num_blocks=32, block_size=4, dtype=jnp.bfloat16):
         cfg.num_layers, cfg.num_kv_heads, num_blocks, block_size,
         cfg.head_dim,
     )
-    return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
+    return layer_caches(shape, dtype), layer_caches(shape, dtype)
 
 
 def _pad(a, n):

@@ -19,6 +19,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -275,9 +276,13 @@ def test_meshed_fused_kernels_tp4(tp4):
 # table sizes are the smoke's.
 
 
-def _step_setup():
-    """(cfg, params, cache_shape): shapes of a 2-layer Mistral-7B-wide
-    model with int8 weights, not yet placed on any device."""
+POOL_BLOCKS = 3400  # mistral7b-int8.chat-steady's pool on one 16 GB chip
+
+
+def _step_setup(num_blocks: int = 1024):
+    """(cfg, params, layer_shape): shapes of a 2-layer Mistral-7B-wide
+    model with int8 weights, not yet placed on any device; layer_shape is
+    one layer's [Hkv, num_blocks, BLOCK, D] cache array."""
     from dynamo_tpu.models import llama
 
     cfg = llama.LlamaConfig.from_hf_dict({
@@ -291,25 +296,24 @@ def _step_setup():
     params = jax.eval_shape(
         lambda: llama.init_params(cfg, jax.random.PRNGKey(0), BF16, True)
     )
-    cache_shape = (cfg.num_layers, HKV, 1024, BLOCK, D)
-    return cfg, params, cache_shape
+    return cfg, params, (HKV, num_blocks, BLOCK, D)
 
 
-def _step_setup_one_chip(one_chip):
-    """(cfg, params, cache) placed on the one described device."""
-    cfg, params, cache_shape = _step_setup()
+def _step_setup_one_chip(one_chip, num_blocks: int = 1024):
+    """(cfg, params, cache) placed on the one described device; cache is
+    the runner's container, one array per layer."""
+    cfg, params, layer_shape = _step_setup(num_blocks)
     params = jax.tree_util.tree_map(
         lambda a: one_chip(a.shape, a.dtype), params
     )
-    return cfg, params, one_chip(cache_shape, BF16)
+    return cfg, params, (one_chip(layer_shape, BF16),) * cfg.num_layers
 
 
-def test_decode_multi_program_one_chip(one_chip):
-    """decode_multi@H4B64: the unrolled horizon with sampling fused in."""
+def _lower_decode_multi(one_chip, num_blocks: int = 1024):
     from dynamo_tpu.engine.jax_engine.model_runner import ModelRunner
     from dynamo_tpu.ops.sampling import MAX_EOS_IDS
 
-    cfg, params, cache = _step_setup_one_chip(one_chip)
+    cfg, params, cache = _step_setup_one_chip(one_chip, num_blocks)
     fn = jax.jit(
         functools.partial(
             ModelRunner._decode_multi_impl, cfg, None, None, BLOCK
@@ -317,22 +321,19 @@ def test_decode_multi_program_one_chip(one_chip):
         static_argnums=(0,), donate_argnums=(2, 3),
     )
     vec = lambda dtype: one_chip((B,), dtype)
-    compiled = fn.lower(
+    return fn.lower(
         4, params, cache, cache, vec(I32), vec(I32),
         one_chip((B, CONTEXT // BLOCK), I32), one_chip((B, 2), jnp.uint32),
         vec(F32), vec(F32), vec(I32), vec(jnp.bool_), vec(I32), vec(I32),
         one_chip((B, MAX_EOS_IDS), I32),
-    ).compile()
-    # 2 layers x 4 unrolled steps, each with the paged decode kernel
-    assert compiled.as_text().count("tpu_custom_call") >= 8
+    )
 
 
-def test_mixed_step_program_one_chip(one_chip):
-    """mixed_step@c1: one 512-token prefill chunk ahead of the decode batch."""
+def _lower_mixed_step(one_chip, num_blocks: int = 1024):
     from dynamo_tpu.engine.jax_engine.model_runner import ModelRunner
     from dynamo_tpu.ops.sampling import MAX_EOS_IDS
 
-    cfg, params, cache = _step_setup_one_chip(one_chip)
+    cfg, params, cache = _step_setup_one_chip(one_chip, num_blocks)
     scalar = lambda dtype: one_chip((), dtype)
     vec = lambda dtype: one_chip((B,), dtype)
     chunk = (
@@ -345,29 +346,174 @@ def test_mixed_step_program_one_chip(one_chip):
         functools.partial(ModelRunner._mixed_impl, cfg, None, None),
         donate_argnums=(1, 2),
     )
-    compiled = fn.lower(
+    return fn.lower(
         params, cache, cache, (chunk,), vec(I32), vec(I32),
         one_chip((B, CONTEXT // BLOCK), I32), vec(I32),
         one_chip((B, 2), jnp.uint32), vec(F32), vec(F32), vec(I32),
         one_chip((B, MAX_EOS_IDS), I32), vec(jnp.bool_),
-    ).compile()
+    )
+
+
+def _lower_prefill_packed(one_chip, tokens: int, num_blocks: int = 1024):
+    from dynamo_tpu.engine.jax_engine.model_runner import ModelRunner
+    from dynamo_tpu.ops.sampling import MAX_EOS_IDS
+
+    cfg, params, cache = _step_setup_one_chip(one_chip, num_blocks)
+    tok = lambda dtype: one_chip((tokens,), dtype)
+    vec = lambda dtype: one_chip((B,), dtype)
+    fn = jax.jit(
+        functools.partial(ModelRunner._prefill_packed_impl, cfg, None),
+        donate_argnums=(1, 2),
+    )
+    return fn.lower(
+        params, cache, cache, tok(I32), tok(I32), tok(I32), tok(I32),
+        vec(I32), one_chip((B, 2), jnp.uint32), vec(F32), vec(F32),
+        vec(I32), vec(F32), one_chip((B, MAX_EOS_IDS), I32), vec(jnp.bool_),
+    )
+
+
+def test_decode_multi_program_one_chip(one_chip):
+    """decode_multi@H4B64: the unrolled horizon with sampling fused in."""
+    compiled = _lower_decode_multi(one_chip).compile()
+    # 2 layers x 4 unrolled steps, each with the paged decode kernel
+    assert compiled.as_text().count("tpu_custom_call") >= 8
+
+
+def test_mixed_step_program_one_chip(one_chip):
+    """mixed_step@c1: one 512-token prefill chunk ahead of the decode batch."""
+    compiled = _lower_mixed_step(one_chip).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_decode_program_tp4(tp4):
-    """decode@B64 over the tp=4 mesh: megatron shardings from shard_llama,
-    the paged decode kernel under shard_map, all-reduces after the
-    row-parallel projections."""
+# A step writes the live lanes' rows and blocks into each layer's own cache
+# buffer. Until PR 26 every program sliced each layer out of one [L, ...]
+# array, re-laid it twice around a scatter and wrote it back: 4 slices, 10
+# copies and 4 dynamic-update-slices of a layer's whole pool in this
+# two-layer decode_multi, three fifths of the device's time in both cells.
+# A layer of the file's 1,024 blocks is small enough for the compiler to
+# stage whole, which the cells' pool is not, so these compile at the cell's
+# 3,400 blocks (shapes only; it costs nothing). Only bf16 results count: the
+# cache is the one bf16 operand of that size here, and the compiler's
+# prefetches of the int8 weights (4096 x 14336 elements, more than a layer's
+# pool) are copies and slices too.
+
+_HLO_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT\s+)?%?(?P<name>[\w.\-]+)\s*=\s*"
+    r"(?P<type>\(.*?\)|\S+)\s+(?P<op>[a-z][\w\-]*)\("
+)
+_HLO_SHAPE = re.compile(r"\bbf16\[([\d,]*)\]")
+_MOVERS = ("dynamic-update-slice", "slice", "copy")
+
+
+def _entry_instructions(text: str):
+    """(name, op, element count of the largest bf16 array in the result's
+    type, line) of each instruction of the module's entry computation."""
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[: entry.index("\n}")]
+    for line in entry.splitlines()[1:]:
+        m = _HLO_INSTRUCTION.match(line)
+        if not m:
+            continue
+        sizes = [
+            int(np.prod([int(d) for d in dims.split(",") if d] or [1]))
+            for dims in _HLO_SHAPE.findall(m["type"])
+        ]
+        yield m["name"], m["op"], max(sizes, default=0), line
+
+
+def _pool_sized_movers(text: str, pool_elements: int) -> list[str]:
+    """Entry instructions that slice, copy or update-in-a-copy at least one
+    layer's pool: plain, asynchronous (-start/-done), or a fusion the
+    compiler named after them."""
+    found = []
+    for name, op, elements, _ in _entry_instructions(text):
+        if elements < pool_elements:
+            continue
+        base = op.removesuffix("-start").removesuffix("-done")
+        if base in _MOVERS or base == "dynamic-slice" or (
+            op == "fusion" and any(word in name for word in _MOVERS)
+        ):
+            found.append(f"{op} {name} ({elements} elements)")
+    return found
+
+
+def _aliased_parameters(text: str) -> set[int]:
+    header = text[: text.index("\n")]
+    start = header.index("input_output_alias={") + len("input_output_alias={")
+    depth, end = 1, start
+    while depth:
+        depth += {"{": 1, "}": -1}.get(header[end], 0)
+        end += 1
+    return {int(n) for n in re.findall(r"\((\d+), \{", header[start:end])}
+
+
+STEP_PROGRAMS = {
+    "decode_multi@H4B64": (_lower_decode_multi, {}),
+    "mixed_step@c1": (_lower_mixed_step, {}),
+    "prefill_packed@512": (_lower_prefill_packed, {"tokens": 512}),
+    "prefill_packed@2048": (_lower_prefill_packed, {"tokens": 2048}),
+}
+
+
+@pytest.mark.parametrize("program", list(STEP_PROGRAMS))
+def test_step_program_moves_no_pool(one_chip, program):
+    lower, kwargs = STEP_PROGRAMS[program]
+    compiled = lower(one_chip, num_blocks=POOL_BLOCKS, **kwargs).compile()
+    text = compiled.as_text()
+    pool_elements = HKV * POOL_BLOCKS * BLOCK * D
+    assert _pool_sized_movers(text, pool_elements) == []
+    # every layer's K and V buffer is written where it lies
+    cache_params = {
+        int(re.search(r"parameter\((\d+)\)", line)[1])
+        for _, op, elements, line in _entry_instructions(text)
+        if op == "parameter" and elements == pool_elements
+    }
+    assert len(cache_params) == 2 * 2  # two layers, K and V
+    assert cache_params <= _aliased_parameters(text)
+    if program.startswith("decode"):
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < pool_elements * 2  # one layer's pool in bf16
+
+
+def test_pool_mover_scan_finds_what_it_forbids():
+    """The scan over the compiled text, on lines as the compiler wrote them
+    for the one-array cache (ledger, PR 25: the four names of `copy_share`)."""
+    text = """HloModule jit_f, input_output_alias={ {1}: (33, {}, may-alias), {2}: (34, {}, may-alias) }, entry_computation_layout={()->()}
+
+ENTRY %main.1 (Arg_0.1: bf16[8,3400,16,128]) -> bf16[8,3400,16,128] {
+  %param.33 = bf16[8,3400,16,128]{3,2,1,0} parameter(33)
+  %slice.7 = bf16[1,8,3400,16,128]{4,3,2,1,0} slice(%p), slice={[0:1], [0:8], [0:3400], [0:16], [0:128]}
+  %copy.3 = bf16[8,54400,128]{2,1,0} copy(%bitcast.9)
+  %copy-start.1 = (bf16[1,8,3400,16,128]{4,1,3,2,0}, bf16[1,8,3400,16,128]{4,3,2,1,0}, u32[]) copy-start(%slice.7)
+  %copy_dynamic-update-slice_fusion = bf16[32,8,3400,16,128]{4,3,2,1,0} fusion(%a, %b), kind=kLoop, calls=%fused
+  %scatter_fusion.2 = bf16[435200,128]{1,0} fusion(%c, %d), kind=kInput, calls=%fused.2
+  %small.1 = bf16[64,8,128]{2,1,0} copy(%e)
+  %copy-start.9 = (s8[4096,14336]{1,0}, s8[4096,14336]{1,0}, u32[]) copy-start(%w), cross_program_prefetch_index=0
+  ROOT %tuple.5 = (bf16[8,3400,16,128]{3,2,1,0}) tuple(%param.33)
+}
+"""
+    found = _pool_sized_movers(text, HKV * POOL_BLOCKS * BLOCK * D)
+    assert [f.split()[1] for f in found] == [
+        "slice.7", "copy.3", "copy-start.1",
+        "copy_dynamic-update-slice_fusion",
+    ]
+    assert _aliased_parameters(text) == {33, 34}
+
+
+def _lower_decode_tp4(tp4, num_blocks: int = 1024):
+    """(lowered, cfg, layer_shape) of decode@B64 over the tp=4 mesh."""
     from dynamo_tpu.engine.jax_engine.model_runner import ModelRunner
     from dynamo_tpu.parallel.sharding import shard_llama
 
     mesh, sds = tp4
-    cfg, params, cache_shape = _step_setup()
+    cfg, params, layer_shape = _step_setup(num_blocks)
     params, kv_sharding = shard_llama(
         mesh, cfg, params,
         put=lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
     )
-    cache = jax.ShapeDtypeStruct(cache_shape, BF16, sharding=kv_sharding)
+    cache = (
+        jax.ShapeDtypeStruct(layer_shape, BF16, sharding=kv_sharding),
+    ) * cfg.num_layers
     repl = NamedSharding(mesh, P())
     fn = jax.jit(
         functools.partial(ModelRunner._decode_impl, cfg, mesh, "tp"),
@@ -375,14 +521,38 @@ def test_decode_program_tp4(tp4):
         out_shardings=((repl,) * 4, kv_sharding, kv_sharding),
     )
     vec = lambda dtype: sds((B,), dtype)
-    compiled = fn.lower(
+    lowered = fn.lower(
         params, cache, cache, vec(I32), vec(I32),
         sds((B, CONTEXT // BLOCK), I32), vec(I32), sds((B, 2), jnp.uint32),
         vec(F32), vec(F32), vec(I32),
-    ).compile()
+    )
+    return lowered, cfg, layer_shape
+
+
+def test_decode_program_tp4(tp4):
+    """decode@B64 over the tp=4 mesh: megatron shardings from shard_llama,
+    the paged decode kernel under shard_map, all-reduces after the
+    row-parallel projections."""
+    lowered, cfg, layer_shape = _lower_decode_tp4(tp4)
+    compiled = lowered.compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "all-reduce" in text
     # heads sharded four ways: each device holds a quarter of the cache
     per_device = compiled.memory_analysis().argument_size_in_bytes
-    full_cache = 2 * int(np.prod(cache_shape)) * 2
+    full_cache = 2 * cfg.num_layers * int(np.prod(layer_shape)) * 2
     assert per_device < 0.5 * full_cache + 2 * 2**30
+
+
+def test_decode_program_tp4_moves_no_pool(tp4):
+    """The row scatter over the head-sharded cache: each device writes its
+    own heads' rows in place (no gather of a layer, no copy), at the 16,448
+    blocks the four-chip smoke holds."""
+    blocks = 16448
+    text = _lower_decode_tp4(tp4, blocks)[0].compile().as_text()
+    per_device_pool = (HKV // 4) * blocks * BLOCK * D
+    assert _pool_sized_movers(text, per_device_pool) == []
+    assert not [
+        line for _, op, elements, line in _entry_instructions(text)
+        if op.startswith("all-gather") and elements >= per_device_pool
+    ]
+    assert len(_aliased_parameters(text)) == 2 * 2

@@ -13,6 +13,15 @@ TEST_WORDS = (
 ).split()
 
 
+def layer_caches(shape, dtype):
+    """A zeroed paged cache as the step programs take it: one
+    [Hkv, num_blocks, block_size, D] array per layer. `shape` is
+    (num_layers, Hkv, num_blocks, block_size, D)."""
+    from dynamo_tpu.ops.kv_quant import make_cache
+
+    return make_cache(shape[0], shape[1:], dtype, quantized=False)
+
+
 def make_test_tokenizer() -> TokenizerWrapper:
     vocab = {"<unk>": 0, "<s>": 1, "</s>": 2}
     for w in TEST_WORDS:
