@@ -425,6 +425,21 @@ def goodput_families(
         label, _, cause = str(key).partition("|")
         rec.add_metric([label, cause or "shape_miss"], float(v))
     yield rec
+    moe = gp.moe if gp is not None else {}
+    for name, what in (
+        ("layer_steps", "(expert layer, decode step) pairs counted"),
+        ("assignments", "token-to-expert assignments of live lanes"),
+        ("experts_touched", "distinct experts with at least one token, "
+         "summed over (layer, step) pairs"),
+        ("max_expert_load", "tokens of the busiest expert, summed over "
+         "(layer, step) pairs"),
+    ):
+        yield CounterMetricFamily(
+            f"{PREFIX}_moe_{name}",
+            f"Sparse-expert decode horizons: {what} (counted on the "
+            "device, fetched with the tokens; fleet sum)",
+            value=float(moe.get(name, 0.0)),
+        )
     comp = GaugeMetricFamily(
         f"{PREFIX}_compile_seconds",
         "First-dispatch (compile-inclusive) wall time per dispatch label "

@@ -466,7 +466,7 @@ class JaxEngine:
         # Disaggregation (SURVEY §7.6): when both are set, long prompts are
         # shipped to the prefill fleet instead of running locally.
         self.disagg_router = disagg_router
-        self.remote_prefill_client = remote_prefill_client
+        self.remote_prefill_client = remote_prefill_client  # checked setter
         # Tiered KV offload (KVBM equivalent): blocks are copied to the
         # host/disk tiers keyed by sequence hash — mid-generation at block
         # boundaries (rate-limited through the priority queue below, like
@@ -476,6 +476,7 @@ class JaxEngine:
         self.block_manager = block_manager
         self._offload_queue = None
         if block_manager is not None:
+            self.runner.require_block_transfer("a tiered block manager")
             from dynamo_tpu.block_manager.offload import OffloadQueue
 
             self._offload_queue = OffloadQueue()
@@ -692,6 +693,29 @@ class JaxEngine:
                 )
 
     # ---------------------------------------------------------- watchdog
+
+    # Disaggregated prefill and peer pulls are wired by plain assignment
+    # after the engine is built (graphs/disagg.py): the setters refuse, at
+    # that moment and in words, a runner whose cache blocks cannot travel.
+    @property
+    def remote_prefill_client(self):
+        return self._remote_prefill_client
+
+    @remote_prefill_client.setter
+    def remote_prefill_client(self, client) -> None:
+        if client is not None:
+            self.runner.require_block_transfer("disaggregated prefill")
+        self._remote_prefill_client = client
+
+    @property
+    def peer_block_client(self):
+        return self._peer_block_client
+
+    @peer_block_client.setter
+    def peer_block_client(self, client) -> None:
+        if client is not None:
+            self.runner.require_block_transfer("a peer block pull")
+        self._peer_block_client = client
 
     async def _dispatch(
         self,
@@ -3123,6 +3147,9 @@ class JaxEngine:
                         self._finish(seq, FinishReason.ERROR)
             return
         with dtrace.phase("loop.emit"):
+            counted = self.runner.step_stats(packed)
+            if counted is not None:
+                self.stats.goodput.record_moe(counted)
             K = (packed.shape[-1] - 2) // 2
             for h in range(H):
                 step = packed[h]
@@ -3308,6 +3335,12 @@ class JaxEngine:
         mcfg = getattr(self.runner, "config", None)
         if mcfg is None or not hasattr(mcfg, "num_layers"):
             return  # mocker/echo engines have no model config
+        from dynamo_tpu.models import cache_kind
+
+        if cache_kind(mcfg).name != "kv_heads":
+            # perf_model.py models the grouped-query block's bytes and
+            # operations only; another family's gauges stay at 0
+            return
         active = [s for s in self.slots if s is not None]
         now = time.monotonic()
         win = self._mfu_window

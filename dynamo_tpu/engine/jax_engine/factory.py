@@ -6,7 +6,6 @@ subprocess)."""
 
 from __future__ import annotations
 
-import dataclasses
 import json
 import os
 from typing import Optional
@@ -18,7 +17,7 @@ from dynamo_tpu.engine.jax_engine.engine import JaxEngine, JaxEngineConfig
 from dynamo_tpu.engine.jax_engine.model_runner import ModelRunner
 from dynamo_tpu.engine.jax_engine.weights import load_or_init_params
 from dynamo_tpu.model_card import ModelDeploymentCard
-from dynamo_tpu.models.llama import LlamaConfig
+from dynamo_tpu.models import cache_kind, config_from_model_dir, forward_for
 from dynamo_tpu.runtime.logging import get_logger
 
 logger = get_logger("dynamo_tpu.engine.factory")
@@ -100,7 +99,16 @@ async def build_jax_engine(
         gguf_file = GgufFile(model_path)
         config, params = params_from_gguf(gguf_file)
     else:
-        config = LlamaConfig.from_model_dir(model_path)
+        config = config_from_model_dir(model_path)
+        refuse_unsupported(
+            config, quantize=quantize, kv_dtype=kv_dtype,
+            meshed=(
+                tensor_parallel_size > 1 or data_parallel_size > 1
+                or context_parallel_size > 1 or expert_parallel_size > 1
+                or is_multihost
+            ),
+            fused_decode=fused_decode,
+        )
         params = load_or_init_params(
             model_path, config, quantize=quantize, seed=rng_seed
         )
@@ -206,6 +214,42 @@ async def build_jax_engine(
     )
     logger.info("jax engine built: %s", json.dumps(_built_facts(engine)))
     return engine, mdc
+
+
+def refuse_unsupported(
+    config, *, quantize: bool = False, kv_dtype: str = "bf16",
+    meshed: bool = False, fused_decode: bool = False,
+) -> None:
+    """Say at start-up, in words, what a model whose layers keep a latent
+    plane is not served with yet; nothing falls back in silence. A
+    grouped-query model passes untouched."""
+    kind = cache_kind(config)
+    if kind.name == "kv_heads":
+        return
+    asked = {
+        "int8 weights (DYN_JAX_QUANTIZE_INT8): not implemented for expert "
+        "stacks": quantize,
+        "an int8-resident cache (DYN_KV_DTYPE=int8): its scales are kept by "
+        "head, and a latent plane has none": kv_dtype == "int8",
+        "a tensor-, expert-, data- or context-parallel mesh or several "
+        "hosts: the latent plane has no head axis to shard and the experts' "
+        "share has no add-up test yet": meshed,
+        "the fused decode step (DYN_FUSED_DECODE): its kernels are the "
+        "grouped-query block's": fused_decode,
+        "block-manager tiers (DYN_KV_HOST_OFFLOAD_GB): a tier's layout is "
+        "keys and values by head": float(
+            os.environ.get("DYN_KV_HOST_OFFLOAD_GB", "0") or 0
+        ) > 0,
+        "speculative decoding (DYN_SPEC_K): no verify program for latent "
+        "attention": spec_decode_settings()["spec_k"] > 0,
+    }
+    refused = [what for what, on in asked.items() if on]
+    if refused:
+        raise ValueError(
+            f"{type(config).__name__} keeps a {kind.name} cache of "
+            f"{kind.width} values a token and is served in bfloat16 on one "
+            "chip; not implemented for it: " + "; ".join(refused)
+        )
 
 
 def _built_facts(engine: JaxEngine) -> dict:
@@ -434,7 +478,7 @@ def hbm_budget_bytes() -> int:
 
 
 def default_num_blocks(
-    config: LlamaConfig,
+    config,
     max_len: int,
     max_batch: int,
     *,
@@ -445,40 +489,35 @@ def default_num_blocks(
     kv_dtype: str = "bf16",
 ) -> int:
     """Blocks for every batch lane at full context plus slack, capped so
-    weights + KV fit the per-device HBM budget."""
+    weights + KV fit the per-device HBM budget. What a block costs comes
+    from the cache the config's layers declare (`models.cache_kind`), what
+    the weights cost from its family's `param_count`."""
     per_seq = (max_len + block_size - 1) // block_size
     want = max_batch * per_seq + 64
-    from dynamo_tpu.models.llama import param_count
-
+    model = forward_for(config)
+    kind = cache_kind(config)
     # int8 quantization applies to dense projections only; MoE expert
     # stacks stay bf16 (see init_params / load_hf_safetensors), so count
     # them at 2 bytes regardless. Experts also divide over ep, not tp,
     # but tp is the conservative divisor available here.
-    dense_params = param_count(
-        dataclasses.replace(config, num_experts=0)
-    )
-    expert_params = param_count(config) - dense_params
+    expert_params = model.expert_param_count(config)
+    dense_params = model.param_count(config) - expert_params
     weight_bytes = (
         dense_params * (1 if quantized else 2) + expert_params * 2
     ) // tp
     # int8-resident KV: 1 byte/value + one f32 scale per (layer, head,
     # block) — the same HBM budget holds ~2x the blocks
     kv_itemsize = 1 if kv_dtype == "int8" else 2
+    heads = max(1, kind.heads // tp)
     scale_bytes = (
-        4 * config.num_layers * (config.num_kv_heads // tp)
+        4 * config.num_layers * heads * kind.planes
         if kv_dtype == "int8"
         else 0
     )
     block_bytes = (
-        2  # k + v
-        * (
-            config.num_layers
-            * block_size
-            * (config.num_kv_heads // tp)
-            * config.head_dim
-            * kv_itemsize
-            + scale_bytes
-        )
+        config.num_layers * block_size
+        * kind.stored_values_per_token(tp) * kv_itemsize
+        + scale_bytes
     )
     budget = int(hbm_budget_bytes() * utilization) - weight_bytes
     cap = max(16, budget // max(1, block_bytes))

@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from dynamo_tpu.models import llama
+from dynamo_tpu.models import cache_kind, forward_for
 from dynamo_tpu.ops.sampling import (
     MAX_EOS_IDS,
     apply_penalties,
@@ -79,7 +79,7 @@ def unrolled_steps(step, init, H: int):
 class ModelRunner:
     def __init__(
         self,
-        config: llama.LlamaConfig,
+        config: Any,  # a family's config (models.forward_for finds its forward)
         params: Any,
         *,
         num_blocks: int,
@@ -124,21 +124,22 @@ class ModelRunner:
         # (128) and block_size sublane-aligned (8).
         from dynamo_tpu.ops.attention import _pallas_tileable
 
+        kind = cache_kind(config)
         if attn_impl == "pallas" and not _pallas_tileable(
-            config.head_dim, block_size
+            kind.stored_width, block_size
         ):
             if on_tpu:
                 # no silent XLA-gather serving on the chip: the caller
                 # passes attn_impl="xla" knowingly or fixes the shape
                 raise ValueError(
                     "pallas attention needs head_dim%128==0 and "
-                    f"block_size%8==0 (got head_dim={config.head_dim}, "
+                    f"block_size%8==0 (got head_dim={kind.stored_width}, "
                     f"block_size={block_size})"
                 )
             logger.warning(
                 "pallas attention needs head_dim%%128==0 and "
                 "block_size%%8==0 (got %d/%d); falling back to xla",
-                config.head_dim, block_size,
+                kind.stored_width, block_size,
             )
             attn_impl = "xla"
         self.attn_impl = attn_impl
@@ -176,15 +177,12 @@ class ModelRunner:
         self.prefill_buckets = sorted(
             prefill_buckets or default_prefill_buckets(block_size, max_model_len)
         )
-        # one array per layer, head-major: each (head, page) is a
+        # one array per layer and plane, head-major: each (head, page) is a
         # contiguous [bs, D] tile (what the pallas kernel streams; TP shards
-        # the leading head axis)
-        layer_shape = (
-            config.num_kv_heads,
-            num_blocks,
-            block_size,
-            config.head_dim,
-        )
+        # the leading head axis). What a layer keeps is the config's
+        # declaration: keys and values by head, or one latent plane
+        self.cache_kind = kind
+        layer_shape = (kind.heads, num_blocks, block_size, kind.stored_width)
         from dynamo_tpu.ops import kv_quant
 
         # DYN_KV_DTYPE=int8: the paged cache itself is int8-resident with
@@ -221,8 +219,14 @@ class ModelRunner:
             # allocate ON device under the sharding (works single- and
             # multi-controller; never materializes host zeros)
             make_zeros = jax.jit(make_zeros, out_shardings=kv_shard_tree)
+        if kind.planes == 1 and (self.kv_quantized or mesh is not None):
+            raise ValueError(
+                f"a {kind.name} cache is served in bfloat16 on one chip: an "
+                "int8-resident cache (DYN_KV_DTYPE=int8) and a mesh are not "
+                "implemented for it"
+            )
         self.k_cache = make_zeros()
-        self.v_cache = make_zeros()
+        self.v_cache = make_zeros() if kind.planes == 2 else ()
         logger.info(
             "kv cache: %d blocks x %d tokens (%s), %.2f GiB",
             num_blocks,
@@ -477,7 +481,7 @@ class ModelRunner:
         params, k_cache, v_cache, tokens, valid_len, block_table,
         key_data, temp, top_p, top_k, rep_pen, eos_ids, eos_suppress,
     ):
-        logits, k_cache, v_cache = llama.prefill(
+        logits, k_cache, v_cache = forward_for(cfg).prefill(
             params, cfg, tokens, valid_len, k_cache, v_cache, block_table,
             mesh=attn_mesh, attn_head_axis=attn_head_axis,
         )
@@ -494,7 +498,7 @@ class ModelRunner:
         mm_embeds, mm_start,
         key_data, temp, top_p, top_k, rep_pen, eos_ids, eos_suppress,
     ):
-        logits, k_cache, v_cache = llama.prefill_mm(
+        logits, k_cache, v_cache = forward_for(cfg).prefill_mm(
             params, cfg, tokens, valid_len, k_cache, v_cache, block_table,
             mm_embeds, mm_start,
             mesh=attn_mesh, attn_head_axis=attn_head_axis,
@@ -513,7 +517,7 @@ class ModelRunner:
     ):
         # per-layer pagination inside the model loop: peak transient is one
         # layer's [P, Hkv, D], never the full [L, P, Hkv, D] stack
-        logits, k_cache, v_cache = llama.prefill_context_parallel(
+        logits, k_cache, v_cache = forward_for(cfg).prefill_context_parallel(
             params, cfg, mesh, tokens, valid_len, head_axis=head_axis,
             k_cache=k_cache, v_cache=v_cache, block_table=block_table,
         )
@@ -529,7 +533,7 @@ class ModelRunner:
         block_table, key_data, temp, top_p, top_k, rep_pen, eos_ids,
         eos_suppress,
     ):
-        logits, k_cache, v_cache = llama.prefill_chunk(
+        logits, k_cache, v_cache = forward_for(cfg).prefill_chunk(
             params, cfg, tokens, chunk_start, valid_len,
             k_cache, v_cache, block_table, mesh=mesh,
         )
@@ -549,7 +553,7 @@ class ModelRunner:
         slot_indices, last_idx, keys, temps, top_ps, top_ks, rep_pens,
         eos_ids, eos_suppress,
     ):
-        logits, k_cache, v_cache = llama.prefill_packed(
+        logits, k_cache, v_cache = forward_for(cfg).prefill_packed(
             params, cfg, tokens, positions, segment_ids, slot_indices,
             k_cache, v_cache, last_idx, mesh=mesh,
         )
@@ -566,7 +570,7 @@ class ModelRunner:
         params, k_cache, v_cache, tokens, positions, block_tables,
         slot_indices, keys, temps, top_ps, top_ks,
     ):
-        logits, k_cache, v_cache = llama.decode(
+        logits, k_cache, v_cache = forward_for(cfg).decode(
             params, cfg, tokens, positions, k_cache, v_cache,
             block_tables, slot_indices,
             mesh=attn_mesh, attn_head_axis=attn_head_axis,
@@ -616,6 +620,8 @@ class ModelRunner:
         B = tokens.shape[0]
         rows = jnp.arange(B)
         eos_valid = eos_ids >= 0
+        model = forward_for(cfg)
+        step_stats = getattr(model, "STEP_STATS", ())
         if pen is not None:
             hist, hist_len, prompt_len, freq, pres, rep = pen
             out_counts, seen = penalty_count_tables(
@@ -633,10 +639,12 @@ class ModelRunner:
                 + positions % block_size
             )
             slot_idx = jnp.where(done, 0, slot_idx)
-            logits, k_cache, v_cache = llama.decode(
+            stats = [] if step_stats else None
+            logits, k_cache, v_cache = model.decode(
                 params, cfg, tokens, positions, k_cache, v_cache,
                 block_tables, slot_idx,
                 mesh=attn_mesh, attn_head_axis=attn_head_axis,
+                **({"stats": stats} if step_stats else {}),
             )
             if pen is not None:
                 logits = apply_penalties_from_tables(
@@ -659,6 +667,15 @@ class ModelRunner:
                 ],
                 axis=-1,
             )  # [B, 2 + 2*num_top]
+            if stats:
+                # what the model's layers counted in this step, summed,
+                # rides the same fetch as one more row behind the lanes
+                # (`step_stats` reads it)
+                counted = sum(stats)  # [len(STEP_STATS)]
+                row = jnp.zeros((1, packed.shape[1]), jnp.float32)
+                packed = jnp.concatenate(
+                    [packed, row.at[0, : counted.shape[0]].set(counted)], axis=0
+                )
             next_tokens = jnp.where(done | is_eos, tokens, tok)
             next_positions = jnp.where(done, positions, positions + 1)
             if pen is not None:
@@ -747,7 +764,7 @@ class ModelRunner:
             + qpos % block_size
         )
         slot = jnp.where(valid, slot, 0)  # frozen lanes hit the null sink
-        logits, k_cache, v_cache = llama.decode_verify(
+        logits, k_cache, v_cache = forward_for(cfg).decode_verify(
             params, cfg, fed, qpos, k_cache, v_cache, block_tables, slot,
             mesh=attn_mesh, attn_head_axis=attn_head_axis,
         )
@@ -839,7 +856,7 @@ class ModelRunner:
                     + qpos_e % block_size
                 )
                 slot_e = jnp.where(alive, slot_e, 0)
-                lg, k_cache, v_cache = llama.decode(
+                lg, k_cache, v_cache = forward_for(cfg).decode(
                     params, cfg, last_tok, qpos_e, k_cache, v_cache,
                     block_tables, slot_e,
                     mesh=attn_mesh, attn_head_axis=attn_head_axis,
@@ -876,7 +893,7 @@ class ModelRunner:
         hist, hist_len, prompt_len, freq_pen, pres_pen, rep_pen,
         eos_ids, eos_suppress,
     ):
-        logits, k_cache, v_cache = llama.decode(
+        logits, k_cache, v_cache = forward_for(cfg).decode(
             params, cfg, tokens, positions, k_cache, v_cache,
             block_tables, slot_indices,
             mesh=attn_mesh, attn_head_axis=attn_head_axis,
@@ -894,7 +911,7 @@ class ModelRunner:
         params, k_cache, v_cache, tokens, positions, block_tables,
         slot_indices, keys, temps, top_ps, top_ks, eos_ids, eos_suppress,
     ):
-        logits, k_cache, v_cache = llama.decode(
+        logits, k_cache, v_cache = forward_for(cfg).decode(
             params, cfg, tokens, positions, k_cache, v_cache,
             block_tables, slot_indices,
             mesh=attn_mesh, attn_head_axis=attn_head_axis,
@@ -1285,7 +1302,7 @@ class ModelRunner:
         if not hasattr(self, "_embed_jit"):
             cfg = self.config
             self._embed_jit = jax.jit(
-                lambda p, t, v: llama.embed_pooled(p, cfg, t, v)
+                lambda p, t, v: forward_for(cfg).embed_pooled(p, cfg, t, v)
             )
         T = len(token_ids)
         bucket = self.pick_bucket(T)
@@ -1374,6 +1391,18 @@ class ModelRunner:
         )
         return out
 
+    def require_block_transfer(self, what: str) -> None:
+        """Blocks leave and enter the cache as `[L, Hkv, n, bs, D]` pairs of
+        keys and values (disagg frames, block-manager tiers, peer pulls):
+        refuse in words for a cache that keeps another kind of plane."""
+        if self.cache_kind.planes != 2:
+            raise ValueError(
+                f"{what} moves cache blocks as keys and values by head; "
+                f"this model keeps a {self.cache_kind.name} plane of "
+                f"{self.cache_kind.width} values a token, which is not "
+                "carried through transfer or tiers yet"
+            )
+
     def _pad_block_count(self, n: int) -> int:
         """Smallest bucket block count >= n (bounds compiled program count).
 
@@ -1390,6 +1419,7 @@ class ModelRunner:
         self, block_ids: list[int]
     ) -> tuple[np.ndarray, np.ndarray]:
         """Gather dense KV blocks [L, Hkv, n, bs, D] for disagg shipping."""
+        self.require_block_transfer("extract_blocks")
         n = len(block_ids)
         padded = self._pad_block_count(n)
         ids = np.zeros(padded, np.int32)
@@ -1410,6 +1440,7 @@ class ModelRunner:
         bucket-sized gather + fetch. Pad to the next power of two instead,
         capped at the bucket pad: compiled-program count stays O(log n),
         frame extracts stay O(frame)."""
+        self.require_block_transfer("extract_blocks_tight")
         n = len(block_ids)
         pow2 = 1
         while pow2 < n:
@@ -1502,6 +1533,7 @@ class ModelRunner:
         decode engines consume these via inject_blocks_device and the
         blocks never leave HBM (the reference's GPUDirect-RDMA role,
         docs/architecture/disagg_serving.md:76-118)."""
+        self.require_block_transfer("extract_blocks_device")
         n = len(block_ids)
         padded = self._pad_block_count(n)
         ids = np.zeros(padded, np.int32)
@@ -1522,6 +1554,7 @@ class ModelRunner:
         runner's devices/sharding first — on a shared TPU slice that is an
         ICI copy, no host round-trip, no serialization. Padding lanes
         target null block 0."""
+        self.require_block_transfer("inject_blocks_device")
         n = len(block_ids)
         padded = self._pad_block_count(n)
         ids = np.zeros(padded, np.int32)
@@ -1565,6 +1598,7 @@ class ModelRunner:
         When the cache is TP-sharded, the scatter's pinned out_sharding makes
         XLA reshard the incoming dense blocks — the block_copy.cu equivalent.
         """
+        self.require_block_transfer("inject_blocks")
         n = len(block_ids)
         padded = self._pad_block_count(n)
         ids = np.zeros(padded, np.int32)
@@ -1738,6 +1772,17 @@ class ModelRunner:
         return out
 
     # ------------------------------------------------- lazy horizon compile
+
+    def step_stats(self, packed: np.ndarray) -> Optional[dict[str, float]]:
+        """What the model counted on the device during one fetched
+        `decode_multi` horizon, summed over its steps and layers (the row
+        behind the lanes); None for a family that counts nothing."""
+        names = getattr(forward_for(self.config), "STEP_STATS", ())
+        if not names:
+            return None
+        # the last row, whatever batch bucket the horizon was compiled for
+        row = packed[:, -1, : len(names)]  # [H, names]
+        return {name: float(row[:, j].sum()) for j, name in enumerate(names)}
 
     def decode_multi_ready(self, H: int) -> bool:
         """True once the horizon program for this H has a compiled

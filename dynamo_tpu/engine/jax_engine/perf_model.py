@@ -69,11 +69,11 @@ def decode_hbm_bytes_per_token(
     int8-resident. Activations: one [B, hidden] write + read per program
     boundary in the layer hot path (UNFUSED/FUSED_LAYER_BOUNDARIES).
     """
-    from dynamo_tpu.models.llama import param_count
+    from dynamo_tpu.models.llama import expert_param_count, param_count
 
     c = config
-    dense_params = param_count(dataclasses.replace(c, num_experts=0))
-    expert_params = param_count(c) - dense_params
+    expert_params = expert_param_count(c)
+    dense_params = param_count(c) - expert_params
     weight_bytes = dense_params * (1 if weights_int8 else 2) + expert_params * 2
     # lm_head/embed are shared in param_count's total already
 

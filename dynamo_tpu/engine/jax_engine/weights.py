@@ -9,14 +9,14 @@ tree, with optional int8 weight-only quantization applied at load.
 from __future__ import annotations
 
 import glob
-import json
 import os
 from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
 
-from dynamo_tpu.models.llama import LlamaConfig, init_params
+from dynamo_tpu.models import forward_for
+from dynamo_tpu.models.llama import LlamaConfig
 from dynamo_tpu.ops.linear import maybe_quantize
 from dynamo_tpu.runtime.logging import get_logger
 
@@ -34,13 +34,115 @@ def load_or_init_params(
     if model_dir:
         files = sorted(glob.glob(os.path.join(model_dir, "*.safetensors")))
         if files:
-            return load_hf_safetensors(
-                model_dir, config, quantize=quantize, dtype=dtype
-            )
+            # a family's checkpoint names, by the module of its config
+            load = LOADERS[forward_for(config).__name__.rsplit(".", 1)[-1]]
+            return load(model_dir, config, quantize=quantize, dtype=dtype)
         logger.warning(
             "%s has no *.safetensors; falling back to random init", model_dir
         )
-    return init_params(config, jax.random.PRNGKey(seed), dtype, quantize)
+    return forward_for(config).init_params(
+        config, jax.random.PRNGKey(seed), dtype, quantize
+    )
+
+
+def _read_safetensors(model_dir: str) -> dict[str, Any]:
+    from safetensors import safe_open
+
+    tensors: dict[str, Any] = {}
+    for path in sorted(glob.glob(os.path.join(model_dir, "*.safetensors"))):
+        with safe_open(path, framework="flax") as f:
+            for name in f.keys():
+                tensors[name] = f.get_tensor(name)
+    return tensors
+
+
+def load_latent_moe_safetensors(
+    model_dir: str,
+    config: Any,  # models.mla_moe.MlaMoeConfig
+    *,
+    quantize: bool = False,
+    dtype: jnp.dtype = jnp.bfloat16,
+) -> Any:
+    """The latent-attention, sparse-expert family's checkpoint names
+    (DeepSeek-V3's): `self_attn.{q_a_proj, q_a_layernorm, q_b_proj,
+    kv_a_proj_with_mqa, kv_a_layernorm, kv_b_proj, o_proj}`, `mlp.gate.weight`
+    (the router), `mlp.gate.e_score_correction_bias`,
+    `mlp.experts.N.{gate,up,down}_proj`, `mlp.shared_experts.*`; a dense
+    layer's `mlp.{gate,up,down}_proj`. Layers behind `num_hidden_layers`
+    (the multi-token-prediction module) are left in the files, in words."""
+    forward_for(config).refuse_int8_weights(quantize)
+    tensors = _read_safetensors(model_dir)
+    c = config
+
+    def get(name: str) -> jax.Array:
+        return jnp.asarray(tensors.pop(name)).astype(dtype)
+
+    def lin(name: str) -> jax.Array:  # HF stores [out, in]; we use [in, out]
+        return get(name).T
+
+    layers = []
+    for i in range(c.num_layers):
+        p = f"model.layers.{i}."
+        a = p + "self_attn."
+        layer = {
+            "attn_norm": get(p + "input_layernorm.weight"),
+            "wq_a": lin(a + "q_a_proj.weight"),
+            "q_norm": get(a + "q_a_layernorm.weight"),
+            "wq_b": lin(a + "q_b_proj.weight"),
+            "wkv_a": lin(a + "kv_a_proj_with_mqa.weight"),
+            "kv_norm": get(a + "kv_a_layernorm.weight"),
+            "wkv_b": lin(a + "kv_b_proj.weight"),
+            "wo": lin(a + "o_proj.weight"),
+            "mlp_norm": get(p + "post_attention_layernorm.weight"),
+        }
+        m = p + "mlp."
+        if c.is_moe_layer(i):
+            experts = range(c.n_routed_experts)
+            layer.update(
+                router=lin(m + "gate.weight"),
+                router_bias=jnp.asarray(
+                    tensors.pop(m + "gate.e_score_correction_bias")
+                ).astype(jnp.float32),
+                wg=jnp.stack([lin(f"{m}experts.{e}.gate_proj.weight") for e in experts]),
+                wu=jnp.stack([lin(f"{m}experts.{e}.up_proj.weight") for e in experts]),
+                wd=jnp.stack([lin(f"{m}experts.{e}.down_proj.weight") for e in experts]),
+            )
+            if c.n_shared_experts:
+                layer.update(
+                    sg=lin(m + "shared_experts.gate_proj.weight"),
+                    su=lin(m + "shared_experts.up_proj.weight"),
+                    sd=lin(m + "shared_experts.down_proj.weight"),
+                )
+        else:
+            layer.update(
+                wg=lin(m + "gate_proj.weight"),
+                wu=lin(m + "up_proj.weight"),
+                wd=lin(m + "down_proj.weight"),
+            )
+        layers.append(layer)
+    params: dict[str, Any] = {
+        "embed": get("model.embed_tokens.weight"),
+        "layers": layers,
+        "final_norm": get("model.norm.weight"),
+    }
+    if not c.tie_word_embeddings and "lm_head.weight" in tensors:
+        params["lm_head"] = lin("lm_head.weight")
+    behind = sorted(
+        n for n in tensors
+        if n.startswith("model.layers.")
+        and int(n.split(".")[2]) >= c.num_layers
+    )
+    if behind:
+        logger.info(
+            "ignored %d tensors of layers behind the %d served (%s ...): the "
+            "multi-token-prediction module takes no part in the next-token "
+            "logits and is not served", len(behind), c.num_layers, behind[0],
+        )
+    logger.info(
+        "loaded latent-attention checkpoint from %s (%d tensors unused)",
+        model_dir, len(tensors),
+    )
+    return params
 
 
 def load_hf_safetensors(
@@ -50,13 +152,7 @@ def load_hf_safetensors(
     quantize: bool = False,
     dtype: jnp.dtype = jnp.bfloat16,
 ) -> Any:
-    from safetensors import safe_open
-
-    tensors: dict[str, Any] = {}
-    for path in sorted(glob.glob(os.path.join(model_dir, "*.safetensors"))):
-        with safe_open(path, framework="flax") as f:
-            for name in f.keys():
-                tensors[name] = f.get_tensor(name)
+    tensors = _read_safetensors(model_dir)
 
     def get(name: str) -> jax.Array:
         t = tensors.pop(name)
@@ -156,3 +252,6 @@ def load_hf_safetensors(
         len(tensors),
     )
     return params
+
+
+LOADERS = {"llama": load_hf_safetensors, "mla_moe": load_latent_moe_safetensors}

@@ -350,6 +350,13 @@ def param_count(config: LlamaConfig) -> int:
     return total
 
 
+def expert_param_count(config: LlamaConfig) -> int:
+    """Parameters in routed expert stacks (kept bf16 whatever the dense
+    projections' precision): 0 for a dense model."""
+    c = config
+    return c.num_layers * c.num_experts * 3 * c.hidden_size * c.intermediate_size
+
+
 # ----------------------------------------------------------------- forward
 
 

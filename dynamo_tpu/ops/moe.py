@@ -26,7 +26,10 @@ way, with dispatch paths chosen by regime:
     over surviving assignments when capacity drops occur.
 
 Routing: softmax over router logits, top-k experts per token, weights
-renormalized over the selected k (Mixtral semantics).
+renormalized over the selected k (Mixtral semantics, `router_topk`); or
+sigmoid scores with a selection-only correction bias (DeepSeek-V3's
+`noaux_tc`, `router_sigmoid_topk`). `dropless_experts` is the routed half
+of `moe_ffn_dropless` for a model that routes itself.
 """
 
 from __future__ import annotations
@@ -65,6 +68,25 @@ def router_topk(
     weights, idx = lax.top_k(logits, top_k)  # [T, k]
     weights = jax.nn.softmax(weights, axis=-1)  # renormalize over chosen k
     return idx, weights
+
+
+def router_sigmoid_topk(
+    logits: jax.Array,  # [T, E] f32 router logits
+    bias: jax.Array,  # [E] f32 score-correction bias: selection only
+    top_k: int,
+    scale: float = 1.0,
+    renormalize: bool = True,
+) -> tuple[jax.Array, jax.Array]:
+    """DeepSeek-V3 `noaux_tc` routing with one group: scores are sigmoids;
+    the k experts with the largest score + bias are chosen; the weights are
+    the chosen experts' *scores* (not score + bias), normalised over the k
+    and multiplied by `scale`. Returns ([T, k] ids, [T, k] f32 weights)."""
+    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    _, idx = lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    weights = jnp.take_along_axis(scores, idx, axis=-1)
+    if renormalize:
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+    return idx, weights * scale
 
 
 def make_dispatch(
@@ -153,6 +175,46 @@ def _sorted_dispatch_combine(
     return y.at[rows].add(ys.astype(jnp.float32) * w_flat[:, None])
 
 
+def dropless_experts(
+    x: jax.Array,  # [T, D]
+    idx: jax.Array,  # [T, k] int32 expert ids
+    weights: jax.Array,  # [T, k] f32
+    wg: jax.Array,  # [E, D, F]
+    wu: jax.Array,
+    wd: jax.Array,  # [E, F, D]
+    valid: Optional[jax.Array] = None,  # [T] bool: False = padding token
+) -> tuple[jax.Array, jax.Array]:
+    """The routed experts of a dropless layer for assignments already made:
+    sort by expert, three grouped products, unsort, weighted sum over k.
+    Returns (y f32 [T, D], group_sizes [E] int32).
+
+    A padding token (a lane that holds no request, the tail of a packed
+    prompt) is given to no expert: its assignments sort behind every real
+    one and no group counts them, so a step reads the weights of the
+    experts its live tokens chose and of no other. Rows behind the last
+    group are not computed by the grouped product and are zeroed here."""
+    T, D = x.shape
+    k = idx.shape[1]
+    E = wg.shape[0]
+    e_flat = idx.reshape(-1).astype(jnp.int32)  # [T*k]
+    if valid is not None:
+        e_flat = jnp.where(jnp.repeat(valid, k), e_flat, E)
+    order = jnp.argsort(e_flat)  # stable: arrival order within expert
+    xs = x[order // k]  # [T*k, D]
+    group_sizes = jnp.sum(
+        e_flat[:, None] == jnp.arange(E, dtype=jnp.int32)[None, :],
+        axis=0, dtype=jnp.int32,
+    )
+    ys = _grouped_ffn(xs, group_sizes, wg, wu, wd)  # [T*k, D]
+    live = jnp.arange(T * k) < jnp.sum(group_sizes)
+    ys = jnp.where(live[:, None], ys.astype(jnp.float32), 0.0)
+    # back to token-major by the inverse permutation: a gather and a sum
+    # over k, where a scatter-add would serialise on duplicate rows
+    inv = jnp.zeros_like(order).at[order].set(jnp.arange(T * k, dtype=order.dtype))
+    y = ys[inv].reshape(T, k, D) * weights.astype(jnp.float32)[:, :, None]
+    return y.sum(axis=1), group_sizes
+
+
 def moe_ffn_dropless(
     x: jax.Array,  # [T, D]
     router_w: jax.Array,  # [D, E]
@@ -168,12 +230,11 @@ def moe_ffn_dropless(
     dropless serving), O(T*k) memory. The engine's default path when
     experts are not ep-sharded.
     """
-    E = router_w.shape[-1]
     logits = jnp.einsum(
         "td,de->te", x.astype(jnp.float32), router_w.astype(jnp.float32)
     )
     idx, weights = router_topk(logits, top_k)  # [T, k]
-    y = _sorted_dispatch_combine(x, idx, weights, E, wg, wu, wd)
+    y, _ = dropless_experts(x, idx, weights, wg, wu, wd)
     return y.astype(x.dtype)
 
 

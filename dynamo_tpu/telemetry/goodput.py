@@ -79,6 +79,14 @@ def enabled_from_env() -> bool:
     return os.environ.get("DYN_GOODPUT", "1") not in ("0", "false", "off")
 
 
+# expert-layer counters of a sparse-expert model, computed on the device in
+# `decode_multi` and fetched with the tokens (`models.mla_moe.STEP_STATS`):
+# sums over (expert layer, step) pairs, whose number is `layer_steps`
+MOE_COUNTERS = (
+    "layer_steps", "assignments", "experts_touched", "max_expert_load",
+)
+
+
 class GoodputStats:
     """Mergeable goodput snapshot (the wire/aggregate half).
 
@@ -107,6 +115,7 @@ class GoodputStats:
         "mfu_sum",
         "hbm_sum",
         "gauge_n",
+        "moe",
     )
 
     def __init__(self) -> None:
@@ -147,6 +156,10 @@ class GoodputStats:
         self.mfu_sum = 0.0
         self.hbm_sum = 0.0
         self.gauge_n = 0
+        # what a sparse-expert model counted on the device, summed over
+        # the decode horizons fetched so far (MOE_COUNTERS; empty for a
+        # model without experts)
+        self.moe: dict[str, float] = {}
 
     # ------------------------------------------------------------- query
 
@@ -217,6 +230,8 @@ class GoodputStats:
         self.mfu_sum += other.mfu_sum
         self.hbm_sum += other.hbm_sum
         self.gauge_n += other.gauge_n
+        for k, v in other.moe.items():
+            self.moe[k] = self.moe.get(k, 0.0) + v
 
     def copy(self) -> "GoodputStats":
         out = GoodputStats()
@@ -245,6 +260,7 @@ class GoodputStats:
             "mfu": self.mfu_sum,
             "hbm": self.hbm_sum,
             "n": self.gauge_n,
+            "moe": dict(self.moe),
         }
 
     @classmethod
@@ -274,6 +290,9 @@ class GoodputStats:
         out.mfu_sum = float(d.get("mfu") or 0.0)
         out.hbm_sum = float(d.get("hbm") or 0.0)
         out.gauge_n = int(d.get("n") or 0)
+        for k, v in (d.get("moe") or {}).items():
+            if k in MOE_COUNTERS:
+                out.moe[k] = float(v)
         return out
 
     # ------------------------------------------------------------- debug
@@ -311,6 +330,7 @@ class GoodputStats:
             },
             "mfu_achieved": round(self.mfu_achieved, 5),
             "hbm_bytes_per_token": round(self.hbm_bytes_per_token, 1),
+            "moe": {k: self.moe.get(k, 0.0) for k in MOE_COUNTERS},
         }
 
 
@@ -394,6 +414,14 @@ class GoodputLedger(GoodputStats):
                     self.phase_gap_s_total += gap
             self._last_end = t_start + elapsed_s
             self._last_phase = phase
+
+    def record_moe(self, counted: dict[str, float]) -> None:
+        """One fetched decode horizon's expert counters (the runner's
+        `step_stats`), summed."""
+        if not self.enabled:
+            return
+        for k in MOE_COUNTERS:
+            self.moe[k] = self.moe.get(k, 0.0) + float(counted.get(k, 0.0))
 
     def record_decode_tokens(self, n: int = 1) -> None:
         if self.enabled:

@@ -100,6 +100,12 @@ INTENTIONALLY_SHARED = {
     "dyn_llm_compile_seconds",
     "dyn_llm_mfu_achieved",
     "dyn_llm_hbm_bytes_per_token_achieved",
+    # expert-layer counters of a sparse-expert model (ISSUE 28) ride the
+    # same shared goodput surface
+    "dyn_llm_moe_layer_steps",
+    "dyn_llm_moe_assignments",
+    "dyn_llm_moe_experts_touched",
+    "dyn_llm_moe_max_expert_load",
     # decision provenance plane (ISSUE 20): every control-plane process
     # (frontend, metrics component, standalone router) exports its OWN
     # ledger's decision counts — decisions are made where they are

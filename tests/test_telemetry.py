@@ -527,8 +527,12 @@ def test_disabled_overhead_guard():
     from benchmarks.trace_overhead_bench import measure_noop_ns
 
     assert not dtrace.enabled()
-    ns = measure_noop_ns(iters=50_000)
-    for name, per_op in ns.items():
+    # the least of five repetitions: the suite runs beside five other
+    # workers, and one descheduled loop of 50,000 calls is the machine's
+    # time, not the call's (ISSUE 28: it failed once in the driver's run)
+    runs = [measure_noop_ns(iters=50_000) for _ in range(5)]
+    for name in runs[0]:
+        per_op = min(r[name] for r in runs)
         assert per_op < 2000, f"disabled {name}() costs {per_op} ns/op"
 
 

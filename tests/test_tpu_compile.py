@@ -556,3 +556,82 @@ def test_decode_program_tp4_moves_no_pool(tp4):
         if op.startswith("all-gather") and elements >= per_device_pool
     ]
     assert len(_aliased_parameters(text)) == 2 * 2
+
+
+# ------------------------------------- the latent-attention, expert family
+#
+# JoyAI-LLM-Flash's widths as `cellbench/configs/joyai-flash-bf16-l5.json`
+# serves them: 32 heads over one 576-wide latent row a token stored 640 wide
+# (a DMA tile is 128 lanes: at 576 Mosaic refuses the page slice), 256
+# experts of width 768, 8 a token, five layers, the cell's pool.
+
+
+def _latent_step_setup(one_chip, num_blocks: int = 31805):
+    import json
+
+    from dynamo_tpu.models import mla_moe
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "cellbench", "configs", "joyai-flash-bf16-l5.json")) as f:
+        conf = json.load(f)
+    cfg = mla_moe.MlaMoeConfig.from_hf_dict(
+        {k: v for k, v in conf.items() if k != "bench"}
+    )
+    cfg = dataclasses.replace(cfg, attn_impl="pallas")
+    params = jax.tree_util.tree_map(
+        lambda a: one_chip(a.shape, a.dtype),
+        jax.eval_shape(lambda: mla_moe.init_params(cfg, jax.random.PRNGKey(0))),
+    )
+    width = cfg.cache_kind().stored_width
+    planes = (one_chip((1, num_blocks, BLOCK, width), BF16),) * cfg.num_layers
+    return cfg, params, planes
+
+
+def test_latent_decode_kernel(one_chip):
+    from dynamo_tpu.ops.pallas_mla import mla_paged_decode_pallas
+
+    text = compile_text(
+        functools.partial(mla_paged_decode_pallas, value_width=512, scale=0.07),
+        one_chip((B, 32, 640), BF16),
+        one_chip((1024, BLOCK, 640), BF16),
+        one_chip((B, 8192 // BLOCK), I32),
+        one_chip((B,), I32),
+    )
+    assert re.search(r" = bf16\[64,32,512\]\S* custom-call\(", text)
+
+
+def test_latent_expert_decode_multi_program_one_chip(one_chip):
+    """`decode_multi@H4B64` at the published widths, five layers, the cell's
+    pool: it compiles, the planes are updated in place (no pool-sized copy),
+    the grouped products are XLA's own kernel with the expert stacks as they
+    lie (no relayout of 805 MB stacks), and weights, planes and temporaries
+    fit the chip."""
+    from dynamo_tpu.engine.jax_engine.model_runner import ModelRunner
+    from dynamo_tpu.ops.sampling import MAX_EOS_IDS
+
+    cfg, params, planes = _latent_step_setup(one_chip)
+    fn = jax.jit(
+        functools.partial(ModelRunner._decode_multi_impl, cfg, None, None, BLOCK),
+        static_argnums=(0,), donate_argnums=(2, 3),
+    )
+    vec = lambda dtype: one_chip((B,), dtype)
+    compiled = fn.lower(
+        4, params, planes, (), vec(I32), vec(I32),
+        one_chip((B, 8192 // BLOCK), I32), one_chip((B, 2), jnp.uint32),
+        vec(F32), vec(F32), vec(I32), vec(jnp.bool_), vec(I32), vec(I32),
+        one_chip((B, MAX_EOS_IDS), I32),
+    ).compile()
+    text = compiled.as_text()
+    steps, expert_layers = 4, 4
+    assert len(re.findall(r" = bf16\[64,32,512\]\S* custom-call\(", text)) == steps * 5
+    assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) == steps * expert_layers * 3
+    pool = 31805 * BLOCK * 640
+    assert _pool_sized_movers(text, pool) == []
+    stack = 256 * 2048 * 768
+    assert [
+        line for _, op, elements, line in _entry_instructions(text)
+        if elements >= stack and op in ("copy", "transpose", "fusion")
+    ] == []
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1 * 2**30
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16_909_336_064
