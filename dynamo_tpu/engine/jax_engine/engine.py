@@ -1471,10 +1471,15 @@ class JaxEngine:
                 and not s.pending_remote
                 and not s.prefilling
             ]
-            if active and self._can_mix(active):
+            if self._can_mix(active):
                 # unified mixed step: every decode lane AND up to
                 # _step_chunk_budget prefill tokens in ONE device
-                # program — the alternating-phase bubble disappears
+                # program — the alternating-phase bubble disappears.
+                # Also with no lane decoding (an idle lane costs the step
+                # its rows in the matmuls and nothing in attention): a long
+                # prompt then runs the programs it runs on a busy server,
+                # so which programs a server has compiled does not depend
+                # on whether its first long prompt met a decoding lane
                 await self._mixed_step_phase(loop, active)
                 await self._stats_and_yield(admitted)
                 return False
@@ -1958,7 +1963,8 @@ class JaxEngine:
                     plan.append((seq, advanced))
             if not chunks:
                 # every in-flight prefill vanished under us; plain decode
-                await self._decode_single_phase(loop, active)
+                if active:
+                    await self._decode_single_phase(loop, active)
                 return
             # -- fill the decode lanes (single-step semantics; the eos-mask
             # variant always runs — neutral rows are a bitwise no-op) --------

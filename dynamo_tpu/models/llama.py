@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from dynamo_tpu.ops.attention import (
     causal_prefill_attention,
     chunked_prefill_attention,
+    live_decode_lanes,
     packed_prefill_attention,
     paged_decode_attention,
     paged_verify_attention,
@@ -504,8 +505,10 @@ def _attn_decode(x, layer, cfg, inv_freqs, positions, k_cache_l, v_cache_l, bloc
     else:
         q, k, v = _qkv(x, layer, cfg, inv_freqs, positions)
     k_cache_l, v_cache_l = write_decode_kv(k_cache_l, v_cache_l, k, v, slot_indices)
+    live = live_decode_lanes(k_cache_l, slot_indices)
     attn = paged_decode_attention(
-        q, k_cache_l, v_cache_l, block_tables, positions + 1,
+        q, k_cache_l, v_cache_l, block_tables,
+        jnp.where(live, positions + 1, 0),
         impl=cfg.attn_impl, mesh=mesh, head_axis=head_axis,
         window=cfg.layer_window(li), scale=cfg.attn_scale,
         logit_softcap=cfg.attn_logit_softcap,

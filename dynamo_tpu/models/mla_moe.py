@@ -43,6 +43,7 @@ from jax import lax
 
 from dynamo_tpu.models import CacheKind, latent_cache
 from dynamo_tpu.ops import mla
+from dynamo_tpu.ops.attention import live_decode_lanes
 from dynamo_tpu.ops.basics import rms_norm, rope_freqs, swiglu
 from dynamo_tpu.ops.kv_quant import scatter_token_rows
 from dynamo_tpu.ops.linear import linear
@@ -497,9 +498,8 @@ def decode(
     """One decode step for a batch, absorbed form. A lane whose row goes to
     the null block holds no request: it reads no cache page and is given to
     no expert. Returns (logits [B, V], planes, ())."""
-    bs = k_cache[0].shape[2]
     inv = _inv_freqs(cfg)
-    live = slot_indices >= bs
+    live = live_decode_lanes(k_cache[0], slot_indices)
     context = jnp.where(live, positions + 1, 0)
     x = params["embed"][tokens]
     planes = []

@@ -215,12 +215,28 @@ def packed_prefill_attention(
     return out.reshape(P, Hq, D).astype(q.dtype)
 
 
+def live_decode_lanes(
+    cache_layer,  # one layer's pages [*, num_blocks, block_size, *] or {"q","s"}
+    slot_indices: jax.Array,  # [B] int32 flat slot each lane's token goes to
+) -> jax.Array:
+    """[B] bool: which decode lanes hold a request. The engine keeps a lane it
+    never admitted at position 0 with a table of zeros, and a step program
+    points a finished lane's write at slot 0: a write slot inside the null
+    block (block 0, which no sequence owns) is the one mark of both, for
+    every model family. Such a lane is handed to attention with a context of
+    0 (`jnp.where(live, positions + 1, 0)`): it reads no page and gets zeros,
+    in the kernels and in the XLA forms alike."""
+    pages = cache_layer["q"] if _cache_quantized(cache_layer) else cache_layer
+    return slot_indices >= pages.shape[2]
+
+
 def paged_decode_attention(
     q: jax.Array,  # [B, Hq, D] — one new token per sequence
     k_cache: jax.Array,  # [Hkv, num_blocks, block_size, D] (this layer)
     v_cache: jax.Array,  # [Hkv, num_blocks, block_size, D]
     block_tables: jax.Array,  # [B, max_blocks] int32 block ids
-    context_lens: jax.Array,  # [B] int32 — INCLUDING the token just written
+    context_lens: jax.Array,  # [B] int32 — INCLUDING the token just written;
+    # 0 = the lane holds no request: it reads no page and its rows are zero
     impl: Optional[str] = None,
     mesh: Optional[jax.sharding.Mesh] = None,
     head_axis: Optional[str] = None,
@@ -235,10 +251,10 @@ def paged_decode_attention(
     and the layout whose leading axis TP shards cleanly.
 
     With `mesh` + `head_axis`, the pallas kernel runs under shard_map over
-    the head-sharded cache: each tp shard's grid is (B, Hkv/tp) and it DMAs
-    only its own heads' pages — the production path for the sharded engine
-    (round-1 VERDICT flagged the XLA-gather fallback here as the top perf
-    weakness). Batch/tables/lens are replicated across tp; the wo psum that
+    the head-sharded cache: each tp shard walks its lanes with its own
+    Hkv/tp heads and DMAs only their strips of a page — the production path
+    for the sharded engine (round-1 VERDICT flagged the XLA-gather fallback
+    here as the top perf weakness). Batch/tables/lens are replicated across tp; the wo psum that
     follows is GSPMD-inserted outside this op.
 
     Int8-resident caches ({"q", "s"} containers, ops/kv_quant.py): the
@@ -332,6 +348,8 @@ def paged_decode_attention(
     scores = jnp.where(mask[:, None, None, :], scores, NEG_INF)
     weights = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bhgs,hbsd->bhgd", weights, v.astype(jnp.float32))
+    # an idle lane (context 0) has no key: give it zeros, as the kernel does
+    out = jnp.where((context_lens > 0)[:, None, None, None], out, 0.0)
     return out.reshape(B, Hq, D).astype(q.dtype)
 
 

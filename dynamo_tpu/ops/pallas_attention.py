@@ -6,15 +6,25 @@ blocks into a dense [B, S, Hkv, D] window each step — O(B*S) HBM traffic
 even for short sequences, plus a materialized gather. This kernel instead
 streams exactly the blocks named by each sequence's block table:
 
-  grid = (B, Hkv); the cache stays in HBM (memory_space=ANY). Each grid
-  step runs a dynamic-length fori_loop over chunks of W pages, manually
-  DMA-gathering the pages named by the scalar-prefetched block table into
-  double-buffered VMEM scratch (chunk c+1's copies are in flight while
-  chunk c computes), folding each [W*bs, D] chunk into an online-softmax
-  (flash) accumulator. The loop bound is ceil(ctx_len / W*bs), so a short
-  sequence costs neither FLOPs nor HBM bandwidth for its unused pages —
-  the cache layout is head-major [Hkv, pages, bs, D] precisely so each
-  (head, page) is one contiguous DMA-able tile.
+  decode: grid = (B,), one cell a lane; the cache stays in HBM
+  (memory_space=ANY). A cell runs a dynamic-length fori_loop over chunks
+  of W pages, manually DMA-gathering the pages named by the
+  scalar-prefetched block table into double-buffered VMEM scratch (chunk
+  c+1's copies are in flight while chunk c computes) and folding each
+  chunk into an online-softmax (flash) accumulator. A page is fetched for
+  every KV head at once: the cache is head-major [Hkv, pages, bs, D]
+  (block manager, transfer, tiers and tp sharding rest on that layout), so
+  the copy is `k_hbm.at[:, page]`, Hkv strips of one [bs, D] tile under one
+  descriptor, into [Hkv, W*bs, D] scratch, and scores and values are
+  products batched over the head axis. The loop bound is
+  ceil(ctx_len / W*bs), so a short sequence costs neither FLOPs nor HBM
+  bandwidth for its unused pages, and a lane whose context is 0 (no request
+  in it: `ops.attention.live_decode_lanes`) costs a scalar test and a row
+  of zeros (PERF.md section 6, PR 29: what a grid of lanes x KV heads and
+  idle lanes at a context of 1 cost).
+
+  verify: the same walk with grid = (B, Hkv), one cell a lane and KV head,
+  each (head, page) one contiguous tile.
 
 All three programs (decode, prefill, verify) carry the full attention
 feature set of the model zoo, applied INSIDE the online softmax:
@@ -29,7 +39,10 @@ feature set of the model zoo, applied INSIDE the online softmax:
     bit-for-bit in f32.
 
 GQA: q for one kv head is the [G, D] group slice; scores are a [G, W*bs]
-matmul per chunk.
+matmul per chunk and head. The decode kernel sends keys to the MXU as the
+bfloat16 they are stored as (every product of two bfloat16 numbers is exact
+in the float32 accumulator); probabilities stay float32 into the value
+product.
 
 Int8-resident caches (DYN_KV_DTYPE=int8, ops/kv_quant.py): the decode and
 verify kernels take optional per-(head, page) scale planes as extra
@@ -58,6 +71,13 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
+# W: pages a decode lane fetches and folds at a time. Read on the chip at
+# Mistral-7B's and Qwen2.5-7B's head counts (PERF.md section 6, PR 29): 16
+# is a tenth to a quarter faster at 456-token lanes, 3% slower at 548 and a
+# tenth slower on int8 pages, and pads a short lane further.
+DECODE_PAGES_PER_CHUNK = 8
+
+
 def _page_scale_row(vals: list, block_size: int) -> jax.Array:
     """[1, W*block_size] f32 row holding vals[i] over page i's columns.
     Built from a lane iota and selects: Mosaic cannot lower the
@@ -83,7 +103,7 @@ def decode_kv_chunks_read(
     ctx_len: int,
     *,
     block_size: int,
-    pages_per_chunk: int = 8,
+    pages_per_chunk: int = DECODE_PAGES_PER_CHUNK,
     window: Optional[int] = None,
 ) -> int:
     """Number of KV chunks the decode kernel DMAs for one sequence — the
@@ -99,24 +119,24 @@ def decode_kv_chunks_read(
 def _decode_kernel(
     # scalar prefetch
     block_tables_ref,  # [B, max_blocks] int32 (SMEM)
-    context_lens_ref,  # [B] int32 (SMEM)
+    context_lens_ref,  # [B] int32 (SMEM); 0 = no request in the lane
     # int8-resident mode only (quantized=True): two extra scalar-prefetch
     # scale planes [Hkv, num_blocks] f32 ride SMEM, then the same refs
     *refs,
     # inputs (in *refs):
-    # q_ref   [1, 1, G, D] VMEM — this (seq, kv head)'s query group
+    # q_ref   [1, Hkv, G, D] VMEM — this lane's queries, every head
     # k_hbm   [Hkv, num_blocks, block_size, D] — full cache, stays in HBM
     #         (int8 mantissas in quantized mode — bf16 pages never touch
     #         HBM; dequant happens on the VMEM tile inside this loop)
     # v_hbm
-    # o_ref   [1, 1, G, D] blocked output
+    # o_ref   [1, Hkv, G, D] blocked output
     # scratch (in *refs):
-    # k_buf   [2, W*block_size, D] VMEM — double-buffered gathered pages
+    # k_buf   [2, Hkv, W*block_size, D] VMEM — double-buffered pages
     # v_buf
     # sems    DMA semaphores [2 slots, 2 (k/v), W pages]
-    # m_ref   [G, 128] f32 — running max (replicated over lanes)
-    # l_ref   [G, 128] f32 — running sum
-    # acc_ref [G, D] f32 — running weighted values
+    # m_ref   [Hkv, G, 128] f32 — running max (replicated over lanes)
+    # l_ref   [Hkv, G, 128] f32 — running sum
+    # acc_ref [Hkv, G, D] f32 — running weighted values
     block_size: int,
     pages_per_chunk: int,
     scale: float,
@@ -131,8 +151,8 @@ def _decode_kernel(
         ks_ref = vs_ref = None
     (q_ref, k_hbm, v_hbm, o_ref,
      k_buf, v_buf, sems, m_ref, l_ref, acc_ref) = refs
+    Hkv = q_ref.shape[1]
     b = pl.program_id(0)
-    h = pl.program_id(1)
     ctx_len = context_lens_ref[b]
     W = pages_per_chunk
     chunk_tokens = W * block_size
@@ -147,18 +167,22 @@ def _decode_kernel(
     else:
         kv_start = jnp.maximum(ctx_len - window, 0)
         c_start = lax.div(kv_start, chunk_tokens)
+    # keys go to the MXU as stored (int8 mantissas widen exactly): a
+    # product of two bfloat16 numbers is exact in the float32 accumulator
+    key_dtype = q_ref.dtype if quantized else jnp.promote_types(
+        q_ref.dtype, k_buf.dtype
+    )
 
-    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-    l_ref[...] = jnp.zeros_like(l_ref)
-    acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    def dma(c, slot, i, buf, hbm, kv):
+    def page_of(c, i):
         # page i of chunk c; pages past the end clamp to the last valid page
         # (fetched redundantly, masked in compute)
-        page = block_tables_ref[b, jnp.minimum(c * W + i, last_page)]
+        return block_tables_ref[b, jnp.minimum(c * W + i, last_page)]
+
+    def dma(c, slot, i, buf, hbm, kv):
+        # every KV head's strip of the page in one descriptor
         return pltpu.make_async_copy(
-            hbm.at[h, page],
-            buf.at[slot, pl.ds(i * block_size, block_size), :],
+            hbm.at[:, page_of(c, i)],
+            buf.at[slot, :, pl.ds(i * block_size, block_size), :],
             sems.at[slot, kv, i],
         )
 
@@ -167,8 +191,15 @@ def _decode_kernel(
             dma(c, slot, i, k_buf, k_hbm, 0).start()
             dma(c, slot, i, v_buf, v_hbm, 1).start()
 
-    @pl.when(n_chunks > c_start)
-    def _go():
+    @pl.when(ctx_len <= 0)
+    def _idle():  # no request in the lane: no page is read, the rows are zero
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(ctx_len > 0)
+    def _live():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
         issue(c_start, c_start % 2)
 
         def loop_body(c, _):
@@ -182,48 +213,49 @@ def _decode_kernel(
                 dma(c, slot, i, k_buf, k_hbm, 0).wait()
                 dma(c, slot, i, v_buf, v_hbm, 1).wait()
 
-            q = q_ref[0, 0].astype(jnp.float32)  # [G, D]
-            k = k_buf[slot].astype(jnp.float32)  # [W*bs, D]
+            q = q_ref[0].astype(key_dtype)  # [Hkv, G, D]
+            k = k_buf[slot].astype(key_dtype)  # [Hkv, W*bs, D]
             v = v_buf[slot].astype(jnp.float32)
-            if quantized:
-                # in-kernel dequant: one SMEM scale per fetched page. A
-                # page's scale is constant over its rows, so it factors
-                # out of both dots: q.(k*s) = (q.k)*s and p.(v*s) = (p*s).v
-                # — applied to the [G, W*bs] scores/probabilities, not
-                # the [W*bs, D] tiles
-                kvals = []
-                vvals = []
-                for i in range(W):
-                    page = block_tables_ref[
-                        b, jnp.minimum(c * W + i, last_page)
-                    ]
-                    kvals.append(ks_ref[h, page])
-                    vvals.append(vs_ref[h, page])
-                kcol = _page_scale_row(kvals, block_size)
-                vcol = _page_scale_row(vvals, block_size)
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
+            s = lax.dot_general(
+                q, k, (((2,), (2,)), ((0,), (0,))),
                 preferred_element_type=jnp.float32,
-            ) * scale  # [G, W*bs]
+            ) * scale  # [Hkv, G, W*bs]
             if quantized:
+                # in-kernel dequant: one SMEM scale per fetched (head,
+                # page). A page's scale is constant over its rows, so it
+                # factors out of both dots: q.(k*s) = (q.k)*s and
+                # p.(v*s) = (p*s).v — applied to the [G, W*bs]
+                # scores/probabilities, not the [W*bs, D] tiles
+                pages = [page_of(c, i) for i in range(W)]
+
+                def page_scales(ref):  # [Hkv, 1, W*bs]
+                    return jnp.stack([
+                        _page_scale_row(
+                            [ref[h, page] for page in pages], block_size
+                        )
+                        for h in range(Hkv)
+                    ])
+
+                kcol, vcol = page_scales(ks_ref), page_scales(vs_ref)
                 s = s * kcol
             s = _apply_softcap(s, softcap)
-            pos = c * chunk_tokens + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, dimension=1
+            pos = c * chunk_tokens + lax.broadcasted_iota(
+                jnp.int32, s.shape, dimension=2
             )
             valid = pos < ctx_len
             if window is not None:
                 valid &= pos >= kv_start
             s = jnp.where(valid, s, NEG_INF)
 
-            m_prev = m_ref[:, :1]  # [G, 1]
-            l_prev = l_ref[:, :1]
+            m_prev = m_ref[:, :, :1]  # [Hkv, G, 1]
+            l_prev = l_ref[:, :, :1]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
             alpha = jnp.exp(m_prev - m_new)
             p = jnp.exp(s - m_new)
             l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-                p * vcol if quantized else p, v, (((1,), (0,)), ((), ())),
+            acc_ref[...] = acc_ref[...] * alpha + lax.dot_general(
+                p * vcol if quantized else p, v,
+                (((2,), (1,)), ((0,), (0,))),
                 preferred_element_type=jnp.float32,
             )
             m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
@@ -231,10 +263,7 @@ def _decode_kernel(
             return 0
 
         lax.fori_loop(c_start, n_chunks, loop_body, 0)
-
-    l = l_ref[:, :1]
-    safe_l = jnp.where(l == 0.0, 1.0, l)
-    o_ref[0, 0] = (acc_ref[...] / safe_l).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l_ref[:, :, :1]).astype(o_ref.dtype)
 
 
 def paged_decode_attention_pallas(
@@ -242,11 +271,12 @@ def paged_decode_attention_pallas(
     k_cache: jax.Array,  # [Hkv, num_blocks, block_size, D] (head-major)
     v_cache: jax.Array,
     block_tables: jax.Array,  # [B, max_blocks] int32
-    context_lens: jax.Array,  # [B] int32, INCLUDING the token just written
+    context_lens: jax.Array,  # [B] int32, INCLUDING the token just written;
+    # 0 = the lane holds no request and gets zeros
     *,
     k_scales: Optional[jax.Array] = None,  # [Hkv, num_blocks] f32 — int8
     v_scales: Optional[jax.Array] = None,  # resident cache when given
-    pages_per_chunk: int = 8,
+    pages_per_chunk: int = DECODE_PAGES_PER_CHUNK,
     window: Optional[int] = None,
     scale: Optional[float] = None,
     logit_softcap: Optional[float] = None,
@@ -267,29 +297,25 @@ def paged_decode_attention_pallas(
     W = max(1, min(pages_per_chunk, max_blocks))
     sc = float(scale) if scale is not None else 1.0 / float(D) ** 0.5
 
-    # index maps receive (b, h, *prefetch_refs); units are block-sized
-    def q_index(b, h, *prefetch):
-        return (b, h, 0, 0)
-
-    def o_index(b, h, *prefetch):
-        return (b, h, 0, 0)
+    def lane(b, *prefetch):  # units are blocks: one lane, all of its heads
+        return (b, 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4 if quantized else 2,
-        grid=(B, Hkv),
+        grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, 1, G, D), q_index),
+            pl.BlockSpec((1, Hkv, G, D), lane),
             pl.BlockSpec(memory_space=pl.ANY),  # K cache stays in HBM
             pl.BlockSpec(memory_space=pl.ANY),  # V cache stays in HBM
         ],
-        out_specs=pl.BlockSpec((1, 1, G, D), o_index),
+        out_specs=pl.BlockSpec((1, Hkv, G, D), lane),
         scratch_shapes=[
-            pltpu.VMEM((2, W * block_size, D), k_cache.dtype),
-            pltpu.VMEM((2, W * block_size, D), v_cache.dtype),
+            pltpu.VMEM((2, Hkv, W * block_size, D), k_cache.dtype),
+            pltpu.VMEM((2, Hkv, W * block_size, D), v_cache.dtype),
             pltpu.SemaphoreType.DMA((2, 2, W)),
-            pltpu.VMEM((G, 128), jnp.float32),
-            pltpu.VMEM((G, 128), jnp.float32),
-            pltpu.VMEM((G, D), jnp.float32),
+            pltpu.VMEM((Hkv, G, 128), jnp.float32),
+            pltpu.VMEM((Hkv, G, 128), jnp.float32),
+            pltpu.VMEM((Hkv, G, D), jnp.float32),
         ],
     )
     kernel = pl.pallas_call(
@@ -305,7 +331,7 @@ def paged_decode_attention_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            dimension_semantics=("parallel",),
         ),
         interpret=interpret,
     )

@@ -23,8 +23,8 @@ per-head key or value is ever made from the cache.
 
 The structure is `pallas_attention._decode_kernel`'s (which see for the DMA
 discipline) with one plane, no grouping and no window; it is a kernel of its
-own because its operands are: that kernel's grid is (lanes, KV heads) over
-[group, 128] tiles, this one's rows are 576 wide and shared by all heads.
+own because its operands are: that kernel's pages are Hkv strips of [bs, 128]
+under [group, 128] query tiles, this one's rows are 576 wide, shared by heads.
 """
 
 from __future__ import annotations

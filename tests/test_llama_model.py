@@ -1,6 +1,9 @@
 """Model math correctness: prefill/decode consistency over the paged cache,
 int8 quantization sanity, sampling ops."""
 
+import dataclasses
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -240,3 +243,105 @@ def test_mistral_sliding_window_serves_full_context():
          "sliding_window": 4096, "use_sliding_window": False}
     )
     assert cfg3.sliding_window is None
+
+
+# --------------------------------------------------- idle lanes (PR 29)
+
+
+@pytest.mark.parametrize("attn_impl", ["xla", "pallas_interpret"])
+def test_idle_lanes_change_nothing_for_the_live(tiny_setup, attn_impl):
+    """`decode_multi@H4` over [never admitted, A, B, never admitted], where
+    B's limit ends it two steps into the horizon (its later writes go to
+    slot 0, like an idle lane's): A and B read what they read as a batch of
+    two, and A what it reads alone."""
+    from dynamo_tpu.engine.jax_engine.model_runner import ModelRunner
+    from dynamo_tpu.ops.sampling import MAX_EOS_IDS
+
+    cfg, params = tiny_setup
+    cfg = dataclasses.replace(cfg, attn_impl=attn_impl)
+    kc, vc = _empty_cache(cfg)
+    t_a = jax.random.randint(jax.random.PRNGKey(2), (7,), 0, 64)
+    t_b = jax.random.randint(jax.random.PRNGKey(3), (5,), 0, 64)
+    pad = lambda a: jnp.concatenate([a, jnp.zeros(8 - a.shape[0], a.dtype)])
+    tab_a, tab_b = jnp.array([1, 2, 5], jnp.int32), jnp.array([3, 4], jnp.int32)
+    _, kc, vc = L.prefill(params, cfg, pad(t_a), jnp.int32(7), kc, vc, tab_a[:2])
+    _, kc, vc = L.prefill(params, cfg, pad(t_b), jnp.int32(5), kc, vc, tab_b)
+    tables = np.zeros((4, 8), np.int32)  # a never-admitted lane: zeros
+    tables[1, :3], tables[2, :2] = tab_a, tab_b
+    tokens = np.array([0, 11, 22, 0], np.int32)
+    positions = np.array([0, 7, 5, 0], np.int32)  # ... at position 0
+    limit = np.array([0, 100, 2, 0], np.int32)
+    active = np.array([False, True, True, False])
+    H = 4
+
+    def run(lanes):
+        n = len(lanes)
+        packed, _, _ = jax.jit(
+            functools.partial(ModelRunner._decode_multi_impl, cfg, None, None, 4),
+            static_argnums=(0,),
+        )(
+            H, params, kc, vc, jnp.asarray(tokens[lanes]),
+            jnp.asarray(positions[lanes]), jnp.asarray(tables[lanes]),
+            jnp.zeros((n, 2), jnp.uint32), jnp.zeros(n), jnp.ones(n),
+            jnp.zeros(n, jnp.int32), jnp.asarray(active[lanes]),
+            jnp.asarray(limit[lanes]), jnp.zeros(n, jnp.int32),
+            jnp.full((n, MAX_EOS_IDS), -1, jnp.int32),
+        )
+        return np.asarray(packed)  # [H, n, 2 + 2K]
+
+    full, pair, alone = run([0, 1, 2, 3]), run([1, 2]), run([1])
+    assert (full[:, [0, 3], 0] == -1).all()  # idle lanes emit nothing
+    assert (full[:2, 2, 0] >= 0).all() and (full[2:, 2, 0] == -1).all()
+    K = (full.shape[-1] - 2) // 2
+    ids = lambda rows: rows[..., [0] + list(range(2, 2 + K))]
+    lps = lambda rows: rows[..., [1] + list(range(2 + K, 2 + 2 * K))]
+    for got, want in [
+        (full[:, 1], pair[:, 0]), (full[:, 1], alone[:, 0]),
+        (full[:2, 2], pair[:2, 1]),
+    ]:
+        assert np.array_equal(ids(got), ids(want))
+        np.testing.assert_allclose(lps(got), lps(want), atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("family", ["llama", "mla_moe"])
+def test_a_slot_in_the_null_block_is_a_context_of_zero(monkeypatch, family, tiny_setup):
+    """One rule for both families: attention is handed a context of 0 for
+    exactly the lanes whose write slot lies in the null block, whatever
+    their position says."""
+    bs = 4
+    positions = jnp.array([0, 7, 9, 5], jnp.int32)
+    slots = jnp.array([0, 1 * bs + 3, bs - 1, 4 * bs + 1], jnp.int32)
+    tables = jnp.zeros((4, 8), jnp.int32).at[1, :2].set(jnp.array([1, 2]))
+    tables = tables.at[3, :2].set(jnp.array([3, 4]))
+    tokens = jnp.array([0, 11, 22, 33], jnp.int32)
+    seen = []
+    if family == "llama":
+        cfg, params = tiny_setup
+        kc, vc = _empty_cache(cfg, block_size=bs)
+        real = L.paged_decode_attention
+        monkeypatch.setattr(
+            L, "paged_decode_attention",
+            lambda q, k, v, bt, ctx, **kw: (
+                seen.append(np.asarray(ctx)), real(q, k, v, bt, ctx, **kw)
+            )[1],
+        )
+        L.decode(params, cfg, tokens, positions, kc, vc, tables, slots)
+        layers = cfg.num_layers
+    else:
+        from dynamo_tpu.models import mla_moe as M
+        from tests.test_mla_moe import BS, planes, toy
+
+        assert BS == bs
+        cfg, params = toy()[:2]
+        real = M.mla.decode_attention
+        monkeypatch.setattr(
+            M.mla, "decode_attention",
+            lambda q, plane, bt, ctx, **kw: (
+                seen.append(np.asarray(ctx)), real(q, plane, bt, ctx, **kw)
+            )[1],
+        )
+        M.decode(params, cfg, tokens, positions, planes(cfg), (), tables, slots)
+        layers = cfg.num_layers
+    assert len(seen) == layers
+    for ctx in seen:
+        assert ctx.tolist() == [0, 8, 0, 6]

@@ -201,6 +201,31 @@ def test_mixed_step_budget_packs_multiple_chunks():
     assert slots_seen and max(slots_seen) == 2, slots_seen
 
 
+def test_a_long_prompt_alone_takes_the_mixed_step():
+    """No lane is decoding when the long prompt comes: it runs the mixed
+    programs it would run on a busy server (so a server's compiled set does
+    not depend on what its first long prompt met), never the lone-prompt
+    chunk program, and its stream is the phase-separated scheduler's."""
+
+    async def run(engine):
+        prompt = list(np.random.default_rng(5).integers(1, 64, size=40))
+        out = await collect(engine, greedy_request(prompt, 6))
+        await engine.close()
+        return out
+
+    ref = asyncio.run(run(make_chunked_engine(8, mixed_step=False)))
+    mixed = make_chunked_engine(8, mixed_step=True, chunk_budget=16)
+    mixed_calls, _ = _spy_programs(mixed)
+
+    def no_chunk_program(*a, **k):
+        raise AssertionError("the lone-prompt chunk program was dispatched")
+
+    mixed.runner.prefill_chunk = no_chunk_program
+    got = asyncio.run(run(mixed))
+    assert got == ref
+    assert mixed_calls == [2, 2, 1]  # 40 tokens: 16 + 16 + 8
+
+
 async def test_chunk_cap_waits_for_step_boundary():
     """Satellite bugfix: a brownout chunk_cap transition landing
     mid-iteration (after the loop-top latch) must NOT re-slice the chunk

@@ -48,6 +48,61 @@ def test_paged_decode_matches_xla(hq, hkv, dtype):
     )
 
 
+# The grid since PR 29: one cell walks a lane and all of its KV heads, and a
+# lane with a context of 0 holds no request. Block 8, two pages
+# a chunk: contexts end inside a page (13, 49), on a page edge that is no
+# chunk edge (24) and on a chunk edge (32); idle lanes lie between the live.
+_LIVE = {1: 13, 3: 24, 4: 32, 6: 49}  # lane -> context, of 8 lanes
+
+
+@pytest.mark.parametrize("hkv,group", [(8, 4), (4, 7), (2, 4), (1, 7)])
+@pytest.mark.parametrize("window", [None, 20])
+@pytest.mark.parametrize("softcap", [None, 5.0])
+@pytest.mark.parametrize("cache", ["bf16", "int8"])
+def test_paged_decode_lanes_and_heads_in_one_cell(hkv, group, window, softcap, cache):
+    from dynamo_tpu.ops.kv_quant import quantize_blocks
+
+    B, D, bs, nb, mb, W = 8, 32, 8, 40, 7, 2
+    hq = hkv * group
+    keys = jax.random.split(jax.random.PRNGKey(hkv * 10 + group), 4)
+    q = _rand(keys[0], (B, hq, D), jnp.bfloat16)
+    kc = _rand(keys[1], (hkv, nb, bs, D), jnp.bfloat16)
+    vc = _rand(keys[2], (hkv, nb, bs, D), jnp.bfloat16)
+    if cache == "int8":
+        kc, vc = quantize_blocks(kc), quantize_blocks(vc)
+    lens = np.zeros(B, np.int32)
+    tables = np.zeros((B, mb), np.int32)  # an idle lane's table is zeros
+    pages = np.asarray(jax.random.permutation(keys[3], nb - 1)) + 1
+    for n, (lane, ctx) in enumerate(_LIVE.items()):
+        lens[lane] = ctx
+        tables[lane] = pages[n * mb:(n + 1) * mb]
+    live = np.array(sorted(_LIVE))
+    kw = dict(window=window, logit_softcap=softcap, scale=0.3)
+
+    def pallas(q_, tables_, lens_):
+        k_, v_ = (kc["q"], vc["q"]) if cache == "int8" else (kc, vc)
+        scales = (
+            dict(k_scales=kc["s"], v_scales=vc["s"]) if cache == "int8" else {}
+        )
+        return np.asarray(paged_decode_attention_pallas(
+            q_, k_, v_, jnp.asarray(tables_), jnp.asarray(lens_),
+            pages_per_chunk=W, interpret=True, **scales, **kw,
+        ), np.float32)
+
+    def xla(q_, tables_, lens_):
+        return np.asarray(A.paged_decode_attention(
+            q_, kc, vc, jnp.asarray(tables_), jnp.asarray(lens_), **kw
+        ), np.float32)
+
+    out, ref = pallas(q, tables, lens), xla(q, tables, lens)
+    np.testing.assert_allclose(out[live], ref[live], atol=2e-2, rtol=2e-2)
+    idle = [b for b in range(B) if b not in _LIVE]
+    assert (out[idle] == 0).all() and (ref[idle] == 0).all()
+    # the live lanes alone: the same rows, bit for bit, from both forms
+    assert np.array_equal(pallas(q[live], tables[live], lens[live]), out[live])
+    assert np.array_equal(xla(q[live], tables[live], lens[live]), ref[live])
+
+
 @pytest.mark.parametrize("p,valid", [(32, 32), (64, 40), (128, 5)])
 @pytest.mark.parametrize("hq,hkv", [(8, 2), (4, 4)])
 def test_flash_prefill_matches_xla(p, valid, hq, hkv):

@@ -88,16 +88,25 @@ def int8_linear(sds, n_in, n_out):
 # ---------------------------------------------------- default-path kernels
 
 
+# (query heads, KV heads) of the paged decode call: Mistral-7B, Qwen2.5-7B
+# (a group of 7), and what a `tp=4` shard of each holds (2 and 1 KV heads).
+# One grid cell walks a lane and all of these heads, and a page is one strided
+# copy over the head axis (`k_hbm.at[:, page]`): if Mosaic refused
+# that copy for some head count, this is where it would say so.
+DECODE_HEADS = [(HQ, HKV), (28, 4), (HQ // 4, HKV // 4), (7, 1)]
+
+
 @pytest.mark.parametrize("window", [None, WINDOW])
-def test_paged_decode_kernel(one_chip, window):
+@pytest.mark.parametrize("hq,hkv", DECODE_HEADS)
+def test_paged_decode_kernel(one_chip, hq, hkv, window):
     from dynamo_tpu.ops.pallas_attention import paged_decode_attention_pallas
 
     nb = 1024
     text = compile_text(
         functools.partial(paged_decode_attention_pallas, window=window),
-        one_chip((B, HQ, D), BF16),
-        one_chip((HKV, nb, BLOCK, D), BF16),
-        one_chip((HKV, nb, BLOCK, D), BF16),
+        one_chip((B, hq, D), BF16),
+        one_chip((hkv, nb, BLOCK, D), BF16),
+        one_chip((hkv, nb, BLOCK, D), BF16),
         one_chip((B, CONTEXT // BLOCK), I32),
         one_chip((B,), I32),
     )
@@ -173,25 +182,28 @@ def test_kernel_payload_ignores_callers_line_numbers(one_chip):
 # 16.00M" for the fused projections with the whole hidden dim as one tile).
 
 
-@pytest.mark.parametrize("kernel", ["decode", "verify"])
-def test_int8_kv_kernels(one_chip, kernel):
+@pytest.mark.parametrize(
+    "kernel,hq,hkv",
+    [("decode", hq, hkv) for hq, hkv in DECODE_HEADS] + [("verify", HQ, HKV)],
+)
+def test_int8_kv_kernels(one_chip, kernel, hq, hkv):
     """DYN_KV_DTYPE=int8: int8 pages (block 32) dequantized in the kernel."""
     from dynamo_tpu.ops import pallas_attention as pa
 
     nb, bs, S = 1024, 32, 5
-    cache = one_chip((HKV, nb, bs, D), I8)
-    scales = one_chip((HKV, nb), F32)
+    cache = one_chip((hkv, nb, bs, D), I8)
+    scales = one_chip((hkv, nb), F32)
     tables = one_chip((B, CONTEXT // bs), I32)
     if kernel == "decode":
         fn = lambda q, k, v, bt, cl, ks, vs: pa.paged_decode_attention_pallas(
             q, k, v, bt, cl, k_scales=ks, v_scales=vs, window=WINDOW
         )
-        q, lens = one_chip((B, HQ, D), BF16), one_chip((B,), I32)
+        q, lens = one_chip((B, hq, D), BF16), one_chip((B,), I32)
     else:
         fn = lambda q, k, v, bt, ps, ks, vs: pa.paged_verify_attention_pallas(
             q, k, v, bt, ps, k_scales=ks, v_scales=vs, window=WINDOW
         )
-        q, lens = one_chip((B, S, HQ, D), BF16), one_chip((B, S), I32)
+        q, lens = one_chip((B, S, hq, D), BF16), one_chip((B, S), I32)
     text = compile_text(fn, q, cache, cache, tables, lens, scales, scales)
     assert "tpu_custom_call" in text
 
