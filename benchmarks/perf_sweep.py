@@ -259,7 +259,7 @@ def main() -> None:
         "--preset",
         choices=[
             "canonical", "swa", "chaos", "disagg", "trace", "slo",
-            "priority", "integrity", "decode_mfu", "blackout", "planner",
+            "priority", "integrity", "blackout", "planner",
             "tail", "goodput", "sim", "mixed", "prefix", "upgrade",
             "provenance",
         ],
@@ -291,10 +291,6 @@ def main() -> None:
         "codec overhead, streamed-disagg TTFT checksums on vs off with "
         "a <=3% bar, and the corrupt_kv/zombie fault proof; banked "
         "artifact benchmarks/integrity_sweep.json). "
-        "decode_mfu = delegates to benchmarks.decode_mfu_bench (modeled "
-        "HBM bytes/token + measured tiny-CPU tok/s for {bf16, int8-w, "
-        "int8-w+int8-KV} x {fused, unfused}; banked artifact "
-        "benchmarks/decode_mfu.json). "
         "blackout = delegates to benchmarks.blackout_sweep (throughput/"
         "TTFT through a mid-traffic control-plane blackout vs steady "
         "state — zero errors, zero divergence — plus warm-restart TTFT "
@@ -385,17 +381,6 @@ def main() -> None:
 
         integrity_sweep.main(
             ["--json", args.json or "benchmarks/integrity_sweep.json"]
-        )
-        return
-    if args.preset == "decode_mfu":
-        # decode-bandwidth matrix has its own harness (modeled HBM
-        # bytes/token + measured tiny-CPU tok/s per {weights, KV, fused}
-        # cell) — one entry point for every banked curve stays
-        # `perf_sweep --preset X`
-        from benchmarks import decode_mfu_bench
-
-        decode_mfu_bench.main(
-            ["--json", args.json or "benchmarks/decode_mfu.json"]
         )
         return
     if args.preset == "planner":

@@ -12,10 +12,12 @@ to consume (round-2 VERDICT weak #6).
 Engines: `mocker` (cost-model sim; CI-fast), `tiny-jax` (real engine, CPU),
 or `jax` with DYN_MODEL_PATH on TPU.
 
-Mocker fidelity: measured wall time is multiplied by the speedup ratio to
+Mocker fidelity: measured time is multiplied by the speedup ratio to
 recover modeled seconds, so event-loop overhead is amplified by the same
 factor — keep speedup LOW (default 10) so the cost model dominates what
-the clock sees.
+the clock sees. Times are read from the process clock (`runtime/clock.py`):
+on the virtual clock of `testing/sim.py` a mocker profile is its cost model
+exactly, whatever else the host is doing.
 
 Usage:
     python benchmarks/profile_sweep.py --engine mocker --out profile.npz
@@ -27,12 +29,13 @@ import argparse
 import asyncio
 import json
 import sys
-import time
 from typing import Optional
 
 import numpy as np
 
 sys.path.insert(0, __file__.rsplit("/", 2)[0])
+
+from dynamo_tpu.runtime import clock as dclock  # noqa: E402
 
 
 async def _one_request(engine, token_ids, max_tokens):
@@ -49,13 +52,13 @@ async def _one_request(engine, token_ids, max_tokens):
         sampling=SamplingOptions(greedy=True),
         stop=StopConditions(max_tokens=max_tokens, ignore_eos=True),
     )
-    t0 = time.perf_counter()
+    t0 = dclock.now()
     first = None
     gaps = []
     last = None
     async for out in engine.generate(req, Context()):
         if out.token_ids:
-            now = time.perf_counter()
+            now = dclock.now()
             if first is None:
                 first = now - t0
             if last is not None:
@@ -77,7 +80,7 @@ async def profile_engine(
     time_scale: float = 1.0,
     rng_seed: int = 0,
 ) -> dict:
-    """Sweep the engine; `time_scale` maps measured wall seconds to
+    """Sweep the engine; `time_scale` maps measured seconds to
     modeled seconds (the mocker runs at a speedup ratio)."""
     rng = np.random.default_rng(rng_seed)
     prefill_ttft, prefill_tok_s = [], []
@@ -102,18 +105,18 @@ async def profile_engine(
                 rng.integers(1, 1000, size=ctx).tolist()
                 for _ in range(n_seqs)
             ]
-            t0 = time.perf_counter()
+            t0 = dclock.now()
             results = await asyncio.gather(
                 *(
                     _one_request(engine, p, max_tokens=decode_osl)
                     for p in prompts
                 )
             )
-            wall = (time.perf_counter() - t0) * time_scale
+            elapsed = (dclock.now() - t0) * time_scale
             gaps = [g for _, gs in results for g in gs]
             itl = (np.mean(gaps) if gaps else 0.0) * time_scale
             decode_itl[ci, ui] = itl * 1e3
-            decode_tok_s[ci, ui] = n_seqs * decode_osl / max(wall, 1e-9)
+            decode_tok_s[ci, ui] = n_seqs * decode_osl / max(elapsed, 1e-9)
 
     out = {
         "prefill_isl": np.asarray(isl_grid, float),

@@ -1,6 +1,6 @@
 """Speculative-decoding smoke bench: spec-on vs spec-off on deterministic
-CPU traces (counts of accepted drafts and dispatches, not device speed;
-`bench.py --spec-k` carries the same knob for the on-chip number).
+CPU traces (counts of accepted drafts and dispatches, not device speed:
+`DYN_SPEC_K` has no reading on the chip yet, ROADMAP D1).
 
 Two workloads, both greedy and fully deterministic:
 

@@ -388,7 +388,7 @@ def cache_entries(cache_dir: str) -> int:
 
 
 def check_ledger(
-    goodput: dict, facts: dict, rehearsal: bool, n_streams: int, log: str
+    goodput: dict, facts: dict, rehearsal: bool, n_streams: int
 ) -> None:
     """The programs that were meant to run did. A run that answered every
     request on the XLA gather, or at H=1, fails here; so does one that
@@ -411,10 +411,6 @@ def check_ledger(
         f"{single} single-step decode dispatches for {n_streams} streams "
         "(the horizon was dropped mid-run?)",
     )
-    with open(log, errors="replace") as f:
-        text = f.read()
-    for words in ("degrading to single-step", "decode_multi@H4 compile failed"):
-        check(words not in text, f"server log says {words!r}:\n" + tail(log))
     check(
         any(l in ("prefill_packed", "prefill_chunk") for l in labels),
         f"no packed or chunked prefill dispatched: {labels}",
@@ -513,7 +509,7 @@ def serve_phase(args, env, model_dir, words, device) -> dict:
         )
         bad = [v for v in verdicts if not v["ok"]]
         check(not bad, f"{len(bad)} of {len(verdicts)} streams failed: {bad}")
-        check_ledger(goodput, facts, rehearsal, len(verdicts), log)
+        check_ledger(goodput, facts, rehearsal, len(verdicts))
     except BaseException:
         if proc.poll() is None:
             proc.kill()

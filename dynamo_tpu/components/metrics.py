@@ -451,18 +451,6 @@ def goodput_families(
     ):
         comp.add_metric([label], float(v))
     yield comp
-    yield GaugeMetricFamily(
-        f"{PREFIX}_mfu_achieved",
-        "Achieved decode MFU from real dispatch shapes through the "
-        "roofline model (fleet mean)",
-        value=float(gp.mfu_achieved if gp is not None else 0.0),
-    )
-    yield GaugeMetricFamily(
-        f"{PREFIX}_hbm_bytes_per_token_achieved",
-        "Achieved HBM bytes per emitted token from real dispatch shapes "
-        "(fleet mean)",
-        value=float(gp.hbm_bytes_per_token if gp is not None else 0.0),
-    )
 
 
 def fleet_upgrade_families(status: Optional[dict]):
@@ -657,24 +645,6 @@ class MetricsComponent:
         self.g_kv_overlap = g(
             "kv_stream_overlap",
             "Fraction of received KV bytes landed before the final frame",
-        )
-        # decode-bandwidth plane (ISSUE 9): fleet-mean modeled HBM bytes
-        # per emitted decode token and the decode-MFU estimate — the live
-        # counterparts of benchmarks/decode_mfu.json
-        self.g_decode_hbm_bytes = g(
-            "decode_hbm_bytes_per_token",
-            "Modeled HBM bytes read per decode token (fleet mean)",
-        )
-        self.g_mfu_decode = g(
-            "mfu_decode_est",
-            "Estimated decode MFU from windowed token rate (fleet mean)",
-        )
-        # meshed decode (ISSUE 19): modeled tp-axis collective bytes per
-        # decode step (perf_model.tp_collective_bytes_per_step; 0 when
-        # unmeshed/tp=1)
-        self.g_tp_collective_bytes = g(
-            "tp_collective_bytes_per_step",
-            "Modeled tp-axis collective bytes per decode step (fleet mean)",
         )
         # control-plane health of THIS process's fabric client (degraded-
         # mode data plane): same families every frontend exports for its
@@ -871,13 +841,6 @@ class MetricsComponent:
                 if xfer is not None:
                     self.g_kv_frames_inflight.set(xfer.kv_frames_inflight)
                     self.g_kv_overlap.set(xfer.overlap_fraction)
-                self.g_decode_hbm_bytes.set(
-                    agg.worker_stats.decode_hbm_bytes_per_token
-                )
-                self.g_mfu_decode.set(agg.worker_stats.mfu_decode_est)
-                self.g_tp_collective_bytes.set(
-                    agg.worker_stats.tp_collective_bytes_per_step
-                )
                 # burn-rate windows advance on every poll, with or without
                 # fresh phase data (recovery to ok needs empty ticks too)
                 self.slo.observe(
@@ -1124,7 +1087,6 @@ class MockWorkerMetrics:
             gp.record_recompile(
                 "decode", "shape_miss", shape=f"lanes={lanes},tokens=0"
             )
-        gp.set_perf_gauges(0.05 * load, 4e8 / (1.0 + 3.0 * load))
         return ForwardPassMetrics(
             worker_stats=WorkerStats(
                 request_active_slots=int(self.total_slots * load),
@@ -1143,11 +1105,6 @@ class MockWorkerMetrics:
                 ),
                 num_blocks_quarantined=self._blocks_quarantined,
                 fenced_rejects_by_plane=dict(self._fenced_rejects) or None,
-                # decode-bandwidth gauges: bytes/token shrinks a little as
-                # load grows (bigger batches amortize the weight stream),
-                # MFU tracks load — deterministic like everything else
-                decode_hbm_bytes_per_token=4e8 / (1.0 + 3.0 * load),
-                mfu_decode_est=0.05 * load,
             ),
             kv_stats=KvStats(
                 kv_active_blocks=active_blocks,

@@ -20,7 +20,6 @@ import contextlib
 import itertools
 import os
 import time
-from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, AsyncIterator, Callable, Optional
 
@@ -98,11 +97,6 @@ class JaxEngineConfig:
     # weight-bandwidth-bound chip the verify premium is small — deploy
     # with a lower value there (DYN_SPEC_COVERAGE).
     spec_min_coverage: float = 0.5
-    # Lazy horizon compile: single-step until the decode_multi program
-    # finishes a BACKGROUND compile (runner.prepare_decode_multi_async),
-    # instead of stalling first tokens behind the unrolled-horizon compile
-    # (the largest of the engine's programs).
-    lazy_horizon: bool = False
     # Stuck-horizon watchdog: a dispatch that exceeds watchdog_mult × its
     # EMA (floored at watchdog_min_s once warm; watchdog_cold_s covers the
     # first dispatch of a label, which includes its XLA compile) trips the
@@ -149,11 +143,7 @@ class JaxEngineConfig:
     # chunk-then-decode loop; the output streams are bit-identical either
     # way (the token-identity parity test pins this), only the step
     # schedule — and with it the phase bubble — changes.
-    mixed_step: bool = field(
-        default_factory=lambda: str(
-            os.environ.get("DYN_MIXED_STEP", "1")
-        ).lower() not in ("0", "false", "no", "off")
-    )
+    mixed_step: bool = True
 
 
 @dataclass
@@ -191,14 +181,6 @@ class EngineStats:
     kv_bytes_overlapped: int = 0
     kv_frames_inflight: int = 0  # gauge (prefill role, bounded window)
     prefill_dropped_expired: int = 0  # queue entries dropped past deadline
-    # decode-bandwidth plane (ISSUE 9): modeled HBM bytes per emitted
-    # token for the live batch shape + a windowed-rate MFU estimate
-    # (engine/jax_engine/perf_model.py); both gauges
-    decode_hbm_bytes_per_token: float = 0.0
-    mfu_decode_est: float = 0.0
-    # meshed decode (ISSUE 19): modeled tp-axis collective bytes each
-    # decode step moves (0 off-mesh / tp=1); gauge
-    tp_collective_bytes_per_step: float = 0.0
     # QoS plane (ISSUE 7): per-class preemption counts (class-aware
     # KV-preserving preemption — bulk absorbs pressure first), storm-guard
     # kills, engine-side brownout sheds, and the live brownout rung
@@ -426,8 +408,6 @@ class JaxEngine:
             total_blocks=self.config.num_blocks - 1,
             total_slots=self.config.max_batch,
         )
-        # windowed token-rate samples feeding the mfu_decode_est gauge
-        self._mfu_window: deque[tuple[float, int]] = deque()
         # self-drafting speculative decoding (spec_k > 0 and a runner that
         # carries the verify program)
         self.drafter = None
@@ -2695,14 +2675,6 @@ class JaxEngine:
         H = self.config.decode_horizon
         if H <= 1 or not hasattr(self.runner, "decode_multi"):
             return 1
-        if self.config.lazy_horizon and hasattr(
-            self.runner, "decode_multi_ready"
-        ):
-            # cold-start path: single-step while the horizon program
-            # compiles in the background (kick is idempotent)
-            if not self.runner.decode_multi_ready(H):
-                self.runner.prepare_decode_multi_async(H)
-                return 1
         # penalties ride the horizon too: the program carries [B, V] count
         # tables on device, so a penalty lane no longer drags the whole
         # batch to single-stepping (VERDICT r4 weak #2)
@@ -2911,11 +2883,7 @@ class JaxEngine:
             # tables can't subtract a rejected draft back out
             E = 0
             if self.config.decode_horizon > 1 and not any_pen:
-                if not self.config.lazy_horizon or (
-                    hasattr(self.runner, "decode_multi_ready")
-                    and self.runner.decode_multi_ready(self.config.decode_horizon)
-                ):
-                    E = self.config.decode_horizon - 1
+                E = self.config.decode_horizon - 1
             # preallocate KV blocks for every potential write this dispatch
             # (same formula as _horizon_for: the last emitted token is never
             # fed, so writes cover lane_steps - 1 positions past pos-1)
@@ -3111,47 +3079,25 @@ class JaxEngine:
                     pres[i] = seq.pres_pen
                     rep[i] = seq.rep_pen
                 penalties = (hist, hist_len, prompt_len, freq, pres, rep)
-        try:
-            async with self._device_lock:
-                packed = await self._dispatch(
-                    # the label names the program that ran: a ledger that
-                    # shows only "decode" served at H=1
-                    f"decode_multi@H{H}B{self.config.max_batch}",
-                    lambda: np.asarray(
-                        self.runner.decode_multi(
-                            H,
-                            self._tokens, self._positions, self._block_tables,
-                            self._temps, self._top_ps, self._top_ks,
-                            self._keys, act, limit_rem, min_rem, eos_ids,
-                            penalties=penalties,
-                        )
-                    ),
-                    lanes=len(active),
-                    capacity=self.config.max_batch,
-                    ctx_tokens=self._ctx_tokens(active),
-                    horizon=H,
-                )
-        except Exception:  # noqa: BLE001
-            if not self.config.lazy_horizon:
-                raise
-            # lazy-horizon first execution can fail at runtime (HBM OOM the
-            # background AOT compile couldn't see). The donated caches may
-            # be consumed: rebuild and degrade to single-step permanently —
-            # live lanes lose cached KV, so fail them rather than decode
-            # against zeros (new admissions re-prefill from scratch).
-            logger.exception(
-                "decode_multi@H%d failed at runtime; degrading to "
-                "single-step", H,
+        async with self._device_lock:
+            packed = await self._dispatch(
+                # the label names the program that ran: a ledger that
+                # shows only "decode" served at H=1
+                f"decode_multi@H{H}B{self.config.max_batch}",
+                lambda: np.asarray(
+                    self.runner.decode_multi(
+                        H,
+                        self._tokens, self._positions, self._block_tables,
+                        self._temps, self._top_ps, self._top_ks,
+                        self._keys, act, limit_rem, min_rem, eos_ids,
+                        penalties=penalties,
+                    )
+                ),
+                lanes=len(active),
+                capacity=self.config.max_batch,
+                ctx_tokens=self._ctx_tokens(active),
+                horizon=H,
             )
-            self.config.decode_horizon = 1
-            if self.runner.ensure_kv_alive():
-                # every slot-holding lane's cached KV is gone (chunked
-                # prefills included); in-flight remote prefills are exempt
-                # — their inject ships complete blocks into the new cache
-                for seq in list(self._admit_order):
-                    if seq.slot is not None and not seq.pending_remote:
-                        self._finish(seq, FinishReason.ERROR)
-            return
         with dtrace.phase("loop.emit"):
             counted = self.runner.step_stats(packed)
             if counted is not None:
@@ -3331,68 +3277,3 @@ class JaxEngine:
         outcomes = getattr(self.peer_block_client, "pull_outcomes", None)
         if outcomes:
             self.stats.kv_pull_outcomes = dict(outcomes)
-        self._update_perf_gauges()
-
-    def _update_perf_gauges(self) -> None:
-        """Decode-bandwidth gauges: modeled HBM bytes per emitted token
-        for the CURRENT batch/context shape, and an MFU estimate from a
-        windowed token rate (engine/jax_engine/perf_model.py — the same
-        arithmetic decode_mfu_bench banks)."""
-        mcfg = getattr(self.runner, "config", None)
-        if mcfg is None or not hasattr(mcfg, "num_layers"):
-            return  # mocker/echo engines have no model config
-        from dynamo_tpu.models import cache_kind
-
-        if cache_kind(mcfg).name != "kv_heads":
-            # perf_model.py models the grouped-query block's bytes and
-            # operations only; another family's gauges stay at 0
-            return
-        active = [s for s in self.slots if s is not None]
-        now = time.monotonic()
-        win = self._mfu_window
-        win.append((now, self.stats.generated_tokens))
-        while len(win) > 2 and now - win[0][0] > 10.0:
-            win.popleft()
-        from dynamo_tpu.engine.jax_engine import perf_model
-
-        if active:
-            mean_ctx = self._ctx_tokens(active) / len(active)
-            params = getattr(self.runner, "params", None)
-            quant_w = False
-            if isinstance(params, dict):
-                layers = params.get("layers") or [{}]
-                quant_w = isinstance(layers[0].get("wq"), dict)
-            mesh = getattr(self.runner, "mesh", None)
-            tp = mesh.shape.get("tp", 1) if mesh is not None else 1
-            mb = perf_model.meshed_decode_hbm_bytes_per_token(
-                mcfg,
-                batch=len(active),
-                context=mean_ctx,
-                block_size=self.config.block_size,
-                tp=tp,
-                weights_int8=quant_w,
-                kv_int8=getattr(self.runner, "kv_quantized", False),
-                fused=getattr(mcfg, "fused_decode", False),
-                overlap=getattr(mcfg, "collective_overlap", False),
-            )
-            # per-CHIP bytes/token: tp=1 degenerates to the old model
-            self.stats.decode_hbm_bytes_per_token = mb.per_chip.total
-            self.stats.tp_collective_bytes_per_step = (
-                mb.tp_collective_bytes_per_step
-            )
-        dt = now - win[0][0]
-        if dt > 0.5:
-            rate = (self.stats.generated_tokens - win[0][1]) / dt
-            self.stats.mfu_decode_est = perf_model.mfu_decode_est(
-                mcfg, rate, perf_model.peak_flops_from_env()
-            )
-        # goodput ledger: latest achieved point from the REAL dispatch
-        # shapes (n=1 sample; the fleet merge averages across workers)
-        self.stats.goodput.set_perf_gauges(
-            self.stats.mfu_decode_est, self.stats.decode_hbm_bytes_per_token
-        )
-        if dtrace.enabled() and self.stats.goodput.enabled:
-            dtrace.counter("mfu_achieved", self.stats.mfu_decode_est)
-            dtrace.counter(
-                "tokens_wasted", float(self.stats.goodput.wasted_total())
-            )

@@ -207,7 +207,6 @@ async def build_jax_engine(
             max_model_len=max_len,
             rng_seed=rng_seed,
             decode_horizon=default_decode_horizon(),
-            lazy_horizon=default_lazy_horizon(),
             **spec_decode_settings(),
         ),
         block_manager=_maybe_block_manager(config, kv_block_size),
@@ -392,12 +391,6 @@ def spec_decode_settings() -> dict:
             os.environ.get("DYN_SPEC_COVERAGE", "0.5") or 0.5
         ),
     }
-
-
-def default_lazy_horizon() -> bool:
-    """DYN_LAZY_HORIZON=1: compile the decode_multi horizon program in the
-    background and single-step until it lands."""
-    return os.environ.get("DYN_LAZY_HORIZON", "0") in ("1", "true", "yes")
 
 
 def default_decode_horizon() -> int:

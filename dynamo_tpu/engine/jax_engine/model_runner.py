@@ -1691,16 +1691,6 @@ class ModelRunner:
             self._to_dev(active), self._to_dev(limit_remaining),
             self._to_dev(min_remaining), self._to_dev(eos_ids),
         )
-        aot = (
-            getattr(self, "_decode_multi_aot", {}).get(H)
-            if penalties is None
-            else None
-        )
-        if aot is not None:
-            # background-compiled executable (lazy_horizon): same program,
-            # no first-call compile stall
-            out, self.k_cache, self.v_cache = aot(*args)
-            return out
         kwargs = {}
         if penalties is not None:
             kwargs["pen"] = tuple(self._to_dev(p) for p in penalties)
@@ -1771,8 +1761,6 @@ class ModelRunner:
         )
         return out
 
-    # ------------------------------------------------- lazy horizon compile
-
     def step_stats(self, packed: np.ndarray) -> Optional[dict[str, float]]:
         """What the model counted on the device during one fetched
         `decode_multi` horizon, summed over its steps and layers (the row
@@ -1784,95 +1772,3 @@ class ModelRunner:
         row = packed[:, -1, : len(names)]  # [H, names]
         return {name: float(row[:, j].sum()) for j, name in enumerate(names)}
 
-    def decode_multi_ready(self, H: int) -> bool:
-        """True once the horizon program for this H has a compiled
-        executable (the engine's lazy_horizon mode single-steps until
-        then, so cold starts never stall the first tokens ~30 s behind
-        the unrolled-horizon compile)."""
-        return H in getattr(self, "_decode_multi_aot", {})
-
-    def prepare_decode_multi_async(self, H: int) -> None:
-        """Kick one background AOT compile of the plain (penalty-free)
-        decode_multi program for this H; idempotent. The compiled
-        executable is picked up by decode_multi_ready; compile failures
-        are recorded so the engine stays on the single-step path instead
-        of re-kicking forever."""
-        if not hasattr(self, "_decode_multi_aot"):
-            self._decode_multi_aot: dict[int, Any] = {}
-            self._decode_multi_aot_pending: set[int] = set()
-        if H in self._decode_multi_aot or H in self._decode_multi_aot_pending:
-            return
-        self._decode_multi_aot_pending.add(H)
-        import threading
-
-        B = self.max_batch
-
-        def build() -> None:
-            try:
-                f32 = jnp.float32
-                sds = lambda c: jax.tree_util.tree_map(
-                    lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), c
-                )
-                args = (
-                    self.params,
-                    sds(self.k_cache),
-                    sds(self.v_cache),
-                    jax.ShapeDtypeStruct((B,), jnp.int32),
-                    jax.ShapeDtypeStruct((B,), jnp.int32),
-                    jax.ShapeDtypeStruct((B, self.max_blocks_per_seq), jnp.int32),
-                    jax.ShapeDtypeStruct((B, 2), jnp.uint32),
-                    jax.ShapeDtypeStruct((B,), f32),
-                    jax.ShapeDtypeStruct((B,), f32),
-                    jax.ShapeDtypeStruct((B,), jnp.int32),
-                    jax.ShapeDtypeStruct((B,), jnp.bool_),
-                    jax.ShapeDtypeStruct((B,), jnp.int32),
-                    jax.ShapeDtypeStruct((B,), jnp.int32),
-                    jax.ShapeDtypeStruct((B, MAX_EOS_IDS), jnp.int32),
-                )
-                compiled = self._decode_multi_fn.lower(H, *args).compile()
-                self._decode_multi_aot[H] = compiled
-                logger.info("decode_multi@H%d compiled in background", H)
-            except Exception:  # noqa: BLE001 — engine stays on H=1
-                logger.exception(
-                    "background decode_multi@H%d compile failed; "
-                    "staying single-step", H
-                )
-            finally:
-                self._decode_multi_aot_pending.discard(H)
-
-        threading.Thread(
-            target=build, daemon=True, name=f"decode-multi-compile-H{H}"
-        ).start()
-
-    def ensure_kv_alive(self) -> bool:
-        """Rebuild the KV caches with zeros if a failed donated call
-        consumed them (runtime OOM in a horizon/verify program leaves the
-        runner referencing deleted arrays — the single-step fallback would
-        then crash). Returns True if a rebuild happened. Shape/dtype are
-        metadata, readable even on a deleted array; the caller is
-        responsible for knowing that live sequences' cached KV is gone."""
-        from dynamo_tpu.ops.kv_quant import cache_zeros_like
-
-        probe = jax.tree_util.tree_leaves(self.k_cache)[0]
-        try:
-            dead = getattr(probe, "is_deleted", lambda: False)()
-        except Exception:  # noqa: BLE001
-            dead = True
-        if not dead:
-            return False
-        for name in ("k_cache", "v_cache"):
-            # shape/dtype are metadata, readable even on deleted arrays —
-            # capture only those (never the dead buffers) in the rebuild
-            spec = jax.tree_util.tree_map(
-                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
-                getattr(self, name),
-            )
-            if self._kv_sharding is not None:
-                make = jax.jit(
-                    lambda sp=spec: cache_zeros_like(sp),
-                    out_shardings=self._kv_shard_tree,
-                )
-                setattr(self, name, make())
-            else:
-                setattr(self, name, cache_zeros_like(spec))
-        return True

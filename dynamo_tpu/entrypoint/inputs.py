@@ -651,7 +651,7 @@ async def run_endpoint(
             ph = None
         # goodput ledger (ISSUE 14): shipped whenever the engine recorded
         # a step / waste / compile, so the aggregator can merge the fleet
-        # efficiency view (step hists, occupancy, waste taxonomy, MFU)
+        # efficiency view (step hists, occupancy, waste taxonomy)
         gp = d.get("goodput")
         if gp is not None and not getattr(gp, "total_events", lambda: 0)():
             gp = None
@@ -685,13 +685,6 @@ async def run_endpoint(
                 # engines publish the dict under "kv_pull_outcomes")
                 kv_pulled_blocks_by_outcome=(
                     dict(d.get("kv_pull_outcomes") or {}) or None
-                ),
-                decode_hbm_bytes_per_token=d.get(
-                    "decode_hbm_bytes_per_token", 0.0
-                ),
-                mfu_decode_est=d.get("mfu_decode_est", 0.0),
-                tp_collective_bytes_per_step=d.get(
-                    "tp_collective_bytes_per_step", 0.0
                 ),
             ),
             kv_stats=KvStats(

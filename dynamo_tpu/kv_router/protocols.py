@@ -14,7 +14,7 @@ kept as an optional override for foreign engines.
 
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, fields
 from typing import Any, Optional
 
 from dynamo_tpu.telemetry.goodput import GoodputStats
@@ -138,14 +138,6 @@ class WorkerStats:
     integrity_failures_by_path: Optional[dict[str, int]] = None
     num_blocks_quarantined: int = 0
     fenced_rejects_by_plane: Optional[dict[str, int]] = None
-    # decode-bandwidth plane (ISSUE 9, both gauges): modeled HBM bytes per
-    # emitted token for the worker's live batch shape, and its windowed
-    # decode-MFU estimate (engine/jax_engine/perf_model.py)
-    decode_hbm_bytes_per_token: float = 0.0
-    mfu_decode_est: float = 0.0
-    # meshed decode (ISSUE 19, gauge): modeled tp-axis collective bytes
-    # per decode step (0 off-mesh / tp=1)
-    tp_collective_bytes_per_step: float = 0.0
     # fleet prefix cache (ISSUE 17): prefix blocks this worker pulled
     # from peers instead of recomputing, by outcome (pulled /
     # fallback_miss / fallback_timeout / fallback_integrity /
@@ -232,6 +224,15 @@ class KvTransferStats:
         self.prefill_dropped_expired += other.prefill_dropped_expired
 
 
+def _known(cls, d: dict[str, Any]):
+    """Build a stats dataclass from a peer's frame, keeping only the
+    fields this version declares: during a rolling upgrade a sender of
+    another version carries fields this one lacks (and the reverse, which
+    the defaults cover), and a stats frame must not raise for it."""
+    names = {f.name for f in fields(cls)}
+    return cls(**{k: v for k, v in d.items() if k in names})
+
+
 @dataclass
 class ForwardPassMetrics:
     worker_stats: WorkerStats = field(default_factory=WorkerStats)
@@ -270,10 +271,10 @@ class ForwardPassMetrics:
         ph = d.get("phase_histograms")
         gp = d.get("goodput")
         return cls(
-            worker_stats=WorkerStats(**d.get("worker_stats", {})),
-            kv_stats=KvStats(**d.get("kv_stats", {})),
-            spec_decode_stats=SpecDecodeStats(**spec) if spec else None,
-            kv_transfer_stats=KvTransferStats(**xfer) if xfer else None,
+            worker_stats=_known(WorkerStats, d.get("worker_stats") or {}),
+            kv_stats=_known(KvStats, d.get("kv_stats") or {}),
+            spec_decode_stats=_known(SpecDecodeStats, spec) if spec else None,
+            kv_transfer_stats=_known(KvTransferStats, xfer) if xfer else None,
             phase_histograms=PhaseHistograms.from_dict(ph) if ph else None,
             goodput=GoodputStats.from_dict(gp) if gp else None,
         )

@@ -257,15 +257,6 @@ class KvMetricsAggregator:
                 agg.worker_stats.brownout_level,
                 m.worker_stats.brownout_level,
             )
-            # decode-bandwidth gauges: averaged over reporting workers
-            # (the /n division below, alongside the cache-usage gauges)
-            agg.worker_stats.decode_hbm_bytes_per_token += (
-                m.worker_stats.decode_hbm_bytes_per_token
-            )
-            agg.worker_stats.mfu_decode_est += m.worker_stats.mfu_decode_est
-            agg.worker_stats.tp_collective_bytes_per_step += (
-                m.worker_stats.tp_collective_bytes_per_step
-            )
             if m.worker_stats.preemptions_by_class:
                 if agg.worker_stats.preemptions_by_class is None:
                     agg.worker_stats.preemptions_by_class = {}
@@ -327,15 +318,11 @@ class KvMetricsAggregator:
                 agg.phase_histograms.merge(m.phase_histograms)
             if m.goodput is not None:
                 # goodput ledger: same contract — counters/buckets add,
-                # compile times take the max, the MFU/HBM gauges ride as
-                # (sum, n) pairs so averaging stays associative
+                # compile times take the max
                 if agg.goodput is None:
                     agg.goodput = GoodputStats()
                 agg.goodput.merge(m.goodput)
         if n:
             agg.kv_stats.gpu_cache_usage_perc /= n
             agg.kv_stats.gpu_prefix_cache_hit_rate /= n
-            agg.worker_stats.decode_hbm_bytes_per_token /= n
-            agg.worker_stats.mfu_decode_est /= n
-            agg.worker_stats.tp_collective_bytes_per_step /= n
         return agg

@@ -30,9 +30,7 @@ Two reduction strategies:
     all-gather is exposed. Ring summation reorders the f32 adds, so this
     path is token-identical (not bit-identical) to the plain psum path.
 
-`perf_model.tp_collective_bytes_per_step` models the same byte streams
-(`dyn_llm_tp_collective_bytes_per_step` gauge); `tests/test_meshed_fused.py`
-holds the parity bars.
+`tests/test_meshed_fused.py` holds the parity bars.
 """
 
 from __future__ import annotations

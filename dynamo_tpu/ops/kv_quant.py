@@ -63,12 +63,6 @@ def make_cache(
     return tuple(layer() for _ in range(num_layers))
 
 
-def cache_zeros_like(cache: KVCache) -> KVCache:
-    return jax.tree_util.tree_map(
-        lambda a: jnp.zeros(a.shape, a.dtype), cache
-    )
-
-
 def cache_nbytes(cache: KVCache) -> int:
     return sum(
         a.size * a.dtype.itemsize

@@ -85,9 +85,9 @@ def maybe_quantize(w: jax.Array, quantize: bool) -> Params:
 
 # Trace-time fused-kernel entry counters: bumped every time a fused
 # wrapper is TRACED into a program (once per compile, not per step — jit
-# caches traces). tests/test_meshed_fused.py and tools/mfu_gate.py reset
-# then read these to prove a meshed decode program actually contains the
-# fused kernels instead of silently falling back to the unfused op chain.
+# caches traces). tests/test_meshed_fused.py resets then reads these to
+# prove a meshed decode program actually contains the fused kernels
+# instead of silently falling back to the unfused op chain.
 FUSED_KERNEL_ENTRIES: dict = {"qkv_rope": 0, "attn_out": 0}
 
 

@@ -6,10 +6,9 @@ GO. This module is the efficiency sensing plane: every engine dispatch
 ("device step") is folded into fixed-log-bucket histograms keyed by its
 dispatch label, alongside occupancy (lanes used vs capacity), prefill /
 decode token throughput, phase-bubble time between dispatches, a
-**token-waste taxonomy** of cumulative counters, per-step achieved
-MFU / HBM-bytes-per-token gauges (fed from `perf_model.py` with the real
-dispatch shapes), and **recompile forensics** — per-label compile time
-plus a counter of *unexpected* recompiles after warmup.
+**token-waste taxonomy** of cumulative counters, and **recompile
+forensics** — per-label compile time plus a counter of *unexpected*
+recompiles after warmup.
 
 Waste taxonomy (the `cause` label on `dyn_llm_tokens_wasted_total`):
 
@@ -91,9 +90,7 @@ class GoodputStats:
     """Mergeable goodput snapshot (the wire/aggregate half).
 
     Merging follows the phase-histogram contract: counters add, bucket
-    grids add, compile times take the max (worst worker), and the
-    MFU/HBM gauges ship as (sum, n) pairs so fleet averaging is
-    associative no matter the merge order.
+    grids add, compile times take the max (worst worker).
     """
 
     __slots__ = (
@@ -112,9 +109,6 @@ class GoodputStats:
         "waste_by_cause",
         "recompiles",
         "compile_s_by_label",
-        "mfu_sum",
-        "hbm_sum",
-        "gauge_n",
         "moe",
     )
 
@@ -151,11 +145,6 @@ class GoodputStats:
         self.recompiles: dict[str, int] = {}
         # label -> first-dispatch (compile-inclusive) seconds
         self.compile_s_by_label: dict[str, float] = {}
-        # achieved-efficiency gauges as associative (sum, n) pairs; a
-        # single worker publishes n=1 with its latest values
-        self.mfu_sum = 0.0
-        self.hbm_sum = 0.0
-        self.gauge_n = 0
         # what a sparse-expert model counted on the device, summed over
         # the decode horizons fetched so far (MOE_COUNTERS; empty for a
         # model without experts)
@@ -177,14 +166,6 @@ class GoodputStats:
         if total <= 0:
             return 0.0
         return self.phase_gap_s_total / total
-
-    @property
-    def mfu_achieved(self) -> float:
-        return self.mfu_sum / self.gauge_n if self.gauge_n else 0.0
-
-    @property
-    def hbm_bytes_per_token(self) -> float:
-        return self.hbm_sum / self.gauge_n if self.gauge_n else 0.0
 
     def wasted_total(self) -> int:
         return sum(self.waste_by_cause.values())
@@ -227,9 +208,6 @@ class GoodputStats:
                 self.compile_s_by_label[k] = max(
                     self.compile_s_by_label.get(k, 0.0), v
                 )
-        self.mfu_sum += other.mfu_sum
-        self.hbm_sum += other.hbm_sum
-        self.gauge_n += other.gauge_n
         for k, v in other.moe.items():
             self.moe[k] = self.moe.get(k, 0.0) + v
 
@@ -257,9 +235,6 @@ class GoodputStats:
             "w": dict(self.waste_by_cause),
             "rc": dict(self.recompiles),
             "cs": {k: round(v, 4) for k, v in self.compile_s_by_label.items()},
-            "mfu": self.mfu_sum,
-            "hbm": self.hbm_sum,
-            "n": self.gauge_n,
             "moe": dict(self.moe),
         }
 
@@ -287,9 +262,6 @@ class GoodputStats:
         for k, v in (d.get("cs") or {}).items():
             if len(out.compile_s_by_label) < MAX_LABELS:
                 out.compile_s_by_label[str(k)] = float(v)
-        out.mfu_sum = float(d.get("mfu") or 0.0)
-        out.hbm_sum = float(d.get("hbm") or 0.0)
-        out.gauge_n = int(d.get("n") or 0)
         for k, v in (d.get("moe") or {}).items():
             if k in MOE_COUNTERS:
                 out.moe[k] = float(v)
@@ -328,8 +300,6 @@ class GoodputStats:
             "compile_s_by_label": {
                 k: round(v, 3) for k, v in self.compile_s_by_label.items()
             },
-            "mfu_achieved": round(self.mfu_achieved, 5),
-            "hbm_bytes_per_token": round(self.hbm_bytes_per_token, 1),
             "moe": {k: self.moe.get(k, 0.0) for k in MOE_COUNTERS},
         }
 
@@ -464,15 +434,6 @@ class GoodputLedger(GoodputStats):
             cause,
             shape or "unknown",
         )
-
-    def set_perf_gauges(self, mfu: float, hbm_bytes_per_token: float) -> None:
-        """Latest achieved-efficiency point (real dispatch shapes through
-        perf_model). Stored as an n=1 sample so fleet merges average."""
-        if not self.enabled:
-            return
-        self.mfu_sum = float(mfu)
-        self.hbm_sum = float(hbm_bytes_per_token)
-        self.gauge_n = 1
 
     def mark_idle(self) -> None:
         """Nothing in flight: the next dispatch's gap is idleness, not a

@@ -289,9 +289,9 @@ class Tracer:
         )
         self._ring: deque[Span] = deque(maxlen=max(16, ring))
         self._requests: OrderedDict[str, str] = OrderedDict()
-        # counter-track samples (goodput ledger: occupancy / step time /
-        # wasted tokens / MFU): (name, proc, unix_ns, value), bounded the
-        # same way the span ring is
+        # counter-track samples (goodput ledger: occupancy / step time):
+        # (name, proc, unix_ns, value), bounded the same way the span
+        # ring is
         self._counters: deque[tuple[str, str, int, float]] = deque(
             maxlen=max(16, ring)
         )
@@ -615,8 +615,8 @@ def event(name: str, **attrs: Any) -> None:
 
 def counter(name: str, value: float) -> None:
     """Record a counter-track sample (Perfetto "ph":"C"): goodput gauges
-    like step occupancy / wasted tokens / achieved MFU ride the trace
-    timeline next to the spans. No-op when tracing is disabled."""
+    like step time and occupancy ride the trace timeline next to the
+    spans. No-op when tracing is disabled."""
     if not _enabled:
         return
     tracer().record_counter(name, value)
@@ -841,8 +841,8 @@ def chrome_trace(trace_id: str) -> dict[str, Any]:
             )
     if spans:
         # Overlay counter-track samples ("ph":"C") that fall inside the
-        # trace window: goodput gauges (step_ms / occupancy / mfu_achieved
-        # / tokens_wasted) render as Perfetto counter lanes next to spans.
+        # trace window: goodput gauges (step_ms / occupancy) render as
+        # Perfetto counter lanes next to spans.
         lo = min(s.start_unix_ns for s in spans)
         hi = max(s.start_unix_ns + s.dur_ns for s in spans)
         for name, proc, ts_ns, value in tracer().counters_between(lo, hi):

@@ -1,50 +1,11 @@
-"""Bench-of-record smoke test (VERDICT r3 weak #1).
+"""Smoke tests of the side harnesses under `benchmarks/` (slow tier): each
+drives the real server process, so harness rot cannot ship silently."""
 
-Runs `bench.py --tiny` as a subprocess — the exact entry the driver uses —
-and asserts the emitted JSON line carries a non-null value. Engine-API
-signature drift (e.g. pack_prefill widening from 7- to 9-tuples in r3) can
-no longer ship silently: this test executes the same compile_phase +
-measure path the real bench does.
-"""
-
-import json
 import os
-import subprocess
-import sys
 
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH = os.path.join(REPO, "bench.py")
-
-
-@pytest.mark.slow
-def test_tiny_bench_emits_nonnull_value():
-    env = dict(os.environ)
-    # bench.py --tiny forces jax_platforms=cpu itself; scrub the test
-    # harness's virtual-8-device flag so the bench sees a plain host.
-    env.pop("XLA_FLAGS", None)
-    env["JAX_PLATFORMS"] = "cpu"
-    proc = subprocess.run(
-        [
-            sys.executable, BENCH,
-            "--tiny", "--requests", "4", "--concurrency", "4",
-            "--budget-s", "150", "--measure-s", "20",
-        ],
-        capture_output=True, text=True, timeout=170, env=env, cwd=REPO,
-    )
-    lines = [l for l in proc.stdout.strip().splitlines() if l.startswith("{")]
-    assert lines, (
-        f"bench emitted no JSON line.\nstdout:\n{proc.stdout[-2000:]}\n"
-        f"stderr:\n{proc.stderr[-2000:]}"
-    )
-    result = json.loads(lines[-1])
-    assert result["metric"] == "output_tok_s_per_chip"
-    assert result.get("value") is not None, f"null value: {result}"
-    assert result["value"] > 0
-    assert result["requests_done"] == 4
-    # tiny/cpu numbers must never claim a baseline comparison
-    assert result["vs_baseline"] is None
 
 
 @pytest.mark.slow

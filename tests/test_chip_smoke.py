@@ -4,7 +4,7 @@ The smoke itself needs a TPU; here its `--cpu-rehearsal` mode drives the same
 code (server child through `dynamo_tpu.run in=http out=jax`, streamed
 requests, goodput-ledger checks, clean shutdown, logits child) on a tiny
 model with interpret kernels. Beside it: the no-fallback rules of PR 21
-(`chip_smoke.py` and `bench.py` fail off-TPU, the peak table has no default)
+(`chip_smoke.py` fails off-TPU, the peak table has no default)
 and the one rule for where the compilation cache lives.
 """
 
@@ -82,18 +82,6 @@ def test_smoke_fails_where_jax_finds_no_accelerator():
     assert "needs 'tpu'" in proc.stderr
 
 
-@pytest.mark.timeout(120)
-def test_bench_has_no_cpu_fallback():
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py")],
-        capture_output=True, text=True, timeout=100, cwd=REPO,
-        env=_child_env(),
-    )
-    assert proc.returncode != 0
-    assert not _json_lines(proc.stdout), proc.stdout
-    assert "no fallback" in proc.stderr
-
-
 def _import_from_root(name: str):
     sys.path.insert(0, REPO)
     try:
@@ -116,40 +104,24 @@ _FACTS = {
 
 
 @pytest.mark.parametrize(
-    "single_steps, log_text, fails_with",
-    [
-        (1, "INFO serving\n", None),
-        (40, "INFO serving\n", "single-step decode dispatches"),
-        (1, "ERROR decode_multi@H4 failed at runtime; degrading to "
-            "single-step\n", "degrading to single-step"),
-        (1, "ERROR background decode_multi@H4 compile failed; staying "
-            "single-step\n", "compile failed"),
-    ],
-    ids=["healthy", "too_many_single_steps", "runtime_degrade", "compile_failed"],
+    "single_steps, fails_with",
+    [(1, None), (40, "single-step decode dispatches")],
+    ids=["healthy", "too_many_single_steps"],
 )
 def test_ledger_check_catches_a_horizon_dropped_mid_run(
-    tmp_path, single_steps, log_text, fails_with
+    single_steps, fails_with
 ):
     """One decode_multi@H4 dispatch is not enough: a run that fell to H=1
     after it must fail too."""
     smoke = _import_from_root("chip_smoke")
-    log = tmp_path / "server.log"
-    log.write_text(log_text)
     goodput = json.loads(json.dumps(_HEALTHY_LEDGER))
     goodput["steps_by_label"]["decode"]["count"] = single_steps
-    args = (goodput, _FACTS, False, 15, str(log))
+    args = (goodput, _FACTS, False, 15)
     if fails_with is None:
         smoke.check_ledger(*args)
     else:
         with pytest.raises(smoke.SmokeFailure, match=fails_with):
             smoke.check_ledger(*args)
-
-
-def test_peak_table_has_no_default():
-    bench = _import_from_root("bench")
-    assert bench.tpu_peak_flops("TPU v5 lite") == bench.TPU_PEAKS["v5e"]
-    with pytest.raises(ValueError, match="no published peak"):
-        bench.tpu_peak_flops("cpu")
 
 
 # ------------------------------------------------- the compilation cache rule

@@ -28,7 +28,7 @@ from dynamo_tpu.protocols.common import (
 
 
 def make_engine(decode_horizon, num_blocks=64, max_batch=4, block_size=4,
-                max_len=64, lazy_horizon=False):
+                max_len=64):
     cfg = L.LlamaConfig.tiny(vocab_size=64)
     params = L.init_params(cfg, jax.random.PRNGKey(0))
     runner = ModelRunner(
@@ -42,7 +42,6 @@ def make_engine(decode_horizon, num_blocks=64, max_batch=4, block_size=4,
             max_batch=max_batch, block_size=block_size,
             num_blocks=num_blocks, max_model_len=max_len,
             watermark_blocks=2, decode_horizon=decode_horizon,
-            lazy_horizon=lazy_horizon,
         ),
     )
 
@@ -202,42 +201,46 @@ async def test_horizon_mixed_batch_and_penalty_fallback():
     assert await run(4) == await run(1)
 
 
-async def test_lazy_horizon_single_steps_then_ramps():
-    """lazy_horizon: the engine single-steps while the decode_multi
-    program AOT-compiles in a background thread, then rides the horizon —
-    same tokens as the eager engine either way (the cold-start saver for
-    opportunistic TPU captures: BENCH_r05 clocked the eager compile at
-    30.4 s of a 46.6 s budget)."""
-    import time
+async def test_failing_horizon_dispatch_ends_the_loop_like_any_other():
+    """A `decode_multi` dispatch that raises is no special case: the loop
+    ends through `_on_loop_done`, every lane gets the structured
+    `engine_loop_crash` error any failing dispatch gives, its blocks are
+    freed, and the engine neither rewrites its configured horizon nor
+    falls back to single steps behind the operator's back."""
+    engine = make_engine(4)
+    single_steps = []
+    orig_decode = engine.runner.decode
 
-    eager = make_engine(4)
-    ref = await collect(eager, greedy_request([5, 9, 17, 23], 24, ignore_eos=True))
-    await eager.close()
-    lazy = make_engine(4, lazy_horizon=True)
-    multi_calls = []
-    orig = lazy.runner.decode_multi
+    def boom(H, *a, **kw):
+        raise RuntimeError("injected horizon failure")
 
-    def spy(H, *a, **kw):
-        multi_calls.append(H)
-        return orig(H, *a, **kw)
+    def spy(*a, **kw):
+        single_steps.append(1)
+        return orig_decode(*a, **kw)
 
-    lazy.runner.decode_multi = spy
-    first = await collect(
-        lazy, greedy_request([5, 9, 17, 23], 24, ignore_eos=True)
+    engine.runner.decode_multi = boom
+    engine.runner.decode = spy
+
+    async def final_of(prompt):
+        last = None
+        async for out in engine.generate(
+            greedy_request(prompt, 12, ignore_eos=True), Context()
+        ):
+            last = out
+        return last
+
+    finals = await asyncio.wait_for(
+        asyncio.gather(final_of([5, 9, 17, 23]), final_of([2, 40, 41])),
+        timeout=60,
     )
-    assert first == ref
-    # the background compile must land (CPU compiles this in seconds)
-    deadline = time.monotonic() + 60
-    while not lazy.runner.decode_multi_ready(4):
-        assert time.monotonic() < deadline, "background compile never landed"
-        await asyncio.sleep(0.05)
-    second = await collect(
-        lazy, greedy_request([5, 9, 17, 23], 24, ignore_eos=True)
-    )
-    await lazy.close()
-    assert second == ref
-    # once ready, the engine actually used the horizon program
-    assert multi_calls and max(multi_calls) == 4
+    for final in finals:
+        assert final.finish_reason is FinishReason.ERROR
+        assert final.error["code"] == "engine_loop_crash"
+        assert "injected horizon failure" in final.error["cause"]
+    assert engine.config.decode_horizon == 4
+    assert not single_steps
+    assert engine.allocator.free_count == engine.config.num_blocks - 1
+    await engine.close()
 
 
 @pytest.mark.slow

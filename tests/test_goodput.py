@@ -119,7 +119,6 @@ def test_disabled_ledger_is_inert(monkeypatch):
     gp.record_waste("spec_rejected", 5)
     gp.record_compile("decode", 2.0)
     gp.record_recompile("decode", "shape_miss")
-    gp.set_perf_gauges(0.4, 1e8)
     assert gp.total_events() == 0
     assert gp.decode_tokens == 0 and gp.occupancy == 0.0
     # the env knob the constructor reads
@@ -153,16 +152,14 @@ def _synthetic_stats(seed: int) -> GoodputStats:
     gp.record_compile("decode", 10.0 + seed)
     if seed % 2:
         gp.record_recompile("decode", "shape_miss", shape="lanes=9")
-    gp.set_perf_gauges(0.1 * (seed + 1), 1e8 * (seed + 1))
     return gp
 
 
 def _assert_stats_equal(a: GoodputStats, b: GoodputStats) -> None:
     da, db = a.to_dict(), b.to_dict()
-    for key in ("st", "ls", "lc", "pt", "dt", "w", "rc", "n", "sh"):
+    for key in ("st", "ls", "lc", "pt", "dt", "w", "rc", "sh"):
         assert da[key] == db[key], key
-    for key in ("bub", "mfu", "hbm"):
-        assert da[key] == pytest.approx(db[key], rel=1e-9), key
+    assert da["bub"] == pytest.approx(db["bub"], rel=1e-9)
     for lbl in set(da["cs"]) | set(db["cs"]):
         assert da["cs"][lbl] == pytest.approx(db["cs"][lbl], rel=1e-9), lbl
 
@@ -193,8 +190,6 @@ def test_merge_associative_and_commutative():
     # merged totals are the sums; compile time is the per-label max
     assert left.steps_total == a.steps_total + b.steps_total + c.steps_total
     assert left.compile_s_by_label["decode"] == 12.0
-    # (sum, n) gauge pairs average correctly after any merge order
-    assert left.mfu_achieved == pytest.approx((0.1 + 0.2 + 0.3) / 3)
 
 
 # --------------------------------------------------- recompile forensics
@@ -483,7 +478,6 @@ async def test_fleet_debug_goodput_matches_direct_merge():
             gp.record_waste("spec_rejected", 10 * (w + 1))
             gp.record_waste("preempt_replay", 5)
             gp.record_compile("decode", 9.0 + w)
-            gp.set_perf_gauges(0.2 + 0.1 * w, 1e8)
             ledgers.append(gp)
             fpm = ForwardPassMetrics(goodput=gp)
             pub = WorkerMetricsPublisher(comp, eid, instance_id=w)
@@ -527,8 +521,6 @@ async def test_fleet_debug_goodput_matches_direct_merge():
         assert fleet["occupancy"] == pytest.approx(direct.occupancy, abs=1e-4)
         # merged compile time is the worst worker's
         assert fleet["compile_s_by_label"]["decode"] == pytest.approx(11.0)
-        # (sum, n) gauges: the fleet MFU is the worker average
-        assert fleet["mfu_achieved"] == pytest.approx(0.3, abs=1e-4)
         # fleet percentiles agree with the pooled samples within the
         # histogram's documented relative error
         pooled = sorted(all_step_ms)
@@ -581,7 +573,6 @@ async def test_mock_worker_metrics_publishes_goodput():
         assert 0.0 < gp.occupancy <= 1.0
         assert gp.waste_by_cause.get("spec_rejected", 0) > 0
         assert "prefill" in gp.compile_s_by_label
-        assert gp.mfu_achieved > 0.0
         await metrics.close()
         await mock.stop()
     finally:

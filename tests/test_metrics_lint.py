@@ -98,8 +98,6 @@ INTENTIONALLY_SHARED = {
     "dyn_llm_tokens_wasted",
     "dyn_llm_recompiles",
     "dyn_llm_compile_seconds",
-    "dyn_llm_mfu_achieved",
-    "dyn_llm_hbm_bytes_per_token_achieved",
     # expert-layer counters of a sparse-expert model (ISSUE 28) ride the
     # same shared goodput surface
     "dyn_llm_moe_layer_steps",
@@ -412,7 +410,7 @@ def test_goodput_families_present_with_correct_types():
     semantics on both the frontend (colocated-engine attach) and the
     metrics component (fleet merge) — step durations as a real histogram,
     waste/recompiles/tokens/bubbles with counter semantics, occupancy and
-    the achieved-efficiency gauges as gauges."""
+    compile time as gauges."""
     regs = _all_registries()
     by_role = {
         role: {f.name: f for f in _families(reg)}
@@ -428,8 +426,6 @@ def test_goodput_families_present_with_correct_types():
             ("dyn_llm_tokens_wasted", "counter"),
             ("dyn_llm_recompiles", "counter"),
             ("dyn_llm_compile_seconds", "gauge"),
-            ("dyn_llm_mfu_achieved", "gauge"),
-            ("dyn_llm_hbm_bytes_per_token_achieved", "gauge"),
         ):
             fam = by_role[role].get(name)
             assert fam is not None and fam.type == typ, (role, name)
@@ -466,28 +462,6 @@ def test_prefix_cache_families_present_with_correct_types():
     for name in ("dyn_llm_kv_pull_plans", "dyn_llm_kv_pull_planned_blocks"):
         fam = by_role["router"].get(name)
         assert fam is not None and fam.type == "counter", name
-
-
-def test_meshed_decode_families_present_with_correct_types():
-    """ISSUE 19: the meshed-decode bandwidth families must exist with the
-    right semantics on the metrics component (fleet merge of the per-worker
-    perf model) — all three are modeled gauges. The tp-collective gauge is
-    component-only: it is derived from worker stats, never from frontend
-    dispatch or router state."""
-    regs = _all_registries()
-    by_role = {
-        role: {f.name: f for f in _families(reg)}
-        for role, reg in regs.items()
-    }
-    for name in (
-        "dyn_llm_decode_hbm_bytes_per_token",
-        "dyn_llm_mfu_decode_est",
-        "dyn_llm_tp_collective_bytes_per_step",
-    ):
-        fam = by_role["component"].get(name)
-        assert fam is not None and fam.type == "gauge", name
-    for role in ("frontend", "router"):
-        assert "dyn_llm_tp_collective_bytes_per_step" not in by_role[role], role
 
 
 def test_decision_families_present_with_correct_types():

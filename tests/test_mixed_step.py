@@ -330,31 +330,3 @@ def test_goodput_mixed_labels_and_phase_gap():
     back = GoodputStats.from_dict(gp2.to_dict())
     assert back.summary() == gp2.summary()
     assert back.summary()["mixed_steps"] == 8
-
-
-def test_perf_model_mixed_step_amortizes_weights():
-    """The HBM model behind the win: a mixed step streams weights once
-    over decode_lanes + chunk_tokens tokens, so the weight term shrinks
-    vs decode-only while KV/activation per-token terms are unchanged."""
-    from dynamo_tpu.engine.jax_engine.perf_model import (
-        decode_hbm_bytes_per_token,
-        mixed_step_hbm_bytes_per_token,
-    )
-    from dynamo_tpu.models import llama as L
-
-    cfg = L.LlamaConfig.tiny(vocab_size=64)
-    base = decode_hbm_bytes_per_token(cfg, batch=4, context=256)
-    mixed = mixed_step_hbm_bytes_per_token(
-        cfg, decode_lanes=4, chunk_tokens=12, context=256
-    )
-    assert mixed.weight_bytes_per_token == pytest.approx(
-        base.weight_bytes_per_token * 4 / 16
-    )
-    assert mixed.kv_bytes_per_token == base.kv_bytes_per_token
-    assert mixed.activation_bytes_per_token == base.activation_bytes_per_token
-    assert mixed.total < base.total
-    # degenerate mixed step (no chunk) collapses to the decode model
-    same = mixed_step_hbm_bytes_per_token(
-        cfg, decode_lanes=4, chunk_tokens=0, context=256
-    )
-    assert same.to_dict() == base.to_dict()

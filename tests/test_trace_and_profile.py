@@ -191,10 +191,28 @@ async def test_kv_router_picks_affinity_on_trace():
     assert hits == total, f"router affinity {hits}/{total}"
 
 
-async def test_profiler_npz_feeds_planner_sla(tmp_path):
+def test_profiler_npz_feeds_planner_sla(tmp_path):
     """profile_sweep (mocker) -> .npz -> interpolators -> Planner SLA mode
     produces scale decisions that grow with demand. The chain the reference
-    runs as profile_sla.py -> planner (load_planner.md:54-56)."""
+    runs as profile_sla.py -> planner (load_planner.md:54-56).
+
+    Runs on the virtual clock: the mocker's 32-token prefill is 0.32 ms of
+    wall time at speedup 10 and its 512-token one 5.4 ms, so on the wall
+    clock one scheduling stall of the host reorders the prefill curve."""
+    from dynamo_tpu.runtime import clock as dclock
+    from dynamo_tpu.testing.sim import SimClock, SimEventLoop
+
+    sim_clock = SimClock()
+    prev_clock = dclock.set_clock(sim_clock)
+    loop = SimEventLoop(sim_clock)
+    try:
+        loop.run_until_complete(_profile_then_plan(tmp_path))
+    finally:
+        loop.close()
+        dclock.set_clock(prev_clock)
+
+
+async def _profile_then_plan(tmp_path):
     from dynamo_tpu.planner.perf_interpolation import (
         DecodeInterpolator,
         PrefillInterpolator,
@@ -215,8 +233,10 @@ async def test_profiler_npz_feeds_planner_sla(tmp_path):
     save_npz(path, prof)
     pre = PrefillInterpolator.from_npz(path)
     dec = DecodeInterpolator.from_npz(path)
-    # sanity: monotone-ish prefill curve, positive throughputs
-    assert pre.ttft(512) > pre.ttft(32) > 0
+    # the prefill curve is the mocker's cost model: a*n + b*n^2, and the
+    # 10 ms decode step that samples the first token
+    assert pre.ttft(32) == pytest.approx(3.2 + 0.01024 + 10.0, rel=1e-6)
+    assert pre.ttft(512) == pytest.approx(51.2 + 2.62144 + 10.0, rel=1e-6)
     assert dec.throughput(0.4) > 0
 
     conn = VirtualConnector()

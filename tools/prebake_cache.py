@@ -187,7 +187,7 @@ def prebake(args) -> dict:
             ),
         ),
     )
-    # the unrolled decode horizon (the 30-60 s compile lazy_horizon dodges)
+    # the unrolled decode horizon (the largest compile of the set)
     H = args.decode_horizon
     if H > 1:
         bake(
