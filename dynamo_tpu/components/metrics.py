@@ -440,6 +440,18 @@ def goodput_families(
             "device, fetched with the tokens; fleet sum)",
             value=float(moe.get(name, 0.0)),
         )
+    sampler = gp.sampler if gp is not None else {}
+    for name, what in (
+        ("dispatches", "decode-family dispatches"),
+        ("pool_dispatches", "decode-family dispatches whose batch held a "
+         "sampled lane with top_k or top_p, so that every step computed "
+         "the sampler's candidate pool"),
+    ):
+        yield CounterMetricFamily(
+            f"{PREFIX}_sampler_{name}",
+            f"Sampler: {what} (counted on the host; fleet sum)",
+            value=float(sampler.get(name, 0)),
+        )
     comp = GaugeMetricFamily(
         f"{PREFIX}_compile_seconds",
         "First-dispatch (compile-inclusive) wall time per dispatch label "

@@ -35,6 +35,7 @@ from dynamo_tpu.engine.jax_engine.kv_cache import (
     SequenceState,
 )
 from dynamo_tpu.engine.jax_engine.model_runner import ModelRunner
+from dynamo_tpu.ops.sampling import draw_restrictions
 from dynamo_tpu.pipeline.context import Context, decisions_of
 from dynamo_tpu.protocols.common import (
     FinishReason,
@@ -713,7 +714,14 @@ class JaxEngine:
         self._device_lock. `lanes`/`capacity` (decode-family steps) and
         `tokens` (prefill chunk size) feed the goodput ledger;
         `ctx_tokens` (summed context of the live lanes) and `horizon` ride
-        the `loop.dispatch` phase into an open profile window."""
+        the `loop.dispatch` phase into an open profile window, and so does
+        `pool`: whether a decode-family dispatch (`capacity` given; its
+        lane arrays were packed just before) holds a sampled lane that
+        restricts its draw, the sampler's own predicate asked of the same
+        three arrays the program is about to receive."""
+        pool = capacity > 0 and bool(
+            draw_restrictions(self._temps, self._top_ps, self._top_ks)[1]
+        )
         slow_factor = 1.0
         if faults.active():
             inj = faults.get_injector()
@@ -736,6 +744,7 @@ class JaxEngine:
                 "loop.dispatch", label=label, lanes=lanes,
                 ctx_tokens=ctx_tokens, prefill_tokens=tokens,
                 horizon=horizon, first=label not in self._dispatch_ema,
+                pool=int(pool),
             ):
                 result = await loop.run_in_executor(None, run)
             if slow_factor > 1.0:
@@ -790,6 +799,8 @@ class JaxEngine:
                     prefill_tokens=tokens,
                     t_start=t0,
                 )
+                if capacity > 0:
+                    gp.record_sampler(pool)
                 if dtrace.enabled():
                     dtrace.counter("step_ms", elapsed * 1e3)
                     if capacity > 0:

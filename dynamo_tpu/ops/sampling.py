@@ -191,15 +191,30 @@ def mask_eos_logits(
 
 # Candidate-pool width for top-k/top-p filtering. Two full [B, V] sorts
 # per step (tens of ms at 128k vocab) are replaced by one lax.top_k(C)
-# pass over a descending candidate pool. Rows with NO restriction
-# (top_k<=0 and top_p>=1) bypass the pool entirely — they draw a full
-# categorical over the temperature-scaled vocab, so the default sampling
-# distribution stays exact at any temperature. Restricted rows are exact
-# whenever their support fits the pool (always true for vocab <= C and
-# any top_k <= C; a nucleus is truncated to the pool only if its mass
-# extends past the top 256 temperature-scaled candidates — ~1e-4 mass on
-# real models near temp 1); top_k > C clamps to C.
+# pass over a descending candidate pool, and that pass (6 to 7 ms a step at
+# 152k vocab on a v5e) runs only in a step that holds a sampled row with a
+# restriction: `sample_tokens` puts the pool under one lax.cond. Rows with
+# NO restriction (top_k<=0 and top_p>=1) bypass the pool entirely, in the
+# result and in the cost — they draw a full categorical over the
+# temperature-scaled vocab, so the default sampling distribution stays
+# exact at any temperature. Restricted rows are exact whenever their
+# support fits the pool (always true for vocab <= C and any top_k <= C; a
+# nucleus is truncated to the pool only if its mass extends past the top
+# 256 temperature-scaled candidates — ~1e-4 mass on real models near
+# temp 1); top_k > C clamps to C.
 SAMPLE_CANDIDATES = 256
+
+
+def draw_restrictions(temperature, top_p, top_k):
+    """(unrestricted [B] bool, need_pool scalar bool) of one batch's
+    sampling parameters: which rows draw over the full vocab, and whether
+    any sampled row (temperature > 0) draws from the candidate pool. Plain
+    array arithmetic, so the device (jax arrays, inside `sample_tokens`)
+    and the host (the engine's numpy lane arrays, for its counter) decide
+    by the same rule."""
+    wide_nucleus = (top_k <= 0) & (top_p >= 0.99) & (temperature > 1.25)
+    unrestricted = ((top_k <= 0) & (top_p >= 1.0)) | wide_nucleus
+    return unrestricted, ((temperature > 0.0) & ~unrestricted).any()
 
 
 def _filtered_candidates(
@@ -253,31 +268,39 @@ def sample_tokens(
     no top_k) and temperature > 1.25 are routed to the full-vocab draw
     instead — trading the top 1% tail cut (which high temperature makes
     ill-defined anyway) for no pool truncation.
+
+    What is computed when: the argmax and the full-vocab draw always, for
+    every row. The pool (top_k over the vocab, its masks, softmax,
+    cumulative sum, its own draw and the map back to vocab ids) only when
+    the batch holds a sampled row (temperature > 0) that restricts its
+    draw — one lax.cond on a predicate reduced from the three parameter
+    arrays, so a greedy row's top_k or an idle lane costs the batch no
+    pool. Every row's token is the same either way: a row's draw reads
+    its own key and its own parameters only.
     """
     greedy_ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     temp = jnp.maximum(temperature, 1e-6)[:, None]
     scaled = logits / temp
-    vals, idx = _filtered_candidates(scaled, top_p, top_k)
-    wide_nucleus = (top_k <= 0) & (top_p >= 0.99) & (temperature > 1.25)
-    unrestricted = ((top_k <= 0) & (top_p >= 1.0)) | wide_nucleus  # [B]
+    unrestricted, need_pool = draw_restrictions(temperature, top_p, top_k)
     if keys is not None:
-        def draw(kd, pool_lg, full_lg):
-            k = jax.random.wrap_key_data(kd.astype(jnp.uint32))
-            return (
-                jax.random.categorical(k, pool_lg),
-                jax.random.categorical(k, full_lg),
-            )
+        def draw(lg):  # [B, N] -> [B]: row i from its own key
+            def row(kd, row_lg):
+                k = jax.random.wrap_key_data(kd.astype(jnp.uint32))
+                return jax.random.categorical(k, row_lg)
 
-        choice, full_choice = jax.vmap(draw)(keys, vals, scaled)
+            return jax.vmap(row)(keys, lg).astype(jnp.int32)
     else:
-        choice = jax.random.categorical(rng, vals, axis=-1)
-        full_choice = jax.random.categorical(rng, scaled, axis=-1)
-    pool_sampled = jnp.take_along_axis(
-        idx, choice[:, None].astype(jnp.int32), axis=-1
-    )[:, 0]
-    sampled = jnp.where(
-        unrestricted, full_choice.astype(jnp.int32), pool_sampled.astype(jnp.int32)
-    )
+        def draw(lg):
+            return jax.random.categorical(rng, lg, axis=-1).astype(jnp.int32)
+
+    full_choice = draw(scaled)
+
+    def through_pool():
+        vals, idx = _filtered_candidates(scaled, top_p, top_k)
+        pool_sampled = jnp.take_along_axis(idx, draw(vals)[:, None], axis=-1)[:, 0]
+        return jnp.where(unrestricted, full_choice, pool_sampled)
+
+    sampled = jax.lax.cond(need_pool, through_pool, lambda: full_choice)
     return jnp.where(temperature <= 0.0, greedy_ids, sampled)
 
 

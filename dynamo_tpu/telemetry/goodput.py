@@ -85,6 +85,12 @@ MOE_COUNTERS = (
     "layer_steps", "assignments", "experts_touched", "max_expert_load",
 )
 
+# the sampler's candidate pool (`ops.sampling.sample_tokens`): decode-family
+# dispatches, and those among them whose batch held a sampled lane that
+# restricts its draw (top_k or top_p), so that the device took the pool's
+# branch in every step of the dispatch; counted on the host, before the call
+SAMPLER_COUNTERS = ("dispatches", "pool_dispatches")
+
 
 class GoodputStats:
     """Mergeable goodput snapshot (the wire/aggregate half).
@@ -110,6 +116,7 @@ class GoodputStats:
         "recompiles",
         "compile_s_by_label",
         "moe",
+        "sampler",
     )
 
     def __init__(self) -> None:
@@ -149,6 +156,8 @@ class GoodputStats:
         # the decode horizons fetched so far (MOE_COUNTERS; empty for a
         # model without experts)
         self.moe: dict[str, float] = {}
+        # SAMPLER_COUNTERS
+        self.sampler: dict[str, int] = {}
 
     # ------------------------------------------------------------- query
 
@@ -210,6 +219,8 @@ class GoodputStats:
                 )
         for k, v in other.moe.items():
             self.moe[k] = self.moe.get(k, 0.0) + v
+        for k, v in other.sampler.items():
+            self.sampler[k] = self.sampler.get(k, 0) + v
 
     def copy(self) -> "GoodputStats":
         out = GoodputStats()
@@ -236,6 +247,7 @@ class GoodputStats:
             "rc": dict(self.recompiles),
             "cs": {k: round(v, 4) for k, v in self.compile_s_by_label.items()},
             "moe": dict(self.moe),
+            "smp": dict(self.sampler),
         }
 
     @classmethod
@@ -265,6 +277,9 @@ class GoodputStats:
         for k, v in (d.get("moe") or {}).items():
             if k in MOE_COUNTERS:
                 out.moe[k] = float(v)
+        for k, v in (d.get("smp") or {}).items():
+            if k in SAMPLER_COUNTERS:
+                out.sampler[k] = int(v)
         return out
 
     # ------------------------------------------------------------- debug
@@ -301,6 +316,7 @@ class GoodputStats:
                 k: round(v, 3) for k, v in self.compile_s_by_label.items()
             },
             "moe": {k: self.moe.get(k, 0.0) for k in MOE_COUNTERS},
+            "sampler": {k: self.sampler.get(k, 0) for k in SAMPLER_COUNTERS},
         }
 
 
@@ -392,6 +408,17 @@ class GoodputLedger(GoodputStats):
             return
         for k in MOE_COUNTERS:
             self.moe[k] = self.moe.get(k, 0.0) + float(counted.get(k, 0.0))
+
+    def record_sampler(self, pool: bool) -> None:
+        """One decode-family dispatch; `pool` says whether its lanes made
+        the device compute the sampler's candidate pool."""
+        if not self.enabled:
+            return
+        self.sampler["dispatches"] = self.sampler.get("dispatches", 0) + 1
+        if pool:
+            self.sampler["pool_dispatches"] = (
+                self.sampler.get("pool_dispatches", 0) + 1
+            )
 
     def record_decode_tokens(self, n: int = 1) -> None:
         if self.enabled:

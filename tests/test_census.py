@@ -124,6 +124,33 @@ def test_stats_frame_without_optional_sections_parses_to_defaults():
     assert GoodputStats.from_dict(old).summary() == gp.summary()
 
 
+def test_ledger_sampler_slot_survives_the_wire_and_a_merge():
+    """The `sampler` slot is a wire field like the others: it round-trips,
+    two workers' counts add, a frame of a version without it reads as
+    zeros, and a counter this version does not declare is dropped."""
+    a, b = GoodputLedger(enabled=True), GoodputLedger(enabled=True)
+    for pool in (True, False, False):
+        a.record_sampler(pool)
+    b.record_sampler(True)
+    want = {"dispatches": 3, "pool_dispatches": 1}
+    assert a.summary()["sampler"] == want
+    wire = a.to_dict()
+    assert GoodputStats.from_dict(wire).summary()["sampler"] == want
+    merged = GoodputStats.from_dict(wire)
+    merged.merge(GoodputStats.from_dict(b.to_dict()))
+    assert merged.summary()["sampler"] == {"dispatches": 4, "pool_dispatches": 2}
+    assert merged.copy().summary()["sampler"] == merged.summary()["sampler"]
+    without = {k: v for k, v in wire.items() if k != "smp"}
+    assert GoodputStats.from_dict(without).summary()["sampler"] == {
+        "dispatches": 0, "pool_dispatches": 0,
+    }
+    later = {**wire, "smp": {**wire["smp"], "counter_of_a_later_version": 7}}
+    assert GoodputStats.from_dict(later).summary()["sampler"] == want
+    off = GoodputLedger(enabled=False)
+    off.record_sampler(True)
+    assert off.summary()["sampler"] == {"dispatches": 0, "pool_dispatches": 0}
+
+
 # ------------------------------------------- the surfaces the gauges were on
 
 

@@ -104,6 +104,10 @@ INTENTIONALLY_SHARED = {
     "dyn_llm_moe_assignments",
     "dyn_llm_moe_experts_touched",
     "dyn_llm_moe_max_expert_load",
+    # the sampler's candidate pool (ISSUE 32): how often a dispatch's lanes
+    # made the device compute it; the same shared goodput surface
+    "dyn_llm_sampler_dispatches",
+    "dyn_llm_sampler_pool_dispatches",
     # decision provenance plane (ISSUE 20): every control-plane process
     # (frontend, metrics component, standalone router) exports its OWN
     # ledger's decision counts — decisions are made where they are

@@ -448,19 +448,20 @@ def operations(text: str) -> dict[str, int]:
 
 
 # Read by this same code: the number of operations of each program, and the
-# digest of its text. `prefill_packed` as on be020f0 (PR 26), which PR 28 and
-# PR 29 left alone; `decode_multi` and `mixed_step` as PR 29 made them (a
-# decode lane whose slot lies in the null block gets a context of 0 and zero
-# rows: 79 and 25 operations more than PR 26's 3285/3245 and 2138/2120). A
-# change to the grouped-query block's programs moves them; say so in PERF.md
-# and re-read.
+# digest of its text, as PR 32 made them: every call of the sampler grew by 18
+# operations (the predicate over the lanes' parameters, the `stablehlo.case`
+# around the candidate pool, a draw written once for each of its two uses),
+# so `prefill_packed` holds 18 more than on be020f0 (PR 26: 1063 and 1051),
+# `mixed_step` 36 and `decode_multi` 72 more than PR 29 made them (2163/2145
+# and 3364/3324). A change to the grouped-query block's programs or to the
+# sampler moves them; say so in PERF.md and re-read.
 PARENT_PROGRAMS = {
-    ("mistral", "decode_multi"): (3364, "66355b442d6792c1"),
-    ("mistral", "mixed_step"): (2163, "339e09805555aa11"),
-    ("mistral", "prefill_packed"): (1063, "2d4861943e06bb5c"),
-    ("qwen", "decode_multi"): (3324, "4af935575defef24"),
-    ("qwen", "mixed_step"): (2145, "ea60cf71bfe4c20e"),
-    ("qwen", "prefill_packed"): (1051, "86fae05c7d6063c1"),
+    ("mistral", "decode_multi"): (3436, "e31214c00676d142"),
+    ("mistral", "mixed_step"): (2199, "2f70c43ab8d77599"),
+    ("mistral", "prefill_packed"): (1081, "002d06ff6d7b7c8c"),
+    ("qwen", "decode_multi"): (3396, "266df7a64fe5c088"),
+    ("qwen", "mixed_step"): (2181, "56edd216864b2281"),
+    ("qwen", "prefill_packed"): (1069, "71bca7b45392e674"),
 }
 
 

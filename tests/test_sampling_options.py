@@ -106,6 +106,217 @@ def test_sample_tokens_full_logprob_surface():
     assert (np.diff(tlps, axis=1) <= 1e-6).all()
 
 
+# ------------------------------------------- the pool under its conditional
+
+
+def _two_draw_sample_tokens(logits, rng, temperature, top_p, top_k, keys=None):
+    """`sample_tokens` as it was before the candidate pool moved under a
+    `lax.cond` (PR 32): the pool and both draws for every row of every
+    call, the choice by `where`. The reference of the identity tests."""
+    import jax.numpy as jnp
+
+    from dynamo_tpu.ops.sampling import _filtered_candidates
+
+    greedy_ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    temp = jnp.maximum(temperature, 1e-6)[:, None]
+    scaled = logits / temp
+    vals, idx = _filtered_candidates(scaled, top_p, top_k)
+    wide_nucleus = (top_k <= 0) & (top_p >= 0.99) & (temperature > 1.25)
+    unrestricted = ((top_k <= 0) & (top_p >= 1.0)) | wide_nucleus
+    if keys is not None:
+        def draw(kd, pool_lg, full_lg):
+            k = jax.random.wrap_key_data(kd.astype(jnp.uint32))
+            return (
+                jax.random.categorical(k, pool_lg),
+                jax.random.categorical(k, full_lg),
+            )
+
+        choice, full_choice = jax.vmap(draw)(keys, vals, scaled)
+    else:
+        choice = jax.random.categorical(rng, vals, axis=-1)
+        full_choice = jax.random.categorical(rng, scaled, axis=-1)
+    pool_sampled = jnp.take_along_axis(
+        idx, choice[:, None].astype(jnp.int32), axis=-1
+    )[:, 0]
+    sampled = jnp.where(
+        unrestricted, full_choice.astype(jnp.int32), pool_sampled.astype(jnp.int32)
+    )
+    return jnp.where(temperature <= 0.0, greedy_ids, sampled)
+
+
+_LANES = 8
+
+# name -> (vocabulary, temperature, top_p, top_k), a scalar for every lane
+# or one value a lane
+DRAW_CASES = {
+    "all_unrestricted_at_0.7": (1024, 0.7, 1.0, 0),
+    "all_greedy": (1024, 0.0, 1.0, 0),
+    "greedy_lanes_that_carry_a_top_k": (
+        1024, [0.0, 0.7, 0.0, 0.7, 0.7, 0.0, 0.7, 0.7], 1.0,
+        [5, 0, 40, 0, 0, 1, 0, 0],
+    ),
+    "one_top_k_lane_among_unrestricted": (
+        1024, 0.7, 1.0, [0, 0, 0, 20, 0, 0, 0, 0],
+    ),
+    "one_top_p_0.9_lane": (
+        1024, 0.7, [1.0, 1.0, 0.9, 1.0, 1.0, 1.0, 1.0, 1.0], 0,
+    ),
+    "top_k_and_top_p_together": (
+        1024, [0.7, 1.0, 0.7, 0.0, 1.2, 0.7, 0.7, 0.3],
+        [0.8, 1.0, 0.9, 0.5, 0.95, 1.0, 0.7, 0.9],
+        [20, 0, 300, 7, 50, 3, 0, 1],
+    ),
+    "wide_nucleus_above_1.25": (
+        1024, [1.5, 1.3, 1.25, 2.0, 0.7, 1.5, 1.5, 0.0],
+        [0.99, 0.995, 0.99, 0.98, 0.99, 1.0, 0.99, 0.99],
+        [0, 0, 0, 0, 0, 0, 4, 0],
+    ),
+    "vocabulary_smaller_than_the_pool": (
+        100, [0.7, 0.0, 1.0, 0.7, 0.7, 1.3, 0.7, 0.7],
+        [1.0, 1.0, 0.9, 1.0, 0.5, 1.0, 1.0, 0.8],
+        [0, 3, 0, 150, 0, 0, 10, 99],
+    ),
+}
+
+
+def _draw_inputs(case: str, seed: int = 0):
+    import jax.numpy as jnp
+
+    V, temp, top_p, top_k = DRAW_CASES[case]
+    lanes = lambda v, dtype: jnp.asarray(np.broadcast_to(v, (_LANES,)), dtype)
+    logits = 3.0 * np.random.default_rng(seed).normal(size=(_LANES, V))
+    keys = np.stack([make_key_data(11 + i, 5 * i + seed) for i in range(_LANES)])
+    return (
+        jnp.asarray(logits, jnp.float32), lanes(temp, jnp.float32),
+        lanes(top_p, jnp.float32), lanes(top_k, jnp.int32), jnp.asarray(keys),
+    )
+
+
+@pytest.mark.parametrize("per_lane_keys", [True, False], ids=["keys", "rng"])
+@pytest.mark.parametrize("case", list(DRAW_CASES))
+def test_sample_tokens_is_the_two_draw_sampler_bit_for_bit(case, per_lane_keys):
+    """Whatever the batch holds, every lane gets the token the sampler gave
+    it when the pool was computed for all of them: an unrestricted lane its
+    full-vocabulary draw, a restricted one the pool's, a greedy one its
+    argmax, each from its own key row."""
+    import jax.numpy as jnp
+
+    for seed in range(3):
+        logits, temp, top_p, top_k, keys = _draw_inputs(case, seed)
+        rng = jax.random.PRNGKey(seed)
+        kw = {"keys": keys} if per_lane_keys else {}
+        want = _two_draw_sample_tokens(logits, rng, temp, top_p, top_k, **kw)
+        got = jax.jit(sample_tokens)(logits, rng, temp, top_p, top_k, **kw)
+        assert got.dtype == jnp.int32
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        if not per_lane_keys:
+            continue
+        # the log-prob surface hangs on the token alone and is as it was
+        full = sample_tokens_full(logits, None, temp, top_p, top_k, keys=keys)
+        logz = jax.nn.log_softmax(logits, axis=-1)
+        top_lps, top_ids = jax.lax.top_k(logz, 20)
+        chosen = jnp.take_along_axis(logz, want[:, None], axis=-1)[:, 0]
+        for g, w in zip(full, (want, chosen, top_ids, top_lps)):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize(
+    "neighbour", [{"top_k": 20}, {"top_p": 0.9}, {"temperature": 0.0, "top_k": 5}],
+    ids=["top_k", "top_p", "greedy_with_top_k"],
+)
+def test_a_lanes_token_does_not_depend_on_its_neighbours(neighbour):
+    """What per-lane keys promise: an unrestricted lane draws the same
+    token whether or not the lane beside it restricts its draw (and so
+    whether or not the batch took the pool's branch)."""
+    logits, temp, top_p, top_k, keys = _draw_inputs("all_unrestricted_at_0.7")
+    alone = np.asarray(sample_tokens(logits, None, temp, top_p, top_k, keys=keys))
+    temp = temp.at[3].set(neighbour.get("temperature", 0.7))
+    top_p = top_p.at[3].set(neighbour.get("top_p", 1.0))
+    top_k = top_k.at[3].set(neighbour.get("top_k", 0))
+    beside = np.asarray(sample_tokens(logits, None, temp, top_p, top_k, keys=keys))
+    others = np.arange(_LANES) != 3
+    np.testing.assert_array_equal(beside[others], alone[others])
+
+
+def _sub_jaxprs(eqn):
+    for v in eqn.params.values():
+        for x in v if isinstance(v, (tuple, list)) else (v,):
+            if hasattr(x, "eqns"):  # Jaxpr
+                yield x
+            elif hasattr(x, "jaxpr") and hasattr(x.jaxpr, "eqns"):  # ClosedJaxpr
+                yield x.jaxpr
+
+
+def _primitives(jaxpr, out=None) -> list[str]:
+    """Every primitive of a jaxpr and of what it calls, by name."""
+    out = [] if out is None else out
+    for eqn in jaxpr.eqns:
+        out.append(eqn.primitive.name)
+        for sub in _sub_jaxprs(eqn):
+            _primitives(sub, out)
+    return out
+
+
+@pytest.mark.parametrize("per_lane_keys", [True, False], ids=["keys", "rng"])
+def test_the_pool_is_inside_one_conditional(per_lane_keys):
+    """The cost, not only the result: one `cond`; the `top_k` of the pool,
+    the cumulative sum and the pool's draw are in one branch and nowhere
+    else; the other branch hands the full-vocabulary draw through."""
+    from dynamo_tpu.ops.sampling import SAMPLE_CANDIDATES
+
+    logits, temp, top_p, top_k, keys = _draw_inputs("all_unrestricted_at_0.7")
+    kw = {"keys": keys} if per_lane_keys else {}
+    jaxpr = jax.make_jaxpr(
+        lambda *a: sample_tokens(a[0], jax.random.PRNGKey(0), *a[1:], **kw)
+    )(logits, temp, top_p, top_k).jaxpr
+    conds = [e for e in jaxpr.eqns if e.primitive.name == "cond"]
+    assert len(conds) == 1 and _primitives(jaxpr).count("cond") == 1
+    selection = {"top_k", "sort", "cumsum", "cumlogsumexp", "cummax"}
+    outside = [
+        e.primitive.name for e in jaxpr.eqns if e.primitive.name != "cond"
+    ]
+    for e in jaxpr.eqns:
+        if e.primitive.name != "cond":
+            for sub in _sub_jaxprs(e):
+                _primitives(sub, outside)
+    assert not selection & set(outside), outside
+    branches = [_primitives(b.jaxpr) for b in conds[0].params["branches"]]
+    with_pool = [b for b in branches if "top_k" in b]
+    without = [b for b in branches if "top_k" not in b]
+    assert len(with_pool) == 1 and len(without) == 1
+    assert "cumsum" in with_pool[0]
+    assert not selection & set(without[0])
+    assert not {"random_bits", "threefry2x32", "argmax"} & set(without[0])
+    pool_top_k = [
+        e for b in conds[0].params["branches"]
+        for e in b.jaxpr.eqns if e.primitive.name == "top_k"
+    ]
+    assert [e.params["k"] for e in pool_top_k] == [SAMPLE_CANDIDATES]
+
+
+def test_draw_restrictions_is_one_rule_for_host_and_device():
+    """The engine's counter asks numpy arrays what `sample_tokens` asks the
+    device's: the same function, the same answers."""
+    import jax.numpy as jnp
+
+    from dynamo_tpu.ops.sampling import draw_restrictions
+
+    for case in DRAW_CASES:
+        _, temp, top_p, top_k, _ = _draw_inputs(case)
+        on_host = draw_restrictions(*map(np.asarray, (temp, top_p, top_k)))
+        on_device = draw_restrictions(temp, top_p, top_k)
+        np.testing.assert_array_equal(on_host[0], np.asarray(on_device[0]))
+        assert bool(on_host[1]) == bool(on_device[1]), case
+    needs = {c: bool(draw_restrictions(*_draw_inputs(c)[1:4])[1]) for c in DRAW_CASES}
+    assert needs == {
+        "all_unrestricted_at_0.7": False, "all_greedy": False,
+        "greedy_lanes_that_carry_a_top_k": False,
+        "one_top_k_lane_among_unrestricted": True, "one_top_p_0.9_lane": True,
+        "top_k_and_top_p_together": True, "wide_nucleus_above_1.25": True,
+        "vocabulary_smaller_than_the_pool": True,
+    }
+
+
 # ---------------------------------------------------------------- engine
 
 
@@ -238,3 +449,42 @@ async def test_min_tokens_suppresses_eos():
     assert reason2 is FinishReason.LENGTH
     assert len(full) == 6
     await engine.close()
+
+
+@pytest.mark.parametrize("restricted", [True, False], ids=["one_top_p", "none"])
+async def test_ledger_counts_the_dispatches_that_needed_the_pool(restricted):
+    """`/debug/goodput`'s `sampler` slot (the ledger's summary) and its
+    Prometheus twin: two lanes that draw over the whole vocabulary, and
+    for a few tokens one with `top_p` 0.9 beside them."""
+    from prometheus_client import generate_latest
+
+    from dynamo_tpu.http.metrics import ServiceMetrics
+
+    engine = make_engine(max_batch=4)
+    try:
+        reqs = [
+            sampled_request([3, 1, 4, 1, 5], 24, temperature=0.7, seed=1),
+            sampled_request([9, 2, 6, 5], 24, temperature=0.7, seed=2),
+        ]
+        if restricted:
+            reqs.append(
+                sampled_request([2, 7, 1, 8], 4, temperature=0.7, top_p=0.9, seed=3)
+            )
+        out = await asyncio.gather(*(collect(engine, r) for r in reqs))
+        assert [len(t) for t, _ in out] == [24, 24, 4][: len(reqs)]
+        summary = engine.stats.goodput.summary()
+        counted, by_label = summary["sampler"], summary["steps_by_label"]
+        assert counted["dispatches"] == sum(
+            v["count"] for k, v in by_label.items() if not k.startswith("prefill")
+        ) > 0
+        if restricted:
+            assert 0 < counted["pool_dispatches"] < counted["dispatches"]
+        else:
+            assert counted["pool_dispatches"] == 0
+        metrics = ServiceMetrics()
+        metrics.attach_goodput({"goodput": engine.stats.goodput}, None)
+        text = generate_latest(metrics.registry).decode()
+        for name, value in counted.items():
+            assert f"dyn_llm_sampler_{name}_total {float(value)}" in text
+    finally:
+        await engine.close()

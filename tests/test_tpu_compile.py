@@ -391,6 +391,58 @@ def test_decode_multi_program_one_chip(one_chip):
     assert compiled.as_text().count("tpu_custom_call") >= 8
 
 
+def _pool_selections(text: str) -> tuple[int, int, int]:
+    """(conditionals of the compiled module, selections of the sampler's
+    candidate pool inside their branches, and outside them): what runs only
+    where a predicate says so, and what in every step. The selection is the
+    instruction whose result is the pair `lax.top_k(scaled, 256)` returns
+    (float32 values and int32 ids, `[B, SAMPLE_CANDIDATES]` each; a
+    projection's `[64, 256]` alone is not it). A branch is a computation a
+    `conditional` names, and whatever that computation calls."""
+    from dynamo_tpu.ops.sampling import SAMPLE_CANDIDATES as C
+
+    pair = rf" = \(f32\[{B},{C}\]\S*, s32\[{B},{C}\]"
+    blocks = {
+        m.group(1): m.group(0)
+        for m in re.finditer(
+            r"^(?:ENTRY )?%?([\w.\-]+) [^\n]*\{\n.*?^\}", text, re.M | re.S
+        )
+    }
+    calls = lambda body: set(
+        re.findall(r"%([\w.\-]+)", " ".join(re.findall(
+            r"(?:calls|to_apply|branch_computations|body|condition)=\{?([^}\n]*?)[,}\n]",
+            body,
+        )))
+    ) & set(blocks)
+    conditionals = re.findall(r"branch_computations=\{([^}]*)\}", text)
+    branch, todo = set(), {
+        n for names in conditionals for n in re.findall(r"%([\w.\-]+)", names)
+    }
+    while todo:
+        name = todo.pop()
+        branch.add(name)
+        todo |= calls(blocks[name]) - branch
+    found = {
+        inside: sum(
+            len(re.findall(pair, body))
+            for name, body in blocks.items() if (name in branch) == inside
+        )
+        for inside in (True, False)
+    }
+    return len(conditionals), found[True], found[False]
+
+
+def test_sampler_pool_is_a_conditional_one_chip(one_chip):
+    """`decode_multi@H4B64` keeps the sampler's candidate pool as a branch:
+    the chip's compiler leaves one conditional a step, each with the
+    `top_k` of `SAMPLE_CANDIDATES` in a branch, and none outside. Were the
+    conditional flattened to a select, every step would pay the selection
+    over the vocabulary again (6 to 7 ms of a 21 ms step at a vocabulary of
+    152,064: ledger, PR 30)."""
+    text = _lower_decode_multi(one_chip).compile().as_text()
+    assert _pool_selections(text) == (4, 4, 0)
+
+
 def test_mixed_step_program_one_chip(one_chip):
     """mixed_step@c1: one 512-token prefill chunk ahead of the decode batch."""
     compiled = _lower_mixed_step(one_chip).compile()
@@ -549,6 +601,9 @@ def test_decode_program_tp4(tp4):
     compiled = lowered.compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "all-reduce" in text
+    # the sampler's pool is a branch here too: its predicate is a reduction
+    # of replicated lane parameters, the same on every shard
+    assert _pool_selections(text) == (1, 1, 0)
     # heads sharded four ways: each device holds a quarter of the cache
     per_device = compiled.memory_analysis().argument_size_in_bytes
     full_cache = 2 * cfg.num_layers * int(np.prod(layer_shape)) * 2
