@@ -452,6 +452,20 @@ def goodput_families(
             f"Sampler: {what} (counted on the host; fleet sum)",
             value=float(sampler.get(name, 0)),
         )
+    ssm = gp.ssm if gp is not None else {}
+    for name, what in (
+        ("layer_steps", "(recurrent layer, decode step) pairs"),
+        ("slots_live", "live lanes, each a state slot, summed over decode "
+         "steps"),
+        ("slot_resets", "sequences whose state slot a prefill program "
+         "zeroed at position 0"),
+        ("scan_tokens", "prompt tokens through the prefill scans"),
+    ):
+        yield CounterMetricFamily(
+            f"{PREFIX}_ssm_{name}",
+            f"Recurrent layers: {what} (counted on the host; fleet sum)",
+            value=float(ssm.get(name, 0)),
+        )
     comp = GaugeMetricFamily(
         f"{PREFIX}_compile_seconds",
         "First-dispatch (compile-inclusive) wall time per dispatch label "

@@ -481,6 +481,11 @@ class JaxEngine:
         # Removed is only published when the LAST holder frees (the router
         # tree would otherwise lose blocks other sequences still cache)
         self._hash_refs: dict[int, int] = {}
+        # layers that keep one state slot a sequence instead of rows per
+        # token (what the runner's model declares; 0 for a paged-only model):
+        # their prefill calls are told each sequence's lane slot, and no
+        # block hash is published (`_emit_stored`)
+        self._recurrent_layers = getattr(self.runner, "recurrent_layers", 0)
         # persistent host-side decode arrays
         B = self.config.max_batch
         self._tokens = np.zeros(B, np.int32)
@@ -709,6 +714,7 @@ class JaxEngine:
         tokens: int = 0,
         ctx_tokens: int = 0,
         horizon: int = 1,
+        state_resets: int = 0,
     ) -> Any:
         """Run one device dispatch in the executor, visible to the
         stuck-horizon watchdog (and to fault injection). Callers hold
@@ -719,7 +725,11 @@ class JaxEngine:
         `pool`: whether a decode-family dispatch (`capacity` given; its
         lane arrays were packed just before) holds a sampled lane that
         restricts its draw, the sampler's own predicate asked of the same
-        three arrays the program is about to receive."""
+        three arrays the program is about to receive. For a model with
+        recurrent layers `state_slots` (lanes that hold a sequence, and so a
+        state) rides the phase too, and the ledger's `ssm` slot counts the
+        dispatch from the same host-side numbers; `state_resets` is how many
+        sequences start at position 0 in it."""
         pool = capacity > 0 and bool(
             draw_restrictions(self._temps, self._top_ps, self._top_ks)[1]
         )
@@ -753,6 +763,10 @@ class JaxEngine:
                 ctx_tokens=ctx_tokens, prefill_tokens=tokens,
                 horizon=horizon, first=first,
                 pool=int(pool),
+                state_slots=(
+                    sum(s is not None for s in self.slots)
+                    if self._recurrent_layers else 0
+                ),
             ):
                 result = await loop.run_in_executor(None, run)
             if slow_factor > 1.0:
@@ -813,6 +827,12 @@ class JaxEngine:
                 )
                 if capacity > 0:
                     gp.record_sampler(pool)
+                if self._recurrent_layers:
+                    gp.record_ssm(
+                        self._recurrent_layers,
+                        decode_steps=horizon if capacity > 0 else 0,
+                        lanes=lanes, resets=state_resets, scan_tokens=tokens,
+                    )
                 if dtrace.enabled():
                     dtrace.counter("step_ms", elapsed * 1e3)
                     if capacity > 0:
@@ -959,8 +979,11 @@ class JaxEngine:
     # ------------------------------------------------------------- events
 
     def _emit_stored(self, seq: _Sequence) -> None:
-        """Publish hash-chain events for newly completed blocks."""
-        if seq.hash_seq is None:
+        """Publish hash-chain events for newly completed blocks. None for
+        a model with a recurrent layer: a router that sent a request here
+        for its prefix would find keys and values for the attention layers
+        and no state for the others."""
+        if seq.hash_seq is None or self._recurrent_layers:
             return
         new = seq.hash_seq.blocks[seq.emitted_hashes :]
         for b in new:
@@ -1417,6 +1440,15 @@ class JaxEngine:
         ]
         self._spawn_tracked(self._offload_task(owned, hash_block))
 
+    def _slot_kw(self, seqs: list[_Sequence]) -> dict:
+        """`state_slots`, the keyword that tells a prefill call of the runner
+        the lane slot of each sequence it holds, for a model that keeps a
+        state there; nothing for any other (and for a runner that never
+        heard of slots)."""
+        if not self._recurrent_layers:
+            return {}
+        return {"state_slots": [s.slot for s in seqs]}
+
     def _try_admit(self, seq: _Sequence) -> bool:
         """Allocate blocks + a slot and run prefill. False if no capacity."""
         free_slots = [i for i, s in enumerate(self.slots) if s is None]
@@ -1753,9 +1785,11 @@ class JaxEngine:
                             key_data=key_row,
                             eos_ids=seq.eos_row,
                             eos_suppress=seq.needs_eos_suppress,
+                            **self._slot_kw([seq]),
                         )
                     ),
                     tokens=len(replay),
+                    state_resets=1,
                 )
             with dtrace.phase("loop.emit"):
                 # the admission pass may have prebuilt the identical chain
@@ -1828,7 +1862,9 @@ class JaxEngine:
                 )
                 for s in group
             ]
-            packed = self.runner.pack_prefill(specs)
+            packed = self.runner.pack_prefill(
+                specs, **self._slot_kw(group)
+            )
         async with self._device_lock:
             sample = await self._dispatch(
                 "prefill_packed",
@@ -1836,6 +1872,7 @@ class JaxEngine:
                     self.runner.prefill_packed_arrays(**packed)
                 ),
                 tokens=sum(len(s.token_ids) for s in group),
+                state_resets=len(group),
             )
         with dtrace.phase("loop.emit"):
             toks, lps, tids, tlps = sample
@@ -1878,11 +1915,13 @@ class JaxEngine:
                     rep_pen=seq.rep_pen, key_data=key_row,
                     eos_ids=seq.eos_row,
                     eos_suppress=seq.needs_eos_suppress,
+                    **self._slot_kw([seq]),
                 )
                 return self.runner.fetch_sample(out) if final else None
 
             sample = await self._dispatch(
-                "prefill_chunk", run_chunk, tokens=len(chunk)
+                "prefill_chunk", run_chunk, tokens=len(chunk),
+                state_resets=int(start == 0),
             )
         with dtrace.phase("loop.emit"):
             if seq.spans:
@@ -2006,6 +2045,7 @@ class JaxEngine:
                     self._block_tables, self._slot_indices, self._keys,
                     self._temps, self._top_ps, self._top_ks,
                     eos_ids=eos_ids, eos_suppress=eos_sup,
+                    **self._slot_kw([p[0] for p in packed]),
                 )
                 fetch: list = []
                 for i in final_slots:
@@ -2017,6 +2057,7 @@ class JaxEngine:
                 f"mixed_step@c{k}", run_mixed,
                 lanes=len(active), capacity=B, tokens=tokens_packed,
                 ctx_tokens=ctx_tokens,
+                state_resets=sum(start == 0 for _, start, _ in packed),
             )
         with dtrace.phase("loop.emit"):
             final_samples = {

@@ -17,7 +17,10 @@ from dynamo_tpu.engine.jax_engine.engine import JaxEngine, JaxEngineConfig
 from dynamo_tpu.engine.jax_engine.model_runner import ModelRunner
 from dynamo_tpu.engine.jax_engine.weights import load_or_init_params
 from dynamo_tpu.model_card import ModelDeploymentCard
-from dynamo_tpu.models import cache_kind, config_from_model_dir, forward_for
+from dynamo_tpu.models import (
+    cache_kind, config_from_model_dir, forward_for, layer_cache_kinds,
+    recurrent_layers,
+)
 from dynamo_tpu.runtime.logging import get_logger
 
 logger = get_logger("dynamo_tpu.engine.factory")
@@ -219,36 +222,79 @@ def refuse_unsupported(
     config, *, quantize: bool = False, kv_dtype: str = "bf16",
     meshed: bool = False, fused_decode: bool = False,
 ) -> None:
-    """Say at start-up, in words, what a model whose layers keep a latent
-    plane is not served with yet; nothing falls back in silence. A
-    grouped-query model passes untouched."""
+    """Say at start-up, in words, what a model is not served with yet;
+    nothing falls back in silence. A grouped-query model passes untouched.
+
+    A model whose layers keep a latent plane (`models/mla_moe.py`) is served
+    in bfloat16 on one chip: no int8 weights, no int8-resident cache, no
+    mesh, no fused decode step, no block-manager tiers, no speculative
+    decoding. A model with a recurrent layer (`models/hybrid_ssm.py`: a
+    state slot a sequence beside paged keys and values) is served the same
+    way and refuses the same six and, besides, disaggregated transfer, peer
+    pulls and live handoff (`ModelRunner.require_block_transfer`); prefix
+    reuse it simply does not offer (the engine publishes no block hashes
+    for it)."""
+    n = recurrent_layers(config)
     kind = cache_kind(config)
-    if kind.name == "kv_heads":
+    if n:
+        what = (
+            f"keeps a recurrent state a sequence in {n} of its "
+            f"{config.num_layers} layers"
+        )
+        why = {
+            "int8_weights": "not implemented for state-space mixers",
+            "int8_cache": "the state is float32 and the pages beside it are "
+            "served in bfloat16",
+            "mesh": "the state's channels have no sharding rule yet",
+            "fused_decode": "its kernels are the grouped-query block's",
+            "tiers": "and with them prefix reuse: a block of keys and values "
+            "without the state at its boundary cannot resume a sequence, and "
+            "no state snapshots are kept yet",
+            "speculation": "a rejected draft would need the state rolled back",
+        }
+    elif kind.name != "kv_heads":
+        what = f"keeps a {kind.name} cache of {kind.width} values a token"
+        why = {
+            "int8_weights": "not implemented for expert stacks",
+            "int8_cache": "its scales are kept by head, and a latent plane "
+            "has none",
+            "mesh": "the latent plane has no head axis to shard and the "
+            "experts' share has no add-up test yet",
+            "fused_decode": "its kernels are the grouped-query block's",
+            "tiers": "a tier's layout is keys and values by head",
+            "speculation": "no verify program for latent attention",
+        }
+    else:
         return
     asked = {
-        "int8 weights (DYN_JAX_QUANTIZE_INT8): not implemented for expert "
-        "stacks": quantize,
-        "an int8-resident cache (DYN_KV_DTYPE=int8): its scales are kept by "
-        "head, and a latent plane has none": kv_dtype == "int8",
-        "a tensor-, expert-, data- or context-parallel mesh or several "
-        "hosts: the latent plane has no head axis to shard and the experts' "
-        "share has no add-up test yet": meshed,
-        "the fused decode step (DYN_FUSED_DECODE): its kernels are the "
-        "grouped-query block's": fused_decode,
-        "block-manager tiers (DYN_KV_HOST_OFFLOAD_GB): a tier's layout is "
-        "keys and values by head": float(
-            os.environ.get("DYN_KV_HOST_OFFLOAD_GB", "0") or 0
-        ) > 0,
-        "speculative decoding (DYN_SPEC_K): no verify program for latent "
-        "attention": spec_decode_settings()["spec_k"] > 0,
+        "int8_weights": quantize,
+        "int8_cache": kv_dtype == "int8",
+        "mesh": meshed,
+        "fused_decode": fused_decode,
+        "tiers": float(os.environ.get("DYN_KV_HOST_OFFLOAD_GB", "0") or 0) > 0,
+        "speculation": spec_decode_settings()["spec_k"] > 0,
     }
-    refused = [what for what, on in asked.items() if on]
+    refused = [
+        f"{_OPTION_WORDS[option]}: {why[option]}"
+        for option, on in asked.items() if on
+    ]
     if refused:
         raise ValueError(
-            f"{type(config).__name__} keeps a {kind.name} cache of "
-            f"{kind.width} values a token and is served in bfloat16 on one "
-            "chip; not implemented for it: " + "; ".join(refused)
+            f"{type(config).__name__} {what} and is served in bfloat16 on "
+            "one chip; not implemented for it: " + "; ".join(refused)
         )
+
+
+# an option `refuse_unsupported` may refuse, in the words its refusal uses
+_OPTION_WORDS = {
+    "int8_weights": "int8 weights (DYN_JAX_QUANTIZE_INT8)",
+    "int8_cache": "an int8-resident cache (DYN_KV_DTYPE=int8)",
+    "mesh": "a tensor-, expert-, data- or context-parallel mesh or several "
+    "hosts",
+    "fused_decode": "the fused decode step (DYN_FUSED_DECODE)",
+    "tiers": "block-manager tiers (DYN_KV_HOST_OFFLOAD_GB)",
+    "speculation": "speculative decoding (DYN_SPEC_K)",
+}
 
 
 def _built_facts(engine: JaxEngine) -> dict:
@@ -507,12 +553,19 @@ def default_num_blocks(
         if kv_dtype == "int8"
         else 0
     )
+    # a block holds rows of the layers that keep rows (all of them, but in
+    # a model with recurrent layers); those layers' slots, one a lane and
+    # the null lane's, come off the budget first
+    kinds = layer_cache_kinds(config)
+    paged_layers = sum(k.name != "recurrent" for k in kinds)
+    slot_bytes = (max_batch + 1) * sum(k.slot_bytes for k in kinds)
+    scale_bytes = scale_bytes * paged_layers // config.num_layers
     block_bytes = (
-        config.num_layers * block_size
+        paged_layers * block_size
         * kind.stored_values_per_token(tp) * kv_itemsize
         + scale_bytes
     )
-    budget = int(hbm_budget_bytes() * utilization) - weight_bytes
+    budget = int(hbm_budget_bytes() * utilization) - weight_bytes - slot_bytes
     cap = max(16, budget // max(1, block_bytes))
     if want > cap:
         logger.warning(

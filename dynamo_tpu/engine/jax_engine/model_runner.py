@@ -21,7 +21,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from dynamo_tpu.models import cache_kind, forward_for
+from dynamo_tpu.models import (
+    cache_kind, forward_for, layer_cache_kinds, recurrent_layers,
+)
 from dynamo_tpu.ops.sampling import (
     MAX_EOS_IDS,
     apply_penalties,
@@ -54,6 +56,13 @@ def default_prefill_buckets(block_size: int, max_len: int) -> list[int]:
     if not buckets or buckets[-1] != top:
         buckets.append(top)
     return buckets
+
+
+def _slots(state_slots) -> dict:
+    """The keyword a family with a recurrent layer takes in its prefill
+    forwards: the lane slot of each sequence in the call. None (every other
+    family: an empty tree, so their programs do not see it) gives nothing."""
+    return {} if state_slots is None else {"state_slots": state_slots}
 
 
 def unrolled_steps(step, init, H: int):
@@ -129,6 +138,9 @@ class ModelRunner:
         from dynamo_tpu.ops.attention import _pallas_tileable
 
         kind = cache_kind(config)
+        # what each layer declares it keeps: rows per token in pages (`kind`),
+        # or one slot a lane (a recurrent state)
+        kinds = layer_cache_kinds(config)
         if attn_impl == "pallas" and not _pallas_tileable(
             kind.stored_width, block_size
         ):
@@ -184,8 +196,14 @@ class ModelRunner:
         # one array per layer and plane, head-major: each (head, page) is a
         # contiguous [bs, D] tile (what the pallas kernel streams; TP shards
         # the leading head axis). What a layer keeps is the config's
-        # declaration: keys and values by head, or one latent plane
+        # declaration: keys and values by head, or one latent plane; a
+        # recurrent layer keeps two arrays indexed by lane slot in their
+        # place, with one slot more than lanes: the null lane's, which
+        # padding writes to as it does to block 0
         self.cache_kind = kind
+        self.layer_kinds = kinds
+        self.recurrent_layers = recurrent_layers(config)
+        self.state_slots = max_batch + 1 if self.recurrent_layers else 0
         layer_shape = (kind.heads, num_blocks, block_size, kind.stored_width)
         from dynamo_tpu.ops import kv_quant
 
@@ -215,24 +233,41 @@ class ModelRunner:
         kv_shard_tree = kv_quant.cache_sharding(
             kv_sharding, config.num_layers, self.kv_quantized
         )
-        make_zeros = lambda: kv_quant.make_cache(
-            config.num_layers, layer_shape, self.kv_dtype,
-            quantized=self.kv_quantized,
-        )
+        def make_zeros(which: int):  # 0: keys or state; 1: values or tail
+            pages = iter(kv_quant.make_cache(
+                len(kinds) - self.recurrent_layers, layer_shape, self.kv_dtype,
+                quantized=self.kv_quantized,
+            ))
+            return tuple(
+                jnp.zeros(
+                    (self.state_slots,) + tuple(k.slot[which][0]),
+                    k.slot[which][1],
+                ) if k.name == "recurrent" else next(pages)
+                for k in kinds
+            )
+
         if kv_sharding is not None:
             # allocate ON device under the sharding (works single- and
             # multi-controller; never materializes host zeros)
-            make_zeros = jax.jit(make_zeros, out_shardings=kv_shard_tree)
-        if kind.planes == 1 and (self.kv_quantized or mesh is not None):
+            make_zeros = jax.jit(
+                make_zeros, static_argnums=0, out_shardings=kv_shard_tree
+            )
+        if (kind.planes == 1 or self.state_slots) and (
+            self.kv_quantized or mesh is not None
+        ):
+            what = (
+                "a model with a recurrent layer" if self.state_slots
+                else f"a {kind.name} cache"
+            )
             raise ValueError(
-                f"a {kind.name} cache is served in bfloat16 on one chip: an "
+                f"{what} is served in bfloat16 on one chip: an "
                 "int8-resident cache (DYN_KV_DTYPE=int8) and a mesh are not "
                 "implemented for it"
             )
-        self.k_cache = make_zeros()
-        self.v_cache = make_zeros() if kind.planes == 2 else ()
+        self.k_cache = make_zeros(0)
+        self.v_cache = make_zeros(1) if kind.planes == 2 else ()
         logger.info(
-            "kv cache: %d blocks x %d tokens (%s), %.2f GiB",
+            "kv cache: %d blocks x %d tokens (%s), %.2f GiB%s",
             num_blocks,
             block_size,
             "int8+scales" if self.kv_quantized else str(
@@ -240,6 +275,10 @@ class ModelRunner:
             ),
             (kv_quant.cache_nbytes(self.k_cache)
              + kv_quant.cache_nbytes(self.v_cache)) / 2**30,
+            f", of it {self.state_slots} state slots in "
+            f"{self.recurrent_layers} recurrent layers, "
+            f"{self.state_slots * sum(k.slot_bytes for k in kinds) / 2**30:.2f} GiB"
+            if self.state_slots else "",
         )
         self._kv_sharding = kv_sharding
         self._kv_shard_tree = kv_shard_tree
@@ -484,10 +523,12 @@ class ModelRunner:
         cfg, attn_mesh, attn_head_axis,
         params, k_cache, v_cache, tokens, valid_len, block_table,
         key_data, temp, top_p, top_k, rep_pen, eos_ids, eos_suppress,
+        state_slots=None,
     ):
         logits, k_cache, v_cache = forward_for(cfg).prefill(
             params, cfg, tokens, valid_len, k_cache, v_cache, block_table,
             mesh=attn_mesh, attn_head_axis=attn_head_axis,
+            **_slots(state_slots),
         )
         out = ModelRunner._sample_one(
             logits, tokens, valid_len, key_data, temp, top_p, top_k, rep_pen,
@@ -535,11 +576,11 @@ class ModelRunner:
     def _prefill_chunk_impl(
         cfg, mesh, params, k_cache, v_cache, tokens, chunk_start, valid_len,
         block_table, key_data, temp, top_p, top_k, rep_pen, eos_ids,
-        eos_suppress,
+        eos_suppress, state_slots=None,
     ):
         logits, k_cache, v_cache = forward_for(cfg).prefill_chunk(
             params, cfg, tokens, chunk_start, valid_len,
-            k_cache, v_cache, block_table, mesh=mesh,
+            k_cache, v_cache, block_table, mesh=mesh, **_slots(state_slots),
         )
         # repetition penalty sees this chunk's tokens only (earlier chunks
         # already left the program); documented approximation for the FIRST
@@ -555,11 +596,11 @@ class ModelRunner:
     def _prefill_packed_impl(
         cfg, mesh, params, k_cache, v_cache, tokens, positions, segment_ids,
         slot_indices, last_idx, keys, temps, top_ps, top_ks, rep_pens,
-        eos_ids, eos_suppress,
+        eos_ids, eos_suppress, state_slots=None,
     ):
         logits, k_cache, v_cache = forward_for(cfg).prefill_packed(
             params, cfg, tokens, positions, segment_ids, slot_indices,
-            k_cache, v_cache, last_idx, mesh=mesh,
+            k_cache, v_cache, last_idx, mesh=mesh, **_slots(state_slots),
         )
         logits = apply_repetition_penalty_packed(
             logits, tokens, segment_ids, rep_pens
@@ -942,12 +983,12 @@ class ModelRunner:
         ids and suppress=False the mask is a bitwise no-op, keeping one
         compiled program per k instead of per sampling-feature set."""
         outs = []
-        for (c_tokens, c_start, c_valid, c_table, c_key, c_temp, c_top_p,
-             c_top_k, c_rep, c_eos, c_sup) in chunk_args:
+        for chunk in chunk_args:
+            # a chunk's tuple is `_prefill_chunk_impl`'s arguments behind the
+            # caches, in its order (`mixed_step` builds it); the sequence's
+            # lane slot is the last, where the model keeps a state there
             c_out, k_cache, v_cache = ModelRunner._prefill_chunk_impl(
-                cfg, attn_mesh, params, k_cache, v_cache, c_tokens, c_start,
-                c_valid, c_table, c_key, c_temp, c_top_p, c_top_k, c_rep,
-                c_eos, c_sup,
+                cfg, attn_mesh, params, k_cache, v_cache, *chunk
             )
             outs.extend(c_out)
         d_out, k_cache, v_cache = ModelRunner._decode_eos_impl(
@@ -990,6 +1031,7 @@ class ModelRunner:
         top_ps, top_ks,
         eos_ids: Optional[np.ndarray] = None,  # [B, MAX_EOS_IDS] i32
         eos_suppress: Optional[np.ndarray] = None,  # [B] bool
+        state_slots: Optional[list[int]] = None,  # each chunk's lane slot
     ) -> tuple[tuple, tuple]:
         """One unified mixed step: the decode batch plus ``chunks`` packed
         prefill-chunk slots in a single dispatch. Chunks of one sequence
@@ -1007,9 +1049,10 @@ class ModelRunner:
         chunk) and one for the decode batch."""
         C = self.prefill_chunk_tokens
         dev_chunks = []
-        for (token_chunk, chunk_start, total_len, block_ids, temperature,
-             top_p, top_k, rep_pen, key_data, c_eos_ids,
-             c_eos_suppress) in chunks:
+        slots = self._lane_slots(state_slots, len(chunks))
+        for i, (token_chunk, chunk_start, total_len, block_ids, temperature,
+                top_p, top_k, rep_pen, key_data, c_eos_ids,
+                c_eos_suppress) in enumerate(chunks):
             n = len(token_chunk)
             ctoks = np.zeros(C, np.int32)
             ctoks[:n] = token_chunk
@@ -1031,6 +1074,7 @@ class ModelRunner:
                 self._to_dev(np.float32(rep_pen)),
                 self._to_dev(np.asarray(c_eos_ids, np.int32)),
                 self._to_dev(np.bool_(c_eos_suppress)),
+                *(() if slots is None else (self._to_dev(slots[i]),)),
             ))
         B = len(np.asarray(tokens))
         if eos_ids is None:
@@ -1082,6 +1126,20 @@ class ModelRunner:
             # float32 trap for consumers that index/serialize with them)
             outs.append(np.asarray(piece, dtype=o.dtype))
         return tuple(outs)
+
+    def _lane_slots(self, slots, n: int) -> Optional[np.ndarray]:
+        """[n] int32 lane slots for a prefill call, or None for a model
+        that keeps no slot a sequence. Such a model's prefill writes each
+        sequence's state where its decode steps will read it, so it must
+        be told."""
+        if not self.state_slots:
+            return None
+        if slots is None or len(slots) != n:
+            raise ValueError(
+                "this model keeps a recurrent state a sequence: a prefill "
+                "call must name the lane slot of each sequence it holds"
+            )
+        return np.asarray(slots, np.int32)
 
     def _next_key_data(self) -> np.ndarray:
         """Default per-call RNG stream: raw threefry key data built on the
@@ -1153,6 +1211,7 @@ class ModelRunner:
         key_data: Optional[np.ndarray] = None,
         eos_ids: Optional[np.ndarray] = None,  # [MAX_EOS_IDS] i32, -1 pad
         eos_suppress: bool = False,  # min_tokens not yet reached
+        state_slots: Optional[list[int]] = None,  # [the sequence's lane slot]
     ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
         """Run one prompt; returns (token, logprob, top_ids, top_logprobs)
         device arrays for the first sampled token."""
@@ -1189,6 +1248,7 @@ class ModelRunner:
             self._to_dev(np.float32(rep_pen)),
             self._to_dev(np.asarray(eos_ids, np.int32)),
             self._to_dev(np.bool_(eos_suppress)),
+            *self._slot_args(state_slots),
         )
         return out
 
@@ -1265,6 +1325,7 @@ class ModelRunner:
         key_data: Optional[np.ndarray] = None,
         eos_ids: Optional[np.ndarray] = None,
         eos_suppress: bool = False,
+        state_slots: Optional[list[int]] = None,  # [the sequence's lane slot]
     ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
         """Run one chunk of a chunked prefill; chunks must arrive in order.
 
@@ -1296,8 +1357,15 @@ class ModelRunner:
             self._to_dev(np.float32(rep_pen)),
             self._to_dev(np.asarray(eos_ids, np.int32)),
             self._to_dev(np.bool_(eos_suppress)),
+            *self._slot_args(state_slots),
         )
         return out
+
+    def _slot_args(self, state_slots) -> tuple:
+        """The trailing argument of a one-sequence prefill call: its lane
+        slot on the device, or nothing."""
+        slots = self._lane_slots(state_slots, 1)
+        return () if slots is None else (self._to_dev(slots[0]),)
 
     def embed(self, token_ids: list[int]) -> np.ndarray:
         """Pooled sequence embedding (llama.embed_pooled), bucket-padded;
@@ -1323,7 +1391,9 @@ class ModelRunner:
         )
         return self._fetch(out)
 
-    def pack_prefill(self, seqs: list[tuple]) -> dict[str, np.ndarray]:
+    def pack_prefill(
+        self, seqs: list[tuple], state_slots: Optional[list[int]] = None,
+    ) -> dict[str, np.ndarray]:
         """Pure host-side packing for the batched-prefill program.
 
         seqs: [(token_ids, block_ids, temp, top_p, top_k, rep_pen,
@@ -1364,17 +1434,24 @@ class ModelRunner:
             eos_ids[i] = er
             eos_suppress[i] = sup
             off += T
-        return dict(
+        packed = dict(
             tokens=tokens, positions=positions, segment_ids=segment_ids,
             slot_indices=slot_indices, last_idx=last_idx, temps=temps,
             top_ps=top_ps, top_ks=top_ks, rep_pens=rep_pens, keys=keys,
             eos_ids=eos_ids, eos_suppress=eos_suppress,
         )
+        slots = self._lane_slots(state_slots, len(seqs))
+        if slots is not None:
+            # a segment that holds no prompt keeps slot 0 here; the program
+            # sends what it computes for it to the null lane
+            packed["state_slots"] = np.zeros(N, np.int32)
+            packed["state_slots"][: len(seqs)] = slots
+        return packed
 
     def prefill_packed_arrays(
         self, tokens, positions, segment_ids, slot_indices, last_idx,
         temps, top_ps, top_ks, rep_pens, keys, eos_ids=None,
-        eos_suppress=None,
+        eos_suppress=None, state_slots=None,
     ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
         """Run the packed batched-prefill program (arrays from
         pack_prefill). Returns (tokens, logprobs, top_ids, top_lps), each
@@ -1392,6 +1469,7 @@ class ModelRunner:
             self._to_dev(temps), self._to_dev(top_ps), self._to_dev(top_ks),
             self._to_dev(rep_pens), self._to_dev(np.asarray(eos_ids, np.int32)),
             self._to_dev(np.asarray(eos_suppress, bool)),
+            *(() if state_slots is None else (self._to_dev(state_slots),)),
         )
         return out
 
@@ -1399,6 +1477,14 @@ class ModelRunner:
         """Blocks leave and enter the cache as `[L, Hkv, n, bs, D]` pairs of
         keys and values (disagg frames, block-manager tiers, peer pulls):
         refuse in words for a cache that keeps another kind of plane."""
+        if self.state_slots:
+            raise ValueError(
+                f"{what} moves cache blocks as keys and values by head; "
+                f"{self.recurrent_layers} of this model's "
+                f"{len(self.layer_kinds)} layers keep a "
+                "recurrent state a sequence and no block, which is not "
+                "carried through transfer or tiers yet"
+            )
         if self.cache_kind.planes != 2:
             raise ValueError(
                 f"{what} moves cache blocks as keys and values by head; "

@@ -254,4 +254,79 @@ def load_hf_safetensors(
     return params
 
 
-LOADERS = {"llama": load_hf_safetensors, "mla_moe": load_latent_moe_safetensors}
+def load_hybrid_ssm_safetensors(
+    model_dir: str,
+    config: Any,  # models.hybrid_ssm.HybridSsmConfig
+    *,
+    quantize: bool = False,
+    dtype: jnp.dtype = jnp.bfloat16,
+) -> Any:
+    """The hybrid state-space family's checkpoint names (Hugging Face
+    Jamba's): `input_layernorm`, `pre_ff_layernorm`, `feed_forward.{gate,up,
+    down}_proj`; an attention layer's `self_attn.{q,k,v,o}_proj`; a Mamba
+    layer's `mamba.{in_proj, conv1d, x_proj, dt_proj, out_proj}`, `mamba.A_log`
+    and `mamba.D` (float32, `[d_inner, d_state]`: held transposed here, as
+    the state is), `mamba.{dt,b,c}_layernorm`; `model.final_layernorm`. The
+    convolution's weight is `[d_inner, 1, d_conv]`; here `[d_conv, d_inner]`."""
+    forward_for(config).refuse_int8_weights(quantize)
+    tensors = _read_safetensors(model_dir)
+    c = config
+
+    def get(name: str, as_dtype=dtype) -> jax.Array:
+        return jnp.asarray(tensors.pop(name)).astype(as_dtype)
+
+    def lin(name: str) -> jax.Array:  # HF stores [out, in]; we use [in, out]
+        return get(name).T
+
+    layers = []
+    for i in range(c.num_layers):
+        p = f"model.layers.{i}."
+        layer = {"mix_norm": get(p + "input_layernorm.weight")}
+        if c.is_attn_layer(i):
+            a = p + "self_attn."
+            layer.update(
+                wq=lin(a + "q_proj.weight"), wk=lin(a + "k_proj.weight"),
+                wv=lin(a + "v_proj.weight"), wo=lin(a + "o_proj.weight"),
+            )
+        else:
+            m = p + "mamba."
+            layer.update(
+                w_in=lin(m + "in_proj.weight"),
+                conv_w=get(m + "conv1d.weight")[:, 0, :].T,
+                conv_b=get(m + "conv1d.bias"),
+                w_x=lin(m + "x_proj.weight"),
+                dt_norm=get(m + "dt_layernorm.weight"),
+                b_norm=get(m + "b_layernorm.weight"),
+                c_norm=get(m + "c_layernorm.weight"),
+                w_dt=lin(m + "dt_proj.weight"),
+                b_dt=get(m + "dt_proj.bias", jnp.float32),
+                A_log=get(m + "A_log", jnp.float32).T,
+                D=get(m + "D", jnp.float32),
+                w_out=lin(m + "out_proj.weight"),
+            )
+        f = p + "feed_forward."
+        layer.update(
+            mlp_norm=get(p + "pre_ff_layernorm.weight"),
+            wg=lin(f + "gate_proj.weight"), wu=lin(f + "up_proj.weight"),
+            wd=lin(f + "down_proj.weight"),
+        )
+        layers.append(layer)
+    params: dict[str, Any] = {
+        "embed": get("model.embed_tokens.weight"),
+        "layers": layers,
+        "final_norm": get("model.final_layernorm.weight"),
+    }
+    if not c.tie_word_embeddings and "lm_head.weight" in tensors:
+        params["lm_head"] = lin("lm_head.weight")
+    tensors.pop("lm_head.weight", None)  # a tied head written out again
+    logger.info(
+        "loaded %d layers from %s (%d tensors left in the files)",
+        len(layers), model_dir, len(tensors),
+    )
+    return params
+
+
+LOADERS = {
+    "llama": load_hf_safetensors, "mla_moe": load_latent_moe_safetensors,
+    "hybrid_ssm": load_hybrid_ssm_safetensors,
+}

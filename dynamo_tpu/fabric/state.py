@@ -21,6 +21,7 @@ from __future__ import annotations
 import asyncio
 import functools
 import inspect
+import time
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Callable, Optional
@@ -193,14 +194,38 @@ class FabricState:
             self._janitor.cancel()
             self._janitor = None
 
+    # a janitor pass this late means the loop that runs the store was held
+    # (a profiler closing its window, a long synchronous call): see below
+    JANITOR_TICK_S = 0.5
+    STALL_S = 1.0
+
     async def _janitor_loop(self) -> None:
-        """Expire dead leases and redeliver unacked queue messages."""
+        """Expire dead leases and redeliver unacked queue messages.
+
+        A store that did not run cannot have counted time: when this loop
+        wakes `STALL_S` or more behind its tick (measured on the machine's
+        own clock, so a test that jumps the process clock still expires its
+        leases), every lease is given the time the store was held back, as
+        a healed blackout gives a grace. Without it a process that serves
+        from its own in-process store fences itself whenever its interpreter
+        is held for a lease's TTL, although nobody else could have seen it
+        missing: the keepalive and this janitor wake together and the janitor
+        may run first."""
         from dynamo_tpu.testing import faults
 
         was_dark = False
+        tick = self.JANITOR_TICK_S
         try:
             while True:
-                await asyncio.sleep(0.5)
+                slept_at = time.monotonic()
+                await asyncio.sleep(tick)
+                held = time.monotonic() - slept_at - tick
+                if held >= self.STALL_S:
+                    logger.info(
+                        "the store's loop was held for %.1fs; every lease "
+                        "gets that long", held,
+                    )
+                    self.extend_all_leases(held)
                 if faults.active():
                     inj = faults.get_injector()
                     if inj is not None and inj.fabric_unreachable():
@@ -563,6 +588,12 @@ class FabricState:
         floor = dclock.now() + grace
         for lease in self.leases.values():
             lease.deadline = max(lease.deadline, floor)
+
+    def extend_all_leases(self, by: float) -> None:
+        """Push every lease's deadline out by `by` seconds (the time this
+        store did not run)."""
+        for lease in self.leases.values():
+            lease.deadline += by
 
     def apply_replicated(self, op: str, a: dict, result) -> None:
         """Apply one journaled mutation from the primary."""

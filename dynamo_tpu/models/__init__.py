@@ -5,8 +5,10 @@ A family is one module that holds its config class and its forward
 functions (`init_params`, `param_count`, `prefill`, `prefill_chunk`,
 `prefill_packed`, `decode`, ...) under the same names and signatures; the
 runner asks `forward_for(config)` for the module and knows no family. A
-config declares the kind of cache its layers keep (`cache_kind()`), and the
-allocator, the block budget and the row scatter derive from the declaration.
+config declares what each of its layers keeps (`layer_cache_kinds`): rows per
+token in hashable blocks (keys and values by head, or one latent plane), or
+one slot of fixed size a sequence (a recurrent state); the allocator, the
+block budget and the row scatter derive from the declaration.
 """
 
 from __future__ import annotations
@@ -14,6 +16,7 @@ from __future__ import annotations
 import contextlib
 import functools
 import json
+import math
 import os
 import sys
 import threading
@@ -25,20 +28,33 @@ import jax.numpy as jnp
 
 @dataclass(frozen=True)
 class CacheKind:
-    """What one layer keeps per cached token.
+    """What one layer keeps.
 
-    `kv_heads`: two planes (keys, values) of `heads` x `width`.
-    `latent`: one plane of one row: `width` values (compressed latent, then
-    the shared rope key), stored `stored_width` wide (zeros behind)."""
+    Per cached token, in blocks: `kv_heads`, two planes (keys, values) of
+    `heads` x `width`; `latent`, one plane of one row: `width` values
+    (compressed latent, then the shared rope key), stored `stored_width`
+    wide (zeros behind).
+    Per sequence, whatever its length: `recurrent`, no rows per token; one
+    slot a lane, `slot` its arrays as (shape, dtype name) pairs (a first and
+    a second: they ride where a paged layer's two planes do)."""
 
-    name: str  # "kv_heads" | "latent"
+    name: str  # "kv_heads" | "latent" | "recurrent"
     planes: int
     heads: int
     width: int
     stored_width: int
+    slot: tuple = ()
 
     def stored_values_per_token(self, tp: int = 1) -> int:
         return self.planes * max(1, self.heads // tp) * self.stored_width
+
+    @property
+    def slot_bytes(self) -> int:
+        """Bytes of one lane's slot (0 for a paged kind)."""
+        return sum(
+            math.prod(shape) * jnp.dtype(dtype).itemsize
+            for shape, dtype in self.slot
+        )
 
 
 def kv_heads_cache(num_kv_heads: int, head_dim: int) -> CacheKind:
@@ -49,13 +65,37 @@ def latent_cache(width: int, lanes: int = 128) -> CacheKind:
     return CacheKind("latent", 1, 1, width, -(-width // lanes) * lanes)
 
 
-def cache_kind(config) -> CacheKind:
-    """The declaration of `config`'s layers; a config without one is the
-    grouped-query family's."""
-    declared = getattr(config, "cache_kind", None)
+def recurrent_state(*arrays: tuple) -> CacheKind:
+    """A layer that keeps one fixed-size slot a sequence: `arrays` are the
+    (shape, dtype name) of what a lane holds."""
+    return CacheKind("recurrent", 0, 0, 0, 0, tuple(arrays))
+
+
+def layer_cache_kinds(config) -> tuple[CacheKind, ...]:
+    """One kind a layer: what the config declares (`layer_cache_kinds`), or
+    `num_layers` copies of the one kind it declares for layers that are all
+    alike (`cache_kind`); a config that declares nothing is the grouped-query
+    family's."""
+    declared = getattr(config, "layer_cache_kinds", None)
     if declared is not None:
-        return declared()
-    return kv_heads_cache(config.num_kv_heads, config.head_dim)
+        return tuple(declared())
+    alike = getattr(config, "cache_kind", None)
+    kind = (
+        alike() if alike is not None
+        else kv_heads_cache(config.num_kv_heads, config.head_dim)
+    )
+    return (kind,) * config.num_layers
+
+
+def cache_kind(config) -> CacheKind:
+    """What `config`'s layers keep per cached token: the kind of the first
+    layer that keeps rows at all (the paged layers of one model are alike)."""
+    return next(k for k in layer_cache_kinds(config) if k.name != "recurrent")
+
+
+def recurrent_layers(config) -> int:
+    """How many of `config`'s layers keep a slot a sequence."""
+    return sum(k.name == "recurrent" for k in layer_cache_kinds(config))
 
 
 _watching = threading.local()
@@ -117,9 +157,18 @@ def config_from_model_dir(model_dir: str):
     """The config of the family that `config.json`'s `model_type` names."""
     with open(os.path.join(model_dir, "config.json")) as f:
         hf = json.load(f)
-    from dynamo_tpu.models import mla_moe
-    from dynamo_tpu.models.llama import LlamaConfig
+    from dynamo_tpu.models import hybrid_ssm, llama, mla_moe
 
-    if hf.get("model_type") in mla_moe.MODEL_TYPES:
+    model_type = hf.get("model_type")
+    if model_type in mla_moe.MODEL_TYPES:
         return mla_moe.MlaMoeConfig.from_hf_dict(hf)
-    return LlamaConfig.from_hf_dict(hf)
+    if model_type in hybrid_ssm.MODEL_TYPES:
+        return hybrid_ssm.HybridSsmConfig.from_hf_dict(hf)
+    if model_type is not None and model_type not in llama.MODEL_TYPES:
+        raise ValueError(
+            f"model_type {model_type!r} is not served: its layers are not "
+            "implemented here, and a dense grouped-query model built from "
+            "its widths would be another model under its name (served: "
+            f"{sorted(llama.MODEL_TYPES + mla_moe.MODEL_TYPES + hybrid_ssm.MODEL_TYPES)})"
+        )
+    return llama.LlamaConfig.from_hf_dict(hf)

@@ -46,6 +46,14 @@ from dynamo_tpu.ops.linear import (
 )
 
 
+# `config.json` `model_type`s this family's config is built from
+# (`models.config_from_model_dir`); one without a `model_type` is taken for it
+MODEL_TYPES = (
+    "llama", "mistral", "mixtral", "qwen2", "gemma", "gemma2", "gemma3",
+    "gemma3_text",
+)
+
+
 @dataclass(frozen=True)
 class LlamaConfig:
     vocab_size: int = 32000

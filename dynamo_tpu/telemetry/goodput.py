@@ -94,6 +94,14 @@ MOE_COUNTERS = (
 SAMPLER_COUNTERS = ("dispatches", "pool_dispatches")
 
 
+# a model with recurrent layers (a state slot a sequence): `layer_steps`,
+# (recurrent layer, decode step) pairs; `slots_live`, live lanes summed over
+# decode steps; `slot_resets`, sequences whose state a prefill program zeroed
+# at position 0; `scan_tokens`, prompt tokens through the prefill scans.
+# Counted on the host where the lane arrays are built, before the call
+SSM_COUNTERS = ("layer_steps", "slots_live", "slot_resets", "scan_tokens")
+
+
 # what a label's first dispatch held beside the device's work
 # (`first_dispatch_by_label`, beside `compile_s_by_label`): the seconds JAX
 # itself reports on the dispatching thread for tracing, for building the MLIR
@@ -174,6 +182,7 @@ class GoodputStats:
         "first_dispatch_by_label",
         "moe",
         "sampler",
+        "ssm",
     )
 
     def __init__(self) -> None:
@@ -217,6 +226,8 @@ class GoodputStats:
         self.moe: dict[str, float] = {}
         # SAMPLER_COUNTERS
         self.sampler: dict[str, int] = {}
+        # SSM_COUNTERS (empty for a model without recurrent layers)
+        self.ssm: dict[str, int] = {}
 
     # ------------------------------------------------------------- query
 
@@ -282,6 +293,8 @@ class GoodputStats:
             self.moe[k] = self.moe.get(k, 0.0) + v
         for k, v in other.sampler.items():
             self.sampler[k] = self.sampler.get(k, 0) + v
+        for k, v in other.ssm.items():
+            self.ssm[k] = self.ssm.get(k, 0) + v
 
     def _merge_first_dispatch(self, label: str, split: dict) -> None:
         """Field by field the larger, as `compile_s_by_label` takes the
@@ -324,6 +337,7 @@ class GoodputStats:
             },
             "moe": dict(self.moe),
             "smp": dict(self.sampler),
+            "ssm": dict(self.ssm),
         }
 
     @classmethod
@@ -359,6 +373,9 @@ class GoodputStats:
         for k, v in (d.get("smp") or {}).items():
             if k in SAMPLER_COUNTERS:
                 out.sampler[k] = int(v)
+        for k, v in (d.get("ssm") or {}).items():
+            if k in SSM_COUNTERS:
+                out.ssm[k] = int(v)
         return out
 
     # ------------------------------------------------------------- debug
@@ -400,6 +417,7 @@ class GoodputStats:
             },
             "moe": {k: self.moe.get(k, 0.0) for k in MOE_COUNTERS},
             "sampler": {k: self.sampler.get(k, 0) for k in SAMPLER_COUNTERS},
+            "ssm": {k: self.ssm.get(k, 0) for k in SSM_COUNTERS},
         }
 
 
@@ -502,6 +520,20 @@ class GoodputLedger(GoodputStats):
             self.sampler["pool_dispatches"] = (
                 self.sampler.get("pool_dispatches", 0) + 1
             )
+
+    def record_ssm(
+        self, layers: int, *, decode_steps: int, lanes: int, resets: int,
+        scan_tokens: int,
+    ) -> None:
+        """One dispatch of a model with `layers` recurrent layers: its
+        decode steps at `lanes` live lanes, the sequences it starts at
+        position 0, the prompt tokens it scans."""
+        if not self.enabled:
+            return
+        for k, v in zip(SSM_COUNTERS, (
+            layers * decode_steps, lanes * decode_steps, resets, scan_tokens,
+        )):
+            self.ssm[k] = self.ssm.get(k, 0) + int(v)
 
     def record_decode_tokens(self, n: int = 1) -> None:
         if self.enabled:

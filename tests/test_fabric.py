@@ -70,6 +70,32 @@ async def test_lease_keepalive_keeps_key():
 
 
 @pytest.mark.asyncio
+async def test_a_held_loop_costs_no_lease_but_a_missed_keepalive_does():
+    """A process whose interpreter is held for longer than a lease's TTL (a
+    profiler closing its window held one for ten seconds on the chip and the
+    server fenced itself) held its store with it: the janitor gives every
+    lease the time it was held back. A lease nobody refreshes still expires."""
+    import time
+
+    state = FabricState()
+    c = FabricClient.in_process(state)
+    lease = await c.lease_grant(1.0)
+    await c.kv_put("instances/held", b"v", lease_id=lease)
+    await asyncio.sleep(0.6)  # the janitor sleeps; a keepalive lands in time
+    assert await c.lease_keepalive(lease)
+    time.sleep(2.5)  # the loop is held for two and a half TTLs
+    await asyncio.sleep(0.05)  # the janitor wakes before any keepalive
+    assert await c.kv_get("instances/held") == b"v"
+    assert await c.lease_keepalive(lease)
+    # and without a keepalive, with the loop running, it goes as before
+    watch = await c.watch_prefix("instances/")
+    ev = await asyncio.wait_for(watch.__anext__(), timeout=4.0)
+    assert ev.type == "delete" and ev.key == "instances/held"
+    assert not await c.lease_keepalive(lease)
+    await watch.cancel()
+
+
+@pytest.mark.asyncio
 async def test_watch_streams_puts_and_deletes():
     c = FabricClient.in_process(FabricState())
     watch = await c.watch_prefix("p/")

@@ -108,6 +108,12 @@ INTENTIONALLY_SHARED = {
     # made the device compute it; the same shared goodput surface
     "dyn_llm_sampler_dispatches",
     "dyn_llm_sampler_pool_dispatches",
+    # recurrent layers' state slots (ISSUE 38): decode steps, live slots,
+    # resets and scanned prompt tokens; the same shared goodput surface
+    "dyn_llm_ssm_layer_steps",
+    "dyn_llm_ssm_slots_live",
+    "dyn_llm_ssm_slot_resets",
+    "dyn_llm_ssm_scan_tokens",
     # decision provenance plane (ISSUE 20): every control-plane process
     # (frontend, metrics component, standalone router) exports its OWN
     # ledger's decision counts — decisions are made where they are

@@ -1,0 +1,150 @@
+"""`ops/ssm.py`: the three forms of the selective scan (packed with resets,
+chunked with carry, single step) and the convolution with a carried tail add
+up to one plain loop over tokens, written here in numpy float64.
+
+Tolerance: the prefill forms run the recurrence in blocks (a block's decays
+multiplied up, then applied to the state it starts from), the same float32
+arithmetic reassociated, so they differ from each other and from the
+float64 loop by float32 rounding over the sequence: 1e-5 absolute on values of order
+1 is ten times what 40 tokens read (1e-6) and far under what a wrong form
+gives (a missed reset or a tail from the wrong place reads 1e-1).
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from dynamo_tpu.ops import ssm
+
+N, D, K = 4, 24, 4
+TOL = 1e-5
+
+
+def inputs(T: int, seed: int):
+    rng = np.random.default_rng(seed)
+    x = rng.standard_normal((T, D)).astype(np.float32)
+    delta = np.log1p(np.exp(rng.standard_normal((T, D)) - 2)).astype(np.float32)
+    b = rng.standard_normal((T, N)).astype(np.float32)
+    c = rng.standard_normal((T, N)).astype(np.float32)
+    return x, delta, b, c
+
+
+A_NEG = -np.exp(np.log(np.arange(1, N + 1, dtype=np.float64))[:, None] * np.ones((1, D)))
+
+
+def loop(x, delta, b, c, h=None):
+    """The plain loop, float64: (y [T, D], the state after every token)."""
+    h = np.zeros((N, D)) if h is None else h.astype(np.float64)
+    ys, hs = [], []
+    for t in range(x.shape[0]):
+        h = np.exp(delta[t][None, :] * A_NEG) * h + (delta[t] * x[t])[None, :] * b[t][:, None]
+        ys.append((h * c[t][:, None]).sum(0))
+        hs.append(h.copy())
+    return np.stack(ys), np.stack(hs)
+
+
+def conv_loop(x, w, bias):
+    T = x.shape[0]
+    padded = np.concatenate([np.zeros((K - 1, D)), x.astype(np.float64)])
+    return np.stack([bias + sum(w[k] * padded[t + k] for k in range(K)) for t in range(T)])
+
+
+def test_three_scan_forms_add_up_to_the_plain_loop():
+    """A 29-token sequence: whole in a pack beside a 7-token neighbour;
+    as chunks of 16 and 13 (the second padded to 16) from a carried state;
+    and its last 5 tokens as single steps from the chunks' state, in a batch
+    with a lane that must keep its slot."""
+    T, T2 = 29, 7
+    x, delta, b, c = inputs(T, 1)
+    x2, delta2, b2, c2 = inputs(T2, 2)
+    want_y, want_h = loop(x, delta, b, c)
+    want_y2, want_h2 = loop(x2, delta2, b2, c2)
+    a_neg = jnp.asarray(A_NEG, jnp.float32)
+    # packed: the neighbour first, then the sequence, then 4 padding tokens
+    P = T2 + T + 4
+    cat = lambda u, v, w: jnp.asarray(np.concatenate([u, v, np.zeros((4,) + u.shape[1:], np.float32)]))
+    positions = jnp.asarray(np.concatenate([np.arange(T2), np.arange(T), np.zeros(4)]).astype(np.int32))
+    # a sequence's last token writes its slot; every other the null slot (3)
+    slots = np.full(P, 3, np.int32)
+    slots[T2 - 1], slots[T2 + T - 1] = 2, 0
+    valid = jnp.asarray(np.arange(P) < T2 + T)
+    states = jnp.full((4, N, D), 9.0, jnp.float32)
+    y, states = jax.jit(ssm.scan_packed)(
+        states, cat(x2, x, 0), cat(delta2, delta, 0), cat(b2, b, 0), cat(c2, c, 0),
+        a_neg, positions, valid, jnp.asarray(slots),
+    )
+    np.testing.assert_allclose(np.asarray(y[:T2]), want_y2, atol=TOL)
+    np.testing.assert_allclose(np.asarray(y[T2:T2 + T]), want_y, atol=TOL)
+    np.testing.assert_allclose(np.asarray(states[0]), want_h[-1], atol=TOL)
+    np.testing.assert_allclose(np.asarray(states[2]), want_h2[-1], atol=TOL)
+    assert np.all(np.asarray(states[1]) == 9.0)  # nobody's slot: untouched
+    # chunked: 16 tokens from zero, then 13 and 3 of padding from the carry
+    C = 16
+    pad = lambda u: jnp.asarray(np.concatenate([u[C:], np.ones((2 * C - T,) + u.shape[1:], np.float32)]))
+    chunk = jax.jit(ssm.scan_chunk)
+    y1, h1 = chunk(jnp.zeros((N, D)), *(jnp.asarray(u[:C]) for u in (x, delta, b, c)), a_neg, jnp.ones(C, bool))
+    y2, h2 = chunk(h1, pad(x), pad(delta), pad(b), pad(c), a_neg, jnp.arange(C) < T - C)
+    np.testing.assert_allclose(np.asarray(y1), want_y[:C], atol=TOL)
+    np.testing.assert_allclose(np.asarray(y2[: T - C]), want_y[C:], atol=TOL)
+    np.testing.assert_allclose(np.asarray(h1), want_h[C - 1], atol=TOL)
+    np.testing.assert_allclose(np.asarray(h2), want_h[-1], atol=TOL)
+    # the chunked and the packed forms block the tokens differently (40
+    # tokens in blocks of 20, 16 in one of 16), so they agree to float32
+    # rounding, not to the bit
+    np.testing.assert_allclose(np.asarray(h2), np.asarray(states[0]), atol=TOL)
+    # single steps: the last 5 tokens from the state 24 tokens leave
+    h = jnp.stack([jnp.asarray(want_h[T - 6], jnp.float32), jnp.full((N, D), 5.0)])
+    step = jax.jit(ssm.scan_step)
+    live = jnp.asarray([True, False])
+    for t in range(T - 5, T):
+        two = lambda u: jnp.stack([jnp.asarray(u[t]), jnp.asarray(u[t])])
+        h, y = step(h, two(x), two(delta), two(b), two(c), a_neg, live)
+        np.testing.assert_allclose(np.asarray(y[0]), want_y[t], atol=TOL)
+    np.testing.assert_allclose(np.asarray(h[0]), want_h[-1], atol=TOL)
+    assert np.all(np.asarray(h[1]) == 5.0)
+
+
+@pytest.mark.parametrize("count", [1, 2, 9])
+def test_convolution_with_a_carried_tail_equals_one_pass(count):
+    """A 20-token stream through the convolution whole; as a first chunk of
+    `count` tokens (fewer than the taps, too) and the rest from its tail;
+    and token by token through `conv_step`; and two streams in one pack."""
+    rng = np.random.default_rng(3)
+    T = 20
+    x = rng.standard_normal((T, D)).astype(np.float32)
+    w = rng.standard_normal((K, D)).astype(np.float32)
+    bias = rng.standard_normal(D).astype(np.float32)
+    want = conv_loop(x, w, bias)
+    zeros = jnp.zeros((K - 1, D))
+    whole, stream = ssm.conv_sequence(jnp.asarray(x), zeros, jnp.arange(T), jnp.asarray(w), jnp.asarray(bias))
+    np.testing.assert_allclose(np.asarray(whole), want, atol=TOL)
+    # a chunk of 12 of which `count` are real, then the rest from its tail
+    first = np.concatenate([x[:count], np.full((12 - count, D), 7.0, np.float32)])
+    out1, s1 = ssm.conv_sequence(jnp.asarray(first), zeros, jnp.arange(12), jnp.asarray(w), jnp.asarray(bias))
+    tail = ssm.tail_after(s1, count, K)
+    np.testing.assert_allclose(np.asarray(out1[:count]), want[:count], atol=TOL)
+    out2, s2 = ssm.conv_sequence(
+        jnp.asarray(x[count:]), tail.reshape(K - 1, D), count + jnp.arange(T - count),
+        jnp.asarray(w), jnp.asarray(bias),
+    )
+    np.testing.assert_allclose(np.asarray(out2), want[count:], atol=TOL)
+    np.testing.assert_array_equal(
+        np.asarray(ssm.tail_after(s2, T - count, K)), np.asarray(ssm.tail_after(stream, T, K))
+    )
+    # token by token from an empty tail
+    t_ = jnp.zeros((1, (K - 1) * D))
+    for t in range(T):
+        out, t_ = ssm.conv_step(jnp.asarray(x[t][None]), t_, jnp.asarray(w), jnp.asarray(bias))
+        np.testing.assert_allclose(np.asarray(out[0]), want[t], atol=TOL)
+    np.testing.assert_array_equal(np.asarray(t_[0]), np.asarray(ssm.tail_after(stream, T, K)))
+    # two streams in one pack: the second sees nothing of the first
+    both = jnp.asarray(np.concatenate([x[:count], x]))
+    positions = jnp.asarray(np.concatenate([np.arange(count), np.arange(T)]).astype(np.int32))
+    packed, _ = ssm.conv_sequence(both, zeros, positions, jnp.asarray(w), jnp.asarray(bias))
+    np.testing.assert_allclose(np.asarray(packed[count:]), want, atol=TOL)
+    tails = ssm.packed_tails(both, positions, jnp.asarray([count - 1, count + T - 1]), K)
+    np.testing.assert_array_equal(np.asarray(tails[1]), np.asarray(ssm.tail_after(stream, T, K)))
+    np.testing.assert_array_equal(np.asarray(tails[0]), np.asarray(tail))

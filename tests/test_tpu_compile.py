@@ -818,3 +818,132 @@ def test_step_program_compiles_to_the_parents(one_chip, program):
     assert hashlib.sha256(
         json.dumps(histogram).encode()
     ).hexdigest()[:16] == digest, histogram
+
+
+# ----------------------- the hybrid state-space family's programs (PR 38)
+#
+# `cellbench/configs/jamba2-3b-bf16.json` at the published widths, cut here to
+# four layers in a toy period of two (two Mamba layers, two attention layers)
+# so that a compile takes seconds: a state slot a lane (`[65, 16, 5120]`
+# float32 and the convolution's tail) beside paged keys and values of one KV
+# head, 64 lanes, the cell's 32,832 blocks. The full depth compiles too
+# (arguments 7.27 GB, temporaries 0.5 to 0.8 GB: PERF.md section 6, PR 38).
+
+
+def _hybrid_step_setup(one_chip, num_blocks: int = 32832, layers: int = 4):
+    from dynamo_tpu.models import hybrid_ssm
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "cellbench", "configs", "jamba2-3b-bf16.json")) as f:
+        conf = json.load(f)
+    cfg = hybrid_ssm.HybridSsmConfig.from_hf_dict(
+        {k: v for k, v in conf.items() if k != "bench"}
+        | {"num_hidden_layers": layers, "attn_layer_period": 2, "attn_layer_offset": 1}
+    )
+    cfg = dataclasses.replace(cfg, attn_impl="pallas")
+    params = jax.tree_util.tree_map(
+        lambda a: one_chip(a.shape, a.dtype),
+        jax.eval_shape(lambda: hybrid_ssm.init_params(cfg, jax.random.PRNGKey(0))),
+    )
+    (state, _), (tail, _) = cfg.state_kind().slot
+    pages = one_chip((1, num_blocks, BLOCK, cfg.head_dim), BF16)
+    first = tuple(
+        pages if cfg.is_attn_layer(i) else one_chip((B + 1,) + state, F32)
+        for i in range(layers)
+    )
+    second = tuple(
+        pages if cfg.is_attn_layer(i) else one_chip((B + 1,) + tail, F32)
+        for i in range(layers)
+    )
+    return cfg, params, first, second
+
+
+def _lower_hybrid(one_chip, program: str):
+    from dynamo_tpu.engine.jax_engine.model_runner import ModelRunner
+    from dynamo_tpu.ops.sampling import MAX_EOS_IDS
+
+    cfg, params, kc, vc = _hybrid_step_setup(one_chip)
+    vec = lambda dtype: one_chip((B,), dtype)
+    scalar = lambda dtype: one_chip((), dtype)
+    table = 8192 // BLOCK
+    if program == "decode_multi@H4B64":
+        fn = jax.jit(
+            functools.partial(ModelRunner._decode_multi_impl, cfg, None, None, BLOCK),
+            static_argnums=(0,), donate_argnums=(2, 3),
+        )
+        return fn.lower(
+            4, params, kc, vc, vec(I32), vec(I32), one_chip((B, table), I32),
+            one_chip((B, 2), jnp.uint32), vec(F32), vec(F32), vec(I32),
+            vec(jnp.bool_), vec(I32), vec(I32), one_chip((B, MAX_EOS_IDS), I32),
+        )
+    if program == "mixed_step@c1":
+        chunk = (
+            one_chip((512,), I32), scalar(I32), scalar(I32), one_chip((table,), I32),
+            one_chip((2,), jnp.uint32), scalar(F32), scalar(F32), scalar(I32),
+            scalar(F32), one_chip((MAX_EOS_IDS,), I32), scalar(jnp.bool_),
+            scalar(I32),  # the chunk's lane slot
+        )
+        fn = jax.jit(
+            functools.partial(ModelRunner._mixed_impl, cfg, None, None),
+            donate_argnums=(1, 2),
+        )
+        return fn.lower(
+            params, kc, vc, (chunk,), vec(I32), vec(I32), one_chip((B, table), I32),
+            vec(I32), one_chip((B, 2), jnp.uint32), vec(F32), vec(F32), vec(I32),
+            one_chip((B, MAX_EOS_IDS), I32), vec(jnp.bool_),
+        )
+    tok = lambda dtype: one_chip((512,), dtype)
+    fn = jax.jit(
+        functools.partial(ModelRunner._prefill_packed_impl, cfg, None),
+        donate_argnums=(1, 2),
+    )
+    return fn.lower(
+        params, kc, vc, tok(I32), tok(I32), tok(I32), tok(I32), vec(I32),
+        one_chip((B, 2), jnp.uint32), vec(F32), vec(F32), vec(I32), vec(F32),
+        one_chip((B, MAX_EOS_IDS), I32), vec(jnp.bool_), vec(I32),
+    )
+
+
+@pytest.mark.parametrize("program,bodies,kernels,loops", [
+    ("decode_multi@H4B64", 2, 2 * 4, 0),  # 2 attention layers x 4 steps
+    ("mixed_step@c1", 4, 2, 6),  # the chunk's scans; its attention is XLA's
+    ("prefill_packed@512", 2, 0, 6),
+])
+def test_hybrid_step_programs_one_chip(one_chip, program, bodies, kernels, loops):
+    """The family's step programs compile for the chip: two layer bodies a
+    pass (the Mamba one and the attention one; a mixed step has a chunk's
+    pass and a decode's), the paged decode kernel under its name in the
+    attention layers, three device loops a Mamba layer where a prompt is
+    scanned (`ops/ssm.py` `_blocked_scan`'s passes), the slot arrays written in place (aliased, no copy of a layer's
+    65 slots beside the compiler's own prefetches), and everything fits."""
+    from dynamo_tpu.models import layer_bodies_called
+
+    jax.clear_caches()  # a body traced by another test would not be counted
+    with layer_bodies_called() as seen:
+        lowered = _lower_hybrid(one_chip, program)
+    assert len(seen) == bodies, sorted(s[1] for s in seen)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    names = re.findall(
+        r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\""
+        r"[^\n]*op_name=\"[^\"\n]*pallas_call\"", text, re.M,
+    )
+    assert len(names) == kernels
+    assert all(name.startswith("tpu_custom_call") for name in names), names
+    assert len(re.findall(r"^\s*%?[\w.\-]+ = [^\n]*? while\(", text, re.M)) == loops
+    mem = compiled.memory_analysis()
+    # two Mamba layers' slot arrays; the tail's 65 rows are tiled to 72
+    slots = 2 * ((B + 1) * 16 + 72 * 3) * 5120 * 4
+    pages = 2 * 2 * 32832 * BLOCK * 128 * 2  # two attention layers' planes
+    assert mem.alias_size_in_bytes == slots + pages
+    assert mem.temp_size_in_bytes < 1 * 2**30
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16_909_336_064
+    if program == "decode_multi@H4B64":
+        # what `ssm_step_ms` reads: the instructions that produce a layer's
+        # new state, float32 [65, 16, 5120] (the step writes lanes 0 to 63 of
+        # the slots' array where it lies), 2 layers x 4 steps of them
+        produced = [
+            line for line in text.splitlines()
+            if re.search(r" = \(?[^=]*f32\[65,16,5120\][^=]* fusion\(", line)
+        ]
+        assert 1 <= len(produced) <= 2 * 4, len(produced)
