@@ -466,6 +466,17 @@ def goodput_families(
             f"Recurrent layers: {what} (counted on the host; fleet sum)",
             value=float(ssm.get(name, 0)),
         )
+    stream = gp.stream if gp is not None else {}
+    for name, what in (
+        ("items", "items put on sequences' streams that carried tokens, "
+         "each what one dispatch produced for one sequence"),
+        ("tokens", "tokens those items carried"),
+    ):
+        yield CounterMetricFamily(
+            f"{PREFIX}_stream_{name}",
+            f"Stream edge: {what} (counted where an item is put; fleet sum)",
+            value=float(stream.get(name, 0)),
+        )
     comp = GaugeMetricFamily(
         f"{PREFIX}_compile_seconds",
         "First-dispatch (compile-inclusive) wall time per dispatch label "

@@ -158,6 +158,11 @@ class EngineStats:
     used_blocks: int = 0
     total_blocks: int = 0
     generated_tokens: int = 0
+    # items put on sequences' streams that carried tokens: one holds what
+    # one dispatch produced for one sequence, so generated tokens that were
+    # streamed, over stream_items, is the tokens an item (the ledger's
+    # `stream` slot counts both at the flush)
+    stream_items: int = 0
     # speculative decoding counters (SpecDecodeStats wire fields): one
     # "draft" = one lane-dispatch that carried >= 1 proposed token; all
     # monotonic over the engine's lifetime
@@ -258,6 +263,9 @@ class _Sequence(SequenceState):
         self.cached_prefix_blocks = 0  # leading blocks found in G2/G3
         self.pending_chain: Optional[TokenBlockSequence] = None  # prebuilt
         self.out: asyncio.Queue = asyncio.Queue()
+        # the tokens of the dispatch being replayed, not yet on `out`
+        # (JaxEngine._append_token gathers, _flush_streams puts)
+        self.pending: Optional[LLMEngineOutput] = None
         self.eos: set[int] = set()
         if not request.stop.ignore_eos:
             self.eos = set(request.eos_token_ids) | set(
@@ -473,6 +481,9 @@ class JaxEngine:
         # top [[id, lp], ...]) — logprobs ride along so the first token's
         # entry isn't missing from logprobs responses
         self._landed: list[tuple[_Sequence, Optional[tuple], Optional[FinishReason]]] = []
+        # sequences whose `pending` item holds tokens of the dispatch being
+        # replayed (_append_token adds, _flush_streams empties)
+        self._unflushed: list[_Sequence] = []
         # Serializes every runner call: the cache arrays are DONATED through
         # prefill/decode/inject, so a concurrent caller (remote-prefill
         # landing, prefill_only service task) would read a deleted array.
@@ -527,10 +538,16 @@ class JaxEngine:
     def _observe_stream(self, seq: _Sequence, item: LLMEngineOutput) -> None:
         """Always-on phase histogram recording at the stream edge (what a
         consumer of this worker actually experiences): TTFT, prefill (the
-        admitted-to-first-token span), inter-token gaps, end-to-end."""
+        admitted-to-first-token span), inter-token gaps, end-to-end. An item
+        of n tokens is n observations of its arrival gap over n (a first
+        item's tokens past the first arrived with it: gaps of 0), so the
+        inter-token count is the streamed tokens less one a stream and its
+        sum the last arrival less the first."""
         ph = self.stats.phase_histograms
         now = time.monotonic()
-        if item.token_ids:
+        n = len(item.token_ids)
+        if n:
+            gap_ms = 0.0
             if seq.t_first is None:
                 seq.t_first = now
                 ph.observe("ttft", (now - seq.t_arrival) * 1e3)
@@ -538,8 +555,11 @@ class JaxEngine:
                     waited = now - seq.t_admitted
                     ph.observe("prefill", waited * 1e3)
                     dtrace.observe_phase("prefill_wait", int(waited * 1e9))
-            elif seq.t_last is not None:
-                ph.observe("inter_token", (now - seq.t_last) * 1e3)
+                n -= 1  # the first token's gap is the TTFT
+            else:
+                gap_ms = (now - seq.t_last) * 1e3 / n
+            if n:
+                ph.observe("inter_token", gap_ms, n)
             seq.t_last = now
         if item.finish_reason is not None:
             ph.observe("e2e", (now - seq.t_arrival) * 1e3)
@@ -549,6 +569,12 @@ class JaxEngine:
     async def generate(
         self, request: PreprocessedRequest, context: Context
     ) -> AsyncIterator[LLMEngineOutput]:
+        """Stream a request's output. An item carries what one dispatch
+        produced for the sequence (one token from a prefill or a single
+        step, a horizon's several, a verify pass's accepted run; `log_probs`
+        and `top_logprobs` position for position where asked for), put on
+        the stream once when the dispatch is replayed; the finish, or a
+        structured error, follows as an item of its own."""
         if self._fenced:
             yield LLMEngineOutput.final_error(
                 context.id, "admission",
@@ -653,10 +679,11 @@ class JaxEngine:
         for seq in list(self.waiting):
             self.waiting.remove(seq)
             self._sp_close_all(seq)
-            seq.out.put_nowait(
+            self._send_final(
+                seq,
                 LLMEngineOutput.final_error(
                     seq.ctx.id, "queue", cause, "engine_loop_crash"
-                )
+                ),
             )
         # _finish_error frees the slot + KV blocks (and publishes Removed)
         # too: a restarted loop must not keep decoding zombie lanes that no
@@ -668,11 +695,12 @@ class JaxEngine:
         for seq in list(self._admit_order):
             if seq.pending_remote:
                 seq.ctx.kill()
-                seq.out.put_nowait(
+                self._send_final(
+                    seq,
                     LLMEngineOutput.final_error(
                         seq.ctx.id, "remote_prefill", cause,
                         "engine_loop_crash",
-                    )
+                    ),
                 )
             else:
                 self._finish_error(
@@ -875,10 +903,11 @@ class JaxEngine:
             self.waiting.remove(seq)
             self._sp_event(seq, "watchdog_trip", label=label)
             self._sp_close_all(seq)
-            seq.out.put_nowait(
+            self._send_final(
+                seq,
                 LLMEngineOutput.final_error(
                     seq.ctx.id, "queue", cause, "watchdog_stuck"
-                )
+                ),
             )
         for seq in list(self._admit_order):
             # blocks are NOT freed: the wedged dispatch may still write
@@ -887,10 +916,11 @@ class JaxEngine:
             seq.ctx.kill()
             self._sp_event(seq, "watchdog_trip", label=label)
             self._sp_close_all(seq)
-            seq.out.put_nowait(
+            self._send_final(
+                seq,
                 LLMEngineOutput.final_error(
                     seq.ctx.id, label, cause, "watchdog_stuck"
-                )
+                ),
             )
         if self.on_watchdog_trip is not None:
             with contextlib.suppress(Exception):
@@ -915,7 +945,9 @@ class JaxEngine:
         # finish every parked consumer so no generate() call hangs
         for seq in list(self.waiting):
             self.waiting.remove(seq)
-            seq.out.put_nowait(LLMEngineOutput.final(FinishReason.CANCELLED))
+            self._send_final(
+                seq, LLMEngineOutput.final(FinishReason.CANCELLED)
+            )
         for seq in list(self._admit_order):
             self._finish(seq, FinishReason.CANCELLED)
 
@@ -1134,7 +1166,7 @@ class JaxEngine:
         if seq.spans:
             self._sp_finish(seq, "decode", tokens=seq.num_generated)
             self._sp_close_all(seq)
-        seq.out.put_nowait(LLMEngineOutput.final(reason))
+        self._send_final(seq, LLMEngineOutput.final(reason))
 
     def _finish_error(
         self, seq: _Sequence, phase: str, cause: str, code: str
@@ -1145,8 +1177,8 @@ class JaxEngine:
         if seq.spans:
             self._sp_event(seq, "error", phase=phase, code=code)
             self._sp_close_all(seq)
-        seq.out.put_nowait(
-            LLMEngineOutput.final_error(seq.ctx.id, phase, cause, code)
+        self._send_final(
+            seq, LLMEngineOutput.final_error(seq.ctx.id, phase, cause, code)
         )
 
     def _maybe_offload(self, seq: _Sequence, reason: FinishReason) -> None:
@@ -1562,7 +1594,9 @@ class JaxEngine:
             if seq.ctx.is_killed() or seq.ctx.is_stopped():
                 self.waiting.remove(seq)
                 self._sp_close_all(seq)
-                seq.out.put_nowait(LLMEngineOutput.final(FinishReason.CANCELLED))
+                self._send_final(
+                    seq, LLMEngineOutput.final(FinishReason.CANCELLED)
+                )
             elif seq.ctx.expired() or seq.ctx.ttft_expired():
                 # queued past its deadline (or past the point where its
                 # first token could still arrive in budget): shed before it
@@ -1572,12 +1606,13 @@ class JaxEngine:
                 seq.ctx.kill()
                 self._sp_event(seq, "deadline_exceeded", phase="queue")
                 self._sp_close_all(seq)
-                seq.out.put_nowait(
+                self._send_final(
+                    seq,
                     LLMEngineOutput.final_error(
                         seq.ctx.id, "queue",
                         "deadline exceeded while queued",
                         "deadline_exceeded",
-                    )
+                    ),
                 )
         for seq in list(self._admit_order):
             # pending_remote seqs keep their blocks until the in-flight
@@ -1591,12 +1626,13 @@ class JaxEngine:
                     self._sp_event(
                         seq, "deadline_exceeded", phase="remote_prefill"
                     )
-                    seq.out.put_nowait(
+                    self._send_final(
+                        seq,
                         LLMEngineOutput.final_error(
                             seq.ctx.id, "remote_prefill",
                             "deadline exceeded awaiting remote prefill",
                             "deadline_exceeded",
-                        )
+                        ),
                     )
                 continue
             if seq.ctx.expired() or (
@@ -1791,7 +1827,7 @@ class JaxEngine:
                     tokens=len(replay),
                     state_resets=1,
                 )
-            with dtrace.phase("loop.emit"):
+            with self._emitting():
                 # the admission pass may have prebuilt the identical chain
                 # for the prefix lookup — reuse instead of re-hashing
                 seq.hash_seq = seq.pending_chain or TokenBlockSequence(
@@ -1847,7 +1883,7 @@ class JaxEngine:
                 ),
                 tokens=len(seq.token_ids),
             )
-        with dtrace.phase("loop.emit"):
+        with self._emitting():
             self._append_sample(seq, sample)
 
     async def _run_packed_prefill(
@@ -1874,7 +1910,7 @@ class JaxEngine:
                 tokens=sum(len(s.token_ids) for s in group),
                 state_resets=len(group),
             )
-        with dtrace.phase("loop.emit"):
+        with self._emitting():
             toks, lps, tids, tlps = sample
             for i, seq in enumerate(group):
                 if seq.slot is None:  # cancelled during the device call
@@ -1923,7 +1959,7 @@ class JaxEngine:
                 "prefill_chunk", run_chunk, tokens=len(chunk),
                 state_resets=int(start == 0),
             )
-        with dtrace.phase("loop.emit"):
+        with self._emitting():
             if seq.spans:
                 sp = seq.spans.get("prefill")
                 if sp is not None and len(sp.events) < 64:
@@ -2059,7 +2095,7 @@ class JaxEngine:
                 ctx_tokens=ctx_tokens,
                 state_resets=sum(start == 0 for _, start, _ in packed),
             )
-        with dtrace.phase("loop.emit"):
+        with self._emitting():
             final_samples = {
                 slot: out[4 * j : 4 * j + 4]
                 for j, slot in enumerate(final_slots)
@@ -2123,6 +2159,7 @@ class JaxEngine:
             top_ids = np.array([t for t, _ in top], np.int32) if top else None
             top_lps = np.array([l for _, l in top], np.float32) if top else None
             self._append_token(seq, token, lp=lp, top_ids=top_ids, top_lps=top_lps)
+        self._flush_streams()
 
     def _kv_stream_enabled(self) -> bool:
         """Streaming KV data plane default-on (DYN_KV_STREAM=0 reverts to
@@ -2873,7 +2910,7 @@ class JaxEngine:
                 capacity=self.config.max_batch,
                 ctx_tokens=self._ctx_tokens(active),
             )
-        with dtrace.phase("loop.emit"):
+        with self._emitting():
             toks, lps, tids, tlps = sample
             for seq in active:
                 if seq.slot is None:
@@ -3027,7 +3064,7 @@ class JaxEngine:
                 ctx_tokens=self._ctx_tokens(active),
                 horizon=1 + E,
             )
-        with dtrace.phase("loop.emit"):
+        with self._emitting():
             K2 = (packed.shape[-1] - 2) // 2
             # verify rows: accept the longest prefix of drafts matching the
             # model's own tokens, then the bonus token
@@ -3162,7 +3199,7 @@ class JaxEngine:
                 ctx_tokens=self._ctx_tokens(active),
                 horizon=H,
             )
-        with dtrace.phase("loop.emit"):
+        with self._emitting():
             counted = self.runner.step_stats(packed)
             if counted is not None:
                 self.stats.goodput.record_moe(counted)
@@ -3184,6 +3221,36 @@ class JaxEngine:
                         top_lps=step[i, 2 + K:],
                     )
 
+    @contextlib.contextmanager
+    def _emitting(self):
+        """The replay of one dispatch's results (`loop.emit`): whatever it
+        appended leaves as one item a sequence when it ends, before the
+        engine's task gives up the event loop."""
+        with dtrace.phase("loop.emit"):
+            try:
+                yield
+            finally:
+                self._flush_streams()
+
+    def _flush_streams(self) -> None:
+        for seq in self._unflushed:
+            self._flush_seq(seq)
+        self._unflushed.clear()
+
+    def _flush_seq(self, seq: _Sequence) -> None:
+        out = seq.pending
+        if out is not None:
+            seq.pending = None
+            self.stats.stream_items += 1
+            self.stats.goodput.record_stream(len(out.token_ids))
+            seq.out.put_nowait(out)
+
+    def _send_final(self, seq: _Sequence, item: LLMEngineOutput) -> None:
+        """Put a finish or an error on a sequence's stream, behind the
+        tokens it still has pending."""
+        self._flush_seq(seq)
+        seq.out.put_nowait(item)
+
     def _append_sample(
         self, seq: _Sequence, sample: tuple[np.ndarray, ...]
     ) -> None:
@@ -3202,7 +3269,11 @@ class JaxEngine:
         top_ids: Optional[np.ndarray] = None,
         top_lps: Optional[np.ndarray] = None,
     ) -> None:
-        """Record a newly generated token: stream it, grow blocks, stop."""
+        """Record a newly generated token: gather it into the sequence's
+        pending item (one item a sequence and dispatch: `_flush_streams`
+        puts it on the stream when the dispatch is replayed), grow blocks,
+        stop. A finish or an error flushes first (`_send_final`), so a
+        consumer sees tokens, then the finish, as its own item."""
         self.stats.generated_tokens += 1
         self.stats.goodput.record_decode_tokens()
         if seq.spans and "decode" not in seq.spans:
@@ -3238,18 +3309,27 @@ class JaxEngine:
         if seq.hash_seq is not None:
             seq.hash_seq.append(token)
             self._emit_stored(seq)
-        out = LLMEngineOutput(token_ids=[token])
+        out = seq.pending
+        if out is None:
+            out = seq.pending = LLMEngineOutput()
+            self._unflushed.append(seq)
+        out.token_ids.append(token)
         if seq.want_logprobs and lp is not None:
-            out.log_probs = [lp]
+            # every token of a dispatch comes with its log-prob or none
+            # does, so an item's lists stay aligned position for position
+            if out.log_probs is None:
+                out.log_probs = []
+            out.log_probs.append(lp)
             k = seq.num_top_lp
             if k and top_ids is not None and top_lps is not None:
-                out.top_logprobs = [
+                if out.top_logprobs is None:
+                    out.top_logprobs = []
+                out.top_logprobs.append(
                     [
                         [int(t), float(l)]
                         for t, l in zip(top_ids[:k], top_lps[:k])
                     ]
-                ]
-        seq.out.put_nowait(out)
+                )
         if (
             seq.num_generated >= seq.max_new
             or len(seq.token_ids) >= self.config.max_model_len
@@ -3299,18 +3379,20 @@ class JaxEngine:
         for seq in list(self.waiting):
             self.waiting.remove(seq)
             self._sp_close_all(seq)
-            seq.out.put_nowait(
+            self._send_final(
+                seq,
                 LLMEngineOutput.final_error(
                     seq.ctx.id, "queue", cause, code
-                )
+                ),
             )
         for seq in list(self._admit_order):
             if seq.pending_remote:
                 seq.ctx.kill()
-                seq.out.put_nowait(
+                self._send_final(
+                    seq,
                     LLMEngineOutput.final_error(
                         seq.ctx.id, "remote_prefill", cause, code
-                    )
+                    ),
                 )
             else:
                 self._finish_error(seq, "decode", cause, code)

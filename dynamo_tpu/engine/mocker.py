@@ -892,10 +892,11 @@ class MockEngine:
         prev_blocks = len(seq.hash_seq.blocks)
         seq.hash_seq.append(tok)
         new_blocks = seq.hash_seq.blocks[prev_blocks:]
-        if new_blocks:
-            if not self.cache.grow(new_blocks):
-                self._preempt_for(seq)
-                return
+        # no room for the block this token completed: someone is preempted
+        # below, after the token is streamed (it is counted in `generated`,
+        # so a replay resumes behind it; JaxEngine streams before it grows)
+        grew = not new_blocks or self.cache.grow(new_blocks)
+        if grew:
             seq.acquired_hashes.extend(b.block_hash for b in new_blocks)
         max_tokens = seq.request.stop.max_tokens or 64
         finished = seq.generated >= max_tokens or seq.context.is_stopped()
@@ -924,6 +925,8 @@ class MockEngine:
             if seq.spans:
                 self._sp_finish(seq, "decode", tokens=seq.generated)
                 self._sp_close_all(seq)
+        elif not grew:
+            self._preempt_for(seq)
 
     def _preempt_for(self, seq: _MockSeq) -> None:
         """Class-aware victim choice (parity with JaxEngine._preempt_victim):

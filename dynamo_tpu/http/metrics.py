@@ -625,7 +625,16 @@ class ServiceMetrics:
 class TokenTimer:
     """Per-request TTFT / inter-token latency observer. Also keeps the
     request's own ttft_ms / max_itl_ms so the DYN_TRACE=auto retention
-    decision can compare this request against its SLO at completion."""
+    decision can compare this request against its SLO at completion.
+
+    `on_token(count)` is called once a streamed chunk, which carries what
+    one dispatch produced for the sequence: one token or several. A chunk
+    of n tokens that arrives `gap` after the last one is n inter-token
+    observations of gap / n (a first chunk's tokens past the first arrived
+    with it: gaps of 0), so the histograms count the streamed tokens less
+    one a stream and sum to the last arrival less the first. `max_itl_ms`
+    stays the largest gap between arrivals: what the user waited. The
+    labelled children are bound once a stream."""
 
     def __init__(self, metrics: ServiceMetrics, model: str) -> None:
         self.metrics = metrics
@@ -634,23 +643,27 @@ class TokenTimer:
         self.last: float | None = None
         self.ttft_ms: float | None = None
         self.max_itl_ms: float | None = None
+        self._ttft = metrics.time_to_first_token.labels(model)
+        self._itl = metrics.inter_token_latency.labels(model)
+        self._output_tokens = metrics.output_tokens.labels(model)
+        self._phase_hist = metrics.phase_hist_for(model)
 
     def on_token(self, count: int = 1) -> None:
         now = time.monotonic()
-        phase_hist = self.metrics.phase_hist_for(self.model)
+        gaps, gap_s = count, 0.0
         if self.last is None:
             self.ttft_ms = (now - self.start) * 1e3
-            self.metrics.time_to_first_token.labels(self.model).observe(
-                now - self.start
-            )
-            phase_hist.observe("ttft", self.ttft_ms)
+            self._ttft.observe(now - self.start)
+            self._phase_hist.observe("ttft", self.ttft_ms)
+            gaps -= 1
         else:
             gap_ms = (now - self.last) * 1e3
             if self.max_itl_ms is None or gap_ms > self.max_itl_ms:
                 self.max_itl_ms = gap_ms
-            self.metrics.inter_token_latency.labels(self.model).observe(
-                now - self.last
-            )
-            phase_hist.observe("inter_token", gap_ms)
+            gap_s = (now - self.last) / count
+        if gaps:
+            for _ in range(gaps):
+                self._itl.observe(gap_s)
+            self._phase_hist.observe("inter_token", gap_s * 1e3, gaps)
         self.last = now
-        self.metrics.output_tokens.labels(self.model).inc(count)
+        self._output_tokens.inc(count)

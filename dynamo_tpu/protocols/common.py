@@ -119,7 +119,11 @@ class PreprocessedRequest:
 
 @dataclass
 class LLMEngineOutput:
-    """One streamed engine step result (a delta, token-space)."""
+    """One streamed engine item (a delta, token-space): what one dispatch
+    produced for the sequence, one token or several (a horizon's, a verify
+    pass's accepted run). `log_probs` and `top_logprobs`, where present,
+    hold one entry a token of `token_ids`, position for position. A finish
+    or an error comes as an item of its own, behind the tokens."""
 
     token_ids: list[int] = field(default_factory=list)
     text: Optional[str] = None  # engines that detokenize themselves

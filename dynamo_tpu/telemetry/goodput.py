@@ -102,6 +102,13 @@ SAMPLER_COUNTERS = ("dispatches", "pool_dispatches")
 SSM_COUNTERS = ("layer_steps", "slots_live", "slot_resets", "scan_tokens")
 
 
+# the stream edge of an engine: `items` put on sequences' streams that carried
+# tokens (one holds what one dispatch produced for one sequence) and the
+# `tokens` they carried, counted together where an item is put, so that
+# tokens an item over any window is two reads
+STREAM_COUNTERS = ("items", "tokens")
+
+
 # what a label's first dispatch held beside the device's work
 # (`first_dispatch_by_label`, beside `compile_s_by_label`): the seconds JAX
 # itself reports on the dispatching thread for tracing, for building the MLIR
@@ -183,6 +190,7 @@ class GoodputStats:
         "moe",
         "sampler",
         "ssm",
+        "stream",
     )
 
     def __init__(self) -> None:
@@ -228,6 +236,8 @@ class GoodputStats:
         self.sampler: dict[str, int] = {}
         # SSM_COUNTERS (empty for a model without recurrent layers)
         self.ssm: dict[str, int] = {}
+        # STREAM_COUNTERS
+        self.stream: dict[str, int] = {}
 
     # ------------------------------------------------------------- query
 
@@ -295,6 +305,8 @@ class GoodputStats:
             self.sampler[k] = self.sampler.get(k, 0) + v
         for k, v in other.ssm.items():
             self.ssm[k] = self.ssm.get(k, 0) + v
+        for k, v in other.stream.items():
+            self.stream[k] = self.stream.get(k, 0) + v
 
     def _merge_first_dispatch(self, label: str, split: dict) -> None:
         """Field by field the larger, as `compile_s_by_label` takes the
@@ -338,6 +350,7 @@ class GoodputStats:
             "moe": dict(self.moe),
             "smp": dict(self.sampler),
             "ssm": dict(self.ssm),
+            "str": dict(self.stream),
         }
 
     @classmethod
@@ -376,6 +389,9 @@ class GoodputStats:
         for k, v in (d.get("ssm") or {}).items():
             if k in SSM_COUNTERS:
                 out.ssm[k] = int(v)
+        for k, v in (d.get("str") or {}).items():
+            if k in STREAM_COUNTERS:
+                out.stream[k] = int(v)
         return out
 
     # ------------------------------------------------------------- debug
@@ -418,6 +434,7 @@ class GoodputStats:
             "moe": {k: self.moe.get(k, 0.0) for k in MOE_COUNTERS},
             "sampler": {k: self.sampler.get(k, 0) for k in SAMPLER_COUNTERS},
             "ssm": {k: self.ssm.get(k, 0) for k in SSM_COUNTERS},
+            "stream": {k: self.stream.get(k, 0) for k in STREAM_COUNTERS},
         }
 
 
@@ -534,6 +551,13 @@ class GoodputLedger(GoodputStats):
             layers * decode_steps, lanes * decode_steps, resets, scan_tokens,
         )):
             self.ssm[k] = self.ssm.get(k, 0) + int(v)
+
+    def record_stream(self, tokens: int) -> None:
+        """One item of `tokens` tokens put on a sequence's stream."""
+        if not self.enabled:
+            return
+        self.stream["items"] = self.stream.get("items", 0) + 1
+        self.stream["tokens"] = self.stream.get("tokens", 0) + tokens
 
     def record_decode_tokens(self, n: int = 1) -> None:
         if self.enabled:

@@ -62,12 +62,14 @@ class PhaseHistogram:
 
     # ------------------------------------------------------------ record
 
-    def observe(self, value_ms: float) -> None:
+    def observe(self, value_ms: float, n: int = 1) -> None:
+        """`n` observations of `value_ms` (an item of n tokens is n
+        inter-token gaps of its arrival gap over n)."""
         if value_ms < 0:
             value_ms = 0.0
-        self.counts[bucket_index(value_ms)] += 1
-        self.count += 1
-        self.sum_ms += value_ms
+        self.counts[bucket_index(value_ms)] += n
+        self.count += n
+        self.sum_ms += value_ms * n
 
     # ------------------------------------------------------------- merge
 
@@ -203,11 +205,11 @@ class PhaseHistograms:
     ) -> None:
         self.phases: dict[str, PhaseHistogram] = phases or {}
 
-    def observe(self, phase: str, value_ms: float) -> None:
+    def observe(self, phase: str, value_ms: float, n: int = 1) -> None:
         h = self.phases.get(phase)
         if h is None:
             h = self.phases[phase] = PhaseHistogram()
-        h.observe(value_ms)
+        h.observe(value_ms, n)
 
     def get(self, phase: str) -> Optional[PhaseHistogram]:
         return self.phases.get(phase)

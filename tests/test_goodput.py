@@ -344,12 +344,17 @@ async def test_mocker_preempt_replay_waste():
         collect(engine, req(list(range(40, 48)), max_tokens=30,
                             priority="interactive"))
     )
-    await asyncio.wait_for(
+    streams = await asyncio.wait_for(
         asyncio.gather(bulk_task, inter_task), timeout=30
     )
     gp = engine.stats()["goodput"]
     n_preempt = sum(engine.preemptions_by_class.values())
     assert n_preempt >= 1
+    # the token whose block found no room is streamed before anyone is
+    # preempted for it: both streams keep their requested count
+    for (toks, final), first in zip(streams, (1, 40)):
+        assert final.finish_reason is FinishReason.LENGTH
+        assert toks == [first + i % 8 for i in range(30)]
     # each preemption wasted at least the victim's 8-token prompt
     assert gp.waste_by_cause["preempt_replay"] >= 8 * n_preempt
     await engine.close()

@@ -42,7 +42,7 @@ SAMPLING = {
 }
 
 
-def make_engine(num_blocks=64):
+def make_engine(num_blocks=64, decode_horizon=4):
     """A two-layer toy at horizon 4 with mixed steps and 8-token chunks, in
     float32: there the CPU's compiler gives both trees one program, bit for
     bit. (A bfloat16 toy's tokens are not the parent's on the CPU: XLA drops
@@ -61,7 +61,7 @@ def make_engine(num_blocks=64):
     )
     return JaxEngine(runner, JaxEngineConfig(
         max_batch=4, block_size=4, num_blocks=num_blocks, max_model_len=64,
-        watermark_blocks=2, mixed_step=True, decode_horizon=4,
+        watermark_blocks=2, mixed_step=True, decode_horizon=decode_horizon,
         preempt_backoff_ms=1.0,
     ))
 

@@ -114,6 +114,10 @@ INTENTIONALLY_SHARED = {
     "dyn_llm_ssm_slots_live",
     "dyn_llm_ssm_slot_resets",
     "dyn_llm_ssm_scan_tokens",
+    # the stream edge (ISSUE 39): items put on sequences' streams and the
+    # tokens they carried; the same shared goodput surface
+    "dyn_llm_stream_items",
+    "dyn_llm_stream_tokens",
     # decision provenance plane (ISSUE 20): every control-plane process
     # (frontend, metrics component, standalone router) exports its OWN
     # ledger's decision counts — decisions are made where they are

@@ -390,16 +390,17 @@ async def test_logprobs_populated():
     async for out in engine.generate(req, Context()):
         if out.token_ids:
             outs.append(out)
-    assert len(outs) == 4
+    # an item carries what one dispatch produced: its lists are aligned
+    assert sum(len(out.token_ids) for out in outs) == 4
     for out in outs:
-        assert out.log_probs is not None and len(out.log_probs) == 1
-        assert out.log_probs[0] <= 0.0
-        assert out.top_logprobs is not None
-        tops = out.top_logprobs[0]
-        assert len(tops) == 3
-        # greedy: the chosen token leads the top list
-        assert tops[0][0] == out.token_ids[0]
-        assert tops[0][1] == pytest.approx(out.log_probs[0], rel=1e-5)
+        assert out.log_probs is not None and out.top_logprobs is not None
+        assert len(out.log_probs) == len(out.top_logprobs) == len(out.token_ids)
+        for tok, lp, tops in zip(out.token_ids, out.log_probs, out.top_logprobs):
+            assert lp <= 0.0
+            assert len(tops) == 3
+            # greedy: the chosen token leads the top list
+            assert tops[0][0] == tok
+            assert tops[0][1] == pytest.approx(lp, rel=1e-5)
     await engine.close()
 
 
