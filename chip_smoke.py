@@ -415,10 +415,12 @@ def check_ledger(
         any(l in ("prefill_packed", "prefill_chunk") for l in labels),
         f"no packed or chunked prefill dispatched: {labels}",
     )
-    check(
-        not goodput["recompiles"],
-        f"a program compiled twice: {goodput['recompiles']}",
-    )
+    # a `stall` is the host's, not a compile (telemetry/goodput.py)
+    compiled_twice = {
+        k: v for k, v in goodput["recompiles"].items()
+        if not k.endswith("|stall")
+    }
+    check(not compiled_twice, f"a program compiled twice: {compiled_twice}")
     check(facts["decode_horizon"] == 4, f"decode horizon {facts}")
     check(facts["mixed_step"] is True, f"mixed steps off: {facts}")
     check(not facts["kv_quantized"], "KV cache is not bf16")

@@ -418,7 +418,8 @@ def goodput_families(
         f"{PREFIX}_recompiles",
         "Unexpected post-warmup XLA recompiles by dispatch label and "
         "cause (shape_miss = unbucketed shape; prebake_miss = drifted "
-        "prebaked cache)",
+        "prebaked cache; stall = as long, but outside the jitted call: "
+        "the host stalled, nothing compiled)",
         labels=["label", "cause"],
     )
     for key, v in sorted((gp.recompiles if gp is not None else {}).items()):
@@ -476,6 +477,20 @@ def goodput_families(
             f"{PREFIX}_stream_{name}",
             f"Stream edge: {what} (counted where an item is put; fleet sum)",
             value=float(stream.get(name, 0)),
+        )
+    launch = gp.launch if gp is not None else {}
+    for name, what in (
+        ("dispatches", "dispatches whose runner counted its launch"),
+        ("upload_arrays", "host arrays those dispatches committed to the "
+         "device"),
+        ("upload_bytes", "bytes of those arrays"),
+        ("fetch_bytes", "bytes of the results read back to the host"),
+    ):
+        yield CounterMetricFamily(
+            f"{PREFIX}_launch_{name}",
+            f"Launch: {what} (counted by the runner where it commits and "
+            "reads; fleet sum)",
+            value=float(launch.get(name, 0)),
         )
     comp = GaugeMetricFamily(
         f"{PREFIX}_compile_seconds",

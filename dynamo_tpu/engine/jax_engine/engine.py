@@ -49,7 +49,9 @@ from dynamo_tpu.telemetry.goodput import (
     GoodputLedger,
     RecompileDetector,
     first_dispatch_split,
+    launch_parts,
     load_prebaked_labels,
+    long_part,
     normalize_label,
 )
 from dynamo_tpu.telemetry.histogram import PhaseHistograms
@@ -770,44 +772,54 @@ class JaxEngine:
 
         first = label not in self._dispatch_ema
         split: dict = {}  # FIRST_DISPATCH_FIELDS, filled by a first dispatch
+        launch = self.runner.launch  # what the call costs at the device's edge
+
+        # event-loop thread: hop to the executor, upload, launch, fetch,
+        # and the wait for the event loop to resume this task. The phase's
+        # own pair of clock readings is the dispatch's start and length for
+        # the watchdog, the EMA and the ledger too
+        dispatch = dtrace.phase(
+            "loop.dispatch", label=label, lanes=lanes,
+            ctx_tokens=ctx_tokens, prefill_tokens=tokens,
+            horizon=horizon, first=first,
+            pool=int(pool),
+            state_slots=(
+                sum(s is not None for s in self.slots)
+                if self._recurrent_layers else 0
+            ),
+        )
+        call = dtrace.phase("runner.call", label=label)
 
         def run():
-            # executor thread: the runner function and its fetch (and, the
-            # first time, JAX's trace, lowering and compile or cache read)
-            with dtrace.phase("runner.call", label=label):
+            # executor thread: the runner function and its fetch, each
+            # part a child phase of the runner's own (`runner.upload`,
+            # `runner.enqueue`, which the first time holds JAX's trace,
+            # lowering and compile or cache read, and `runner.fetch`)
+            launch.clear()
+            with call:
                 if not first:
                     return fn()
                 with first_dispatch_split(split):
                     return fn()
 
         loop = asyncio.get_running_loop()
-        self._dispatch_info = (label, time.monotonic())
-        t0 = self._dispatch_info[1]
         try:
-            # event-loop thread: hop to the executor, upload, launch, fetch,
-            # and the wait for the event loop to resume this task
-            with dtrace.phase(
-                "loop.dispatch", label=label, lanes=lanes,
-                ctx_tokens=ctx_tokens, prefill_tokens=tokens,
-                horizon=horizon, first=first,
-                pool=int(pool),
-                state_slots=(
-                    sum(s is not None for s in self.slots)
-                    if self._recurrent_layers else 0
-                ),
-            ):
+            with dispatch:
+                t0 = dispatch.start_s
+                self._dispatch_info = (label, t0)
                 result = await loop.run_in_executor(None, run)
-            if slow_factor > 1.0:
-                # injected gray-worker fault: stretch the dispatch to
-                # FACTOR times its real duration (the device did the work;
-                # the worker is throttled, not wedged — the watchdog's EMA
-                # budget tracks the stretched time so it doesn't trip)
-                await asyncio.sleep(
-                    (slow_factor - 1.0) * (time.monotonic() - t0)
-                )
+                if slow_factor > 1.0:
+                    # injected gray-worker fault: stretch the dispatch to
+                    # FACTOR times its real duration (the device did the
+                    # work; the worker is throttled, not wedged — the
+                    # watchdog's EMA budget tracks the stretched time so it
+                    # doesn't trip)
+                    await asyncio.sleep(
+                        (slow_factor - 1.0) * (time.monotonic() - t0)
+                    )
             return result
         finally:
-            elapsed = time.monotonic() - t0
+            elapsed = dispatch.seconds
             self._dispatch_info = None
             ema = self._dispatch_ema.get(label)
             self._dispatch_ema[label] = (
@@ -835,16 +847,26 @@ class JaxEngine:
                             shape=f"lanes={lanes},tokens={tokens}",
                         )
                 elif self._recompile.is_recompile(elapsed, ema):
-                    cause = (
-                        "prebake_miss"
-                        if normalize_label(label) in self._prebaked_labels
-                        else "shape_miss"
+                    # a compile can only happen inside the jitted call;
+                    # a dispatch that was long elsewhere is the host's stall
+                    parts = launch_parts(
+                        elapsed, max(0.0, call.start_s - t0), call.seconds,
+                        launch,
                     )
-                    gp.record_recompile(
-                        label,
-                        cause,
-                        shape=f"lanes={lanes},tokens={tokens}",
-                    )
+                    if long_part(parts) == "enqueue":
+                        gp.record_recompile(
+                            label,
+                            "prebake_miss"
+                            if normalize_label(label) in self._prebaked_labels
+                            else "shape_miss",
+                            shape=f"lanes={lanes},tokens={tokens}",
+                        )
+                    else:
+                        gp.record_stall(label, parts)
+                gp.record_launch(
+                    launch.upload_arrays, launch.upload_bytes,
+                    launch.fetch_bytes,
+                )
                 gp.record_step(
                     label,
                     elapsed,
@@ -3049,7 +3071,7 @@ class JaxEngine:
         async with self._device_lock:
             packed = await self._dispatch(
                 "spec_verify",
-                lambda: np.asarray(
+                lambda: self.runner.fetch_horizon(
                     self.runner.spec_verify(
                         K, E,
                         self._tokens, draft_arr, draft_len,
@@ -3185,7 +3207,7 @@ class JaxEngine:
                 # the label names the program that ran: a ledger that
                 # shows only "decode" served at H=1
                 f"decode_multi@H{H}B{self.config.max_batch}",
-                lambda: np.asarray(
+                lambda: self.runner.fetch_horizon(
                     self.runner.decode_multi(
                         H,
                         self._tokens, self._positions, self._block_tables,

@@ -36,6 +36,7 @@ from dynamo_tpu.ops.sampling import (
     spec_accept_len,
 )
 from dynamo_tpu.runtime.logging import get_logger
+from dynamo_tpu.telemetry import trace as dtrace
 
 logger = get_logger("dynamo_tpu.engine.runner")
 
@@ -87,6 +88,28 @@ def unrolled_steps(step, init, H: int):
         carry, y = step(carry, jnp.int32(h))
         ys.append(y)
     return carry, jnp.stack(ys)
+
+
+class Launch:
+    """What the runner's calls cost at the host's edge of the device since
+    the record was last cleared: the host arrays committed (`_to_dev`) and
+    their bytes, the bytes read back, and the seconds of the three phases
+    `runner.upload`, `runner.enqueue` and `runner.fetch`. The engine clears
+    it before a dispatch's call and reads it after (`JaxEngine._dispatch`:
+    the ledger's `launch` slot, and which part a long dispatch was long in).
+    Runner calls are serialised, so plain additions do."""
+
+    __slots__ = (
+        "upload_arrays", "upload_bytes", "fetch_bytes",
+        "upload_s", "enqueue_s", "fetch_s",
+    )
+
+    def __init__(self) -> None:
+        self.clear()
+
+    def clear(self) -> None:
+        self.upload_arrays = self.upload_bytes = self.fetch_bytes = 0
+        self.upload_s = self.enqueue_s = self.fetch_s = 0.0
 
 
 class ModelRunner:
@@ -188,6 +211,7 @@ class ModelRunner:
         self.cp_min_tokens = cp_min_tokens
         self._rng_seed = rng_seed
         self._pack_fetch_jit = None  # lazy: see fetch_sample
+        self.launch = Launch()
         self._step_counter = 0
         self._key_offset = 0  # monotonic decode-key counter (never reused)
         self.prefill_buckets = sorted(
@@ -1048,7 +1072,7 @@ class ModelRunner:
         top_logprobs) tuple per chunk slot (meaningful only on a final
         chunk) and one for the decode batch."""
         C = self.prefill_chunk_tokens
-        dev_chunks = []
+        host_chunks = []
         slots = self._lane_slots(state_slots, len(chunks))
         for i, (token_chunk, chunk_start, total_len, block_ids, temperature,
                 top_p, top_k, rep_pen, key_data, c_eos_ids,
@@ -1062,34 +1086,24 @@ class ModelRunner:
                 key_data = self._next_key_data()
             if c_eos_ids is None:
                 c_eos_ids = np.full(MAX_EOS_IDS, -1, np.int32)
-            dev_chunks.append((
-                self._to_dev(ctoks),
-                self._to_dev(np.int32(chunk_start)),
-                self._to_dev(np.int32(total_len)),
-                self._to_dev(table),
-                self._to_dev(key_data),
-                self._to_dev(np.float32(temperature)),
-                self._to_dev(np.float32(top_p)),
-                self._to_dev(np.int32(top_k)),
-                self._to_dev(np.float32(rep_pen)),
-                self._to_dev(np.asarray(c_eos_ids, np.int32)),
-                self._to_dev(np.bool_(c_eos_suppress)),
-                *(() if slots is None else (self._to_dev(slots[i]),)),
+            host_chunks.append((
+                ctoks, np.int32(chunk_start), np.int32(total_len), table,
+                key_data, np.float32(temperature), np.float32(top_p),
+                np.int32(top_k), np.float32(rep_pen),
+                np.asarray(c_eos_ids, np.int32), np.bool_(c_eos_suppress),
+                *(() if slots is None else (slots[i],)),
             ))
         B = len(np.asarray(tokens))
         if eos_ids is None:
             eos_ids = np.full((B, MAX_EOS_IDS), -1, np.int32)
         if eos_suppress is None:
             eos_suppress = np.zeros(B, bool)
-        k = len(dev_chunks)
-        out, self.k_cache, self.v_cache = self._mixed_jit_for(k)(
-            self.params, self.k_cache, self.v_cache, tuple(dev_chunks),
-            self._to_dev(tokens), self._to_dev(positions),
-            self._to_dev(block_tables), self._to_dev(slot_indices),
-            self._to_dev(keys), self._to_dev(temps),
-            self._to_dev(top_ps), self._to_dev(top_ks),
-            self._to_dev(np.asarray(eos_ids, np.int32)),
-            self._to_dev(np.asarray(eos_suppress, bool)),
+        k = len(host_chunks)
+        out = self._launch(
+            self._mixed_jit_for(k), tuple(host_chunks),
+            tokens, positions, block_tables, slot_indices, keys, temps,
+            top_ps, top_ks, np.asarray(eos_ids, np.int32),
+            np.asarray(eos_suppress, bool),
         )
         chunk_outs = tuple(out[4 * i: 4 * i + 4] for i in range(k))
         return chunk_outs, tuple(out[4 * k: 4 * k + 4])
@@ -1115,7 +1129,7 @@ class ModelRunner:
                     else {}
                 ),
             )
-        flat = np.asarray(self._pack_fetch_jit(*out))
+        flat = self._fetch(out, pack=self._pack_fetch_jit)
         outs: list[np.ndarray] = []
         off = 0
         for o in out:
@@ -1176,19 +1190,70 @@ class ModelRunner:
     def _to_dev(self, a) -> jax.Array:
         """Commit a host input: local array normally; fully-replicated
         GLOBAL array under multi-controller (all processes pass the same
-        value — the SPMD step channel guarantees it)."""
+        value — the SPMD step channel guarantees it). What is on the device
+        already stays as it is and is not counted."""
+        host = isinstance(a, (np.ndarray, np.generic))
+        if not host and isinstance(a, jax.Array):
+            return a
         if self._repl is not None:
             a = np.asarray(a)
-            return jax.make_array_from_process_local_data(
+            out = jax.make_array_from_process_local_data(
                 self._repl, a, global_shape=a.shape
             )
-        return jnp.asarray(a)
+        else:
+            out = jnp.asarray(a)
+        self.launch.upload_arrays += 1
+        # numpy's own count where there is one: a device array's `nbytes`
+        # is a product computed in Python, a microsecond
+        self.launch.upload_bytes += a.nbytes if host else out.nbytes
+        return out
 
-    def _fetch(self, x) -> np.ndarray:
-        """Host-side read of a (replicated) device result."""
-        if self._repl is not None:
-            return np.asarray(x.addressable_data(0))
-        return np.asarray(jax.device_get(x))
+    def _commit(self, host):
+        """`_to_dev` of every array in a (nested) tuple of host inputs."""
+        if isinstance(host, tuple):
+            return tuple(self._commit(h) for h in host)
+        return self._to_dev(host)
+
+    def _launch(self, program, *host, static: tuple = (), **host_kw):
+        """Commit a step's host inputs and call its program on them, behind
+        `params` and the cache arrays, which the program hands back. The two
+        halves of every step method, so that each is one span: all of the
+        call's `_to_dev`s in `runner.upload`; the jitted call up to the
+        return of its output arrays in `runner.enqueue` (a label's first
+        time, JAX's trace, lowering and compile; warm, argument handling and
+        the hand-over to the runtime: the device's work is waited for in
+        `runner.fetch`)."""
+        with dtrace.phase("runner.upload") as up:
+            dev = self._commit(host)
+            dev_kw = {k: self._commit(v) for k, v in host_kw.items()}
+        with dtrace.phase("runner.enqueue") as enq:
+            out, self.k_cache, self.v_cache = program(
+                *static, self.params, self.k_cache, self.v_cache,
+                *dev, **dev_kw,
+            )
+        self.launch.upload_s += up.seconds
+        self.launch.enqueue_s += enq.seconds
+        return out
+
+    def _fetch(self, x, pack=None) -> np.ndarray:
+        """Host-side read of a (replicated) device result, the one place a
+        step's result reaches the host (`runner.fetch`): `pack`, where
+        given, first makes one device array of a tuple of them."""
+        with dtrace.phase("runner.fetch") as ph:
+            if pack is not None:
+                x = pack(*x)
+            if self._repl is not None:
+                out = np.asarray(x.addressable_data(0))
+            else:
+                out = np.asarray(jax.device_get(x))
+        self.launch.fetch_bytes += out.nbytes
+        self.launch.fetch_s += ph.seconds
+        return out
+
+    def fetch_horizon(self, packed: jax.Array) -> np.ndarray:
+        """The packed array of a `decode_multi` or `spec_verify` dispatch as
+        host numpy: the horizon's one fetch."""
+        return self._fetch(packed)
 
     # -------------------------------------------------------------- calls
 
@@ -1239,18 +1304,12 @@ class ModelRunner:
             key_data = self._next_key_data()
         if eos_ids is None:
             eos_ids = np.full(MAX_EOS_IDS, -1, np.int32)
-        out, self.k_cache, self.v_cache = prefill_fn(
-            self.params, self.k_cache, self.v_cache,
-            self._to_dev(tokens), self._to_dev(np.int32(T)),
-            self._to_dev(table), self._to_dev(key_data),
-            self._to_dev(np.float32(temperature)),
-            self._to_dev(np.float32(top_p)), self._to_dev(np.int32(top_k)),
-            self._to_dev(np.float32(rep_pen)),
-            self._to_dev(np.asarray(eos_ids, np.int32)),
-            self._to_dev(np.bool_(eos_suppress)),
-            *self._slot_args(state_slots),
+        return self._launch(
+            prefill_fn, tokens, np.int32(T), table, key_data,
+            np.float32(temperature), np.float32(top_p), np.int32(top_k),
+            np.float32(rep_pen), np.asarray(eos_ids, np.int32),
+            np.bool_(eos_suppress), *self._slot_args(state_slots),
         )
-        return out
 
     def prefill_mm(
         self,
@@ -1292,25 +1351,14 @@ class ModelRunner:
             eos_ids = np.full(MAX_EOS_IDS, -1, np.int32)
         # device-path embeddings (already jax arrays, e.g. handed over via
         # transfer_embeds_device) stay on device; host payloads upload here
-        mm_dev = (
-            mm_embeds
-            if isinstance(mm_embeds, jax.Array)
-            else self._to_dev(np.asarray(mm_embeds, np.float32))
+        if not isinstance(mm_embeds, jax.Array):
+            mm_embeds = np.asarray(mm_embeds, np.float32)
+        return self._launch(
+            self._prefill_mm_jit, tokens, np.int32(T), table, mm_embeds,
+            np.int32(mm_start), key_data, np.float32(temperature),
+            np.float32(top_p), np.int32(top_k), np.float32(rep_pen),
+            np.asarray(eos_ids, np.int32), np.bool_(eos_suppress),
         )
-        out, self.k_cache, self.v_cache = self._prefill_mm_jit(
-            self.params, self.k_cache, self.v_cache,
-            self._to_dev(tokens), self._to_dev(np.int32(T)),
-            self._to_dev(table),
-            mm_dev,
-            self._to_dev(np.int32(mm_start)),
-            self._to_dev(key_data),
-            self._to_dev(np.float32(temperature)),
-            self._to_dev(np.float32(top_p)), self._to_dev(np.int32(top_k)),
-            self._to_dev(np.float32(rep_pen)),
-            self._to_dev(np.asarray(eos_ids, np.int32)),
-            self._to_dev(np.bool_(eos_suppress)),
-        )
-        return out
 
     def prefill_chunk(
         self,
@@ -1347,25 +1395,19 @@ class ModelRunner:
             key_data = self._next_key_data()
         if eos_ids is None:
             eos_ids = np.full(MAX_EOS_IDS, -1, np.int32)
-        out, self.k_cache, self.v_cache = self._chunk_jit(
-            self.params, self.k_cache, self.v_cache,
-            self._to_dev(tokens), self._to_dev(np.int32(chunk_start)),
-            self._to_dev(np.int32(total_len)),
-            self._to_dev(table), self._to_dev(key_data),
-            self._to_dev(np.float32(temperature)),
-            self._to_dev(np.float32(top_p)), self._to_dev(np.int32(top_k)),
-            self._to_dev(np.float32(rep_pen)),
-            self._to_dev(np.asarray(eos_ids, np.int32)),
-            self._to_dev(np.bool_(eos_suppress)),
+        return self._launch(
+            self._chunk_jit, tokens, np.int32(chunk_start),
+            np.int32(total_len), table, key_data, np.float32(temperature),
+            np.float32(top_p), np.int32(top_k), np.float32(rep_pen),
+            np.asarray(eos_ids, np.int32), np.bool_(eos_suppress),
             *self._slot_args(state_slots),
         )
-        return out
 
     def _slot_args(self, state_slots) -> tuple:
         """The trailing argument of a one-sequence prefill call: its lane
-        slot on the device, or nothing."""
+        slot, or nothing."""
         slots = self._lane_slots(state_slots, 1)
-        return () if slots is None else (self._to_dev(slots[0]),)
+        return () if slots is None else (slots[0],)
 
     def embed(self, token_ids: list[int]) -> np.ndarray:
         """Pooled sequence embedding (llama.embed_pooled), bucket-padded;
@@ -1461,17 +1503,12 @@ class ModelRunner:
             eos_ids = np.full((N, MAX_EOS_IDS), -1, np.int32)
         if eos_suppress is None:
             eos_suppress = np.zeros(N, bool)
-        out, self.k_cache, self.v_cache = self._packed_jit(
-            self.params, self.k_cache, self.v_cache,
-            self._to_dev(tokens), self._to_dev(positions),
-            self._to_dev(segment_ids), self._to_dev(slot_indices),
-            self._to_dev(last_idx), self._to_dev(keys),
-            self._to_dev(temps), self._to_dev(top_ps), self._to_dev(top_ks),
-            self._to_dev(rep_pens), self._to_dev(np.asarray(eos_ids, np.int32)),
-            self._to_dev(np.asarray(eos_suppress, bool)),
-            *(() if state_slots is None else (self._to_dev(state_slots),)),
+        return self._launch(
+            self._packed_jit, tokens, positions, segment_ids, slot_indices,
+            last_idx, keys, temps, top_ps, top_ks, rep_pens,
+            np.asarray(eos_ids, np.int32), np.asarray(eos_suppress, bool),
+            *(() if state_slots is None else (state_slots,)),
         )
-        return out
 
     def require_block_transfer(self, what: str) -> None:
         """Blocks leave and enter the cache as `[L, Hkv, n, bs, D]` pairs of
@@ -1732,22 +1769,16 @@ class ModelRunner:
         top_logprobs) device arrays, each batch-major."""
         if keys is None:
             keys = self._next_decode_keys(tokens.shape[0])
-        args = [
-            self.params, self.k_cache, self.v_cache,
-            self._to_dev(tokens), self._to_dev(positions),
-            self._to_dev(block_tables), self._to_dev(slot_indices),
-            self._to_dev(keys),
-            self._to_dev(temps), self._to_dev(top_ps), self._to_dev(top_ks),
-        ]
         if penalties is not None:
-            args.extend(self._to_dev(p) for p in penalties)
-            out, self.k_cache, self.v_cache = self._decode_pen_fn(*args)
+            program, extra = self._decode_pen_fn, penalties
         elif eos_mask is not None:
-            args.extend(self._to_dev(p) for p in eos_mask)
-            out, self.k_cache, self.v_cache = self._decode_eos_fn(*args)
+            program, extra = self._decode_eos_fn, eos_mask
         else:
-            out, self.k_cache, self.v_cache = self._decode_fn(*args)
-        return out
+            program, extra = self._decode_fn, ()
+        return self._launch(
+            program, tokens, positions, block_tables, slot_indices, keys,
+            temps, top_ps, top_ks, *extra,
+        )
 
     def decode_multi(
         self,
@@ -1773,21 +1804,12 @@ class ModelRunner:
         """H chained decode steps; returns the packed [H, B, 2+2*num_top]
         f32 device array (token, logprob, top_ids, top_lps per step) — ONE
         host fetch per horizon. See _decode_multi_impl for freeze rules."""
-        args = (
-            self.params, self.k_cache, self.v_cache,
-            self._to_dev(tokens), self._to_dev(positions),
-            self._to_dev(block_tables), self._to_dev(keys),
-            self._to_dev(temps), self._to_dev(top_ps), self._to_dev(top_ks),
-            self._to_dev(active), self._to_dev(limit_remaining),
-            self._to_dev(min_remaining), self._to_dev(eos_ids),
+        return self._launch(
+            self._decode_multi_fn, tokens, positions, block_tables, keys,
+            temps, top_ps, top_ks, active, limit_remaining, min_remaining,
+            eos_ids, static=(H,),
+            **({} if penalties is None else {"pen": tuple(penalties)}),
         )
-        kwargs = {}
-        if penalties is not None:
-            kwargs["pen"] = tuple(self._to_dev(p) for p in penalties)
-        out, self.k_cache, self.v_cache = self._decode_multi_fn(
-            H, *args, **kwargs
-        )
-        return out
 
     def spec_verify(
         self,
@@ -1835,21 +1857,13 @@ class ModelRunner:
                     else {}
                 ),
             )
-        kwargs = {}
-        if penalties is not None:
-            kwargs["pen"] = tuple(self._to_dev(p) for p in penalties)
-        out, self.k_cache, self.v_cache = self._spec_verify_jit(
-            spec_k + 1, extras,
-            self.params, self.k_cache, self.v_cache,
-            self._to_dev(tokens), self._to_dev(drafts),
-            self._to_dev(draft_len), self._to_dev(positions),
-            self._to_dev(block_tables), self._to_dev(keys),
-            self._to_dev(temps), self._to_dev(top_ps), self._to_dev(top_ks),
-            self._to_dev(active), self._to_dev(limit_remaining),
-            self._to_dev(min_remaining), self._to_dev(eos_ids),
-            **kwargs,
+        return self._launch(
+            self._spec_verify_jit, tokens, drafts, draft_len, positions,
+            block_tables, keys, temps, top_ps, top_ks, active,
+            limit_remaining, min_remaining, eos_ids,
+            static=(spec_k + 1, extras),
+            **({} if penalties is None else {"pen": tuple(penalties)}),
         )
-        return out
 
     def step_stats(self, packed: np.ndarray) -> Optional[dict[str, float]]:
         """What the model counted on the device during one fetched

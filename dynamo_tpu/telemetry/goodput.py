@@ -33,6 +33,9 @@ Recompile causes (`dyn_llm_recompiles_total{label,cause}`):
   * ``prebake_miss`` — same, but the label was pre-baked by
                        `tools/prebake_cache.py` — cache drift, the image
                        no longer matches the serve shapes
+  * ``stall``        — a warm label dispatched as far off its EMA with the
+                       time outside the jitted call, where alone a compile
+                       can happen: the host stalled, nothing compiled
 
 Everything here follows the `telemetry/histogram.py` contract: fixed
 grids, plain-addition merges (associative + commutative), sparse
@@ -69,7 +72,7 @@ WASTE_CAUSES = (
     "hedge_loser",
 )
 
-RECOMPILE_CAUSES = ("shape_miss", "prebake_miss")
+RECOMPILE_CAUSES = ("shape_miss", "prebake_miss", "stall")
 
 # Bound on dict-keyed state: dispatch labels are a small closed set, but
 # a bug (label built from a shape) must never grow the ledger unbounded.
@@ -107,6 +110,41 @@ SSM_COUNTERS = ("layer_steps", "slots_live", "slot_resets", "scan_tokens")
 # `tokens` they carried, counted together where an item is put, so that
 # tokens an item over any window is two reads
 STREAM_COUNTERS = ("items", "tokens")
+
+
+# the launch of a dispatch at the host's edge of the device: `dispatches`
+# whose runner keeps the record, the host arrays they committed
+# (`ModelRunner._to_dev`: `upload_arrays`, `upload_bytes`) and the bytes of
+# the results read back (`fetch_bytes`), counted by the runner where it
+# commits and reads, entered here once a dispatch
+LAUNCH_COUNTERS = ("dispatches", "upload_arrays", "upload_bytes", "fetch_bytes")
+
+# a dispatch in the order it happens: the hop from the event loop to the
+# executor thread, the three phases of the runner's call (`runner.upload`,
+# `runner.enqueue`, `runner.fetch`) and what else the call held, and the
+# wait for the event loop to resume the engine's task
+LAUNCH_PARTS = ("hop", "upload", "enqueue", "fetch", "call_rest", "resume")
+
+
+def launch_parts(
+    elapsed_s: float, hop_s: float, call_s: float, launch: Any
+) -> dict[str, float]:
+    """Where a dispatch of `elapsed_s` spent it, LAUNCH_PARTS in seconds:
+    `hop_s` before its `runner.call` began, `call_s` inside it, of which
+    `launch` (the runner's record: `upload_s`, `enqueue_s`, `fetch_s`)
+    names three parts."""
+    upload, enqueue, fetch = launch.upload_s, launch.enqueue_s, launch.fetch_s
+    return dict(zip(LAUNCH_PARTS, (
+        hop_s, upload, enqueue, fetch, call_s - upload - enqueue - fetch,
+        elapsed_s - hop_s - call_s,
+    )))
+
+
+def long_part(parts: dict[str, float]) -> str:
+    """The part that holds most of a dispatch. A dispatch ten times its
+    usual length (`RecompileDetector`) spent nine tenths of it where it was
+    held up, whichever part usually waits for the device."""
+    return max(LAUNCH_PARTS, key=lambda k: parts.get(k, 0.0))
 
 
 # what a label's first dispatch held beside the device's work
@@ -191,6 +229,7 @@ class GoodputStats:
         "sampler",
         "ssm",
         "stream",
+        "launch",
     )
 
     def __init__(self) -> None:
@@ -238,6 +277,8 @@ class GoodputStats:
         self.ssm: dict[str, int] = {}
         # STREAM_COUNTERS
         self.stream: dict[str, int] = {}
+        # LAUNCH_COUNTERS
+        self.launch: dict[str, int] = {}
 
     # ------------------------------------------------------------- query
 
@@ -307,6 +348,8 @@ class GoodputStats:
             self.ssm[k] = self.ssm.get(k, 0) + v
         for k, v in other.stream.items():
             self.stream[k] = self.stream.get(k, 0) + v
+        for k, v in other.launch.items():
+            self.launch[k] = self.launch.get(k, 0) + v
 
     def _merge_first_dispatch(self, label: str, split: dict) -> None:
         """Field by field the larger, as `compile_s_by_label` takes the
@@ -351,6 +394,7 @@ class GoodputStats:
             "smp": dict(self.sampler),
             "ssm": dict(self.ssm),
             "str": dict(self.stream),
+            "lch": dict(self.launch),
         }
 
     @classmethod
@@ -392,6 +436,9 @@ class GoodputStats:
         for k, v in (d.get("str") or {}).items():
             if k in STREAM_COUNTERS:
                 out.stream[k] = int(v)
+        for k, v in (d.get("lch") or {}).items():
+            if k in LAUNCH_COUNTERS:
+                out.launch[k] = int(v)
         return out
 
     # ------------------------------------------------------------- debug
@@ -435,6 +482,7 @@ class GoodputStats:
             "sampler": {k: self.sampler.get(k, 0) for k in SAMPLER_COUNTERS},
             "ssm": {k: self.ssm.get(k, 0) for k in SSM_COUNTERS},
             "stream": {k: self.stream.get(k, 0) for k in STREAM_COUNTERS},
+            "launch": {k: self.launch.get(k, 0) for k in LAUNCH_COUNTERS},
         }
 
 
@@ -559,6 +607,17 @@ class GoodputLedger(GoodputStats):
         self.stream["items"] = self.stream.get("items", 0) + 1
         self.stream["tokens"] = self.stream.get("tokens", 0) + tokens
 
+    def record_launch(
+        self, upload_arrays: int, upload_bytes: int, fetch_bytes: int
+    ) -> None:
+        """One dispatch's launch, as its runner counted it."""
+        if not self.enabled:
+            return
+        for k, v in zip(LAUNCH_COUNTERS, (
+            1, upload_arrays, upload_bytes, fetch_bytes,
+        )):
+            self.launch[k] = self.launch.get(k, 0) + v
+
     def record_decode_tokens(self, n: int = 1) -> None:
         if self.enabled:
             self.decode_tokens += n
@@ -594,9 +653,7 @@ class GoodputLedger(GoodputStats):
         offending shape — a recompile mid-serving is an SLO incident."""
         if not self.enabled:
             return
-        key = f"{label}|{cause}"
-        if len(self.recompiles) < MAX_LABELS or key in self.recompiles:
-            self.recompiles[key] = self.recompiles.get(key, 0) + 1
+        self._count_recompile(label, cause)
         logger.warning(
             "unexpected recompile of %s (%s): offending shape %s — "
             "a serve-time XLA compile stalls every lane; widen the shape "
@@ -604,6 +661,29 @@ class GoodputLedger(GoodputStats):
             label,
             cause,
             shape or "unknown",
+        )
+
+    def _count_recompile(self, label: str, cause: str) -> None:
+        key = f"{label}|{cause}"
+        if len(self.recompiles) < MAX_LABELS or key in self.recompiles:
+            self.recompiles[key] = self.recompiles.get(key, 0) + 1
+
+    def record_stall(self, label: str, parts: dict[str, float]) -> None:
+        """A warm dispatch as far off its EMA as a recompile would be, but
+        long outside the jitted call (`launch_parts`, `long_part`): nothing
+        compiled, the host held it up. Counted beside the recompiles under
+        the cause `stall`, and WARNed with where the time went."""
+        if not self.enabled:
+            return
+        self._count_recompile(label, "stall")
+        logger.warning(
+            "stalled dispatch of %s: %.3f s, long in its %s (%s) — nothing "
+            "compiled: the time is outside the jitted call (a fetch waits "
+            "for the device, every part for the host's threads)",
+            label,
+            sum(parts.values()),
+            long_part(parts),
+            ", ".join(f"{k} {parts[k]:.3f}" for k in LAUNCH_PARTS),
         )
 
     def mark_idle(self) -> None:

@@ -656,13 +656,18 @@ def _phase_slot(name: str) -> list[int]:
 class phase:
     """Time one process-level phase: `with phase("loop.pack"): ...`.
     Self time is the duration less the phases opened inside it by the same
-    task. Attributes ride the profiler annotation only."""
+    task. Attributes ride the profiler annotation only. Its one pair of
+    clock readings stays readable (`start_s` once entered, `seconds` once
+    left), so that code around a phase times nothing a second time."""
 
-    __slots__ = ("name", "attrs", "_t0", "_children_ns", "_token", "_ann")
+    __slots__ = (
+        "name", "attrs", "_t0", "_dur", "_children_ns", "_token", "_ann",
+    )
 
     def __init__(self, name: str, **attrs: Any) -> None:
         self.name = name
         self.attrs = attrs
+        self._t0 = self._dur = 0
 
     def __enter__(self) -> "phase":
         self._children_ns = 0
@@ -672,7 +677,7 @@ class phase:
         return self
 
     def __exit__(self, et: Any, ev: Any, tb: Any) -> bool:
-        dur = _monotonic_ns() - self._t0
+        dur = self._dur = _monotonic_ns() - self._t0
         if self._ann is not None:
             with contextlib.suppress(Exception):
                 self._ann.__exit__(et, ev, tb)
@@ -689,6 +694,16 @@ class phase:
         slot[1] += dur
         slot[2] += dur - self._children_ns
         return False
+
+    @property
+    def start_s(self) -> float:
+        """When the phase was entered, on `time.monotonic`'s clock."""
+        return self._t0 / 1e9
+
+    @property
+    def seconds(self) -> float:
+        """How long the phase lasted, once it has been left."""
+        return self._dur / 1e9
 
 
 def _annotation(ph: phase) -> Any:

@@ -647,3 +647,104 @@ def test_always_on_step_observe_overhead():
     assert per_op_ns < 2000, (
         f"record_step cost {per_op_ns:.0f}ns/op at a quiet host's speed"
     )
+
+
+# ------------------------------------------- a stall is not a recompile
+
+
+def test_launch_parts_name_where_a_dispatch_was_long():
+    """The hop, the call's three phases, the call's rest and the wait to be
+    resumed add up to the dispatch; the longest names it."""
+    from dynamo_tpu.engine.jax_engine.model_runner import Launch
+    from dynamo_tpu.telemetry.goodput import LAUNCH_PARTS, launch_parts, long_part
+
+    launch = Launch()
+    launch.upload_s, launch.enqueue_s, launch.fetch_s = 0.002, 0.003, 0.050
+    parts = launch_parts(0.9, 0.8, 0.06, launch)
+    assert tuple(parts) == LAUNCH_PARTS
+    assert sum(parts.values()) == pytest.approx(0.9)
+    assert parts["call_rest"] == pytest.approx(0.005) and parts["resume"] == pytest.approx(0.04)
+    assert long_part(parts) == "hop"
+    assert long_part(launch_parts(0.9, 0.001, 0.06, launch)) == "resume"
+    launch.enqueue_s = 2.0
+    assert long_part(launch_parts(2.2, 0.001, 2.1, launch)) == "enqueue"
+
+
+def test_launch_slot_rides_the_wire_and_the_exporter():
+    gp = GoodputLedger(enabled=True)
+    gp.record_launch(11, 9000, 4096)
+    gp.record_launch(21, 30000, 512)
+    want = {"dispatches": 2, "upload_arrays": 32, "upload_bytes": 39000, "fetch_bytes": 4608}
+    assert gp.launch == want and gp.summary()["launch"] == want
+    back = GoodputStats.from_dict(json.loads(json.dumps(gp.to_dict())))
+    assert back.launch == want
+    back.merge(gp)
+    assert back.launch == {k: 2 * v for k, v in want.items()}
+    assert GoodputStats().summary()["launch"] == dict.fromkeys(want, 0)
+    off = GoodputLedger(enabled=False)
+    off.record_launch(1, 1, 1)
+    off.record_stall("decode", {"fetch": 1.0})
+    assert off.launch == {} and off.recompiles == {}
+
+    class Registry:
+        def collect(self):
+            return goodput_families(gp)
+
+    text = generate_latest(Registry()).decode()
+    for name, value in want.items():
+        assert f"dyn_llm_launch_{name}_total {float(value)}" in text
+
+
+@pytest.mark.parametrize("where, label, cause", [
+    ("fetch", "prefill_packed", "stall"), ("enqueue", "decode_multi@H4B4", "shape_miss"),
+])
+async def test_a_long_warm_dispatch_is_a_recompile_only_in_its_jitted_call(
+    monkeypatch, caplog, where, label, cause
+):
+    """A warm dispatch a hundred times its usual length: with the time in
+    the runner's fetch (the next one: a packed prefill's) it is counted and
+    logged as a stall, with the durations and no advice about shape buckets;
+    with the time in the jitted call (`decode_multi`'s) it is the recompile
+    it always was."""
+    import jax
+
+    from tests.test_jax_engine import collect
+    from tests.test_layer_bodies import make_engine, request
+
+    engine = make_engine()
+    runner = engine.runner
+    greedy = SamplingOptions(greedy=True)
+    try:
+        await collect(engine, request([5, 6, 7, 8, 9], 24, greedy))  # warm
+        assert engine.stats.goodput.recompiles == {}
+        # the EMAs still carry the first dispatches' compiles: say what
+        # long-served labels read
+        assert label in engine._dispatch_ema
+        engine._dispatch_ema.update(dict.fromkeys(engine._dispatch_ema, 0.004))
+        slept = []
+
+        def once(real):
+            def slow(*a, **kw):
+                if not slept:
+                    slept.append(time.sleep(0.4))
+                return real(*a, **kw)
+            return slow
+
+        if where == "fetch":
+            monkeypatch.setattr(jax, "device_get", once(jax.device_get))
+        else:
+            monkeypatch.setattr(runner, "_decode_multi_fn", once(runner._decode_multi_fn))
+        with caplog.at_level(logging.WARNING, logger="dynamo_tpu.telemetry.goodput"):
+            await collect(engine, request([9, 8, 7, 6, 5], 24, greedy))
+    finally:
+        await engine.close()
+    assert slept
+    assert engine.stats.goodput.recompiles == {f"{label}|{cause}": 1}
+    said = " ".join(r.getMessage() for r in caplog.records)
+    if cause == "stall":
+        assert f"stalled dispatch of {label}" in said and "long in its fetch" in said
+        for part in ("hop", "upload", "enqueue", "fetch"):
+            assert f"{part} 0." in said
+        assert "shape buckets" not in said and "recompile" not in said
+    else:
+        assert f"unexpected recompile of {label} (shape_miss)" in said

@@ -118,6 +118,12 @@ INTENTIONALLY_SHARED = {
     # tokens they carried; the same shared goodput surface
     "dyn_llm_stream_items",
     "dyn_llm_stream_tokens",
+    # the launch at the host's edge of the device (ISSUE 40): dispatches,
+    # host arrays and bytes committed, bytes read back; the same surface
+    "dyn_llm_launch_dispatches",
+    "dyn_llm_launch_upload_arrays",
+    "dyn_llm_launch_upload_bytes",
+    "dyn_llm_launch_fetch_bytes",
     # decision provenance plane (ISSUE 20): every control-plane process
     # (frontend, metrics component, standalone router) exports its OWN
     # ledger's decision counts — decisions are made where they are

@@ -221,3 +221,148 @@ async def test_engine_loop_records_its_phases_in_order(monkeypatch):
     # nothing of a pass is counted twice or lost
     own = sum(v["self_ms"] for k, v in t.items() if k.startswith("loop."))
     assert own == pytest.approx(t["loop.iter"]["ms"], abs=0.001 * len(t) + 1e-6)
+
+
+# ------------------------------------------------- the launch's three phases
+
+LAUNCH = ["runner.upload", "runner.enqueue", "runner.fetch"]
+
+
+class _Recorded(dtrace.phase):
+    """A phase that also notes its exit: name, thread, and for a
+    `runner.call` its label and what the runner counted for it."""
+
+    log: list = []
+    runner = None
+
+    def __exit__(self, et, ev, tb):
+        out = super().__exit__(et, ev, tb)
+        arrays = self.runner.launch.upload_arrays if self.name == "runner.call" else None
+        self.log.append((self.name, threading.get_ident(), self.attrs.get("label"), arrays))
+        return out
+
+
+async def _serve_toy(monkeypatch, horizon: int):
+    """A short and a long prompt through the toy engine (8-token chunks,
+    mixed steps): a packed prefill, mixed steps while the first decodes, and
+    the horizon's dispatches. Gives the engine and the exits in order."""
+    from tests.test_layer_bodies import make_engine, request
+    from tests.test_jax_engine import collect
+    from dynamo_tpu.protocols.common import SamplingOptions
+
+    _Recorded.log = log = []
+    monkeypatch.setattr(dtrace, "phase", _Recorded)
+    engine = make_engine(decode_horizon=horizon)
+    _Recorded.runner = engine.runner
+    greedy = SamplingOptions(greedy=True)
+    try:
+        first = asyncio.ensure_future(collect(engine, request([5, 6, 7, 8, 9], 24, greedy)))
+        await asyncio.sleep(0.3)  # the first decodes when the long one arrives
+        await collect(engine, request(list(range(1, 30)), 6, greedy))
+        await first
+    finally:
+        await engine.close()
+    return engine, log
+
+
+def _arrays_by_the_code(label: str) -> set[int]:
+    """Host arrays a call of `label` commits without penalties: the lane
+    arrays, and eleven a chunk of a mixed step beside its ten."""
+    if label.startswith("mixed_step@c"):
+        return {11 * int(label.rsplit("c", 1)[1]) + 10}
+    return {"decode_multi@H4B4": {11}, "decode": {8, 10}, "prefill_packed": {12}}[label]
+
+
+@pytest.mark.parametrize("horizon, labels", [
+    (4, ("prefill_packed", "mixed_step@c", "decode_multi@H4B4")),
+    (1, ("prefill_packed", "mixed_step@c", "decode")),
+])
+async def test_every_runner_call_has_its_three_children(monkeypatch, horizon, labels):
+    """Each `runner.call` holds exactly one `runner.upload`, one
+    `runner.enqueue` and one `runner.fetch`, in that order, on its own
+    thread; the three are all of its children (their ms are its ms less its
+    self ms); and the ledger's `launch` slot counts what the runner counted:
+    11 host arrays for a `decode_multi` without penalties."""
+    engine, log = await _serve_toy(monkeypatch, horizon)
+    calls: dict[str, list] = {}
+    since: list = []
+    for name, thread, label, arrays in log:
+        if name in LAUNCH:
+            since.append((name, thread))
+        elif name == "runner.call":
+            assert [n for n, _ in since] == LAUNCH, (label, since)
+            assert {t for _, t in since} == {thread}
+            calls.setdefault(label, []).append(arrays)
+            since = []
+    assert not since
+    for label in labels:
+        assert any(seen.startswith(label) for seen in calls), (label, sorted(calls))
+    t = dtrace.phase_summary()
+    n_calls = sum(len(v) for v in calls.values())
+    assert t["runner.call"]["count"] == n_calls == t["loop.dispatch"]["count"]
+    for name in LAUNCH:
+        assert t[name]["count"] == n_calls
+        assert t[name]["ms"] == t[name]["self_ms"]  # no phase inside them
+    assert sum(t[n]["ms"] for n in LAUNCH) == pytest.approx(
+        t["runner.call"]["ms"] - t["runner.call"]["self_ms"], abs=5e-3
+    )
+    for label, counted in calls.items():
+        assert set(counted) <= _arrays_by_the_code(label), (label, counted)
+    launch = engine.stats.goodput.launch
+    assert launch["dispatches"] == n_calls
+    assert launch["upload_arrays"] == sum(sum(v) for v in calls.values())
+    assert launch["upload_bytes"] > 0 and launch["fetch_bytes"] > 0
+    if horizon == 4:
+        multi = calls["decode_multi@H4B4"]
+        assert sum(multi) / len(multi) == 11
+
+
+async def test_the_launch_phases_are_annotations_on_the_executor_thread(monkeypatch, tmp_path):
+    """While a profile window is open the three lie in the trace as
+    `dyn:runner.*`, on the line of `dyn:runner.call` (the executor's
+    thread) and inside it, not on the event loop's line."""
+    from jax.profiler import ProfileData
+
+    info = dprofile.start(60.0, str(tmp_path))
+    assert "error" not in info, info
+    try:
+        await _serve_toy(monkeypatch, 4)
+    finally:
+        dprofile.stop()
+    found = glob.glob(os.path.join(info["profile_dir"], "**", "*.xplane.pb"), recursive=True)
+    assert found
+    lines = {}
+    for plane in ProfileData.from_file(found[0]).planes:
+        for line in plane.lines:
+            evs = [(ev.name[4:], ev.start_ns, ev.start_ns + ev.duration_ns)
+                   for ev in line.events if ev.name.startswith("dyn:")]
+            if evs:
+                lines[(plane.name, line.name)] = evs
+    executor = [evs for evs in lines.values() if any(n == "runner.call" for n, _, _ in evs)]
+    assert executor
+    n_calls = n_kids = 0
+    for evs in executor:
+        assert not any(n.startswith("loop.") for n, _, _ in evs)
+        calls = sorted((s, e) for n, s, e in evs if n == "runner.call")
+        n_calls += len(calls)
+        for name in LAUNCH:
+            kids = [(s, e) for n, s, e in evs if n == name]
+            n_kids += len(kids)
+            for s, e in kids:
+                assert any(lo <= s and e <= hi for lo, hi in calls), name
+    assert n_kids == 3 * n_calls > 0
+    for evs in lines.values():
+        if any(n == "loop.dispatch" for n, _, _ in evs):
+            assert not any(n in LAUNCH for n, _, _ in evs)
+
+
+def test_a_phase_keeps_its_start_and_duration_readable():
+    """`_dispatch` reads the dispatch's start and length off its phase and
+    times nothing a second time."""
+    before = time.monotonic()
+    with dtrace.phase("loop.dispatch") as ph:
+        assert before <= ph.start_s <= time.monotonic()
+        time.sleep(0.003)
+    assert 0.003 <= ph.seconds < 1.0
+    assert dtrace.phase_summary()["loop.dispatch"]["ms"] == pytest.approx(ph.seconds * 1e3, abs=1e-3)
+    assert dtrace.phase("never.entered").seconds == 0.0
