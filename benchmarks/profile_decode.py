@@ -108,19 +108,21 @@ def main():
     results["decode_serving_ms"] = serving_s * 1e3
 
     # ---- 1. pure compute: device-resident inputs, reuse jitted fn
-    d = lambda a: jax.device_put(a)  # noqa: E731
-    dev_args = [
-        runner.params, runner.k_cache, runner.v_cache,
-        d(tokens), d(positions), d(bt), d(slots), d(keys),
-        d(temps), d(top_ps), d(top_ks),
-    ]
+    # the step's inputs committed once, as `_launch` commits them: one
+    # packed buffer, and the tokens beside it (on the device already) so
+    # that each iteration can feed the last one's sample
+    layout, inputs = runner._commit(
+        jax.device_put(tokens), positions, bt, slots, keys,
+        temps, top_ps, top_ks,
+    )
+    dev_args = [runner.params, runner.k_cache, runner.v_cache, *inputs]
 
     def compute_step(i):
-        out, k2, v2 = runner._decode_fn(*dev_args)
+        out, k2, v2 = runner._decode_fn(layout, *dev_args)
         # donation invalidates the cache refs; rebind for the next call,
         # and chain the sampled tokens so inputs differ every iteration
         dev_args[1], dev_args[2] = k2, v2
-        dev_args[3] = out[0]
+        dev_args[4] = out[0]
         out[0].block_until_ready()
 
     compute_s = bench_it(compute_step, warmup=4, iters=15)

@@ -14,7 +14,7 @@ TPU discipline (SURVEY.md / pallas guide):
 
 from __future__ import annotations
 
-import functools
+import math
 from typing import Any, Optional
 
 import jax
@@ -90,10 +90,73 @@ def unrolled_steps(step, init, H: int):
     return carry, jnp.stack(ys)
 
 
+def _packs(dtype: np.dtype) -> bool:
+    """Whether a host leaf of this dtype rides a step's packed buffer: 32
+    bits wide (viewed as int32, not converted) or a bool (widened to 0/1)."""
+    return dtype == np.bool_ or (dtype.itemsize == 4 and dtype.kind in "iuf")
+
+
+def pack_inputs(tree) -> tuple[tuple, np.ndarray, list]:
+    """A step's host inputs as ONE transfer: every host leaf (`np.ndarray`
+    or `np.generic`) that `_packs` is ravelled into one freshly allocated 1-D
+    int32 array, in the tree's order. Fresh every call: the engine rewrites
+    its lane arrays between dispatches, and a staging buffer kept across
+    them could be rewritten under a transfer that still reads it.
+
+    Returns (layout, buffer, beside). The layout is hashable, derived from
+    the leaves alone: the tree's structure and, a leaf, its (shape, dtype,
+    offset) in the buffer, or None for a leaf that travels beside it as it
+    is (a `jax.Array` already on the device, a host leaf of another width);
+    `beside` holds those, in order. `unpack_inputs` is its inverse on the
+    device."""
+    leaves, treedef = jax.tree_util.tree_flatten(
+        tree, is_leaf=lambda x: isinstance(x, list)
+    )
+    spec, packed, beside, size = [], [], [], 0
+    for leaf in leaves:
+        if isinstance(leaf, (np.ndarray, np.generic)) and _packs(leaf.dtype):
+            spec.append((leaf.shape, leaf.dtype, size))
+            packed.append((size, leaf))
+            size += leaf.size
+        else:
+            spec.append(None)
+            beside.append(leaf)
+    buf = np.empty(size, np.int32)
+    for off, leaf in packed:
+        flat = np.ravel(leaf)
+        # a bool is cast to 0/1 by the assignment; the others keep their bits
+        buf[off:off + flat.size] = (
+            flat if flat.dtype == np.bool_ else flat.view(np.int32)
+        )
+    return (treedef, tuple(spec)), buf, beside
+
+
+def unpack_inputs(layout: tuple, buf: jax.Array, beside):
+    """`pack_inputs`' tree again inside a program, each packed leaf bit for
+    bit with its shape and dtype: static slices of the buffer, reshaped,
+    bitcast to float32 or uint32, `!= 0` for a bool."""
+    treedef, spec = layout
+    beside = iter(beside)
+    leaves = []
+    for entry in spec:
+        if entry is None:
+            leaves.append(next(beside))
+            continue
+        shape, dtype, off = entry
+        x = jax.lax.slice(buf, (off,), (off + math.prod(shape),)).reshape(shape)
+        if dtype == np.bool_:
+            x = x != 0
+        elif dtype != np.int32:
+            x = jax.lax.bitcast_convert_type(x, dtype)
+        leaves.append(x)
+    return jax.tree_util.tree_unflatten(treedef, leaves)
+
+
 class Launch:
     """What the runner's calls cost at the host's edge of the device since
-    the record was last cleared: the host arrays committed (`_to_dev`) and
-    their bytes, the bytes read back, and the seconds of the three phases
+    the record was last cleared: the host arrays committed (`_to_dev`: one
+    array a call of a step, its packed buffer) and their bytes, the bytes
+    read back, and the seconds of the three phases
     `runner.upload`, `runner.enqueue` and `runner.fetch`. The engine clears
     it before a dispatch's call and reads it after (`JaxEngine._dispatch`:
     the ledger's `launch` slot, and which part a long dispatch was long in).
@@ -321,16 +384,13 @@ class ModelRunner:
         jit_kwargs: dict[str, Any] = {}
         if cache_out is not None:
             jit_kwargs["out_shardings"] = cache_out
-        # one jitted callable each; jit's shape cache handles the buckets.
+        # one jitted callable each (`_step_jit`: a step's host inputs arrive
+        # as one packed buffer); jit's shape cache handles the buckets.
         # The FULL mesh rides along (MoE dispatch-path selection in _mlp
         # keys on its ep size); attention shard_maps only when head_axis
         # is set.
-        self._prefill_jit = jax.jit(
-            functools.partial(
-                self._prefill_impl, self.config,
-                self.mesh, self._attn_head_axis,
-            ),
-            donate_argnums=(1, 2),  # k_cache, v_cache
+        self._prefill_jit = self._step_jit(
+            self._prefill_impl, self.config, self.mesh, self._attn_head_axis,
             **jit_kwargs,
         )
         # context-parallel (ring attention) prefill when the mesh has an sp
@@ -346,19 +406,12 @@ class ModelRunner:
             head_axis = (
                 "tp" if mesh.shape.get("tp", 1) > 1 else None
             )
-            self._prefill_cp_jit = jax.jit(
-                functools.partial(
-                    self._prefill_cp_impl, self.config, mesh, head_axis
-                ),
-                donate_argnums=(1, 2),
+            self._prefill_cp_jit = self._step_jit(
+                self._prefill_cp_impl, self.config, mesh, head_axis,
                 **jit_kwargs,
             )
-        self._decode_fn = jax.jit(
-            functools.partial(
-                self._decode_impl, self.config,
-                self.mesh, self._attn_head_axis,
-            ),
-            donate_argnums=(1, 2),  # k_cache, v_cache
+        self._decode_fn = self._step_jit(
+            self._decode_impl, self.config, self.mesh, self._attn_head_axis,
             **jit_kwargs,
         )
         # horizon decode: H chained steps per dispatch (one compile per
@@ -369,45 +422,34 @@ class ModelRunner:
             if kv_sharding is not None
             else None
         )
-        self._decode_multi_fn = jax.jit(
-            functools.partial(
-                self._decode_multi_impl, self.config,
-                self.mesh, self._attn_head_axis, self.block_size,
-            ),
-            static_argnums=(0,),  # H (first arg after the partial binds)
-            donate_argnums=(2, 3),  # k_cache, v_cache
+        self._decode_multi_fn = self._step_jit(
+            self._decode_multi_impl, self.config,
+            self.mesh, self._attn_head_axis, self.block_size,
+            n_static=1,  # H (first arg after the bound ones)
             **({"out_shardings": multi_out} if multi_out is not None else {}),
         )
         # penalty-enabled decode variant: compiled lazily on the first
         # request that sets a penalty, so the hot path (and the bench) stays
         # on the slim program with no history input.
-        self._decode_pen_fn = jax.jit(
-            functools.partial(
-                self._decode_pen_impl, self.config,
-                self.mesh, self._attn_head_axis,
-            ),
-            donate_argnums=(1, 2),  # k_cache, v_cache
+        self._decode_pen_fn = self._step_jit(
+            self._decode_pen_impl, self.config,
+            self.mesh, self._attn_head_axis,
             **jit_kwargs,
         )
         # eos-mask-only variant (min_tokens set, no penalties): masks EOS
         # logits without the [B, max_model_len] history upload the penalty
         # program pays on every step.
-        self._decode_eos_fn = jax.jit(
-            functools.partial(
-                self._decode_eos_impl, self.config,
-                self.mesh, self._attn_head_axis,
-            ),
-            donate_argnums=(1, 2),  # k_cache, v_cache
+        self._decode_eos_fn = self._step_jit(
+            self._decode_eos_impl, self.config,
+            self.mesh, self._attn_head_axis,
             **jit_kwargs,
         )
         # packed batched prefill: N short prompts in ONE [P] program
         # (segment-masked attention); admission batches prompts up to this
         # token budget per engine iteration. Shares the chunk budget so the
         # compile surface stays at one packed + one chunk program.
-        self._packed_jit = jax.jit(
-            functools.partial(self._prefill_packed_impl, self.config, self.mesh),
-            donate_argnums=(1, 2),  # k_cache, v_cache
-            **jit_kwargs,
+        self._packed_jit = self._step_jit(
+            self._prefill_packed_impl, self.config, self.mesh, **jit_kwargs,
         )
         # chunked prefill (vLLM-style): ONE program serves every chunk of
         # every long prompt, letting the engine interleave decode steps
@@ -420,12 +462,8 @@ class ModelRunner:
         self.prefill_chunk_tokens = min(
             prefill_chunk_tokens, self.prefill_buckets[-1]
         )
-        self._chunk_jit = jax.jit(
-            functools.partial(
-                self._prefill_chunk_impl, self.config, self.mesh
-            ),
-            donate_argnums=(1, 2),  # k_cache, v_cache
-            **jit_kwargs,
+        self._chunk_jit = self._step_jit(
+            self._prefill_chunk_impl, self.config, self.mesh, **jit_kwargs,
         )
         # unified mixed step (Sarathi/POD-style): k prefill chunks ride
         # along the full decode batch in ONE device program, so the two
@@ -1035,12 +1073,9 @@ class ModelRunner:
                     self._kv_shard_tree,
                     self._kv_shard_tree,
                 )
-            fn = jax.jit(
-                functools.partial(
-                    self._mixed_impl, self.config,
-                    self.mesh, self._attn_head_axis,
-                ),
-                donate_argnums=(1, 2),  # k_cache, v_cache
+            fn = self._step_jit(
+                self._mixed_impl, self.config,
+                self.mesh, self._attn_head_axis,
                 **kw,
             )
             self._mixed_jits[k] = fn
@@ -1188,10 +1223,12 @@ class ModelRunner:
         return keys
 
     def _to_dev(self, a) -> jax.Array:
-        """Commit a host input: local array normally; fully-replicated
-        GLOBAL array under multi-controller (all processes pass the same
-        value — the SPMD step channel guarantees it). What is on the device
-        already stays as it is and is not counted."""
+        """Commit a host input, one transfer: local array normally;
+        fully-replicated GLOBAL array under multi-controller (all processes
+        pass the same value — the SPMD step channel guarantees it). What is
+        on the device already stays as it is and is not counted. A step
+        commits one array a call, its packed buffer (`_launch`); the block
+        movement paths and `embed` commit theirs one by one."""
         host = isinstance(a, (np.ndarray, np.generic))
         if not host and isinstance(a, jax.Array):
             return a
@@ -1208,28 +1245,53 @@ class ModelRunner:
         self.launch.upload_bytes += a.nbytes if host else out.nbytes
         return out
 
-    def _commit(self, host):
-        """`_to_dev` of every array in a (nested) tuple of host inputs."""
-        if isinstance(host, tuple):
-            return tuple(self._commit(h) for h in host)
-        return self._to_dev(host)
+    @staticmethod
+    def _step_jit(impl, *bound, n_static: int = 0, **jit_kwargs):
+        """The jitted program of a step as `_launch` calls it: behind the
+        layout and the impl's own `n_static` leading arguments (all static)
+        come params, the two caches (donated), the packed buffer and the
+        leaves beside it. It takes the buffer apart (`unpack_inputs`) and
+        calls `impl`, behind its `bound` arguments, with the host arguments
+        `_launch` was given, unchanged."""
+
+        def program(layout, *args):
+            static, args = args[:n_static], args[n_static:]
+            params, k_cache, v_cache, buf, *beside = args
+            host, host_kw = unpack_inputs(layout, buf, beside)
+            return impl(
+                *bound, *static, params, k_cache, v_cache, *host, **host_kw
+            )
+
+        return jax.jit(
+            program,
+            static_argnums=tuple(range(1 + n_static)),
+            donate_argnums=(2 + n_static, 3 + n_static),  # k_cache, v_cache
+            **jit_kwargs,
+        )
+
+    def _commit(self, *host, **host_kw) -> tuple:
+        """A step's host inputs on the device, one array a call: the packed
+        buffer of `pack_inputs` through `_to_dev`, and beside it whatever
+        does not pack (already on the device: untouched and uncounted).
+        Gives the layout and the program's trailing arguments."""
+        layout, buf, beside = pack_inputs((host, host_kw))
+        return layout, (self._to_dev(buf), *map(self._to_dev, beside))
 
     def _launch(self, program, *host, static: tuple = (), **host_kw):
         """Commit a step's host inputs and call its program on them, behind
         `params` and the cache arrays, which the program hands back. The two
-        halves of every step method, so that each is one span: all of the
-        call's `_to_dev`s in `runner.upload`; the jitted call up to the
-        return of its output arrays in `runner.enqueue` (a label's first
-        time, JAX's trace, lowering and compile; warm, argument handling and
-        the hand-over to the runtime: the device's work is waited for in
-        `runner.fetch`)."""
+        halves of every step method, so that each is one span: the pack and
+        its one `_to_dev`, one array a call, in `runner.upload`; the jitted
+        call up to the return of its output arrays in `runner.enqueue` (a
+        label's first time, JAX's trace, lowering and compile; warm,
+        argument handling and the hand-over to the runtime: the device's
+        work is waited for in `runner.fetch`)."""
         with dtrace.phase("runner.upload") as up:
-            dev = self._commit(host)
-            dev_kw = {k: self._commit(v) for k, v in host_kw.items()}
+            layout, dev = self._commit(*host, **host_kw)
         with dtrace.phase("runner.enqueue") as enq:
             out, self.k_cache, self.v_cache = program(
-                *static, self.params, self.k_cache, self.v_cache,
-                *dev, **dev_kw,
+                layout, *static, self.params, self.k_cache, self.v_cache,
+                *dev,
             )
         self.launch.upload_s += up.seconds
         self.launch.enqueue_s += enq.seconds
@@ -1330,12 +1392,9 @@ class ModelRunner:
         text-only deployments never compile it; one program per (bucket,
         num_patches) pair."""
         if not hasattr(self, "_prefill_mm_jit"):
-            self._prefill_mm_jit = jax.jit(
-                functools.partial(
-                    self._prefill_mm_impl, self.config,
-                    self.mesh, self._attn_head_axis,
-                ),
-                donate_argnums=(1, 2),  # k_cache, v_cache
+            self._prefill_mm_jit = self._step_jit(
+                self._prefill_mm_impl, self.config,
+                self.mesh, self._attn_head_axis,
             )
         T = len(token_ids)
         bucket = self.pick_bucket(T)
@@ -1844,13 +1903,10 @@ class ModelRunner:
                 if self._kv_sharding is not None
                 else None
             )
-            self._spec_verify_jit = jax.jit(
-                functools.partial(
-                    self._spec_verify_impl, self.config,
-                    self.mesh, self._attn_head_axis, self.block_size,
-                ),
-                static_argnums=(0, 1),  # S, E
-                donate_argnums=(3, 4),  # k_cache, v_cache
+            self._spec_verify_jit = self._step_jit(
+                self._spec_verify_impl, self.config,
+                self.mesh, self._attn_head_axis, self.block_size,
+                n_static=2,  # S, E
                 **(
                     {"out_shardings": spec_out}
                     if spec_out is not None

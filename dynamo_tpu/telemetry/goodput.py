@@ -114,7 +114,8 @@ STREAM_COUNTERS = ("items", "tokens")
 
 # the launch of a dispatch at the host's edge of the device: `dispatches`
 # whose runner keeps the record, the host arrays they committed
-# (`ModelRunner._to_dev`: `upload_arrays`, `upload_bytes`) and the bytes of
+# (`ModelRunner._to_dev`: `upload_arrays`, one a dispatch, the packed buffer
+# of its host inputs, and `upload_bytes`) and the bytes of
 # the results read back (`fetch_bytes`), counted by the runner where it
 # commits and reads, entered here once a dispatch
 LAUNCH_COUNTERS = ("dispatches", "upload_arrays", "upload_bytes", "fetch_bytes")

@@ -1,0 +1,242 @@
+"""A step's host inputs reach the device as one packed transfer (PR 42).
+
+`model_runner.pack_inputs` writes every 32-bit or bool host leaf of a call
+into one fresh int32 buffer and `unpack_inputs` takes it apart at the top of
+the program, so that the impl receives what it received when each array was
+committed alone. Held here on the CPU: the round trip is bit for bit over
+every kind of leaf a step sends; what is on the device already passes beside
+the buffer and is not counted; a toy engine streams, token for token and
+log-prob for log-prob, what it streams when every leaf travels alone (the
+parent's path, had again by telling `_packs` that nothing packs); and a
+dispatch does not see what the engine wrote into its arrays afterwards.
+"""
+
+import asyncio
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from dynamo_tpu.engine.jax_engine import model_runner as MR
+from dynamo_tpu.protocols.common import (
+    PreprocessedRequest,
+    SamplingOptions,
+    StopConditions,
+)
+from dynamo_tpu.pipeline.context import Context
+from tests.test_layer_bodies import make_engine
+
+NAN_PAYLOAD = np.array([0x7FC12345, 0xFFC00001, 0x7F800001], np.uint32).view(np.float32)
+
+LEAVES = {
+    "int32": np.array([[0, -1, 2**31 - 1], [-(2**31), 7, 8]], np.int32),
+    "uint32_high_bit": np.array([[0x80000000, 0xFFFFFFFF], [0x9E3779B9, 1]], np.uint32),
+    "float32_specials": np.array([-0.0, 0.0, np.inf, -np.inf, 1e-45, 0.7], np.float32),
+    "float32_nan_payload": NAN_PAYLOAD,
+    "bool": np.array([True, False, False, True, True]),
+    "scalar_int32": np.int32(-5),
+    "scalar_float32": np.float32(-0.0),
+    "scalar_bool": np.bool_(True),
+    "scalar_uint32": np.uint32(0xDEADBEEF),
+    "empty": np.zeros((0, 4), np.int32),
+    "strided": np.arange(24, dtype=np.int32).reshape(4, 6)[:, ::2],
+}
+
+
+def roundtrip(tree):
+    layout, buf, beside = MR.pack_inputs(tree)
+    assert buf.dtype == np.int32 and buf.ndim == 1
+    hash(layout)  # a static argument of the program
+    unpack = jax.jit(
+        lambda lay, b, *rest: MR.unpack_inputs(lay, b, rest), static_argnums=0
+    )
+    return layout, buf, beside, unpack(layout, jnp.asarray(buf), *beside)
+
+
+def same_bits(host, dev):
+    dev = np.asarray(dev)
+    assert dev.shape == np.shape(host) and dev.dtype == host.dtype, (dev.dtype, host.dtype)
+    assert dev.tobytes() == np.ascontiguousarray(host).tobytes()
+
+
+@pytest.mark.parametrize("kind", sorted(LEAVES))
+def test_a_leaf_comes_back_bit_for_bit(kind):
+    """Pack, then unpack under `jax.jit`: the leaf's shape, dtype and every
+    bit (a key's high bit, -0.0, an infinity, a NaN's payload), and a bool as
+    a bool."""
+    leaf = LEAVES[kind]
+    _, buf, beside, (out,) = roundtrip((leaf,))
+    assert beside == [] and buf.size == leaf.size
+    same_bits(leaf, out)
+
+
+def test_a_nested_tuple_and_a_keyword_tree_come_back_whole():
+    """What `_launch` hands over: positional leaves, a tuple of per-chunk
+    tuples (`mixed_step`), and a `pen=` keyword tree."""
+    chunk = (LEAVES["int32"], np.int32(8), LEAVES["scalar_float32"], np.bool_(False))
+    host = (
+        (chunk, chunk), LEAVES["uint32_high_bit"], LEAVES["bool"],
+        LEAVES["float32_specials"],
+    )
+    kw = {"pen": (LEAVES["int32"], LEAVES["float32_nan_payload"], LEAVES["scalar_bool"])}
+    layout, buf, beside, (out, out_kw) = roundtrip((host, kw))
+    assert beside == []
+    assert jax.tree.structure((out, out_kw)) == jax.tree.structure((host, kw))
+    for a, b in zip(jax.tree.leaves((host, kw)), jax.tree.leaves((out, out_kw))):
+        same_bits(a, b)
+    assert buf.size == sum(np.size(x) for x in jax.tree.leaves((host, kw)))
+    # the same leaves give the same layout, other shapes another one
+    assert MR.pack_inputs((host, kw))[0] == layout
+    assert MR.pack_inputs((host[1:], kw))[0] != layout
+
+
+@pytest.mark.parametrize("kind", ["device", "int64", "float64", "int8", "list"])
+def test_what_does_not_pack_travels_beside_the_buffer(kind):
+    """The rule reads the dtype: a leaf on the device already, or a host
+    leaf of another width, keeps its place in the tree and its own way to
+    the device."""
+    other = {
+        "device": jnp.arange(6, dtype=jnp.float32).reshape(2, 3),
+        "int64": np.arange(4, dtype=np.int64),
+        "float64": np.float64(0.25),
+        "int8": np.arange(5, dtype=np.int8),
+        "list": [1, 2, 3],
+    }[kind]
+    layout, buf, beside, out = roundtrip((LEAVES["int32"], other, LEAVES["bool"]))
+    assert len(beside) == 1 and beside[0] is other
+    assert layout[1][1] is None and buf.size == LEAVES["int32"].size + LEAVES["bool"].size
+    same_bits(LEAVES["int32"], out[0])
+    same_bits(LEAVES["bool"], out[2])
+    np.testing.assert_array_equal(np.asarray(out[1]), np.asarray(other))
+
+
+def test_a_device_leaf_is_not_counted():
+    """`_commit` counts one array a call, the buffer; `prefill_mm`'s
+    embeddings already on the device ride along uncounted, a host leaf of
+    another width is one more array."""
+    runner = make_engine().runner
+    on_device = jnp.ones((3, 8), jnp.float32)
+    tokens = np.arange(8, dtype=np.int32)
+    runner.launch.clear()
+    layout, dev = runner._commit(tokens, on_device, np.float32(0.5), pen=(tokens,))
+    assert runner.launch.upload_arrays == 1
+    assert runner.launch.upload_bytes == 4 * (8 + 1 + 8)
+    assert len(dev) == 2 and dev[1] is on_device
+    host, kw = jax.jit(MR.unpack_inputs, static_argnums=0)(layout, dev[0], dev[1:])
+    same_bits(tokens, host[0])
+    same_bits(tokens, kw["pen"][0])
+    assert float(host[2]) == 0.5
+    runner.launch.clear()
+    runner._commit(tokens, np.arange(3, dtype=np.int8))
+    assert runner.launch.upload_arrays == 2
+
+
+def test_a_commit_does_not_see_what_the_engine_writes_afterwards():
+    """The engine keeps its lane arrays and rewrites them between dispatches:
+    each commit packs into a buffer of its own, so the first dispatch's
+    values are still the first's after the second was packed."""
+    runner = make_engine().runner
+    tokens = np.arange(16, dtype=np.int32)
+    temps = np.full(16, 0.5, np.float32)
+    active = np.ones(16, bool)
+    lay_a, buf_a, _ = MR.pack_inputs(((tokens, temps, active), {}))
+    _, dev_a = runner._commit(tokens, temps, active)
+    before = (tokens.copy(), temps.copy(), active.copy())
+    tokens += 100
+    temps[:] = 2.0
+    active[::2] = False
+    lay_b, buf_b, _ = MR.pack_inputs(((tokens, temps, active), {}))
+    _, dev_b = runner._commit(tokens, temps, active)
+    assert lay_a == lay_b
+    assert not np.shares_memory(buf_a, buf_b)
+    assert not any(np.shares_memory(buf_b, x) for x in (tokens, temps, active))
+    unpack = jax.jit(MR.unpack_inputs, static_argnums=0)
+    for host, dev in ((before, dev_a), ((tokens, temps, active), dev_b)):
+        out, _ = unpack(lay_a, dev[0], ())
+        for a, b in zip(host, out):
+            same_bits(a, b)
+
+
+# ------------------------------------------------------------- the engine
+
+
+async def stream(engine, req, started=None):
+    """Tokens, log-probs and top log-probs of one request, in order."""
+    toks, lps, tops = [], [], []
+    async for out in engine.generate(req, Context()):
+        toks.extend(out.token_ids)
+        lps.extend(out.log_probs or [])
+        tops.extend(out.top_logprobs or [])
+        if started is not None and toks:
+            started.set()
+    return toks, lps, tops
+
+
+async def serve(horizon: int):
+    """Four requests through the toy engine: a short prompt that keeps its
+    EOS masked for `min_tokens` decodes while a 29-token prompt arrives and
+    rides mixed steps in 8-token chunks (a lane with penalties is never
+    mixed); then one with all three penalties and `min_tokens` beside a
+    seeded one with `top_k`. Gives the streams and the ledger."""
+    engine = make_engine(decode_horizon=horizon)
+    masked = PreprocessedRequest(
+        token_ids=[5, 6, 7, 8, 9],
+        sampling=SamplingOptions(greedy=True, logprobs=True, top_logprobs=3),
+        stop=StopConditions(max_tokens=24, min_tokens=30),
+        eos_token_ids=[33, 21, 16],
+    )
+    long = PreprocessedRequest(
+        token_ids=list(range(1, 30)),
+        sampling=SamplingOptions(greedy=True, logprobs=True, top_logprobs=2),
+        stop=StopConditions(max_tokens=6, ignore_eos=True),
+    )
+    pen = PreprocessedRequest(
+        token_ids=[3, 1, 4, 1, 5],
+        sampling=SamplingOptions(
+            greedy=True, frequency_penalty=0.4, presence_penalty=0.2,
+            repetition_penalty=1.3, logprobs=True, top_logprobs=3,
+        ),
+        stop=StopConditions(max_tokens=14, min_tokens=20),
+        eos_token_ids=[33, 35],
+    )
+    seeded = PreprocessedRequest(
+        token_ids=[9, 8, 7],
+        sampling=SamplingOptions(temperature=0.9, top_k=8, seed=0x9E3779B9, logprobs=True),
+        stop=StopConditions(max_tokens=10, ignore_eos=True),
+    )
+    try:
+        started = asyncio.Event()
+        first = asyncio.ensure_future(stream(engine, masked, started))
+        await started.wait()  # the first decodes when the long one arrives
+        streams = [await stream(engine, long), await first]
+        streams += await asyncio.gather(stream(engine, pen), stream(engine, seeded))
+        gp = engine.stats.goodput
+        return streams, dict(gp.launch), set(gp.summary()["compile_s_by_label"])
+    finally:
+        await engine.close()
+
+
+@pytest.mark.parametrize("horizon", [1, 4])
+def test_the_toy_engine_streams_what_separate_transfers_stream(monkeypatch, horizon):
+    """One packed transfer a dispatch against the parent's one transfer a
+    leaf (nothing packs, so every leaf goes through `_to_dev` alone and the
+    program is handed them as they are): the same tokens, log-probs and top
+    log-probs to the last bit, through a packed prefill, mixed steps, the
+    penalty and EOS-mask programs and the horizon."""
+    packed, launch, labels = asyncio.run(serve(horizon))
+    assert launch["upload_arrays"] == launch["dispatches"] > 0
+    assert "prefill_packed" in labels, labels
+    assert any(l.startswith("mixed_step@c") for l in labels), labels
+    assert ("decode_multi@H4B4" if horizon == 4 else "decode") in labels, labels
+    monkeypatch.setattr(MR, "_packs", lambda dtype: False)
+    alone, launch_alone, labels_alone = asyncio.run(serve(horizon))
+    assert labels_alone == labels
+    assert launch_alone["dispatches"] == launch["dispatches"]
+    # the empty buffer and at least eight lane arrays a dispatch
+    assert launch_alone["upload_arrays"] >= 9 * launch["dispatches"]
+    assert packed == alone
+    assert [len(toks) for toks, _, _ in packed] == [6, 24, 14, 10]
+    for toks, lps, _ in packed:
+        assert len(lps) == len(toks)
+    assert all(len(top) == 3 for top in packed[1][2] + packed[2][2])

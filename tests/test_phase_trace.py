@@ -230,15 +230,17 @@ LAUNCH = ["runner.upload", "runner.enqueue", "runner.fetch"]
 
 class _Recorded(dtrace.phase):
     """A phase that also notes its exit: name, thread, and for a
-    `runner.call` its label and what the runner counted for it."""
+    `runner.call` its label and what the runner counted for it (arrays and
+    bytes committed)."""
 
     log: list = []
     runner = None
 
     def __exit__(self, et, ev, tb):
         out = super().__exit__(et, ev, tb)
-        arrays = self.runner.launch.upload_arrays if self.name == "runner.call" else None
-        self.log.append((self.name, threading.get_ident(), self.attrs.get("label"), arrays))
+        launch = self.runner.launch
+        counted = (launch.upload_arrays, launch.upload_bytes) if self.name == "runner.call" else None
+        self.log.append((self.name, threading.get_ident(), self.attrs.get("label"), counted))
         return out
 
 
@@ -265,12 +267,22 @@ async def _serve_toy(monkeypatch, horizon: int):
     return engine, log
 
 
-def _arrays_by_the_code(label: str) -> set[int]:
-    """Host arrays a call of `label` commits without penalties: the lane
-    arrays, and eleven a chunk of a mixed step beside its ten."""
+def _bytes_by_the_code(label: str) -> set[int]:
+    """Bytes a call of `label` commits in its one array on the toy engine
+    (4 lanes, 16 table columns, 8-token chunks, 4 EOS ids) without
+    penalties: every lane array's elements, a bool as four bytes; a chunk
+    of a mixed step brings 8 tokens, 16 table entries, a key, 4 EOS ids and
+    seven scalars beside the decode half's ten arrays."""
+    B, W, C, E = 4, 16, 8, 4
+    lanes = B * W + 2 * B  # block tables and keys
     if label.startswith("mixed_step@c"):
-        return {11 * int(label.rsplit("c", 1)[1]) + 10}
-    return {"decode_multi@H4B4": {11}, "decode": {8, 10}, "prefill_packed": {12}}[label]
+        k = int(label.rsplit("c", 1)[1])
+        return {4 * (k * (C + W + 2 + E + 7) + lanes + 7 * B + B * E)}
+    return {
+        "decode_multi@H4B4": {4 * (lanes + 8 * B + B * E)},
+        "decode": {4 * (lanes + 6 * B), 4 * (lanes + 7 * B + B * E)},
+        "prefill_packed": {4 * (4 * C + 2 * B + 6 * B + B * E)},
+    }[label]
 
 
 @pytest.mark.parametrize("horizon, labels", [
@@ -282,17 +294,19 @@ async def test_every_runner_call_has_its_three_children(monkeypatch, horizon, la
     `runner.enqueue` and one `runner.fetch`, in that order, on its own
     thread; the three are all of its children (their ms are its ms less its
     self ms); and the ledger's `launch` slot counts what the runner counted:
-    11 host arrays for a `decode_multi` without penalties."""
+    one array a call whatever the label, the packed buffer of its host
+    inputs (eleven arrays for a `decode_multi` until PR 42), of the bytes
+    the label's arrays hold."""
     engine, log = await _serve_toy(monkeypatch, horizon)
     calls: dict[str, list] = {}
     since: list = []
-    for name, thread, label, arrays in log:
+    for name, thread, label, counted in log:
         if name in LAUNCH:
             since.append((name, thread))
         elif name == "runner.call":
             assert [n for n, _ in since] == LAUNCH, (label, since)
             assert {t for _, t in since} == {thread}
-            calls.setdefault(label, []).append(arrays)
+            calls.setdefault(label, []).append(counted)
             since = []
     assert not since
     for label in labels:
@@ -307,14 +321,12 @@ async def test_every_runner_call_has_its_three_children(monkeypatch, horizon, la
         t["runner.call"]["ms"] - t["runner.call"]["self_ms"], abs=5e-3
     )
     for label, counted in calls.items():
-        assert set(counted) <= _arrays_by_the_code(label), (label, counted)
+        assert {arrays for arrays, _ in counted} == {1}, (label, counted)
+        assert {nbytes for _, nbytes in counted} <= _bytes_by_the_code(label), (label, counted)
     launch = engine.stats.goodput.launch
-    assert launch["dispatches"] == n_calls
-    assert launch["upload_arrays"] == sum(sum(v) for v in calls.values())
-    assert launch["upload_bytes"] > 0 and launch["fetch_bytes"] > 0
-    if horizon == 4:
-        multi = calls["decode_multi@H4B4"]
-        assert sum(multi) / len(multi) == 11
+    assert launch["dispatches"] == n_calls == launch["upload_arrays"]
+    assert launch["upload_bytes"] == sum(b for v in calls.values() for _, b in v)
+    assert launch["fetch_bytes"] > 0
 
 
 async def test_the_launch_phases_are_annotations_on_the_executor_thread(monkeypatch, tmp_path):
@@ -331,14 +343,17 @@ async def test_the_launch_phases_are_annotations_on_the_executor_thread(monkeypa
         dprofile.stop()
     found = glob.glob(os.path.join(info["profile_dir"], "**", "*.xplane.pb"), recursive=True)
     assert found
-    lines = {}
+    # a list, one entry a line: the host plane names every Python thread's
+    # line "python", and in a table keyed by the name one thread's line
+    # replaced another's (whichever came last, by thread id)
+    lines = []
     for plane in ProfileData.from_file(found[0]).planes:
         for line in plane.lines:
             evs = [(ev.name[4:], ev.start_ns, ev.start_ns + ev.duration_ns)
                    for ev in line.events if ev.name.startswith("dyn:")]
             if evs:
-                lines[(plane.name, line.name)] = evs
-    executor = [evs for evs in lines.values() if any(n == "runner.call" for n, _, _ in evs)]
+                lines.append(evs)
+    executor = [evs for evs in lines if any(n == "runner.call" for n, _, _ in evs)]
     assert executor
     n_calls = n_kids = 0
     for evs in executor:
@@ -351,9 +366,10 @@ async def test_the_launch_phases_are_annotations_on_the_executor_thread(monkeypa
             for s, e in kids:
                 assert any(lo <= s and e <= hi for lo, hi in calls), name
     assert n_kids == 3 * n_calls > 0
-    for evs in lines.values():
-        if any(n == "loop.dispatch" for n, _, _ in evs):
-            assert not any(n in LAUNCH for n, _, _ in evs)
+    loops = [evs for evs in lines if any(n == "loop.dispatch" for n, _, _ in evs)]
+    assert loops
+    for evs in loops:
+        assert not any(n in LAUNCH for n, _, _ in evs)
 
 
 def test_a_phase_keeps_its_start_and_duration_readable():

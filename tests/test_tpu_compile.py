@@ -324,27 +324,51 @@ def _step_setup_one_chip(one_chip, num_blocks: int = 1024, layers: int = 2):
     return cfg, params, (one_chip(layer_shape, BF16),) * cfg.num_layers
 
 
-def _lower_decode_multi(one_chip, num_blocks: int = 1024, layers: int = 2):
+def _lower_step(one_chip, impl, params, caches, host, static=(), packed=False):
+    """A step's impl lowered behind params and the two donated caches: on its
+    host arguments one by one (the impl alone, as the parent launched it), or
+    `packed`, as `ModelRunner._launch` launches it since PR 42: the runner's
+    own `_step_jit` on the layout and one int32 buffer of `pack_inputs`."""
+    n = len(static)
+    if not packed:
+        fn = jax.jit(
+            impl, static_argnums=tuple(range(n)), donate_argnums=(n + 1, n + 2),
+        )
+        return fn.lower(*static, params, *caches, *host)
+    from dynamo_tpu.engine.jax_engine.model_runner import ModelRunner, pack_inputs
+
+    zeros = jax.tree.map(lambda a: np.zeros(a.shape, a.dtype), host)
+    layout, buf, beside = pack_inputs((zeros, {}))
+    assert beside == []
+    return ModelRunner._step_jit(impl, n_static=n).lower(
+        layout, *static, params, *caches, one_chip(buf.shape, I32)
+    )
+
+
+def _lower_decode_multi(
+    one_chip, num_blocks: int = 1024, layers: int = 2, packed: bool = False
+):
     from dynamo_tpu.engine.jax_engine.model_runner import ModelRunner
     from dynamo_tpu.ops.sampling import MAX_EOS_IDS
 
     cfg, params, cache = _step_setup_one_chip(one_chip, num_blocks, layers)
-    fn = jax.jit(
-        functools.partial(
-            ModelRunner._decode_multi_impl, cfg, None, None, BLOCK
-        ),
-        static_argnums=(0,), donate_argnums=(2, 3),
-    )
     vec = lambda dtype: one_chip((B,), dtype)
-    return fn.lower(
-        4, params, cache, cache, vec(I32), vec(I32),
+    host = (
+        vec(I32), vec(I32),
         one_chip((B, CONTEXT // BLOCK), I32), one_chip((B, 2), jnp.uint32),
         vec(F32), vec(F32), vec(I32), vec(jnp.bool_), vec(I32), vec(I32),
         one_chip((B, MAX_EOS_IDS), I32),
     )
+    return _lower_step(
+        one_chip,
+        functools.partial(ModelRunner._decode_multi_impl, cfg, None, None, BLOCK),
+        params, (cache, cache), host, static=(4,), packed=packed,
+    )
 
 
-def _lower_mixed_step(one_chip, num_blocks: int = 1024, layers: int = 2):
+def _lower_mixed_step(
+    one_chip, num_blocks: int = 1024, layers: int = 2, packed: bool = False
+):
     from dynamo_tpu.engine.jax_engine.model_runner import ModelRunner
     from dynamo_tpu.ops.sampling import MAX_EOS_IDS
 
@@ -357,20 +381,21 @@ def _lower_mixed_step(one_chip, num_blocks: int = 1024, layers: int = 2):
         scalar(F32), scalar(F32), scalar(I32), scalar(F32),
         one_chip((MAX_EOS_IDS,), I32), scalar(jnp.bool_),
     )
-    fn = jax.jit(
-        functools.partial(ModelRunner._mixed_impl, cfg, None, None),
-        donate_argnums=(1, 2),
-    )
-    return fn.lower(
-        params, cache, cache, (chunk,), vec(I32), vec(I32),
+    host = (
+        (chunk,), vec(I32), vec(I32),
         one_chip((B, CONTEXT // BLOCK), I32), vec(I32),
         one_chip((B, 2), jnp.uint32), vec(F32), vec(F32), vec(I32),
         one_chip((B, MAX_EOS_IDS), I32), vec(jnp.bool_),
     )
+    return _lower_step(
+        one_chip, functools.partial(ModelRunner._mixed_impl, cfg, None, None),
+        params, (cache, cache), host, packed=packed,
+    )
 
 
 def _lower_prefill_packed(
-    one_chip, tokens: int = 512, num_blocks: int = 1024, layers: int = 2
+    one_chip, tokens: int = 512, num_blocks: int = 1024, layers: int = 2,
+    packed: bool = False,
 ):
     from dynamo_tpu.engine.jax_engine.model_runner import ModelRunner
     from dynamo_tpu.ops.sampling import MAX_EOS_IDS
@@ -378,14 +403,14 @@ def _lower_prefill_packed(
     cfg, params, cache = _step_setup_one_chip(one_chip, num_blocks, layers)
     tok = lambda dtype: one_chip((tokens,), dtype)
     vec = lambda dtype: one_chip((B,), dtype)
-    fn = jax.jit(
-        functools.partial(ModelRunner._prefill_packed_impl, cfg, None),
-        donate_argnums=(1, 2),
-    )
-    return fn.lower(
-        params, cache, cache, tok(I32), tok(I32), tok(I32), tok(I32),
+    host = (
+        tok(I32), tok(I32), tok(I32), tok(I32),
         vec(I32), one_chip((B, 2), jnp.uint32), vec(F32), vec(F32),
         vec(I32), vec(F32), one_chip((B, MAX_EOS_IDS), I32), vec(jnp.bool_),
+    )
+    return _lower_step(
+        one_chip, functools.partial(ModelRunner._prefill_packed_impl, cfg, None),
+        params, (cache, cache), host, packed=packed,
     )
 
 
@@ -820,6 +845,43 @@ def test_step_program_compiles_to_the_parents(one_chip, program):
     ).hexdigest()[:16] == digest, histogram
 
 
+@pytest.mark.parametrize("program", [p for p in BODY_PROGRAMS if not p.startswith("latent")])
+def test_step_program_takes_its_host_inputs_from_one_buffer(one_chip, program):
+    """The program as `ModelRunner._launch` calls it since PR 42, through the
+    runner's own `_step_jit`: one int32 buffer in place of the eleven to
+    twenty-one host arguments, taken apart by static slices in front of the
+    impl. The chip's compiler takes it: the donated caches are still aliased
+    byte for byte and written where they lie, every kernel is there under
+    its name, and the temporaries are the impl's own (the slices are views
+    of a 70 KB parameter; the compiler's prefetches of small operands and
+    weight slices fall differently, which moves the total a per cent or
+    two)."""
+    lower, kernels = BODY_PROGRAMS[program]
+    compiled = lower(one_chip, layers=2, packed=True).compile()
+    text = compiled.as_text()
+    _, _, temp, alias, telling = PARENT_COMPILED[program]
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == alias
+    assert mem.temp_size_in_bytes < 1.02 * temp
+    pool_elements = HKV * 1024 * BLOCK * D
+    cache_params = {
+        int(re.search(r"parameter\((\d+)\)", line)[1])
+        for _, op, elements, line in _entry_instructions(text)
+        if op == "parameter" and elements == pool_elements
+    }
+    assert len(cache_params) == 2 * 2 and cache_params <= _aliased_parameters(text)
+    # one parameter for the host's inputs: the packed buffer
+    small = [
+        line for _, op, elements, line in _entry_instructions(text)
+        if op == "parameter" and re.search(r"= [su]32\[|= f32\[|= pred\[", line)
+    ]
+    assert len(small) == 1 and "s32[" in small[0], small
+    histogram = collections.Counter(_MODULE_INSTRUCTION.findall(text))
+    for op in ("convolution", "scatter", "conditional"):
+        assert histogram[op] == telling[op], (op, histogram[op])
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"", text)) >= kernels
+
+
 # ----------------------- the hybrid state-space family's programs (PR 38)
 #
 # `cellbench/configs/jamba2-3b-bf16.json` at the published widths, cut here to
@@ -858,7 +920,7 @@ def _hybrid_step_setup(one_chip, num_blocks: int = 32832, layers: int = 4):
     return cfg, params, first, second
 
 
-def _lower_hybrid(one_chip, program: str):
+def _lower_hybrid(one_chip, program: str, packed: bool = False):
     from dynamo_tpu.engine.jax_engine.model_runner import ModelRunner
     from dynamo_tpu.ops.sampling import MAX_EOS_IDS
 
@@ -867,14 +929,14 @@ def _lower_hybrid(one_chip, program: str):
     scalar = lambda dtype: one_chip((), dtype)
     table = 8192 // BLOCK
     if program == "decode_multi@H4B64":
-        fn = jax.jit(
+        return _lower_step(
+            one_chip,
             functools.partial(ModelRunner._decode_multi_impl, cfg, None, None, BLOCK),
-            static_argnums=(0,), donate_argnums=(2, 3),
-        )
-        return fn.lower(
-            4, params, kc, vc, vec(I32), vec(I32), one_chip((B, table), I32),
-            one_chip((B, 2), jnp.uint32), vec(F32), vec(F32), vec(I32),
-            vec(jnp.bool_), vec(I32), vec(I32), one_chip((B, MAX_EOS_IDS), I32),
+            params, (kc, vc), (
+                vec(I32), vec(I32), one_chip((B, table), I32),
+                one_chip((B, 2), jnp.uint32), vec(F32), vec(F32), vec(I32),
+                vec(jnp.bool_), vec(I32), vec(I32), one_chip((B, MAX_EOS_IDS), I32),
+            ), static=(4,), packed=packed,
         )
     if program == "mixed_step@c1":
         chunk = (
@@ -883,33 +945,32 @@ def _lower_hybrid(one_chip, program: str):
             scalar(F32), one_chip((MAX_EOS_IDS,), I32), scalar(jnp.bool_),
             scalar(I32),  # the chunk's lane slot
         )
-        fn = jax.jit(
-            functools.partial(ModelRunner._mixed_impl, cfg, None, None),
-            donate_argnums=(1, 2),
-        )
-        return fn.lower(
-            params, kc, vc, (chunk,), vec(I32), vec(I32), one_chip((B, table), I32),
-            vec(I32), one_chip((B, 2), jnp.uint32), vec(F32), vec(F32), vec(I32),
-            one_chip((B, MAX_EOS_IDS), I32), vec(jnp.bool_),
+        return _lower_step(
+            one_chip, functools.partial(ModelRunner._mixed_impl, cfg, None, None),
+            params, (kc, vc), (
+                (chunk,), vec(I32), vec(I32), one_chip((B, table), I32),
+                vec(I32), one_chip((B, 2), jnp.uint32), vec(F32), vec(F32), vec(I32),
+                one_chip((B, MAX_EOS_IDS), I32), vec(jnp.bool_),
+            ), packed=packed,
         )
     tok = lambda dtype: one_chip((512,), dtype)
-    fn = jax.jit(
-        functools.partial(ModelRunner._prefill_packed_impl, cfg, None),
-        donate_argnums=(1, 2),
-    )
-    return fn.lower(
-        params, kc, vc, tok(I32), tok(I32), tok(I32), tok(I32), vec(I32),
-        one_chip((B, 2), jnp.uint32), vec(F32), vec(F32), vec(I32), vec(F32),
-        one_chip((B, MAX_EOS_IDS), I32), vec(jnp.bool_), vec(I32),
+    return _lower_step(
+        one_chip, functools.partial(ModelRunner._prefill_packed_impl, cfg, None),
+        params, (kc, vc), (
+            tok(I32), tok(I32), tok(I32), tok(I32), vec(I32),
+            one_chip((B, 2), jnp.uint32), vec(F32), vec(F32), vec(I32), vec(F32),
+            one_chip((B, MAX_EOS_IDS), I32), vec(jnp.bool_), vec(I32),
+        ), packed=packed,
     )
 
 
+@pytest.mark.parametrize("packed", [False, True], ids=["impl", "as_launched"])
 @pytest.mark.parametrize("program,bodies,kernels,loops", [
     ("decode_multi@H4B64", 2, 2 * 4, 0),  # 2 attention layers x 4 steps
     ("mixed_step@c1", 4, 2, 6),  # the chunk's scans; its attention is XLA's
     ("prefill_packed@512", 2, 0, 6),
 ])
-def test_hybrid_step_programs_one_chip(one_chip, program, bodies, kernels, loops):
+def test_hybrid_step_programs_one_chip(one_chip, program, bodies, kernels, loops, packed):
     """The family's step programs compile for the chip: two layer bodies a
     pass (the Mamba one and the attention one; a mixed step has a chunk's
     pass and a decode's), the paged decode kernel under its name in the
@@ -920,7 +981,7 @@ def test_hybrid_step_programs_one_chip(one_chip, program, bodies, kernels, loops
 
     jax.clear_caches()  # a body traced by another test would not be counted
     with layer_bodies_called() as seen:
-        lowered = _lower_hybrid(one_chip, program)
+        lowered = _lower_hybrid(one_chip, program, packed)
     assert len(seen) == bodies, sorted(s[1] for s in seen)
     compiled = lowered.compile()
     text = compiled.as_text()
