@@ -34,13 +34,22 @@ SHAPES = {  # query heads, KV heads, layers (for the per-step column)
     "qwen25-7b": (28, 4, 28),
     "tp4-shard-of-mistral7b": (8, 2, 32),
     "tp4-shard-of-qwen25-7b": (7, 1, 28),
+    # 64-wide heads cached two to a row (`models/conv_moe.py`): read through
+    # `ops.attention.paged_decode_attention`, which widens the queries, at the
+    # cell's lanes and context, with the XLA form's time beside the kernel's
+    "lfm2-8b-a1b-l16": (32, 8, 4),
 }
+PAIRED = {"lfm2-8b-a1b-l16": (64, 2, [(64, 1100), (45, 1100), (8, 1100)])}
+# prompt lengths of the single-prompt prefill program read at the paired
+# shapes: a chunk (`prefill@512`), and the mix's median-plus and longest prompts
+PAIRED_PREFILL = (512, 1024, 4096)
 B, D, MAX_BLOCKS = 64, 128, 256
 
 
-def _inputs(hq, hkv, *, live, ctx, idle_ctx, quantized, seed):
+def _inputs(hq, hkv, *, live, ctx, idle_ctx, quantized, seed, D=D, pack=1):
     """`live` lanes of `ctx` tokens scattered among `B - live` idle ones
-    (context `idle_ctx`, table of zeros), pages drawn without replacement."""
+    (context `idle_ctx`, table of zeros), pages drawn without replacement.
+    `pack` > 1: heads of `D` cached `pack` to a row."""
     rng = np.random.default_rng(seed)
     bs = 32 if quantized else 16
     nb = 1 + B * (-(-ctx // bs))
@@ -53,7 +62,7 @@ def _inputs(hq, hkv, *, live, ctx, idle_ctx, quantized, seed):
         tables[lane, :per] = pages[n * per:(n + 1) * per]
     keys = jax.random.split(jax.random.PRNGKey(seed), 3)
     q = jax.random.normal(keys[0], (B, hq, D), jnp.bfloat16)
-    shape = (hkv, nb, bs, D)
+    shape = (hkv // pack, nb, bs, D * pack)
     if quantized:
         cache = [
             {
@@ -136,6 +145,9 @@ def main() -> None:
         mixes = [(B, 40, False), (3, 70, False), (3, 70, True)]
     for name in args.shapes:
         hq, hkv, layers = SHAPES[name]
+        if name in PAIRED:
+            _paired(name, args, dev)
+            continue
         for live, ctx, quantized in mixes:
             for idle_ctx in (args.idle_context if live < B else [0]):
                 inp = _inputs(hq, hkv, live=live, ctx=ctx, idle_ctx=idle_ctx,
@@ -162,6 +174,69 @@ def main() -> None:
                         "idle_rows_zero": bool((out[lens == 0] == 0).all()),
                         "device": dev.device_kind,
                     }), flush=True)
+
+
+def _paired(name, args, dev) -> None:
+    """Narrow heads cached in pairs: the public call on the stored rows, the
+    Pallas form and the XLA form timed alike, one line a mix."""
+    hq, hkv, layers = SHAPES[name]
+    width, pack, mixes = PAIRED[name]
+    kernel_impl = "pallas_interpret" if args.rehearse else "pallas"
+    for live, ctx in ([(3, 70)] if args.rehearse else mixes):
+        inp = _inputs(hq, hkv, live=live, ctx=ctx, idle_ctx=0, quantized=False,
+                      seed=args.seed, D=width, pack=pack)
+        lens = np.asarray(inp[4])
+        forms = {
+            impl: lambda q, k, v, t, n, impl=impl: A.paged_decode_attention(
+                q, k, v, t, n, impl=impl)
+            for impl in (kernel_impl, "xla")
+        }
+        out = {k: np.asarray(jax.jit(f)(*inp), np.float32) for k, f in forms.items()}
+        ms = {
+            k: _time_ms(_chain(f, args.calls), inp, args.calls, 1 if args.rehearse else 10)
+            for k, f in forms.items()
+        }
+        print(json.dumps({
+            "shape": name, "head_width": width, "heads_a_row": pack,
+            "live": live, "ctx": ctx, "idle_ctx": 0,
+            "call_ms": round(ms[kernel_impl], 4),
+            "step_ms": round(ms[kernel_impl] * layers, 3),
+            "xla_call_ms": round(ms["xla"], 4),
+            "xla_step_ms": round(ms["xla"] * layers, 3),
+            "max_abs_err_vs_xla_live": float(
+                np.abs(out[kernel_impl] - out["xla"])[lens > 1].max()),
+            "idle_rows_zero": bool((out[kernel_impl][lens == 0] == 0).all()),
+            "device": dev.device_kind,
+        }), flush=True)
+    # the flash prefill kernel over the same stored rows (one prompt of P
+    # tokens, the last eighth padding): no cell's traffic dispatches the
+    # single-prompt program, so this is its only reading on the chip
+    for P in ([128] if args.rehearse else PAIRED_PREFILL):
+        keys = jax.random.split(jax.random.PRNGKey(args.seed + P), 3)
+        q = jax.random.normal(keys[0], (P, hq, width), jnp.bfloat16)
+        k, v = (jax.random.normal(x, (P, hkv // pack, width * pack), jnp.bfloat16)
+                for x in keys[1:])
+        valid = jnp.int32(P - P // 8)
+        forms = {
+            impl: lambda q, k, v, n, impl=impl: A.causal_prefill_attention(
+                q, k, v, n, impl=impl)
+            for impl in (kernel_impl, "xla")
+        }
+        inp = (q, k, v, valid)
+        out = {n: np.asarray(jax.jit(f)(*inp), np.float32) for n, f in forms.items()}
+        ms = {
+            n: _time_ms(_chain(f, args.calls), inp, args.calls, 1 if args.rehearse else 10)
+            for n, f in forms.items()
+        }
+        print(json.dumps({
+            "shape": name, "prefill_tokens": P, "valid": int(valid),
+            "head_width": width, "heads_a_row": pack,
+            "call_ms": round(ms[kernel_impl], 4),
+            "xla_call_ms": round(ms["xla"], 4),
+            "max_abs_err_vs_xla_valid": float(
+                np.abs(out[kernel_impl] - out["xla"])[: int(valid)].max()),
+            "device": dev.device_kind,
+        }), flush=True)
 
 
 if __name__ == "__main__":

@@ -229,11 +229,12 @@ def refuse_unsupported(
     in bfloat16 on one chip: no int8 weights, no int8-resident cache, no
     mesh, no fused decode step, no block-manager tiers, no speculative
     decoding. A model with a recurrent layer (`models/hybrid_ssm.py`: a
-    state slot a sequence beside paged keys and values) is served the same
-    way and refuses the same six and, besides, disaggregated transfer, peer
-    pulls and live handoff (`ModelRunner.require_block_transfer`); prefix
-    reuse it simply does not offer (the engine publishes no block hashes
-    for it)."""
+    state slot a sequence beside paged keys and values; `models/conv_moe.py`:
+    a short convolution's tail a sequence, and routed experts) is served the
+    same way and refuses the same six and, besides, disaggregated transfer,
+    peer pulls and live handoff (`ModelRunner.require_block_transfer`);
+    prefix reuse it simply does not offer (the engine publishes no block
+    hashes for it)."""
     n = recurrent_layers(config)
     kind = cache_kind(config)
     if n:
@@ -242,10 +243,12 @@ def refuse_unsupported(
             f"{config.num_layers} layers"
         )
         why = {
-            "int8_weights": "not implemented for state-space mixers",
-            "int8_cache": "the state is float32 and the pages beside it are "
-            "served in bfloat16",
-            "mesh": "the state's channels have no sharding rule yet",
+            "int8_weights": "not implemented for state-space mixers, short "
+            "convolutions and expert stacks",
+            "int8_cache": "the slots keep their own dtype and the pages "
+            "beside them are served in bfloat16",
+            "mesh": "the slots' channels have no sharding rule yet (and an "
+            "experts' share has no add-up test)",
             "fused_decode": "its kernels are the grouped-query block's",
             "tiers": "and with them prefix reuse: a block of keys and values "
             "without the state at its boundary cannot resume a sequence, and "

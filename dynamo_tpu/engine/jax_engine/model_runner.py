@@ -234,13 +234,14 @@ class ModelRunner:
                 # no silent XLA-gather serving on the chip: the caller
                 # passes attn_impl="xla" knowingly or fixes the shape
                 raise ValueError(
-                    "pallas attention needs head_dim%128==0 and "
-                    f"block_size%8==0 (got head_dim={kind.stored_width}, "
-                    f"block_size={block_size})"
+                    "pallas attention needs cached rows of a multiple of 128 "
+                    "values (a head, or narrow heads side by side where the "
+                    "family declares them so) and block_size%8==0 (got rows "
+                    f"of {kind.stored_width}, block_size={block_size})"
                 )
             logger.warning(
-                "pallas attention needs head_dim%%128==0 and "
-                "block_size%%8==0 (got %d/%d); falling back to xla",
+                "pallas attention needs cached rows of a multiple of 128 "
+                "values and block_size%%8==0 (got %d/%d); falling back to xla",
                 kind.stored_width, block_size,
             )
             attn_impl = "xla"
@@ -283,10 +284,12 @@ class ModelRunner:
         # one array per layer and plane, head-major: each (head, page) is a
         # contiguous [bs, D] tile (what the pallas kernel streams; TP shards
         # the leading head axis). What a layer keeps is the config's
-        # declaration: keys and values by head, or one latent plane; a
-        # recurrent layer keeps two arrays indexed by lane slot in their
-        # place, with one slot more than lanes: the null lane's, which
-        # padding writes to as it does to block 0
+        # declaration: keys and values by head (a row a head, or several
+        # narrow heads a row), or one latent plane; a recurrent layer keeps
+        # its slot's arrays indexed by lane slot in their place (one array
+        # where the keys ride and None for the values, or one in each), with
+        # one slot more than lanes: the null lane's, which padding writes to
+        # as it does to block 0
         self.cache_kind = kind
         self.layer_kinds = kinds
         self.recurrent_layers = recurrent_layers(config)
@@ -320,16 +323,20 @@ class ModelRunner:
         kv_shard_tree = kv_quant.cache_sharding(
             kv_sharding, config.num_layers, self.kv_quantized
         )
-        def make_zeros(which: int):  # 0: keys or state; 1: values or tail
+        def make_zeros(which: int):  # 0: keys or a slot's first array; 1: values or its second
             pages = iter(kv_quant.make_cache(
                 len(kinds) - self.recurrent_layers, layer_shape, self.kv_dtype,
                 quantized=self.kv_quantized,
             ))
+
+            def slot_array(k):  # None: a slot of one array has no second
+                if which >= len(k.slot):
+                    return None
+                shape, dtype = k.slot[which]
+                return jnp.zeros((self.state_slots,) + tuple(shape), dtype)
+
             return tuple(
-                jnp.zeros(
-                    (self.state_slots,) + tuple(k.slot[which][0]),
-                    k.slot[which][1],
-                ) if k.name == "recurrent" else next(pages)
+                slot_array(k) if k.name == "recurrent" else next(pages)
                 for k in kinds
             )
 

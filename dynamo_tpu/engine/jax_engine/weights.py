@@ -326,7 +326,91 @@ def load_hybrid_ssm_safetensors(
     return params
 
 
+def load_conv_moe_safetensors(
+    model_dir: str,
+    config: Any,  # models.conv_moe.ConvMoeConfig
+    *,
+    quantize: bool = False,
+    dtype: jnp.dtype = jnp.bfloat16,
+) -> Any:
+    """The short-convolution, sparse-expert family's checkpoint names, written
+    from Hugging Face's `Lfm2Moe*` classes and NOT yet tried on a real
+    checkpoint (none is in the repository; a synthetic state dict under these
+    names round-trips in `tests/test_conv_moe.py`): `operator_norm`,
+    `ffn_norm`; a convolution layer's `conv.{in_proj, conv, out_proj}` (the
+    convolution's weight is `[hidden, 1, conv_L_cache]`; here
+    `[conv_L_cache, hidden]`); an attention layer's `self_attn.{q_proj,
+    k_proj, v_proj, out_proj, q_layernorm, k_layernorm}`; a dense layer's
+    `feed_forward.{w1, w3, w2}` (gate, up, down); an expert layer's
+    `feed_forward.gate` (the router), `feed_forward.expert_bias` (float32)
+    and `feed_forward.experts.N.{w1, w3, w2}`; `model.embedding_norm`."""
+    forward_for(config).refuse_int8_weights(quantize)
+    tensors = _read_safetensors(model_dir)
+    c = config
+
+    def get(name: str, as_dtype=dtype) -> jax.Array:
+        return jnp.asarray(tensors.pop(name)).astype(as_dtype)
+
+    def lin(name: str) -> jax.Array:  # HF stores [out, in]; we use [in, out]
+        return get(name).T
+
+    layers = []
+    for i in range(c.num_layers):
+        p = f"model.layers.{i}."
+        layer = {"op_norm": get(p + "operator_norm.weight")}
+        if c.is_attn_layer(i):
+            a = p + "self_attn."
+            layer.update(
+                wq=lin(a + "q_proj.weight"), wk=lin(a + "k_proj.weight"),
+                wv=lin(a + "v_proj.weight"), wo=lin(a + "out_proj.weight"),
+                q_norm=get(a + "q_layernorm.weight"),
+                k_norm=get(a + "k_layernorm.weight"),
+            )
+        else:
+            m = p + "conv."
+            layer.update(
+                w_in=lin(m + "in_proj.weight"),
+                conv_w=get(m + "conv.weight")[:, 0, :].T,
+                w_out=lin(m + "out_proj.weight"),
+            )
+        layer["ffn_norm"] = get(p + "ffn_norm.weight")
+        f = p + "feed_forward."
+        if c.is_moe_layer(i):
+            experts = range(c.num_experts)
+            layer.update(
+                router=lin(f + "gate.weight"),
+                router_bias=(
+                    get(f + "expert_bias", jnp.float32) if c.use_expert_bias
+                    else jnp.zeros((c.num_experts,), jnp.float32)
+                ),
+                wg=jnp.stack([lin(f"{f}experts.{e}.w1.weight") for e in experts]),
+                wu=jnp.stack([lin(f"{f}experts.{e}.w3.weight") for e in experts]),
+                wd=jnp.stack([lin(f"{f}experts.{e}.w2.weight") for e in experts]),
+            )
+        else:
+            layer.update(
+                wg=lin(f + "w1.weight"), wu=lin(f + "w3.weight"),
+                wd=lin(f + "w2.weight"),
+            )
+        layers.append(layer)
+    params: dict[str, Any] = {
+        "embed": get("model.embed_tokens.weight"),
+        "layers": layers,
+        "final_norm": get("model.embedding_norm.weight"),
+    }
+    if not c.tie_word_embeddings and "lm_head.weight" in tensors:
+        params["lm_head"] = lin("lm_head.weight")
+    tensors.pop("lm_head.weight", None)  # a tied head written out again
+    logger.info(
+        "loaded %d layers from %s (%d tensors left in the files); this "
+        "family's checkpoint names are untried on a published checkpoint",
+        len(layers), model_dir, len(tensors),
+    )
+    return params
+
+
 LOADERS = {
     "llama": load_hf_safetensors, "mla_moe": load_latent_moe_safetensors,
     "hybrid_ssm": load_hybrid_ssm_safetensors,
+    "conv_moe": load_conv_moe_safetensors,
 }

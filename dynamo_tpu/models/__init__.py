@@ -31,12 +31,16 @@ class CacheKind:
     """What one layer keeps.
 
     Per cached token, in blocks: `kv_heads`, two planes (keys, values) of
-    `heads` x `width`; `latent`, one plane of one row: `width` values
-    (compressed latent, then the shared rope key), stored `stored_width`
-    wide (zeros behind).
+    `heads` rows of `width` values, a row one KV head, or `pack` narrow ones
+    side by side where a head is narrower than a tile's 128 lanes (64-wide
+    heads in pairs: `ops/attention.py`); `latent`, one plane of one row:
+    `width` values (compressed latent, then the shared rope key), stored
+    `stored_width` wide (zeros behind).
     Per sequence, whatever its length: `recurrent`, no rows per token; one
-    slot a lane, `slot` its arrays as (shape, dtype name) pairs (a first and
-    a second: they ride where a paged layer's two planes do)."""
+    slot a lane, `slot` its arrays as (shape, dtype name) pairs: one array
+    (a short convolution's tail) or two (a state-space layer's state and
+    tail). The first rides where a paged layer's keys do, the second where
+    its values do; a slot of one array leaves None there."""
 
     name: str  # "kv_heads" | "latent" | "recurrent"
     planes: int
@@ -44,6 +48,7 @@ class CacheKind:
     width: int
     stored_width: int
     slot: tuple = ()
+    pack: int = 1
 
     def stored_values_per_token(self, tp: int = 1) -> int:
         return self.planes * max(1, self.heads // tp) * self.stored_width
@@ -57,8 +62,13 @@ class CacheKind:
         )
 
 
-def kv_heads_cache(num_kv_heads: int, head_dim: int) -> CacheKind:
-    return CacheKind("kv_heads", 2, num_kv_heads, head_dim, head_dim)
+def kv_heads_cache(num_kv_heads: int, head_dim: int, pack: int = 1) -> CacheKind:
+    """Keys and values by head; with `pack` > 1, that many heads a stored
+    row (the same values a token, in rows a kernel can tile)."""
+    if num_kv_heads % pack:
+        raise ValueError(f"{num_kv_heads} KV heads do not fill rows of {pack}")
+    width = head_dim * pack
+    return CacheKind("kv_heads", 2, num_kv_heads // pack, width, width, pack=pack)
 
 
 def latent_cache(width: int, lanes: int = 128) -> CacheKind:
@@ -157,18 +167,24 @@ def config_from_model_dir(model_dir: str):
     """The config of the family that `config.json`'s `model_type` names."""
     with open(os.path.join(model_dir, "config.json")) as f:
         hf = json.load(f)
-    from dynamo_tpu.models import hybrid_ssm, llama, mla_moe
+    from dynamo_tpu.models import conv_moe, hybrid_ssm, llama, mla_moe
 
     model_type = hf.get("model_type")
     if model_type in mla_moe.MODEL_TYPES:
         return mla_moe.MlaMoeConfig.from_hf_dict(hf)
     if model_type in hybrid_ssm.MODEL_TYPES:
         return hybrid_ssm.HybridSsmConfig.from_hf_dict(hf)
+    if model_type in conv_moe.MODEL_TYPES:
+        return conv_moe.ConvMoeConfig.from_hf_dict(hf)
     if model_type is not None and model_type not in llama.MODEL_TYPES:
+        served = (
+            llama.MODEL_TYPES + mla_moe.MODEL_TYPES + hybrid_ssm.MODEL_TYPES
+            + conv_moe.MODEL_TYPES
+        )
         raise ValueError(
             f"model_type {model_type!r} is not served: its layers are not "
             "implemented here, and a dense grouped-query model built from "
             "its widths would be another model under its name (served: "
-            f"{sorted(llama.MODEL_TYPES + mla_moe.MODEL_TYPES + hybrid_ssm.MODEL_TYPES)})"
+            f"{sorted(served)})"
         )
     return llama.LlamaConfig.from_hf_dict(hf)

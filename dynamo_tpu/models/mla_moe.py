@@ -47,17 +47,15 @@ from dynamo_tpu.ops.attention import live_decode_lanes
 from dynamo_tpu.ops.basics import rms_norm, rope_freqs, swiglu
 from dynamo_tpu.ops.kv_quant import scatter_token_rows
 from dynamo_tpu.ops.linear import linear
-from dynamo_tpu.ops.moe import dropless_experts, router_sigmoid_topk
+# `STEP_STATS` is read off the family's module by the runner (`decode_multi`)
+from dynamo_tpu.ops.moe import (  # noqa: F401
+    STEP_STATS, dropless_experts, expert_step_stats, router_sigmoid_topk,
+)
 from dynamo_tpu.runtime.logging import get_logger
 
 logger = get_logger("dynamo_tpu.models.mla_moe")
 
 MODEL_TYPES = ("joyai_llm_flash",)
-
-# what one expert layer of one step reports (`decode(..., stats=[])`):
-# itself (1), its live assignments, its experts with a token, its busiest
-# expert's tokens; the runner sums them over layers and steps
-STEP_STATS = ("layer_steps", "assignments", "experts_touched", "max_expert_load")
 
 
 @dataclass(frozen=True)
@@ -359,10 +357,7 @@ def _ffn(x, layer, cfg, valid):
         y, group_sizes = dropless_experts(
             h, idx, weights, layer["wg"], layer["wu"], layer["wd"], valid=valid
         )
-    counted = jnp.stack([
-        jnp.int32(1), jnp.sum(group_sizes), jnp.sum(group_sizes > 0),
-        jnp.max(group_sizes),
-    ]).astype(jnp.float32)
+    counted = expert_step_stats(group_sizes)
     if "sg" in layer:
         with jax.named_scope("moe.shared"):
             y = y + linear(

@@ -20,6 +20,20 @@ the only thing that forces the XLA path is a shape the Mosaic tiling
 can't express (_pallas_tileable) or an unpadded prompt length
 (_prefill_block). See README "Kernel coverage" for the full matrix.
 
+Heads narrower than the 128 lanes of a tile (64-wide: `models/conv_moe.py`)
+reach the kernels as *stored rows*: a cache declared with `pack` KV heads a
+row (`models.kv_heads_cache(..., pack=2)`) is `[Hkv / pack, num_blocks,
+block_size, pack * D]`, KV heads `s * pack .. s * pack + pack - 1` side by
+side in row `s`, which is the free reshape `[.., Hkv, D] -> [.., Hkv / pack,
+pack * D]` of what the projections produce, at the same bytes a token.
+Queries always arrive by head. Where keys and values are `pack` times as wide
+as the queries, the Pallas forms give each query head zeros in its
+neighbours' lanes (`_pack_queries`), run the kernels unchanged on heads of
+`pack * D` (a score is the head's own: the zeros cancel the neighbours'
+keys), and keep each head's own lanes of the result (`_own_lanes`); the XLA
+forms split the rows back into heads (`_rows_to_heads`). The scale is the
+narrow head's, `1/sqrt(D)`.
+
 All functions are jit-safe: static shapes, masks instead of dynamic slicing.
 """
 
@@ -65,14 +79,79 @@ def _prefill_block(P: int) -> Optional[int]:
 def _pallas_tileable(
     head_dim: int, block_size: int = 8, kv_bits: int = 16
 ) -> bool:
-    """Mosaic VMEM tiling: lane dim (head_dim) must be a multiple of 128,
-    sublane dim (page block_size) a multiple of 8 — compiling outside
-    that fails on real TPU ('Slice shape ... must be aligned to tiling').
+    """Mosaic VMEM tiling: lane dim must be a multiple of 128, sublane dim
+    (page block_size) a multiple of 8 — compiling outside that fails on
+    real TPU ('Slice shape ... must be aligned to tiling'). `head_dim` is
+    the width keys and values are *stored* at: a head's own width, or
+    `pack` narrow heads side by side in one row (64-wide heads in pairs:
+    the module's docstring), so 64-wide heads are tileable where their
+    cache is declared in pairs and not where it keeps a head a row.
     int8-resident pages tighten the sublane minimum to 32 (the int8 tile
     is (32, 128)). Interpret mode has no such limits, so CPU tests still
     cover any shape; production callers (ModelRunner) pre-check too."""
     sub = 32 if kv_bits == 8 else 8
     return head_dim % 128 == 0 and block_size % sub == 0
+
+
+_said: set = set()
+
+
+def _falls_to_xla(what: str, width: int, block_size: int) -> str:
+    """"xla", said once a process and shape in the log: the Pallas form was
+    asked for and the shape cannot be tiled."""
+    if (what, width, block_size) not in _said:
+        _said.add((what, width, block_size))
+        from dynamo_tpu.runtime.logging import get_logger
+
+        get_logger("dynamo_tpu.ops.attention").warning(
+            "%s: rows of %d values in pages of %d tokens cannot be tiled "
+            "for the Pallas kernel (128 lanes, 8 sublanes; 32 for int8 "
+            "pages); this shape is served by the XLA gather form",
+            what, width, block_size,
+        )
+    return "xla"
+
+
+def _row_pack(q_width: int, kv_width: int) -> int:
+    """KV heads side by side in one stored row: 1 for a head a row."""
+    if kv_width % q_width:
+        raise ValueError(
+            f"keys of {kv_width} values a row do not hold whole heads of "
+            f"{q_width}"
+        )
+    return kv_width // q_width
+
+
+def _pack_queries(q: jax.Array, rows: int, pack: int) -> jax.Array:
+    """q [..., Hq, D] -> [..., Hq, pack * D] for keys stored `pack` heads a
+    row in `rows` rows: query head `(s * pack + j) * G + g` keeps its values
+    in lanes `[j * D, (j + 1) * D)` of the wide head and has zeros in the
+    others, so its product with row `s` is its product with KV head
+    `s * pack + j` alone."""
+    *lead, Hq, D = q.shape
+    G = Hq // (rows * pack)
+    qr = q.reshape(*lead, rows, pack, G, 1, D)
+    own = jnp.eye(pack, dtype=q.dtype).reshape(pack, 1, pack, 1)
+    return (qr * own).reshape(*lead, Hq, pack * D)
+
+
+def _own_lanes(o: jax.Array, rows: int, pack: int) -> jax.Array:
+    """The inverse on the result [..., Hq, pack * D]: each head's own lanes
+    (the others hold its probabilities times a neighbour's values)."""
+    *lead, Hq, W = o.shape
+    D, G = W // pack, Hq // (rows * pack)
+    wide = o.reshape(*lead, rows, pack, G, pack, D)
+    own = jnp.stack([wide[..., j, :, j, :] for j in range(pack)], axis=-3)
+    return own.reshape(*lead, Hq, D)  # [..., rows, pack, G, D] by head
+
+
+def _rows_to_heads(x: jax.Array, pack: int) -> jax.Array:
+    """Stored rows [Hs, ..., pack * D] -> heads [Hs * pack, ..., D]."""
+    if pack == 1:
+        return x
+    Hs, *mid, W = x.shape
+    heads = jnp.moveaxis(x.reshape(Hs, *mid, pack, W // pack), -2, 1)
+    return heads.reshape(Hs * pack, *mid, W // pack)
 
 
 def _cache_quantized(cache) -> bool:
@@ -89,8 +168,8 @@ def _softcap(scores: jax.Array, cap: Optional[float]) -> jax.Array:
 
 def causal_prefill_attention(
     q: jax.Array,  # [P, Hq, D]
-    k: jax.Array,  # [P, Hkv, D]
-    v: jax.Array,  # [P, Hkv, D]
+    k: jax.Array,  # [P, Hkv, D], or stored rows [P, Hkv / pack, pack * D]
+    v: jax.Array,  # as k
     valid_len: jax.Array,  # scalar int32: true sequence length (<= P)
     impl: Optional[str] = None,
     mesh: Optional[jax.sharding.Mesh] = None,
@@ -113,8 +192,22 @@ def causal_prefill_attention(
     unpadded prompt length forces XLA.
     """
     impl = get_attention_impl(impl)
-    if impl == "pallas" and not _pallas_tileable(q.shape[-1]):
-        impl = "xla"
+    pack = _row_pack(q.shape[-1], k.shape[-1])
+    if impl == "pallas" and not _pallas_tileable(k.shape[-1]):
+        impl = _falls_to_xla("prefill attention", k.shape[-1], 8)
+    if pack > 1:
+        if impl != "xla" and _prefill_block(q.shape[0]) is not None:
+            # keys and values as stored rows: wide heads through the kernel
+            out = causal_prefill_attention(
+                _pack_queries(q, k.shape[1], pack), k, v, valid_len, impl,
+                mesh, head_axis, window,
+                scale if scale is not None else q.shape[-1] ** -0.5,
+                logit_softcap,
+            )
+            return _own_lanes(out, k.shape[1], pack)
+        k, v = (
+            x.reshape(x.shape[0], x.shape[1] * pack, q.shape[-1]) for x in (k, v)
+        )
     if impl != "xla":
         bq = _prefill_block(q.shape[0])
         if bq is not None:
@@ -232,8 +325,9 @@ def live_decode_lanes(
 
 def paged_decode_attention(
     q: jax.Array,  # [B, Hq, D] — one new token per sequence
-    k_cache: jax.Array,  # [Hkv, num_blocks, block_size, D] (this layer)
-    v_cache: jax.Array,  # [Hkv, num_blocks, block_size, D]
+    k_cache: jax.Array,  # [Hkv, num_blocks, block_size, D] (this layer), or
+    # stored rows [Hkv / pack, num_blocks, block_size, pack * D]
+    v_cache: jax.Array,  # as k_cache
     block_tables: jax.Array,  # [B, max_blocks] int32 block ids
     context_lens: jax.Array,  # [B] int32 — INCLUDING the token just written;
     # 0 = the lane holds no request: it reads no page and its rows are zero
@@ -266,10 +360,20 @@ def paged_decode_attention(
     kq = k_cache["q"] if quant else k_cache
     vq = v_cache["q"] if quant else v_cache
     impl = get_attention_impl(impl)
+    pack = _row_pack(q.shape[-1], kq.shape[-1])
     if impl == "pallas" and not _pallas_tileable(
-        q.shape[-1], kq.shape[2], kv_bits=8 if quant else 16
+        kq.shape[-1], kq.shape[2], kv_bits=8 if quant else 16
     ):
-        impl = "xla"
+        impl = _falls_to_xla("paged decode attention", kq.shape[-1], kq.shape[2])
+    if pack > 1 and impl != "xla":
+        # a cache of `pack` KV heads a row: wide heads through the kernel
+        out = paged_decode_attention(
+            _pack_queries(q, kq.shape[0], pack), k_cache, v_cache,
+            block_tables, context_lens, impl, mesh, head_axis, window,
+            scale if scale is not None else q.shape[-1] ** -0.5,
+            logit_softcap,
+        )
+        return _own_lanes(out, kq.shape[0], pack)
     if impl != "xla":
         from dynamo_tpu.ops.pallas_attention import paged_decode_attention_pallas
 
@@ -314,26 +418,28 @@ def paged_decode_attention(
             interpret=interp,
         )
     B, Hq, D = q.shape
-    Hkv, _, block_size, _ = kq.shape
+    Hs, _, block_size, W = kq.shape
+    Hkv = Hs * pack
     G = Hq // Hkv
     max_blocks = block_tables.shape[1]
     S = max_blocks * block_size
     sc = jnp.float32(scale) if scale is not None else (
         1.0 / jnp.sqrt(D).astype(jnp.float32)
     )
-    # [Hkv, B, max_blocks, block_size, D] -> [Hkv, B, S, D]
+    # [Hs, B, max_blocks, block_size, W] -> [Hkv, B, S, D]
     if quant:
         from dynamo_tpu.ops.kv_quant import dequantize
 
         k = dequantize(
             kq[:, block_tables], k_cache["s"][:, block_tables]
-        ).reshape(Hkv, B, S, D)
+        ).reshape(Hs, B, S, W)
         v = dequantize(
             vq[:, block_tables], v_cache["s"][:, block_tables]
-        ).reshape(Hkv, B, S, D)
+        ).reshape(Hs, B, S, W)
     else:
-        k = k_cache[:, block_tables].reshape(Hkv, B, S, D)
-        v = v_cache[:, block_tables].reshape(Hkv, B, S, D)
+        k = k_cache[:, block_tables].reshape(Hs, B, S, W)
+        v = v_cache[:, block_tables].reshape(Hs, B, S, W)
+    k, v = _rows_to_heads(k, pack), _rows_to_heads(v, pack)
     qr = q.reshape(B, Hkv, G, D)
     scores = jnp.einsum(
         "bhgd,hbsd->bhgs", qr.astype(jnp.float32), k.astype(jnp.float32)
@@ -387,10 +493,15 @@ def paged_verify_attention(
     kq = k_cache["q"] if quant else k_cache
     vq = v_cache["q"] if quant else v_cache
     impl = get_attention_impl(impl)
+    if kq.shape[-1] != q.shape[-1]:
+        raise NotImplementedError(
+            "verification over a cache of several KV heads a row: no family "
+            "that declares one is served with speculation"
+        )
     if impl == "pallas" and not _pallas_tileable(
         q.shape[-1], kq.shape[2], kv_bits=8 if quant else 16
     ):
-        impl = "xla"
+        impl = _falls_to_xla("paged verify attention", q.shape[-1], kq.shape[2])
     if impl != "xla":
         from dynamo_tpu.ops.pallas_attention import (
             paged_verify_attention_pallas,
@@ -497,7 +608,9 @@ def chunked_prefill_attention(
     C, Hq, D = q.shape
     quant = _cache_quantized(k_cache)
     kc = k_cache["q"] if quant else k_cache
-    Hkv, _, block_size, _ = kc.shape
+    Hs, _, block_size, W = kc.shape
+    pack = _row_pack(D, W)
+    Hkv = Hs * pack
     G = Hq // Hkv
     S = block_table.shape[0] * block_size
     sc = jnp.float32(scale) if scale is not None else (
@@ -508,13 +621,14 @@ def chunked_prefill_attention(
 
         k = dequantize(
             kc[:, block_table], k_cache["s"][:, block_table]
-        ).reshape(Hkv, S, D)
+        ).reshape(Hs, S, W)
         v = dequantize(
             v_cache["q"][:, block_table], v_cache["s"][:, block_table]
-        ).reshape(Hkv, S, D)
+        ).reshape(Hs, S, W)
     else:
-        k = k_cache[:, block_table].reshape(Hkv, S, D)
-        v = v_cache[:, block_table].reshape(Hkv, S, D)
+        k = k_cache[:, block_table].reshape(Hs, S, W)
+        v = v_cache[:, block_table].reshape(Hs, S, W)
+    k, v = _rows_to_heads(k, pack), _rows_to_heads(v, pack)
     qr = q.reshape(C, Hkv, G, D)
     scores = jnp.einsum(
         "chgd,hsd->hgcs", qr.astype(jnp.float32), k.astype(jnp.float32)

@@ -76,16 +76,19 @@ def router_sigmoid_topk(
     top_k: int,
     scale: float = 1.0,
     renormalize: bool = True,
+    eps: float = 1e-20,
 ) -> tuple[jax.Array, jax.Array]:
     """DeepSeek-V3 `noaux_tc` routing with one group: scores are sigmoids;
     the k experts with the largest score + bias are chosen; the weights are
     the chosen experts' *scores* (not score + bias), normalised over the k
-    and multiplied by `scale`. Returns ([T, k] ids, [T, k] f32 weights)."""
+    (their sum plus `eps`: 1e-20 as DeepSeek-V3 publishes it, 1e-6 in
+    LFM2's routing) and multiplied by `scale`. Returns ([T, k] ids, [T, k]
+    f32 weights)."""
     scores = jax.nn.sigmoid(logits.astype(jnp.float32))
     _, idx = lax.top_k(scores + bias.astype(jnp.float32), top_k)
     weights = jnp.take_along_axis(scores, idx, axis=-1)
     if renormalize:
-        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + eps)
     return idx, weights * scale
 
 
@@ -213,6 +216,21 @@ def dropless_experts(
     inv = jnp.zeros_like(order).at[order].set(jnp.arange(T * k, dtype=order.dtype))
     y = ys[inv].reshape(T, k, D) * weights.astype(jnp.float32)[:, :, None]
     return y.sum(axis=1), group_sizes
+
+
+# what one expert layer of one decode step reports (a family's
+# `decode(..., stats=[])` and its `STEP_STATS`): itself (1), its live
+# assignments, its experts with a token, its busiest expert's tokens; the
+# runner sums them over layers and steps (`telemetry.goodput.MOE_COUNTERS`)
+STEP_STATS = ("layer_steps", "assignments", "experts_touched", "max_expert_load")
+
+
+def expert_step_stats(group_sizes: jax.Array) -> jax.Array:
+    """`STEP_STATS` of one expert layer from its experts' live tokens [E]."""
+    return jnp.stack([
+        jnp.int32(1), jnp.sum(group_sizes), jnp.sum(group_sizes > 0),
+        jnp.max(group_sizes),
+    ]).astype(jnp.float32)
 
 
 def moe_ffn_dropless(

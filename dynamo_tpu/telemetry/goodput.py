@@ -84,7 +84,7 @@ def enabled_from_env() -> bool:
 
 
 # expert-layer counters of a sparse-expert model, computed on the device in
-# `decode_multi` and fetched with the tokens (`models.mla_moe.STEP_STATS`):
+# `decode_multi` and fetched with the tokens (`ops.moe.STEP_STATS`):
 # sums over (expert layer, step) pairs, whose number is `layer_steps`
 MOE_COUNTERS = (
     "layer_steps", "assignments", "experts_touched", "max_expert_load",
@@ -97,11 +97,13 @@ MOE_COUNTERS = (
 SAMPLER_COUNTERS = ("dispatches", "pool_dispatches")
 
 
-# a model with recurrent layers (a state slot a sequence): `layer_steps`,
-# (recurrent layer, decode step) pairs; `slots_live`, live lanes summed over
-# decode steps; `slot_resets`, sequences whose state a prefill program zeroed
-# at position 0; `scan_tokens`, prompt tokens through the prefill scans.
-# Counted on the host where the lane arrays are built, before the call
+# a model with recurrent layers (a slot a sequence: a state-space layer's
+# state and tail, a short convolution's tail): `layer_steps`, (recurrent
+# layer, decode step) pairs; `slots_live`, live lanes summed over decode
+# steps; `slot_resets`, sequences whose slot a prefill program started from
+# zeros at position 0; `scan_tokens`, prompt tokens through the prefill scans
+# or convolutions. Counted on the host where the lane arrays are built,
+# before the call
 SSM_COUNTERS = ("layer_steps", "slots_live", "slot_resets", "scan_tokens")
 
 

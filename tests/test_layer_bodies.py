@@ -243,3 +243,44 @@ def test_the_latent_family_has_a_dense_and_an_expert_body():
     # the named scopes cross the call boundary into the operations' locations
     for scope in ("mla.attend", "moe.route", "moe.experts", "moe.shared"):
         assert scope in text4, scope
+
+
+def test_the_short_convolution_family_has_three_bodies_whatever_its_depth():
+    """`models/conv_moe.py`: a dense convolution layer, an expert convolution
+    layer and an expert attention layer are three bodies a program, lowered
+    once each: a model of twice the depth, its literal `layer_types` repeated,
+    lowers to as many private functions."""
+    from dynamo_tpu.models import conv_moe as C
+    from dynamo_tpu.models import layer_bodies_called
+    from tests.test_conv_moe import HF
+
+    def lowered(cfg):
+        jax.clear_caches()
+        params = C.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
+        pages = lambda: jnp.zeros((cfg.num_kv_heads // cfg.kv_pack, 8, 4, cfg.kv_pack * cfg.head_dim), jnp.float32)
+        k_cache = tuple(
+            pages() if cfg.is_attn_layer(i) else jnp.zeros((3, 2 * cfg.hidden_size), jnp.float32)
+            for i in range(cfg.num_layers)
+        )
+        v_cache = tuple(pages() if cfg.is_attn_layer(i) else None for i in range(cfg.num_layers))
+        ids = jnp.zeros((2,), jnp.int32)
+        fn = jax.jit(lambda p, k, v: C.decode(
+            p, cfg, ids, ids, k, v, jnp.zeros((2, 2), jnp.int32), ids + 4,
+        ))
+        with layer_bodies_called() as bodies:
+            text = fn.lower(params, k_cache, v_cache).as_text(debug_info=True)
+        return text, len(bodies)
+
+    cfg = C.ConvMoeConfig.from_hf_dict(HF)
+    text6, called = lowered(cfg)
+    assert called == 3
+    kinds = list(HF["layer_types"])
+    deep = C.ConvMoeConfig.from_hf_dict(dict(
+        HF, num_hidden_layers=10, layer_types=kinds + kinds[2:],
+    ))
+    text10, called10 = lowered(deep)
+    assert called10 == 3
+    assert private_functions(text10) == private_functions(text6)
+    # the named scopes cross the call boundary into the operations' locations
+    for scope in ("conv.mix", "moe.route", "moe.experts"):
+        assert scope in text6, scope

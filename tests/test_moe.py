@@ -314,3 +314,38 @@ def test_moe_ep_a2a_with_tp():
         )
     )(x)
     np.testing.assert_allclose(np.asarray(out), ref, atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("eps", [1e-20, 1e-6])
+def test_sigmoid_router_bias_moves_the_choice_and_never_the_weights(eps):
+    """`router_sigmoid_topk`: the bias takes part in the choice only; the
+    weights are the chosen experts' own scores over (their sum + `eps`), 1e-20
+    by default (DeepSeek-V3's, the programs JoyAI compiles) and 1e-6 where a
+    family says so (LFM2's published routing)."""
+    import inspect
+
+    from dynamo_tpu.ops.moe import router_sigmoid_topk
+
+    assert inspect.signature(router_sigmoid_topk).parameters["eps"].default == 1e-20
+    logits = jnp.asarray([[2.0, 1.0, 0.5, -1.0, 0.0, -3.0]], jnp.float32)
+    plain, w_plain = router_sigmoid_topk(logits, jnp.zeros(6), 2, eps=eps)
+    assert sorted(np.asarray(plain[0]).tolist()) == [0, 1]
+    # a bias that lifts the last expert over every other: it is chosen, and
+    # its weight is its own small score's share, not the lifted one's
+    bias = jnp.asarray([0.0, 0.0, 0.0, 0.0, 0.0, 5.0], jnp.float32)
+    idx, w = router_sigmoid_topk(logits, bias, 2, scale=1.0, eps=eps)
+    assert sorted(np.asarray(idx[0]).tolist()) == [0, 5]
+    s = np.asarray(jax.nn.sigmoid(logits[0]), np.float64)
+    want = {0: s[0] / (s[0] + s[5] + eps), 5: s[5] / (s[0] + s[5] + eps)}
+    for e, got in zip(np.asarray(idx[0]).tolist(), np.asarray(w[0]).tolist()):
+        assert abs(got - want[e]) < 1e-6, (e, got, want[e])
+    # the weights of an unmoved choice do not see the bias at all
+    small = jnp.asarray([0.01, 0.02, 0.0, 0.0, 0.0, 0.0], jnp.float32)
+    idx2, w2 = router_sigmoid_topk(logits, small, 2, eps=eps)
+    np.testing.assert_array_equal(np.asarray(idx2), np.asarray(plain))
+    np.testing.assert_array_equal(np.asarray(w2), np.asarray(w_plain))
+    # the normaliser is told apart where the scores are tiny
+    tiny = jnp.full((1, 6), -20.0, jnp.float32)
+    _, w_tiny = router_sigmoid_topk(tiny, jnp.zeros(6), 2, eps=eps)
+    total = float(w_tiny.sum())
+    assert (total > 0.99) if eps == 1e-20 else (total < 0.01)

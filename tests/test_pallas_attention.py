@@ -220,3 +220,107 @@ def test_runner_untileable_config_downgrades_to_xla():
         max_model_len=64, attn_impl="pallas",
     )
     assert runner.attn_impl == "xla"
+
+
+# ---------------------------------------------- 64-wide heads in pairs (PR 44)
+
+
+def _rows(cache, pack):
+    """A cache of a head a row `[Hkv, nb, bs, D]` as stored rows of `pack`
+    heads side by side `[Hkv / pack, nb, bs, pack * D]`."""
+    hkv, nb, bs, d = cache.shape
+    paired = jnp.moveaxis(cache.reshape(hkv // pack, pack, nb, bs, d), 1, 3)
+    return paired.reshape(hkv // pack, nb, bs, pack * d)
+
+
+@pytest.mark.parametrize("hq,hkv,D,pack", [(32, 8, 64, 2), (8, 4, 64, 2), (8, 4, 32, 4), (4, 2, 64, 2)])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_decode_over_paired_rows_matches_xla(hq, hkv, D, pack, dtype):
+    """Heads narrower than a tile's lanes, cached `pack` to a row: the
+    public call widens the queries, runs the kernel on heads of `pack * D`
+    and keeps each head's own lanes; it gives what the XLA form gives on the
+    same rows, and both give what a cache of a head a row gives. An idle lane
+    gets zeros; the scale stays the narrow head's."""
+    B, block_size, num_blocks, max_blocks = 5, 16, 40, 6
+    keys = jax.random.split(jax.random.PRNGKey(3), 4)
+    q = _rand(keys[0], (B, hq, D), dtype)
+    k_cache = _rand(keys[1], (hkv, num_blocks, block_size, D), dtype)
+    v_cache = _rand(keys[2], (hkv, num_blocks, block_size, D), dtype)
+    tables = jax.random.permutation(keys[3], num_blocks)[: B * max_blocks].reshape(B, max_blocks).astype(jnp.int32)
+    lens = jnp.array([0, 1, 17, 64, 96], jnp.int32)
+    by_head = A.paged_decode_attention(q, k_cache, v_cache, tables, lens, impl="xla")
+    kr, vr = _rows(k_cache, pack), _rows(v_cache, pack)
+    assert kr.shape[-1] == pack * D and kr.size == k_cache.size  # the same bytes
+    xla = A.paged_decode_attention(q, kr, vr, tables, lens, impl="xla")
+    np.testing.assert_array_equal(np.asarray(xla, np.float32), np.asarray(by_head, np.float32))
+    out = A.paged_decode_attention(q, kr, vr, tables, lens, impl="pallas_interpret")
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(by_head, np.float32), atol=tol, rtol=tol
+    )
+    assert (np.asarray(out, np.float32)[0] == 0).all()
+    scaled = A.paged_decode_attention(q, kr, vr, tables, lens, impl="pallas_interpret", scale=0.05)
+    want = A.paged_decode_attention(q, k_cache, v_cache, tables, lens, impl="xla", scale=0.05)
+    np.testing.assert_allclose(
+        np.asarray(scaled, np.float32), np.asarray(want, np.float32), atol=tol, rtol=tol
+    )
+
+
+@pytest.mark.parametrize("p,valid", [(32, 32), (64, 40), (128, 5)])
+@pytest.mark.parametrize("hq,hkv,D,pack", [(32, 8, 64, 2), (8, 4, 32, 4)])
+def test_flash_prefill_over_paired_rows_matches_xla(p, valid, hq, hkv, D, pack):
+    """Keys and values handed to prefill attention as stored rows (the free
+    reshape of what the projections produce): the flash kernel on widened
+    queries against the XLA form by head."""
+    keys = jax.random.split(jax.random.PRNGKey(4), 3)
+    q = _rand(keys[0], (p, hq, D))
+    k = _rand(keys[1], (p, hkv, D))
+    v = _rand(keys[2], (p, hkv, D))
+    vl = jnp.int32(valid)
+    ref = A.causal_prefill_attention(q, k, v, vl, impl="xla")
+    kr, vr = k.reshape(p, hkv // pack, pack * D), v.reshape(p, hkv // pack, pack * D)
+    xla = A.causal_prefill_attention(q, kr, vr, vl, impl="xla")
+    np.testing.assert_array_equal(np.asarray(xla)[:valid], np.asarray(ref)[:valid])
+    out = A.causal_prefill_attention(q, kr, vr, vl, impl="pallas_interpret")
+    np.testing.assert_allclose(
+        np.asarray(out)[:valid], np.asarray(ref)[:valid], atol=2e-5, rtol=2e-5
+    )
+
+
+def test_chunked_prefill_reads_paired_rows():
+    keys = jax.random.split(jax.random.PRNGKey(5), 4)
+    hq, hkv, D, pack, nb, bs = 8, 4, 64, 2, 24, 8
+    q = _rand(keys[0], (16, hq, D))
+    k_cache = _rand(keys[1], (hkv, nb, bs, D))
+    v_cache = _rand(keys[2], (hkv, nb, bs, D))
+    table = jax.random.permutation(keys[3], nb)[:6].astype(jnp.int32)
+    ref = A.chunked_prefill_attention(q, k_cache, v_cache, table, jnp.int32(24))
+    out = A.chunked_prefill_attention(q, _rows(k_cache, pack), _rows(v_cache, pack), table, jnp.int32(24))
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+
+
+def test_a_shape_that_falls_to_xla_says_so_once(caplog):
+    """`impl="pallas"` on rows the kernel cannot tile (64-wide heads a head a
+    row, as the grouped-query family caches them) is served by the XLA form
+    and the log says so, once a shape; rows that do not hold whole heads are
+    refused."""
+    import logging
+
+    keys = jax.random.split(jax.random.PRNGKey(6), 3)
+    B, hq, hkv, D, bs, nb = 2, 4, 2, 64, 16, 16
+    q = _rand(keys[0], (B, hq, D))
+    kc = _rand(keys[1], (hkv, nb, bs, D))
+    vc = _rand(keys[2], (hkv, nb, bs, D))
+    bt = jnp.tile(jnp.arange(4, dtype=jnp.int32), (B, 1))
+    cl = jnp.array([3, 9], jnp.int32)
+    A._said.clear()
+    with caplog.at_level(logging.WARNING, logger="dynamo_tpu.ops.attention"):
+        for _ in range(3):
+            out = A.paged_decode_attention(q, kc, vc, bt, cl, impl="pallas")
+    ref = A.paged_decode_attention(q, kc, vc, bt, cl, impl="xla")
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-6)
+    said = [r for r in caplog.records if "cannot be tiled" in r.getMessage()]
+    assert len(said) == 1 and "rows of 64 values" in said[0].getMessage()
+    assert A._pallas_tileable(128, 16) and not A._pallas_tileable(64, 16)
+    with pytest.raises(ValueError, match="do not hold whole heads"):
+        A.paged_decode_attention(q, _rand(keys[1], (1, nb, bs, 96)), vc, bt, cl)

@@ -921,10 +921,17 @@ def _hybrid_step_setup(one_chip, num_blocks: int = 32832, layers: int = 4):
 
 
 def _lower_hybrid(one_chip, program: str, packed: bool = False):
+    return _lower_slotted(one_chip, _hybrid_step_setup, program, packed)
+
+
+def _lower_slotted(one_chip, setup, program: str, packed: bool = False):
+    """A step program of a family that keeps a slot a lane (`setup` gives its
+    config, parameters and the two cache containers), lowered at the cells'
+    shapes: 64 lanes, a 512-entry block table, a 512-token chunk or pack."""
     from dynamo_tpu.engine.jax_engine.model_runner import ModelRunner
     from dynamo_tpu.ops.sampling import MAX_EOS_IDS
 
-    cfg, params, kc, vc = _hybrid_step_setup(one_chip)
+    cfg, params, kc, vc = setup(one_chip)
     vec = lambda dtype: one_chip((B,), dtype)
     scalar = lambda dtype: one_chip((), dtype)
     table = 8192 // BLOCK
@@ -951,6 +958,16 @@ def _lower_hybrid(one_chip, program: str, packed: bool = False):
                 (chunk,), vec(I32), vec(I32), one_chip((B, table), I32),
                 vec(I32), one_chip((B, 2), jnp.uint32), vec(F32), vec(F32), vec(I32),
                 one_chip((B, MAX_EOS_IDS), I32), vec(jnp.bool_),
+            ), packed=packed,
+        )
+    if program == "prefill@512":
+        return _lower_step(
+            one_chip, functools.partial(ModelRunner._prefill_impl, cfg, None, None),
+            params, (kc, vc), (
+                one_chip((512,), I32), scalar(I32), one_chip((table,), I32),
+                one_chip((2,), jnp.uint32), scalar(F32), scalar(F32), scalar(I32),
+                scalar(F32), one_chip((MAX_EOS_IDS,), I32), scalar(jnp.bool_),
+                scalar(I32),  # the sequence's lane slot
             ), packed=packed,
         )
     tok = lambda dtype: one_chip((512,), dtype)
@@ -1008,3 +1025,116 @@ def test_hybrid_step_programs_one_chip(one_chip, program, bodies, kernels, loops
             if re.search(r" = \(?[^=]*f32\[65,16,5120\][^=]* fusion\(", line)
         ]
         assert 1 <= len(produced) <= 2 * 4, len(produced)
+
+
+# ------------- the short-convolution, sparse-expert family's programs (PR 44)
+#
+# `cellbench/configs/lfm2-8b-a1b-bf16-l16.json` at the published widths, cut
+# here to one period of the pattern behind the two dense layers' place (a
+# dense convolution layer, an expert convolution layer, an expert attention
+# layer, an expert convolution layer) so that a compile takes seconds: a tail
+# slot a lane (`[65, 4096]` bfloat16, ONE array, nothing where a paged
+# layer's values ride) beside paged keys and values of 8 KV heads of 64 cached
+# two to a row of 128 lanes, 64 lanes, 27,000 blocks. The full depth compiles
+# too (PERF.md section 6, PR 44).
+
+
+def _conv_moe_setup(one_chip, num_blocks: int = 27000, layers: int = 4):
+    from dynamo_tpu.models import conv_moe
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "cellbench", "configs", "lfm2-8b-a1b-bf16-l16.json")) as f:
+        conf = json.load(f)
+    cfg = conv_moe.ConvMoeConfig.from_hf_dict(
+        {k: v for k, v in conf.items() if k != "bench"}
+        | {"num_hidden_layers": layers, "num_dense_layers": 1,
+           "layer_types": ["conv", "conv", "full_attention", "conv"][:layers]}
+    )
+    cfg = dataclasses.replace(cfg, attn_impl="pallas")
+    params = jax.tree_util.tree_map(
+        lambda a: one_chip(a.shape, a.dtype),
+        jax.eval_shape(lambda: conv_moe.init_params(cfg, jax.random.PRNGKey(0))),
+    )
+    ((tail, tail_dtype),) = cfg.tail_kind().slot
+    pages = one_chip(
+        (cfg.num_kv_heads // cfg.kv_pack, num_blocks, BLOCK, cfg.kv_pack * cfg.head_dim), BF16
+    )
+    first = tuple(
+        pages if cfg.is_attn_layer(i) else one_chip((B + 1,) + tail, jnp.dtype(tail_dtype))
+        for i in range(layers)
+    )
+    second = tuple(pages if cfg.is_attn_layer(i) else None for i in range(layers))
+    return cfg, params, first, second
+
+
+def _lower_conv_moe(one_chip, program: str, packed: bool = False):
+    return _lower_slotted(one_chip, _conv_moe_setup, program, packed)
+
+
+def test_paged_kernels_at_64_wide_heads_in_pairs(one_chip):
+    """32 query heads over 8 KV heads of 64, cached two heads to a row of 128
+    lanes: the paged decode kernel and the flash prefill kernel compile for
+    the chip on the rows (`ops/attention.py` widens the queries), where a
+    cache of a head a row is refused by the tiling and falls to XLA."""
+    from dynamo_tpu.ops import attention
+
+    nb = 1024
+    decode = lambda q, k, v, t, c: attention.paged_decode_attention(q, k, v, t, c, impl="pallas")
+    host = (one_chip((B, CONTEXT // BLOCK), I32), one_chip((B,), I32))
+    rows = one_chip((4, nb, BLOCK, 128), BF16)
+    text = compile_text(decode, one_chip((B, 32, 64), BF16), rows, rows, *host)
+    assert "tpu_custom_call" in text
+    heads = one_chip((8, nb, BLOCK, 64), BF16)
+    text = compile_text(decode, one_chip((B, 32, 64), BF16), heads, heads, *host)
+    assert "tpu_custom_call" not in text
+    prefill = lambda q, k, v, n: attention.causal_prefill_attention(q, k, v, n, impl="pallas")
+    text = compile_text(
+        prefill, one_chip((512, 32, 64), BF16), one_chip((512, 4, 128), BF16),
+        one_chip((512, 4, 128), BF16), one_chip((), I32),
+    )
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("packed", [False, True], ids=["impl", "as_launched"])
+@pytest.mark.parametrize("program,bodies,kernels", [
+    ("decode_multi@H4B64", 3, 1 * 4),  # 1 attention layer x 4 steps
+    ("mixed_step@c1", 6, 1),  # the chunk's attention is XLA's
+    ("prefill_packed@512", 3, 0),
+    ("prefill@512", 3, 1),  # the flash prefill kernel
+])
+def test_conv_moe_step_programs_one_chip(one_chip, program, bodies, kernels, packed):
+    """The family's step programs compile for the chip: three layer bodies a
+    pass (dense-convolution, expert-convolution, expert-attention; a mixed
+    step has a chunk's pass and a decode's), the paged kernels under their
+    name at 64-wide heads, the grouped products of the expert layers, the
+    slot arrays and the pages written in place (aliased), no device loop (a
+    convolution over three positions is three shifted products), and
+    everything fits."""
+    from dynamo_tpu.models import layer_bodies_called
+
+    jax.clear_caches()  # a body traced by another test would not be counted
+    with layer_bodies_called() as seen:
+        lowered = _lower_conv_moe(one_chip, program, packed)
+    assert len(seen) == bodies, sorted(s[1] for s in seen)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    names = re.findall(
+        r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\""
+        r"[^\n]*op_name=\"[^\"\n]*pallas_call\"", text, re.M,
+    )
+    assert len(names) == kernels
+    assert all(name.startswith("tpu_custom_call") for name in names), names
+    assert "ragged-dot" in text or "ragged_dot" in text
+    assert not re.findall(r"^\s*%?[\w.\-]+ = [^\n]*? while\(", text, re.M)
+    mem = compiled.memory_analysis()
+    # three convolution layers' tails (65 rows are tiled to 72)
+    # and one attention layer's two planes, at the published bytes a token
+    tails = 3 * 72 * 4096 * 2
+    pages = 2 * 27000 * BLOCK * 8 * 64 * 2
+    assert mem.alias_size_in_bytes == tails + pages
+    assert mem.temp_size_in_bytes < 1.5 * 2**30
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16_909_336_064
+    if program == "decode_multi@H4B64":
+        # what `short_conv_ms` reads: instructions that mention a layer's
+        # tails, bfloat16 [65, 4096]
+        assert re.search(r"bf16\[65,4096\]", text)
