@@ -485,6 +485,8 @@ def goodput_families(
          "device"),
         ("upload_bytes", "bytes of those arrays"),
         ("fetch_bytes", "bytes of the results read back to the host"),
+        ("chained", "dispatches enqueued behind a decode_multi the host had "
+         "not read yet, their lanes taken from its carry on the device"),
     ):
         yield CounterMetricFamily(
             f"{PREFIX}_launch_{name}",

@@ -53,6 +53,7 @@ from dynamo_tpu.telemetry.goodput import (
     load_prebaked_labels,
     long_part,
     normalize_label,
+    step_phase,
 )
 from dynamo_tpu.telemetry.histogram import PhaseHistograms
 from dynamo_tpu.tokens import TokenBlockSequence
@@ -350,6 +351,41 @@ class _Sequence(SequenceState):
         return self.num_prompt + max(0, self.num_generated - 1)
 
 
+# why a dispatch that is no `decode_multi` was not chained, by the family of
+# its label (goodput.step_phase): an arrival's prefill, a step that carries
+# somebody's chunk; the decode family's callers say it themselves
+_WHY_BY_PHASE = {"prefill": "arrival", "mixed": "prefilling"}
+
+
+@dataclass(eq=False)
+class _Flight:
+    """A `decode_multi` dispatch on the device's queue whose tokens the host
+    has not read yet. The engine keeps at most one between two passes of its
+    loop, and a second, chained on the first's carry, while it waits for the
+    first's fetch (`JaxEngine._decode_multi_phase`)."""
+
+    label: str
+    lanes: list  # the sequences it was launched with: its replay walks them
+    H: int
+    # when its time began for the watchdog, the EMA and the ledger: its own
+    # launch, or the return of the fetch before it where that came later
+    since: float = 0.0
+    packed: Any = None  # the runner's device array, read when it lands
+    # blocks of lanes the host alone ended while this dispatch named them
+    # (`_free_seq`): given back when it has landed
+    held: list = field(default_factory=list)
+    # what its own launch took where that was a call of its own, and so
+    # part of its time (`runner.upload`, `runner.enqueue`: seconds)
+    launch_s: tuple = (0.0, 0.0)
+    # the dispatch before it, while that one's fetch is awaited
+    prev: Optional["_Flight"] = None
+
+    def names(self, seq) -> bool:
+        return any(s is seq for s in self.lanes) or (
+            self.prev is not None and self.prev.names(seq)
+        )
+
+
 class JaxEngine:
     """AsyncEngine implementation backed by a ModelRunner."""
 
@@ -446,6 +482,14 @@ class JaxEngine:
         # and an EMA of past dispatch durations per label
         self._dispatch_info: Optional[tuple[str, float]] = None
         self._dispatch_ema: dict[str, float] = {}
+        # the `decode_multi` dispatch left on the device's queue between two
+        # passes of the loop (steady decode launches ahead), why the last
+        # one was landed or none was left (of goodput.CHAIN_BREAKS: what the
+        # next unchained `decode_multi` counts), and why `_horizon_for`
+        # last answered 1
+        self._flight: Optional[_Flight] = None
+        self._chain_why = "other"
+        self._horizon_why = "other"
         self._watchdog_task: Optional[asyncio.Task] = None
         self._tripped = False
         # recompile forensics (ISSUE 14): a warm label dispatching far off
@@ -745,6 +789,9 @@ class JaxEngine:
         ctx_tokens: int = 0,
         horizon: int = 1,
         state_resets: int = 0,
+        why: Optional[str] = None,
+        launches: Optional[_Flight] = None,
+        lands: Optional[_Flight] = None,
     ) -> Any:
         """Run one device dispatch in the executor, visible to the
         stuck-horizon watchdog (and to fault injection). Callers hold
@@ -759,12 +806,26 @@ class JaxEngine:
         recurrent layers `state_slots` (lanes that hold a sequence, and so a
         state) rides the phase too, and the ledger's `ssm` slot counts the
         dispatch from the same host-side numbers; `state_resets` is how many
-        sequences start at position 0 in it."""
-        pool = capacity > 0 and bool(
+        sequences start at position 0 in it.
+
+        As a rule `fn` launches a program and reads its result: one
+        dispatch, whole. The engine's launch ahead splits the two. With
+        `launches`, `fn` launches that `decode_multi` and leaves it on the
+        device's queue (`self._flight`); with `lands`, `fn` reads the result
+        of the one launched before; with both, in that order: the new one is
+        queued behind the old before the old one's tokens are waited for.
+        What belongs to a launch (fault injection, the ledger's `launch`,
+        `sampler` and `ssm`, and `why` it was not chained, of
+        goodput.CHAIN_BREAKS) is recorded for the dispatch this call
+        launches; what belongs to a dispatch's time (the watchdog, the EMA,
+        the ledger's step record) for the one whose result it reads, from
+        `lands.since` on: the older of the two."""
+        launching = launches is not None or lands is None
+        pool = launching and capacity > 0 and bool(
             draw_restrictions(self._temps, self._top_ps, self._top_ks)[1]
         )
         slow_factor = 1.0
-        if faults.active():
+        if launching and faults.active():
             inj = faults.get_injector()
             if inj is not None:
                 await inj.on_dispatch()
@@ -806,7 +867,14 @@ class JaxEngine:
         try:
             with dispatch:
                 t0 = dispatch.start_s
-                self._dispatch_info = (label, t0)
+                if lands is None:
+                    # nothing older is on the device: this one's time runs
+                    self._dispatch_info = (label, t0)
+                if launches is not None:
+                    # from here on a lane the host ends is named by it
+                    launches.since = t0
+                    launches.prev = lands
+                    self._flight = launches
                 result = await loop.run_in_executor(None, run)
                 if slow_factor > 1.0:
                     # injected gray-worker fault: stretch the dispatch to
@@ -819,74 +887,116 @@ class JaxEngine:
                     )
             return result
         finally:
-            elapsed = dispatch.seconds
-            self._dispatch_info = None
-            ema = self._dispatch_ema.get(label)
-            self._dispatch_ema[label] = (
-                elapsed if ema is None else 0.8 * ema + 0.2 * elapsed
-            )
+            end = t0 + dispatch.seconds
             gp = self.stats.goodput
+            if lands is not None:
+                # it has landed: what waited for it goes back, and the one
+                # queued behind it is the oldest on the device from now
+                if lands.held:
+                    self.allocator.free(lands.held)
+                    lands.held = []
+                if launches is not None:
+                    launches.prev = None
+                    launches.since = end
+                elif self._flight is lands:
+                    self._flight = None
+            elif launches is not None:
+                launches.launch_s = (launch.upload_s, launch.enqueue_s)
+            self._watch_flight()
+            if launches is None or lands is not None:
+                # a dispatch's time ends with this call
+                since = t0 if lands is None else lands.since
+                elapsed = end - since
+                ema = self._dispatch_ema.get(label)
+                self._dispatch_ema[label] = (
+                    elapsed if ema is None else 0.8 * ema + 0.2 * elapsed
+                )
+                if gp.enabled:
+                    if ema is None:
+                        # first dispatch of this label includes its XLA
+                        # compile (same fact the cold watchdog budget uses)
+                        gp.record_compile(label, elapsed, split)
+                        logger.info(
+                            "first dispatch of %s: %.2f s (%s)", label,
+                            elapsed,
+                            ", ".join(f"{k} {v:.2f}" for k, v in split.items()),
+                        )
+                        if (
+                            normalize_label(label) in self._prebaked_labels
+                            and elapsed >= self._recompile.min_s
+                        ):
+                            # a prebaked label should boot as a cache HIT;
+                            # a compile-sized first dispatch is cache drift
+                            gp.record_recompile(
+                                label,
+                                "prebake_miss",
+                                shape=f"lanes={lanes},tokens={tokens}",
+                            )
+                    elif self._recompile.is_recompile(elapsed, ema):
+                        # a compile can only happen inside the jitted call;
+                        # a dispatch that was long elsewhere is the host's
+                        # stall (the parts are this call's own)
+                        parts = launch_parts(
+                            end - t0, max(0.0, call.start_s - t0),
+                            call.seconds, launch,
+                        )
+                        if lands is not None:
+                            parts["upload"] += lands.launch_s[0]
+                            parts["enqueue"] += lands.launch_s[1]
+                        if long_part(parts) == "enqueue":
+                            gp.record_recompile(
+                                label,
+                                "prebake_miss"
+                                if normalize_label(label)
+                                in self._prebaked_labels
+                                else "shape_miss",
+                                shape=f"lanes={lanes},tokens={tokens}",
+                            )
+                        else:
+                            gp.record_stall(label, parts)
+                    gp.record_step(
+                        label,
+                        elapsed,
+                        lanes=lanes if lands is None else len(lands.lanes),
+                        capacity=(
+                            capacity if lands is None
+                            else self.config.max_batch
+                        ),
+                        prefill_tokens=tokens,
+                        t_start=since,
+                    )
+                    if dtrace.enabled():
+                        dtrace.counter("step_ms", elapsed * 1e3)
             if gp.enabled:
-                if ema is None:
-                    # first dispatch of this label includes its XLA
-                    # compile (same fact the cold watchdog budget uses)
-                    gp.record_compile(label, elapsed, split)
-                    logger.info(
-                        "first dispatch of %s: %.2f s (%s)", label, elapsed,
-                        ", ".join(f"{k} {v:.2f}" for k, v in split.items()),
-                    )
-                    if (
-                        normalize_label(label) in self._prebaked_labels
-                        and elapsed >= self._recompile.min_s
-                    ):
-                        # a prebaked label should boot as a cache HIT;
-                        # a compile-sized first dispatch is cache drift
-                        gp.record_recompile(
-                            label,
-                            "prebake_miss",
-                            shape=f"lanes={lanes},tokens={tokens}",
-                        )
-                elif self._recompile.is_recompile(elapsed, ema):
-                    # a compile can only happen inside the jitted call;
-                    # a dispatch that was long elsewhere is the host's stall
-                    parts = launch_parts(
-                        elapsed, max(0.0, call.start_s - t0), call.seconds,
-                        launch,
-                    )
-                    if long_part(parts) == "enqueue":
-                        gp.record_recompile(
-                            label,
-                            "prebake_miss"
-                            if normalize_label(label) in self._prebaked_labels
-                            else "shape_miss",
-                            shape=f"lanes={lanes},tokens={tokens}",
-                        )
-                    else:
-                        gp.record_stall(label, parts)
+                if why is None:
+                    why = _WHY_BY_PHASE.get(step_phase(label), "other")
                 gp.record_launch(
                     launch.upload_arrays, launch.upload_bytes,
                     launch.fetch_bytes,
+                    dispatches=int(launching),
+                    chained=launches is not None and lands is not None,
+                    why=why,
                 )
-                gp.record_step(
-                    label,
-                    elapsed,
-                    lanes=lanes,
-                    capacity=capacity,
-                    prefill_tokens=tokens,
-                    t_start=t0,
-                )
-                if capacity > 0:
-                    gp.record_sampler(pool)
-                if self._recurrent_layers:
-                    gp.record_ssm(
-                        self._recurrent_layers,
-                        decode_steps=horizon if capacity > 0 else 0,
-                        lanes=lanes, resets=state_resets, scan_tokens=tokens,
-                    )
-                if dtrace.enabled():
-                    dtrace.counter("step_ms", elapsed * 1e3)
+                if launching:
                     if capacity > 0:
+                        gp.record_sampler(pool)
+                    if self._recurrent_layers:
+                        gp.record_ssm(
+                            self._recurrent_layers,
+                            decode_steps=horizon if capacity > 0 else 0,
+                            lanes=lanes, resets=state_resets,
+                            scan_tokens=tokens,
+                        )
+                    if capacity > 0 and dtrace.enabled():
                         dtrace.counter("occupancy", lanes / capacity)
+
+    def _watch_flight(self) -> None:
+        """The watchdog times the oldest dispatch on the device: the one in
+        flight, from when its time began, or nothing."""
+        fl = self._flight
+        if fl is not None and fl.prev is not None:
+            fl = fl.prev
+        self._dispatch_info = None if fl is None else (fl.label, fl.since)
 
     async def _watchdog_loop(self) -> None:
         poll = max(0.02, min(1.0, self.config.watchdog_min_s / 4))
@@ -1171,7 +1281,17 @@ class JaxEngine:
             self.slots[seq.slot] = None
             seq.slot = None
         if seq.block_ids:
-            self.allocator.free(seq.block_ids)
+            fl = self._flight
+            if fl is not None and fl.names(seq):
+                # a dispatch on the device's queue may still write this
+                # lane's rows (the host alone ended it: the device saw
+                # neither an EOS nor its limit): the blocks go back when
+                # that dispatch has landed. The lane's slot needs no such
+                # care: only an admission takes a slot, and none runs while
+                # a dispatch is in flight (`_chain_break`)
+                fl.held.extend(seq.block_ids)
+            else:
+                self.allocator.free(seq.block_ids)
             seq.block_ids = []
         if seq in self._admit_order:
             self._admit_order.remove(seq)
@@ -1503,16 +1623,22 @@ class JaxEngine:
             return {}
         return {"state_slots": [s.slot for s in seqs]}
 
+    def _room_for(self, seq: _Sequence) -> bool:
+        """A free lane, and the sequence's blocks above the watermark."""
+        return None in self.slots and (
+            self.allocator.free_count
+            >= seq.blocks_needed(self.config.block_size)
+            + self.config.watermark_blocks
+        )
+
     def _try_admit(self, seq: _Sequence) -> bool:
         """Allocate blocks + a slot and run prefill. False if no capacity."""
-        free_slots = [i for i, s in enumerate(self.slots) if s is None]
-        if not free_slots:
+        if not self._room_for(seq):
             return False
-        need = seq.blocks_needed(self.config.block_size)
-        if self.allocator.free_count < need + self.config.watermark_blocks:
-            return False
-        seq.block_ids = self.allocator.alloc(need)
-        seq.slot = free_slots[0]
+        seq.block_ids = self.allocator.alloc(
+            seq.blocks_needed(self.config.block_size)
+        )
+        seq.slot = self.slots.index(None)
         self.slots[seq.slot] = seq
         self._admit_order.append(seq)
         return True
@@ -1521,14 +1647,19 @@ class JaxEngine:
 
     async def _engine_loop(self) -> None:
         loop = asyncio.get_running_loop()
-        while not self._closed:
-            # one pass; every part of it is a process-level phase
-            # (telemetry/trace.py::phase), so its host time has a name in
-            # `/debug/goodput` and, while a profile window is open, on the
-            # device timeline
-            with dtrace.phase("loop.iter"):
-                if await self._loop_pass(loop):
-                    return
+        try:
+            while not self._closed:
+                # one pass; every part of it is a process-level phase
+                # (telemetry/trace.py::phase), so its host time has a name
+                # in `/debug/goodput` and, while a profile window is open,
+                # on the device timeline
+                with dtrace.phase("loop.iter"):
+                    if await self._loop_pass(loop):
+                        return
+        finally:
+            # closed, fenced or crashed with a dispatch in flight: nobody
+            # will read it (its lanes have been failed or will be)
+            self._drop_flight()
 
     async def _stats_and_yield(self, admitted: bool) -> None:
         with dtrace.phase("loop.stats"):
@@ -1541,6 +1672,12 @@ class JaxEngine:
         """One pass of the engine loop; True when the loop must end."""
         with dtrace.phase("loop.reap"):
             self._reap_cancelled()
+        if self._flight is not None:
+            why = self._chain_break()
+            if why is not None:
+                # whatever else this pass does comes behind the dispatch in
+                # flight: read and replay it first, then today's order
+                await self._land_flight(why)
         self._process_landed()
         await self._drain_offload()
         # latch the QoS-degraded chunk size and per-step budget ONCE
@@ -1610,6 +1747,77 @@ class JaxEngine:
         await self._decode_phase(loop, active)
         await self._stats_and_yield(admitted)
         return False
+
+    def _admission_due(self) -> bool:
+        """Whether `_admit_phase` would take somebody off the queue now: its
+        own test of the first sequence it would try, asked without taking
+        anything."""
+        now = time.monotonic()
+        for seq in self.waiting:
+            if not (seq.requeue_after and now < seq.requeue_after):
+                return self._room_for(seq)
+        return False
+
+    def _chain_break(self) -> Optional[str]:
+        """Why no `decode_multi` may be queued behind another one now (of
+        goodput.CHAIN_BREAKS), read from the engine's own state; None while
+        steady decode is all there is to do."""
+        if self._closed or not any(s is not None for s in self.slots):
+            return "other"
+        if self._admission_due():
+            return "arrival"
+        if self._prefilling or any(
+            s is not None and (s.pending_remote or s.prefilling)
+            for s in self.slots
+        ):
+            return "prefilling"
+        if (
+            self._landed
+            or self._remote_tasks
+            or self._device_lock.locked()
+            or (self._offload_queue is not None and len(self._offload_queue))
+        ):
+            # a landed remote prefill, block movement waiting for the device
+            return "other"
+        return None
+
+    def _may_fly(self, label: str, penalties) -> bool:
+        """Whether a plain `decode_multi` about to be launched may stay on
+        the device's queue unread, for the next pass to chain on: the runner
+        keeps the carry, the program is warm (a first dispatch is its
+        compile, and is timed whole), nobody drafts, no lane carries
+        penalties, and nothing else waits for the device."""
+        return (
+            penalties is None
+            and self.drafter is None
+            and label in self._dispatch_ema
+            and getattr(self.runner, "chains_horizons", False)
+            and self._chain_break() is None
+        )
+
+    async def _land_flight(self, why: str) -> None:
+        """End the chain: read and replay the dispatch in flight. `why`
+        (of goodput.CHAIN_BREAKS) is what the next unchained `decode_multi`
+        counts."""
+        fl = self._flight
+        self._chain_why = why
+        async with self._device_lock:
+            packed = await self._dispatch(
+                fl.label,
+                lambda: self.runner.fetch_horizon(fl.packed),
+                horizon=fl.H,
+                lands=fl,
+            )
+        self._replay_horizon(fl.lanes, fl.H, packed)
+
+    def _drop_flight(self) -> None:
+        fl, self._flight = self._flight, None
+        while fl is not None:
+            if fl.held:
+                self.allocator.free(fl.held)
+                fl.held = []
+            fl = fl.prev
+        self._dispatch_info = None
 
     def _reap_cancelled(self) -> None:
         for seq in list(self.waiting):
@@ -2793,8 +3001,17 @@ class JaxEngine:
         self._keys[i] = self._key_row(seq)
         return pos
 
-    def _horizon_for(self, active: list[_Sequence]) -> int:
-        """Pick this iteration's decode horizon. 1 = single-step path."""
+    def _horizon_for(
+        self, active: list[_Sequence], chained: bool = False
+    ) -> int:
+        """Pick this iteration's decode horizon. 1 = single-step path.
+        `chained`: for a dispatch to be queued behind the one in flight,
+        whose tokens the host has not replayed: the lanes are up to a
+        horizon further than the host's arrays say, so everything here
+        reaches two horizons from them; any answer but that dispatch's
+        horizon means it must be landed first (`_decode_phase`).
+        `self._horizon_why` says why the answer was 1."""
+        self._horizon_why = "other"
         H = self.config.decode_horizon
         if H <= 1 or not hasattr(self.runner, "decode_multi"):
             return 1
@@ -2814,35 +3031,53 @@ class JaxEngine:
         # tokens left on every lane) still runs the ONE horizon program —
         # lanes freeze on device at their own limit — because a program
         # per tail length (decode_multi@H3, @H2) is a cold compile of the
-        # largest program family in the middle of serving.
-        if max(self._lane_remaining(s) for s in active) <= 1:
+        # largest program family in the middle of serving. Chained: every
+        # lane ends inside the dispatch in flight, and one behind it would
+        # run frozen lanes only.
+        remaining = [self._lane_remaining(s) for s in active]
+        if max(remaining) <= (H if chained else 1):
             return 1
         # preallocate KV blocks to cover every horizon write — capped at
         # each lane's OWN remaining budget (a lane one token from its limit
         # must not grow past max_blocks_per_seq). On pressure, fall back to
         # single-step (its just-in-time alloc can preempt).
         bs = self.config.block_size
-        for seq in active:
-            lane_steps = min(H, self._lane_remaining(seq))
-            last_write = (seq.pos - 1) + (lane_steps - 1)
+        reach = 2 * H if chained else H
+        for seq, left in zip(active, remaining):
+            last_write = (seq.pos - 1) + (min(reach, left) - 1)
             need = last_write // bs + 1 - len(seq.block_ids)
             if need > 0:
                 try:
                     seq.block_ids.extend(self.allocator.alloc(need))
                 except OutOfBlocks:
+                    self._horizon_why = "blocks"
                     return 1
         return H
 
     async def _decode_phase(self, loop, active: list[_Sequence]) -> None:
         # brownout >= spec_off pauses drafting: the verify premium and
         # drafter host time go back to real tokens while the SLO burns
+        fl = self._flight
         with dtrace.phase("loop.pack"):
             # drafting and the horizon's block preallocation are host work
             # of this dispatch, like the lane arrays built below
             drafts = None
             if self.drafter is not None and not self._spec_paused:
                 drafts = self._collect_drafts(active)
-            H = self._horizon_for(active) if drafts is None else 1
+            H = (
+                self._horizon_for(active, chained=fl is not None)
+                if drafts is None else 1
+            )
+        if fl is not None and H != fl.H:
+            # nothing can be queued behind the dispatch in flight (the two
+            # horizons' blocks are not there, or every lane ends in it):
+            # land it, then today's order on what its replay left
+            await self._land_flight(self._horizon_why)
+            active = [s for s in active if s.slot is not None]
+            if not active:
+                return
+            with dtrace.phase("loop.pack"):
+                H = self._horizon_for(active)
         if drafts is not None:
             await self._spec_decode_phase(loop, active, drafts)
             return
@@ -2931,6 +3166,7 @@ class JaxEngine:
                 lanes=len(active),
                 capacity=self.config.max_batch,
                 ctx_tokens=self._ctx_tokens(active),
+                why="penalties" if any_pen else self._horizon_why,
             )
         with self._emitting():
             toks, lps, tids, tlps = sample
@@ -3202,25 +3438,66 @@ class JaxEngine:
                     pres[i] = seq.pres_pen
                     rep[i] = seq.rep_pen
                 penalties = (hist, hist_len, prompt_len, freq, pres, rep)
+        label = f"decode_multi@H{H}B{self.config.max_batch}"
+        # steady decode launches ahead: this dispatch goes on the device's
+        # queue and stays there (`new`), and what the device does while the
+        # host replays and serves the one before it (`fl`, not read yet) is
+        # this. Behind one in flight its lanes go on from that one's carry
+        # on the device: the arrays above are the host's state as of THAT
+        # dispatch's start, which `decode_multi` is told with `chain`.
+        # Otherwise one dispatch, whole: launched, read, replayed
+        fl = self._flight
+        new = None
+        if fl is not None or self._may_fly(label, penalties):
+            new = _Flight(label, list(active), H)
+            extra = {"chain": act if fl is not None else None}
+        else:
+            extra = {"penalties": penalties}
+
+        def call():
+            packed = self.runner.decode_multi(
+                H,
+                self._tokens, self._positions, self._block_tables,
+                self._temps, self._top_ps, self._top_ks,
+                self._keys, act, limit_rem, min_rem, eos_ids, **extra,
+            )
+            if new is None:
+                return self.runner.fetch_horizon(packed)
+            new.packed = packed
+            if fl is not None:
+                return self.runner.fetch_horizon(fl.packed)
+
         async with self._device_lock:
             packed = await self._dispatch(
                 # the label names the program that ran: a ledger that
                 # shows only "decode" served at H=1
-                f"decode_multi@H{H}B{self.config.max_batch}",
-                lambda: self.runner.fetch_horizon(
-                    self.runner.decode_multi(
-                        H,
-                        self._tokens, self._positions, self._block_tables,
-                        self._temps, self._top_ps, self._top_ks,
-                        self._keys, act, limit_rem, min_rem, eos_ids,
-                        penalties=penalties,
-                    )
-                ),
+                label,
+                call,
                 lanes=len(active),
                 capacity=self.config.max_batch,
-                ctx_tokens=self._ctx_tokens(active),
+                # a chained lane is up to a horizon further than the host's
+                # arrays say
+                ctx_tokens=self._ctx_tokens(active)
+                + (H * len(active) if fl is not None else 0),
                 horizon=H,
+                why="penalties" if penalties is not None else self._chain_why,
+                launches=new,
+                lands=fl,
             )
+        self._chain_why = "other"
+        if new is None:
+            self._replay_horizon(active, H, packed)
+        elif fl is not None:
+            self._replay_horizon(fl.lanes, H, packed)
+
+    def _replay_horizon(
+        self, lanes: list[_Sequence], H: int, packed: np.ndarray
+    ) -> None:
+        """Replay one fetched horizon through `_append_token`, step by
+        step, over the lanes it was launched with. A lane that has finished
+        since (in this horizon, or, under the launch ahead, in the one
+        before while this one was on the device already) has no slot and is
+        skipped: whatever the device still ran of it is dropped."""
         with self._emitting():
             counted = self.runner.step_stats(packed)
             if counted is not None:
@@ -3228,7 +3505,7 @@ class JaxEngine:
             K = (packed.shape[-1] - 2) // 2
             for h in range(H):
                 step = packed[h]
-                for seq in active:
+                for seq in lanes:
                     if seq.slot is None:
                         continue  # finished earlier in this horizon
                     i = seq.slot

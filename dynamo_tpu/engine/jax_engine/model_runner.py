@@ -176,6 +176,10 @@ class Launch:
 
 
 class ModelRunner:
+    # `decode_multi` keeps its lanes' carry on the device and takes `chain`:
+    # the engine may enqueue a horizon behind one it has not read yet
+    chains_horizons = True
+
     def __init__(
         self,
         config: Any,  # a family's config (models.forward_for finds its forward)
@@ -423,12 +427,17 @@ class ModelRunner:
         )
         # horizon decode: H chained steps per dispatch (one compile per
         # distinct H; the engine uses a single configured H). Output
-        # sharding: packed samples replicated, caches keep theirs.
+        # sharding: packed samples and the lanes' carry replicated, caches
+        # keep theirs.
         multi_out = (
-            (self._repl, kv_shard_tree, kv_shard_tree)
+            ((self._repl, (self._repl,) * 4), kv_shard_tree, kv_shard_tree)
             if kv_sharding is not None
             else None
         )
+        # what the last `decode_multi` left its lanes, on the device (see
+        # `_decode_multi_impl`): the next call takes it as it is, and the
+        # `chain` mask says which lanes start from it. Zeros until then
+        self._horizon_carry: Optional[tuple] = None
         self._decode_multi_fn = self._step_jit(
             self._decode_multi_impl, self.config,
             self.mesh, self._attn_head_axis, self.block_size,
@@ -707,6 +716,12 @@ class ModelRunner:
         limit_remaining,  # [B] i32 — tokens the lane may still emit
         min_remaining,    # [B] i32 — steps during which EOS stays masked
         eos_ids,          # [B, MAX_EOS_IDS] i32, -1 pads
+        chain=None,       # [B] bool — lane continues from `carry`
+        carry=None,       # the previous call's carry, on the device:
+                          # (tokens [B] i32, positions [B] i32, done [B]
+                          # bool, advanced [B] i32); the runner always
+                          # passes one. None: the horizon alone, and
+                          # `packed` is all that comes back beside the caches
         pen=None,         # optional (hist [B, L] i32, hist_len [B] i32,
                           # prompt_len [B] i32, freq [B], pres [B], rep [B])
     ):
@@ -730,9 +745,29 @@ class ModelRunner:
         token-for-token. Lanes without penalties run freq=0/pres=0/rep=1
         — bit-exact pass-through — so ONE dispatch serves mixed batches
         instead of dragging everyone to H=1 (VERDICT r4 weak #2).
+
+        The carry: beside `packed` the program hands on what its last step
+        left a lane (the token to feed next, its position, `done`) and how
+        many tokens the lane emitted here (`advanced`, 0..H). A lane whose
+        `chain` is set starts from the previous call's carry, not from the
+        host's arrays, which then describe the lane as of THAT call's start:
+        its limit, its `min_remaining` and its key's counter move on by what
+        it advanced there, and a lane whose limit is used up starts frozen.
+        So the engine can enqueue a horizon before it has read the one
+        before. Every other lane starts from the host's arrays as ever.
         """
         B = tokens.shape[0]
         rows = jnp.arange(B)
+        done = None
+        if carry is not None:
+            c_tokens, c_positions, c_done, c_advanced = carry
+            advanced = jnp.where(chain, c_advanced, 0)
+            tokens = jnp.where(chain, c_tokens, tokens)
+            positions = jnp.where(chain, c_positions, positions)
+            limit_remaining = limit_remaining - advanced
+            min_remaining = min_remaining - advanced
+            keys = keys.at[:, 1].add(advanced.astype(jnp.uint32))
+            done = jnp.where(chain, c_done | (limit_remaining <= 0), ~active)
         eos_valid = eos_ids >= 0
         model = forward_for(cfg)
         step_stats = getattr(model, "STEP_STATS", ())
@@ -807,12 +842,18 @@ class ModelRunner:
                 carry = carry + (out_counts, seen)
             return carry, packed
 
-        init = (tokens, positions, k_cache, v_cache, ~active)
+        init = (
+            tokens, positions, k_cache, v_cache,
+            ~active if done is None else done,
+        )
         if pen is not None:
             init = init + (out_counts, seen)
-        carry, packed = unrolled_steps(step, init, H)
-        k_cache, v_cache = carry[2], carry[3]
-        return packed, k_cache, v_cache  # packed [H, B, 2+2K]
+        last, packed = unrolled_steps(step, init, H)
+        k_cache, v_cache = last[2], last[3]
+        if carry is None:
+            return packed, k_cache, v_cache  # packed [H, B, 2+2K]
+        emitted = jnp.sum(packed[:, :B, 0] >= 0, axis=0).astype(jnp.int32)
+        return (packed, (last[0], last[1], last[4], emitted)), k_cache, v_cache
 
     @staticmethod
     def _spec_verify_impl(
@@ -1866,16 +1907,51 @@ class ModelRunner:
         # i32, freq [B] f32, pres [B] f32, rep [B] f32): uploaded once per
         # horizon, scattered into on-device count tables (a second trace
         # of the same program; plain batches never pay the [B, L] input)
+        chain: Optional[np.ndarray] = None,  # [B] bool
+        # lanes that go on from where the previous `decode_multi` call left
+        # them on the device, whose result the caller need not have read:
+        # for them every host array describes the lane as of THAT call's
+        # start (`_decode_multi_impl`). None: no lane does.
     ) -> jax.Array:
         """H chained decode steps; returns the packed [H, B, 2+2*num_top]
         f32 device array (token, logprob, top_ids, top_lps per step) — ONE
-        host fetch per horizon. See _decode_multi_impl for freeze rules."""
-        return self._launch(
+        host fetch per horizon. See _decode_multi_impl for freeze rules.
+        The lanes' carry stays on the device for the next call."""
+        B = tokens.shape[0]
+        if chain is None:
+            chain = np.zeros(B, bool)
+        carry = self._horizon_carry
+        if carry is None or carry[0].shape[0] != B:
+            if chain.any():
+                raise ValueError(
+                    "decode_multi: no earlier call of this batch size to "
+                    "chain on"
+                )
+            carry = self._zero_carry(B)
+        packed, self._horizon_carry = self._launch(
             self._decode_multi_fn, tokens, positions, block_tables, keys,
             temps, top_ps, top_ks, active, limit_remaining, min_remaining,
-            eos_ids, static=(H,),
+            eos_ids, chain, carry, static=(H,),
             **({} if penalties is None else {"pen": tuple(penalties)}),
         )
+        return packed
+
+    def _zero_carry(self, B: int) -> tuple:
+        """A carry nobody chains on, of the shapes and the placement the
+        program hands back, so that the first call and every later one are
+        one compiled program."""
+        zeros = (
+            np.zeros(B, np.int32), np.zeros(B, np.int32), np.ones(B, bool),
+            np.zeros(B, np.int32),
+        )
+        if self._repl is not None:
+            return tuple(
+                jax.make_array_from_process_local_data(
+                    self._repl, z, global_shape=z.shape
+                )
+                for z in zeros
+            )
+        return tuple(jnp.asarray(z) for z in zeros)
 
     def spec_verify(
         self,

@@ -212,6 +212,11 @@ class SpmdModelRunner:
         self._runner = runner
         self._channel = channel
 
+    # followers replay the leader's calls one by one, each fetched before
+    # the next: the engine keeps its serial order here (`decode_multi` below
+    # passes no `chain`)
+    chains_horizons = False
+
     def __getattr__(self, name):  # delegate everything not intercepted
         return getattr(self._runner, name)
 

@@ -119,8 +119,23 @@ STREAM_COUNTERS = ("items", "tokens")
 # (`ModelRunner._to_dev`: `upload_arrays`, one a dispatch, the packed buffer
 # of its host inputs, and `upload_bytes`) and the bytes of
 # the results read back (`fetch_bytes`), counted by the runner where it
-# commits and reads, entered here once a dispatch
-LAUNCH_COUNTERS = ("dispatches", "upload_arrays", "upload_bytes", "fetch_bytes")
+# commits and reads, entered here once a dispatch; `chained`, those of the
+# dispatches that were enqueued behind a `decode_multi` the host had not read
+# yet and took their lanes from its carry on the device (the engine's launch
+# ahead: `JaxEngine._decode_multi_phase`)
+LAUNCH_COUNTERS = (
+    "dispatches", "upload_arrays", "upload_bytes", "fetch_bytes", "chained",
+)
+
+# why a dispatch was not chained, one count a dispatch, so that with
+# `launch.chained` they add up to `launch.dispatches`: `arrival` (a prefill
+# program, or the `decode_multi` that follows somebody's admission),
+# `prefilling` (a mixed step or a chunk, or the `decode_multi` behind one),
+# `penalties` (the penalties programs), `blocks` (the two horizons'
+# preallocation failed, or a single step that ran for want of blocks),
+# `other` (a label's first dispatch, a start after idleness, a verify pass,
+# the last token of every lane, block movement waiting for the device)
+CHAIN_BREAKS = ("arrival", "prefilling", "penalties", "blocks", "other")
 
 # a dispatch in the order it happens: the hop from the event loop to the
 # executor thread, the three phases of the runner's call (`runner.upload`,
@@ -233,6 +248,7 @@ class GoodputStats:
         "ssm",
         "stream",
         "launch",
+        "chain_breaks",
     )
 
     def __init__(self) -> None:
@@ -282,6 +298,8 @@ class GoodputStats:
         self.stream: dict[str, int] = {}
         # LAUNCH_COUNTERS
         self.launch: dict[str, int] = {}
+        # CHAIN_BREAKS
+        self.chain_breaks: dict[str, int] = {}
 
     # ------------------------------------------------------------- query
 
@@ -353,6 +371,8 @@ class GoodputStats:
             self.stream[k] = self.stream.get(k, 0) + v
         for k, v in other.launch.items():
             self.launch[k] = self.launch.get(k, 0) + v
+        for k, v in other.chain_breaks.items():
+            self.chain_breaks[k] = self.chain_breaks.get(k, 0) + v
 
     def _merge_first_dispatch(self, label: str, split: dict) -> None:
         """Field by field the larger, as `compile_s_by_label` takes the
@@ -398,6 +418,7 @@ class GoodputStats:
             "ssm": dict(self.ssm),
             "str": dict(self.stream),
             "lch": dict(self.launch),
+            "cb": dict(self.chain_breaks),
         }
 
     @classmethod
@@ -442,6 +463,9 @@ class GoodputStats:
         for k, v in (d.get("lch") or {}).items():
             if k in LAUNCH_COUNTERS:
                 out.launch[k] = int(v)
+        for k, v in (d.get("cb") or {}).items():
+            if k in CHAIN_BREAKS:
+                out.chain_breaks[k] = int(v)
         return out
 
     # ------------------------------------------------------------- debug
@@ -486,6 +510,9 @@ class GoodputStats:
             "ssm": {k: self.ssm.get(k, 0) for k in SSM_COUNTERS},
             "stream": {k: self.stream.get(k, 0) for k in STREAM_COUNTERS},
             "launch": {k: self.launch.get(k, 0) for k in LAUNCH_COUNTERS},
+            "chain_breaks": {
+                k: self.chain_breaks.get(k, 0) for k in CHAIN_BREAKS
+            },
         }
 
 
@@ -611,15 +638,24 @@ class GoodputLedger(GoodputStats):
         self.stream["tokens"] = self.stream.get("tokens", 0) + tokens
 
     def record_launch(
-        self, upload_arrays: int, upload_bytes: int, fetch_bytes: int
+        self, upload_arrays: int, upload_bytes: int, fetch_bytes: int,
+        *, dispatches: int = 1, chained: bool = False, why: str = "other",
     ) -> None:
-        """One dispatch's launch, as its runner counted it."""
+        """What one hop to the runner counted. It launched one dispatch,
+        `chained` or else not for the reason `why` (of CHAIN_BREAKS); or
+        none (`dispatches` 0): it only read the result of one launched
+        before."""
         if not self.enabled:
             return
         for k, v in zip(LAUNCH_COUNTERS, (
-            1, upload_arrays, upload_bytes, fetch_bytes,
+            dispatches, upload_arrays, upload_bytes, fetch_bytes,
+            dispatches if chained else 0,
         )):
             self.launch[k] = self.launch.get(k, 0) + v
+        if dispatches and not chained:
+            if why not in CHAIN_BREAKS:
+                why = "other"
+            self.chain_breaks[why] = self.chain_breaks.get(why, 0) + dispatches
 
     def record_decode_tokens(self, n: int = 1) -> None:
         if self.enabled:

@@ -304,3 +304,373 @@ async def test_horizon_penalty_only_batch_diverges_from_unpenalized():
     # frequency penalty forbids runaway repetition: the penalized stream
     # must not equal the unpenalized one for a prompt that induces repeats
     assert toks_pen != toks_plain
+
+
+# ------------------------------------------- the launch ahead (steady decode)
+#
+# In steady decode the engine queues `decode_multi` N+1 behind N before it
+# reads N's tokens, and N+1's lanes go on from N's carry on the device
+# (engine.py `_decode_multi_phase`, model_runner.py `_decode_multi_impl`).
+# A request's stream must be token for token what the serial loop gives:
+# the serial loop here is the same engine on a runner that says it keeps no
+# carry (`chains_horizons` False: what `SpmdModelRunner` says).
+
+import functools
+import threading
+
+import jax.numpy as jnp
+
+FAMILIES = ("llama", "mla_moe", "hybrid_ssm", "conv_moe")
+SAMPLING = ("greedy", "seeded")
+
+
+@functools.lru_cache(maxsize=None)
+def family_runner(family, num_blocks=48, max_batch=4, max_len=96):
+    """One runner a family for the whole file: its programs are traced
+    once, and engines come and go on it one after another (an engine with a
+    smaller pool hands out the first of the runner's blocks)."""
+    if family == "llama":
+        cfg = L.LlamaConfig.tiny(vocab_size=300)
+        params = L.init_params(cfg, jax.random.PRNGKey(0))
+    else:
+        import dataclasses
+        import importlib
+
+        hf = importlib.import_module(f"tests.test_{family}").HF
+        M = importlib.import_module(f"dynamo_tpu.models.{family}")
+        config = {
+            "mla_moe": "MlaMoeConfig", "hybrid_ssm": "HybridSsmConfig",
+            "conv_moe": "ConvMoeConfig",
+        }[family]
+        cfg = dataclasses.replace(
+            getattr(M, config).from_hf_dict(hf), attn_impl="xla"
+        )
+        params = M.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
+    return ModelRunner(
+        cfg, params, num_blocks=num_blocks, block_size=4,
+        max_batch=max_batch, max_model_len=max_len, kv_dtype=jnp.float32,
+        attn_impl="xla",
+    )
+
+
+def family_engine(family, chained, num_blocks=48, **config):
+    runner = family_runner(family)
+    runner.chains_horizons = chained  # False: the serial loop
+    return JaxEngine(
+        runner,
+        JaxEngineConfig(
+            max_batch=runner.max_batch, block_size=4, num_blocks=num_blocks,
+            max_model_len=runner.max_model_len, watermark_blocks=2,
+            decode_horizon=4, **config,
+        ),
+    )
+
+
+def request(prompt, max_tokens, sampling, seed=7, eos=None):
+    return PreprocessedRequest(
+        token_ids=list(prompt),
+        sampling=(
+            SamplingOptions(greedy=True) if sampling == "greedy"
+            else SamplingOptions(temperature=0.8, seed=seed)
+        ),
+        stop=StopConditions(max_tokens=max_tokens),
+        **({} if eos is None else {"eos_token_ids": [eos]}),
+    )
+
+
+class DeviceQueue:
+    """What the engine's calls to its runner say about the device's queue:
+    the `decode_multi` dispatches launched and not read yet, with the blocks
+    their live lanes name, and every block handed out while one names it."""
+
+    def __init__(self, engine):
+        self.engine, self.unread, self.most = engine, {}, 0
+        self.reused, self.prefill_behind_unread = [], 0
+        runner, alloc = engine.runner, engine.allocator
+        launch, fetch, take = runner.decode_multi, runner.fetch_horizon, alloc.alloc
+        prefill = runner.prefill_packed_arrays
+
+        def decode_multi(H, tokens, positions, tables, *rest, **kw):
+            active = rest[4]
+            packed = launch(H, tokens, positions, tables, *rest, **kw)
+            self.unread[id(packed)] = set(tables[active].ravel().tolist()) - {0}
+            self.most = max(self.most, len(self.unread))
+            return packed
+
+        def fetch_horizon(packed):
+            out = fetch(packed)
+            self.unread.pop(id(packed))
+            return out
+
+        def alloc_(n):
+            ids = take(n)
+            named = set().union(*self.unread.values()) if self.unread else set()
+            self.reused += [b for b in ids if b in named]
+            return ids
+
+        def prefill_(*a, **kw):
+            self.prefill_behind_unread += bool(self.unread)
+            return prefill(*a, **kw)
+
+        self.patched = dict(
+            decode_multi=decode_multi, fetch_horizon=fetch_horizon,
+            prefill_packed_arrays=prefill_,
+        )
+        for name, fn in self.patched.items():
+            setattr(runner, name, fn)
+        alloc.alloc = alloc_
+
+    def restore(self):
+        for name in self.patched:
+            delattr(self.engine.runner, name)
+
+
+async def run_both(family, script, **config):
+    """`script(engine)` on the serial loop and on the chained engine: the
+    two results, and the chained engine's ledger, queue and leftovers."""
+    serial = family_engine(family, False, **config)
+    want = await script(serial)
+    await serial.close()
+    assert serial.stats.goodput.summary()["launch"]["chained"] == 0
+    engine = family_engine(family, True, **config)
+    queue = DeviceQueue(engine)
+    try:
+        got = await script(engine)
+    finally:
+        queue.restore()
+    await engine.close()
+    ledger = engine.stats.goodput.summary()
+    assert queue.most == 2 and not queue.unread  # one running, one behind it
+    assert not queue.reused and not queue.prefill_behind_unread
+    assert engine._flight is None
+    assert engine.allocator.free_count == engine.config.num_blocks - 1
+    assert engine.runner._decode_multi_fn._cache_size() == 1  # one program
+    launch, breaks = ledger["launch"], ledger["chain_breaks"]
+    assert launch["chained"] + sum(breaks.values()) == launch["dispatches"]
+    assert launch["upload_arrays"] == launch["dispatches"]
+    return want, got, ledger, engine, serial
+
+
+PROMPTS = ([5, 9, 17, 23], [2, 40, 41, 7, 7, 3], [60, 3, 3, 3, 8, 1, 12, 90, 4])
+
+
+@pytest.mark.parametrize("sampling", SAMPLING)
+@pytest.mark.parametrize("family", FAMILIES)
+async def test_chained_streams_are_the_serial_loops(family, sampling):
+    """(a) lanes that end by `max_tokens` in the middle of a horizon while
+    their neighbours go on, and (b) a lane that ends by an EOS the device
+    sees: the chained engine's streams are the serial loop's."""
+
+    async def by_length(engine):
+        return await asyncio.gather(*[
+            collect(engine, request(p, n, sampling, seed=11 + i))
+            for i, (p, n) in enumerate(zip(PROMPTS, (6, 13, 30)))
+        ])
+
+    want, got, ledger, *_ = await run_both(family, by_length)
+    assert got == want
+    assert [len(t) for t, _ in got] == [6, 13, 30]
+    assert ledger["launch"]["chained"] >= 4
+    # (b): the longest stream's 18th token becomes its EOS (or wherever that
+    # id first shows): frozen on the device in the middle of a chain
+    long = want[2][0]
+    eos = long[17]
+    cut = long.index(eos)
+
+    async def by_eos(engine):
+        return await asyncio.gather(
+            collect(engine, request(PROMPTS[0], 25, sampling, seed=11)),
+            collect(engine, request(PROMPTS[2], 30, sampling, seed=13, eos=eos)),
+        )
+
+    want, got, ledger, *_ = await run_both(family, by_eos)
+    assert got == want
+    assert got[1] == (long[:cut], FinishReason.EOS) and len(got[0][0]) == 25
+    assert ledger["launch"]["chained"] >= 3
+
+
+@pytest.mark.parametrize("sampling", SAMPLING)
+@pytest.mark.parametrize("family", FAMILIES)
+async def test_a_lane_the_host_alone_stops_runs_out_unseen(family, sampling):
+    """(c) a consumer that stops its context after three items: the device
+    cannot know, the lane runs at most one horizon more in its own blocks
+    and slot, nothing of that reaches the stream, the blocks go back only
+    when no dispatch on the device's queue names them (`DeviceQueue.reused`,
+    in `run_both`, with a pool tight enough that the neighbours take them at
+    once), and the neighbours' streams are the serial loop's."""
+    held = []
+
+    async def script(engine):
+        stopped, ctx = [], Context()
+
+        async def stop_after_three():
+            items = 0
+            async for out in engine.generate(
+                request(PROMPTS[1], 40, sampling, seed=5), ctx
+            ):
+                stopped.extend(out.token_ids)
+                items += bool(out.token_ids)
+                if items == 3 and not ctx.is_stopped():
+                    ctx.stop_generating()
+                    fl = engine._flight
+                    held.append(fl is not None and any(
+                        s.ctx is ctx for s in fl.lanes
+                    ))
+            return stopped
+
+        return await asyncio.gather(
+            stop_after_three(),
+            collect(engine, request(PROMPTS[0], 34, sampling, seed=3)),
+            collect(engine, request(PROMPTS[2], 29, sampling, seed=4)),
+        )
+
+    want, got, ledger, *_ = await run_both(family, script, num_blocks=30)
+    assert got[1:] == want[1:] and len(got[1][0]) == 34
+    # three items and no more: a prefill's one token, two horizons' four
+    assert got[0] == want[0] and len(got[0]) == 9
+    assert held == [False, True]  # the chained engine had it in flight
+    assert ledger["launch"]["chained"] >= 4
+
+
+@pytest.mark.parametrize("sampling", SAMPLING)
+@pytest.mark.parametrize("family", FAMILIES)
+async def test_an_arrival_breaks_the_chain_and_it_resumes(family, sampling):
+    """(d) a request that arrives in the middle of a chain: the dispatch in
+    flight is read and replayed, then the prefill runs (never behind an
+    unread dispatch: `DeviceQueue.prefill_behind_unread`), then a new chain
+    starts; all three streams are the serial loop's."""
+
+    async def script(engine):
+        late = asyncio.Event()
+
+        async def first():
+            toks = []
+            async for out in engine.generate(
+                request(PROMPTS[0], 37, sampling, seed=21), Context()
+            ):
+                toks.extend(out.token_ids)
+                if len(toks) >= 13:
+                    late.set()
+            return toks
+
+        async def third():
+            await late.wait()
+            return await collect(engine, request(PROMPTS[2], 18, sampling, seed=23))
+
+        return await asyncio.gather(
+            first(), collect(engine, request(PROMPTS[1], 31, sampling, seed=22)),
+            third(),
+        )
+
+    want, got, ledger, *_ = await run_both(family, script)
+    assert got == want and len(got[0]) == 37 and len(got[2][0]) == 18
+    assert ledger["chain_breaks"]["arrival"] >= 3  # two prefills, a restart
+    assert ledger["launch"]["chained"] >= 5
+    assert ledger["steps_by_label"]["prefill_packed"]["count"] == 2
+
+
+@pytest.mark.parametrize("sampling", SAMPLING)
+@pytest.mark.parametrize("family", FAMILIES)
+async def test_two_horizons_blocks_failing_falls_back(family, sampling):
+    """(e) a pool in which one horizon's blocks are there and two horizons'
+    are not: the chain ends (`chain_breaks.blocks`), the serial order takes
+    over and nobody is preempted who would not have been: as in the serial
+    loop, nobody at all."""
+
+    lengths = (10, 36, 40)
+
+    async def script(engine):
+        return await asyncio.gather(*[
+            collect(engine, request(p, n, sampling, seed=31 + i))
+            for i, (p, n) in enumerate(zip(PROMPTS, lengths))
+        ])
+
+    want, got, ledger, engine, serial = await run_both(
+        family, script, num_blocks=23
+    )
+    assert got == want and [len(t) for t, _ in got] == list(lengths)
+    assert ledger["chain_breaks"]["blocks"] >= 1
+    assert ledger["launch"]["chained"] >= 3
+    # the serial loop itself falls to single steps for want of blocks here
+    assert serial.stats.goodput.summary()["chain_breaks"]["blocks"] >= 1
+    assert not engine.stats.preemptions_by_class
+    assert not serial.stats.preemptions_by_class
+
+
+async def test_watchdog_times_the_older_of_two_dispatches_in_flight():
+    """A healthy chain of many dispatches, each a fifth of the watchdog's
+    budget, lasts several budgets and trips nothing: a dispatch's time
+    begins when the one before it was read. A fetch that never returns
+    trips it on the older of the two dispatches on the device's queue."""
+    engine = family_engine(
+        "llama", True, watchdog_min_s=0.5, watchdog_mult=3.0,
+    )
+    runner = engine.runner
+    fetch, stuck, release = runner.fetch_horizon, [], threading.Event()
+    tripped = []
+
+    def slow_fetch(packed):
+        if stuck:
+            release.wait(30)
+        else:
+            import time
+
+            time.sleep(0.1)
+        return fetch(packed)
+
+    runner.fetch_horizon = slow_fetch
+    engine.on_watchdog_trip = lambda: tripped.append(
+        (engine._flight, engine._flight.prev, engine._dispatch_info)
+    )
+    try:
+        toks, reason = await collect(engine, request(PROMPTS[0], 81, "greedy"))
+        assert len(toks) == 81 and reason is FinishReason.LENGTH
+        ledger = engine.stats.goodput.summary()
+        assert ledger["launch"]["chained"] >= 15  # two seconds of chain
+        assert engine.stats.watchdog_trips == 0 and not tripped
+        assert engine._dispatch_info is None
+        stuck.append(True)
+        final = None
+        async for out in engine.generate(request(PROMPTS[1], 60, "greedy"), Context()):
+            final = out
+        assert final.error["code"] == "watchdog_stuck"
+        assert engine.stats.watchdog_trips == 1
+        (newer, older, info), = tripped
+        assert older is not None and newer.prev is older
+        assert info == (older.label, older.since) and older.since < newer.since
+    finally:
+        release.set()
+        del runner.fetch_horizon
+        await engine.close()
+
+
+async def test_chained_and_chain_breaks_add_up_to_the_dispatches():
+    """Every dispatch is chained or counted with why it was not: a prefill
+    (`arrival`), the penalties program, and the `decode_multi` that follows
+    each; `launch.chained` and `chain_breaks` add up to `launch.dispatches`,
+    on the wire and through a merge too."""
+    from dynamo_tpu.telemetry.goodput import CHAIN_BREAKS, GoodputStats
+
+    engine = family_engine("llama", True)
+    await collect(engine, request(PROMPTS[0], 22, "greedy"))
+    pen = PreprocessedRequest(
+        token_ids=[8, 2, 44],
+        sampling=SamplingOptions(greedy=True, repetition_penalty=1.3),
+        stop=StopConditions(max_tokens=10),
+    )
+    await asyncio.gather(
+        collect(engine, pen), collect(engine, request(PROMPTS[1], 19, "seeded"))
+    )
+    await engine.close()
+    gp = engine.stats.goodput
+    ledger = gp.summary()
+    launch, breaks = ledger["launch"], ledger["chain_breaks"]
+    assert tuple(breaks) == CHAIN_BREAKS
+    assert launch["chained"] >= 3 and breaks["arrival"] >= 2
+    assert breaks["penalties"] >= 2  # a penalties lane in every batch
+    assert launch["chained"] + sum(breaks.values()) == launch["dispatches"]
+    assert launch["dispatches"] == ledger["steps_total"]
+    back = GoodputStats.from_dict(gp.to_dict())
+    assert back.chain_breaks == gp.chain_breaks and back.launch == gp.launch
+    back.merge(gp)
+    assert back.chain_breaks["arrival"] == 2 * breaks["arrival"]

@@ -672,10 +672,15 @@ def test_launch_parts_name_where_a_dispatch_was_long():
 
 def test_launch_slot_rides_the_wire_and_the_exporter():
     gp = GoodputLedger(enabled=True)
-    gp.record_launch(11, 9000, 4096)
-    gp.record_launch(21, 30000, 512)
-    want = {"dispatches": 2, "upload_arrays": 32, "upload_bytes": 39000, "fetch_bytes": 4608}
+    gp.record_launch(11, 9000, 4096, why="arrival")
+    gp.record_launch(21, 30000, 512, chained=True)
+    gp.record_launch(0, 0, 256, dispatches=0)  # a hop that only read a result
+    want = {"dispatches": 2, "upload_arrays": 32, "upload_bytes": 39000, "fetch_bytes": 4864,
+            "chained": 1}
     assert gp.launch == want and gp.summary()["launch"] == want
+    assert gp.chain_breaks == {"arrival": 1}
+    assert gp.summary()["chain_breaks"] == {
+        "arrival": 1, "prefilling": 0, "penalties": 0, "blocks": 0, "other": 0}
     back = GoodputStats.from_dict(json.loads(json.dumps(gp.to_dict())))
     assert back.launch == want
     back.merge(gp)

@@ -124,6 +124,7 @@ INTENTIONALLY_SHARED = {
     "dyn_llm_launch_upload_arrays",
     "dyn_llm_launch_upload_bytes",
     "dyn_llm_launch_fetch_bytes",
+    "dyn_llm_launch_chained",
     # decision provenance plane (ISSUE 20): every control-plane process
     # (frontend, metrics component, standalone router) exports its OWN
     # ledger's decision counts — decisions are made where they are

@@ -99,8 +99,8 @@ def _spy_programs(engine):
         mixed_calls.append(len(chunks))
         return orig_mixed(chunks, *a, **k)
 
-    def pick_spy(active):
-        H = orig_pick(active)
+    def pick_spy(active, **chained):
+        H = orig_pick(active, **chained)
         horizons.append(
             (H, max(engine._lane_remaining(s) for s in active))
         )

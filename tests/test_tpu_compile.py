@@ -346,8 +346,12 @@ def _lower_step(one_chip, impl, params, caches, host, static=(), packed=False):
 
 
 def _lower_decode_multi(
-    one_chip, num_blocks: int = 1024, layers: int = 2, packed: bool = False
+    one_chip, num_blocks: int = 1024, layers: int = 2, packed: bool = False,
+    carried: bool = False,
 ):
+    """`carried`: as the runner calls it since PR 45, with the `chain` mask
+    and the previous call's carry behind the host's arrays, and the new
+    carry handed back beside `packed`."""
     from dynamo_tpu.engine.jax_engine.model_runner import ModelRunner
     from dynamo_tpu.ops.sampling import MAX_EOS_IDS
 
@@ -359,6 +363,8 @@ def _lower_decode_multi(
         vec(F32), vec(F32), vec(I32), vec(jnp.bool_), vec(I32), vec(I32),
         one_chip((B, MAX_EOS_IDS), I32),
     )
+    if carried:
+        host += (vec(jnp.bool_), (vec(I32), vec(I32), vec(jnp.bool_), vec(I32)))
     return _lower_step(
         one_chip,
         functools.partial(ModelRunner._decode_multi_impl, cfg, None, None, BLOCK),
@@ -543,6 +549,7 @@ def _aliased_parameters(text: str) -> set[int]:
 
 STEP_PROGRAMS = {
     "decode_multi@H4B64": (_lower_decode_multi, {}),
+    "decode_multi@H4B64 carried": (_lower_decode_multi, {"carried": True}),
     "mixed_step@c1": (_lower_mixed_step, {}),
     "prefill_packed@512": (_lower_prefill_packed, {"tokens": 512}),
     "prefill_packed@2048": (_lower_prefill_packed, {"tokens": 2048}),
