@@ -434,6 +434,9 @@ def goodput_families(
          "summed over (layer, step) pairs"),
         ("max_expert_load", "tokens of the busiest expert, summed over "
          "(layer, step) pairs"),
+        ("assignments_made", "assignments a router made for live lanes in a "
+         "layer that holds a share of its experts, the absent experts' "
+         "among them (0 where every expert is held)"),
     ):
         yield CounterMetricFamily(
             f"{PREFIX}_moe_{name}",

@@ -19,7 +19,7 @@ from dynamo_tpu.engine.jax_engine.weights import load_or_init_params
 from dynamo_tpu.model_card import ModelDeploymentCard
 from dynamo_tpu.models import (
     cache_kind, config_from_model_dir, forward_for, layer_cache_kinds,
-    recurrent_layers,
+    paged_layers, recurrent_layers,
 )
 from dynamo_tpu.runtime.logging import get_logger
 
@@ -230,7 +230,9 @@ def refuse_unsupported(
     mesh, no fused decode step, no block-manager tiers, no speculative
     decoding. A model with a recurrent layer (`models/hybrid_ssm.py`: a
     state slot a sequence beside paged keys and values; `models/conv_moe.py`:
-    a short convolution's tail a sequence, and routed experts) is served the
+    a short convolution's tail a sequence, and routed experts;
+    `models/ssm2_moe.py`: a Mamba-2 state and tail a sequence, expert layers
+    that keep nothing and may hold a share of their experts) is served the
     same way and refuses the same six and, besides, disaggregated transfer,
     peer pulls and live handoff (`ModelRunner.require_block_transfer`);
     prefix reuse it simply does not offer (the engine publishes no block
@@ -247,8 +249,10 @@ def refuse_unsupported(
             "convolutions and expert stacks",
             "int8_cache": "the slots keep their own dtype and the pages "
             "beside them are served in bfloat16",
-            "mesh": "the slots' channels have no sharding rule yet (and an "
-            "experts' share has no add-up test)",
+            "mesh": "the slots' channels have no sharding rule yet, and the "
+            "exchange between the chips that share an expert layer is not "
+            "written (a held share of the experts runs on one chip without "
+            "it)",
             "fused_decode": "its kernels are the grouped-query block's",
             "tiers": "and with them prefix reuse: a block of keys and values "
             "without the state at its boundary cannot resume a sequence, and "
@@ -560,11 +564,11 @@ def default_num_blocks(
     # a model with recurrent layers); those layers' slots, one a lane and
     # the null lane's, come off the budget first
     kinds = layer_cache_kinds(config)
-    paged_layers = sum(k.name != "recurrent" for k in kinds)
+    paged = paged_layers(config)
     slot_bytes = (max_batch + 1) * sum(k.slot_bytes for k in kinds)
-    scale_bytes = scale_bytes * paged_layers // config.num_layers
+    scale_bytes = scale_bytes * paged // config.num_layers
     block_bytes = (
-        paged_layers * block_size
+        paged * block_size
         * kind.stored_values_per_token(tp) * kv_itemsize
         + scale_bytes
     )

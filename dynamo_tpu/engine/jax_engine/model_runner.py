@@ -22,7 +22,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from dynamo_tpu.models import (
-    cache_kind, forward_for, layer_cache_kinds, recurrent_layers,
+    cache_kind, forward_for, layer_cache_kinds, paged_layers,
+    recurrent_layers,
 )
 from dynamo_tpu.ops.sampling import (
     MAX_EOS_IDS,
@@ -329,19 +330,18 @@ class ModelRunner:
         )
         def make_zeros(which: int):  # 0: keys or a slot's first array; 1: values or its second
             pages = iter(kv_quant.make_cache(
-                len(kinds) - self.recurrent_layers, layer_shape, self.kv_dtype,
+                paged_layers(config), layer_shape, self.kv_dtype,
                 quantized=self.kv_quantized,
             ))
 
-            def slot_array(k):  # None: a slot of one array has no second
+            def slot_array(k):  # None: a slot of one array (or none) has no second
                 if which >= len(k.slot):
                     return None
                 shape, dtype = k.slot[which]
                 return jnp.zeros((self.state_slots,) + tuple(shape), dtype)
 
             return tuple(
-                slot_array(k) if k.name == "recurrent" else next(pages)
-                for k in kinds
+                next(pages) if k.planes else slot_array(k) for k in kinds
             )
 
         if kv_sharding is not None:

@@ -409,8 +409,91 @@ def load_conv_moe_safetensors(
     return params
 
 
+def load_ssm2_moe_safetensors(
+    model_dir: str,
+    config: Any,  # models.ssm2_moe.Ssm2MoeConfig
+    *,
+    quantize: bool = False,
+    dtype: jnp.dtype = jnp.bfloat16,
+) -> Any:
+    """The Mamba-2, latent-expert family's checkpoint names, written from
+    Hugging Face's `NemotronH*` classes and untried on a published
+    checkpoint (none is at hand; a synthetic state dict under these names
+    round-trips in `tests/test_ssm2_moe.py`): `backbone.embeddings`,
+    `backbone.layers.N.norm`; a Mamba-2 layer's `mixer.{in_proj, conv1d,
+    out_proj}`, `mixer.{dt_bias, A_log, D}` (float32, `[heads]`),
+    `mixer.norm` (the gated norm); an attention layer's `mixer.{q,k,v,o}_proj`;
+    an expert layer's `mixer.gate.weight`, `mixer.gate.e_score_correction_bias`
+    (float32), `mixer.fc1_latent_proj`, `mixer.fc2_latent_proj`,
+    `mixer.experts.K.{up,down}_proj`, `mixer.shared_experts.{up,down}_proj`;
+    `backbone.norm_f`, `lm_head`. The convolution's weight is `[channels, 1,
+    kernel]`; here `[kernel, channels]`. Of a layer's experts only those this
+    chip holds (`[first_held_expert, first_held_expert + num_experts)`) are
+    read; the others' tensors stay in the files."""
+    forward_for(config).refuse_int8_weights(quantize)
+    tensors = _read_safetensors(model_dir)
+    c = config
+
+    def get(name: str, as_dtype=dtype) -> jax.Array:
+        return jnp.asarray(tensors.pop(name)).astype(as_dtype)
+
+    def lin(name: str) -> jax.Array:  # HF stores [out, in]; we use [in, out]
+        return get(name).T
+
+    layers = []
+    for i in range(c.num_layers):
+        p = f"backbone.layers.{i}."
+        m = p + "mixer."
+        layer = {"norm": get(p + "norm.weight")}
+        kind = c.kind(i)
+        if kind == "*":
+            layer.update(
+                wq=lin(m + "q_proj.weight"), wk=lin(m + "k_proj.weight"),
+                wv=lin(m + "v_proj.weight"), wo=lin(m + "o_proj.weight"),
+            )
+        elif kind == "M":
+            layer.update(
+                w_in=lin(m + "in_proj.weight"),
+                conv_w=get(m + "conv1d.weight")[:, 0, :].T,
+                conv_b=get(m + "conv1d.bias"),
+                dt_bias=get(m + "dt_bias", jnp.float32),
+                A_log=get(m + "A_log", jnp.float32),
+                D=get(m + "D", jnp.float32),
+                gate_norm=get(m + "norm.weight"),
+                w_out=lin(m + "out_proj.weight"),
+            )
+        else:
+            held = range(c.first_held_expert, c.first_held_expert + c.num_experts)
+            layer.update(
+                router=lin(m + "gate.weight"),
+                router_bias=get(m + "gate.e_score_correction_bias", jnp.float32),
+                w_fc1=lin(m + "fc1_latent_proj.weight"),
+                w_fc2=lin(m + "fc2_latent_proj.weight"),
+                wu=jnp.stack([lin(f"{m}experts.{e}.up_proj.weight") for e in held]),
+                wd=jnp.stack([lin(f"{m}experts.{e}.down_proj.weight") for e in held]),
+                shared_wu=lin(m + "shared_experts.up_proj.weight"),
+                shared_wd=lin(m + "shared_experts.down_proj.weight"),
+            )
+        layers.append(layer)
+    params: dict[str, Any] = {
+        "embed": get("backbone.embeddings.weight"),
+        "layers": layers,
+        "final_norm": get("backbone.norm_f.weight"),
+    }
+    if not c.tie_word_embeddings:
+        params["lm_head"] = lin("lm_head.weight")
+    logger.info(
+        "loaded %d layers from %s (%d tensors left in the files, the "
+        "experts other chips hold among them); this family's checkpoint "
+        "names are untried on a published checkpoint",
+        len(layers), model_dir, len(tensors),
+    )
+    return params
+
+
 LOADERS = {
     "llama": load_hf_safetensors, "mla_moe": load_latent_moe_safetensors,
     "hybrid_ssm": load_hybrid_ssm_safetensors,
     "conv_moe": load_conv_moe_safetensors,
+    "ssm2_moe": load_ssm2_moe_safetensors,
 }

@@ -40,9 +40,12 @@ class CacheKind:
     slot a lane, `slot` its arrays as (shape, dtype name) pairs: one array
     (a short convolution's tail) or two (a state-space layer's state and
     tail). The first rides where a paged layer's keys do, the second where
-    its values do; a slot of one array leaves None there."""
+    its values do; a slot of one array leaves None there.
+    Nothing at all: `nothing`, a layer that is a feed-forward alone (an
+    expert mixer of a model whose layers are one mixer each); None rides in
+    both places."""
 
-    name: str  # "kv_heads" | "latent" | "recurrent"
+    name: str  # "kv_heads" | "latent" | "recurrent" | "nothing"
     planes: int
     heads: int
     width: int
@@ -81,6 +84,16 @@ def recurrent_state(*arrays: tuple) -> CacheKind:
     return CacheKind("recurrent", 0, 0, 0, 0, tuple(arrays))
 
 
+def keeps_nothing() -> CacheKind:
+    """A layer that keeps neither rows per token nor a slot."""
+    return CacheKind("nothing", 0, 0, 0, 0)
+
+
+def paged_layers(config) -> int:
+    """How many of `config`'s layers keep rows per token in blocks."""
+    return sum(k.planes > 0 for k in layer_cache_kinds(config))
+
+
 def layer_cache_kinds(config) -> tuple[CacheKind, ...]:
     """One kind a layer: what the config declares (`layer_cache_kinds`), or
     `num_layers` copies of the one kind it declares for layers that are all
@@ -100,7 +113,7 @@ def layer_cache_kinds(config) -> tuple[CacheKind, ...]:
 def cache_kind(config) -> CacheKind:
     """What `config`'s layers keep per cached token: the kind of the first
     layer that keeps rows at all (the paged layers of one model are alike)."""
-    return next(k for k in layer_cache_kinds(config) if k.name != "recurrent")
+    return next(k for k in layer_cache_kinds(config) if k.planes)
 
 
 def recurrent_layers(config) -> int:
@@ -167,7 +180,7 @@ def config_from_model_dir(model_dir: str):
     """The config of the family that `config.json`'s `model_type` names."""
     with open(os.path.join(model_dir, "config.json")) as f:
         hf = json.load(f)
-    from dynamo_tpu.models import conv_moe, hybrid_ssm, llama, mla_moe
+    from dynamo_tpu.models import conv_moe, hybrid_ssm, llama, mla_moe, ssm2_moe
 
     model_type = hf.get("model_type")
     if model_type in mla_moe.MODEL_TYPES:
@@ -176,10 +189,12 @@ def config_from_model_dir(model_dir: str):
         return hybrid_ssm.HybridSsmConfig.from_hf_dict(hf)
     if model_type in conv_moe.MODEL_TYPES:
         return conv_moe.ConvMoeConfig.from_hf_dict(hf)
+    if model_type in ssm2_moe.MODEL_TYPES:
+        return ssm2_moe.Ssm2MoeConfig.from_hf_dict(hf)
     if model_type is not None and model_type not in llama.MODEL_TYPES:
         served = (
             llama.MODEL_TYPES + mla_moe.MODEL_TYPES + hybrid_ssm.MODEL_TYPES
-            + conv_moe.MODEL_TYPES
+            + conv_moe.MODEL_TYPES + ssm2_moe.MODEL_TYPES
         )
         raise ValueError(
             f"model_type {model_type!r} is not served: its layers are not "

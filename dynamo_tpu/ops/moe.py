@@ -186,10 +186,23 @@ def dropless_experts(
     wu: jax.Array,
     wd: jax.Array,  # [E, F, D]
     valid: Optional[jax.Array] = None,  # [T] bool: False = padding token
+    *,
+    first_held: Optional[int] = None,  # `idx` names experts of a wider router
+    form: str = "swiglu",  # or "relu2": two products, `wg` is None
 ) -> tuple[jax.Array, jax.Array]:
     """The routed experts of a dropless layer for assignments already made:
     sort by expert, three grouped products, unsort, weighted sum over k.
     Returns (y f32 [T, D], group_sizes [E] int32).
+
+    `first_held`: the layer holds a share of the router's experts, those
+    numbered `[first_held, first_held + E)`, E the stacks' leading size. An
+    assignment to an expert outside the range is treated as a padding
+    token's: it sorts behind every held one, no group counts it and no row
+    is computed for it; `y` is the held experts' part of the sum. Default:
+    the stacks hold every expert the router names.
+
+    `form`: "swiglu", `wd(silu(wg x) * (wu x))`; "relu2", `wd(relu(wu x)^2)`
+    with no gate (`wg` None).
 
     A padding token (a lane that holds no request, the tail of a packed
     prompt) is given to no expert: its assignments sort behind every real
@@ -198,8 +211,11 @@ def dropless_experts(
     group are not computed by the grouped product and are zeroed here."""
     T, D = x.shape
     k = idx.shape[1]
-    E = wg.shape[0]
+    E = wu.shape[0]
     e_flat = idx.reshape(-1).astype(jnp.int32)  # [T*k]
+    if first_held is not None:
+        e_flat = e_flat - first_held
+        e_flat = jnp.where((e_flat >= 0) & (e_flat < E), e_flat, E)
     if valid is not None:
         e_flat = jnp.where(jnp.repeat(valid, k), e_flat, E)
     order = jnp.argsort(e_flat)  # stable: arrival order within expert
@@ -208,7 +224,11 @@ def dropless_experts(
         e_flat[:, None] == jnp.arange(E, dtype=jnp.int32)[None, :],
         axis=0, dtype=jnp.int32,
     )
-    ys = _grouped_ffn(xs, group_sizes, wg, wu, wd)  # [T*k, D]
+    if form == "relu2":
+        up = jax.nn.relu(lax.ragged_dot(xs, wu, group_sizes))
+        ys = lax.ragged_dot(up * up, wd, group_sizes)  # [T*k, D]
+    else:
+        ys = _grouped_ffn(xs, group_sizes, wg, wu, wd)  # [T*k, D]
     live = jnp.arange(T * k) < jnp.sum(group_sizes)
     ys = jnp.where(live[:, None], ys.astype(jnp.float32), 0.0)
     # back to token-major by the inverse permutation: a gather and a sum
@@ -223,14 +243,22 @@ def dropless_experts(
 # assignments, its experts with a token, its busiest expert's tokens; the
 # runner sums them over layers and steps (`telemetry.goodput.MOE_COUNTERS`)
 STEP_STATS = ("layer_steps", "assignments", "experts_touched", "max_expert_load")
+# of a layer that holds a share of its router's experts: the four above count
+# what it holds, and one more number every assignment its router made for a
+# live token, so that the held share of the routing is a counter
+HELD_STEP_STATS = STEP_STATS + ("assignments_made",)
 
 
-def expert_step_stats(group_sizes: jax.Array) -> jax.Array:
-    """`STEP_STATS` of one expert layer from its experts' live tokens [E]."""
-    return jnp.stack([
+def expert_step_stats(group_sizes: jax.Array, made=None) -> jax.Array:
+    """`STEP_STATS` of one expert layer from its experts' live tokens [E];
+    `HELD_STEP_STATS` where `made`, the router's live assignments, is given."""
+    counted = [
         jnp.int32(1), jnp.sum(group_sizes), jnp.sum(group_sizes > 0),
         jnp.max(group_sizes),
-    ]).astype(jnp.float32)
+    ]
+    if made is not None:
+        counted.append(made)
+    return jnp.stack(counted).astype(jnp.float32)
 
 
 def moe_ffn_dropless(

@@ -20,6 +20,11 @@ it; and one token for every lane of a decode batch (`_update`). The first
 two run `_blocked_scan`: blocks of 32 tokens side by side, three passes of
 device loops in plain XLA (no kernel that keeps the state in fast memory
 yet: ROADMAP M4).
+
+Mamba-2 (`ssd_step`, `ssd_chunk`, `ssd_packed`, at the file's end) is the
+same recurrence with one scalar decay a head and `B`, `C` shared by the heads
+of a group, so a head's state is a matrix `[head_dim, d_state]` and a prefill
+is matrix products over chunks of tokens.
 """
 
 from __future__ import annotations
@@ -196,3 +201,138 @@ def scan_packed(states, x, delta, b, c, a_neg, positions, valid, write_slots):
         (positions != 0).astype(F32), capture=(states, write_slots),
     )
     return y, states
+
+
+# ---------------------------------------------------------------- Mamba-2
+#
+# Per head j of H (each `P` channels wide), group g = j // (H / G):
+#
+#     S_t[j] = exp(dt_t[j] * a[j]) * S_{t-1}[j] + (dt_t[j] * x_t[j]) outer B_t[g]
+#     y_t[j] = S_t[j] C_t[g]
+#
+# with `a = -exp(A_log)` a scalar a head and S[j] `[P, N]` float32, `d_state`
+# last (the TPU's tiled dimension). A lane's slot is `[H, P, N]`.
+
+
+def ssd_step(s, x, dt, a, b, c, live):
+    """One token for every lane. s [B, H, P, N]; x [B, H, P]; dt [B, H]
+    (behind its softplus); a [H] (negative); b, c [B, G, N]; live [B] bool:
+    a lane that holds no decoding sequence keeps its slot as it is. Returns
+    (s_new, y [B, H, P])."""
+    B, H, P, N = s.shape
+    G = b.shape[1]
+    by_group = lambda v: v.reshape((B, G, H // G) + v.shape[2:])
+    s5, dt5 = by_group(s), by_group(dt)
+    decay = jnp.exp(dt5 * a.reshape(G, H // G))
+    new = (
+        decay[..., None, None] * s5
+        + (dt5[..., None] * by_group(x))[..., None] * b[:, :, None, None, :]
+    )
+    y = jnp.sum(new * c[:, :, None, None, :], axis=-1)
+    new = jnp.where(live[:, None, None, None], new.reshape(s.shape), s)
+    return new, y.reshape(B, H, P)
+
+
+def _ssd(h0, x, dt, a, b, c, resets, chunk):
+    """The recurrence over T tokens in chunks of `chunk`: within a chunk by
+    products against the lower-triangular decay, between chunks by the
+    carried state (Mamba-2's own algorithm), float32 at the highest matmul
+    precision.
+
+    h0 [H, P, N]; x [T, H, P]; dt [T, H], 0 where a token must leave the
+    state as it is (its decay is then 1 and its input 0); a [H]; b, c
+    [T, G, N]; resets [T] bool: the state is zeroed before the token (a
+    sequence's first in a pack). A token sees an earlier one, or the carried
+    state, only where no reset lies between them. The decay between two
+    tokens is the exponential of a difference of float32 sums counted from
+    the chunk's start, so it carries the sums' rounding: a relative 6e-8
+    times the sum, 4e-5 where a head forgets at 5 a token over a chunk of 128
+    (the fastest the published constants give), against 4e-3 a bfloat16
+    state would carry. Returns (y [T, H, P], the state behind the last token
+    [H, P, N], `behind`: token index -> the state behind that token)."""
+    T, H, P = x.shape
+    G, N = b.shape[1:]
+    R, Q = H // G, chunk
+    pad = (-T) % Q
+    if pad:
+        x, dt, b, c, resets = (
+            jnp.pad(v, ((0, pad),) + ((0, 0),) * (v.ndim - 1))
+            for v in (x, dt, b, c, resets)
+        )
+    nc = (T + pad) // Q
+    hi = lax.Precision.HIGHEST
+    # [nc, G, R, Q(, P)]: a head's chunk is a row of Q values, the products
+    # below are batched over (chunk, group[, head of the group])
+    heads = lambda v: jnp.moveaxis(v.reshape((nc, Q, G, R) + v.shape[2:]), 1, 3)
+    cum = jnp.cumsum(heads(dt * a), axis=3)  # log of the decay since the chunk's start
+    xd = heads(x * dt[..., None])
+    bq = jnp.swapaxes(b.reshape(nc, Q, G, N), 1, 2)  # [nc, G, Q, N]
+    cq = jnp.swapaxes(c.reshape(nc, Q, G, N), 1, 2)
+    r = jnp.cumsum(resets.astype(jnp.int32)).reshape(nc, Q)  # resets so far
+    r_in = jnp.concatenate([jnp.zeros((1,), jnp.int32), r[:-1, -1]])
+    at = jnp.arange(Q)
+    sees = (r[:, :, None] == r[:, None, :]) & (at[None, :, None] >= at[None, None, :])
+    decay = jnp.exp(jnp.where(
+        sees[:, None, None], cum[..., :, None] - cum[..., None, :], -jnp.inf
+    ))  # [nc, G, R, Q, Q]
+    cb = jnp.einsum("cgin,cgjn->cgij", cq, bq, precision=hi)
+    y = jnp.einsum("cgrij,cgrjp->cgrip", cb[:, :, None] * decay, xd, precision=hi)
+    # what each chunk adds to the state behind its last token, and what it
+    # lets through of the state it was handed
+    to_end = jnp.exp(jnp.where(
+        (r == r[:, -1:])[:, None, None], cum[..., -1:] - cum, -jnp.inf
+    ))
+    added = jnp.einsum("cgrjp,cgjn->cgrpn", to_end[..., None] * xd, bq, precision=hi)
+    through = jnp.exp(cum[..., -1]) * (r[:, -1] == r_in)[:, None, None]
+
+    def across(h, chunk_):
+        add, keep = chunk_
+        return keep[..., None, None] * h + add, h
+
+    h_last, h_in = lax.scan(
+        across, h0.astype(F32).reshape(G, R, P, N), (added, through)
+    )
+    from_in = jnp.exp(cum) * (r == r_in[:, None])[:, None, None]
+    y = y + from_in[..., None] * jnp.einsum(
+        "cgin,cgrpn->cgrip", cq, h_in, precision=hi
+    )
+    y = jnp.moveaxis(y, 3, 1).reshape(nc * Q, H, P)[:T]
+
+    def behind(t):
+        ci, i = t // Q, t % Q
+        cum_c, r_c = cum[ci], r[ci]
+        seen = (at <= i) & (r_c == r_c[i])
+        w = jnp.exp(jnp.where(seen, cum_c[..., i, None] - cum_c, -jnp.inf))
+        local = jnp.einsum("grjp,gjn->grpn", w[..., None] * xd[ci], bq[ci], precision=hi)
+        carried = jnp.exp(cum_c[..., i]) * (r_c[i] == r_in[ci])
+        return (local + carried[..., None, None] * h_in[ci]).reshape(H, P, N)
+
+    return y, h_last.reshape(H, P, N), behind
+
+
+def ssd_chunk(h0, x, dt, a, b, c, valid, chunk):
+    """One chunk of one sequence's prompt. h0 [H, P, N]: the state carried
+    in; valid [T] bool: a padded token leaves the state as it is. Returns
+    (y [T, H, P], the state carried out)."""
+    dt = jnp.where(valid[:, None], dt, 0.0)
+    y, h, _ = _ssd(h0, x, dt, a, b, c, jnp.zeros(valid.shape, bool), chunk)
+    return y, h
+
+
+def ssd_packed(states, x, dt, a, b, c, positions, valid, last_idx, seg_slots, count, chunk):
+    """Several sequences back to back. states [S, H, P, N]: every lane's
+    slot; positions [T]: a token at position 0 starts from a zero state;
+    valid [T] bool (padding is not); last_idx [n]: each sequence's last
+    token; seg_slots [n]: its slot; count (scalar): how many of the n
+    segments hold a sequence (the first `count`; no state is computed for
+    the others). Returns (y [T, H, P], states)."""
+    dt = jnp.where(valid[:, None], dt, 0.0)
+    y, _, behind = _ssd(
+        jnp.zeros(states.shape[1:], F32), x, dt, a, b, c,
+        valid & (positions == 0), chunk,
+    )
+
+    def keep(n, states):
+        return states.at[seg_slots[n]].set(behind(last_idx[n]))
+
+    return y, lax.fori_loop(0, count, keep, states)

@@ -85,9 +85,14 @@ def enabled_from_env() -> bool:
 
 # expert-layer counters of a sparse-expert model, computed on the device in
 # `decode_multi` and fetched with the tokens (`ops.moe.STEP_STATS`):
-# sums over (expert layer, step) pairs, whose number is `layer_steps`
+# sums over (expert layer, step) pairs, whose number is `layer_steps`. A
+# layer that holds a share of its router's experts counts what it holds
+# under the first four and every assignment its router made for a live token
+# under `assignments_made` (`ops.moe.HELD_STEP_STATS`; 0 for a model whose
+# layers hold every expert: there `assignments` is all that were made)
 MOE_COUNTERS = (
     "layer_steps", "assignments", "experts_touched", "max_expert_load",
+    "assignments_made",
 )
 
 # the sampler's candidate pool (`ops.sampling.sample_tokens`): decode-family

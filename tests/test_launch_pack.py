@@ -12,6 +12,7 @@ dispatch does not see what the engine wrote into its arrays afterwards.
 """
 
 import asyncio
+import time
 
 import jax
 import jax.numpy as jnp
@@ -161,25 +162,60 @@ def test_a_commit_does_not_see_what_the_engine_writes_afterwards():
 # ------------------------------------------------------------- the engine
 
 
-async def stream(engine, req, started=None):
+async def stream(engine, req):
     """Tokens, log-probs and top log-probs of one request, in order."""
     toks, lps, tops = [], [], []
     async for out in engine.generate(req, Context()):
         toks.extend(out.token_ids)
         lps.extend(out.log_probs or [])
         tops.extend(out.top_logprobs or [])
-        if started is not None and toks:
-            started.set()
     return toks, lps, tops
+
+
+def arrive_during(engine, program: str, nth: int, start) -> None:
+    """Call `start()` on the event loop while the engine's `nth` dispatch of
+    the runner's `program` is at the runner, and let that dispatch go on only
+    once the engine's queue holds the arrival. An arrival tied to a stream's
+    first token lands wherever the engine's loop happens to be by then (since
+    the launch ahead of PR 45: one dispatch further on a busy machine, which
+    moves a mixed step and with it the number of dispatches of the whole
+    serving); tied to the engine's own count of dispatches, two servings
+    take the same schedule."""
+    loop = asyncio.get_running_loop()
+    runner = engine.runner
+    real = getattr(runner, program)
+    calls = 0
+
+    def spy(*args, **kwargs):
+        nonlocal calls
+        calls += 1
+        if calls == nth:
+            loop.call_soon_threadsafe(start)
+            deadline = time.monotonic() + 30.0
+            while not engine.waiting and time.monotonic() < deadline:
+                time.sleep(0.001)  # the runner's thread; the loop runs beside it
+            assert engine.waiting, "the arrival never reached the engine's queue"
+        return real(*args, **kwargs)
+
+    setattr(runner, program, spy)
 
 
 async def serve(horizon: int):
     """Four requests through the toy engine: a short prompt that keeps its
-    EOS masked for `min_tokens` decodes while a 29-token prompt arrives and
-    rides mixed steps in 8-token chunks (a lane with penalties is never
-    mixed); then one with all three penalties and `min_tokens` beside a
-    seeded one with `top_k`. Gives the streams and the ledger."""
+    EOS masked for `min_tokens` decodes while a 29-token prompt arrives
+    (during the engine's second decode dispatch: `arrive_during`) and rides
+    mixed steps in 8-token chunks (a lane with penalties is never mixed);
+    then one with all three penalties and `min_tokens` beside a seeded one
+    with `top_k`. Gives the streams and the ledger."""
     engine = make_engine(decode_horizon=horizon)
+    # the serial loop (a dispatch is launched, read and replayed before the
+    # next): with PR 45's launch ahead, which steps of the first stream are
+    # mixed steps and which a horizon's follows the host's timing, and a
+    # mixed step's decode half and the horizon's program round a logit's
+    # last bit differently, so two servings agree to the last bit only on
+    # one schedule. That the chained engine streams the serial loop's tokens
+    # is `tests/test_decode_horizon.py`'s to hold, family by family
+    engine.runner.chains_horizons = False
     masked = PreprocessedRequest(
         token_ids=[5, 6, 7, 8, 9],
         sampling=SamplingOptions(greedy=True, logprobs=True, top_logprobs=3),
@@ -206,10 +242,13 @@ async def serve(horizon: int):
         stop=StopConditions(max_tokens=10, ignore_eos=True),
     )
     try:
-        started = asyncio.Event()
-        first = asyncio.ensure_future(stream(engine, masked, started))
-        await started.wait()  # the first decodes when the long one arrives
-        streams = [await stream(engine, long), await first]
+        arrived: list = []
+        arrive_during(
+            engine, "decode_multi" if horizon > 1 else "decode", 2,
+            lambda: arrived.append(asyncio.ensure_future(stream(engine, long))),
+        )
+        first = await stream(engine, masked)  # it decodes when the long one arrives
+        streams = [await arrived[0], first]
         streams += await asyncio.gather(stream(engine, pen), stream(engine, seeded))
         gp = engine.stats.goodput
         return streams, dict(gp.launch), set(gp.summary()["compile_s_by_label"])

@@ -284,3 +284,38 @@ def test_the_short_convolution_family_has_three_bodies_whatever_its_depth():
     # the named scopes cross the call boundary into the operations' locations
     for scope in ("conv.mix", "moe.route", "moe.experts"):
         assert scope in text6, scope
+
+
+def test_the_mamba2_expert_family_has_three_bodies_whatever_its_depth():
+    """`models/ssm2_moe.py`: a Mamba-2 layer, an expert layer (which keeps
+    nothing) and an attention layer are three bodies a program, lowered once
+    each, told apart by what a layer is and never by its index: a model of
+    twice the depth, its literal pattern repeated, lowers to as many private
+    functions; and the spans of step 6 are in the operations' locations."""
+    from dynamo_tpu.models import ssm2_moe as S
+    from tests.test_ssm2_moe import HF, caches
+
+    def lowered(cfg):
+        jax.clear_caches()
+        params = S.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
+        k_cache, v_cache = caches(cfg, lanes=2, blocks=8)
+        ids = jnp.zeros((2,), jnp.int32)
+        fn = jax.jit(lambda p, k, v: S.decode(
+            p, cfg, ids, ids, k, v, jnp.zeros((2, 2), jnp.int32), ids + 4,
+        ))
+        with layer_bodies_called() as bodies:
+            text = fn.lower(params, k_cache, v_cache).as_text(debug_info=True)
+        return text, sorted(b[1] for b in bodies)
+
+    cfg = S.Ssm2MoeConfig.from_hf_dict(HF)
+    text6, called = lowered(cfg)
+    assert called == ["_attn_decode_layer", "_experts", "_mamba_decode_layer"]
+    pattern = HF["hybrid_override_pattern"]
+    deep = S.Ssm2MoeConfig.from_hf_dict(dict(
+        HF, num_hidden_layers=2 * len(pattern), hybrid_override_pattern=2 * pattern,
+    ))
+    text12, called12 = lowered(deep)
+    assert called12 == called
+    assert private_functions(text12) == private_functions(text6)
+    for scope in ("ssm2.mix", "ssm2.update", "moe.route", "moe.latent", "moe.experts", "moe.shared"):
+        assert scope in text6, scope

@@ -104,6 +104,8 @@ INTENTIONALLY_SHARED = {
     "dyn_llm_moe_assignments",
     "dyn_llm_moe_experts_touched",
     "dyn_llm_moe_max_expert_load",
+    # a held share of the experts (ISSUE 46): every assignment the router made
+    "dyn_llm_moe_assignments_made",
     # the sampler's candidate pool (ISSUE 32): how often a dispatch's lanes
     # made the device compute it; the same shared goodput surface
     "dyn_llm_sampler_dispatches",

@@ -349,3 +349,139 @@ def test_sigmoid_router_bias_moves_the_choice_and_never_the_weights(eps):
     _, w_tiny = router_sigmoid_topk(tiny, jnp.zeros(6), 2, eps=eps)
     total = float(w_tiny.sum())
     assert (total > 0.99) if eps == 1e-20 else (total < 0.01)
+
+
+# ----------------------------------------------- a held share of the experts
+
+
+def _held_case(T=12, k=3, R=16, D=8, F=10, seed=5):
+    """Assignments of T tokens over a router of R experts, a latent-width
+    input, and all R experts' two-product stacks."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    x = jax.random.normal(ks[0], (T, D), jnp.float32)
+    scores = jax.random.uniform(ks[1], (T, R))
+    weights, idx = jax.lax.top_k(scores, k)
+    wu = jax.random.normal(ks[2], (R, D, F)) / np.sqrt(D)
+    wd = jax.random.normal(ks[3], (R, F, D)) / np.sqrt(F)
+    return x, idx.astype(jnp.int32), weights, wu, wd
+
+
+def _relu2_loop(x, idx, weights, wu, wd, first, held, valid=None):
+    """Per-token oracle of the held part: expert e of the router is stack
+    row e - first; an expert outside [first, first + held) adds nothing."""
+    x, wu, wd = (np.asarray(a, np.float64) for a in (x, wu, wd))
+    out = np.zeros(x.shape, np.float64)
+    for t in range(x.shape[0]):
+        if valid is not None and not bool(valid[t]):
+            continue
+        for e, w in zip(np.asarray(idx[t]).tolist(), np.asarray(weights[t]).tolist()):
+            if first <= e < first + held:
+                up = np.maximum(x[t] @ wu[e - first], 0.0)
+                out[t] += w * ((up * up) @ wd[e - first])
+    return out
+
+
+@pytest.mark.parametrize("first,held", [(0, 16), (0, 4), (4, 4), (12, 4)])
+def test_held_range_squared_relu_against_a_loop(first, held):
+    """`dropless_experts(first_held=, form="relu2")`: the stacks hold the
+    router's experts `[first, first + held)`; the result is their part of
+    the sum, two products with a squared ReLU between them and no gate; an
+    assignment outside the range, like a padding token's, reaches no group."""
+    from dynamo_tpu.ops.moe import dropless_experts
+
+    x, idx, weights, wu, wd = _held_case()
+    valid = jnp.asarray([True] * 9 + [False] * 3)
+    y, sizes = dropless_experts(
+        x, idx, weights, None, wu[first: first + held], wd[first: first + held],
+        valid, first_held=first, form="relu2",
+    )
+    want = _relu2_loop(x, idx, weights, wu[first: first + held], wd[first: first + held],
+                       first, held, valid)
+    np.testing.assert_allclose(np.asarray(y), want, atol=1e-4, rtol=1e-4)
+    live = np.asarray(idx)[:9].ravel()
+    counts = np.bincount(live[(live >= first) & (live < first + held)] - first, minlength=held)
+    np.testing.assert_array_equal(np.asarray(sizes), counts)
+    assert np.all(np.asarray(y)[9:] == 0.0)
+
+
+def test_no_row_is_computed_for_an_assignment_outside_the_held_range():
+    """Held assignments sort in front and the rows behind the last group (the
+    absent experts' and the padding's) are given to no expert and kept by
+    nobody: a token none of whose experts is held gets exact zeros, each
+    share's groups count its own assignments only, and the four shares of a
+    router's 16 experts add up to what one stack of all 16 gives."""
+    from dynamo_tpu.ops.moe import dropless_experts
+
+    x, idx, weights, wu, wd = _held_case()
+    whole, sizes_whole = dropless_experts(x, idx, weights, None, wu, wd, form="relu2")
+    parts, held_sizes = [], []
+    for first in (0, 4, 8, 12):
+        y, sizes = dropless_experts(
+            x, idx, weights, None, wu[first: first + 4], wd[first: first + 4],
+            first_held=first, form="relu2",
+        )
+        parts.append(np.asarray(y))
+        held_sizes.append(np.asarray(sizes))
+    np.testing.assert_allclose(sum(parts), np.asarray(whole), atol=1e-4, rtol=1e-4)
+    np.testing.assert_array_equal(np.concatenate(held_sizes), np.asarray(sizes_whole))
+    # a share that holds nothing a token chose gives exact zeros for it
+    none_held = np.all((np.asarray(idx) < 4) | (np.asarray(idx) >= 8), axis=1)
+    assert np.all(parts[1][none_held] == 0.0)
+
+
+def test_the_default_is_the_parents_program_to_the_bit():
+    """With no held range and the SwiGLU form, `dropless_experts` traces to
+    the jaxpr it traced to before it took either option (what JoyAI's and
+    LFM2's programs compile), and `expert_step_stats` to its four numbers."""
+    from dynamo_tpu.ops import moe
+
+    router_w, wg, wu, wd = _weights(8, 16, 24)
+    x = jax.random.normal(jax.random.PRNGKey(1), (10, 16))
+    idx, w = router_topk(x @ router_w, 2)
+    valid = jnp.arange(10) < 7
+
+    def parents(x, idx, weights, wg, wu, wd, valid):
+        T, D = x.shape
+        k = idx.shape[1]
+        E = wg.shape[0]
+        e_flat = idx.reshape(-1).astype(jnp.int32)
+        e_flat = jnp.where(jnp.repeat(valid, k), e_flat, E)
+        order = jnp.argsort(e_flat)
+        xs = x[order // k]
+        group_sizes = jnp.sum(
+            e_flat[:, None] == jnp.arange(E, dtype=jnp.int32)[None, :],
+            axis=0, dtype=jnp.int32,
+        )
+        ys = moe._grouped_ffn(xs, group_sizes, wg, wu, wd)
+        live = jnp.arange(T * k) < jnp.sum(group_sizes)
+        ys = jnp.where(live[:, None], ys.astype(jnp.float32), 0.0)
+        inv = jnp.zeros_like(order).at[order].set(jnp.arange(T * k, dtype=order.dtype))
+        y = ys[inv].reshape(T, k, D) * weights.astype(jnp.float32)[:, :, None]
+        return y.sum(axis=1), group_sizes
+
+    args = (x, idx, w, wg, wu, wd, valid)
+    assert str(jax.make_jaxpr(moe.dropless_experts)(*args)) == str(jax.make_jaxpr(parents)(*args))
+    got, want = moe.dropless_experts(*args), parents(*args)
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+    sizes = jnp.asarray([3, 0, 2, 1], jnp.int32)
+    assert moe.STEP_STATS == ("layer_steps", "assignments", "experts_touched", "max_expert_load")
+    np.testing.assert_array_equal(np.asarray(moe.expert_step_stats(sizes)), [1, 6, 3, 3])
+
+
+@pytest.mark.parametrize("live", [0, 5, 12])
+def test_assignments_made_counts_every_assignment_of_the_live_tokens(live):
+    """A held layer's fifth counter: `T x k` of the live tokens, the absent
+    experts' assignments among them, beside the held ones in the second."""
+    from dynamo_tpu.ops import moe
+
+    x, idx, weights, wu, wd = _held_case()
+    valid = jnp.arange(12) < live
+    _, sizes = moe.dropless_experts(
+        x, idx, weights, None, wu[4:8], wd[4:8], valid, first_held=4, form="relu2")
+    made = jnp.sum(valid.astype(jnp.int32)) * idx.shape[1]
+    counted = np.asarray(moe.expert_step_stats(sizes, made))
+    assert moe.HELD_STEP_STATS[-1] == "assignments_made" and counted.shape == (5,)
+    chosen = np.asarray(idx)[:live].ravel()
+    assert counted[4] == live * 3
+    assert counted[1] == np.sum((chosen >= 4) & (chosen < 8)) <= counted[4]

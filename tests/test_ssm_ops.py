@@ -7,7 +7,9 @@ multiplied up, then applied to the state it starts from), the same float32
 arithmetic reassociated, so they differ from each other and from the
 float64 loop by float32 rounding over the sequence: 1e-5 absolute on values of order
 1 is ten times what 40 tokens read (1e-6) and far under what a wrong form
-gives (a missed reset or a tail from the wrong place reads 1e-1).
+gives (a missed reset or a tail from the wrong place reads 1e-1). The
+Mamba-2 forms (`ssd_packed`, `ssd_chunk`, `ssd_step`) are held to their own
+loop the same way, at several sizes of the recurrence's own chunk.
 """
 
 from __future__ import annotations
@@ -148,3 +150,101 @@ def test_convolution_with_a_carried_tail_equals_one_pass(count):
     tails = ssm.packed_tails(both, positions, jnp.asarray([count - 1, count + T - 1]), K)
     np.testing.assert_array_equal(np.asarray(tails[1]), np.asarray(ssm.tail_after(stream, T, K)))
     np.testing.assert_array_equal(np.asarray(tails[0]), np.asarray(tail))
+
+
+# ---------------------------------------------------------------- Mamba-2
+
+H2, P2, G2, N2 = 6, 5, 2, 4  # heads, head width, groups of B and C, state
+
+
+def inputs2(T: int, seed: int):
+    rng = np.random.default_rng(seed)
+    x = rng.standard_normal((T, H2, P2)).astype(np.float32)
+    # steps from gentle (a decay of 0.98 a token) to one that forgets a state
+    # in a token (1e-9 at the fastest head)
+    dt = np.log1p(np.exp(rng.standard_normal((T, H2)) - 2)).astype(np.float32)
+    b = rng.standard_normal((T, G2, N2)).astype(np.float32)
+    c = rng.standard_normal((T, G2, N2)).astype(np.float32)
+    return x, dt, b, c
+
+
+A2 = -np.linspace(1.0, 16.0, H2)
+
+
+def loop2(x, dt, b, c, s=None):
+    """The plain loop over positions, float64: head j reads group j // 3.
+    Returns (y [T, H, P], the state after every token [T, H, P, N])."""
+    s = np.zeros((H2, P2, N2)) if s is None else s.astype(np.float64)
+    ys, ss = [], []
+    for t in range(x.shape[0]):
+        bh = np.repeat(b[t].astype(np.float64), H2 // G2, axis=0)
+        ch = np.repeat(c[t].astype(np.float64), H2 // G2, axis=0)
+        s = (
+            np.exp(dt[t] * A2)[:, None, None] * s
+            + (dt[t][:, None] * x[t])[:, :, None] * bh[:, None, :]
+        )
+        ys.append((s * ch[:, None, :]).sum(-1))
+        ss.append(s.copy())
+    return np.stack(ys), np.stack(ss)
+
+
+@pytest.mark.parametrize("chunk", [4, 8, 64])
+def test_three_mamba2_forms_add_up_to_the_plain_loop(chunk):
+    """A 29-token sequence: whole in a pack between a 7-token and a 3-token
+    neighbour (the boundaries fall inside the recurrence's own chunks, the
+    last sequence ends the pack's valid part); as chunks of 16 and 13 (the
+    second padded to 16) from a carried state; and its last 5 tokens as
+    single steps from the chunks' state, in a batch with a lane that must
+    keep its slot. At a chunk of 4 and of 8 (several of the recurrence's
+    chunks a program) and of 64 (one, padded)."""
+    T, T0, T2 = 29, 7, 3
+    x, dt, b, c = inputs2(T, 1)
+    x0, dt0, b0, c0 = inputs2(T0, 2)
+    x2, dt2, b2, c2 = inputs2(T2, 3)
+    want_y, want_s = loop2(x, dt, b, c)
+    a = jnp.asarray(A2, jnp.float32)
+
+    P = 48  # the pack: 7 + 29 + 3 tokens and 9 of padding
+    pad = P - T0 - T - T2
+    cat = lambda u, v, w: jnp.asarray(
+        np.concatenate([u, v, w, np.zeros((pad,) + v.shape[1:], np.float32)]))
+    positions = jnp.asarray(np.concatenate(
+        [np.arange(T0), np.arange(T), np.arange(T2), np.zeros(pad)]).astype(np.int32))
+    valid = jnp.arange(P) < T0 + T + T2
+    states = jnp.full((5, H2, P2, N2), 7.0, jnp.float32)  # dirty slots; 4 is the null one
+    last_idx = jnp.asarray([T0 - 1, T0 + T - 1, T0 + T + T2 - 1, 0], jnp.int32)
+    seg_slots = jnp.asarray([3, 1, 0, 4], jnp.int32)
+    y, states = jax.jit(ssm.ssd_packed, static_argnums=11)(
+        states, cat(x0, x, x2), cat(dt0, dt, dt2), a, cat(b0, b, b2), cat(c0, c, c2),
+        positions, valid, last_idx, seg_slots, jnp.int32(3), chunk,
+    )
+    np.testing.assert_allclose(np.asarray(y)[T0:T0 + T], want_y, atol=TOL)
+    np.testing.assert_allclose(np.asarray(states[1]), want_s[-1], atol=TOL)
+    np.testing.assert_allclose(np.asarray(states[3]), loop2(x0, dt0, b0, c0)[1][-1], atol=TOL)
+    np.testing.assert_allclose(np.asarray(states[0]), loop2(x2, dt2, b2, c2)[1][-1], atol=TOL)
+    # no state is computed for the fourth segment, which holds no sequence
+    assert np.all(np.asarray(states[2]) == 7.0) and np.all(np.asarray(states[4]) == 7.0)
+
+    C = 16
+    chunked = jax.jit(ssm.ssd_chunk, static_argnums=7)
+    h = jnp.zeros((H2, P2, N2), jnp.float32)
+    ys = []
+    for start in (0, C):
+        n = min(C, T - start)
+        grow = lambda v: jnp.asarray(
+            np.concatenate([v[start:start + n], np.ones((C - n,) + v.shape[1:], np.float32)]))
+        yc, h = chunked(h, grow(x), grow(dt), a, grow(b), grow(c), jnp.arange(C) < n, chunk)
+        ys.append(np.asarray(yc)[:n])
+    np.testing.assert_allclose(np.concatenate(ys), want_y, atol=TOL)
+    np.testing.assert_allclose(np.asarray(h), want_s[-1], atol=TOL)
+
+    # single steps: lane 0 continues the sequence from the state behind token
+    # 23, lane 1 holds no decoding sequence and keeps what its slot holds
+    s = jnp.stack([jnp.asarray(want_s[23], jnp.float32), jnp.full((H2, P2, N2), 3.0)])
+    step = jax.jit(ssm.ssd_step)
+    for t in range(24, T):
+        two = lambda v: jnp.stack([jnp.asarray(v[t]), jnp.asarray(v[t])])
+        s, y1 = step(s, two(x), two(dt), a, two(b), two(c), jnp.asarray([True, False]))
+        np.testing.assert_allclose(np.asarray(y1[0]), want_y[t], atol=TOL)
+    np.testing.assert_allclose(np.asarray(s[0]), want_s[-1], atol=TOL)
+    assert np.all(np.asarray(s[1]) == 3.0)

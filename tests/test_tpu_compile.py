@@ -1145,3 +1145,80 @@ def test_conv_moe_step_programs_one_chip(one_chip, program, bodies, kernels, pac
         # what `short_conv_ms` reads: instructions that mention a layer's
         # tails, bfloat16 [65, 4096]
         assert re.search(r"bf16\[65,4096\]", text)
+
+
+# ------------------- the Mamba-2, latent-expert family's programs (PR 46)
+#
+# `cellbench/configs/nemotron3-super-bf16-l11-e128.json` at the published
+# widths, cut here to one layer of each kind (`ME*`) so that a compile takes
+# seconds: a state slot a lane (`[65, 128, 64, 128]` float32, 4 MiB a lane,
+# and the tail of `x, B, C` together) for the Mamba-2 layer, nothing for the
+# expert layer (128 held experts of a 512-wide router), paged keys and values
+# of 2 KV heads for the attention layer, 64 lanes, the cell's 32,832 blocks.
+# The full depth compiles too (PERF.md section 6, PR 46).
+
+
+def _ssm2_moe_setup(one_chip, num_blocks: int = 32832, pattern: str = "ME*"):
+    from dynamo_tpu.models import ssm2_moe
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "cellbench", "configs", "nemotron3-super-bf16-l11-e128.json")) as f:
+        conf = json.load(f)
+    cfg = ssm2_moe.Ssm2MoeConfig.from_hf_dict(
+        {k: v for k, v in conf.items() if k != "bench"}
+        | {"num_hidden_layers": len(pattern), "hybrid_override_pattern": pattern}
+    )
+    cfg = dataclasses.replace(cfg, attn_impl="pallas")
+    params = jax.tree_util.tree_map(
+        lambda a: one_chip(a.shape, a.dtype),
+        jax.eval_shape(lambda: ssm2_moe.init_params(cfg, jax.random.PRNGKey(0))),
+    )
+    (state, _), (tail, _) = cfg.state_kind().slot
+    by_kind = lambda slot: {
+        "M": one_chip((B + 1,) + slot, F32), "E": None,
+        "*": one_chip((cfg.num_kv_heads, num_blocks, BLOCK, cfg.head_dim), BF16),
+    }
+    first = tuple(by_kind(state)[k] for k in pattern)
+    second = tuple(by_kind(tail)[k] for k in pattern)
+    return cfg, params, first, second
+
+
+@pytest.mark.parametrize("packed", [False, True], ids=["impl", "as_launched"])
+@pytest.mark.parametrize("program,bodies,kernels", [
+    ("decode_multi@H4B64", 3, 1 * 4),  # 1 attention layer x 4 steps
+    ("mixed_step@c1", 6, 1),  # the chunk's attention is XLA's
+    ("prefill_packed@512", 3, 0),
+])
+def test_ssm2_moe_step_programs_one_chip(one_chip, program, bodies, kernels, packed):
+    """The family's step programs compile for the chip: three layer bodies a
+    pass (Mamba-2, experts, attention; a mixed step has a chunk's pass and a
+    decode's), the paged decode kernel under its name at 2 KV heads, the
+    grouped products of the held experts, the slot arrays and the pages
+    written in place (aliased), and everything fits."""
+    from dynamo_tpu.models import layer_bodies_called
+
+    jax.clear_caches()  # a body traced by another test would not be counted
+    with layer_bodies_called() as seen:
+        lowered = _lower_slotted(one_chip, _ssm2_moe_setup, program, packed)
+    assert len(seen) == bodies, sorted(s[1] for s in seen)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    names = re.findall(
+        r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\""
+        r"[^\n]*op_name=\"[^\"\n]*pallas_call\"", text, re.M,
+    )
+    assert len(names) == kernels
+    assert all(name.startswith("tpu_custom_call") for name in names), names
+    assert "ragged-dot" in text or "ragged_dot" in text
+    mem = compiled.memory_analysis()
+    # one Mamba-2 layer's slot arrays (the tail's 65 rows are tiled to 72)
+    # and one attention layer's two planes, at the published bytes a token
+    slots = (B + 1) * 128 * 64 * 128 * 4 + 72 * 3 * 10240 * 4
+    pages = 2 * 32832 * BLOCK * 2 * 128 * 2
+    assert mem.alias_size_in_bytes == slots + pages
+    assert mem.temp_size_in_bytes < 2 * 2**30
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16_909_336_064
+    if program == "decode_multi@H4B64":
+        # what `ssm2_step_ms` reads: instructions that mention a layer's
+        # states, float32 [65, 128, 64, 128] (or its heads by group)
+        assert re.search(r"f32\[65,(128|8,16),64,128\]", text)
