@@ -73,7 +73,8 @@ def unrolled_steps(step, init, H: int):
     A lax.scan would compile the body once, but carrying the multi-GB KV
     caches through a scan makes XLA double-buffer them (the r04 bench OOMed
     HBM by ~0.9G exactly this way). Unrolled, each layer's own cache buffer
-    threads through a chain of row scatters that write it in place; that is
+    threads through a chain of writes in place (the paged decode kernel's
+    aliased outputs, or row scatters where the pair runs); that is
     a property of the compiled program, not of this dataflow, and
     tests/test_tpu_compile.py holds it there (no slice, copy or update the
     size of a layer's pool). H is small (<=16) and fixed per deployment.

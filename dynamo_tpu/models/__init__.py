@@ -25,6 +25,8 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
+from dynamo_tpu.ops.attention import kv_appends_traced
+
 
 @dataclass(frozen=True)
 class CacheKind:
@@ -141,21 +143,38 @@ def layer_body(*static: str):
 
         @functools.wraps(fn)
         def call(*args, **kwargs):
+            leaves, tree = jax.tree_util.tree_flatten(
+                (args, {k: v for k, v in kwargs.items() if k not in static})
+            )
+            body = (
+                fn.__module__, fn.__qualname__,
+                tuple(kwargs.get(k) for k in static), tree,
+                tuple((jnp.shape(a), jnp.result_type(a)) for a in leaves),
+            )
+            with kv_appends_traced() as appends:
+                out = jitted(*args, **kwargs)
+            if any(appends):  # the body was traced (or ran) just now
+                _BODY_APPENDS[body] = tuple(appends)
             seen = getattr(_watching, "bodies", None)
             if seen is not None:
-                leaves, tree = jax.tree_util.tree_flatten(
-                    (args, {k: v for k, v in kwargs.items() if k not in static})
+                seen.add(body)
+                # a layer is told by its parameters (the second argument of
+                # every body), which a program's every step passes again
+                params = tuple(jax.tree_util.tree_leaves(args[1]))
+                _watching.layers[(body, tuple(map(id, params)))] = (
+                    params, _BODY_APPENDS.get(body, (0, 0))
                 )
-                seen.add((
-                    fn.__module__, fn.__qualname__,
-                    tuple(kwargs.get(k) for k in static), tree,
-                    tuple((jnp.shape(a), jnp.result_type(a)) for a in leaves),
-                ))
-            return jitted(*args, **kwargs)
+            return out
 
         return call
 
     return wrap
+
+
+# body (as `layer_bodies_called` keys it) -> (folded, scattered): the cache
+# appends its trace made (`ops.attention.kv_appends_traced`); a body whose
+# trace JAX has cached runs no Python, so the count is kept here
+_BODY_APPENDS: dict = {}
 
 
 @contextlib.contextmanager
@@ -165,10 +184,28 @@ def layer_bodies_called():
     programs traced there lower once each, whatever their depth (the goodput
     ledger's `layer_bodies` of a label's first dispatch)."""
     seen = _watching.bodies = set()
+    layers = _watching.layers = {}
     try:
         yield seen
     finally:
-        _watching.bodies = None
+        _watching.bodies = _watching.layers = None
+        # the counts outlive the block, the layers' parameters do not
+        counted = [c for _, c in layers.values()]
+        _watching.appends = {
+            "kv_append_folded": sum(c[0] for c in counted),
+            "kv_append_scattered": sum(c[1] for c in counted),
+        }
+
+
+def kv_appends_called() -> dict:
+    """`kv_append_folded` and `kv_append_scattered` of the calling thread's
+    last `layer_bodies_called` block: of the distinct layers called inside it
+    (a body and its parameters: a horizon's steps call a layer again and count
+    it once), how many append a decode token's rows to their cache inside the
+    paged decode kernel, and how many by the row scatter before it."""
+    return dict(getattr(_watching, "appends", None) or {
+        "kv_append_folded": 0, "kv_append_scattered": 0,
+    })
 
 
 def forward_for(config):

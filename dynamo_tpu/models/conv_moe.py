@@ -81,8 +81,9 @@ from dynamo_tpu.models import (
 )
 from dynamo_tpu.ops import ssm
 from dynamo_tpu.ops.attention import (
-    causal_prefill_attention, chunked_prefill_attention, live_decode_lanes,
-    packed_prefill_attention, paged_decode_attention, write_decode_kv,
+    causal_prefill_attention, chunked_prefill_attention,
+    decode_append_attention, live_decode_lanes, packed_prefill_attention,
+    write_decode_kv,
 )
 from dynamo_tpu.ops.basics import apply_rope, rms_norm, rope_freqs, swiglu
 from dynamo_tpu.ops.linear import linear
@@ -583,10 +584,10 @@ def _conv_decode_layer(x, layer, tail, live, *, cfg):
 @layer_body("cfg", "mesh", "head_axis")
 def _attn_decode_layer(x, layer, k_l, v_l, inv_freqs, positions, live, context, block_tables, slot_indices, *, cfg, mesh, head_axis):
     q, k, v = _qkv(x, layer, cfg, inv_freqs, positions)
-    k_l, v_l = write_decode_kv(k_l, v_l, _rows(k, cfg), _rows(v, cfg), slot_indices)
-    attn = paged_decode_attention(
-        q, k_l, v_l, block_tables, context, impl=cfg.attn_impl, mesh=mesh,
-        head_axis=head_axis, scale=cfg.attn_scale,
+    attn, k_l, v_l = decode_append_attention(
+        q, k_l, v_l, _rows(k, cfg), _rows(v, cfg), slot_indices, block_tables,
+        context, impl=cfg.attn_impl, mesh=mesh, head_axis=head_axis,
+        scale=cfg.attn_scale,
     )
     x, counted = _ffn(_attn_out(attn, x, layer, cfg), layer, cfg, live)
     return x, k_l, v_l, counted

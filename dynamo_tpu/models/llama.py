@@ -28,9 +28,9 @@ from dynamo_tpu.models import layer_body
 from dynamo_tpu.ops.attention import (
     causal_prefill_attention,
     chunked_prefill_attention,
+    decode_append_attention,
     live_decode_lanes,
     packed_prefill_attention,
-    paged_decode_attention,
     paged_verify_attention,
     write_chunk_kv,
     write_decode_kv,
@@ -523,10 +523,9 @@ def _attn_decode(x, layer, cfg, inv_freqs, positions, k_cache_l, v_cache_l, bloc
         q, k, v = _fused_qkv_dispatch(x, layer, cfg, inv_freqs, positions, mesh)
     else:
         q, k, v = _qkv(x, layer, cfg, inv_freqs, positions)
-    k_cache_l, v_cache_l = write_decode_kv(k_cache_l, v_cache_l, k, v, slot_indices)
     live = live_decode_lanes(k_cache_l, slot_indices)
-    attn = paged_decode_attention(
-        q, k_cache_l, v_cache_l, block_tables,
+    attn, k_cache_l, v_cache_l = decode_append_attention(
+        q, k_cache_l, v_cache_l, k, v, slot_indices, block_tables,
         jnp.where(live, positions + 1, 0),
         impl=cfg.attn_impl, mesh=mesh, head_axis=head_axis,
         window=window, scale=cfg.attn_scale,

@@ -94,8 +94,8 @@ from dynamo_tpu.models import (
 )
 from dynamo_tpu.ops import ssm
 from dynamo_tpu.ops.attention import (
-    chunked_prefill_attention, live_decode_lanes, packed_prefill_attention,
-    paged_decode_attention, write_decode_kv,
+    chunked_prefill_attention, decode_append_attention, live_decode_lanes,
+    packed_prefill_attention, write_decode_kv,
 )
 from dynamo_tpu.ops.basics import rms_norm
 from dynamo_tpu.ops.linear import linear
@@ -635,10 +635,10 @@ def _mamba_decode_layer(x, layer, state, tail, live, *, cfg):
 @layer_body("cfg", "mesh", "head_axis")
 def _attn_decode_layer(x, layer, k_l, v_l, context, block_tables, slot_indices, *, cfg, mesh, head_axis):
     q, k, v = _qkv(x, layer, cfg)
-    k_l, v_l = write_decode_kv(k_l, v_l, k, v, slot_indices)
-    attn = paged_decode_attention(
-        q, k_l, v_l, block_tables, context, impl=cfg.attn_impl, mesh=mesh,
-        head_axis=head_axis, scale=cfg.attn_scale,
+    attn, k_l, v_l = decode_append_attention(
+        q, k_l, v_l, k, v, slot_indices, block_tables, context,
+        impl=cfg.attn_impl, mesh=mesh, head_axis=head_axis,
+        scale=cfg.attn_scale,
     )
     return _attn_out(attn, x, layer, cfg), k_l, v_l
 

@@ -39,7 +39,9 @@ All functions are jit-safe: static shapes, masks instead of dynamic slicing.
 
 from __future__ import annotations
 
+import contextlib
 import os
+import threading
 from typing import Optional
 
 import jax
@@ -110,6 +112,44 @@ def _falls_to_xla(what: str, width: int, block_size: int) -> str:
             what, width, block_size,
         )
     return "xla"
+
+
+_appends = threading.local()
+
+
+@contextlib.contextmanager
+def kv_appends_traced():
+    """Yields `[folded, scattered]`: how many times the code traced (or run)
+    by the calling thread inside the block appended tokens' rows to a layer's
+    cache inside the paged decode kernel (`decode_append_attention`'s kernel
+    form), and how many times by the row scatter (`write_decode_kv`). A trace
+    that JAX has cached runs no Python, so a block around a cached call counts
+    nothing: `models.layer_body` keeps what each body's trace counted."""
+    before = getattr(_appends, "tally", None)
+    tally = _appends.tally = [0, 0]
+    try:
+        yield tally
+    finally:
+        _appends.tally = before
+
+
+def _note_kv_append(folded: bool) -> None:
+    tally = getattr(_appends, "tally", None)
+    if tally is not None:
+        tally[0 if folded else 1] += 1
+
+
+def _paged_decode_impl(impl: Optional[str], pages: jax.Array, quant: bool) -> str:
+    """The form a paged decode call takes on these pages: the one asked for,
+    or "xla" where the Pallas form was asked for and cannot tile them."""
+    impl = get_attention_impl(impl)
+    if impl == "pallas" and not _pallas_tileable(
+        pages.shape[-1], pages.shape[2], kv_bits=8 if quant else 16
+    ):
+        impl = _falls_to_xla(
+            "paged decode attention", pages.shape[-1], pages.shape[2]
+        )
+    return impl
 
 
 def _row_pack(q_width: int, kv_width: int) -> int:
@@ -337,7 +377,10 @@ def paged_decode_attention(
     window: Optional[int] = None,
     scale: Optional[float] = None,
     logit_softcap: Optional[float] = None,
-) -> jax.Array:
+    append: Optional[tuple] = None,  # (k_new, v_new) [B, Hkv, D], or stored
+    # rows: the kernel form of `decode_append_attention`, which alone passes
+    # it; the result is then (attn, k_cache, v_cache)
+):
     """Decode-step attention: gather each sequence's blocks and attend.
 
     The cache is head-major [Hkv, blocks, bs, D]: each (head, page) is a
@@ -359,64 +402,59 @@ def paged_decode_attention(
     quant = _cache_quantized(k_cache)
     kq = k_cache["q"] if quant else k_cache
     vq = v_cache["q"] if quant else v_cache
-    impl = get_attention_impl(impl)
+    impl = _paged_decode_impl(impl, kq, quant)
     pack = _row_pack(q.shape[-1], kq.shape[-1])
-    if impl == "pallas" and not _pallas_tileable(
-        kq.shape[-1], kq.shape[2], kv_bits=8 if quant else 16
-    ):
-        impl = _falls_to_xla("paged decode attention", kq.shape[-1], kq.shape[2])
     if pack > 1 and impl != "xla":
         # a cache of `pack` KV heads a row: wide heads through the kernel
         out = paged_decode_attention(
             _pack_queries(q, kq.shape[0], pack), k_cache, v_cache,
             block_tables, context_lens, impl, mesh, head_axis, window,
             scale if scale is not None else q.shape[-1] ** -0.5,
-            logit_softcap,
+            logit_softcap, append,
         )
-        return _own_lanes(out, kq.shape[0], pack)
+        if append is None:
+            return _own_lanes(out, kq.shape[0], pack)
+        return _own_lanes(out[0], kq.shape[0], pack), out[1], out[2]
     if impl != "xla":
         from dynamo_tpu.ops.pallas_attention import paged_decode_attention_pallas
 
         interp = impl == "pallas_interpret"
-        ks = k_cache["s"] if quant else None
-        vs = v_cache["s"] if quant else None
+        # what rides beside the five operands every form takes: an int8
+        # cache's scale planes, or the rows the call appends (never both)
+        beside = (k_cache["s"], v_cache["s"]) if quant else (append or ())
+
+        def kernel(q_, k_, v_, bt_, cl_, *more):
+            names = ("k_scales", "v_scales") if quant else ("k_new", "v_new")
+            return paged_decode_attention_pallas(
+                q_, k_, v_, bt_, cl_, **dict(zip(names, more)),
+                window=window, scale=scale,
+                logit_softcap=logit_softcap, interpret=interp,
+            )
+
         if mesh is not None and head_axis is not None:
             cache_spec = PSpec(head_axis, None, None, None)
+            attn_spec = PSpec(None, head_axis, None)  # [B, heads, D]
             in_specs = [
-                PSpec(None, head_axis, None),  # q [B, Hq, D]
+                attn_spec,  # q [B, Hq, D]
                 cache_spec,  # k cache [Hkv, nb, bs, D]
                 cache_spec,
                 PSpec(None, None),  # block tables
                 PSpec(None),  # context lens
             ]
-            if quant:
-                in_specs += [PSpec(head_axis, None)] * 2  # scale planes
-
-            def _kern(q_, k_, v_, bt_, cl_, *scales):
-                ks_, vs_ = scales if scales else (None, None)
-                return paged_decode_attention_pallas(
-                    q_, k_, v_, bt_, cl_, k_scales=ks_, v_scales=vs_,
-                    window=window, scale=scale,
-                    logit_softcap=logit_softcap, interpret=interp,
-                )
-
-            fn = jax.shard_map(
-                _kern,
+            # scale planes [Hkv, nb], or new rows [B, Hkv, D]
+            in_specs += [PSpec(head_axis, None) if quant else attn_spec] * len(beside)
+            kernel = jax.shard_map(
+                kernel,
                 mesh=mesh,
                 in_specs=tuple(in_specs),
-                out_specs=PSpec(None, head_axis, None),
+                out_specs=(
+                    attn_spec if append is None
+                    else (attn_spec, cache_spec, cache_spec)
+                ),
                 check_vma=False,
             )
-            args = (q, kq, vq, block_tables, context_lens)
-            if quant:
-                args += (ks, vs)
-            return fn(*args)
-        return paged_decode_attention_pallas(
-            q, kq, vq, block_tables, context_lens,
-            k_scales=ks, v_scales=vs,
-            window=window, scale=scale, logit_softcap=logit_softcap,
-            interpret=interp,
-        )
+        return kernel(q, kq, vq, block_tables, context_lens, *beside)
+    assert append is None, "the XLA form appends by write_decode_kv"
     B, Hq, D = q.shape
     Hs, _, block_size, W = kq.shape
     Hkv = Hs * pack
@@ -457,6 +495,60 @@ def paged_decode_attention(
     # an idle lane (context 0) has no key: give it zeros, as the kernel does
     out = jnp.where((context_lens > 0)[:, None, None, None], out, 0.0)
     return out.reshape(B, Hq, D).astype(q.dtype)
+
+
+def decode_append_attention(
+    q: jax.Array,  # [B, Hq, D] — one new token per sequence
+    k_cache: jax.Array,  # [Hkv, num_blocks, block_size, D] (this layer),
+    # stored rows, or the int8-resident {"q", "s"} container
+    v_cache: jax.Array,  # as k_cache
+    k_new: jax.Array,  # [B, Hkv, D] the token's keys, or stored rows
+    v_new: jax.Array,  # [B, Hkv / pack, pack * D] where the cache keeps them
+    slot_indices: jax.Array,  # [B] int32 flat slot = block_id*block_size + offset
+    block_tables: jax.Array,  # [B, max_blocks] int32 block ids
+    context_lens: jax.Array,  # [B] int32 — INCLUDING the new token; 0 = the
+    # lane holds no request: it attends to nothing and its rows are zero
+    impl: Optional[str] = None,
+    mesh: Optional[jax.sharding.Mesh] = None,
+    head_axis: Optional[str] = None,
+    window: Optional[int] = None,
+    scale: Optional[float] = None,
+    logit_softcap: Optional[float] = None,
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """A decode step's cache append and its attention, one call:
+    `(attn, k_cache, v_cache)`. The meaning is `write_decode_kv` followed by
+    `paged_decode_attention`, and that pair is the form this falls to, by
+    what it sees in its input: an int8-resident cache (an append regrows a
+    block's scale, which is no row write), the XLA form, a shape the kernel
+    cannot tile.
+
+    Otherwise the paged decode kernel does both (`ops/pallas_attention.py`):
+    it already holds each lane's last page in fast memory, so it puts the
+    new row there, attends, and copies the page back, where the pair ran a
+    scatter program of `B x Hkv` row writes a plane before the kernel
+    (PERF.md section 6, PR 47: 2 ms of a 14 ms step). The kernel finds the
+    row by `context_lens - 1` through the lane's table; `slot_indices` must
+    name the same slot for every live lane, as every step program's does.
+    The two forms differ in one place: the pair writes an idle lane's row
+    into the null block (slot 0), the kernel writes nothing for it. (And
+    where two lanes name one slot, which no step program does, the pair's
+    last write wins for both while the kernel attends each over its own.)"""
+    quant = _cache_quantized(k_cache)
+    impl = _paged_decode_impl(impl, k_cache["q"] if quant else k_cache, quant)
+    if quant or impl == "xla":
+        k_cache, v_cache = write_decode_kv(
+            k_cache, v_cache, k_new, v_new, slot_indices
+        )
+        attn = paged_decode_attention(
+            q, k_cache, v_cache, block_tables, context_lens, impl, mesh,
+            head_axis, window, scale, logit_softcap,
+        )
+        return attn, k_cache, v_cache
+    _note_kv_append(folded=True)
+    return paged_decode_attention(
+        q, k_cache, v_cache, block_tables, context_lens, impl, mesh,
+        head_axis, window, scale, logit_softcap, append=(k_new, v_new),
+    )
 
 
 def paged_verify_attention(
@@ -717,6 +809,7 @@ def write_decode_kv(
     it grows), so decode/verify/packed writes stay duplicate-safe."""
     from dynamo_tpu.ops.kv_quant import scatter_token_rows, write_tokens_quant
 
+    _note_kv_append(folded=False)
     if _cache_quantized(k_cache):
         return (
             write_tokens_quant(k_cache, k_new, slot_indices),

@@ -132,7 +132,16 @@ def scatter_token_rows(
     The form matters, not only the result: with the indexed dimension
     major, XLA scatters into the donated buffer where it lies; with slots
     indexed behind the head axis (`flat.at[:, slots]`) it re-lays the whole
-    layer before and after every write (PERF.md section 6, PR 26)."""
+    layer before and after every write (PERF.md section 6, PR 26).
+
+    Who still calls it (through `ops.attention.write_decode_kv`): the
+    programs that write many tokens a lane or run no decode kernel (packed
+    and chunked prefill, the verify window, `parallel/pipeline.py`), the
+    int8-resident append below, and a decode step whose attention is the
+    XLA form. A decode step through the paged kernel does not: XLA writes
+    the `T x Hkv` rows one after another, 2 ms of a 14 ms step at 64 lanes
+    and 8 KV heads in 32 layers, so the kernel appends the row itself
+    (`ops.attention.decode_append_attention`; PERF.md section 6, PR 47)."""
     Hkv, nb, bs, D = pages.shape
     N = nb * bs
     rows = (

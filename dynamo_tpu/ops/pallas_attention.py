@@ -23,6 +23,17 @@ streams exactly the blocks named by each sequence's block table:
   of zeros (PERF.md section 6, PR 29: what a grid of lanes x KV heads and
   idle lanes at a context of 1 cost).
 
+  decode with the append folded in (`k_new`/`v_new` given): the step's new
+  key and value rows reach the cache inside this call and not by a scatter
+  program before it. A live lane's last page is always in the last chunk
+  it fetches, so the cell puts the new row into that page's strip in VMEM
+  (an iota compare on the sublane index) before the products, attends over
+  it, and copies the strip back to the page in HBM from staging rows of
+  its own; the caches come back as outputs aliased onto their inputs. The
+  write is waited for two lanes later (or in the last cell), never by the
+  lane that started it. An idle lane writes nothing. The staging rows and
+  their flags live across cells, so this form's grid is "arbitrary".
+
   verify: the same walk with grid = (B, Hkv), one cell a lane and KV head,
   each (head, page) one contiguous tile.
 
@@ -131,7 +142,12 @@ def _decode_kernel(
     #         (int8 mantissas in quantized mode — bf16 pages never touch
     #         HBM; dequant happens on the VMEM tile inside this loop)
     # v_hbm
+    # kn_ref  [lanes_per_block, Hkv * D] VMEM — append only: the new key
+    #         rows of this lane's block of lanes, a lane a sublane
+    # vn_ref
     # o_ref   [1, Hkv, G, D] blocked output
+    # k_out   append only: the caches again, aliased onto k_hbm / v_hbm;
+    # v_out   pages are read through the inputs and written through these
     # scratch (in *refs):
     # k_buf   [2, Hkv, W*block_size, D] VMEM — double-buffered pages
     # v_buf
@@ -139,21 +155,33 @@ def _decode_kernel(
     # m_ref   [Hkv, G, 128] f32 — running max (replicated over lanes)
     # l_ref   [Hkv, G, 128] f32 — running sum
     # acc_ref [Hkv, G, D] f32 — running weighted values
+    # append only, all kept from one cell to the next:
+    # k_stage [2, Hkv, block_size, D] VMEM — the page a lane writes back
+    # v_stage
+    # wsems   DMA semaphores [2 stages, 2 (k/v)]
+    # pending [2] int32 SMEM — 1 while a stage's write-back is not waited for
     block_size: int,
     pages_per_chunk: int,
     scale: float,
     window: Optional[int],
     softcap: Optional[float],
     quantized: bool = False,
+    append: bool = False,
 ):
     if quantized:
         ks_ref, vs_ref = refs[0], refs[1]
         refs = refs[2:]
     else:
         ks_ref = vs_ref = None
-    (q_ref, k_hbm, v_hbm, o_ref,
-     k_buf, v_buf, sems, m_ref, l_ref, acc_ref) = refs
-    Hkv = q_ref.shape[1]
+    if append:
+        (q_ref, k_hbm, v_hbm, kn_ref, vn_ref, o_ref, k_out, v_out,
+         k_buf, v_buf, sems, m_ref, l_ref, acc_ref,
+         k_stage, v_stage, wsems, pending) = refs
+    else:
+        (q_ref, k_hbm, v_hbm, o_ref,
+         k_buf, v_buf, sems, m_ref, l_ref, acc_ref) = refs
+    Hkv, D = q_ref.shape[1], q_ref.shape[3]
+    lanes_per_block = kn_ref.shape[0] if append else None
     b = pl.program_id(0)
     ctx_len = context_lens_ref[b]
     W = pages_per_chunk
@@ -193,6 +221,66 @@ def _decode_kernel(
             dma(c, slot, i, k_buf, k_hbm, 0).start()
             dma(c, slot, i, v_buf, v_hbm, 1).start()
 
+    def write_back(stage, page):
+        # the staged page's strips, every KV head under one descriptor each
+        return (
+            pltpu.make_async_copy(
+                k_stage.at[stage], k_out.at[:, page], wsems.at[stage, 0]
+            ),
+            pltpu.make_async_copy(
+                v_stage.at[stage], v_out.at[:, page], wsems.at[stage, 1]
+            ),
+        )
+
+    def settle(stage):
+        # wait for the write-back a stage still has in flight (the wait
+        # reads the semaphore and the size, not the page)
+        @pl.when(pending[stage] == 1)
+        def _():
+            for copy in write_back(stage, 0):
+                copy.wait()
+            pending[stage] = 0
+
+    def put_new_row(c, slot):
+        # the new token's row into its page's strip, in the chunk just
+        # fetched (the products read it there) and in this lane's stage,
+        # which is copied to the page in HBM beside the lane's products
+        stage = b % 2
+        settle(stage)  # the lane two before this one, long done
+        start = pl.multiple_of((last_page - c * W) * block_size, block_size)
+        offset = (ctx_len - 1) % block_size
+        for buf, new_ref, staged in (
+            (k_buf, kn_ref, k_stage), (v_buf, vn_ref, v_stage)
+        ):
+            # a head at a time. The rows arrive as the projection lays
+            # them, [lanes, Hkv * D] with a lane a sublane, `lanes_per_block`
+            # lanes a block (a relayout to a lane's own [Hkv, D] tile would
+            # be an XLA copy a layer): this lane's row is picked by a
+            # masked maximum over the block's sublanes (against -inf the
+            # row comes back bit for bit, a zero of either sign too)
+            mine = lax.broadcasted_iota(
+                jnp.int32, (lanes_per_block, D), 0
+            ) == b % lanes_per_block
+            for h in range(Hkv):
+                rows = new_ref[:, h * D:(h + 1) * D].astype(jnp.float32)
+                new = jnp.max(
+                    jnp.where(mine, rows, -jnp.inf), axis=0, keepdims=True
+                ).astype(buf.dtype)  # [1, D]
+                strip = buf[slot, h, pl.ds(start, block_size), :]
+                row = lax.broadcasted_iota(jnp.int32, strip.shape, 0)
+                strip = jnp.where(row == offset, new, strip)
+                buf[slot, h, pl.ds(start, block_size), :] = strip
+                staged[stage, h] = strip
+        for copy in write_back(stage, block_tables_ref[b, last_page]):
+            copy.start()
+        pending[stage] = 1
+
+    if append:
+        @pl.when(b == 0)
+        def _first_cell():
+            pending[0] = 0
+            pending[1] = 0
+
     @pl.when(ctx_len <= 0)
     def _idle():  # no request in the lane: no page is read, the rows are zero
         o_ref[...] = jnp.zeros_like(o_ref)
@@ -204,16 +292,24 @@ def _decode_kernel(
         acc_ref[...] = jnp.zeros_like(acc_ref)
         issue(c_start, c_start % 2)
 
-        def loop_body(c, _):
+        def fold_chunk(c, last: Optional[bool]):
+            # `last`: None where the loop does not know (it asks the
+            # counter before it prefetches), else whether `c` is the
+            # lane's last chunk, the one that holds the new row's page
             slot = c % 2
 
-            @pl.when(c + 1 < n_chunks)
-            def _prefetch():
+            if last is None:
+                @pl.when(c + 1 < n_chunks)
+                def _prefetch():
+                    issue(c + 1, (c + 1) % 2)
+            elif not last:
                 issue(c + 1, (c + 1) % 2)
 
             for i in range(W):
                 dma(c, slot, i, k_buf, k_hbm, 0).wait()
                 dma(c, slot, i, v_buf, v_hbm, 1).wait()
+            if last:
+                put_new_row(c, slot)
 
             q = q_ref[0].astype(key_dtype)  # [Hkv, G, D]
             k = k_buf[slot].astype(key_dtype)  # [Hkv, W*bs, D]
@@ -264,8 +360,22 @@ def _decode_kernel(
             l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
             return 0
 
-        lax.fori_loop(c_start, n_chunks, loop_body, 0)
+        if append:
+            lax.fori_loop(
+                c_start, n_chunks - 1, lambda c, _: fold_chunk(c, False), 0
+            )
+            fold_chunk(n_chunks - 1, True)
+        else:
+            lax.fori_loop(
+                c_start, n_chunks, lambda c, _: fold_chunk(c, None), 0
+            )
         o_ref[0] = (acc_ref[...] / l_ref[:, :, :1]).astype(o_ref.dtype)
+
+    if append:
+        @pl.when(b == pl.num_programs(0) - 1)
+        def _last_cell():  # nothing is in flight when the call returns
+            settle(0)
+            settle(1)
 
 
 def paged_decode_attention_pallas(
@@ -276,6 +386,8 @@ def paged_decode_attention_pallas(
     context_lens: jax.Array,  # [B] int32, INCLUDING the token just written;
     # 0 = the lane holds no request and gets zeros
     *,
+    k_new: Optional[jax.Array] = None,  # [B, Hkv, D]: the token's rows,
+    v_new: Optional[jax.Array] = None,  # appended by this call when given
     k_scales: Optional[jax.Array] = None,  # [Hkv, num_blocks] f32 — int8
     v_scales: Optional[jax.Array] = None,  # resident cache when given
     pages_per_chunk: int = DECODE_PAGES_PER_CHUNK,
@@ -283,18 +395,28 @@ def paged_decode_attention_pallas(
     scale: Optional[float] = None,
     logit_softcap: Optional[float] = None,
     interpret: bool = False,
-) -> jax.Array:
+):
     """Flash paged decode attention; numerics match the XLA reference for
     every feature combination (window / scale / softcap).
 
     With `k_scales`/`v_scales`, the cache holds int8 mantissas: the kernel
     DMAs the int8 pages (half the HBM traffic) and multiplies each page's
     scalar-prefetched scale onto the VMEM tile inside the online-softmax
-    loop — bf16 K/V never materializes in HBM."""
+    loop — bf16 K/V never materializes in HBM.
+
+    With `k_new`/`v_new` (a plain cache only) the call also appends: each
+    live lane's row goes to position `context_lens - 1` of its table, the
+    lane attends over it, and the result is `(attn, k_cache, v_cache)`
+    with the caches aliased onto the arguments: written where they lie
+    when the caller donates them. What `ops.attention.write_decode_kv`
+    followed by this call without the rows gives, but for the null block,
+    which an idle lane no longer writes."""
     B, Hq, D = q.shape
     Hkv, num_blocks, block_size, _ = k_cache.shape
     G = Hq // Hkv
     quantized = k_scales is not None
+    append = k_new is not None
+    assert not (append and quantized), "an int8 page's scale is not a row"
     max_blocks = block_tables.shape[1]
     W = max(1, min(pages_per_chunk, max_blocks))
     sc = float(scale) if scale is not None else 1.0 / float(D) ** 0.5
@@ -302,24 +424,38 @@ def paged_decode_attention_pallas(
     def lane(b, *prefetch):  # units are blocks: one lane, all of its heads
         return (b, 0, 0, 0)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4 if quantized else 2,
-        grid=(B,),
-        in_specs=[
-            pl.BlockSpec((1, Hkv, G, D), lane),
-            pl.BlockSpec(memory_space=pl.ANY),  # K cache stays in HBM
-            pl.BlockSpec(memory_space=pl.ANY),  # V cache stays in HBM
-        ],
-        out_specs=pl.BlockSpec((1, Hkv, G, D), lane),
-        scratch_shapes=[
-            pltpu.VMEM((2, Hkv, W * block_size, D), k_cache.dtype),
-            pltpu.VMEM((2, Hkv, W * block_size, D), v_cache.dtype),
-            pltpu.SemaphoreType.DMA((2, 2, W)),
-            pltpu.VMEM((Hkv, G, 128), jnp.float32),
-            pltpu.VMEM((Hkv, G, 128), jnp.float32),
-            pltpu.VMEM((Hkv, G, D), jnp.float32),
-        ],
-    )
+    in_specs = [
+        pl.BlockSpec((1, Hkv, G, D), lane),
+        pl.BlockSpec(memory_space=pl.ANY),  # K cache stays in HBM
+        pl.BlockSpec(memory_space=pl.ANY),  # V cache stays in HBM
+    ]
+    out_specs = pl.BlockSpec((1, Hkv, G, D), lane)
+    out_shape = jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype)
+    scratch_shapes = [
+        pltpu.VMEM((2, Hkv, W * block_size, D), k_cache.dtype),
+        pltpu.VMEM((2, Hkv, W * block_size, D), v_cache.dtype),
+        pltpu.SemaphoreType.DMA((2, 2, W)),
+        pltpu.VMEM((Hkv, G, 128), jnp.float32),
+        pltpu.VMEM((Hkv, G, 128), jnp.float32),
+        pltpu.VMEM((Hkv, G, D), jnp.float32),
+    ]
+    if append:
+        # the new rows, eight lanes a block (one tile of sublanes; a batch
+        # that is no multiple of eight is one block)
+        R = 8 if B % 8 == 0 else B
+        in_specs += [pl.BlockSpec((R, Hkv * D), lambda b, *_: (b // R, 0))] * 2
+        out_specs = [out_specs] + [pl.BlockSpec(memory_space=pl.ANY)] * 2
+        out_shape = [
+            out_shape,
+            jax.ShapeDtypeStruct(k_cache.shape, k_cache.dtype),
+            jax.ShapeDtypeStruct(v_cache.shape, v_cache.dtype),
+        ]
+        scratch_shapes += [
+            pltpu.VMEM((2, Hkv, block_size, D), k_cache.dtype),
+            pltpu.VMEM((2, Hkv, block_size, D), v_cache.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((2,), jnp.int32),
+        ]
     kernel = pl.pallas_call(
         functools.partial(
             _decode_kernel,
@@ -329,11 +465,23 @@ def paged_decode_attention_pallas(
             window=int(window) if window is not None else None,
             softcap=float(logit_softcap) if logit_softcap is not None else None,
             quantized=quantized,
+            append=append,
         ),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4 if quantized else 2,
+            grid=(B,),
+            in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=scratch_shapes,
+        ),
+        out_shape=out_shape,
+        # operands are counted with the two scalar-prefetch ones: the
+        # caches are the fourth and fifth, and outputs one and two
+        input_output_aliases={3: 1, 4: 2} if append else {},
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",),
+            # a write-back is waited for by a later cell: the cells of the
+            # appending form run in order on one core
+            dimension_semantics=("arbitrary" if append else "parallel",),
         ),
         interpret=interpret,
     )
@@ -346,8 +494,15 @@ def paged_decode_attention_pallas(
         prefetch += [
             k_scales.astype(jnp.float32), v_scales.astype(jnp.float32)
         ]
-    out = run_kernel(kernel, *prefetch, q_grouped, k_cache, v_cache)
-    return out.reshape(B, Hq, D)
+    if not append:
+        out = run_kernel(kernel, *prefetch, q_grouped, k_cache, v_cache)
+        return out.reshape(B, Hq, D)
+    out, k_cache, v_cache = run_kernel(
+        kernel, *prefetch, q_grouped, k_cache, v_cache,
+        k_new.astype(k_cache.dtype).reshape(B, Hkv * D),
+        v_new.astype(v_cache.dtype).reshape(B, Hkv * D),
+    )
+    return out.reshape(B, Hq, D), k_cache, v_cache
 
 
 # ---------------------------------------------------------- paged verify

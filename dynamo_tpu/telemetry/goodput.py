@@ -176,8 +176,14 @@ def long_part(parts: dict[str, float]) -> str:
 # module and for the backend (a compile, or the read of a cached one), and
 # how many distinct layer bodies (`models.layer_body`) its programs called,
 # each lowered once whatever the depth; the rest of the label's `compile_s`
-# is argument handling, the upload, the run and the fetch
-FIRST_DISPATCH_FIELDS = ("trace_s", "lower_s", "backend_s", "layer_bodies")
+# is argument handling, the upload, the run and the fetch. And of the
+# layers its programs called, how many append a decode token's keys and
+# values to their cache inside the paged decode kernel and how many by the
+# row scatter before it (`models.kv_appends_called`)
+FIRST_DISPATCH_FIELDS = (
+    "trace_s", "lower_s", "backend_s", "layer_bodies",
+    "kv_append_folded", "kv_append_scattered",
+)
 
 _JAX_STAGES = {
     "/jax/core/compile/jaxpr_trace_duration": "trace_s",
@@ -207,7 +213,7 @@ def first_dispatch_split(into: dict):
     global _listening
     import jax.monitoring
 
-    from dynamo_tpu.models import layer_bodies_called
+    from dynamo_tpu.models import kv_appends_called, layer_bodies_called
 
     with _listener_lock:
         if not _listening:
@@ -222,6 +228,7 @@ def first_dispatch_split(into: dict):
         for stage, kept in spans.items():
             into[stage] = sum(end - start for start, end in kept)
         into["layer_bodies"] = len(bodies)
+        into.update(kv_appends_called())
 
 
 class GoodputStats:

@@ -42,7 +42,7 @@ SAMPLING = {
 }
 
 
-def make_engine(num_blocks=64, decode_horizon=4):
+def make_engine(num_blocks=64, decode_horizon=4, attn_impl="auto"):
     """A two-layer toy at horizon 4 with mixed steps and 8-token chunks, in
     float32: there the CPU's compiler gives both trees one program, bit for
     bit. (A bfloat16 toy's tokens are not the parent's on the CPU: XLA drops
@@ -58,6 +58,7 @@ def make_engine(num_blocks=64, decode_horizon=4):
     runner = ModelRunner(
         cfg, params, num_blocks=num_blocks, block_size=4, max_batch=4,
         max_model_len=64, prefill_chunk_tokens=8, kv_dtype=jnp.float32,
+        attn_impl=attn_impl,
     )
     return JaxEngine(runner, JaxEngineConfig(
         max_batch=4, block_size=4, num_blocks=num_blocks, max_model_len=64,
@@ -149,6 +150,29 @@ def test_the_ledger_splits_a_first_dispatch():
     assert mixed == {2}, bodies
 
 
+@pytest.mark.parametrize("attn_impl,folded", [("pallas_interpret", 2), ("auto", 0)])
+def test_the_ledger_says_where_each_program_appends(attn_impl, folded):
+    """`kv_append_folded` and `kv_append_scattered` of a label's first
+    dispatch: through the kernel, the toy's horizon appends inside the paged
+    decode call in both of its layers (counted once for the four steps) and
+    its packed prefill, which writes many tokens a lane, scatters in both;
+    on the CPU's default, the XLA form, the entry falls to the pair and the
+    horizon scatters too."""
+    async def run():
+        engine = make_engine(attn_impl=attn_impl)
+        await collect(engine, request([1, 2, 3], 12, SamplingOptions(greedy=True)))
+        split = engine.stats.goodput.summary()["first_dispatch_by_label"]
+        await engine.close()
+        return split
+
+    jax.clear_caches()  # a body traced by an earlier test counts from its memo
+    split = asyncio.run(run())
+    appends = lambda label: (split[label]["kv_append_folded"], split[label]["kv_append_scattered"])
+    assert appends("decode_multi@H4B4") == (folded, 2 - folded)
+    assert appends("prefill_packed") == (0, 2)
+    assert all(tuple(fields) == FIRST_DISPATCH_FIELDS for fields in split.values())
+
+
 def test_a_nested_trace_counts_once():
     """JAX reports a jitted function traced inside another before the outer
     one, whose span covers it: the split keeps the outer's seconds alone,
@@ -168,13 +192,16 @@ def test_a_nested_trace_counts_once():
 
 def test_the_split_merges_and_crosses_the_wire():
     a, b = GoodputLedger(enabled=True), GoodputLedger(enabled=True)
-    a.record_compile("decode", 12.0, {"trace_s": 1.0, "lower_s": 0.5, "backend_s": 9.0, "layer_bodies": 1})
-    b.record_compile("decode", 11.0, {"trace_s": 2.0, "lower_s": 0.25, "backend_s": 8.0, "layer_bodies": 1})
+    a.record_compile("decode", 12.0, {"trace_s": 1.0, "lower_s": 0.5, "backend_s": 9.0, "layer_bodies": 1, "kv_append_folded": 32})
+    b.record_compile("decode", 11.0, {"trace_s": 2.0, "lower_s": 0.25, "backend_s": 8.0, "layer_bodies": 1, "kv_append_scattered": 2})
     b.record_compile("prefill_packed", 3.0)  # no split given: none kept
     merged = GoodputStats.from_dict(a.to_dict())
     merged.merge(GoodputStats.from_dict(b.to_dict()))
     assert merged.first_dispatch_by_label == {
-        "decode": {"trace_s": 2.0, "lower_s": 0.5, "backend_s": 9.0, "layer_bodies": 1.0},
+        "decode": {
+            "trace_s": 2.0, "lower_s": 0.5, "backend_s": 9.0, "layer_bodies": 1.0,
+            "kv_append_folded": 32.0, "kv_append_scattered": 2.0,
+        },
     }
     assert merged.summary()["first_dispatch_by_label"]["decode"]["trace_s"] == 2.0
     off = GoodputLedger(enabled=False)

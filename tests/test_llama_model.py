@@ -318,11 +318,12 @@ def test_a_slot_in_the_null_block_is_a_context_of_zero(monkeypatch, family, tiny
     if family == "llama":
         cfg, params = tiny_setup
         kc, vc = _empty_cache(cfg, block_size=bs)
-        real = L.paged_decode_attention
+        real = L.decode_append_attention  # the append and the attention
         monkeypatch.setattr(
-            L, "paged_decode_attention",
-            lambda q, k, v, bt, ctx, **kw: (
-                seen.append(np.asarray(ctx)), real(q, k, v, bt, ctx, **kw)
+            L, "decode_append_attention",
+            lambda q, k, v, kn, vn, sl, bt, ctx, **kw: (
+                seen.append(np.asarray(ctx)),
+                real(q, k, v, kn, vn, sl, bt, ctx, **kw),
             )[1],
         )
         with jax.disable_jit():  # the spy reads values: run each layer's body

@@ -324,3 +324,140 @@ def test_a_shape_that_falls_to_xla_says_so_once(caplog):
     assert A._pallas_tileable(128, 16) and not A._pallas_tileable(64, 16)
     with pytest.raises(ValueError, match="do not hold whole heads"):
         A.paged_decode_attention(q, _rand(keys[1], (1, nb, bs, 96)), vc, bt, cl)
+
+
+# ------------------------- the append inside the decode kernel (PR 47)
+#
+# `A.decode_append_attention`'s kernel form against the pair it stands for,
+# `write_decode_kv` then `paged_decode_attention` in the XLA form. Pages of
+# 16 tokens, eight a chunk; lane -> context INCLUDING the new token, 0 = idle.
+
+_APPEND_CASES = {
+    "8 of 32 heads": dict(hq=32, hkv=8),
+    "4 of 28 heads": dict(hq=28, hkv=4),
+    "1 of 20 heads": dict(hq=20, hkv=1),
+    "paired 64-wide heads": dict(hq=32, hkv=8, D=64, pack=2),
+    "a window shorter than the context": dict(window=24, lens=(70, 150, 25, 9)),
+    "a softcap": dict(softcap=5.0),
+    "a context of 1": dict(lens=(1, 1, 40, 1)),
+    "a write at offset 0": dict(lens=(17, 33, 1, 129)),
+    "a write at offset 15": dict(lens=(16, 32, 128, 144)),
+    "the new token opens a chunk": dict(lens=(8 * 16 + 1, 2 * 8 * 16 + 1, 5, 8 * 16)),
+    "idle lanes among live ones": dict(lens=(0, 37, 0, 0, 130, 0, 16, 0)),
+}
+
+
+def _append_inputs(dtype, hq=8, hkv=2, D=32, pack=1, lens=(37, 70, 16, 131), seed=0):
+    bs, W = 16, 8
+    B, mb = len(lens), max(-(-max(lens) // bs), 1) + 1
+    nb = 1 + sum(-(-n // bs) for n in lens) + 3  # null, the lanes', three no one's
+    keys = jax.random.split(jax.random.PRNGKey(seed), 6)
+    q = _rand(keys[0], (B, hq, D), dtype)
+    stored = (hkv // pack, nb, bs, pack * D)
+    k_cache, v_cache = _rand(keys[1], stored, dtype), _rand(keys[2], stored, dtype)
+    k_new = _rand(keys[3], (B, hkv // pack, pack * D), dtype)
+    v_new = _rand(keys[4], (B, hkv // pack, pack * D), dtype)
+    # zeros of both signs among the new values: the caches are held to bits
+    k_new = k_new.at[:, :, 0].set(-0.0).at[:, :, 1].set(0.0)
+    pages = np.asarray(jax.random.permutation(keys[5], nb - 1)) + 1
+    tables, slots, at = np.zeros((B, mb), np.int32), np.zeros(B, np.int32), 0
+    for b, n in enumerate(lens):  # an idle lane: a table of zeros, slot 0
+        used = -(-n // bs)
+        tables[b, :used] = pages[at:at + used]
+        at += used
+        if n:
+            slots[b] = tables[b, (n - 1) // bs] * bs + (n - 1) % bs
+    return (
+        q, k_cache, v_cache, k_new, v_new, jnp.asarray(slots),
+        jnp.asarray(tables), jnp.asarray(lens, jnp.int32),
+    )
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", list(_APPEND_CASES))
+def test_decode_append_inside_the_kernel_is_the_pair(case, dtype):
+    """The caches hold, bit for bit, what the scatter wrote in every block a
+    live lane owns; the null block and every block no lane owns are as they
+    were (the pair writes an idle lane's row into the null block: the one
+    place the two differ); the rows that changed are the live lanes' slots
+    and no others; the attention is the pair's within the file's tolerance,
+    and an idle lane's is zeros."""
+    spec = dict(_APPEND_CASES[case])
+    kw = dict(window=spec.pop("window", None), logit_softcap=spec.pop("softcap", None))
+    inputs = _append_inputs(dtype, **spec)
+    q, k_cache, v_cache, k_new, v_new, slots, tables, lens = inputs
+    with A.kv_appends_traced() as counted:
+        out, k_got, v_got = A.decode_append_attention(*inputs, impl="pallas_interpret", **kw)
+    assert counted == [1, 0]
+    k_ref, v_ref = A.write_decode_kv(k_cache, v_cache, k_new, v_new, slots)
+    ref = A.paged_decode_attention(q, k_ref, v_ref, tables, lens, impl="xla", **kw)
+
+    live = np.asarray(lens) > 0
+    bs = k_cache.shape[2]
+    for got, want, was in ((k_got, k_ref, k_cache), (v_got, v_ref, v_cache)):
+        got, want, was = (np.asarray(x, np.float32) for x in (got, want, was))
+        assert np.array_equal(got[:, 1:], want[:, 1:])
+        assert np.array_equal(np.signbit(got[:, 1:]), np.signbit(want[:, 1:]))
+        assert np.array_equal(got[:, 0], was[:, 0])  # the null block
+        changed = np.argwhere((got != was).any(axis=(0, 3)))  # (block, offset)
+        assert sorted(int(b * bs + o) for b, o in changed) == sorted(
+            int(s) for s in np.asarray(slots)[live]
+        )
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
+    np.testing.assert_allclose(out[live], ref[live], atol=tol, rtol=tol)
+    assert (out[~live] == 0).all()
+
+
+@pytest.mark.parametrize("form", ["an int8-resident cache", "the XLA form", "an untileable shape"])
+def test_decode_append_falls_to_the_pair_by_what_it_sees(form):
+    """No flag: an int8-resident cache (an append regrows a block's scale),
+    `impl="xla"`, and rows the kernel cannot tile go through the entry to
+    `write_decode_kv` and `paged_decode_attention` and give what they give,
+    the null block's row with it."""
+    from dynamo_tpu.ops.kv_quant import quantize_blocks
+
+    inputs = _append_inputs(
+        jnp.bfloat16, lens=(37, 0, 16, 131), D=64 if form == "an untileable shape" else 32
+    )
+    q, k_cache, v_cache, k_new, v_new, slots, tables, lens = inputs
+    impl = {"an int8-resident cache": "pallas_interpret", "the XLA form": "xla", "an untileable shape": "pallas"}[form]
+    if form == "an int8-resident cache":
+        k_cache, v_cache = quantize_blocks(k_cache), quantize_blocks(v_cache)
+    with A.kv_appends_traced() as counted:
+        out, k_got, v_got = A.decode_append_attention(
+            q, k_cache, v_cache, k_new, v_new, slots, tables, lens, impl=impl
+        )
+    assert counted == [0, 1]
+    k_ref, v_ref = A.write_decode_kv(k_cache, v_cache, k_new, v_new, slots)
+    ref = A.paged_decode_attention(q, k_ref, v_ref, tables, lens, impl=impl)
+    same = lambda a, b: np.array_equal(np.asarray(a, np.float32), np.asarray(b, np.float32))
+    assert same(out, ref)
+    assert jax.tree.all(jax.tree.map(same, (k_got, v_got), (k_ref, v_ref)))
+
+
+def test_decode_append_under_jit_with_the_caches_donated():
+    """As a step program calls it: jitted, the two caches donated, a second
+    step on the first one's result."""
+    inputs = _append_inputs(jnp.bfloat16, lens=(15, 0, 31, 130))
+    q, k_cache, v_cache, k_new, v_new, slots, tables, lens = inputs
+    step = jax.jit(
+        lambda k, v, n, s: A.decode_append_attention(
+            q, k, v, k_new, v_new, s, tables, n, impl="pallas_interpret"
+        ), donate_argnums=(0, 1),
+    )
+    live = lens > 0
+    k_ref, v_ref, outs, refs = k_cache, v_cache, [], []
+    k_got, v_got = jnp.copy(k_cache), jnp.copy(v_cache)
+    for _ in range(2):
+        out, k_got, v_got = step(k_got, v_got, lens, slots)
+        k_ref, v_ref = A.write_decode_kv(k_ref, v_ref, k_new, v_new, slots)
+        refs.append(A.paged_decode_attention(q, k_ref, v_ref, tables, lens, impl="xla"))
+        outs.append(out)
+        lens, slots = jnp.where(live, lens + 1, 0), jnp.where(live, slots + 1, 0)
+    assert np.array_equal(np.asarray(k_got[:, 1:], np.float32), np.asarray(k_ref[:, 1:], np.float32))
+    assert np.array_equal(np.asarray(v_got[:, 1:], np.float32), np.asarray(v_ref[:, 1:], np.float32))
+    for out, ref in zip(outs, refs):
+        np.testing.assert_allclose(
+            np.asarray(out, np.float32), np.asarray(ref, np.float32), atol=2e-2, rtol=2e-2
+        )

@@ -221,9 +221,12 @@ def test_gemma3_pattern_end_to_end_all_layers_flash(monkeypatch):
             params, c, tokens, jnp.int32(P), kc, vc, table
         )
         # one decode step for a 2-lane batch on top of the same prompt
+        # (the lanes share the prompt's pages and open a page each: two
+        # lanes never write one slot, and where they did the scatter let the
+        # last one win while the kernel's append keeps each lane's own row)
         bt = jnp.tile(
             jnp.arange(1, 1 + nb - 1, dtype=jnp.int32)[None, :], (2, 1)
-        )
+        ).at[1, P // bs].set(nb - 1)
         positions = jnp.array([P, P], jnp.int32)
         slots = bt[jnp.arange(2), positions // bs] * bs + positions % bs
         logits_d, kc, vc = L.decode(

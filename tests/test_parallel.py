@@ -97,8 +97,10 @@ def test_pallas_shard_map_attention_matches_xla():
     assert all(c.sharding.spec == kv_sharding.spec for c in kc_pl)
 
     # decode step: pallas shard_map vs the unsharded xla reference
-    bt = jnp.zeros((1, 4), jnp.int32).at[0, :2].set(table)
-    slot = jnp.array([2 * 4 + 0], jnp.int32)
+    # position 8 opens the table's third block: the slot names the place
+    # the table gives (the kernel finds the row through the table)
+    bt = jnp.zeros((1, 4), jnp.int32).at[0, :3].set(jnp.array([1, 2, 3]))
+    slot = jnp.array([3 * 4 + 0], jnp.int32)
     logits_d_ref, _, _ = L.decode(
         params, cfg, jnp.array([3], jnp.int32), jnp.array([8], jnp.int32),
         kc_ref, vc_ref, bt, slot,

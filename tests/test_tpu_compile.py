@@ -116,6 +116,38 @@ def test_paged_decode_kernel(one_chip, hq, hkv, window):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("window", [None, WINDOW])
+@pytest.mark.parametrize("hq,hkv", DECODE_HEADS)
+def test_paged_decode_kernel_that_appends(one_chip, hq, hkv, window):
+    """The call with the step's new rows (PR 47): a page's strip read, changed
+    and copied back under one descriptor, the rows taken as the projection
+    lays them (`[B, Hkv * D]`, eight lanes a block), the caches aliased onto
+    the call's operands. (A one-row copy into a page Mosaic refuses for
+    bfloat16: "Slice shape along dimension 2 must be aligned to tiling (8),
+    but is 1", read here in PR 47; so the page goes back whole.)"""
+    from dynamo_tpu.ops.pallas_attention import paged_decode_attention_pallas
+
+    nb = 1024
+    fn = jax.jit(
+        lambda q, k, v, bt, cl, kn, vn: paged_decode_attention_pallas(
+            q, k, v, bt, cl, k_new=kn, v_new=vn, window=window
+        ), donate_argnums=(1, 2),
+    )
+    text = fn.lower(
+        one_chip((B, hq, D), BF16),
+        one_chip((hkv, nb, BLOCK, D), BF16),
+        one_chip((hkv, nb, BLOCK, D), BF16),
+        one_chip((B, CONTEXT // BLOCK), I32),
+        one_chip((B,), I32),
+        one_chip((B, hkv, D), BF16),
+        one_chip((B, hkv, D), BF16),
+    ).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "output_to_operand_aliasing={{1}: (3, {}), {2}: (4, {})}" in text
+    assert _aliased_parameters(text) == {1, 2}  # the donated caches
+    assert _pool_sized_movers(text, hkv * nb * BLOCK * D) == []
+
+
 def test_paged_verify_kernel(one_chip):
     from dynamo_tpu.ops.pallas_attention import paged_verify_attention_pallas
 
@@ -756,11 +788,23 @@ def test_latent_expert_decode_multi_program_one_chip(one_chip):
 # instructions of the compiled module, the digest of its histogram by
 # operation, temporaries and aliased bytes, and a few telling operations.
 
+#
+# The two programs that decode are PR 47's, re-read by the same functions:
+# the append is inside the paged decode call, so `decode_multi` lost 16 of its
+# 17 scatters (two layers, four steps, keys and values; the one left is the
+# sampler's) and `mixed_step` 4 of 11 (its chunk still writes whole blocks);
+# the new rows reach the call as the projection lays them and are relaid
+# once a layer and step by a fusion of `bf16[64,1024]` (8 of the 63 copies
+# and fusions more; 1 us a step on the chip: PERF.md section 6, PR 47). PR
+# 37's: 7619, a044e7f020604b20, 28982784 temporaries, fusion 399, copy 55,
+# copy-start 148, slice-start 60, scatter 17; and 3858, 2aee35c5c34abc43,
+# fusion 216, copy 35, copy-start 76, scatter 11.
+
 PARENT_COMPILED = {
-    "decode_multi@H4B64": (7619, "a044e7f020604b20", 28982784, 134217728,
-        {"fusion": 399, "custom-call": 48, "convolution": 60, "copy": 55, "copy-start": 148, "slice-start": 60, "scatter": 17, "conditional": 4}),
-    "mixed_step@c1": (3858, "2aee35c5c34abc43", 293632000, 134217728,
-        {"fusion": 216, "custom-call": 30, "convolution": 33, "copy": 35, "copy-start": 76, "slice-start": 60, "scatter": 11, "conditional": 2}),
+    "decode_multi@H4B64": (7414, "98bedc1318b4d02c", 30562816, 134217728,
+        {"fusion": 381, "custom-call": 49, "convolution": 60, "copy": 63, "copy-start": 169, "slice-start": 64, "scatter": 1, "conditional": 4}),
+    "mixed_step@c1": (3783, "c9f7e94e17155491", 293632000, 134217728,
+        {"fusion": 211, "custom-call": 30, "convolution": 33, "copy": 37, "copy-start": 74, "slice-start": 60, "scatter": 7, "conditional": 2}),
     "prefill_packed@512": (2198, "ae7537686dbdb584", 9436160, 134217728,
         {"fusion": 126, "custom-call": 19, "convolution": 19, "copy": 24, "copy-start": 48, "slice-start": 44, "scatter": 6, "conditional": 1}),
     "latent decode_multi@H4B64": (10430, "4457f7d44b8a8a85", 194732544, 167772160,
