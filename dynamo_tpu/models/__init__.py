@@ -13,6 +13,7 @@ block budget and the row scatter derive from the declaration.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import functools
 import json
@@ -25,7 +26,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
-from dynamo_tpu.ops.attention import kv_appends_traced
+from dynamo_tpu.ops.basics import forms_traced
 
 
 @dataclass(frozen=True)
@@ -151,10 +152,10 @@ def layer_body(*static: str):
                 tuple(kwargs.get(k) for k in static), tree,
                 tuple((jnp.shape(a), jnp.result_type(a)) for a in leaves),
             )
-            with kv_appends_traced() as appends:
+            with forms_traced() as forms:
                 out = jitted(*args, **kwargs)
-            if any(appends):  # the body was traced (or ran) just now
-                _BODY_APPENDS[body] = tuple(appends)
+            if forms:  # the body was traced (or ran) just now
+                _BODY_FORMS[body] = forms
             seen = getattr(_watching, "bodies", None)
             if seen is not None:
                 seen.add(body)
@@ -162,7 +163,7 @@ def layer_body(*static: str):
                 # every body), which a program's every step passes again
                 params = tuple(jax.tree_util.tree_leaves(args[1]))
                 _watching.layers[(body, tuple(map(id, params)))] = (
-                    params, _BODY_APPENDS.get(body, (0, 0))
+                    params, _BODY_FORMS.get(body)
                 )
             return out
 
@@ -171,10 +172,11 @@ def layer_body(*static: str):
     return wrap
 
 
-# body (as `layer_bodies_called` keys it) -> (folded, scattered): the cache
-# appends its trace made (`ops.attention.kv_appends_traced`); a body whose
-# trace JAX has cached runs no Python, so the count is kept here
-_BODY_APPENDS: dict = {}
+# body (as `layer_bodies_called` keys it) -> what its trace counted
+# (`ops.basics.forms_traced`: the cache appends and the grouped products by
+# the form each took); a body whose trace JAX has cached runs no Python, so
+# the count is kept here
+_BODY_FORMS: dict = {}
 
 
 @contextlib.contextmanager
@@ -190,22 +192,23 @@ def layer_bodies_called():
     finally:
         _watching.bodies = _watching.layers = None
         # the counts outlive the block, the layers' parameters do not
-        counted = [c for _, c in layers.values()]
-        _watching.appends = {
-            "kv_append_folded": sum(c[0] for c in counted),
-            "kv_append_scattered": sum(c[1] for c in counted),
-        }
+        _watching.forms = sum(
+            (forms for _, forms in layers.values() if forms),
+            collections.Counter(),
+        )
 
 
-def kv_appends_called() -> dict:
-    """`kv_append_folded` and `kv_append_scattered` of the calling thread's
-    last `layer_bodies_called` block: of the distinct layers called inside it
-    (a body and its parameters: a horizon's steps call a layer again and count
-    it once), how many append a decode token's rows to their cache inside the
-    paged decode kernel, and how many by the row scatter before it."""
-    return dict(getattr(_watching, "appends", None) or {
-        "kv_append_folded": 0, "kv_append_scattered": 0,
-    })
+def forms_called() -> collections.Counter:
+    """What the layers counted that the calling thread's last
+    `layer_bodies_called` block called, by the form's name: of the distinct
+    layers (a body and its parameters: a horizon's steps call a layer again
+    and count it once), how many append a decode token's rows to their cache
+    inside the paged decode kernel (`kv_append_folded`) and how many by the
+    row scatter before it (`kv_append_scattered`); how many grouped products
+    of their experts run in the Pallas kernel (`grouped_product_kernel`) and
+    how many in XLA's (`grouped_product_xla`). A name nothing counted reads
+    0."""
+    return collections.Counter(getattr(_watching, "forms", None))
 
 
 def forward_for(config):

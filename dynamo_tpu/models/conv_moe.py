@@ -428,7 +428,8 @@ def _ffn(x, layer, cfg, valid):
         )
     with jax.named_scope("moe.experts"):
         y, group_sizes = dropless_experts(
-            h, idx, weights, layer["wg"], layer["wu"], layer["wd"], valid=valid
+            h, idx, weights, layer["wg"], layer["wu"], layer["wd"], valid=valid,
+            impl=cfg.attn_impl,
         )
     return x + y.astype(x.dtype), expert_step_stats(group_sizes)
 

@@ -505,7 +505,7 @@ def _experts(x, layer, valid, *, cfg):
     with jax.named_scope("moe.experts"):
         y, group_sizes = dropless_experts(
             v, idx, weights, None, layer["wu"], layer["wd"], valid=valid,
-            first_held=cfg.first_held_expert, form="relu2",
+            first_held=cfg.first_held_expert, form="relu2", impl=cfg.attn_impl,
         )
     with jax.named_scope("moe.latent"):
         routed = linear(y.astype(x.dtype), layer["w_fc2"])

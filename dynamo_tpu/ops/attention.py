@@ -39,14 +39,14 @@ All functions are jit-safe: static shapes, masks instead of dynamic slicing.
 
 from __future__ import annotations
 
-import contextlib
 import os
-import threading
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as PSpec
+
+from dynamo_tpu.ops.basics import note_form
 
 NEG_INF = -1e30
 
@@ -112,31 +112,6 @@ def _falls_to_xla(what: str, width: int, block_size: int) -> str:
             what, width, block_size,
         )
     return "xla"
-
-
-_appends = threading.local()
-
-
-@contextlib.contextmanager
-def kv_appends_traced():
-    """Yields `[folded, scattered]`: how many times the code traced (or run)
-    by the calling thread inside the block appended tokens' rows to a layer's
-    cache inside the paged decode kernel (`decode_append_attention`'s kernel
-    form), and how many times by the row scatter (`write_decode_kv`). A trace
-    that JAX has cached runs no Python, so a block around a cached call counts
-    nothing: `models.layer_body` keeps what each body's trace counted."""
-    before = getattr(_appends, "tally", None)
-    tally = _appends.tally = [0, 0]
-    try:
-        yield tally
-    finally:
-        _appends.tally = before
-
-
-def _note_kv_append(folded: bool) -> None:
-    tally = getattr(_appends, "tally", None)
-    if tally is not None:
-        tally[0 if folded else 1] += 1
 
 
 def _paged_decode_impl(impl: Optional[str], pages: jax.Array, quant: bool) -> str:
@@ -544,7 +519,7 @@ def decode_append_attention(
             head_axis, window, scale, logit_softcap,
         )
         return attn, k_cache, v_cache
-    _note_kv_append(folded=True)
+    note_form("kv_append_folded")
     return paged_decode_attention(
         q, k_cache, v_cache, block_tables, context_lens, impl, mesh,
         head_axis, window, scale, logit_softcap, append=(k_new, v_new),
@@ -809,7 +784,7 @@ def write_decode_kv(
     it grows), so decode/verify/packed writes stay duplicate-safe."""
     from dynamo_tpu.ops.kv_quant import scatter_token_rows, write_tokens_quant
 
-    _note_kv_append(folded=False)
+    note_form("kv_append_scattered")
     if _cache_quantized(k_cache):
         return (
             write_tokens_quant(k_cache, k_new, slot_indices),

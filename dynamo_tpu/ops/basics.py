@@ -6,6 +6,10 @@ Kept as small pure functions so XLA fuses them into the surrounding matmuls
 
 from __future__ import annotations
 
+import collections
+import contextlib
+import threading
+
 import jax
 import jax.numpy as jnp
 
@@ -80,3 +84,30 @@ def run_kernel(kernel, *operands):
         return kernel(*args)
 
     return jax.jit(tpu_custom_call)(*operands)
+
+
+_forms = threading.local()
+
+
+@contextlib.contextmanager
+def forms_traced():
+    """Yields a counter, name -> how many times the code traced (or run) by
+    the calling thread inside the block took the form of that name, where one
+    entry chooses between two by its input (`note_form`: a decode token's rows
+    appended inside the paged decode kernel or by the row scatter,
+    `ops.attention.decode_append_attention`; a grouped product in the Pallas
+    kernel or XLA's, `ops.grouped_product`). A trace that JAX has cached runs
+    no Python, so a block around a cached call counts nothing:
+    `models.layer_body` keeps what each body's trace counted."""
+    before = getattr(_forms, "tally", None)
+    tally = _forms.tally = collections.Counter()
+    try:
+        yield tally
+    finally:
+        _forms.tally = before
+
+
+def note_form(name: str) -> None:
+    tally = getattr(_forms, "tally", None)
+    if tally is not None:
+        tally[name] += 1

@@ -8,7 +8,9 @@ way, with dispatch paths chosen by regime:
   * `moe_ffn_dropless` — DROPLESS sort + grouped-GEMM (`lax.ragged_dot`)
     dispatch: assignments sorted by expert, one ragged matmul per
     projection. O(T*k) memory, no capacity tensors, exact Mixtral serving
-    semantics. The engine's default on a single chip / pure-TP mesh.
+    semantics. The engine's default on a single chip / pure-TP mesh. Its
+    routed half, `dropless_experts`, runs the products in the Pallas grouped
+    product (`ops/grouped_product.py`) where its `impl` asks for the kernels.
   * `moe_ffn_ep_a2a` — token-sharded wide-EP dispatch under shard_map
     (the DeepEP all-to-all equivalent): each ep shard routes ITS tokens,
     buckets assignments by destination shard, `lax.all_to_all` over ICI,
@@ -44,6 +46,7 @@ from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from dynamo_tpu.ops.basics import rms_norm, swiglu
+from dynamo_tpu.ops.grouped_product import grouped_product
 from dynamo_tpu.ops.linear import linear
 
 
@@ -139,13 +142,20 @@ def _grouped_ffn(
     wg: jax.Array,  # [E, D, F]
     wu: jax.Array,
     wd: jax.Array,  # [E, F, D]
+    product=lax.ragged_dot,
 ) -> jax.Array:
     """SwiGLU FFN as three grouped GEMMs (lax.ragged_dot): each contiguous
     row-group multiplies its own expert's weights — the MXU-friendly
-    dropless dispatch (MegaBlocks-style, no [T, E, C] capacity tensors)."""
-    gate = lax.ragged_dot(xs, wg, group_sizes)
-    up = lax.ragged_dot(xs, wu, group_sizes)
-    return lax.ragged_dot(swiglu(gate, up), wd, group_sizes)
+    dropless dispatch (MegaBlocks-style, no [T, E, C] capacity tensors).
+
+    `product`: `dropless_experts`, the single-chip path, gives
+    `ops.grouped_product.grouped_product`, the Pallas kernel where its `impl`
+    asks for one; `_sorted_dispatch_combine`, `moe_ffn_shard_map` and
+    `moe_ffn_ep_a2a` (Mixtral's and the meshes' paths, which no cell of the
+    benchmark runs) keep `lax.ragged_dot`."""
+    gate = product(xs, wg, group_sizes)
+    up = product(xs, wu, group_sizes)
+    return product(swiglu(gate, up), wd, group_sizes)
 
 
 def _sorted_dispatch_combine(
@@ -189,6 +199,7 @@ def dropless_experts(
     *,
     first_held: Optional[int] = None,  # `idx` names experts of a wider router
     form: str = "swiglu",  # or "relu2": two products, `wg` is None
+    impl: Optional[str] = None,  # the grouped products' form, as attention's
 ) -> tuple[jax.Array, jax.Array]:
     """The routed experts of a dropless layer for assignments already made:
     sort by expert, three grouped products, unsort, weighted sum over k.
@@ -203,6 +214,14 @@ def dropless_experts(
 
     `form`: "swiglu", `wd(silu(wg x) * (wu x))`; "relu2", `wd(relu(wu x)^2)`
     with no gate (`wg` None).
+
+    `impl`: what the family gives its attention calls (`cfg.attn_impl`, which
+    the runner pins: "pallas" on the chip, "xla" elsewhere). The products run
+    in the Pallas kernel where it says "pallas" and their shapes can be tiled,
+    else in `lax.ragged_dot` (`ops.grouped_product`, which counts the form
+    taken); with "xla", or with none given (`moe_ffn_dropless`, Mixtral's
+    path, which may run under a mesh), the program traced here is the one
+    `lax.ragged_dot` gave.
 
     A padding token (a lane that holds no request, the tail of a packed
     prompt) is given to no expert: its assignments sort behind every real
@@ -224,11 +243,15 @@ def dropless_experts(
         e_flat[:, None] == jnp.arange(E, dtype=jnp.int32)[None, :],
         axis=0, dtype=jnp.int32,
     )
+    product = (
+        lax.ragged_dot if impl is None
+        else functools.partial(grouped_product, impl=impl)
+    )
     if form == "relu2":
-        up = jax.nn.relu(lax.ragged_dot(xs, wu, group_sizes))
-        ys = lax.ragged_dot(up * up, wd, group_sizes)  # [T*k, D]
+        up = jax.nn.relu(product(xs, wu, group_sizes))
+        ys = product(up * up, wd, group_sizes)  # [T*k, D]
     else:
-        ys = _grouped_ffn(xs, group_sizes, wg, wu, wd)  # [T*k, D]
+        ys = _grouped_ffn(xs, group_sizes, wg, wu, wd, product)  # [T*k, D]
     live = jnp.arange(T * k) < jnp.sum(group_sizes)
     ys = jnp.where(live[:, None], ys.astype(jnp.float32), 0.0)
     # back to token-major by the inverse permutation: a gather and a sum

@@ -177,13 +177,17 @@ def long_part(parts: dict[str, float]) -> str:
 # how many distinct layer bodies (`models.layer_body`) its programs called,
 # each lowered once whatever the depth; the rest of the label's `compile_s`
 # is argument handling, the upload, the run and the fetch. And of the
-# layers its programs called, how many append a decode token's keys and
-# values to their cache inside the paged decode kernel and how many by the
-# row scatter before it (`models.kv_appends_called`)
+# layers its programs called (`models.forms_called`), how many append a
+# decode token's keys and values to their cache inside the paged decode
+# kernel and how many by the row scatter before it, and how many grouped
+# products of their experts run in the Pallas kernel and how many in XLA's
+LAYER_FORMS = (
+    "kv_append_folded", "kv_append_scattered",
+    "grouped_product_kernel", "grouped_product_xla",
+)
 FIRST_DISPATCH_FIELDS = (
     "trace_s", "lower_s", "backend_s", "layer_bodies",
-    "kv_append_folded", "kv_append_scattered",
-)
+) + LAYER_FORMS
 
 _JAX_STAGES = {
     "/jax/core/compile/jaxpr_trace_duration": "trace_s",
@@ -213,7 +217,7 @@ def first_dispatch_split(into: dict):
     global _listening
     import jax.monitoring
 
-    from dynamo_tpu.models import kv_appends_called, layer_bodies_called
+    from dynamo_tpu.models import forms_called, layer_bodies_called
 
     with _listener_lock:
         if not _listening:
@@ -228,7 +232,8 @@ def first_dispatch_split(into: dict):
         for stage, kept in spans.items():
             into[stage] = sum(end - start for start, end in kept)
         into["layer_bodies"] = len(bodies)
-        into.update(kv_appends_called())
+        counted = forms_called()
+        into.update({f: counted[f] for f in LAYER_FORMS})
 
 
 class GoodputStats:

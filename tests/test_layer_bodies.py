@@ -173,6 +173,54 @@ def test_the_ledger_says_where_each_program_appends(attn_impl, folded):
     assert all(tuple(fields) == FIRST_DISPATCH_FIELDS for fields in split.values())
 
 
+def make_expert_engine(attn_impl):
+    """A dense layer and an expert layer of the latent family at widths the
+    grouped product's kernel can tile (128 lanes; 16 lanes x 8 experts a
+    token and a 16-token chunk are one tile of 128 rows each)."""
+    from dynamo_tpu.engine.jax_engine.engine import JaxEngine, JaxEngineConfig
+    from dynamo_tpu.engine.jax_engine.model_runner import ModelRunner
+
+    cfg = M.MlaMoeConfig(
+        vocab_size=64, hidden_size=128, intermediate_size=128,
+        moe_intermediate_size=128, num_layers=2, first_k_dense=1, num_heads=2,
+        q_lora_rank=32, kv_lora_rank=32, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, rope_theta=10000.0,
+        max_position_embeddings=64, n_routed_experts=16, num_experts_per_tok=8,
+    )
+    runner = ModelRunner(
+        cfg, M.init_params(cfg, jax.random.PRNGKey(0)), num_blocks=96,
+        block_size=4, max_batch=16, max_model_len=64, prefill_chunk_tokens=16,
+        attn_impl=attn_impl,
+    )
+    return JaxEngine(runner, JaxEngineConfig(
+        max_batch=16, block_size=4, num_blocks=96, max_model_len=64,
+        watermark_blocks=2, mixed_step=True, decode_horizon=4,
+        preempt_backoff_ms=1.0,
+    ))
+
+
+@pytest.mark.parametrize("attn_impl,kernel", [("pallas_interpret", 3), ("auto", 0)])
+def test_the_ledger_says_which_form_the_grouped_products_took(attn_impl, kernel):
+    """`grouped_product_kernel` and `grouped_product_xla` of a label's first
+    dispatch: through the kernel, the toy's one expert layer runs its three
+    products in the Pallas grouped product in the horizon (counted once for
+    the four steps) and in the packed prefill; on the CPU's default, the XLA
+    form, all three are `lax.ragged_dot` in both."""
+    async def run():
+        engine = make_expert_engine(attn_impl)
+        await collect(engine, request([1, 2, 3], 12, SamplingOptions(greedy=True)))
+        split = engine.stats.goodput.summary()["first_dispatch_by_label"]
+        await engine.close()
+        return split
+
+    jax.clear_caches()  # a body traced by an earlier test counts from its memo
+    split = asyncio.run(run())
+    products = lambda label: (split[label]["grouped_product_kernel"], split[label]["grouped_product_xla"])
+    assert products("decode_multi@H4B16") == (kernel, 3 - kernel)
+    assert products("prefill_packed") == (kernel, 3 - kernel)
+    assert all(tuple(fields) == FIRST_DISPATCH_FIELDS for fields in split.values())
+
+
 def test_a_nested_trace_counts_once():
     """JAX reports a jitted function traced inside another before the outer
     one, whose span covers it: the split keeps the outer's seconds alone,
@@ -192,8 +240,8 @@ def test_a_nested_trace_counts_once():
 
 def test_the_split_merges_and_crosses_the_wire():
     a, b = GoodputLedger(enabled=True), GoodputLedger(enabled=True)
-    a.record_compile("decode", 12.0, {"trace_s": 1.0, "lower_s": 0.5, "backend_s": 9.0, "layer_bodies": 1, "kv_append_folded": 32})
-    b.record_compile("decode", 11.0, {"trace_s": 2.0, "lower_s": 0.25, "backend_s": 8.0, "layer_bodies": 1, "kv_append_scattered": 2})
+    a.record_compile("decode", 12.0, {"trace_s": 1.0, "lower_s": 0.5, "backend_s": 9.0, "layer_bodies": 1, "kv_append_folded": 32, "grouped_product_kernel": 42})
+    b.record_compile("decode", 11.0, {"trace_s": 2.0, "lower_s": 0.25, "backend_s": 8.0, "layer_bodies": 1, "kv_append_scattered": 2, "grouped_product_xla": 3})
     b.record_compile("prefill_packed", 3.0)  # no split given: none kept
     merged = GoodputStats.from_dict(a.to_dict())
     merged.merge(GoodputStats.from_dict(b.to_dict()))
@@ -201,6 +249,7 @@ def test_the_split_merges_and_crosses_the_wire():
         "decode": {
             "trace_s": 2.0, "lower_s": 0.5, "backend_s": 9.0, "layer_bodies": 1.0,
             "kv_append_folded": 32.0, "kv_append_scattered": 2.0,
+            "grouped_product_kernel": 42.0, "grouped_product_xla": 3.0,
         },
     }
     assert merged.summary()["first_dispatch_by_label"]["decode"]["trace_s"] == 2.0

@@ -14,6 +14,7 @@ import numpy as np
 import pytest
 
 from dynamo_tpu.ops import attention as A
+from dynamo_tpu.ops.basics import forms_traced
 from dynamo_tpu.ops.pallas_attention import (
     flash_prefill_attention_pallas,
     paged_decode_attention_pallas,
@@ -386,9 +387,9 @@ def test_decode_append_inside_the_kernel_is_the_pair(case, dtype):
     kw = dict(window=spec.pop("window", None), logit_softcap=spec.pop("softcap", None))
     inputs = _append_inputs(dtype, **spec)
     q, k_cache, v_cache, k_new, v_new, slots, tables, lens = inputs
-    with A.kv_appends_traced() as counted:
+    with forms_traced() as counted:
         out, k_got, v_got = A.decode_append_attention(*inputs, impl="pallas_interpret", **kw)
-    assert counted == [1, 0]
+    assert counted == {"kv_append_folded": 1}
     k_ref, v_ref = A.write_decode_kv(k_cache, v_cache, k_new, v_new, slots)
     ref = A.paged_decode_attention(q, k_ref, v_ref, tables, lens, impl="xla", **kw)
 
@@ -424,11 +425,11 @@ def test_decode_append_falls_to_the_pair_by_what_it_sees(form):
     impl = {"an int8-resident cache": "pallas_interpret", "the XLA form": "xla", "an untileable shape": "pallas"}[form]
     if form == "an int8-resident cache":
         k_cache, v_cache = quantize_blocks(k_cache), quantize_blocks(v_cache)
-    with A.kv_appends_traced() as counted:
+    with forms_traced() as counted:
         out, k_got, v_got = A.decode_append_attention(
             q, k_cache, v_cache, k_new, v_new, slots, tables, lens, impl=impl
         )
-    assert counted == [0, 1]
+    assert counted == {"kv_append_scattered": 1}
     k_ref, v_ref = A.write_decode_kv(k_cache, v_cache, k_new, v_new, slots)
     ref = A.paged_decode_attention(q, k_ref, v_ref, tables, lens, impl=impl)
     same = lambda a, b: np.array_equal(np.asarray(a, np.float32), np.asarray(b, np.float32))

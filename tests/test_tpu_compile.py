@@ -553,6 +553,16 @@ def _entry_instructions(text: str):
         yield m["name"], m["op"], max(sizes, default=0), line
 
 
+def _stack_relayouts(text: str, stack_elements: int) -> list[str]:
+    """Entry instructions that copy, transpose or fuse into an array of just
+    an expert layer's weight stack's size: the stacks reach the grouped
+    products as they lie."""
+    return [
+        line for _, op, elements, line in _entry_instructions(text)
+        if elements == stack_elements and op in ("copy", "transpose", "fusion")
+    ]
+
+
 def _pool_sized_movers(text: str, pool_elements: int) -> list[str]:
     """Entry instructions that slice, copy or update-in-a-copy at least one
     layer's pool: plain, asynchronous (-start/-done), or a fusion the
@@ -756,14 +766,19 @@ def _lower_latent_decode_multi(one_chip, num_blocks: int = 31805, layers: int = 
 def test_latent_expert_decode_multi_program_one_chip(one_chip):
     """`decode_multi@H4B64` at the published widths, five layers, the cell's
     pool: it compiles, the planes are updated in place (no pool-sized copy),
-    the grouped products are XLA's own kernel with the expert stacks as they
-    lie (no relayout of 805 MB stacks), and weights, planes and temporaries
-    fit the chip."""
+    the grouped products are the Pallas kernel (`ops/grouped_product.py`; XLA's
+    own, `%ragged-dot-none`, until PR 49) with the expert stacks as they lie
+    among its operands (no relayout of 805 MB stacks), and weights, planes and
+    temporaries fit the chip."""
     compiled = _lower_latent_decode_multi(one_chip).compile()
     text = compiled.as_text()
     steps, expert_layers = 4, 4
     assert len(re.findall(r" = bf16\[64,32,512\]\S* custom-call\(", text)) == steps * 5
-    assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) == steps * expert_layers * 3
+    assert len(re.findall(
+        r"^\s*%tpu_custom_call[.\d]* = [^\n]*custom_call_target=\"tpu_custom_call\""
+        r"[^\n]*bf16\[256,(?:2048,768|768,2048)\]\{", text, re.M,
+    )) == steps * expert_layers * 3
+    assert "ragged-dot" not in text
     pool = 31805 * BLOCK * 640
     assert _pool_sized_movers(text, pool) == []
     stack = 256 * 2048 * 768
@@ -799,6 +814,15 @@ def test_latent_expert_decode_multi_program_one_chip(one_chip):
 # 37's: 7619, a044e7f020604b20, 28982784 temporaries, fusion 399, copy 55,
 # copy-start 148, slice-start 60, scatter 17; and 3858, 2aee35c5c34abc43,
 # fusion 216, copy 35, copy-start 76, scatter 11.
+#
+# The latent family's is PR 49's, re-read by the same function: its expert
+# layer's three grouped products a step are the Pallas kernel
+# (`ops/grouped_product.py`) where they were XLA's `ragged-dot`, and the
+# kernel's walk over the groups (which group and row tile a grid cell works
+# on: sums and compares of the group sizes, no scatter and no device loop)
+# is a few small fusions in front of each call. PR 47's: 10430,
+# 4457f7d44b8a8a85, 194732544 temporaries, fusion 613, custom-call 131,
+# copy 113, copy-start 186, slice-start 280, scatter 13, and 281 + 4 bitcasts.
 
 PARENT_COMPILED = {
     "decode_multi@H4B64": (7414, "98bedc1318b4d02c", 30562816, 134217728,
@@ -807,8 +831,8 @@ PARENT_COMPILED = {
         {"fusion": 211, "custom-call": 30, "convolution": 33, "copy": 37, "copy-start": 74, "slice-start": 60, "scatter": 7, "conditional": 2}),
     "prefill_packed@512": (2198, "ae7537686dbdb584", 9436160, 134217728,
         {"fusion": 126, "custom-call": 19, "convolution": 19, "copy": 24, "copy-start": 48, "slice-start": 44, "scatter": 6, "conditional": 1}),
-    "latent decode_multi@H4B64": (10430, "4457f7d44b8a8a85", 194732544, 167772160,
-        {"fusion": 613, "custom-call": 131, "convolution": 80, "copy": 113, "copy-start": 186, "slice-start": 280, "scatter": 13, "conditional": 4}),
+    "latent decode_multi@H4B64": (11182, "89c5634c668ba6e9", 191540736, 167772160,
+        {"fusion": 649, "custom-call": 121, "convolution": 80, "copy": 113, "copy-start": 131, "slice-start": 256, "scatter": 13, "conditional": 4, "ragged-dot": 0}),
 }
 
 BODY_PROGRAMS = {
@@ -816,8 +840,8 @@ BODY_PROGRAMS = {
     "mixed_step@c1": (_lower_mixed_step, 1),  # a chunk's (XLA) and a decode's
     "prefill_packed@512": (_lower_prefill_packed, 0),
     "latent decode_multi@H4B64": (
-        functools.partial(_lower_latent_decode_multi, num_blocks=4096), 2,
-    ),  # the dense layer's and the expert layers'
+        functools.partial(_lower_latent_decode_multi, num_blocks=4096), 2 + 3,
+    ),  # the dense layer's and the expert layers', whose body holds its 3 products
 }
 
 _MODULE_INSTRUCTION = re.compile(
@@ -863,11 +887,8 @@ def test_step_program_compiles_to_the_parents(one_chip, program):
     """The chip's compiler, given the calls, writes the program it wrote for
     the unrolled layers: the same instructions by operation, the same
     temporaries, the same bytes aliased (the donated caches, still written
-    in place), and the kernels keep their names. The latent family's module
-    holds four `bitcast`s more (281 ->
-    285: the counters' four-element row crosses a call boundary once a step;
-    a bitcast moves nothing), so its digest is the change's and the parent's
-    count is held to with those four."""
+    in place), and the kernels keep their names (the grouped products'
+    among them, since PR 49)."""
     lower, kernels = BODY_PROGRAMS[program]
     compiled = lower(one_chip, layers=2).compile()
     text = compiled.as_text()
@@ -887,9 +908,6 @@ def test_step_program_compiles_to_the_parents(one_chip, program):
     mem = compiled.memory_analysis()
     assert (mem.temp_size_in_bytes, mem.alias_size_in_bytes) == (temp, alias)
     assert {op: histogram.get(op, 0) for op in telling} == telling, histogram
-    if program.startswith("latent"):
-        assert histogram.pop("bitcast") == 281 + 4
-        histogram = dict(sorted({**histogram, "bitcast": 281}.items()))
     assert sum(histogram.values()) == count, histogram
     assert hashlib.sha256(
         json.dumps(histogram).encode()
@@ -1148,17 +1166,18 @@ def test_paged_kernels_at_64_wide_heads_in_pairs(one_chip):
 
 @pytest.mark.parametrize("packed", [False, True], ids=["impl", "as_launched"])
 @pytest.mark.parametrize("program,bodies,kernels", [
-    ("decode_multi@H4B64", 3, 1 * 4),  # 1 attention layer x 4 steps
-    ("mixed_step@c1", 6, 1),  # the chunk's attention is XLA's
-    ("prefill_packed@512", 3, 0),
-    ("prefill@512", 3, 1),  # the flash prefill kernel
+    # 1 attention layer x 4 steps, and 3 expert layers' 3 grouped products a pass
+    ("decode_multi@H4B64", 3, 1 * 4 + 9 * 4),
+    ("mixed_step@c1", 6, 1 + 9 * 2),  # the chunk's attention is XLA's
+    ("prefill_packed@512", 3, 9),
+    ("prefill@512", 3, 1 + 9),  # the flash prefill kernel
 ])
 def test_conv_moe_step_programs_one_chip(one_chip, program, bodies, kernels, packed):
     """The family's step programs compile for the chip: three layer bodies a
     pass (dense-convolution, expert-convolution, expert-attention; a mixed
     step has a chunk's pass and a decode's), the paged kernels under their
-    name at 64-wide heads, the grouped products of the expert layers, the
-    slot arrays and the pages written in place (aliased), no device loop (a
+    name at 64-wide heads, the grouped products of the expert layers in the
+    Pallas kernel and none in XLA's (PR 49), the slot arrays and the pages written in place (aliased), no device loop (a
     convolution over three positions is three shifted products), and
     everything fits."""
     from dynamo_tpu.models import layer_bodies_called
@@ -1175,7 +1194,8 @@ def test_conv_moe_step_programs_one_chip(one_chip, program, bodies, kernels, pac
     )
     assert len(names) == kernels
     assert all(name.startswith("tpu_custom_call") for name in names), names
-    assert "ragged-dot" in text or "ragged_dot" in text
+    assert "ragged-dot" not in text and "ragged_dot" not in text
+    assert _stack_relayouts(text, 32 * 2048 * 1792) == []
     assert not re.findall(r"^\s*%?[\w.\-]+ = [^\n]*? while\(", text, re.M)
     mem = compiled.memory_analysis()
     # three convolution layers' tails (65 rows are tiled to 72)
@@ -1229,15 +1249,17 @@ def _ssm2_moe_setup(one_chip, num_blocks: int = 32832, pattern: str = "ME*"):
 
 @pytest.mark.parametrize("packed", [False, True], ids=["impl", "as_launched"])
 @pytest.mark.parametrize("program,bodies,kernels", [
-    ("decode_multi@H4B64", 3, 1 * 4),  # 1 attention layer x 4 steps
-    ("mixed_step@c1", 6, 1),  # the chunk's attention is XLA's
-    ("prefill_packed@512", 3, 0),
+    # 1 attention layer x 4 steps, and 1 expert layer's 2 grouped products a pass
+    ("decode_multi@H4B64", 3, 1 * 4 + 2 * 4),
+    ("mixed_step@c1", 6, 1 + 2 * 2),  # the chunk's attention is XLA's
+    ("prefill_packed@512", 3, 2),
 ])
 def test_ssm2_moe_step_programs_one_chip(one_chip, program, bodies, kernels, packed):
     """The family's step programs compile for the chip: three layer bodies a
     pass (Mamba-2, experts, attention; a mixed step has a chunk's pass and a
     decode's), the paged decode kernel under its name at 2 KV heads, the
-    grouped products of the held experts, the slot arrays and the pages
+    grouped products of the held experts in the Pallas kernel and none in
+    XLA's (PR 49), the slot arrays and the pages
     written in place (aliased), and everything fits."""
     from dynamo_tpu.models import layer_bodies_called
 
@@ -1253,7 +1275,8 @@ def test_ssm2_moe_step_programs_one_chip(one_chip, program, bodies, kernels, pac
     )
     assert len(names) == kernels
     assert all(name.startswith("tpu_custom_call") for name in names), names
-    assert "ragged-dot" in text or "ragged_dot" in text
+    assert "ragged-dot" not in text and "ragged_dot" not in text
+    assert _stack_relayouts(text, 128 * 1024 * 2688) == []
     mem = compiled.memory_analysis()
     # one Mamba-2 layer's slot arrays (the tail's 65 rows are tiled to 72)
     # and one attention layer's two planes, at the published bytes a token
