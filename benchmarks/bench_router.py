@@ -1,20 +1,19 @@
 """Router scale benchmark: indexer event ingest + query latency +
 scheduler selection at fleet scale.
 
-Role-equivalent of the scale the reference designs its sharded indexer
-for (lib/llm/src/kv_router/indexer.rs:187-860 — events from every block
-of every request fleet-wide). Default load: 64 workers, ~100k blocks,
+Role-equivalent of the scale the reference designs its indexer for
+(lib/llm/src/kv_router/indexer.rs:187-860 — events from every block of
+every request fleet-wide). Default load: 64 workers, ~100k blocks,
 prefix-heavy chains (a quarter of chains share one of 50 hot prefixes).
 
     python -m benchmarks.bench_router [--workers 64] [--blocks 102400]
-        [--mode single|sharded] [--shards 8] [--json out.json]
+        [--json out.json]
 
 Prints one JSON line with events/s, blocks/s, find_matches p50/p99, and
 schedule p50/p99. Context for the floor: the reference's headline decode
 exemplar is ~51 tok/s/GPU (load_planner.md:56) — 64 such workers emit
 64*51/16 ≈ 200 blocks/s fleet-wide; ingest measured here is three orders
-of magnitude above that, so one event loop holds the line (the sharded
-mode exists for fleets beyond it; see ShardedKvIndexer).
+of magnitude above that, so one event loop holds the line.
 """
 
 from __future__ import annotations
@@ -30,13 +29,11 @@ def run_bench(
     total_blocks: int = 102_400,
     block_size: int = 16,
     chain_blocks: int = 32,
-    mode: str = "single",
-    shards: int = 8,
     queries: int = 5_000,
     schedules: int = 2_000,
     seed: int = 0,
 ) -> dict:
-    from dynamo_tpu.kv_router.indexer import KvIndexer, ShardedKvIndexer
+    from dynamo_tpu.kv_router.indexer import KvIndexer
     from dynamo_tpu.kv_router.protocols import (
         KvCacheEvent,
         KvCacheStoredBlock,
@@ -45,10 +42,7 @@ def run_bench(
     from dynamo_tpu.kv_router.scheduler import KvScheduler
 
     rng = random.Random(seed)
-    if mode == "sharded":
-        idx = ShardedKvIndexer(block_size, num_shards=shards)
-    else:
-        idx = KvIndexer(block_size)
+    idx = KvIndexer(block_size)
 
     # -------- ingest: store events, prefix-heavy hash chains
     chains: list[list[int]] = []
@@ -119,7 +113,6 @@ def run_bench(
         return xs[min(len(xs) - 1, int(p * len(xs)))] * 1e6
 
     return {
-        "mode": mode,
         "workers": workers,
         "stored_blocks": stored_blocks,
         "events_per_s": round(len(events) / ingest_s),
@@ -137,16 +130,12 @@ def main() -> None:
     ap.add_argument("--workers", type=int, default=64)
     ap.add_argument("--blocks", type=int, default=102_400)
     ap.add_argument("--block-size", type=int, default=16)
-    ap.add_argument("--mode", choices=["single", "sharded"], default="single")
-    ap.add_argument("--shards", type=int, default=8)
     ap.add_argument("--json", default=None, help="also write result here")
     args = ap.parse_args()
     result = run_bench(
         workers=args.workers,
         total_blocks=args.blocks,
         block_size=args.block_size,
-        mode=args.mode,
-        shards=args.shards,
     )
     line = json.dumps(result)
     print(line)

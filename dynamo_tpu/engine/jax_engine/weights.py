@@ -15,7 +15,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
-from dynamo_tpu.models import forward_for
+from dynamo_tpu.models import forward_for, served_model_types
 from dynamo_tpu.models.llama import LlamaConfig
 from dynamo_tpu.ops.linear import maybe_quantize
 from dynamo_tpu.runtime.logging import get_logger
@@ -34,8 +34,7 @@ def load_or_init_params(
     if model_dir:
         files = sorted(glob.glob(os.path.join(model_dir, "*.safetensors")))
         if files:
-            # a family's checkpoint names, by the module of its config
-            load = LOADERS[forward_for(config).__name__.rsplit(".", 1)[-1]]
+            load = LOADERS[type(config)]
             return load(model_dir, config, quantize=quantize, dtype=dtype)
         logger.warning(
             "%s has no *.safetensors; falling back to random init", model_dir
@@ -491,9 +490,15 @@ def load_ssm2_moe_safetensors(
     return params
 
 
+# A family's checkpoint names, by its config class as the families' one table
+# has it (`models.served_model_types`): a loader reads the names that one of
+# the family's `model_type`s publishes.
+_SERVED = served_model_types()
 LOADERS = {
-    "llama": load_hf_safetensors, "mla_moe": load_latent_moe_safetensors,
-    "hybrid_ssm": load_hybrid_ssm_safetensors,
-    "conv_moe": load_conv_moe_safetensors,
-    "ssm2_moe": load_ssm2_moe_safetensors,
+    _SERVED["llama"]: load_hf_safetensors,
+    _SERVED["joyai_llm_flash"]: load_latent_moe_safetensors,
+    _SERVED["jamba"]: load_hybrid_ssm_safetensors,
+    _SERVED["lfm2_moe"]: load_conv_moe_safetensors,
+    _SERVED["nemotron_h"]: load_ssm2_moe_safetensors,
 }
+assert set(LOADERS) == set(_SERVED.values()), "a family without checkpoint names"

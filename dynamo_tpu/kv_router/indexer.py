@@ -229,64 +229,6 @@ class KvIndexer(_ChainQuery):
         self.tree.remove_worker(worker_id)
 
 
-class ShardedKvIndexer(_ChainQuery):
-    """Worker-partitioned indexer (reference indexer.rs:696 sharded
-    variant): each shard owns a disjoint subset of workers with its own
-    RadixTree + jump table.
-
-    What sharding buys here: per-shard structures stay small under
-    fleet-wide event storms, a worker's removal/clear walks only its
-    shard, and one worker's pathological event stream cannot bloat the
-    tree every query walks. What it costs: find_matches fans out to every
-    shard and merges scores (workers are disjoint, so the merge is a dict
-    union). The single-tree bench numbers (benchmarks/bench_router.py)
-    show one tree already sustains the reference design point on one
-    event loop — this exists for the router-fleet scale beyond it, and
-    for parity with the reference.
-    """
-
-    def __init__(
-        self,
-        block_size: int,
-        num_shards: int = 8,
-        expiration_duration: Optional[float] = None,
-        now_fn: Callable[[], float] = dclock.now,
-    ) -> None:
-        if num_shards < 1:
-            raise ValueError("num_shards must be >= 1")
-        self._block_size = block_size
-        self.shards = [
-            KvIndexer(block_size, expiration_duration, now_fn=now_fn)
-            for _ in range(num_shards)
-        ]
-
-    def _shard(self, worker_id: int) -> KvIndexer:
-        return self.shards[worker_id % len(self.shards)]
-
-    def apply_event(self, event: RouterEvent) -> None:
-        self._shard(event.worker_id).apply_event(event)
-
-    def find_matches(self, sequence: list[int]) -> OverlapScores:
-        merged = OverlapScores()
-        for shard in self.shards:
-            sc = shard.find_matches(sequence)
-            merged.scores.update(sc.scores)  # worker sets are disjoint
-            # frequencies: every fan-out query touches every shard that
-            # holds the prefix, so each holder's per-depth count already
-            # equals the single-tree access count — merge with MAX
-            # (summing would scale hotness by the number of holding
-            # shards, diverging from KvIndexer semantics)
-            for i, f in enumerate(sc.frequencies):
-                if i < len(merged.frequencies):
-                    merged.frequencies[i] = max(merged.frequencies[i], f)
-                else:
-                    merged.frequencies.append(f)
-        return merged
-
-    def remove_worker(self, worker_id: int) -> None:
-        self._shard(worker_id).remove_worker(worker_id)
-
-
 class ApproxKvIndexer(_ChainQuery):
     """TTL-based indexer needing NO worker events (reference approx.rs:166).
 

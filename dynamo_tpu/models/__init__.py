@@ -16,6 +16,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import functools
+import importlib
 import json
 import math
 import os
@@ -216,30 +217,33 @@ def forward_for(config):
     return sys.modules[type(config).__module__]
 
 
+# The family modules. Each holds `MODEL_TYPES`, the `config.json`
+# `model_type`s it serves, and `CONFIG`, its config class.
+FAMILIES = ("llama", "mla_moe", "hybrid_ssm", "conv_moe", "ssm2_moe")
+
+
+@functools.lru_cache(maxsize=None)
+def served_model_types() -> dict:
+    """`model_type` -> the config class of the family that serves it."""
+    table = {}
+    for name in FAMILIES:
+        module = importlib.import_module(f"{__name__}.{name}")
+        table.update(dict.fromkeys(module.MODEL_TYPES, module.CONFIG))
+    return table
+
+
 def config_from_model_dir(model_dir: str):
     """The config of the family that `config.json`'s `model_type` names."""
     with open(os.path.join(model_dir, "config.json")) as f:
         hf = json.load(f)
-    from dynamo_tpu.models import conv_moe, hybrid_ssm, llama, mla_moe, ssm2_moe
-
+    served = served_model_types()
     model_type = hf.get("model_type")
-    if model_type in mla_moe.MODEL_TYPES:
-        return mla_moe.MlaMoeConfig.from_hf_dict(hf)
-    if model_type in hybrid_ssm.MODEL_TYPES:
-        return hybrid_ssm.HybridSsmConfig.from_hf_dict(hf)
-    if model_type in conv_moe.MODEL_TYPES:
-        return conv_moe.ConvMoeConfig.from_hf_dict(hf)
-    if model_type in ssm2_moe.MODEL_TYPES:
-        return ssm2_moe.Ssm2MoeConfig.from_hf_dict(hf)
-    if model_type is not None and model_type not in llama.MODEL_TYPES:
-        served = (
-            llama.MODEL_TYPES + mla_moe.MODEL_TYPES + hybrid_ssm.MODEL_TYPES
-            + conv_moe.MODEL_TYPES + ssm2_moe.MODEL_TYPES
-        )
+    if model_type is not None and model_type not in served:
         raise ValueError(
             f"model_type {model_type!r} is not served: its layers are not "
             "implemented here, and a dense grouped-query model built from "
             "its widths would be another model under its name (served: "
             f"{sorted(served)})"
         )
-    return llama.LlamaConfig.from_hf_dict(hf)
+    # a `config.json` that names no `model_type` is a grouped-query model's
+    return served.get(model_type, served["llama"]).from_hf_dict(hf)

@@ -77,13 +77,14 @@ import jax.numpy as jnp
 from jax import lax
 
 from dynamo_tpu.models import (
-    CacheKind, kv_heads_cache, layer_body, recurrent_state,
+    CacheKind, kv_heads_cache, layer_body, programs, recurrent_state,
 )
+from dynamo_tpu.models.programs import Body, Family
+from dynamo_tpu.models.programs import dense as _dense, normal as _normal
 from dynamo_tpu.ops import ssm
 from dynamo_tpu.ops.attention import (
     causal_prefill_attention, chunked_prefill_attention,
-    decode_append_attention, live_decode_lanes, packed_prefill_attention,
-    write_decode_kv,
+    decode_append_attention, packed_prefill_attention, write_decode_kv,
 )
 from dynamo_tpu.ops.basics import apply_rope, rms_norm, rope_freqs, swiglu
 from dynamo_tpu.ops.linear import linear
@@ -250,6 +251,9 @@ class ConvMoeConfig:
         )
 
 
+CONFIG = ConvMoeConfig  # `models.served_model_types` reads it
+
+
 # ------------------------------------------------------------------ params
 
 KEYS_PER_LAYER = 12
@@ -300,21 +304,6 @@ def init_params(
 def _layer_keys(attends: bool, routed: bool) -> int:
     """Keys a layer's draw consumes."""
     return (4 if attends else 3) + (5 if routed else 3)
-
-
-def _normal(key, shape):
-    """A float32 normal draw that a jit leaves as it is: behind the barrier
-    the compiler cannot fold the draw's own constants into what multiplies
-    or divides it next, which rounds one value in some thousands differently
-    from the same draw made outside a jit (the reference's)."""
-    return lax.optimization_barrier(jax.random.normal(key, shape, dtype=F32))
-
-
-def _dense(key, shape, fan_in, dtype):
-    # the divisor behind a barrier too: a division by a known constant is
-    # compiled as a product with its reciprocal
-    by = lax.optimization_barrier(jnp.sqrt(F32(fan_in)))
-    return (_normal(key, shape) / by).astype(dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("c", "dtype", "attends", "routed"))
@@ -432,14 +421,6 @@ def _ffn(x, layer, cfg, valid):
             impl=cfg.attn_impl,
         )
     return x + y.astype(x.dtype), expert_step_stats(group_sizes)
-
-
-def _logits(x, params, cfg):
-    h = rms_norm(x, params["final_norm"], cfg.rms_eps)
-    w = params.get("lm_head")
-    if w is None:
-        return jnp.matmul(h, params["embed"].T.astype(h.dtype)).astype(F32)
-    return linear(h, w).astype(F32)
 
 
 def _inv_freqs(cfg):
@@ -594,183 +575,61 @@ def _attn_decode_layer(x, layer, k_l, v_l, inv_freqs, positions, live, context, 
     return x, k_l, v_l, counted
 
 
-def _walk(params, cfg, x, k_cache, v_cache, conv, attend, stats=None):
-    """The layers in order, each with what it keeps; `conv` is (x, layer,
-    tail) -> (x, tail, counted), `attend` (x, layer, keys, values) -> (x,
-    keys, values, counted). The expert layers' counters are appended to
-    `stats` where a list is given."""
-    k_out, v_out = [], []
-    for i, layer in enumerate(params["layers"]):
-        if cfg.is_attn_layer(i):
-            x, a, b, counted = attend(x, layer, k_cache[i], v_cache[i])
-        else:
-            (x, a, counted), b = conv(x, layer, k_cache[i]), None
-        k_out.append(a)
-        v_out.append(b)
-        if stats is not None and counted is not None:
-            stats.append(counted)
-    return x, tuple(k_out), tuple(v_out)
+def _rope(cfg, **_):
+    return {"inv_freqs": _inv_freqs(cfg)}
 
 
-def _first(cfg, attends: bool) -> int:
-    """The first attention layer, or the first convolution layer."""
-    return next(
-        i for i in range(cfg.num_layers) if cfg.is_attn_layer(i) == attends
-    )
-
-
-def _page_size(cfg, k_cache) -> int:
-    return k_cache[_first(cfg, True)].shape[2]
-
-
-def _segment_slots(cfg, k_cache, segment_ids, state_slots):
-    """[N]: each segment's lane slot; the null lane's for a segment that
-    holds no prompt."""
-    null = k_cache[_first(cfg, False)].shape[0] - 1
+def _packed_values(cfg, *, segment_ids, state_slots, null, **_):
+    """`seg_slots` [N]: each segment's lane slot, the null lane's for a
+    segment that holds no prompt; and rope's frequencies."""
     used = jnp.arange(state_slots.shape[0]) <= jnp.max(segment_ids)
-    return jnp.where(used, state_slots, null)
+    return {"seg_slots": jnp.where(used, state_slots, null), **_rope(cfg)}
 
 
-def prefill_packed(
-    params: dict,
-    cfg: ConvMoeConfig,
-    tokens: jax.Array,  # [P] int32: several prompts packed back to back
-    positions: jax.Array,  # [P] int32: restart at 0 per segment
-    segment_ids: jax.Array,  # [P] int32; -1 marks padding
-    slot_indices: jax.Array,  # [P] int32 flat cache slots per token
-    k_cache: tuple,  # per layer: pages [Hkv/pack, nb, bs, pack*D], or the tail [S, (L-1)*H]
-    v_cache: tuple,  # per layer: pages, or None
-    last_idx: jax.Array,  # [N] int32
-    *,
-    state_slots: jax.Array,  # [N] int32: the lane slot of each segment
-    mesh=None,
-) -> tuple[jax.Array, tuple, tuple]:
-    """Fresh prompts: every segment's convolution starts from zeros at its
-    position 0 and leaves its tail in its slot. Returns (logits [N, V],
-    caches)."""
-    valid = segment_ids >= 0
-    seg_slots = _segment_slots(cfg, k_cache, segment_ids, state_slots)
-    inv_freqs = _inv_freqs(cfg)
-    x, k_out, v_out = _walk(
-        params, cfg, params["embed"][tokens], k_cache, v_cache,
-        lambda x, layer, t: _conv_packed_layer(
-            x, layer, t, positions, valid, last_idx, seg_slots, cfg=cfg),
-        lambda x, layer, k, v: _attn_packed_layer(
-            x, layer, k, v, inv_freqs, positions, segment_ids, slot_indices, cfg=cfg),
-    )
-    return _logits(x[last_idx], params, cfg), k_out, v_out
-
-
-def prefill(
-    params, cfg, tokens, valid_len, k_cache, v_cache, block_table,
-    *, state_slots, mesh=None, attn_head_axis=None,
-):
-    """One whole prompt (padded to a bucket): one segment through the
-    convolution layers' packed body, the attention layers through the flash
-    prefill kernel. `state_slots`: its lane slot (scalar). Returns (logits
-    [V], caches)."""
-    P = tokens.shape[0]
-    bs = _page_size(cfg, k_cache)
-    pos = jnp.arange(P, dtype=jnp.int32)
-    live = pos < valid_len
-    slots = jnp.where(live, block_table[pos // bs] * bs + pos % bs, 0)
-    last_idx = (valid_len - 1)[None]
-    seg_slots = jnp.reshape(state_slots, (1,))
-    inv_freqs = _inv_freqs(cfg)
-    x, k_out, v_out = _walk(
-        params, cfg, params["embed"][tokens], k_cache, v_cache,
-        lambda x, layer, t: _conv_packed_layer(
-            x, layer, t, pos, live, last_idx, seg_slots, cfg=cfg),
-        lambda x, layer, k, v: _attn_prefill_layer(
-            x, layer, k, v, inv_freqs, pos, valid_len, slots,
-            cfg=cfg, mesh=mesh, head_axis=attn_head_axis),
-    )
-    return _logits(x[last_idx], params, cfg)[0], k_out, v_out
-
-
-def prefill_chunk(
-    params: dict,
-    cfg: ConvMoeConfig,
-    tokens: jax.Array,  # [C] int32
-    chunk_start: jax.Array,  # scalar int32
-    valid_len: jax.Array,  # scalar int32: total prompt length
-    k_cache: tuple,
-    v_cache: tuple,
-    block_table: jax.Array,  # [max_nb] int32
-    *,
-    state_slots: jax.Array,  # scalar int32: the sequence's lane slot
-    mesh=None,
-) -> tuple[jax.Array, tuple, tuple]:
-    """One chunk of a chunked prefill: the tail is taken from the sequence's
-    slot (zeros at `chunk_start` 0) and left there; keys and values are
-    written, then the chunk attends over what the cache holds."""
-    C = tokens.shape[0]
-    bs = _page_size(cfg, k_cache)
-    positions = chunk_start + jnp.arange(C, dtype=jnp.int32)
-    valid = positions < valid_len
-    # the table is read behind its end by a last chunk's padded tail:
-    # those rows go to the null block
-    n = block_table.shape[0]
-    page = jnp.where(positions // bs < n, block_table[jnp.minimum(positions // bs, n - 1)], 0)
-    slots = jnp.where(valid, page * bs + positions % bs, 0)
-    slot = jnp.reshape(state_slots, ())
-    inv_freqs = _inv_freqs(cfg)
-    x, k_out, v_out = _walk(
-        params, cfg, params["embed"][tokens], k_cache, v_cache,
-        lambda x, layer, t: _conv_chunk_layer(
-            x, layer, t, positions, valid, slot, chunk_start, cfg=cfg),
-        lambda x, layer, k, v: _attn_chunk_layer(
-            x, layer, k, v, inv_freqs, positions, valid, slots, block_table,
-            chunk_start, cfg=cfg),
-    )
-    idx = jnp.clip(valid_len - 1 - chunk_start, 0, C - 1)
-    return _logits(x[idx][None, :], params, cfg)[0], k_out, v_out
-
-
-def decode(
-    params: dict,
-    cfg: ConvMoeConfig,
-    tokens: jax.Array,  # [B] int32
-    positions: jax.Array,  # [B] int32
-    k_cache: tuple,
-    v_cache: tuple,
-    block_tables: jax.Array,  # [B, max_blocks] int32
-    slot_indices: jax.Array,  # [B] int32; a slot in the null block = idle lane
-    *,
-    mesh=None,
-    attn_head_axis=None,
-    stats: Optional[list] = None,
-) -> tuple[jax.Array, tuple, tuple]:
-    """One decode step for a batch; lane b's tail is row b of the slot
-    arrays. A lane whose row goes to the null block holds no decoding
-    sequence: it reads no page, is given to no expert, and its slot stays as
-    it is. Returns (logits [B, V], caches)."""
-    live = live_decode_lanes(k_cache[_first(cfg, True)], slot_indices)
-    context = jnp.where(live, positions + 1, 0)
-    inv_freqs = _inv_freqs(cfg)
-    x, k_out, v_out = _walk(
-        params, cfg, params["embed"][tokens], k_cache, v_cache,
-        lambda x, layer, t: _conv_decode_layer(x, layer, t, live, cfg=cfg),
-        lambda x, layer, k, v: _attn_decode_layer(
-            x, layer, k, v, inv_freqs, positions, live, context, block_tables,
-            slot_indices, cfg=cfg, mesh=mesh, head_axis=attn_head_axis),
-        stats,
-    )
-    return _logits(x, params, cfg), k_out, v_out
-
-
-def _not_served(what: str):
-    def refuse(*_a, **_k):
-        raise NotImplementedError(
-            f"{what} is not implemented for the short-convolution family"
-        )
-
-    return refuse
-
-
-prefill_mm = _not_served("multimodal prefill")
-prefill_context_parallel = _not_served("context-parallel prefill")
-embed_pooled = _not_served("pooled embedding")
-decode_verify = _not_served(
-    "speculative verification (a rejected draft would need the tail rolled back)"
+# A layer by whether it attends: an attention layer keeps pages of keys and
+# values `[Hkv/pack, nb, bs, pack*D]`, a convolution layer its tail
+# `[S, (L-1)*H]` and nothing beside it. One whole prompt is a program of its
+# own: one segment through the convolution layers' packed body, the
+# attention layers through the flash prefill kernel.
+_CONV_PACKED = Body(_conv_packed_layer, 1, (
+    "positions", "valid", "last_idx", "seg_slots"))
+FAMILY = Family(
+    kind=ConvMoeConfig.is_attn_layer,
+    prepare={
+        "packed": _packed_values, "whole": _rope, "chunk": _rope, "decode": _rope,
+    },
+    packed={
+        False: _CONV_PACKED,
+        True: Body(_attn_packed_layer, 2, (
+            "inv_freqs", "positions", "segment_ids", "slot_indices")),
+    },
+    whole={
+        False: _CONV_PACKED,
+        True: Body(
+            _attn_prefill_layer, 2,
+            ("inv_freqs", "positions", "valid_len", "slot_indices"),
+            static=("mesh", "head_axis"),
+        ),
+    },
+    chunk={
+        False: Body(_conv_chunk_layer, 1, (
+            "positions", "valid", "lane_slot", "chunk_start")),
+        True: Body(_attn_chunk_layer, 2, (
+            "inv_freqs", "positions", "valid", "slot_indices", "block_table",
+            "chunk_start")),
+    },
+    decode={
+        False: Body(_conv_decode_layer, 1, ("live",)),
+        True: Body(
+            _attn_decode_layer, 2, (
+                "inv_freqs", "positions", "live", "context", "block_tables",
+                "slot_indices"),
+            static=("mesh", "head_axis"),
+        ),
+    },
+)
+prefill_packed, prefill, prefill_chunk, decode = programs.bound(FAMILY)
+prefill_mm, prefill_context_parallel, embed_pooled, decode_verify = programs.refused(
+    "the short-convolution family",
+    "speculative verification (a rejected draft would need the tail rolled back)",
 )

@@ -45,11 +45,12 @@ import jax
 import jax.numpy as jnp
 
 from dynamo_tpu.models import (
-    CacheKind, kv_heads_cache, layer_body, recurrent_state,
+    CacheKind, kv_heads_cache, layer_body, programs, recurrent_state,
 )
+from dynamo_tpu.models.programs import Body, Family
 from dynamo_tpu.ops import ssm
 from dynamo_tpu.ops.attention import (
-    chunked_prefill_attention, decode_append_attention, live_decode_lanes,
+    chunked_prefill_attention, decode_append_attention,
     packed_prefill_attention, write_decode_kv,
 )
 from dynamo_tpu.ops.basics import rms_norm, swiglu
@@ -164,6 +165,9 @@ class HybridSsmConfig:
             paged if self.is_attn_layer(i) else state
             for i in range(self.num_layers)
         )
+
+
+CONFIG = HybridSsmConfig  # `models.served_model_types` reads it
 
 
 # ------------------------------------------------------------------ params
@@ -318,14 +322,6 @@ def _mlp(x, layer, cfg):
     return x + linear(swiglu(linear(h, layer["wg"]), linear(h, layer["wu"])), layer["wd"])
 
 
-def _logits(x, params, cfg):
-    h = rms_norm(x, params["final_norm"], cfg.rms_eps)
-    w = params.get("lm_head")
-    if w is None:
-        return jnp.matmul(h, params["embed"].T.astype(h.dtype)).astype(F32)
-    return linear(h, w).astype(F32)
-
-
 def _qkv(x, layer, cfg):
     T = x.shape[0]
     h = rms_norm(x, layer["mix_norm"], cfg.rms_eps)
@@ -456,48 +452,10 @@ def _attn_decode_layer(x, layer, k_l, v_l, context, block_tables, slot_indices, 
     return _mlp(_attn_out(attn, x, layer, cfg), layer, cfg), k_l, v_l
 
 
-def _walk(params, cfg, x, k_cache, v_cache, mamba, attend):
-    """The layers in order, each with its own two arrays; `mamba` and
-    `attend` are (x, layer, first, second) -> (x, first, second)."""
-    k_out, v_out = [], []
-    for i, layer in enumerate(params["layers"]):
-        body = attend if cfg.is_attn_layer(i) else mamba
-        x, a, b = body(x, layer, k_cache[i], v_cache[i])
-        k_out.append(a)
-        v_out.append(b)
-    return x, tuple(k_out), tuple(v_out)
-
-
-def _first(cfg, attends: bool) -> int:
-    """The first attention layer, or the first Mamba layer."""
-    return next(
-        i for i in range(cfg.num_layers) if cfg.is_attn_layer(i) == attends
-    )
-
-
-def _page_size(cfg, k_cache) -> int:
-    return k_cache[_first(cfg, True)].shape[2]
-
-
-def prefill_packed(
-    params: dict,
-    cfg: HybridSsmConfig,
-    tokens: jax.Array,  # [P] int32: several prompts packed back to back
-    positions: jax.Array,  # [P] int32: restart at 0 per segment
-    segment_ids: jax.Array,  # [P] int32; -1 marks padding
-    slot_indices: jax.Array,  # [P] int32 flat cache slots per token
-    k_cache: tuple,  # per layer: pages [Hkv, nb, bs, D], or the state [S, N, Di]
-    v_cache: tuple,  # per layer: pages, or the tail [S, (K-1)*Di]
-    last_idx: jax.Array,  # [N] int32
-    *,
-    state_slots: jax.Array,  # [N] int32: the lane slot of each segment
-    mesh=None,
-) -> tuple[jax.Array, tuple, tuple]:
-    """Fresh prompts: every segment's state starts from zero at its position
-    0 and ends in its slot. Returns (logits [N, V], caches)."""
-    n_seg, n_tok = last_idx.shape[0], tokens.shape[0]
-    null = k_cache[_first(cfg, False)].shape[0] - 1
-    valid = segment_ids >= 0
+def _packed_slots(cfg, *, positions, segment_ids, last_idx, state_slots, null, **_):
+    """The packed program's lane slots: each segment's (`seg_slots` [N]) and
+    each token's (`write_slots` [P])."""
+    n_seg, n_tok = last_idx.shape[0], positions.shape[0]
     # a segment that holds no prompt sends what is computed for it to the
     # null lane; a sequence's last token writes its state to its slot
     used = jnp.arange(n_seg) <= jnp.max(segment_ids)
@@ -505,114 +463,36 @@ def prefill_packed(
     write_slots = jnp.full((n_tok,), null, jnp.int32).at[
         jnp.where(used, last_idx, n_tok)
     ].set(seg_slots.astype(jnp.int32), mode="drop")
-    x, k_out, v_out = _walk(
-        params, cfg, params["embed"][tokens], k_cache, v_cache,
-        lambda x, layer, s, t: _mamba_packed_layer(
-            x, layer, s, t, positions, valid, write_slots, last_idx, seg_slots, cfg=cfg),
-        lambda x, layer, k, v: _attn_packed_layer(
-            x, layer, k, v, segment_ids, slot_indices, cfg=cfg),
-    )
-    return _logits(x[last_idx], params, cfg), k_out, v_out
+    return {"seg_slots": seg_slots, "write_slots": write_slots}
 
 
-def prefill(
-    params, cfg, tokens, valid_len, k_cache, v_cache, block_table,
-    *, state_slots, mesh=None, attn_head_axis=None,
-):
-    """One whole prompt (padded to a bucket): the packed program with one
-    segment. `state_slots`: its lane slot (scalar). Returns (logits [V],
-    caches)."""
-    P = tokens.shape[0]
-    bs = _page_size(cfg, k_cache)
-    pos = jnp.arange(P, dtype=jnp.int32)
-    live = pos < valid_len
-    slots = jnp.where(live, block_table[pos // bs] * bs + pos % bs, 0)
-    logits, k_out, v_out = prefill_packed(
-        params, cfg, tokens, pos, jnp.where(live, 0, -1), slots,
-        k_cache, v_cache, (valid_len - 1)[None],
-        state_slots=jnp.reshape(state_slots, (1,)),
-    )
-    return logits[0], k_out, v_out
-
-
-def prefill_chunk(
-    params: dict,
-    cfg: HybridSsmConfig,
-    tokens: jax.Array,  # [C] int32
-    chunk_start: jax.Array,  # scalar int32
-    valid_len: jax.Array,  # scalar int32: total prompt length
-    k_cache: tuple,
-    v_cache: tuple,
-    block_table: jax.Array,  # [max_nb] int32
-    *,
-    state_slots: jax.Array,  # scalar int32: the sequence's lane slot
-    mesh=None,
-) -> tuple[jax.Array, tuple, tuple]:
-    """One chunk of a chunked prefill: the state is taken from the
-    sequence's slot (zero at `chunk_start` 0) and left there; keys and values
-    are written, then the chunk attends over what the cache holds."""
-    C = tokens.shape[0]
-    bs = _page_size(cfg, k_cache)
-    positions = chunk_start + jnp.arange(C, dtype=jnp.int32)
-    valid = positions < valid_len
-    # the table is read behind its end by a last chunk's padded tail:
-    # those rows go to the null block
-    n = block_table.shape[0]
-    page = jnp.where(positions // bs < n, block_table[jnp.minimum(positions // bs, n - 1)], 0)
-    slots = jnp.where(valid, page * bs + positions % bs, 0)
-    slot = jnp.reshape(state_slots, ())
-    x, k_out, v_out = _walk(
-        params, cfg, params["embed"][tokens], k_cache, v_cache,
-        lambda x, layer, s, t: _mamba_chunk_layer(
-            x, layer, s, t, positions, valid, slot, chunk_start, cfg=cfg),
-        lambda x, layer, k, v: _attn_chunk_layer(
-            x, layer, k, v, slots, block_table, chunk_start, cfg=cfg),
-    )
-    idx = jnp.clip(valid_len - 1 - chunk_start, 0, C - 1)
-    return _logits(x[idx][None, :], params, cfg)[0], k_out, v_out
-
-
-def decode(
-    params: dict,
-    cfg: HybridSsmConfig,
-    tokens: jax.Array,  # [B] int32
-    positions: jax.Array,  # [B] int32
-    k_cache: tuple,
-    v_cache: tuple,
-    block_tables: jax.Array,  # [B, max_blocks] int32
-    slot_indices: jax.Array,  # [B] int32; a slot in the null block = idle lane
-    *,
-    mesh=None,
-    attn_head_axis=None,
-) -> tuple[jax.Array, tuple, tuple]:
-    """One decode step for a batch; lane b's state is row b of the slot
-    arrays. A lane whose row goes to the null block holds no decoding
-    sequence: it reads no page and its slot stays as it is. Returns (logits
-    [B, V], caches)."""
-    live = live_decode_lanes(k_cache[_first(cfg, True)], slot_indices)
-    context = jnp.where(live, positions + 1, 0)
-    x, k_out, v_out = _walk(
-        params, cfg, params["embed"][tokens], k_cache, v_cache,
-        lambda x, layer, s, t: _mamba_decode_layer(x, layer, s, t, live, cfg=cfg),
-        lambda x, layer, k, v: _attn_decode_layer(
-            x, layer, k, v, context, block_tables, slot_indices,
-            cfg=cfg, mesh=mesh, head_axis=attn_head_axis),
-    )
-    return _logits(x, params, cfg), k_out, v_out
-
-
-def _not_served(what: str):
-    def refuse(*_a, **_k):
-        raise NotImplementedError(
-            f"{what} is not implemented for the hybrid state-space family"
-        )
-
-    return refuse
-
-
-prefill_mm = _not_served("multimodal prefill")
-prefill_context_parallel = _not_served("context-parallel prefill")
-embed_pooled = _not_served("pooled embedding")
-decode_verify = _not_served(
-    "speculative verification (a rejected draft would need the state rolled back)"
+# A layer by whether it attends. Either kind keeps two arrays: pages of keys
+# and values `[Hkv, nb, bs, D]`, or the state `[S, N, Di]` and the tail
+# `[S, (K-1)*Di]`.
+FAMILY = Family(
+    kind=HybridSsmConfig.is_attn_layer,
+    prepare={"packed": _packed_slots},
+    packed={
+        False: Body(_mamba_packed_layer, 2, (
+            "positions", "valid", "write_slots", "last_idx", "seg_slots")),
+        True: Body(_attn_packed_layer, 2, ("segment_ids", "slot_indices")),
+    },
+    chunk={
+        False: Body(_mamba_chunk_layer, 2, (
+            "positions", "valid", "lane_slot", "chunk_start")),
+        True: Body(_attn_chunk_layer, 2, (
+            "slot_indices", "block_table", "chunk_start")),
+    },
+    decode={
+        False: Body(_mamba_decode_layer, 2, ("live",)),
+        True: Body(
+            _attn_decode_layer, 2, ("context", "block_tables", "slot_indices"),
+            static=("mesh", "head_axis"),
+        ),
+    },
+)
+prefill_packed, prefill, prefill_chunk, decode = programs.bound(FAMILY)
+prefill_mm, prefill_context_parallel, embed_pooled, decode_verify = programs.refused(
+    "the hybrid state-space family",
+    "speculative verification (a rejected draft would need the state rolled back)",
 )

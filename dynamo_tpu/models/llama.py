@@ -123,10 +123,8 @@ class LlamaConfig:
     # MoE (Mixtral-style): num_experts == 0 means dense SwiGLU FFN
     num_experts: int = 0
     num_experts_per_tok: int = 2
+    # the all-to-all dispatch's bucket a destination shard (`_mlp`)
     moe_capacity_factor: float = 1.25
-    # None -> regime-based (a2a / psum / dropless, see _mlp);
-    # "gshard" -> force the capacity-bucketed GSPMD einsum dispatch
-    moe_impl: Optional[str] = None
 
     @classmethod
     def from_hf_dict(cls, d: dict[str, Any]) -> "LlamaConfig":
@@ -273,6 +271,9 @@ class LlamaConfig:
     @property
     def kv_dim(self) -> int:
         return self.num_kv_heads * self.head_dim
+
+
+CONFIG = LlamaConfig  # `models.served_model_types` reads it
 
 
 # ------------------------------------------------------------------ params
@@ -552,7 +553,6 @@ def _mlp(x, layer, cfg, mesh=None):
     h = rms_norm(x, layer["mlp_norm"], cfg.rms_eps)
     if "router" in layer:
         from dynamo_tpu.ops.moe import (
-            moe_ffn,
             moe_ffn_dropless,
             moe_ffn_ep_a2a,
             moe_ffn_shard_map,
@@ -576,12 +576,6 @@ def _mlp(x, layer, cfg, mesh=None):
             else:
                 # decode-size batches: replicated-token psum (dropless)
                 y = moe_ffn_shard_map(mesh, *args, top_k=k)
-        elif cfg.moe_impl == "gshard":
-            # explicit opt-in to the capacity-bucketed GSPMD einsum path
-            # (params GSPMD-ep-sharded without an explicit mesh in hand)
-            y = moe_ffn(
-                *args, top_k=k, capacity_factor=cfg.moe_capacity_factor
-            )
         else:
             # single chip / pure-TP mesh: dropless grouped-GEMM (exact
             # serving semantics); GSPMD shards the FFN feature dim over tp

@@ -5,7 +5,8 @@ The MoE analogue of the reference's SGLang WideEP deployments
 here a Mixtral-style model is a LlamaConfig with num_experts > 0 — the
 attention stack, paged cache, context-parallel prefill, and engine are
 shared with the dense family (models/llama.py), the FFN routes through
-ops/moe.py (GShard dispatch; experts shard over the `ep` mesh axis).
+ops/moe.py (dropless sort and grouped products; under an `ep` mesh axis the
+experts shard over it and tokens reach them by all-to-all or psum).
 
 This module is the HF-facing front-end: config presets + weight loading
 glue for `model_type: mixtral` checkpoints.

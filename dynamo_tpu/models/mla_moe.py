@@ -41,9 +41,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from dynamo_tpu.models import CacheKind, latent_cache, layer_body
+from dynamo_tpu.models import CacheKind, latent_cache, layer_body, programs
+from dynamo_tpu.models.programs import Body, Family
 from dynamo_tpu.ops import mla
-from dynamo_tpu.ops.attention import live_decode_lanes
 from dynamo_tpu.ops.basics import rms_norm, rope_freqs, swiglu
 from dynamo_tpu.ops.kv_quant import scatter_token_rows
 from dynamo_tpu.ops.linear import linear
@@ -167,6 +167,9 @@ class MlaMoeConfig:
 
     def cache_kind(self) -> CacheKind:
         return latent_cache(self.kv_lora_rank + self.qk_rope_head_dim)
+
+
+CONFIG = MlaMoeConfig  # `models.served_model_types` reads it
 
 
 # ------------------------------------------------------------------ params
@@ -367,14 +370,6 @@ def _ffn(x, layer, cfg, valid):
     return x + y.astype(x.dtype), counted
 
 
-def _logits(x, params, cfg):
-    h = rms_norm(x, params["final_norm"], cfg.rms_eps)
-    w = params.get("lm_head")
-    if w is None:
-        return jnp.matmul(h, params["embed"].T.astype(h.dtype)).astype(jnp.float32)
-    return linear(h, w).astype(jnp.float32)
-
-
 def _inv_freqs(cfg):
     return rope_freqs(cfg.qk_rope_head_dim, cfg.rope_theta, None)
 
@@ -439,127 +434,23 @@ def _decode_layer(x, layer, plane, inv_freqs, positions, live, context, block_ta
     return x, plane, counted
 
 
-def _walk(body, params, cfg, x, planes, stats, *args):
-    """`body` over the layers, each with its own plane; returns x and the
-    planes, and appends the expert layers' counters to `stats` if given."""
-    out = []
-    for layer, plane in zip(params["layers"], planes):
-        x, plane, counted = body(x, layer, plane, *args, cfg=cfg)
-        out.append(plane)
-        if stats is not None and counted is not None:
-            stats.append(counted)
-    return x, tuple(out)
-
-
-def prefill_packed(
-    params: dict,
-    cfg: MlaMoeConfig,
-    tokens: jax.Array,  # [P] int32: several prompts packed back to back
-    positions: jax.Array,  # [P] int32: restart at 0 per segment
-    segment_ids: jax.Array,  # [P] int32; -1 marks padding
-    slot_indices: jax.Array,  # [P] int32 flat cache slots per token
-    k_cache: tuple,  # per layer [1, num_blocks, block_size, stored_width]
-    v_cache: tuple,  # ()
-    last_idx: jax.Array,  # [N] int32
-    *,
-    mesh=None,
-    stats: Optional[list] = None,
-) -> tuple[jax.Array, tuple, tuple]:
-    """Fresh prompts, nothing earlier in the cache: the per-head form.
-    Returns (logits [N, V], planes, ())."""
-    x, planes = _walk(
-        _packed_layer, params, cfg, params["embed"][tokens], k_cache, stats,
-        _inv_freqs(cfg), positions, segment_ids, slot_indices,
-    )
-    return _logits(x[last_idx], params, cfg), planes, ()
-
-
-def prefill(
-    params, cfg, tokens, valid_len, k_cache, v_cache, block_table,
-    *, mesh=None, attn_head_axis=None,
-):
-    """One whole prompt (padded to a bucket): the packed program with one
-    segment. Returns (logits [V], planes, ())."""
-    P = tokens.shape[0]
-    bs = k_cache[0].shape[2]
-    pos = jnp.arange(P, dtype=jnp.int32)
-    live = pos < valid_len
-    slots = jnp.where(live, block_table[pos // bs] * bs + pos % bs, 0)
-    logits, planes, _ = prefill_packed(
-        params, cfg, tokens, pos, jnp.where(live, 0, -1), slots,
-        k_cache, v_cache, (valid_len - 1)[None],
-    )
-    return logits[0], planes, ()
-
-
-def prefill_chunk(
-    params: dict,
-    cfg: MlaMoeConfig,
-    tokens: jax.Array,  # [C] int32
-    chunk_start: jax.Array,  # scalar int32
-    valid_len: jax.Array,  # scalar int32: total prompt length
-    k_cache: tuple,
-    v_cache: tuple,
-    block_table: jax.Array,  # [max_nb] int32
-    *,
-    mesh=None,
-    stats: Optional[list] = None,
-) -> tuple[jax.Array, tuple, tuple]:
-    """One chunk of a chunked prefill: its rows are written, then it attends
-    in the absorbed form over everything the cache holds of its prompt."""
-    C = tokens.shape[0]
-    bs = k_cache[0].shape[2]
-    positions = chunk_start + jnp.arange(C, dtype=jnp.int32)
-    valid = positions < valid_len
-    # the table is read behind its end by a last chunk's padded tail:
-    # those rows go to the null block
-    n = block_table.shape[0]
-    page = jnp.where(positions // bs < n, block_table[jnp.minimum(positions // bs, n - 1)], 0)
-    slots = jnp.where(valid, page * bs + positions % bs, 0)
-    x, planes = _walk(
-        _chunk_layer, params, cfg, params["embed"][tokens], k_cache, stats,
-        _inv_freqs(cfg), positions, valid, slots, block_table, chunk_start,
-    )
-    idx = jnp.clip(valid_len - 1 - chunk_start, 0, C - 1)
-    return _logits(x[idx][None, :], params, cfg)[0], planes, ()
-
-
-def decode(
-    params: dict,
-    cfg: MlaMoeConfig,
-    tokens: jax.Array,  # [B] int32
-    positions: jax.Array,  # [B] int32
-    k_cache: tuple,
-    v_cache: tuple,
-    block_tables: jax.Array,  # [B, max_blocks] int32
-    slot_indices: jax.Array,  # [B] int32; a slot in the null block = idle lane
-    *,
-    mesh=None,
-    attn_head_axis=None,
-    stats: Optional[list] = None,
-) -> tuple[jax.Array, tuple, tuple]:
-    """One decode step for a batch, absorbed form. A lane whose row goes to
-    the null block holds no request: it reads no cache page and is given to
-    no expert. Returns (logits [B, V], planes, ())."""
-    live = live_decode_lanes(k_cache[0], slot_indices)
-    context = jnp.where(live, positions + 1, 0)
-    x, planes = _walk(
-        _decode_layer, params, cfg, params["embed"][tokens], k_cache, stats,
-        _inv_freqs(cfg), positions, live, context, block_tables, slot_indices,
-    )
-    return _logits(x, params, cfg), planes, ()
-
-
-def _not_served(what: str):
-    def refuse(*_a, **_k):
-        raise NotImplementedError(
-            f"{what} is not implemented for the latent-attention family"
-        )
-
-    return refuse
-
-
-prefill_mm = _not_served("multimodal prefill")
-prefill_context_parallel = _not_served("context-parallel prefill")
-embed_pooled = _not_served("pooled embedding")
-decode_verify = _not_served("speculative verification")
+# Every layer alike: it keeps one plane `[1, num_blocks, block_size,
+# stored_width]` and nothing beside it (`v_cache` is `()`). Fresh prompts
+# attend in the per-head form; a chunk and a decode step in the absorbed form
+# over everything the cache holds.
+FAMILY = Family(
+    kind=lambda cfg, i: "latent",
+    behind_embedding={"inv_freqs": _inv_freqs},
+    packed={"latent": Body(_packed_layer, 1, (
+        "inv_freqs", "positions", "segment_ids", "slot_indices"))},
+    chunk={"latent": Body(_chunk_layer, 1, (
+        "inv_freqs", "positions", "valid", "slot_indices", "block_table",
+        "chunk_start"))},
+    decode={"latent": Body(_decode_layer, 1, (
+        "inv_freqs", "positions", "live", "context", "block_tables",
+        "slot_indices"))},
+)
+prefill_packed, prefill, prefill_chunk, decode = programs.bound(FAMILY)
+prefill_mm, prefill_context_parallel, embed_pooled, decode_verify = programs.refused(
+    "the latent-attention family"
+)

@@ -90,11 +90,14 @@ import jax.numpy as jnp
 from jax import lax
 
 from dynamo_tpu.models import (
-    CacheKind, keeps_nothing, kv_heads_cache, layer_body, recurrent_state,
+    CacheKind, keeps_nothing, kv_heads_cache, layer_body, programs,
+    recurrent_state,
 )
+from dynamo_tpu.models.programs import Body, Family
+from dynamo_tpu.models.programs import dense as _dense, normal as _normal
 from dynamo_tpu.ops import ssm
 from dynamo_tpu.ops.attention import (
-    chunked_prefill_attention, decode_append_attention, live_decode_lanes,
+    chunked_prefill_attention, decode_append_attention,
     packed_prefill_attention, write_decode_kv,
 )
 from dynamo_tpu.ops.basics import rms_norm
@@ -288,6 +291,9 @@ class Ssm2MoeConfig:
         return tuple(by_kind[k] for k in self.pattern)
 
 
+CONFIG = Ssm2MoeConfig  # `models.served_model_types` reads it
+
+
 # ------------------------------------------------------------------ params
 
 KEYS_PER_LAYER = 12
@@ -344,18 +350,6 @@ def init_params(
         layers.append(_draw_layer(keys[used: used + n], c=c, dtype=dtype, kind=kind))
         used += n
     return {"layers": layers, **_draw_top(keys[used: used + 2], c=c, dtype=dtype)}
-
-
-def _normal(key, shape):
-    """A float32 normal draw that a jit leaves as it is (`models/conv_moe.py`
-    `_normal`: behind the barrier the compiler cannot fold the draw's own
-    constants into what multiplies or divides it next)."""
-    return lax.optimization_barrier(jax.random.normal(key, shape, dtype=F32))
-
-
-def _dense(key, shape, fan_in, dtype):
-    by = lax.optimization_barrier(jnp.sqrt(F32(fan_in)))
-    return (_normal(key, shape) / by).astype(dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("c", "dtype", "kind"))
@@ -458,14 +452,6 @@ def param_count(config: Ssm2MoeConfig) -> int:
 
 
 # ----------------------------------------------------------------- forward
-
-
-def _logits(x, params, cfg):
-    h = rms_norm(x, params["final_norm"], cfg.rms_eps)
-    w = params.get("lm_head")
-    if w is None:
-        return jnp.matmul(h, params["embed"].T.astype(h.dtype)).astype(F32)
-    return linear(h, w).astype(F32)
 
 
 def _qkv(x, layer, cfg):
@@ -646,171 +632,47 @@ def _attn_decode_layer(x, layer, k_l, v_l, context, block_tables, slot_indices, 
 _expert_layer = layer_body("cfg")(_experts)
 
 
-def _walk(params, cfg, x, k_cache, v_cache, mamba, attend, valid, stats=None):
-    """The layers in order, each with what it keeps; `mamba` and `attend` are
-    (x, layer, first, second) -> (x, first, second); an expert layer keeps
-    nothing and is told which tokens are real (`valid`). The expert layers'
-    counters are appended to `stats` where a list is given."""
-    k_out, v_out = [], []
-    for i, layer in enumerate(params["layers"]):
-        kind = cfg.kind(i)
-        if kind == "E":
-            x, counted = _expert_layer(x, layer, valid, cfg=cfg)
-            a = b = None
-            if stats is not None:
-                stats.append(counted)
-        else:
-            body = attend if kind == "*" else mamba
-            x, a, b = body(x, layer, k_cache[i], v_cache[i])
-        k_out.append(a)
-        v_out.append(b)
-    return x, tuple(k_out), tuple(v_out)
-
-
-def _first(cfg, kind: str) -> int:
-    """The first layer of a kind."""
-    return cfg.pattern.index(kind)
-
-
-def _page_size(cfg, k_cache) -> int:
-    return k_cache[_first(cfg, "*")].shape[2]
-
-
-def prefill_packed(
-    params: dict,
-    cfg: Ssm2MoeConfig,
-    tokens: jax.Array,  # [P] int32: several prompts packed back to back
-    positions: jax.Array,  # [P] int32: restart at 0 per segment
-    segment_ids: jax.Array,  # [P] int32; -1 marks padding
-    slot_indices: jax.Array,  # [P] int32 flat cache slots per token
-    k_cache: tuple,  # per layer: pages [Hkv, nb, bs, D], the state [S, Hm, P, N], or None
-    v_cache: tuple,  # per layer: pages, the tail [S, (K-1)*conv_dim], or None
-    last_idx: jax.Array,  # [N] int32
-    *,
-    state_slots: jax.Array,  # [N] int32: the lane slot of each segment
-    mesh=None,
-) -> tuple[jax.Array, tuple, tuple]:
-    """Fresh prompts: every segment's state starts from zero at its position
-    0 and ends in its slot. Returns (logits [N, V], caches)."""
-    null = k_cache[_first(cfg, "M")].shape[0] - 1
-    valid = segment_ids >= 0
+def _packed_slots(cfg, *, segment_ids, last_idx, state_slots, null, **_):
+    """The packed program's lane slots (`seg_slots` [N]) and how many of its
+    segments hold a prompt (`count`)."""
     # a segment that holds no prompt sends what is computed for it to the
     # null lane
     count = jnp.max(segment_ids) + 1
     used = jnp.arange(last_idx.shape[0]) < count
     seg_slots = jnp.where(used, state_slots, null).astype(jnp.int32)
-    x, k_out, v_out = _walk(
-        params, cfg, params["embed"][tokens], k_cache, v_cache,
-        lambda x, layer, s, t: _mamba_packed_layer(
-            x, layer, s, t, positions, valid, last_idx, seg_slots, count, cfg=cfg),
-        lambda x, layer, k, v: _attn_packed_layer(
-            x, layer, k, v, segment_ids, slot_indices, cfg=cfg),
-        valid,
-    )
-    return _logits(x[last_idx], params, cfg), k_out, v_out
+    return {"seg_slots": seg_slots, "count": count}
 
 
-def prefill(
-    params, cfg, tokens, valid_len, k_cache, v_cache, block_table,
-    *, state_slots, mesh=None, attn_head_axis=None,
-):
-    """One whole prompt (padded to a bucket): the packed program with one
-    segment. `state_slots`: its lane slot (scalar). Returns (logits [V],
-    caches)."""
-    P = tokens.shape[0]
-    bs = _page_size(cfg, k_cache)
-    pos = jnp.arange(P, dtype=jnp.int32)
-    live = pos < valid_len
-    slots = jnp.where(live, block_table[pos // bs] * bs + pos % bs, 0)
-    logits, k_out, v_out = prefill_packed(
-        params, cfg, tokens, pos, jnp.where(live, 0, -1), slots,
-        k_cache, v_cache, (valid_len - 1)[None],
-        state_slots=jnp.reshape(state_slots, (1,)),
-    )
-    return logits[0], k_out, v_out
-
-
-def prefill_chunk(
-    params: dict,
-    cfg: Ssm2MoeConfig,
-    tokens: jax.Array,  # [C] int32
-    chunk_start: jax.Array,  # scalar int32
-    valid_len: jax.Array,  # scalar int32: total prompt length
-    k_cache: tuple,
-    v_cache: tuple,
-    block_table: jax.Array,  # [max_nb] int32
-    *,
-    state_slots: jax.Array,  # scalar int32: the sequence's lane slot
-    mesh=None,
-) -> tuple[jax.Array, tuple, tuple]:
-    """One chunk of a chunked prefill: the state is taken from the
-    sequence's slot (zero at `chunk_start` 0) and left there; keys and values
-    are written, then the chunk attends over what the cache holds."""
-    C = tokens.shape[0]
-    bs = _page_size(cfg, k_cache)
-    positions = chunk_start + jnp.arange(C, dtype=jnp.int32)
-    valid = positions < valid_len
-    # the table is read behind its end by a last chunk's padded tail:
-    # those rows go to the null block
-    n = block_table.shape[0]
-    page = jnp.where(positions // bs < n, block_table[jnp.minimum(positions // bs, n - 1)], 0)
-    slots = jnp.where(valid, page * bs + positions % bs, 0)
-    slot = jnp.reshape(state_slots, ())
-    x, k_out, v_out = _walk(
-        params, cfg, params["embed"][tokens], k_cache, v_cache,
-        lambda x, layer, s, t: _mamba_chunk_layer(
-            x, layer, s, t, positions, valid, slot, chunk_start, cfg=cfg),
-        lambda x, layer, k, v: _attn_chunk_layer(
-            x, layer, k, v, slots, block_table, chunk_start, cfg=cfg),
-        valid,
-    )
-    idx = jnp.clip(valid_len - 1 - chunk_start, 0, C - 1)
-    return _logits(x[idx][None, :], params, cfg)[0], k_out, v_out
-
-
-def decode(
-    params: dict,
-    cfg: Ssm2MoeConfig,
-    tokens: jax.Array,  # [B] int32
-    positions: jax.Array,  # [B] int32
-    k_cache: tuple,
-    v_cache: tuple,
-    block_tables: jax.Array,  # [B, max_blocks] int32
-    slot_indices: jax.Array,  # [B] int32; a slot in the null block = idle lane
-    *,
-    mesh=None,
-    attn_head_axis=None,
-    stats: Optional[list] = None,
-) -> tuple[jax.Array, tuple, tuple]:
-    """One decode step for a batch; lane b's state is row b of the slot
-    arrays. A lane whose row goes to the null block holds no decoding
-    sequence: it reads no page, is given to no expert, and its slot stays as
-    it is. Returns (logits [B, V], caches)."""
-    live = live_decode_lanes(k_cache[_first(cfg, "*")], slot_indices)
-    context = jnp.where(live, positions + 1, 0)
-    x, k_out, v_out = _walk(
-        params, cfg, params["embed"][tokens], k_cache, v_cache,
-        lambda x, layer, s, t: _mamba_decode_layer(x, layer, s, t, live, cfg=cfg),
-        lambda x, layer, k, v: _attn_decode_layer(
-            x, layer, k, v, context, block_tables, slot_indices,
-            cfg=cfg, mesh=mesh, head_axis=attn_head_axis),
-        live, stats,
-    )
-    return _logits(x, params, cfg), k_out, v_out
-
-
-def _not_served(what: str):
-    def refuse(*_a, **_k):
-        raise NotImplementedError(
-            f"{what} is not implemented for the Mamba-2, latent-expert family"
-        )
-
-    return refuse
-
-
-prefill_mm = _not_served("multimodal prefill")
-prefill_context_parallel = _not_served("context-parallel prefill")
-embed_pooled = _not_served("pooled embedding")
-decode_verify = _not_served(
-    "speculative verification (a rejected draft would need the state rolled back)"
+# A layer by its letter in the pattern. `M` keeps the state `[S, Hm, P, N]`
+# and the tail `[S, (K-1)*conv_dim]`, `*` pages of keys and values
+# `[Hkv, nb, bs, D]`; `E` keeps nothing and is told which tokens are real.
+FAMILY = Family(
+    kind=Ssm2MoeConfig.kind,
+    prepare={"packed": _packed_slots},
+    packed={
+        "M": Body(_mamba_packed_layer, 2, (
+            "positions", "valid", "last_idx", "seg_slots", "count")),
+        "*": Body(_attn_packed_layer, 2, ("segment_ids", "slot_indices")),
+        "E": Body(_expert_layer, 0, ("valid",)),
+    },
+    chunk={
+        "M": Body(_mamba_chunk_layer, 2, (
+            "positions", "valid", "lane_slot", "chunk_start")),
+        "*": Body(_attn_chunk_layer, 2, (
+            "slot_indices", "block_table", "chunk_start")),
+        "E": Body(_expert_layer, 0, ("valid",)),
+    },
+    decode={
+        "M": Body(_mamba_decode_layer, 2, ("live",)),
+        "*": Body(
+            _attn_decode_layer, 2, ("context", "block_tables", "slot_indices"),
+            static=("mesh", "head_axis"),
+        ),
+        "E": Body(_expert_layer, 0, ("live",)),
+    },
+)
+prefill_packed, prefill, prefill_chunk, decode = programs.bound(FAMILY)
+prefill_mm, prefill_context_parallel, embed_pooled, decode_verify = programs.refused(
+    "the Mamba-2, latent-expert family",
+    "speculative verification (a rejected draft would need the state rolled back)",
 )

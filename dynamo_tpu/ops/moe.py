@@ -21,11 +21,6 @@ way, with dispatch paths chosen by regime:
     sees all T tokens, computes only its local experts' assignments
     (dropless, weight-masked), one psum combines. Right for tiny decode
     batches where an all-to-all would be latency-bound.
-  * `moe_ffn` — GShard-style dispatch/combine einsums over a capacity-
-    bucketed [T, E, C] routing tensor; the pure-GSPMD fallback ("annotate
-    shardings, let XLA insert collectives"). Token axis is chunked so
-    dispatch memory stays O(chunk^2), and routing weights renormalize
-    over surviving assignments when capacity drops occur.
 
 Routing: softmax over router logits, top-k experts per token, weights
 renormalized over the selected k (Mixtral semantics, `router_topk`); or
@@ -48,19 +43,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 from dynamo_tpu.ops.basics import rms_norm, swiglu
 from dynamo_tpu.ops.grouped_product import grouped_product
 from dynamo_tpu.ops.linear import linear
-
-
-def default_capacity(T: int, E: int, top_k: int, factor: float) -> int:
-    """Expert capacity: DROPLESS (capacity = T) for decode-sized batches,
-    where routing collisions are routine (B=4, E=8, top_k=2 gives only 1
-    slot/expert under the classic T*k/E rule — a dropped token silently
-    corrupts its logits). Large prefill T keeps the capacity-factor bucket:
-    the [T, E, C] dispatch tensor at C=T would be quadratic in prompt
-    length, and balanced routers essentially never overflow factor*mean.
-    """
-    if T <= 64:
-        return T
-    return max(int(factor * T * top_k / E), top_k)
 
 
 def router_topk(
@@ -93,47 +75,6 @@ def router_sigmoid_topk(
     if renormalize:
         weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + eps)
     return idx, weights * scale
-
-
-def make_dispatch(
-    idx: jax.Array,  # [T, k] int32 expert ids
-    weights: jax.Array,  # [T, k] f32
-    num_experts: int,
-    capacity: int,
-    mask: Optional[jax.Array] = None,  # [T, k] bool: valid assignments
-) -> tuple[jax.Array, jax.Array]:
-    """Build GShard dispatch/combine tensors.
-
-    dispatch [T, E, C] bool: token t occupies slot c of expert e.
-    combine  [T, E, C] f32: same positions carrying the routing weight.
-    Slot assignment is order-of-arrival per expert (cumsum); tokens past
-    capacity are dropped from that expert. Masked-out assignments neither
-    dispatch nor consume capacity (used by the EP shard_map path to keep
-    only this shard's experts).
-    """
-    T, k = idx.shape
-    onehot = jax.nn.one_hot(idx, num_experts, dtype=jnp.int32)  # [T, k, E]
-    if mask is not None:
-        onehot = onehot * mask[..., None].astype(jnp.int32)
-    # position of (t, k) within expert e's queue, counting over t-major
-    flat = onehot.reshape(T * k, num_experts)
-    pos = jnp.cumsum(flat, axis=0) - flat  # [T*k, E]
-    pos = pos.reshape(T, k, num_experts)
-    in_cap = pos < capacity
-    slot = jnp.clip(pos, 0, capacity - 1)
-    disp = (
-        jax.nn.one_hot(slot, capacity, dtype=jnp.float32)
-        * (onehot * in_cap)[..., None]
-    )  # [T, k, E, C]
-    combine = disp * weights[:, :, None, None]
-    return disp.sum(1), combine.sum(1)  # [T, E, C] each
-
-
-def _expert_ffn(xe: jax.Array, wg, wu, wd) -> jax.Array:
-    """Per-expert SwiGLU FFN on dispatched tokens xe [E, C, D]."""
-    gate = jnp.einsum("ecd,edf->ecf", xe, wg)
-    up = jnp.einsum("ecd,edf->ecf", xe, wu)
-    return jnp.einsum("ecf,efd->ecd", swiglu(gate, up), wd)
 
 
 def _grouped_ffn(
@@ -304,61 +245,6 @@ def moe_ffn_dropless(
     )
     idx, weights = router_topk(logits, top_k)  # [T, k]
     y, _ = dropless_experts(x, idx, weights, wg, wu, wd)
-    return y.astype(x.dtype)
-
-
-def moe_ffn(
-    x: jax.Array,  # [T, D]
-    router_w: jax.Array,  # [D, E]
-    wg: jax.Array,  # [E, D, F] expert gate projections
-    wu: jax.Array,  # [E, D, F]
-    wd: jax.Array,  # [E, F, D]
-    top_k: int,
-    capacity_factor: float = 1.25,
-    capacity: Optional[int] = None,
-    token_chunk: int = 512,
-) -> jax.Array:
-    """GShard-dispatch MoE FFN (pure-GSPMD fallback path).
-
-    With wg/wu/wd sharded P("ep", ...) and x dp/sp-sharded, XLA inserts the
-    token all-to-all at the dispatch einsum and the reverse at combine.
-
-    The token axis is processed in `token_chunk`-sized chunks so the
-    [T, E, C] dispatch tensors stay O(chunk^2) instead of O(T^2) (ADVICE
-    r1: an 8k-token prefill would otherwise materialize GB-scale dispatch
-    tensors). Routing weights renormalize over surviving assignments when
-    capacity overflow drops occur, so a drop degrades smoothly instead of
-    silently deleting a token's expert contribution.
-    """
-    T, D = x.shape
-    if capacity is None and token_chunk and T > token_chunk:
-        pad = (-T) % token_chunk
-        xp = jnp.pad(x, ((0, pad), (0, 0)))
-        chunks = xp.reshape(-1, token_chunk, D)
-        yc = jax.vmap(
-            lambda c: moe_ffn(
-                c, router_w, wg, wu, wd, top_k,
-                capacity_factor=capacity_factor, token_chunk=0,
-            )
-        )(chunks)
-        return yc.reshape(-1, D)[:T]
-    E = router_w.shape[-1]
-    logits = jnp.einsum(
-        "td,de->te", x.astype(jnp.float32), router_w.astype(jnp.float32)
-    )
-    idx, weights = router_topk(logits, top_k)
-    if capacity is None:
-        capacity = default_capacity(T, E, top_k, capacity_factor)
-    disp, combine = make_dispatch(idx, weights, E, capacity)
-    xe = jnp.einsum("td,tec->ecd", x.astype(jnp.float32), disp)  # a2a here
-    ye = _expert_ffn(
-        xe.astype(x.dtype), wg, wu, wd
-    )  # [E, C, D], expert-sharded
-    y = jnp.einsum("ecd,tec->td", ye.astype(jnp.float32), combine)  # a2a back
-    # renormalize over the weight mass that actually survived capacity
-    # (kept == 1 when nothing dropped -> no-op)
-    kept = combine.sum(axis=(1, 2))  # [T]
-    y = y / jnp.maximum(kept, 1e-9)[:, None]
     return y.astype(x.dtype)
 
 
