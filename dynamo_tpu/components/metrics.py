@@ -470,6 +470,34 @@ def goodput_families(
             f"Recurrent layers: {what} (counted on the host; fleet sum)",
             value=float(ssm.get(name, 0)),
         )
+    pool = gp.pool if gp is not None else {}
+    for name, what in (
+        ("decode_steps", "decode steps"),
+        ("lane_steps", "live lanes summed over decode steps"),
+        ("window_rows", "rows the window layers of a step must read, the "
+         "sum over live lanes of min(context, window), summed over steps"),
+        ("full_rows", "rows the full layers of a step must read, the live "
+         "lanes' contexts, summed over steps"),
+        ("lanes_past_window", "live lanes whose context is past the window, "
+         "summed over decode steps"),
+        ("window_blocks_past", "window blocks those lanes held, summed over "
+         "decode steps"),
+        ("window_in_use_steps", "blocks of the window group's pool in use, "
+         "summed over decode steps"),
+        ("window_capacity_steps", "blocks of the window group's pool, "
+         "summed over decode steps"),
+        ("full_in_use_steps", "blocks of the full group's pool in use, "
+         "summed over decode steps"),
+        ("full_capacity_steps", "blocks of the full group's pool, summed "
+         "over decode steps"),
+        ("window_blocks_given_back", "window blocks given back to their "
+         "pool because the window had left them"),
+    ):
+        yield CounterMetricFamily(
+            f"{PREFIX}_pool_{name}",
+            f"Page groups: {what} (counted on the host; fleet sum)",
+            value=float(pool.get(name, 0)),
+        )
     stream = gp.stream if gp is not None else {}
     for name, what in (
         ("items", "items put on sequences' streams that carried tokens, "

@@ -374,6 +374,9 @@ class _Flight:
     # blocks of lanes the host alone ended while this dispatch named them
     # (`_free_seq`): given back when it has landed
     held: list = field(default_factory=list)
+    # window blocks that left their window while this dispatch's tables
+    # still named them (`_give_back_window`): given back when it has landed
+    held_window: list = field(default_factory=list)
     # what its own launch took where that was a call of its own, and so
     # part of its time (`runner.upload`, `runner.enqueue`: seconds)
     launch_s: tuple = (0.0, 0.0)
@@ -407,7 +410,15 @@ class JaxEngine:
             num_blocks=runner.num_blocks,
             max_model_len=runner.max_model_len,
         )
-        self.allocator = BlockAllocator(self.config.num_blocks)
+        # a model whose paged layers are of two groups (window layers beside
+        # full ones) has a pool a group; the runner builds the window
+        # group's tables from the allocator's own map of companions
+        self._window = None
+        window_blocks = getattr(runner, "window_blocks", 0)
+        self.allocator = BlockAllocator(self.config.num_blocks, window_blocks)
+        if window_blocks:
+            self._window = runner.page_groups[1].window
+            runner.window_of = self.allocator.window_of
         self.slots: list[Optional[_Sequence]] = [None] * self.config.max_batch
         # priority-then-deadline ordered admission queue (kept sorted by
         # _enqueue): (class rank, deadline, arrival) — interactive overtakes
@@ -548,7 +559,9 @@ class JaxEngine:
         self._tokens = np.zeros(B, np.int32)
         self._positions = np.zeros(B, np.int32)
         self._block_tables = np.zeros(
-            (B, self.runner.max_blocks_per_seq), np.int32
+            (B, getattr(
+                self.runner, "table_width", self.runner.max_blocks_per_seq
+            )), np.int32,
         )
         self._slot_indices = np.zeros(B, np.int32)
         self._temps = np.ones(B, np.float32)
@@ -831,6 +844,13 @@ class JaxEngine:
                 await inj.on_dispatch()
                 slow_factor = inj.dispatch_slow_factor()
 
+        # a model with a window group of pages: what this dispatch's decode
+        # steps must read and what the pools hold, from the host's numbers
+        pool_counts = (
+            self._pool_counts(horizon)
+            if launching and capacity > 0 and self._window is not None
+            else None
+        )
         first = label not in self._dispatch_ema
         split: dict = {}  # FIRST_DISPATCH_FIELDS, filled by a first dispatch
         launch = self.runner.launch  # what the call costs at the device's edge
@@ -847,6 +867,10 @@ class JaxEngine:
             state_slots=(
                 sum(s is not None for s in self.slots)
                 if self._recurrent_layers else 0
+            ),
+            **(
+                {k: pool_counts[k] for k in ("window_rows", "full_rows")}
+                if pool_counts else {}
             ),
         )
         call = dtrace.phase("runner.call", label=label)
@@ -892,9 +916,7 @@ class JaxEngine:
             if lands is not None:
                 # it has landed: what waited for it goes back, and the one
                 # queued behind it is the oldest on the device from now
-                if lands.held:
-                    self.allocator.free(lands.held)
-                    lands.held = []
+                self._release_held(lands)
                 if launches is not None:
                     launches.prev = None
                     launches.since = end
@@ -980,6 +1002,8 @@ class JaxEngine:
                 if launching:
                     if capacity > 0:
                         gp.record_sampler(pool)
+                    if pool_counts:
+                        gp.record_pool(**pool_counts)
                     if self._recurrent_layers:
                         gp.record_ssm(
                             self._recurrent_layers,
@@ -1146,8 +1170,13 @@ class JaxEngine:
         """Publish hash-chain events for newly completed blocks. None for
         a model with a recurrent layer: a router that sent a request here
         for its prefix would find keys and values for the attention layers
-        and no state for the others."""
-        if seq.hash_seq is None or self._recurrent_layers:
+        and no state for the others. None for a model whose window layers
+        give their pages back: the prefix's blocks are there for the full
+        layers and gone for the others."""
+        if (
+            seq.hash_seq is None or self._recurrent_layers
+            or self._window is not None
+        ):
             return
         new = seq.hash_seq.blocks[seq.emitted_hashes :]
         for b in new:
@@ -1293,6 +1322,7 @@ class JaxEngine:
             else:
                 self.allocator.free(seq.block_ids)
             seq.block_ids = []
+            seq.window_released = 0
         if seq in self._admit_order:
             self._admit_order.remove(seq)
         if seq in self._prefilling:
@@ -1623,6 +1653,65 @@ class JaxEngine:
             return {}
         return {"state_slots": [s.slot for s in seqs]}
 
+    def _give_back_window(self) -> None:
+        """Give back the window blocks that have wholly left their window:
+        those below the block that holds the oldest key any later query of
+        the sequence can see (the next chunk's first query, or the token a
+        decode step feeds next; the host's view, which a dispatch in flight
+        is ahead of and never behind). A block the tables of a dispatch in
+        flight still name goes back when that dispatch has landed. Counted
+        on the host from the numbers the tables are built from."""
+        w = self._window
+        if w is None:
+            return
+        bs = self.config.block_size
+        fl = self._flight
+        for seq in self.slots:
+            if seq is None or seq.pending_remote or not seq.block_ids:
+                continue
+            q_min = seq.prefill_pos if seq.prefilling else seq.pos - 1
+            upto = min(max(0, q_min - w + 1) // bs, len(seq.block_ids))
+            if upto <= seq.window_released:
+                continue
+            given = self.allocator.give_back(
+                seq.block_ids[seq.window_released : upto]
+            )
+            seq.window_released = upto
+            self.stats.goodput.record_pool(window_blocks_given_back=len(given))
+            if fl is not None and fl.names(seq):
+                fl.held_window.extend(given)
+            else:
+                self.allocator.free_window(given)
+
+    def _pool_counts(self, steps: int) -> dict:
+        """goodput.POOL_COUNTERS of one decode-family dispatch of `steps`
+        steps, from what the lane arrays are built from: a decoding lane's
+        context at step h is its tokens so far and h more."""
+        w, alloc = self._window, self.allocator
+        lanes = [
+            s for s in self.slots
+            if s is not None and not s.prefilling and not s.pending_remote
+        ]
+        ctx = (
+            np.asarray([len(s.token_ids) for s in lanes], np.int64)[:, None]
+            + np.arange(steps)[None, :]
+        )
+        past = [s for s in lanes if len(s.token_ids) > w]
+        return {
+            "decode_steps": steps,
+            "lane_steps": len(lanes) * steps,
+            "window_rows": int(np.minimum(ctx, w).sum()),
+            "full_rows": int(ctx.sum()),
+            "lanes_past_window": len(past) * steps,
+            "window_blocks_past": steps * sum(
+                len(s.block_ids) - s.window_released for s in past
+            ),
+            "window_in_use_steps": steps * alloc.window_in_use,
+            "window_capacity_steps": steps * (alloc.window_blocks - 1),
+            "full_in_use_steps": steps * alloc.in_use,
+            "full_capacity_steps": steps * (alloc.num_blocks - 1),
+        }
+
     def _room_for(self, seq: _Sequence) -> bool:
         """A free lane, and the sequence's blocks above the watermark."""
         return None in self.slots and (
@@ -1672,6 +1761,7 @@ class JaxEngine:
         """One pass of the engine loop; True when the loop must end."""
         with dtrace.phase("loop.reap"):
             self._reap_cancelled()
+            self._give_back_window()
         if self._flight is not None:
             why = self._chain_break()
             if why is not None:
@@ -1810,12 +1900,19 @@ class JaxEngine:
             )
         self._replay_horizon(fl.lanes, fl.H, packed)
 
+    def _release_held(self, fl: _Flight) -> None:
+        """What waited for the dispatch `fl` to land goes back to its pool."""
+        if fl.held:
+            self.allocator.free(fl.held)
+            fl.held = []
+        if fl.held_window:
+            self.allocator.free_window(fl.held_window)
+            fl.held_window = []
+
     def _drop_flight(self) -> None:
         fl, self._flight = self._flight, None
         while fl is not None:
-            if fl.held:
-                self.allocator.free(fl.held)
-                fl.held = []
+            self._release_held(fl)
             fl = fl.prev
         self._dispatch_info = None
 
@@ -2995,6 +3092,15 @@ class JaxEngine:
         self._tokens[i] = seq.token_ids[-1]
         self._positions[i] = pos
         self._block_tables[i, : len(seq.block_ids)] = seq.block_ids
+        if self._window is not None:
+            # the window group's table behind the full group's: each block's
+            # companion; the blocks given back keep the null block the row
+            # was cleared to
+            w = self.runner.max_blocks_per_seq + seq.window_released
+            kept = seq.block_ids[seq.window_released :]
+            self._block_tables[i, w : w + len(kept)] = (
+                self.allocator.window_of[kept]
+            )
         self._temps[i] = seq.temperature
         self._top_ps[i] = seq.top_p
         self._top_ks[i] = seq.top_k

@@ -19,7 +19,7 @@ from dynamo_tpu.engine.jax_engine.weights import load_or_init_params
 from dynamo_tpu.model_card import ModelDeploymentCard
 from dynamo_tpu.models import (
     cache_kind, config_from_model_dir, forward_for, layer_cache_kinds,
-    paged_layers, recurrent_layers,
+    page_groups, recurrent_layers,
 )
 from dynamo_tpu.runtime.logging import get_logger
 
@@ -121,8 +121,9 @@ async def build_jax_engine(
     )
     mesh = None
     kv_sharding = None
+    window_blocks = None  # the runner's default: as many as `num_blocks`
     if num_blocks is None:
-        num_blocks = default_num_blocks(
+        num_blocks, window_blocks = default_block_pools(
             config, max_len, max_batch,
             block_size=kv_block_size, quantized=quantize,
             tp=tensor_parallel_size, kv_dtype=kv_dtype,
@@ -162,6 +163,7 @@ async def build_jax_engine(
         config,
         params,
         num_blocks=num_blocks,
+        window_blocks=window_blocks,
         block_size=kv_block_size,
         max_batch=max_batch,
         max_model_len=max_len,
@@ -236,7 +238,9 @@ def refuse_unsupported(
     same way and refuses the same six and, besides, disaggregated transfer,
     peer pulls and live handoff (`ModelRunner.require_block_transfer`);
     prefix reuse it simply does not offer (the engine publishes no block
-    hashes for it)."""
+    hashes for it). A model whose paged layers are of two groups
+    (`models/afmoe.py`: window layers that give their pages back beside
+    layers that keep every position) refuses the same, for its own reasons."""
     n = recurrent_layers(config)
     kind = cache_kind(config)
     if n:
@@ -258,6 +262,29 @@ def refuse_unsupported(
             "without the state at its boundary cannot resume a sequence, and "
             "no state snapshots are kept yet",
             "speculation": "a rejected draft would need the state rolled back",
+        }
+    elif len(page_groups(config)) > 1:
+        window = page_groups(config)[1].window
+        what = (
+            f"keeps the last {window} positions alone in its window layers, "
+            "which give their blocks back, beside layers that keep every "
+            "position"
+        )
+        why = {
+            "int8_weights": "not implemented for expert stacks and the "
+            "gated attention",
+            "int8_cache": "a block's scale regrows on append, which the "
+            "paged call that appends does not do, and the two pools have no "
+            "scale planes",
+            "mesh": "the two pools have no sharding rule yet, and the "
+            "exchange between the chips that share an expert layer is not "
+            "written (a held share of the experts runs on one chip without "
+            "it)",
+            "fused_decode": "its kernels are the grouped-query block's",
+            "tiers": "and with them prefix reuse: a tier's block is every "
+            "layer's rows under one id, and a window layer's are gone once "
+            "the window has left them",
+            "speculation": "no verify program over two page groups",
         }
     elif kind.name != "kv_heads":
         what = f"keeps a {kind.name} cache of {kind.width} values a token"
@@ -324,6 +351,7 @@ def _built_facts(engine: JaxEngine) -> dict:
         "mixed_step": engine.config.mixed_step,
         "kv_quantized": runner.kv_quantized,
         "num_blocks": runner.num_blocks,
+        "window_blocks": runner.window_blocks,
         "max_batch": runner.max_batch,
         "max_model_len": runner.max_model_len,
         "mesh": dict(runner.mesh.shape) if runner.mesh is not None else None,
@@ -523,7 +551,13 @@ def hbm_budget_bytes() -> int:
     return 16 * 2**30
 
 
-def default_num_blocks(
+def default_num_blocks(config, max_len: int, max_batch: int, **kw) -> int:
+    """`default_block_pools` of a model whose paged layers are one group
+    (or the full group's share, of one with two)."""
+    return default_block_pools(config, max_len, max_batch, **kw)[0]
+
+
+def default_block_pools(
     config,
     max_len: int,
     max_batch: int,
@@ -533,11 +567,22 @@ def default_num_blocks(
     tp: int = 1,
     utilization: float = 0.85,
     kv_dtype: str = "bf16",
-) -> int:
-    """Blocks for every batch lane at full context plus slack, capped so
+) -> tuple[int, int]:
+    """(blocks, blocks of the window group's pool: 0 where there is none).
+    Blocks for every batch lane at full context plus slack, capped so
     weights + KV fit the per-device HBM budget. What a block costs comes
     from the cache the config's layers declare (`models.cache_kind`), what
-    the weights cost from its family's `param_count`."""
+    the weights cost from its family's `param_count`.
+
+    Two page groups (`models.page_groups`): a block of a group costs its own
+    layers' rows; a lane needs its whole context of the full group and no
+    more than the window, a chunk and a block or two of the other. Where the
+    budget is short of every lane at full context, both pools shrink by one
+    factor from what a batch needs whose every lane stands at three quarters
+    of the served context: between a lane's mean context over a life that
+    runs to the end (a half, which would starve the full group of a batch of
+    long sessions) and the end itself (which would starve the window group
+    of a batch of short ones, whose rows cost it four times the bytes)."""
     per_seq = (max_len + block_size - 1) // block_size
     want = max_batch * per_seq + 64
     model = forward_for(config)
@@ -564,18 +609,42 @@ def default_num_blocks(
     # a model with recurrent layers); those layers' slots, one a lane and
     # the null lane's, come off the budget first
     kinds = layer_cache_kinds(config)
-    paged = paged_layers(config)
+    groups = page_groups(config)
     slot_bytes = (max_batch + 1) * sum(k.slot_bytes for k in kinds)
-    scale_bytes = scale_bytes * paged // config.num_layers
-    block_bytes = (
-        paged * block_size
-        * kind.stored_values_per_token(tp) * kv_itemsize
-        + scale_bytes
-    )
     budget = int(hbm_budget_bytes() * utilization) - weight_bytes - slot_bytes
-    cap = max(16, budget // max(1, block_bytes))
-    if want > cap:
-        logger.warning(
-            "KV cache capped by HBM budget: want %d blocks, fit %d", want, cap
+
+    def block_bytes(group) -> int:
+        layers = sum(k == group for k in kinds)
+        return (
+            layers * block_size
+            * group.stored_values_per_token(tp) * kv_itemsize
+            + scale_bytes * layers // config.num_layers
         )
-    return min(want, cap)
+
+    if len(groups) == 1:
+        cap = max(16, budget // max(1, block_bytes(groups[0])))
+        if want > cap:
+            logger.warning(
+                "KV cache capped by HBM budget: want %d blocks, fit %d", want, cap
+            )
+        return min(want, cap), 0
+    full, window = groups
+    # a lane's most of the window group: the window, a chunk that attends
+    # across its edge, and two blocks of slack for what a launch ahead holds
+    lane_window = min(per_seq, -(-(window.window + 512) // block_size) + 2)
+    want_window = max_batch * lane_window + 64
+    if want * block_bytes(full) + want_window * block_bytes(window) <= budget:
+        return want, want_window
+    ref_full = max_batch * -(-3 * per_seq // 4) + 64
+    shrink = budget / (
+        ref_full * block_bytes(full) + want_window * block_bytes(window)
+    )
+    pools = (
+        min(want, max(16, int(ref_full * shrink))),
+        min(want_window, max(16, int(want_window * shrink))),
+    )
+    logger.warning(
+        "KV cache capped by HBM budget: want %d + %d blocks, fit %d + %d",
+        want, want_window, *pools,
+    )
+    return pools

@@ -22,8 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from dynamo_tpu.models import (
-    cache_kind, forward_for, layer_cache_kinds, paged_layers,
-    recurrent_layers,
+    cache_kind, forward_for, layer_cache_kinds, page_groups, recurrent_layers,
 )
 from dynamo_tpu.ops.sampling import (
     MAX_EOS_IDS,
@@ -191,6 +190,10 @@ class ModelRunner:
         block_size: int,
         max_batch: int,
         max_model_len: int,
+        # blocks of the window group's pool, for a model whose paged layers
+        # are of two groups (`models.page_groups`); None: as many as
+        # `num_blocks`
+        window_blocks: Optional[int] = None,
         rng_seed: int = 0,
         prefill_buckets: Optional[list[int]] = None,
         # "int8" (or jnp.int8 / np.int8) => int8-resident paged cache with
@@ -277,6 +280,27 @@ class ModelRunner:
         self.max_batch = max_batch
         self.max_model_len = max_model_len
         self.max_blocks_per_seq = (max_model_len + block_size - 1) // block_size
+        # the groups of paged layers, each with its own pool and a table a
+        # sequence (the group that keeps every position first): one for
+        # every model but one that mixes window and full attention layers.
+        # A sequence's tables ride side by side in one table argument,
+        # `max_blocks_per_seq` entries a group (`_table`)
+        self.page_groups = page_groups(config)
+        if len(self.page_groups) > 2 or (
+            len(self.page_groups) == 2 and self.page_groups[0].window is not None
+        ):
+            raise ValueError(
+                "paged layers of more than two groups, or of two windows, "
+                f"are not implemented: {self.page_groups}"
+            )
+        self.window_blocks = (
+            0 if len(self.page_groups) < 2
+            else num_blocks if window_blocks is None else window_blocks
+        )
+        self.table_width = self.max_blocks_per_seq * len(self.page_groups)
+        # each full block's companion in the window group, the allocator's
+        # own array (`BlockAllocator.window_of`): the engine hands it over
+        self.window_of: Optional[np.ndarray] = None
         self.mesh = mesh
         self.cp_min_tokens = cp_min_tokens
         self._rng_seed = rng_seed
@@ -300,8 +324,11 @@ class ModelRunner:
         self.layer_kinds = kinds
         self.recurrent_layers = recurrent_layers(config)
         self.state_slots = max_batch + 1 if self.recurrent_layers else 0
-        layer_shape = (kind.heads, num_blocks, block_size, kind.stored_width)
         from dynamo_tpu.ops import kv_quant
+
+        def layer_shape(k):  # a paged layer's planes, of its group's blocks
+            blocks = self.window_blocks if k.window is not None else num_blocks
+            return (k.heads, blocks, block_size, k.stored_width)
 
         # DYN_KV_DTYPE=int8: the paged cache itself is int8-resident with
         # per-(layer, head, block) f32 scales — the PR-4 wire codec
@@ -330,10 +357,10 @@ class ModelRunner:
             kv_sharding, config.num_layers, self.kv_quantized
         )
         def make_zeros(which: int):  # 0: keys or a slot's first array; 1: values or its second
-            pages = iter(kv_quant.make_cache(
-                paged_layers(config), layer_shape, self.kv_dtype,
-                quantized=self.kv_quantized,
-            ))
+            def pages(k):
+                return kv_quant.make_cache(
+                    1, layer_shape(k), self.kv_dtype, quantized=self.kv_quantized,
+                )[0]
 
             def slot_array(k):  # None: a slot of one array (or none) has no second
                 if which >= len(k.slot):
@@ -342,7 +369,7 @@ class ModelRunner:
                 return jnp.zeros((self.state_slots,) + tuple(shape), dtype)
 
             return tuple(
-                next(pages) if k.planes else slot_array(k) for k in kinds
+                pages(k) if k.planes else slot_array(k) for k in kinds
             )
 
         if kv_sharding is not None:
@@ -351,12 +378,13 @@ class ModelRunner:
             make_zeros = jax.jit(
                 make_zeros, static_argnums=0, out_shardings=kv_shard_tree
             )
-        if (kind.planes == 1 or self.state_slots) and (
+        if (kind.planes == 1 or self.state_slots or self.window_blocks) and (
             self.kv_quantized or mesh is not None
         ):
             what = (
                 "a model with a recurrent layer" if self.state_slots
-                else f"a {kind.name} cache"
+                else "a model with window layers that give their pages back"
+                if self.window_blocks else f"a {kind.name} cache"
             )
             raise ValueError(
                 f"{what} is served in bfloat16 on one chip: an "
@@ -366,8 +394,10 @@ class ModelRunner:
         self.k_cache = make_zeros(0)
         self.v_cache = make_zeros(1) if kind.planes == 2 else ()
         logger.info(
-            "kv cache: %d blocks x %d tokens (%s), %.2f GiB%s",
+            "kv cache: %d blocks%s x %d tokens (%s), %.2f GiB%s",
             num_blocks,
+            f" and {self.window_blocks} of the window layers'"
+            if self.window_blocks else "",
             block_size,
             "int8+scales" if self.kv_quantized else str(
                 kv_dtype.__name__ if hasattr(kv_dtype, "__name__") else kv_dtype
@@ -1164,8 +1194,7 @@ class ModelRunner:
             n = len(token_chunk)
             ctoks = np.zeros(C, np.int32)
             ctoks[:n] = token_chunk
-            table = np.zeros(self.max_blocks_per_seq, np.int32)
-            table[: len(block_ids)] = block_ids
+            table = self._table(block_ids, self.max_blocks_per_seq)
             if key_data is None:
                 key_data = self._next_key_data()
             if c_eos_ids is None:
@@ -1396,9 +1425,8 @@ class ModelRunner:
         tokens = np.zeros(bucket, np.int32)
         tokens[:T] = token_ids
         nb = bucket // self.block_size
-        table = np.zeros(nb, np.int32)
         used = (T + self.block_size - 1) // self.block_size
-        table[:used] = block_ids[:used]
+        table = self._table(block_ids[:used], nb)
         # padding region scatters into the null block 0 — harmless.
         # Ring attention only pays off past a length threshold: short
         # prompts skip the sp ppermute rounds and run the serial path.
@@ -1497,8 +1525,7 @@ class ModelRunner:
         # regardless of prompt length (one compiled program per bucket,
         # same as single-shot prefill)
         nb_table = self.pick_bucket(total_len) // self.block_size
-        table = np.zeros(nb_table, np.int32)
-        table[: len(block_ids)] = block_ids
+        table = self._table(block_ids, nb_table)
         if key_data is None:
             key_data = self._next_key_data()
         if eos_ids is None:
@@ -1510,6 +1537,18 @@ class ModelRunner:
             np.asarray(eos_ids, np.int32), np.bool_(eos_suppress),
             *self._slot_args(state_slots),
         )
+
+    def _table(self, block_ids, nb: int) -> np.ndarray:
+        """One sequence's block table, `nb` entries a page group: its blocks
+        (the null block behind them), and for a model with a window group
+        each block's companion there behind those (the null block where it
+        was given back)."""
+        n = min(len(block_ids), nb)
+        table = np.zeros(nb * len(self.page_groups), np.int32)
+        table[:n] = block_ids[:n]
+        if self.window_blocks:
+            table[nb : nb + n] = self.window_of[table[:n]]
+        return table
 
     def _slot_args(self, state_slots) -> tuple:
         """The trailing argument of a one-sequence prefill call: its lane
@@ -1558,7 +1597,9 @@ class ModelRunner:
         tokens = np.zeros(P, np.int32)
         positions = np.zeros(P, np.int32)
         segment_ids = np.full(P, -1, np.int32)
-        slot_indices = np.zeros(P, np.int32)
+        # both groups' slots side by side where the paged layers are of two
+        G = len(self.page_groups)
+        slot_indices = np.zeros(G * P, np.int32)
         last_idx = np.zeros(N, np.int32)
         temps = np.zeros(N, np.float32)
         top_ps = np.ones(N, np.float32)
@@ -1575,9 +1616,12 @@ class ModelRunner:
             positions[off : off + T] = np.arange(T)
             segment_ids[off : off + T] = i
             t_idx = np.arange(T)
-            slot_indices[off : off + T] = (
-                np.asarray(bids, np.int64)[t_idx // bs] * bs + t_idx % bs
-            )
+            blocks = np.asarray(bids, np.int64)[t_idx // bs]
+            slot_indices[off : off + T] = blocks * bs + t_idx % bs
+            if G > 1:
+                slot_indices[P + off : P + off + T] = (
+                    self.window_of[blocks].astype(np.int64) * bs + t_idx % bs
+                )
             last_idx[i] = off + T - 1
             temps[i], top_ps[i], top_ks[i], rep_pens[i] = te, tp_, tk, rp
             keys[i] = kd
@@ -1622,6 +1666,14 @@ class ModelRunner:
         """Blocks leave and enter the cache as `[L, Hkv, n, bs, D]` pairs of
         keys and values (disagg frames, block-manager tiers, peer pulls):
         refuse in words for a cache that keeps another kind of plane."""
+        if self.window_blocks:
+            raise ValueError(
+                f"{what} moves cache blocks as keys and values by head of "
+                "every layer under one block id; this model's paged layers "
+                "are of two groups, each with its own blocks, and its window "
+                "layers give theirs back, which is not carried through "
+                "transfer or tiers yet"
+            )
         if self.state_slots:
             raise ValueError(
                 f"{what} moves cache blocks as keys and values by head; "

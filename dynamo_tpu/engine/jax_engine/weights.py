@@ -490,6 +490,86 @@ def load_ssm2_moe_safetensors(
     return params
 
 
+def load_afmoe_safetensors(
+    model_dir: str,
+    config: Any,  # models.afmoe.AfmoeConfig
+    *,
+    quantize: bool = False,
+    dtype: jnp.dtype = jnp.bfloat16,
+) -> Any:
+    """The window-and-full attention, sparse-expert family's checkpoint
+    names, written from Hugging Face's `modeling_afmoe.py` and untried on a
+    published checkpoint (none is at hand; a synthetic state dict under these
+    names round-trips in `tests/test_afmoe.py`): `model.embed_tokens`;
+    `model.layers.N.{input_layernorm, post_attention_layernorm,
+    pre_mlp_layernorm, post_mlp_layernorm}`; `self_attn.{q,k,v,o}_proj`,
+    `self_attn.gate_proj` (the output gate), `self_attn.{q,k}_norm`; a dense
+    layer's `mlp.{gate,up,down}_proj`; an expert layer's
+    `mlp.router.gate.weight`, `mlp.expert_bias` (float32),
+    `mlp.shared_experts.{gate,up,down}_proj`,
+    `mlp.experts.K.{gate,up,down}_proj`; `model.norm`, `lm_head`. Of a
+    layer's experts only those this chip holds (`[first_held_expert,
+    first_held_expert + num_experts)`) are read; the others' tensors stay in
+    the files."""
+    forward_for(config).refuse_int8_weights(quantize)
+    tensors = _read_safetensors(model_dir)
+    c = config
+
+    def get(name: str, as_dtype=dtype) -> jax.Array:
+        return jnp.asarray(tensors.pop(name)).astype(as_dtype)
+
+    def lin(name: str) -> jax.Array:  # HF stores [out, in]; we use [in, out]
+        return get(name).T
+
+    layers = []
+    held = range(c.first_held_expert, c.first_held_expert + c.num_experts)
+    for i in range(c.num_layers):
+        p = f"model.layers.{i}."
+        a, m = p + "self_attn.", p + "mlp."
+        layer = {
+            "attn_norm": get(p + "input_layernorm.weight"),
+            "post_attn_norm": get(p + "post_attention_layernorm.weight"),
+            "pre_mlp_norm": get(p + "pre_mlp_layernorm.weight"),
+            "post_mlp_norm": get(p + "post_mlp_layernorm.weight"),
+            "wq": lin(a + "q_proj.weight"), "wk": lin(a + "k_proj.weight"),
+            "wv": lin(a + "v_proj.weight"), "w_gate": lin(a + "gate_proj.weight"),
+            "wo": lin(a + "o_proj.weight"),
+            "q_norm": get(a + "q_norm.weight"), "k_norm": get(a + "k_norm.weight"),
+        }
+        if not c.is_moe_layer(i):
+            layer.update(
+                wg=lin(m + "gate_proj.weight"), wu=lin(m + "up_proj.weight"),
+                wd=lin(m + "down_proj.weight"),
+            )
+        else:
+            stack = lambda w: jnp.stack(
+                [lin(f"{m}experts.{e}.{w}_proj.weight") for e in held]
+            )
+            layer.update(
+                router=lin(m + "router.gate.weight"),
+                router_bias=get(m + "expert_bias", jnp.float32),
+                shared_wg=lin(m + "shared_experts.gate_proj.weight"),
+                shared_wu=lin(m + "shared_experts.up_proj.weight"),
+                shared_wd=lin(m + "shared_experts.down_proj.weight"),
+                wg=stack("gate"), wu=stack("up"), wd=stack("down"),
+            )
+        layers.append(layer)
+    params: dict[str, Any] = {
+        "embed": get("model.embed_tokens.weight"),
+        "layers": layers,
+        "final_norm": get("model.norm.weight"),
+    }
+    if not c.tie_word_embeddings:
+        params["lm_head"] = lin("lm_head.weight")
+    logger.info(
+        "loaded %d layers from %s (%d tensors left in the files, the "
+        "experts other chips hold among them); this family's checkpoint "
+        "names are untried on a published checkpoint",
+        len(layers), model_dir, len(tensors),
+    )
+    return params
+
+
 # A family's checkpoint names, by its config class as the families' one table
 # has it (`models.served_model_types`): a loader reads the names that one of
 # the family's `model_type`s publishes.
@@ -500,5 +580,6 @@ LOADERS = {
     _SERVED["jamba"]: load_hybrid_ssm_safetensors,
     _SERVED["lfm2_moe"]: load_conv_moe_safetensors,
     _SERVED["nemotron_h"]: load_ssm2_moe_safetensors,
+    _SERVED["afmoe"]: load_afmoe_safetensors,
 }
 assert set(LOADERS) == set(_SERVED.values()), "a family without checkpoint names"

@@ -23,6 +23,7 @@ import os
 import sys
 import threading
 from dataclasses import dataclass
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -47,7 +48,11 @@ class CacheKind:
     its values do; a slot of one array leaves None there.
     Nothing at all: `nothing`, a layer that is a feed-forward alone (an
     expert mixer of a model whose layers are one mixer each); None rides in
-    both places."""
+    both places.
+    `window`: a paged layer that attends over the last `window` positions
+    alone keeps no more than those: its blocks that have wholly left the
+    window go back to the pool (`page_groups`). None: it keeps every
+    position."""
 
     name: str  # "kv_heads" | "latent" | "recurrent" | "nothing"
     planes: int
@@ -56,6 +61,7 @@ class CacheKind:
     stored_width: int
     slot: tuple = ()
     pack: int = 1
+    window: Optional[int] = None
 
     def stored_values_per_token(self, tp: int = 1) -> int:
         return self.planes * max(1, self.heads // tp) * self.stored_width
@@ -69,13 +75,18 @@ class CacheKind:
         )
 
 
-def kv_heads_cache(num_kv_heads: int, head_dim: int, pack: int = 1) -> CacheKind:
+def kv_heads_cache(
+    num_kv_heads: int, head_dim: int, pack: int = 1, window: Optional[int] = None,
+) -> CacheKind:
     """Keys and values by head; with `pack` > 1, that many heads a stored
-    row (the same values a token, in rows a kernel can tile)."""
+    row (the same values a token, in rows a kernel can tile); with `window`,
+    of the last `window` positions alone."""
     if num_kv_heads % pack:
         raise ValueError(f"{num_kv_heads} KV heads do not fill rows of {pack}")
     width = head_dim * pack
-    return CacheKind("kv_heads", 2, num_kv_heads // pack, width, width, pack=pack)
+    return CacheKind(
+        "kv_heads", 2, num_kv_heads // pack, width, width, pack=pack, window=window,
+    )
 
 
 def latent_cache(width: int, lanes: int = 128) -> CacheKind:
@@ -96,6 +107,20 @@ def keeps_nothing() -> CacheKind:
 def paged_layers(config) -> int:
     """How many of `config`'s layers keep rows per token in blocks."""
     return sum(k.planes > 0 for k in layer_cache_kinds(config))
+
+
+def page_groups(config) -> tuple[CacheKind, ...]:
+    """The distinct kinds among `config`'s paged layers: layers of one kind
+    share a block table and a pool of blocks, and a group's blocks live as
+    long as its kind says. The group that keeps every position comes first:
+    its table is the one every model has. One group for every model whose
+    paged layers are alike; two for a model that mixes window and full
+    attention layers (`models/afmoe.py`)."""
+    kinds = []
+    for kind in layer_cache_kinds(config):
+        if kind.planes and kind not in kinds:
+            kinds.append(kind)
+    return tuple(sorted(kinds, key=lambda k: k.window is not None))
 
 
 def layer_cache_kinds(config) -> tuple[CacheKind, ...]:
@@ -219,7 +244,7 @@ def forward_for(config):
 
 # The family modules. Each holds `MODEL_TYPES`, the `config.json`
 # `model_type`s it serves, and `CONFIG`, its config class.
-FAMILIES = ("llama", "mla_moe", "hybrid_ssm", "conv_moe", "ssm2_moe")
+FAMILIES = ("llama", "mla_moe", "hybrid_ssm", "conv_moe", "ssm2_moe", "afmoe")
 
 
 @functools.lru_cache(maxsize=None)

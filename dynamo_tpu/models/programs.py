@@ -69,7 +69,17 @@ class Family:
     program's lane slots, rope's frequencies); `behind_embedding[name](cfg)`
     is called behind it. Two places, because the families' programs
     compiled so before they were written once; one is a later change's,
-    judged by the programs' texts (`tests/test_mla_moe.py` PARENT_PROGRAMS)."""
+    judged by the programs' texts (`tests/test_mla_moe.py` PARENT_PROGRAMS).
+    `embed_scale(cfg)`: what a token's embedding is multiplied by before the
+    first layer, where the family's model scales it.
+
+    A family whose paged layers are of two groups (`models.page_groups`:
+    window and full attention) is handed both groups' tables side by side in
+    the one table argument (`[..., 2 * nb]`: the full group's `nb` entries,
+    then the window group's) and both groups' packed slots in the one slot
+    argument (`[2 * P]`); what the programs here derive from a table reads
+    the first group's half, and the family's `prepare` takes the halves
+    apart."""
 
     kind: Callable[[Any, int], Hashable]
     packed: Mapping[Hashable, Body]
@@ -78,6 +88,7 @@ class Family:
     whole: Optional[Mapping[Hashable, Body]] = None
     prepare: Mapping[str, Callable] = field(default_factory=dict)
     behind_embedding: Mapping[str, Callable] = field(default_factory=dict)
+    embed_scale: Optional[Callable[[Any], float]] = None
 
 
 def _logits(x, params, cfg):
@@ -110,6 +121,8 @@ def _walk(family, program, params, cfg, tokens, k_cache, v_cache, values, stats)
     if prepare is not None:
         values.update(prepare(cfg, **values))
     x = params["embed"][tokens]
+    if family.embed_scale is not None:
+        x = x * jnp.asarray(family.embed_scale(cfg), x.dtype)
     for name, derive in family.behind_embedding.items():
         values[name] = derive(cfg)
     bodies = getattr(family, program)
@@ -153,6 +166,7 @@ def prefill_packed(
     values = dict(
         positions=positions, segment_ids=segment_ids, slot_indices=slot_indices,
         last_idx=last_idx, valid=segment_ids >= 0, mesh=mesh,
+        page_size=_page_size(cfg, k_cache),
     )
     if state_slots is not None:
         null = k_cache[_first(cfg, False)].shape[0] - 1
@@ -188,7 +202,7 @@ def prefill(
     values = dict(
         positions=pos, valid=live, valid_len=valid_len, slot_indices=slots,
         last_idx=last_idx, seg_slots=seg_slots(), mesh=mesh,
-        head_axis=attn_head_axis,
+        head_axis=attn_head_axis, block_table=block_table, page_size=bs,
     )
     x, k_out, v_out = _walk(
         family, "whole", params, cfg, tokens, k_cache, v_cache, values, None
@@ -227,6 +241,7 @@ def prefill_chunk(
     values = dict(
         positions=positions, valid=valid, slot_indices=slots,
         block_table=block_table, chunk_start=chunk_start, mesh=mesh,
+        page_size=bs,
     )
     if state_slots is not None:
         values["lane_slot"] = jnp.reshape(state_slots, ())
@@ -260,7 +275,7 @@ def decode(
     values = dict(
         positions=positions, live=live, context=jnp.where(live, positions + 1, 0),
         block_tables=block_tables, slot_indices=slot_indices, mesh=mesh,
-        head_axis=attn_head_axis,
+        head_axis=attn_head_axis, page_size=_page_size(cfg, k_cache),
     )
     x, k_out, v_out = _walk(
         family, "decode", params, cfg, tokens, k_cache, v_cache, values, stats

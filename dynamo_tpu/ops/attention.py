@@ -712,6 +712,69 @@ def chunked_prefill_attention(
     return out.reshape(C, Hq, D).astype(q.dtype)
 
 
+def chunked_prefill_attention_by_blocks(
+    q: jax.Array,  # [C, Hq, D] — one chunk of the prompt
+    k_cache: jax.Array,  # [Hkv, num_blocks, block_size, D] (this layer)
+    v_cache: jax.Array,
+    block_table: jax.Array,  # [max_nb] int32 — the WHOLE prompt's blocks
+    chunk_start: jax.Array,  # scalar int32 — position of q[0]
+    key_block: int = 2048,
+    scale: Optional[float] = None,
+) -> jax.Array:
+    """`chunked_prefill_attention` for a table too wide to score at once:
+    the keys are taken `key_block` at a time, up to the chunk's last query
+    and no further, with a running maximum and sum (the flash recurrence in
+    XLA), so the transient is `[Hq, C, key_block]` float32 scores whatever
+    the table's width: 0.2 GB at 48 heads, a chunk of 512 and 2,048 keys,
+    where the whole width of a 24,576-token table would be 2.4 GB. Plain
+    causal attention over a plain cache: no window, no soft-cap."""
+    C, Hq, D = q.shape
+    Hs, _, block_size, W = k_cache.shape
+    pack = _row_pack(D, W)
+    Hkv = Hs * pack
+    G = Hq // Hkv
+    pages = max(1, key_block // block_size)
+    keys = pages * block_size
+    n = block_table.shape[0]
+    table = jnp.concatenate(
+        [block_table, jnp.zeros((-n) % pages, block_table.dtype)]
+    )
+    sc = jnp.float32(scale) if scale is not None else (
+        1.0 / jnp.sqrt(D).astype(jnp.float32)
+    )
+    qr = q.reshape(C, Hkv, G, D).astype(jnp.float32)
+    qpos = chunk_start + jnp.arange(C)
+
+    def block(j, carry):
+        m, l, acc = carry
+        ids = jax.lax.dynamic_slice(table, (j * pages,), (pages,))
+        k = _rows_to_heads(k_cache[:, ids].reshape(Hs, keys, W), pack)
+        v = _rows_to_heads(v_cache[:, ids].reshape(Hs, keys, W), pack)
+        s = jnp.einsum("chgd,hsd->hgcs", qr, k.astype(jnp.float32)) * sc
+        mask = (j * keys + jnp.arange(keys))[None, :] <= qpos[:, None]
+        s = jnp.where(mask[None, None], s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        # a query that sees no key of this block keeps what it has
+        p = jnp.where(mask[None, None], jnp.exp(s - m_new[..., None]), 0.0)
+        alpha = jnp.exp(m - m_new)
+        l = l * alpha + jnp.sum(p, axis=-1)
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "hgcs,hsd->hgcd", p, v.astype(jnp.float32)
+        )
+        return m_new, l, acc
+
+    steps = jnp.minimum(
+        (chunk_start + C + keys - 1) // keys, table.shape[0] // pages
+    )
+    m, l, acc = jax.lax.fori_loop(0, steps, block, (
+        jnp.full((Hkv, G, C), NEG_INF, jnp.float32),
+        jnp.zeros((Hkv, G, C), jnp.float32),
+        jnp.zeros((Hkv, G, C, D), jnp.float32),
+    ))
+    out = acc / l[..., None]  # key 0 is in the first block and every query sees it
+    return out.transpose(2, 0, 1, 3).reshape(C, Hq, D).astype(q.dtype)
+
+
 def write_chunk_kv(
     k_cache: jax.Array,  # [Hkv, num_blocks, block_size, D]
     v_cache: jax.Array,

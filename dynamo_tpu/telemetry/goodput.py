@@ -112,6 +112,28 @@ SAMPLER_COUNTERS = ("dispatches", "pool_dispatches")
 SSM_COUNTERS = ("layer_steps", "slots_live", "slot_resets", "scan_tokens")
 
 
+# a model whose paged layers are of two groups (window layers that give
+# their pages back beside layers that keep every position), over its
+# decode-family dispatches: `decode_steps`; `lane_steps`, live lanes summed
+# over them; `window_rows` and `full_rows`, the rows a step's window layers
+# and full layers must read of each live lane (`min(context, window)` and
+# `context`), summed over lanes and steps; `lanes_past_window`, live lanes
+# whose context is past the window, summed over steps, and
+# `window_blocks_past`, the window blocks those lanes held; the two pools'
+# blocks in use summed over steps beside their capacity summed likewise
+# (`*_in_use_steps`, `*_capacity_steps`: a share in use is their quotient);
+# `window_blocks_given_back`, window blocks that went back to their pool
+# because the window had left them. Counted on the host where the lane
+# arrays are built, before the call; nothing is fetched
+POOL_COUNTERS = (
+    "decode_steps", "lane_steps", "window_rows", "full_rows",
+    "lanes_past_window", "window_blocks_past",
+    "window_in_use_steps", "window_capacity_steps",
+    "full_in_use_steps", "full_capacity_steps",
+    "window_blocks_given_back",
+)
+
+
 # the stream edge of an engine: `items` put on sequences' streams that carried
 # tokens (one holds what one dispatch produced for one sequence) and the
 # `tokens` they carried, counted together where an item is put, so that
@@ -263,6 +285,7 @@ class GoodputStats:
         "moe",
         "sampler",
         "ssm",
+        "pool",
         "stream",
         "launch",
         "chain_breaks",
@@ -311,6 +334,8 @@ class GoodputStats:
         self.sampler: dict[str, int] = {}
         # SSM_COUNTERS (empty for a model without recurrent layers)
         self.ssm: dict[str, int] = {}
+        # POOL_COUNTERS (empty for a model with one group of paged layers)
+        self.pool: dict[str, int] = {}
         # STREAM_COUNTERS
         self.stream: dict[str, int] = {}
         # LAUNCH_COUNTERS
@@ -384,6 +409,8 @@ class GoodputStats:
             self.sampler[k] = self.sampler.get(k, 0) + v
         for k, v in other.ssm.items():
             self.ssm[k] = self.ssm.get(k, 0) + v
+        for k, v in other.pool.items():
+            self.pool[k] = self.pool.get(k, 0) + v
         for k, v in other.stream.items():
             self.stream[k] = self.stream.get(k, 0) + v
         for k, v in other.launch.items():
@@ -433,6 +460,7 @@ class GoodputStats:
             "moe": dict(self.moe),
             "smp": dict(self.sampler),
             "ssm": dict(self.ssm),
+            "pool": dict(self.pool),
             "str": dict(self.stream),
             "lch": dict(self.launch),
             "cb": dict(self.chain_breaks),
@@ -474,6 +502,9 @@ class GoodputStats:
         for k, v in (d.get("ssm") or {}).items():
             if k in SSM_COUNTERS:
                 out.ssm[k] = int(v)
+        for k, v in (d.get("pool") or {}).items():
+            if k in POOL_COUNTERS:
+                out.pool[k] = int(v)
         for k, v in (d.get("str") or {}).items():
             if k in STREAM_COUNTERS:
                 out.stream[k] = int(v)
@@ -525,6 +556,7 @@ class GoodputStats:
             "moe": {k: self.moe.get(k, 0.0) for k in MOE_COUNTERS},
             "sampler": {k: self.sampler.get(k, 0) for k in SAMPLER_COUNTERS},
             "ssm": {k: self.ssm.get(k, 0) for k in SSM_COUNTERS},
+            "pool": {k: self.pool.get(k, 0) for k in POOL_COUNTERS},
             "stream": {k: self.stream.get(k, 0) for k in STREAM_COUNTERS},
             "launch": {k: self.launch.get(k, 0) for k in LAUNCH_COUNTERS},
             "chain_breaks": {
@@ -646,6 +678,15 @@ class GoodputLedger(GoodputStats):
             layers * decode_steps, lanes * decode_steps, resets, scan_tokens,
         )):
             self.ssm[k] = self.ssm.get(k, 0) + int(v)
+
+    def record_pool(self, **counted: int) -> None:
+        """One decode-family dispatch of a model with a window group of
+        pages: `counted` by POOL_COUNTERS' names."""
+        if not self.enabled:
+            return
+        for k in POOL_COUNTERS:
+            if k in counted:
+                self.pool[k] = self.pool.get(k, 0) + int(counted[k])
 
     def record_stream(self, tokens: int) -> None:
         """One item of `tokens` tokens put on a sequence's stream."""

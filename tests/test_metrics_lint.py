@@ -116,6 +116,19 @@ INTENTIONALLY_SHARED = {
     "dyn_llm_ssm_slots_live",
     "dyn_llm_ssm_slot_resets",
     "dyn_llm_ssm_scan_tokens",
+    # the two page groups' pools (ISSUE 52): rows a step's window and full
+    # layers must read, blocks held, in use and given back; the same surface
+    "dyn_llm_pool_decode_steps",
+    "dyn_llm_pool_lane_steps",
+    "dyn_llm_pool_window_rows",
+    "dyn_llm_pool_full_rows",
+    "dyn_llm_pool_lanes_past_window",
+    "dyn_llm_pool_window_blocks_past",
+    "dyn_llm_pool_window_in_use_steps",
+    "dyn_llm_pool_window_capacity_steps",
+    "dyn_llm_pool_full_in_use_steps",
+    "dyn_llm_pool_full_capacity_steps",
+    "dyn_llm_pool_window_blocks_given_back",
     # the stream edge (ISSUE 39): items put on sequences' streams and the
     # tokens they carried; the same shared goodput surface
     "dyn_llm_stream_items",

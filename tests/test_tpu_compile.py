@@ -1289,3 +1289,124 @@ def test_ssm2_moe_step_programs_one_chip(one_chip, program, bodies, kernels, pac
         # what `ssm2_step_ms` reads: instructions that mention a layer's
         # states, float32 [65, 128, 64, 128] (or its heads by group)
         assert re.search(r"f32\[65,(128|8,16),64,128\]", text)
+
+
+# -------- the window-and-full attention, sparse-expert family's programs (PR 52)
+#
+# `cellbench/configs/trinity-large-bf16-l5-e32.json` whole: five layers at
+# the published widths (a dense window layer, then window, window, full,
+# window with 32 held experts of a 256-wide router), 64 lanes, a served
+# context of 24,576 (1,536 table entries a page group, both groups' side by
+# side), the pools the budget gives the cell.
+
+AFMOE_POOLS = (43_000, 10_900)  # blocks of the full group's pool, of the window group's
+
+
+def _afmoe_setup(one_chip):
+    from dynamo_tpu.models import afmoe
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "cellbench", "configs", "trinity-large-bf16-l5-e32.json")) as f:
+        conf = json.load(f)
+    cfg = afmoe.AfmoeConfig.from_hf_dict({k: v for k, v in conf.items() if k != "bench"})
+    cfg = dataclasses.replace(cfg, attn_impl="pallas")
+    params = jax.tree_util.tree_map(
+        lambda a: one_chip(a.shape, a.dtype),
+        jax.eval_shape(lambda: afmoe.init_params(cfg, jax.random.PRNGKey(0))),
+    )
+    full, window = AFMOE_POOLS
+    pages = tuple(
+        one_chip((cfg.num_kv_heads, window if cfg.is_window_layer(i) else full, BLOCK, cfg.head_dim), BF16)
+        for i in range(cfg.num_layers)
+    )
+    return cfg, params, pages
+
+
+def _lower_afmoe(one_chip, program: str):
+    from dynamo_tpu.engine.jax_engine.model_runner import ModelRunner
+    from dynamo_tpu.ops.sampling import MAX_EOS_IDS
+
+    cfg, params, pages = _afmoe_setup(one_chip)
+    vec = lambda dtype: one_chip((B,), dtype)
+    scalar = lambda dtype: one_chip((), dtype)
+    table = 2 * (24576 // BLOCK)
+    if program == "decode_multi@H4B64":
+        host = (
+            vec(I32), vec(I32), one_chip((B, table), I32), one_chip((B, 2), jnp.uint32),
+            vec(F32), vec(F32), vec(I32), vec(jnp.bool_), vec(I32), vec(I32),
+            one_chip((B, MAX_EOS_IDS), I32),
+            vec(jnp.bool_), (vec(I32), vec(I32), vec(jnp.bool_), vec(I32)),
+        )
+        return _lower_step(
+            one_chip, functools.partial(ModelRunner._decode_multi_impl, cfg, None, None, BLOCK),
+            params, (pages, pages), host, static=(4,), packed=True,
+        )
+    if program == "mixed_step@c1":
+        chunk = (
+            one_chip((512,), I32), scalar(I32), scalar(I32), one_chip((table,), I32),
+            one_chip((2,), jnp.uint32), scalar(F32), scalar(F32), scalar(I32),
+            scalar(F32), one_chip((MAX_EOS_IDS,), I32), scalar(jnp.bool_),
+        )
+        host = (
+            (chunk,), vec(I32), vec(I32), one_chip((B, table), I32), vec(I32),
+            one_chip((B, 2), jnp.uint32), vec(F32), vec(F32), vec(I32),
+            one_chip((B, MAX_EOS_IDS), I32), vec(jnp.bool_),
+        )
+        return _lower_step(
+            one_chip, functools.partial(ModelRunner._mixed_impl, cfg, None, None),
+            params, (pages, pages), host, packed=True,
+        )
+    tok = lambda n: one_chip((n,), I32)
+    host = (
+        tok(512), tok(512), tok(512), tok(2 * 512), vec(I32), one_chip((B, 2), jnp.uint32),
+        vec(F32), vec(F32), vec(I32), vec(F32), one_chip((B, MAX_EOS_IDS), I32), vec(jnp.bool_),
+    )
+    return _lower_step(
+        one_chip, functools.partial(ModelRunner._prefill_packed_impl, cfg, None),
+        params, (pages, pages), host, packed=True,
+    )
+
+
+@pytest.mark.parametrize("program,bodies,kernels,temp_gib", [
+    # 5 layers' paged calls x 4 steps, and 4 expert layers' 3 grouped products a pass
+    ("decode_multi@H4B64", 3, 5 * 4 + 12 * 4, 1.0),
+    # the chunk's attention is XLA's: a window layer scores 4,640 keys, the
+    # full layer 2,048 at a time
+    ("mixed_step@c1", 6, 5 + 12 * 2, 2.0),
+    ("prefill_packed@512", 3, 12, 1.0),
+])
+def test_afmoe_step_programs_one_chip(one_chip, program, bodies, kernels, temp_gib):
+    """The family's step programs compile for the chip at the cell's whole
+    configuration: three layer bodies a pass (the dense window layer, the
+    expert window layers, the expert full layer), the paged decode kernel that appends in every
+    layer, over a table of the window's 257 blocks in the window layers and of
+    1,536 in the full one, the held experts' products in the Pallas grouped
+    product, both groups' pages written in place, and weights, pools and a
+    step's transients together inside the chip."""
+    from dynamo_tpu.models import layer_bodies_called
+
+    jax.clear_caches()  # a body traced by another test would not be counted
+    with layer_bodies_called() as seen:
+        lowered = _lower_afmoe(one_chip, program)
+    assert len(seen) == bodies, sorted(s[1] for s in seen)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    names = re.findall(
+        r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\""
+        r"[^\n]*op_name=\"[^\"\n]*pallas_call\"", text, re.M,
+    )
+    assert len(names) == kernels, len(names)
+    assert "ragged-dot" not in text and "ragged_dot" not in text
+    assert _stack_relayouts(text, 32 * 3072 * 3072) == []
+    mem = compiled.memory_analysis()
+    full, window = AFMOE_POOLS
+    pages = 2 * (full + 4 * window) * BLOCK * 8 * 128 * 2
+    assert mem.alias_size_in_bytes == pages
+    assert mem.temp_size_in_bytes < temp_gib * 2**30, mem.temp_size_in_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16_909_336_064
+    if program == "decode_multi@H4B64":
+        # what `window_attn_ms` and `full_attn_ms` find: a paged call's page
+        # array, of its group's block count
+        assert re.search(rf"bf16\[8,{window},16,128\]", text) and re.search(rf"bf16\[8,{full},16,128\]", text)
+        # the window layers' calls are handed 257 blocks a lane, the full layer's 1,536
+        assert re.search(r"s32\[64,257\]", text) and re.search(r"s32\[64,1536\]", text)
