@@ -113,7 +113,7 @@ def main():
     # that each iteration can feed the last one's sample
     layout, inputs = runner._commit(
         jax.device_put(tokens), positions, bt, slots, keys,
-        temps, top_ps, top_ks,
+        temps, top_ps, top_ks, np.ones(len(tokens), bool),
     )
     dev_args = [runner.params, runner.k_cache, runner.v_cache, *inputs]
 

@@ -450,6 +450,9 @@ def goodput_families(
         ("pool_dispatches", "decode-family dispatches whose batch held a "
          "sampled lane with top_k or top_p, so that every step computed "
          "the sampler's candidate pool"),
+        ("logprob_dispatches", "decode-family dispatches whose batch held a "
+         "lane that asked for log-probs, so that every step computed the "
+         "log-prob surface"),
     ):
         yield CounterMetricFamily(
             f"{PREFIX}_sampler_{name}",

@@ -35,7 +35,7 @@ from dynamo_tpu.engine.jax_engine.kv_cache import (
     SequenceState,
 )
 from dynamo_tpu.engine.jax_engine.model_runner import ModelRunner
-from dynamo_tpu.ops.sampling import draw_restrictions
+from dynamo_tpu.ops.sampling import draw_restrictions, surface_wanted
 from dynamo_tpu.pipeline.context import Context, decisions_of
 from dynamo_tpu.protocols.common import (
     FinishReason,
@@ -567,6 +567,9 @@ class JaxEngine:
         self._temps = np.ones(B, np.float32)
         self._top_ps = np.ones(B, np.float32)
         self._top_ks = np.zeros(B, np.int32)
+        # lanes whose request set `logprobs`: the sampler computes the
+        # log-prob surface only in a step where one of them is set
+        self._want_lps = np.zeros(B, bool)
         self._keys = np.zeros((B, 2), np.uint32)
         # unseeded sequences draw from (engine seed base + seq_id) streams:
         # deterministic per engine run AND stable across preemption replay
@@ -815,7 +818,9 @@ class JaxEngine:
         `pool`: whether a decode-family dispatch (`capacity` given; its
         lane arrays were packed just before) holds a sampled lane that
         restricts its draw, the sampler's own predicate asked of the same
-        three arrays the program is about to receive. For a model with
+        three arrays the program is about to receive; `logprobs` likewise,
+        whether a lane of it asked for log-probs (`surface_wanted` of the
+        fourth). For a model with
         recurrent layers `state_slots` (lanes that hold a sequence, and so a
         state) rides the phase too, and the ledger's `ssm` slot counts the
         dispatch from the same host-side numbers; `state_resets` is how many
@@ -836,6 +841,9 @@ class JaxEngine:
         launching = launches is not None or lands is None
         pool = launching and capacity > 0 and bool(
             draw_restrictions(self._temps, self._top_ps, self._top_ks)[1]
+        )
+        logprobs = launching and capacity > 0 and bool(
+            surface_wanted(self._want_lps)
         )
         slow_factor = 1.0
         if launching and faults.active():
@@ -863,7 +871,7 @@ class JaxEngine:
             "loop.dispatch", label=label, lanes=lanes,
             ctx_tokens=ctx_tokens, prefill_tokens=tokens,
             horizon=horizon, first=first,
-            pool=int(pool),
+            pool=int(pool), logprobs=int(logprobs),
             state_slots=(
                 sum(s is not None for s in self.slots)
                 if self._recurrent_layers else 0
@@ -1001,7 +1009,7 @@ class JaxEngine:
                 )
                 if launching:
                     if capacity > 0:
-                        gp.record_sampler(pool)
+                        gp.record_sampler(pool, logprobs)
                     if pool_counts:
                         gp.record_pool(**pool_counts)
                     if self._recurrent_layers:
@@ -2148,6 +2156,7 @@ class JaxEngine:
                             key_data=key_row,
                             eos_ids=seq.eos_row,
                             eos_suppress=seq.needs_eos_suppress,
+                            want_logprobs=seq.want_logprobs,
                             **self._slot_kw([seq]),
                         )
                     ),
@@ -2206,6 +2215,7 @@ class JaxEngine:
                         key_data=key_row,
                         eos_ids=seq.eos_row,
                         eos_suppress=seq.needs_eos_suppress,
+                        want_logprobs=seq.want_logprobs,
                     )
                 ),
                 tokens=len(seq.token_ids),
@@ -2226,7 +2236,8 @@ class JaxEngine:
                 for s in group
             ]
             packed = self.runner.pack_prefill(
-                specs, **self._slot_kw(group)
+                specs, want_logprobs=[s.want_logprobs for s in group],
+                **self._slot_kw(group),
             )
         async with self._device_lock:
             sample = await self._dispatch(
@@ -2278,6 +2289,7 @@ class JaxEngine:
                     rep_pen=seq.rep_pen, key_data=key_row,
                     eos_ids=seq.eos_row,
                     eos_suppress=seq.needs_eos_suppress,
+                    want_logprobs=seq.want_logprobs,
                     **self._slot_kw([seq]),
                 )
                 return self.runner.fetch_sample(out) if final else None
@@ -2379,9 +2391,7 @@ class JaxEngine:
             self._block_tables.fill(0)
             self._positions.fill(0)
             self._slot_indices.fill(0)  # null block slot 0
-            self._temps.fill(0.0)
-            self._top_ps.fill(1.0)
-            self._top_ks.fill(0)
+            self._idle_sampling()
             bs = self.config.block_size
             eos_ids = np.full((B, MAX_EOS_IDS), -1, np.int32)
             eos_sup = np.zeros(B, bool)
@@ -2408,6 +2418,8 @@ class JaxEngine:
                     self._block_tables, self._slot_indices, self._keys,
                     self._temps, self._top_ps, self._top_ks,
                     eos_ids=eos_ids, eos_suppress=eos_sup,
+                    want_logprobs=self._want_lps,
+                    chunk_want_logprobs=[p[0].want_logprobs for p in packed],
                     **self._slot_kw([p[0] for p in packed]),
                 )
                 fetch: list = []
@@ -2747,6 +2759,7 @@ class JaxEngine:
                         key_data=key_row,
                         eos_ids=seq.eos_row,
                         eos_suppress=seq.needs_eos_suppress,
+                        want_logprobs=seq.want_logprobs,
                     )
                 ),
             )
@@ -3083,9 +3096,18 @@ class JaxEngine:
             ),
         )
 
+    def _idle_sampling(self) -> None:
+        """Every lane's sampling inputs as an idle lane's: greedy, nothing
+        that restricts a draw, no log-probs asked for, so that only a live
+        lane (`_fill_lane`) makes a step pay the pool or the surface."""
+        self._temps.fill(0.0)
+        self._top_ps.fill(1.0)
+        self._top_ks.fill(0)
+        self._want_lps.fill(False)
+
     def _fill_lane(self, seq: _Sequence) -> int:
         """Write one active lane's shared per-step inputs into the batch
-        arrays (both decode phases use the identical seven); returns the
+        arrays (both decode phases use the identical eight); returns the
         fed token's position."""
         i = seq.slot
         pos = seq.pos - 1  # position of the token being fed
@@ -3104,6 +3126,7 @@ class JaxEngine:
         self._temps[i] = seq.temperature
         self._top_ps[i] = seq.top_p
         self._top_ks[i] = seq.top_k
+        self._want_lps[i] = seq.want_logprobs
         self._keys[i] = self._key_row(seq)
         return pos
 
@@ -3200,9 +3223,7 @@ class JaxEngine:
             self._block_tables.fill(0)
             self._positions.fill(0)
             self._slot_indices.fill(0)  # null block slot 0
-            self._temps.fill(0.0)
-            self._top_ps.fill(1.0)
-            self._top_ks.fill(0)
+            self._idle_sampling()
             bs = self.config.block_size
             for seq in active:
                 pos = self._fill_lane(seq)
@@ -3267,6 +3288,7 @@ class JaxEngine:
                         keys=self._keys,
                         penalties=penalties,
                         eos_mask=eos_mask,
+                        want_logprobs=self._want_lps,
                     )
                 ),
                 lanes=len(active),
@@ -3367,9 +3389,7 @@ class JaxEngine:
                         return
             self._block_tables.fill(0)
             self._positions.fill(0)
-            self._temps.fill(0.0)
-            self._top_ps.fill(1.0)
-            self._top_ks.fill(0)
+            self._idle_sampling()
             act = np.zeros(B, bool)
             limit_rem = np.ones(B, np.int32)
             min_rem = np.zeros(B, np.int32)
@@ -3420,7 +3440,7 @@ class JaxEngine:
                         self._positions, self._block_tables,
                         self._temps, self._top_ps, self._top_ks,
                         self._keys, act, limit_rem, min_rem, eos_ids,
-                        penalties=penalties,
+                        penalties=penalties, want_logprobs=self._want_lps,
                     )
                 ),
                 lanes=len(active),
@@ -3508,9 +3528,7 @@ class JaxEngine:
             B = self.config.max_batch
             self._block_tables.fill(0)
             self._positions.fill(0)
-            self._temps.fill(0.0)
-            self._top_ps.fill(1.0)
-            self._top_ks.fill(0)
+            self._idle_sampling()
             act = np.zeros(B, bool)
             limit_rem = np.ones(B, np.int32)
             min_rem = np.zeros(B, np.int32)
@@ -3565,7 +3583,8 @@ class JaxEngine:
                 H,
                 self._tokens, self._positions, self._block_tables,
                 self._temps, self._top_ps, self._top_ks,
-                self._keys, act, limit_rem, min_rem, eos_ids, **extra,
+                self._keys, act, limit_rem, min_rem, eos_ids,
+                want_logprobs=self._want_lps, **extra,
             )
             if new is None:
                 return self.runner.fetch_horizon(packed)

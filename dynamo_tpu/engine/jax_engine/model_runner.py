@@ -613,7 +613,7 @@ class ModelRunner:
 
     @staticmethod
     def _sample_one(logits, prompt, n_prompt, key_data, temp, top_p, top_k,
-                    rep_pen, eos_ids, eos_suppress):
+                    want_lp, rep_pen, eos_ids, eos_suppress):
         """Shared prefill tail: prompt repetition penalty + min_tokens EOS
         mask + sample + logprobs for the single first token (freq/presence
         are zero by definition)."""
@@ -623,7 +623,7 @@ class ModelRunner:
         logits = mask_eos_logits(logits, eos_ids, eos_suppress)
         tok, lp, tids, tlps = sample_tokens_full(
             logits[None, :], None, temp[None], top_p[None], top_k[None],
-            keys=key_data[None, :],
+            want_lp[None], keys=key_data[None, :],
         )
         return tok[0], lp[0], tids[0], tlps[0]
 
@@ -631,7 +631,7 @@ class ModelRunner:
     def _prefill_impl(
         cfg, attn_mesh, attn_head_axis,
         params, k_cache, v_cache, tokens, valid_len, block_table,
-        key_data, temp, top_p, top_k, rep_pen, eos_ids, eos_suppress,
+        key_data, temp, top_p, top_k, want_lp, rep_pen, eos_ids, eos_suppress,
         state_slots=None,
     ):
         logits, k_cache, v_cache = forward_for(cfg).prefill(
@@ -640,8 +640,8 @@ class ModelRunner:
             **_slots(state_slots),
         )
         out = ModelRunner._sample_one(
-            logits, tokens, valid_len, key_data, temp, top_p, top_k, rep_pen,
-            eos_ids, eos_suppress,
+            logits, tokens, valid_len, key_data, temp, top_p, top_k, want_lp,
+            rep_pen, eos_ids, eos_suppress,
         )
         return out, k_cache, v_cache
 
@@ -650,7 +650,7 @@ class ModelRunner:
         cfg, attn_mesh, attn_head_axis,
         params, k_cache, v_cache, tokens, valid_len, block_table,
         mm_embeds, mm_start,
-        key_data, temp, top_p, top_k, rep_pen, eos_ids, eos_suppress,
+        key_data, temp, top_p, top_k, want_lp, rep_pen, eos_ids, eos_suppress,
     ):
         logits, k_cache, v_cache = forward_for(cfg).prefill_mm(
             params, cfg, tokens, valid_len, k_cache, v_cache, block_table,
@@ -658,15 +658,15 @@ class ModelRunner:
             mesh=attn_mesh, attn_head_axis=attn_head_axis,
         )
         out = ModelRunner._sample_one(
-            logits, tokens, valid_len, key_data, temp, top_p, top_k, rep_pen,
-            eos_ids, eos_suppress,
+            logits, tokens, valid_len, key_data, temp, top_p, top_k, want_lp,
+            rep_pen, eos_ids, eos_suppress,
         )
         return out, k_cache, v_cache
 
     @staticmethod
     def _prefill_cp_impl(
         cfg, mesh, head_axis, params, k_cache, v_cache, tokens, valid_len,
-        block_table, key_data, temp, top_p, top_k, rep_pen, eos_ids,
+        block_table, key_data, temp, top_p, top_k, want_lp, rep_pen, eos_ids,
         eos_suppress,
     ):
         # per-layer pagination inside the model loop: peak transient is one
@@ -676,15 +676,15 @@ class ModelRunner:
             k_cache=k_cache, v_cache=v_cache, block_table=block_table,
         )
         out = ModelRunner._sample_one(
-            logits, tokens, valid_len, key_data, temp, top_p, top_k, rep_pen,
-            eos_ids, eos_suppress,
+            logits, tokens, valid_len, key_data, temp, top_p, top_k, want_lp,
+            rep_pen, eos_ids, eos_suppress,
         )
         return out, k_cache, v_cache
 
     @staticmethod
     def _prefill_chunk_impl(
         cfg, mesh, params, k_cache, v_cache, tokens, chunk_start, valid_len,
-        block_table, key_data, temp, top_p, top_k, rep_pen, eos_ids,
+        block_table, key_data, temp, top_p, top_k, want_lp, rep_pen, eos_ids,
         eos_suppress, state_slots=None,
     ):
         logits, k_cache, v_cache = forward_for(cfg).prefill_chunk(
@@ -696,16 +696,16 @@ class ModelRunner:
         # token of a chunked long prompt — decode steps use the full history
         n_in_chunk = jnp.clip(valid_len - chunk_start, 0, tokens.shape[0])
         out = ModelRunner._sample_one(
-            logits, tokens, n_in_chunk, key_data, temp, top_p, top_k, rep_pen,
-            eos_ids, eos_suppress,
+            logits, tokens, n_in_chunk, key_data, temp, top_p, top_k, want_lp,
+            rep_pen, eos_ids, eos_suppress,
         )
         return out, k_cache, v_cache
 
     @staticmethod
     def _prefill_packed_impl(
         cfg, mesh, params, k_cache, v_cache, tokens, positions, segment_ids,
-        slot_indices, last_idx, keys, temps, top_ps, top_ks, rep_pens,
-        eos_ids, eos_suppress, state_slots=None,
+        slot_indices, last_idx, keys, temps, top_ps, top_ks, want_lps,
+        rep_pens, eos_ids, eos_suppress, state_slots=None,
     ):
         logits, k_cache, v_cache = forward_for(cfg).prefill_packed(
             params, cfg, tokens, positions, segment_ids, slot_indices,
@@ -715,21 +715,25 @@ class ModelRunner:
             logits, tokens, segment_ids, rep_pens
         )
         logits = mask_eos_logits(logits, eos_ids, eos_suppress)
-        out = sample_tokens_full(logits, None, temps, top_ps, top_ks, keys=keys)
+        out = sample_tokens_full(
+            logits, None, temps, top_ps, top_ks, want_lps, keys=keys
+        )
         return out, k_cache, v_cache
 
     @staticmethod
     def _decode_impl(
         cfg, attn_mesh, attn_head_axis,
         params, k_cache, v_cache, tokens, positions, block_tables,
-        slot_indices, keys, temps, top_ps, top_ks,
+        slot_indices, keys, temps, top_ps, top_ks, want_lps,
     ):
         logits, k_cache, v_cache = forward_for(cfg).decode(
             params, cfg, tokens, positions, k_cache, v_cache,
             block_tables, slot_indices,
             mesh=attn_mesh, attn_head_axis=attn_head_axis,
         )
-        out = sample_tokens_full(logits, None, temps, top_ps, top_ks, keys=keys)
+        out = sample_tokens_full(
+            logits, None, temps, top_ps, top_ks, want_lps, keys=keys
+        )
         return out, k_cache, v_cache
 
     @staticmethod
@@ -743,6 +747,7 @@ class ModelRunner:
                           # counter column advances by 1 per step, exactly
                           # what the engine's per-token _key_row would send
         temps, top_ps, top_ks,  # [B]
+        want_lps,         # [B] bool — lane's request asked for log-probs
         active,           # [B] bool — lane live at horizon start
         limit_remaining,  # [B] i32 — tokens the lane may still emit
         min_remaining,    # [B] i32 — steps during which EOS stays masked
@@ -834,7 +839,7 @@ class ModelRunner:
             logits = mask_eos_logits(logits, eos_ids, suppress)
             step_keys = keys.at[:, 1].add(h.astype(jnp.uint32))
             tok, lp, top_ids, top_lps = sample_tokens_full(
-                logits, None, temps, top_ps, top_ks, keys=step_keys
+                logits, None, temps, top_ps, top_ks, want_lps, keys=step_keys
             )
             is_eos = jnp.any((tok[:, None] == eos_ids) & eos_valid, axis=-1)
             out_tok = jnp.where(done, -1, tok)
@@ -899,6 +904,7 @@ class ModelRunner:
                           # counter column advances by 1 per emitted
                           # position, exactly matching _key_row per token
         temps, top_ps, top_ks,  # [B]
+        want_lps,         # [B] bool — lane's request asked for log-probs
         active,           # [B] bool
         limit_remaining,  # [B] i32 — tokens the lane may still emit
         min_remaining,    # [B] i32 — steps during which EOS stays masked
@@ -968,7 +974,7 @@ class ModelRunner:
             tok, lp, top_ids, top_lps = sample_tokens_full(
                 lg, None,
                 jnp.repeat(temps, S), jnp.repeat(top_ps, S),
-                jnp.repeat(top_ks, S), keys=keys_rep,
+                jnp.repeat(top_ks, S), jnp.repeat(want_lps, S), keys=keys_rep,
             )
             t = tok.reshape(B, S)
             packed_v = jnp.concatenate(
@@ -1006,7 +1012,7 @@ class ModelRunner:
                 lg = mask_eos_logits(lg, eos_ids, suppress)
                 step_keys = keys.at[:, 1].add(jnp.uint32(h))
                 tok, lp, top_ids, top_lps = sample_tokens_full(
-                    lg, None, temps, top_ps, top_ks, keys=step_keys
+                    lg, None, temps, top_ps, top_ks, want_lps, keys=step_keys
                 )
                 toks.append(tok)
                 out_tok = jnp.where(valid[:, h], tok, -1)
@@ -1051,7 +1057,7 @@ class ModelRunner:
                 lg = mask_eos_logits(lg, eos_ids, suppress)
                 step_keys = keys.at[:, 1].add(count.astype(jnp.uint32))
                 tok, lp, top_ids, top_lps = sample_tokens_full(
-                    lg, None, temps, top_ps, top_ks, keys=step_keys
+                    lg, None, temps, top_ps, top_ks, want_lps, keys=step_keys
                 )
                 is_eos = jnp.any((tok[:, None] == eos_ids) & eos_valid, axis=-1)
                 out_tok = jnp.where(alive, tok, -1)
@@ -1075,7 +1081,7 @@ class ModelRunner:
     def _decode_pen_impl(
         cfg, attn_mesh, attn_head_axis,
         params, k_cache, v_cache, tokens, positions, block_tables,
-        slot_indices, keys, temps, top_ps, top_ks,
+        slot_indices, keys, temps, top_ps, top_ks, want_lps,
         hist, hist_len, prompt_len, freq_pen, pres_pen, rep_pen,
         eos_ids, eos_suppress,
     ):
@@ -1088,14 +1094,17 @@ class ModelRunner:
             logits, hist, hist_len, prompt_len, freq_pen, pres_pen, rep_pen
         )
         logits = mask_eos_logits(logits, eos_ids, eos_suppress)
-        out = sample_tokens_full(logits, None, temps, top_ps, top_ks, keys=keys)
+        out = sample_tokens_full(
+            logits, None, temps, top_ps, top_ks, want_lps, keys=keys
+        )
         return out, k_cache, v_cache
 
     @staticmethod
     def _decode_eos_impl(
         cfg, attn_mesh, attn_head_axis,
         params, k_cache, v_cache, tokens, positions, block_tables,
-        slot_indices, keys, temps, top_ps, top_ks, eos_ids, eos_suppress,
+        slot_indices, keys, temps, top_ps, top_ks, want_lps, eos_ids,
+        eos_suppress,
     ):
         logits, k_cache, v_cache = forward_for(cfg).decode(
             params, cfg, tokens, positions, k_cache, v_cache,
@@ -1103,7 +1112,9 @@ class ModelRunner:
             mesh=attn_mesh, attn_head_axis=attn_head_axis,
         )
         logits = mask_eos_logits(logits, eos_ids, eos_suppress)
-        out = sample_tokens_full(logits, None, temps, top_ps, top_ks, keys=keys)
+        out = sample_tokens_full(
+            logits, None, temps, top_ps, top_ks, want_lps, keys=keys
+        )
         return out, k_cache, v_cache
 
     @staticmethod
@@ -1112,7 +1123,7 @@ class ModelRunner:
         params, k_cache, v_cache,
         chunk_args,  # tuple of per-chunk arg tuples (see mixed_step)
         tokens, positions, block_tables, slot_indices, keys, temps,
-        top_ps, top_ks, eos_ids, eos_suppress,
+        top_ps, top_ks, want_lps, eos_ids, eos_suppress,
     ):
         """One packed device step: k chunked-prefill sub-computations
         followed by the full decode batch, threading the donated KV caches
@@ -1135,7 +1146,7 @@ class ModelRunner:
         d_out, k_cache, v_cache = ModelRunner._decode_eos_impl(
             cfg, attn_mesh, attn_head_axis, params, k_cache, v_cache,
             tokens, positions, block_tables, slot_indices, keys, temps,
-            top_ps, top_ks, eos_ids, eos_suppress,
+            top_ps, top_ks, want_lps, eos_ids, eos_suppress,
         )
         outs.extend(d_out)
         return tuple(outs), k_cache, v_cache
@@ -1170,6 +1181,8 @@ class ModelRunner:
         eos_ids: Optional[np.ndarray] = None,  # [B, MAX_EOS_IDS] i32
         eos_suppress: Optional[np.ndarray] = None,  # [B] bool
         state_slots: Optional[list[int]] = None,  # each chunk's lane slot
+        want_logprobs: Optional[np.ndarray] = None,  # [B] bool, the lanes'
+        chunk_want_logprobs: Optional[list[bool]] = None,  # each chunk's
     ) -> tuple[tuple, tuple]:
         """One unified mixed step: the decode batch plus ``chunks`` packed
         prefill-chunk slots in a single dispatch. Chunks of one sequence
@@ -1188,6 +1201,7 @@ class ModelRunner:
         C = self.prefill_chunk_tokens
         host_chunks = []
         slots = self._lane_slots(state_slots, len(chunks))
+        chunk_wants = self._want_lanes(chunk_want_logprobs, len(chunks))
         for i, (token_chunk, chunk_start, total_len, block_ids, temperature,
                 top_p, top_k, rep_pen, key_data, c_eos_ids,
                 c_eos_suppress) in enumerate(chunks):
@@ -1202,7 +1216,7 @@ class ModelRunner:
             host_chunks.append((
                 ctoks, np.int32(chunk_start), np.int32(total_len), table,
                 key_data, np.float32(temperature), np.float32(top_p),
-                np.int32(top_k), np.float32(rep_pen),
+                np.int32(top_k), chunk_wants[i], np.float32(rep_pen),
                 np.asarray(c_eos_ids, np.int32), np.bool_(c_eos_suppress),
                 *(() if slots is None else (slots[i],)),
             ))
@@ -1215,8 +1229,8 @@ class ModelRunner:
         out = self._launch(
             self._mixed_jit_for(k), tuple(host_chunks),
             tokens, positions, block_tables, slot_indices, keys, temps,
-            top_ps, top_ks, np.asarray(eos_ids, np.int32),
-            np.asarray(eos_suppress, bool),
+            top_ps, top_ks, self._want_lanes(want_logprobs, B),
+            np.asarray(eos_ids, np.int32), np.asarray(eos_suppress, bool),
         )
         chunk_outs = tuple(out[4 * i: 4 * i + 4] for i in range(k))
         return chunk_outs, tuple(out[4 * k: 4 * k + 4])
@@ -1253,6 +1267,15 @@ class ModelRunner:
             # float32 trap for consumers that index/serialize with them)
             outs.append(np.asarray(piece, dtype=o.dtype))
         return tuple(outs)
+
+    @staticmethod
+    def _want_lanes(want_logprobs, n: int) -> np.ndarray:
+        """[n] bool: which lanes of a call asked for log-probs. A caller
+        that does not say gets the surface for every lane, as before the
+        sampler learned to leave it out; the engine always says."""
+        if want_logprobs is None:
+            return np.ones(n, bool)
+        return np.asarray(want_logprobs, bool)
 
     def _lane_slots(self, slots, n: int) -> Optional[np.ndarray]:
         """[n] int32 lane slots for a prefill call, or None for a model
@@ -1417,6 +1440,7 @@ class ModelRunner:
         eos_ids: Optional[np.ndarray] = None,  # [MAX_EOS_IDS] i32, -1 pad
         eos_suppress: bool = False,  # min_tokens not yet reached
         state_slots: Optional[list[int]] = None,  # [the sequence's lane slot]
+        want_logprobs: bool = True,  # False: the last three come back zeros
     ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
         """Run one prompt; returns (token, logprob, top_ids, top_logprobs)
         device arrays for the first sampled token."""
@@ -1446,8 +1470,9 @@ class ModelRunner:
         return self._launch(
             prefill_fn, tokens, np.int32(T), table, key_data,
             np.float32(temperature), np.float32(top_p), np.int32(top_k),
-            np.float32(rep_pen), np.asarray(eos_ids, np.int32),
-            np.bool_(eos_suppress), *self._slot_args(state_slots),
+            np.bool_(want_logprobs), np.float32(rep_pen),
+            np.asarray(eos_ids, np.int32), np.bool_(eos_suppress),
+            *self._slot_args(state_slots),
         )
 
     def prefill_mm(
@@ -1463,6 +1488,7 @@ class ModelRunner:
         key_data: Optional[np.ndarray] = None,
         eos_ids: Optional[np.ndarray] = None,
         eos_suppress: bool = False,
+        want_logprobs: bool = True,
     ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
         """Multimodal prefill (vision embeddings spliced over placeholder
         positions — reference prefill_worker.py:249-258). Jitted lazily so
@@ -1492,8 +1518,9 @@ class ModelRunner:
         return self._launch(
             self._prefill_mm_jit, tokens, np.int32(T), table, mm_embeds,
             np.int32(mm_start), key_data, np.float32(temperature),
-            np.float32(top_p), np.int32(top_k), np.float32(rep_pen),
-            np.asarray(eos_ids, np.int32), np.bool_(eos_suppress),
+            np.float32(top_p), np.int32(top_k), np.bool_(want_logprobs),
+            np.float32(rep_pen), np.asarray(eos_ids, np.int32),
+            np.bool_(eos_suppress),
         )
 
     def prefill_chunk(
@@ -1510,6 +1537,7 @@ class ModelRunner:
         eos_ids: Optional[np.ndarray] = None,
         eos_suppress: bool = False,
         state_slots: Optional[list[int]] = None,  # [the sequence's lane slot]
+        want_logprobs: bool = True,
     ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
         """Run one chunk of a chunked prefill; chunks must arrive in order.
 
@@ -1533,9 +1561,9 @@ class ModelRunner:
         return self._launch(
             self._chunk_jit, tokens, np.int32(chunk_start),
             np.int32(total_len), table, key_data, np.float32(temperature),
-            np.float32(top_p), np.int32(top_k), np.float32(rep_pen),
-            np.asarray(eos_ids, np.int32), np.bool_(eos_suppress),
-            *self._slot_args(state_slots),
+            np.float32(top_p), np.int32(top_k), np.bool_(want_logprobs),
+            np.float32(rep_pen), np.asarray(eos_ids, np.int32),
+            np.bool_(eos_suppress), *self._slot_args(state_slots),
         )
 
     def _table(self, block_ids, nb: int) -> np.ndarray:
@@ -1582,14 +1610,15 @@ class ModelRunner:
 
     def pack_prefill(
         self, seqs: list[tuple], state_slots: Optional[list[int]] = None,
+        want_logprobs: Optional[list[bool]] = None,  # each sequence's
     ) -> dict[str, np.ndarray]:
         """Pure host-side packing for the batched-prefill program.
 
         seqs: [(token_ids, block_ids, temp, top_p, top_k, rep_pen,
         key_row [2] uint32, eos_row [MAX_EOS_IDS] i32, suppress bool), ...]
         with total tokens <= prefill_chunk_tokens and len(seqs) <=
-        max_batch. Padding lanes carry segment -1 and scatter into null
-        block 0."""
+        max_batch. Padding lanes carry segment -1, scatter into null
+        block 0 and ask for no log-probs."""
         P = self.prefill_chunk_tokens
         N = self.max_batch
         bs = self.block_size
@@ -1608,6 +1637,8 @@ class ModelRunner:
         keys = np.zeros((N, 2), np.uint32)
         eos_ids = np.full((N, MAX_EOS_IDS), -1, np.int32)
         eos_suppress = np.zeros(N, bool)
+        want_lps = np.zeros(N, bool)
+        want_lps[: len(seqs)] = self._want_lanes(want_logprobs, len(seqs))
         off = 0
         for i, (tids, bids, te, tp_, tk, rp, kd, er, sup) in enumerate(seqs):
             T = len(tids)
@@ -1633,6 +1664,7 @@ class ModelRunner:
             slot_indices=slot_indices, last_idx=last_idx, temps=temps,
             top_ps=top_ps, top_ks=top_ks, rep_pens=rep_pens, keys=keys,
             eos_ids=eos_ids, eos_suppress=eos_suppress,
+            want_logprobs=want_lps,
         )
         slots = self._lane_slots(state_slots, len(seqs))
         if slots is not None:
@@ -1645,7 +1677,7 @@ class ModelRunner:
     def prefill_packed_arrays(
         self, tokens, positions, segment_ids, slot_indices, last_idx,
         temps, top_ps, top_ks, rep_pens, keys, eos_ids=None,
-        eos_suppress=None, state_slots=None,
+        eos_suppress=None, state_slots=None, want_logprobs=None,
     ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
         """Run the packed batched-prefill program (arrays from
         pack_prefill). Returns (tokens, logprobs, top_ids, top_lps), each
@@ -1657,7 +1689,8 @@ class ModelRunner:
             eos_suppress = np.zeros(N, bool)
         return self._launch(
             self._packed_jit, tokens, positions, segment_ids, slot_indices,
-            last_idx, keys, temps, top_ps, top_ks, rep_pens,
+            last_idx, keys, temps, top_ps, top_ks,
+            self._want_lanes(want_logprobs, N), rep_pens,
             np.asarray(eos_ids, np.int32), np.asarray(eos_suppress, bool),
             *(() if state_slots is None else (state_slots,)),
         )
@@ -1924,6 +1957,9 @@ class ModelRunner:
         # eos_mask = (eos_ids [B, MAX_EOS_IDS] i32, eos_suppress [B] bool):
         # min_tokens without penalties — masks EOS on device but skips the
         # [B, L] history transfer. Ignored when penalties is given.
+        want_logprobs: Optional[np.ndarray] = None,  # [B] bool
+        # lanes whose request asked for log-probs: the last three results
+        # are computed only in a step where one did, else zeros
     ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
         """One batched decode step. Returns (tokens, logprobs, top_ids,
         top_logprobs) device arrays, each batch-major."""
@@ -1937,7 +1973,8 @@ class ModelRunner:
             program, extra = self._decode_fn, ()
         return self._launch(
             program, tokens, positions, block_tables, slot_indices, keys,
-            temps, top_ps, top_ks, *extra,
+            temps, top_ps, top_ks,
+            self._want_lanes(want_logprobs, tokens.shape[0]), *extra,
         )
 
     def decode_multi(
@@ -1965,6 +2002,7 @@ class ModelRunner:
         # them on the device, whose result the caller need not have read:
         # for them every host array describes the lane as of THAT call's
         # start (`_decode_multi_impl`). None: no lane does.
+        want_logprobs: Optional[np.ndarray] = None,  # [B] bool, as `decode`'s
     ) -> jax.Array:
         """H chained decode steps; returns the packed [H, B, 2+2*num_top]
         f32 device array (token, logprob, top_ids, top_lps per step) — ONE
@@ -1983,7 +2021,8 @@ class ModelRunner:
             carry = self._zero_carry(B)
         packed, self._horizon_carry = self._launch(
             self._decode_multi_fn, tokens, positions, block_tables, keys,
-            temps, top_ps, top_ks, active, limit_remaining, min_remaining,
+            temps, top_ps, top_ks, self._want_lanes(want_logprobs, B),
+            active, limit_remaining, min_remaining,
             eos_ids, chain, carry, static=(H,),
             **({} if penalties is None else {"pen": tuple(penalties)}),
         )
@@ -2025,6 +2064,7 @@ class ModelRunner:
         min_remaining: np.ndarray,  # [B] i32
         eos_ids: np.ndarray,  # [B, MAX_EOS_IDS] i32
         penalties: Optional[tuple] = None,  # decode_multi's 6-tuple
+        want_logprobs: Optional[np.ndarray] = None,  # [B] bool, as `decode`'s
     ) -> jax.Array:
         """Speculative draft-verify dispatch: ONE weight pass scores the
         spec_k + 1 draft positions per lane, then `extras` chained decode
@@ -2051,7 +2091,8 @@ class ModelRunner:
             )
         return self._launch(
             self._spec_verify_jit, tokens, drafts, draft_len, positions,
-            block_tables, keys, temps, top_ps, top_ks, active,
+            block_tables, keys, temps, top_ps, top_ks,
+            self._want_lanes(want_logprobs, tokens.shape[0]), active,
             limit_remaining, min_remaining, eos_ids,
             static=(spec_k + 1, extras),
             **({} if penalties is None else {"pen": tuple(penalties)}),

@@ -222,8 +222,13 @@ class SpmdModelRunner:
 
     # -- intercepted calls (must match follower_loop's dispatch table) --
 
+    # `want_logprobs` rides every broadcast: the sampler computes the
+    # log-prob surface under a conditional on it, and a follower that took
+    # the other branch would run another program's collectives
+
     def prefill(self, token_ids, block_ids, temperature, top_p, top_k,
-                rep_pen=1.0, key_data=None, eos_ids=None, eos_suppress=False):
+                rep_pen=1.0, key_data=None, eos_ids=None, eos_suppress=False,
+                want_logprobs=True):
         t = np.asarray(token_ids, np.int32)
         b = np.asarray(block_ids, np.int32)
         # materialize the RNG row HERE so leader and followers run the
@@ -234,7 +239,8 @@ class SpmdModelRunner:
             eos_ids = np.full(_EOS_K, -1, np.int32)
         self._channel.send(
             OP_PREFILL,
-            [len(t), len(b), 1 if eos_suppress else 0],
+            [len(t), len(b), 1 if eos_suppress else 0,
+             1 if want_logprobs else 0],
             (t, b, np.float32(temperature), np.float32(top_p),
              np.int32(top_k), np.float32(rep_pen),
              np.asarray(key_data, np.uint32),
@@ -245,13 +251,14 @@ class SpmdModelRunner:
                 list(token_ids), list(block_ids), temperature, top_p, top_k,
                 rep_pen=float(rep_pen), key_data=np.asarray(key_data),
                 eos_ids=np.asarray(eos_ids), eos_suppress=bool(eos_suppress),
+                want_logprobs=bool(want_logprobs),
             )
         )
 
     def prefill_chunk(
         self, token_chunk, chunk_start, total_len, block_ids, temperature,
         top_p, top_k, rep_pen=1.0, key_data=None, eos_ids=None,
-        eos_suppress=False,
+        eos_suppress=False, want_logprobs=True,
     ):
         t = np.asarray(token_chunk, np.int32)
         b = np.asarray(block_ids, np.int32)
@@ -262,7 +269,7 @@ class SpmdModelRunner:
         self._channel.send(
             OP_CHUNK,
             [len(t), len(b), int(chunk_start), int(total_len),
-             1 if eos_suppress else 0],
+             1 if eos_suppress else 0, 1 if want_logprobs else 0],
             (t, b, np.float32(temperature), np.float32(top_p),
              np.int32(top_k), np.float32(rep_pen),
              np.asarray(key_data, np.uint32),
@@ -274,12 +281,15 @@ class SpmdModelRunner:
                 list(block_ids), temperature, top_p, top_k,
                 rep_pen=float(rep_pen), key_data=np.asarray(key_data),
                 eos_ids=np.asarray(eos_ids), eos_suppress=bool(eos_suppress),
+                want_logprobs=bool(want_logprobs),
             )
         )
 
     def decode(self, tokens, positions, block_tables, slot_indices, temps,
-               top_ps, top_ks, keys=None, penalties=None, eos_mask=None):
+               top_ps, top_ks, keys=None, penalties=None, eos_mask=None,
+               want_logprobs=None):
         B = tokens.shape[0]
+        want_logprobs = self._runner._want_lanes(want_logprobs, B)
         if keys is None:
             # same default derivation the inner runner would use, but built
             # here so the broadcast carries the authoritative rows
@@ -293,6 +303,7 @@ class SpmdModelRunner:
             np.asarray(top_ps, np.float32),
             np.asarray(top_ks, np.int32),
             np.asarray(keys, np.uint32),
+            want_logprobs,
         ]
         # variant flag: 0 slim, 1 full penalties, 2 eos-mask only
         variant = 1 if penalties is not None else (
@@ -309,13 +320,14 @@ class SpmdModelRunner:
             self._runner.decode(
                 tokens, positions, block_tables, slot_indices, temps,
                 top_ps, top_ks, keys=keys, penalties=penalties,
-                eos_mask=eos_mask,
+                eos_mask=eos_mask, want_logprobs=want_logprobs,
             )
         )
 
     def decode_multi(self, H, tokens, positions, block_tables, temps,
                      top_ps, top_ks, keys, active, limit_remaining,
-                     min_remaining, eos_ids, penalties=None):
+                     min_remaining, eos_ids, penalties=None,
+                     want_logprobs=None):
         # horizon decode is a collective program: broadcast the full input
         # set so followers launch the identical H-step scan (without this
         # the leader would wedge the slice — same hazard as embed/extract).
@@ -335,6 +347,8 @@ class SpmdModelRunner:
             np.asarray(min_remaining, np.int32),
             np.asarray(eos_ids, np.int32),
         )
+        B = payload[0].shape[0]
+        want_logprobs = self._runner._want_lanes(want_logprobs, B)
         pen_payload = None
         if penalties is not None:
             hist, hist_len, prompt_len, freq, pres, rep = penalties
@@ -346,14 +360,14 @@ class SpmdModelRunner:
                 np.asarray(pres, np.float32),
                 np.asarray(rep, np.float32),
             )
-        B = payload[0].shape[0]
         self._channel.send(
             OP_DECODE_MULTI,
             [int(H), B, block_tables.shape[1], 1 if pen_payload else 0],
-            payload + (pen_payload or ()),
+            payload + (want_logprobs,) + (pen_payload or ()),
         )
         return self._runner.decode_multi(
-            int(H), *payload, penalties=pen_payload
+            int(H), *payload, penalties=pen_payload,
+            want_logprobs=want_logprobs,
         )
 
     def _fetch_sample(self, out: tuple):
@@ -362,7 +376,7 @@ class SpmdModelRunner:
     def prefill_packed_arrays(
         self, tokens, positions, segment_ids, slot_indices, last_idx,
         temps, top_ps, top_ks, rep_pens, keys, eos_ids=None,
-        eos_suppress=None,
+        eos_suppress=None, want_logprobs=None,
     ):
         N = len(last_idx)
         if eos_ids is None:
@@ -378,6 +392,7 @@ class SpmdModelRunner:
             np.asarray(rep_pens, np.float32), np.asarray(keys, np.uint32),
             np.asarray(eos_ids, np.int32),
             np.asarray(eos_suppress, bool),
+            self._runner._want_lanes(want_logprobs, N),
         )
         self._channel.send(
             OP_PACKED, [len(payload[0]), len(payload[4])], payload
@@ -386,7 +401,7 @@ class SpmdModelRunner:
             self._runner.prefill_packed_arrays(
                 tokens, positions, segment_ids, slot_indices, last_idx,
                 temps, top_ps, top_ks, rep_pens, keys, eos_ids=eos_ids,
-                eos_suppress=eos_suppress,
+                eos_suppress=eos_suppress, want_logprobs=payload[-1],
             )
         )
 
@@ -416,7 +431,7 @@ class SpmdModelRunner:
 
     def prefill_mm(self, token_ids, block_ids, mm_embeds, mm_start,
                    temperature, top_p, top_k, rep_pen=1.0, key_data=None,
-                   eos_ids=None, eos_suppress=False):
+                   eos_ids=None, eos_suppress=False, want_logprobs=True):
         # multimodal prefill is a collective program like prefill; without
         # this broadcast the leader would launch it alone and wedge the
         # slice. Embeddings ride the broadcast as host f32 (the device
@@ -432,7 +447,8 @@ class SpmdModelRunner:
         self._channel.send(
             OP_MM_PREFILL,
             [len(t), len(b), emb.shape[0], emb.shape[1],
-             int(mm_start), 1 if eos_suppress else 0],
+             int(mm_start), 1 if eos_suppress else 0,
+             1 if want_logprobs else 0],
             (t, b, emb, np.float32(temperature), np.float32(top_p),
              np.int32(top_k), np.float32(rep_pen),
              np.asarray(key_data, np.uint32),
@@ -445,6 +461,7 @@ class SpmdModelRunner:
                 key_data=np.asarray(key_data),
                 eos_ids=np.asarray(eos_ids),
                 eos_suppress=bool(eos_suppress),
+                want_logprobs=bool(want_logprobs),
             )
         )
 
@@ -587,6 +604,7 @@ def follower_loop(runner, channel: SpmdStepChannel, progress_cb=None) -> None:
                 np.zeros((B, nb), np.int32), np.zeros(B, np.int32),
                 np.zeros(B, np.float32), np.zeros(B, np.float32),
                 np.zeros(B, np.int32), np.zeros((B, 2), np.uint32),
+                np.zeros(B, bool),
             ]
             if variant == 1:  # full penalties
                 Lh = runner.max_model_len
@@ -607,17 +625,18 @@ def follower_loop(runner, channel: SpmdStepChannel, progress_cb=None) -> None:
                     ]
                 )
             got = channel.recv_payload(tuple(template))
-            (tok, pos, bt, slot, te, tp_, tk, keys) = got[:8]
-            extra = tuple(np.asarray(p) for p in got[8:])
+            (tok, pos, bt, slot, te, tp_, tk, keys, want) = got[:9]
+            extra = tuple(np.asarray(p) for p in got[9:])
             runner.decode(
                 np.asarray(tok), np.asarray(pos), np.asarray(bt),
                 np.asarray(slot), np.asarray(te), np.asarray(tp_),
                 np.asarray(tk), keys=np.asarray(keys),
                 penalties=extra if variant == 1 else None,
                 eos_mask=extra if variant == 2 else None,
+                want_logprobs=np.asarray(want),
             )
         elif op == OP_PREFILL:
-            T, nb, sup = int(h[1]), int(h[2]), int(h[3])
+            T, nb, sup, want = int(h[1]), int(h[2]), int(h[3]), int(h[4])
             (t, b, te, tp_, tk, rp, kd, er) = channel.recv_payload(
                 (
                     np.zeros(T, np.int32), np.zeros(nb, np.int32),
@@ -631,10 +650,12 @@ def follower_loop(runner, channel: SpmdStepChannel, progress_cb=None) -> None:
                 float(te), float(tp_), int(tk),
                 rep_pen=float(rp), key_data=np.asarray(kd),
                 eos_ids=np.asarray(er), eos_suppress=bool(sup),
+                want_logprobs=bool(want),
             )
         elif op == OP_CHUNK:
-            T, nb, start, total, sup = (
-                int(h[1]), int(h[2]), int(h[3]), int(h[4]), int(h[5])
+            T, nb, start, total, sup, want = (
+                int(h[1]), int(h[2]), int(h[3]), int(h[4]), int(h[5]),
+                int(h[6]),
             )
             (t, b, te, tp_, tk, rp, kd, er) = channel.recv_payload(
                 (
@@ -649,6 +670,7 @@ def follower_loop(runner, channel: SpmdStepChannel, progress_cb=None) -> None:
                 np.asarray(b).tolist(), float(te), float(tp_), int(tk),
                 rep_pen=float(rp), key_data=np.asarray(kd),
                 eos_ids=np.asarray(er), eos_suppress=bool(sup),
+                want_logprobs=bool(want),
             )
         elif op == OP_PACKED:
             P, N = int(h[1]), int(h[2])
@@ -660,13 +682,15 @@ def follower_loop(runner, channel: SpmdStepChannel, progress_cb=None) -> None:
                     np.zeros(N, np.float32), np.zeros(N, np.int32),
                     np.ones(N, np.float32), np.zeros((N, 2), np.uint32),
                     np.full((N, _EOS_K), -1, np.int32), np.zeros(N, bool),
+                    np.zeros(N, bool),
                 )
             )
-            runner.prefill_packed_arrays(*(np.asarray(a) for a in got))
+            *arrays, want = (np.asarray(a) for a in got)
+            runner.prefill_packed_arrays(*arrays, want_logprobs=want)
         elif op == OP_MM_PREFILL:
-            T, nb, M, H, start, sup = (
+            T, nb, M, H, start, sup, want = (
                 int(h[1]), int(h[2]), int(h[3]), int(h[4]), int(h[5]),
-                int(h[6]),
+                int(h[6]), int(h[7]),
             )
             (t, b, emb, te, tp_, tk, rp, kd, er) = channel.recv_payload(
                 (
@@ -682,6 +706,7 @@ def follower_loop(runner, channel: SpmdStepChannel, progress_cb=None) -> None:
                 np.asarray(emb), start, float(te), float(tp_), int(tk),
                 rep_pen=float(rp), key_data=np.asarray(kd),
                 eos_ids=np.asarray(er), eos_suppress=bool(sup),
+                want_logprobs=bool(want),
             )
         elif op == OP_DECODE_MULTI:
             Hn, B, nb = int(h[1]), int(h[2]), int(h[3])
@@ -694,6 +719,7 @@ def follower_loop(runner, channel: SpmdStepChannel, progress_cb=None) -> None:
                 np.zeros(B, bool), np.zeros(B, np.int32),
                 np.zeros(B, np.int32),
                 np.full((B, _EOS_K), -1, np.int32),
+                np.zeros(B, bool),
             )
             if has_pen:
                 L = runner.max_model_len
@@ -703,8 +729,10 @@ def follower_loop(runner, channel: SpmdStepChannel, progress_cb=None) -> None:
                     np.zeros(B, np.float32), np.ones(B, np.float32),
                 )
             got = [np.asarray(a) for a in channel.recv_payload(templates)]
-            pen = tuple(got[11:]) if has_pen else None
-            runner.decode_multi(Hn, *got[:11], penalties=pen)
+            pen = tuple(got[12:]) if has_pen else None
+            runner.decode_multi(
+                Hn, *got[:11], penalties=pen, want_logprobs=got[11]
+            )
         elif op == OP_EMBED:
             T = int(h[1])
             (t,) = channel.recv_payload((np.zeros(T, np.int32),))

@@ -98,8 +98,11 @@ MOE_COUNTERS = (
 # the sampler's candidate pool (`ops.sampling.sample_tokens`): decode-family
 # dispatches, and those among them whose batch held a sampled lane that
 # restricts its draw (top_k or top_p), so that the device took the pool's
-# branch in every step of the dispatch; counted on the host, before the call
-SAMPLER_COUNTERS = ("dispatches", "pool_dispatches")
+# branch in every step of the dispatch; and those whose batch held a lane
+# that asked for log-probs, so that every step computed the log-prob surface
+# (`ops.sampling.sample_tokens_full`: `dispatches - logprob_dispatches` is
+# how often it was left out); counted on the host, before the call
+SAMPLER_COUNTERS = ("dispatches", "pool_dispatches", "logprob_dispatches")
 
 
 # a model with recurrent layers (a slot a sequence: a state-space layer's
@@ -654,16 +657,18 @@ class GoodputLedger(GoodputStats):
         for k in MOE_COUNTERS:
             self.moe[k] = self.moe.get(k, 0.0) + float(counted.get(k, 0.0))
 
-    def record_sampler(self, pool: bool) -> None:
+    def record_sampler(self, pool: bool, logprobs: bool) -> None:
         """One decode-family dispatch; `pool` says whether its lanes made
-        the device compute the sampler's candidate pool."""
+        the device compute the sampler's candidate pool, `logprobs` whether
+        they made it compute the log-prob surface."""
         if not self.enabled:
             return
-        self.sampler["dispatches"] = self.sampler.get("dispatches", 0) + 1
-        if pool:
-            self.sampler["pool_dispatches"] = (
-                self.sampler.get("pool_dispatches", 0) + 1
-            )
+        for name, took in (
+            ("dispatches", True), ("pool_dispatches", pool),
+            ("logprob_dispatches", logprobs),
+        ):
+            if took:
+                self.sampler[name] = self.sampler.get(name, 0) + 1
 
     def record_ssm(
         self, layers: int, *, decode_steps: int, lanes: int, resets: int,

@@ -296,6 +296,7 @@ def greedy(B):
     return dict(
         keys=jnp.zeros((B, 2), jnp.uint32), temps=jnp.zeros(B, jnp.float32),
         top_ps=jnp.ones(B, jnp.float32), top_ks=jnp.zeros(B, jnp.int32),
+        want=jnp.ones(B, bool),
         eos_ids=jnp.full((B, MAX_EOS_IDS), -1, jnp.int32),
     )
 
@@ -322,7 +323,7 @@ def test_decode_multi_writes_its_slots_only(model):
     )(
         H, params, per_layer(k0), per_layer(v0), tokens,
         jnp.asarray(positions), jnp.asarray(tables), g["keys"], g["temps"],
-        g["top_ps"], g["top_ks"], jnp.ones(B, bool),
+        g["top_ps"], g["top_ks"], g["want"], jnp.ones(B, bool),
         jnp.full(B, 100, jnp.int32), jnp.zeros(B, jnp.int32), g["eos_ids"],
     )
     step = jax.jit(functools.partial(ref_decode, params, cfg))
@@ -335,7 +336,7 @@ def test_decode_multi_writes_its_slots_only(model):
             jnp.asarray(slots),
         )
         sample = sample_tokens_full(
-            logits, None, g["temps"], g["top_ps"], g["top_ks"],
+            logits, None, g["temps"], g["top_ps"], g["top_ks"], g["want"],
             keys=g["keys"].at[:, 1].add(jnp.uint32(h)),
         )
         np.testing.assert_array_equal(bits(packed[h]), pack(sample))
@@ -358,7 +359,8 @@ def test_mixed_step_writes_its_slots_and_blocks_only(model):
     chunk = (
         c_tokens, jnp.int32(0), jnp.int32(valid), jnp.asarray(chunk_table),
         jnp.zeros(2, jnp.uint32), jnp.float32(0.0), jnp.float32(1.0),
-        jnp.int32(0), jnp.float32(1.0), jnp.full(MAX_EOS_IDS, -1, jnp.int32),
+        jnp.int32(0), jnp.bool_(True), jnp.float32(1.0),
+        jnp.full(MAX_EOS_IDS, -1, jnp.int32),
         jnp.bool_(False),
     )
     tokens = jnp.asarray([5, 9], jnp.int32)
@@ -367,7 +369,8 @@ def test_mixed_step_writes_its_slots_and_blocks_only(model):
     )(
         params, per_layer(k0), per_layer(v0), (chunk,), tokens,
         jnp.asarray(positions), jnp.asarray(tables), jnp.asarray(slots),
-        g["keys"], g["temps"], g["top_ps"], g["top_ks"], g["eos_ids"],
+        g["keys"], g["temps"], g["top_ps"], g["top_ks"], g["want"],
+        g["eos_ids"],
         jnp.zeros(B, bool),
     )
     def ref_mixed(k, v):
@@ -382,7 +385,7 @@ def test_mixed_step_writes_its_slots_and_blocks_only(model):
             c_logits, c_tokens, jnp.int32(valid), *chunk[4:]
         )
         d_out = sample_tokens_full(
-            d_logits, None, g["temps"], g["top_ps"], g["top_ks"],
+            d_logits, None, g["temps"], g["top_ps"], g["top_ks"], g["want"],
             keys=g["keys"],
         )
         return tuple(c_out) + tuple(d_out), k, v

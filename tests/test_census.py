@@ -130,25 +130,26 @@ def test_ledger_sampler_slot_survives_the_wire_and_a_merge():
     zeros, and a counter this version does not declare is dropped."""
     a, b = GoodputLedger(enabled=True), GoodputLedger(enabled=True)
     for pool in (True, False, False):
-        a.record_sampler(pool)
-    b.record_sampler(True)
-    want = {"dispatches": 3, "pool_dispatches": 1}
+        a.record_sampler(pool, not pool)
+    b.record_sampler(True, False)
+    want = {"dispatches": 3, "pool_dispatches": 1, "logprob_dispatches": 2}
     assert a.summary()["sampler"] == want
     wire = a.to_dict()
     assert GoodputStats.from_dict(wire).summary()["sampler"] == want
     merged = GoodputStats.from_dict(wire)
     merged.merge(GoodputStats.from_dict(b.to_dict()))
-    assert merged.summary()["sampler"] == {"dispatches": 4, "pool_dispatches": 2}
+    assert merged.summary()["sampler"] == {
+        "dispatches": 4, "pool_dispatches": 2, "logprob_dispatches": 2,
+    }
     assert merged.copy().summary()["sampler"] == merged.summary()["sampler"]
     without = {k: v for k, v in wire.items() if k != "smp"}
-    assert GoodputStats.from_dict(without).summary()["sampler"] == {
-        "dispatches": 0, "pool_dispatches": 0,
-    }
+    none = {"dispatches": 0, "pool_dispatches": 0, "logprob_dispatches": 0}
+    assert GoodputStats.from_dict(without).summary()["sampler"] == none
     later = {**wire, "smp": {**wire["smp"], "counter_of_a_later_version": 7}}
     assert GoodputStats.from_dict(later).summary()["sampler"] == want
     off = GoodputLedger(enabled=False)
-    off.record_sampler(True)
-    assert off.summary()["sampler"] == {"dispatches": 0, "pool_dispatches": 0}
+    off.record_sampler(True, True)
+    assert off.summary()["sampler"] == none
 
 
 # ------------------------------------------- the surfaces the gauges were on

@@ -76,7 +76,7 @@ def horizon(cfg, params, k_cache, v_cache):
             jnp.asarray(POSITIONS, jnp.int32), jnp.asarray(tables),
             jnp.zeros((B, 2), jnp.uint32), jnp.zeros(B, jnp.float32),
             jnp.ones(B, jnp.float32), jnp.zeros(B, jnp.int32),
-            jnp.asarray(ACTIVE), jnp.full(B, 100, jnp.int32),
+            jnp.ones(B, bool), jnp.asarray(ACTIVE), jnp.full(B, 100, jnp.int32),
             jnp.zeros(B, jnp.int32), jnp.full((B, MAX_EOS_IDS), -1, jnp.int32),
         )
     return np.asarray(packed), k_cache, v_cache, forms_called()

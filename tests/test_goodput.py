@@ -700,6 +700,34 @@ def test_launch_slot_rides_the_wire_and_the_exporter():
         assert f"dyn_llm_launch_{name}_total {float(value)}" in text
 
 
+def test_sampler_slot_carries_logprob_dispatches_to_the_wire_and_the_exporter():
+    """`logprob_dispatches` beside `pool_dispatches` (PR 53): a dispatch may
+    take either branch, both or neither; `dispatches - logprob_dispatches`
+    is how often the log-prob surface was left out."""
+    gp = GoodputLedger(enabled=True)
+    for pool, logprobs in ((False, False), (False, True), (True, True), (False, False)):
+        gp.record_sampler(pool, logprobs)
+    want = {"dispatches": 4, "pool_dispatches": 1, "logprob_dispatches": 2}
+    assert gp.sampler == want and gp.summary()["sampler"] == want
+    back = GoodputStats.from_dict(json.loads(json.dumps(gp.to_dict())))
+    assert back.summary()["sampler"] == want
+    back.merge(gp)
+    assert back.summary()["sampler"] == {k: 2 * v for k, v in want.items()}
+    # a frame of a version that counted the pool alone reads as no log-probs
+    older = gp.to_dict()
+    del older["smp"]["logprob_dispatches"]
+    assert GoodputStats.from_dict(older).summary()["sampler"] == {
+        **want, "logprob_dispatches": 0}
+
+    class Registry:
+        def collect(self):
+            return goodput_families(gp)
+
+    text = generate_latest(Registry()).decode()
+    for name, value in want.items():
+        assert f"dyn_llm_sampler_{name}_total {float(value)}" in text
+
+
 @pytest.mark.parametrize("where, label, cause", [
     ("fetch", "prefill_packed", "stall"), ("enqueue", "decode_multi@H4B4", "shape_miss"),
 ])

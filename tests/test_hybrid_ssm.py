@@ -131,9 +131,11 @@ def pack(prompts: list[list[int]], tables: np.ndarray, P: int):
 
 
 def greedy(B):
+    """(keys, temps, top_ps, top_ks, want_lps): every lane greedy, every
+    lane asking for its log-probs."""
     return (
         jnp.zeros((B, 2), jnp.uint32), jnp.zeros(B, jnp.float32),
-        jnp.ones(B, jnp.float32), jnp.zeros(B, jnp.int32),
+        jnp.ones(B, jnp.float32), jnp.zeros(B, jnp.int32), jnp.ones(B, bool),
     )
 
 
@@ -167,14 +169,14 @@ def tables_for(n_lanes: int = LANES) -> np.ndarray:
 
 def decode_multi(cfg, params, H, kc, vc, tokens, positions, tables, active, limit):
     B = len(tokens)
-    keys, temps, top_ps, top_ks = greedy(B)
+    keys, temps, top_ps, top_ks, want = greedy(B)
     return jax.jit(
         functools.partial(ModelRunner._decode_multi_impl, cfg, None, None, BS),
         static_argnums=(0,),
     )(
         H, params, kc, vc, jnp.asarray(tokens, jnp.int32),
         jnp.asarray(positions, jnp.int32), jnp.asarray(tables), keys, temps,
-        top_ps, top_ks, jnp.asarray(active), jnp.asarray(limit, jnp.int32),
+        top_ps, top_ks, want, jnp.asarray(active), jnp.asarray(limit, jnp.int32),
         jnp.zeros(B, jnp.int32), jnp.full((B, MAX_EOS_IDS), -1, jnp.int32),
     )
 
@@ -270,7 +272,7 @@ def chunk_args(tokens, start, total, table, slot, C):
     return (
         jnp.asarray(ctoks), jnp.int32(start), jnp.int32(total),
         jnp.asarray(table), jnp.zeros(2, jnp.uint32), jnp.float32(0.0),
-        jnp.float32(1.0), jnp.int32(0), jnp.float32(1.0),
+        jnp.float32(1.0), jnp.int32(0), jnp.bool_(True), jnp.float32(1.0),
         jnp.full(MAX_EOS_IDS, -1, jnp.int32), jnp.bool_(False), jnp.int32(slot),
     )
 
@@ -293,7 +295,7 @@ def test_a_prompt_prefilled_in_two_chunks_equals_one_pass():
     )
     tok = np.zeros(LANES, np.int32)
     tok[[0, 2]] = np.asarray(jnp.argmax(logits, axis=-1), np.int32)[:2]
-    keys, temps, top_ps, top_ks = greedy(LANES)
+    keys, temps, top_ps, top_ks, want = greedy(LANES)
     mixed = jax.jit(functools.partial(ModelRunner._mixed_impl, cfg, None, None))
     sequences = {0: prompts[0] + [int(tok[0])], 2: prompts[1] + [int(tok[2])]}
     lane_ids, lane_lps = {0: [], 2: []}, {0: [], 2: []}
@@ -305,7 +307,7 @@ def test_a_prompt_prefilled_in_two_chunks_equals_one_pass():
         slots[1] = 0  # lane 1 does not decode: its write goes to the null block
         outs, kc, vc = mixed(
             params, kc, vc, (chunk,), jnp.asarray(tok), jnp.asarray(positions),
-            jnp.asarray(tables), jnp.asarray(slots), keys, temps, top_ps, top_ks,
+            jnp.asarray(tables), jnp.asarray(slots), keys, temps, top_ps, top_ks, want,
             jnp.full((LANES, MAX_EOS_IDS), -1, jnp.int32), jnp.zeros(LANES, bool),
         )
         chunk_out, (new, _, ids, lps) = outs[:4], outs[4:8]
@@ -359,7 +361,7 @@ def test_decode_multi_equals_single_steps_with_a_lane_that_ends_inside():
     )
     packed = np.asarray(packed)
     assert (packed[2:, 0, 0] == -1).all() and (packed[:2, 0, 0] >= 0).all()
-    keys, temps, top_ps, top_ks = greedy(LANES)
+    keys, temps, top_ps, top_ks, want = greedy(LANES)
     single = jax.jit(functools.partial(ModelRunner._decode_impl, cfg, None, None))
     tok = np.asarray([first[0], 0, first[1]], np.int32)
     pos = np.asarray([n, 0, n], np.int32)
@@ -370,7 +372,7 @@ def test_decode_multi_equals_single_steps_with_a_lane_that_ends_inside():
         step_keys = keys.at[:, 1].add(jnp.uint32(h))
         (t, lp, _, _), k1, v1 = single(
             params, k1, v1, jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(tables),
-            jnp.asarray(slots.astype(np.int32)), step_keys, temps, top_ps, top_ks,
+            jnp.asarray(slots.astype(np.int32)), step_keys, temps, top_ps, top_ks, want,
         )
         t, lp = np.asarray(t), np.asarray(lp)
         for lane in (0, 2):

@@ -159,6 +159,73 @@ def test_a_commit_does_not_see_what_the_engine_writes_afterwards():
             same_bits(a, b)
 
 
+def test_the_lanes_logprob_flags_ride_the_packed_buffer(monkeypatch):
+    """The lane array that says who asked for log-probs (PR 53) is one more
+    bool leaf of the step's one buffer: still one array a dispatch, every
+    leaf of every call bit for bit through `unpack_inputs`, the asking lane
+    set in it while its stream lives and no lane after, and the ledger's
+    `logprob_dispatches` counts the decode-family calls in which one was."""
+    seen, program = [], [None]
+    real_pack, real_launch = MR.pack_inputs, MR.ModelRunner._launch
+
+    def launch(self, prog, *host, **kw):
+        program[0] = prog
+        return real_launch(self, prog, *host, **kw)
+
+    def pack(tree):
+        out = real_pack(tree)
+        # the engine rewrites its lane arrays between dispatches: keep the values
+        held = jax.tree.map(lambda x: x.copy() if isinstance(x, np.ndarray) else x, tree)
+        seen.append((program[0], held, out))
+        return out
+
+    monkeypatch.setattr(MR.ModelRunner, "_launch", launch)
+    monkeypatch.setattr(MR, "pack_inputs", pack)
+
+    async def run():
+        engine = make_engine(decode_horizon=4)
+        asks = PreprocessedRequest(
+            token_ids=[5, 6, 7],
+            sampling=SamplingOptions(greedy=True, logprobs=True, top_logprobs=2),
+            stop=StopConditions(max_tokens=6, ignore_eos=True),
+        )
+        quiet = PreprocessedRequest(
+            token_ids=[9, 8, 7, 6], sampling=SamplingOptions(greedy=True),
+            stop=StopConditions(max_tokens=18, ignore_eos=True),
+        )
+        try:
+            out = await asyncio.gather(stream(engine, asks), stream(engine, quiet))
+            gp = engine.stats.goodput
+            return out, engine.runner, dict(gp.launch), gp.summary()["sampler"]
+        finally:
+            await engine.close()
+
+    (asked, quiet), runner, launch_counts, sampler = asyncio.run(run())
+    assert launch_counts["upload_arrays"] == launch_counts["dispatches"] == len(seen)
+    assert len(asked[1]) == len(asked[0]) == 6 and quiet[1] == [] and len(quiet[0]) == 18
+    unpack = jax.jit(MR.unpack_inputs, static_argnums=0)
+    # where the lanes' flags stand among a decode-family program's arguments
+    flags_at = {
+        runner._decode_multi_fn: 7, runner._decode_fn: 8, runner._decode_eos_fn: 8,
+        **{fn: 9 for fn in runner._mixed_jits.values()},
+    }
+    taken = []
+    for prog, tree, (layout, buf, beside) in seen:
+        assert beside == [] or prog is runner._decode_multi_fn  # the carry
+        back = unpack(layout, jnp.asarray(buf), tuple(beside))
+        for host, dev in zip(jax.tree.leaves(tree), jax.tree.leaves(back)):
+            if isinstance(host, (np.ndarray, np.generic)):
+                same_bits(host, dev)
+        if prog in flags_at:
+            flags = tree[0][flags_at[prog]]
+            assert flags.dtype == np.bool_ and flags.shape == (4,)
+            taken.append(bool(flags.any()))
+    assert runner._decode_multi_fn in {prog for prog, _, _ in seen}
+    assert sampler["dispatches"] == len(taken)
+    assert sampler["logprob_dispatches"] == sum(taken)
+    assert 0 < sum(taken) < len(taken) and not taken[-1]
+
+
 # ------------------------------------------------------------- the engine
 
 

@@ -283,7 +283,8 @@ def test_idle_lanes_change_nothing_for_the_live(tiny_setup, attn_impl):
             H, params, kc, vc, jnp.asarray(tokens[lanes]),
             jnp.asarray(positions[lanes]), jnp.asarray(tables[lanes]),
             jnp.zeros((n, 2), jnp.uint32), jnp.zeros(n), jnp.ones(n),
-            jnp.zeros(n, jnp.int32), jnp.asarray(active[lanes]),
+            jnp.zeros(n, jnp.int32), jnp.ones(n, bool),
+            jnp.asarray(active[lanes]),
             jnp.asarray(limit[lanes]), jnp.zeros(n, jnp.int32),
             jnp.full((n, MAX_EOS_IDS), -1, jnp.int32),
         )

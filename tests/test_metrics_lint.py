@@ -110,6 +110,8 @@ INTENTIONALLY_SHARED = {
     # made the device compute it; the same shared goodput surface
     "dyn_llm_sampler_dispatches",
     "dyn_llm_sampler_pool_dispatches",
+    # and the log-prob surface (ISSUE 53)
+    "dyn_llm_sampler_logprob_dispatches",
     # recurrent layers' state slots (ISSUE 38): decode steps, live slots,
     # resets and scanned prompt tokens; the same shared goodput surface
     "dyn_llm_ssm_layer_steps",

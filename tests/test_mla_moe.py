@@ -110,9 +110,11 @@ def pack(prompts: list[list[int]], tables: np.ndarray, P: int):
 
 
 def greedy(B):
+    """(keys, temps, top_ps, top_ks, want_lps): every lane greedy, every
+    lane asking for its log-probs."""
     return (
         jnp.zeros((B, 2), jnp.uint32), jnp.zeros(B, jnp.float32),
-        jnp.ones(B, jnp.float32), jnp.zeros(B, jnp.int32),
+        jnp.ones(B, jnp.float32), jnp.zeros(B, jnp.int32), jnp.ones(B, bool),
     )
 
 
@@ -159,14 +161,14 @@ def test_packed_prefill_then_decode_multi_against_the_reference(attn_impl, dtype
         *head, planes(cfg, dt), (), last
     )
     first = np.asarray(jnp.argmax(logits, axis=-1), np.int32)  # [2]
-    keys, temps, top_ps, top_ks = greedy(B)
+    keys, temps, top_ps, top_ks, want = greedy(B)
     packed, kc, vc = jax.jit(
         functools.partial(ModelRunner._decode_multi_impl, cfg, None, None, BS),
         static_argnums=(0,),
     )(
         H, params, kc, (), jnp.asarray([first[0], first[1], 0], jnp.int32),
         jnp.asarray([n, n, 0], jnp.int32), jnp.asarray(tables), keys, temps,
-        top_ps, top_ks, jnp.asarray([True, True, False]),
+        top_ps, top_ks, want, jnp.asarray([True, True, False]),
         jnp.full(B, 100, jnp.int32), jnp.zeros(B, jnp.int32),
         jnp.full((B, MAX_EOS_IDS), -1, jnp.int32),
     )
@@ -220,7 +222,7 @@ def test_mixed_step_with_a_chunked_prompt_against_the_reference():
         *head, planes(cfg), (), last
     )
     tok = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
-    keys, temps, top_ps, top_ks = greedy(B)
+    keys, temps, top_ps, top_ks, want = greedy(B)
     mixed = jax.jit(functools.partial(ModelRunner._mixed_impl, cfg, None, None))
     sequences = [p + [int(t)] for p, t in zip(prompts, tok)]
     lane_ids, lane_lps, chunk_out = [[], []], [[], []], None
@@ -231,14 +233,14 @@ def test_mixed_step_with_a_chunked_prompt_against_the_reference():
         chunk = (
             jnp.asarray(ctoks), jnp.int32(start), jnp.int32(n_long),
             jnp.asarray(long_table), jnp.zeros(2, jnp.uint32), jnp.float32(0.0),
-            jnp.float32(1.0), jnp.int32(0), jnp.float32(1.0),
+            jnp.float32(1.0), jnp.int32(0), jnp.bool_(True), jnp.float32(1.0),
             jnp.full(MAX_EOS_IDS, -1, jnp.int32), jnp.bool_(False),
         )
         positions = np.asarray([n + step, n + step], np.int32)
         slots = tables[np.arange(B), positions // BS] * BS + positions % BS
         outs, kc, _ = mixed(
             params, kc, (), (chunk,), jnp.asarray(tok), jnp.asarray(positions),
-            jnp.asarray(tables), jnp.asarray(slots), keys, temps, top_ps, top_ks,
+            jnp.asarray(tables), jnp.asarray(slots), keys, temps, top_ps, top_ks, want,
             jnp.full((B, MAX_EOS_IDS), -1, jnp.int32), jnp.zeros(B, bool),
         )
         chunk_out, (tok, _, ids, lps) = outs[:4], outs[4:8]
@@ -432,7 +434,10 @@ def lowered(cfg, program: str) -> str:
     # where a layer keeps a slot a lane, a program that prefills is told each
     # sequence's, behind its other arguments
     slot = lambda *shape: (sds(shape, i32),) if slotted else ()
-    lanes = (sds((B, 2), jnp.uint32), sds((B,), f32), sds((B,), f32), sds((B,), i32))
+    lanes = (
+        sds((B, 2), jnp.uint32), sds((B,), f32), sds((B,), f32), sds((B,), i32),
+        sds((B,), jnp.bool_),
+    )
     if program == "decode_multi":
         return jax.jit(
             functools.partial(ModelRunner._decode_multi_impl, cfg, None, None, BS),
@@ -446,7 +451,8 @@ def lowered(cfg, program: str) -> str:
         chunk = (
             sds((C,), i32), sds((), i32), sds((), i32), sds((MAX_BLOCKS,), i32),
             sds((2,), jnp.uint32), sds((), f32), sds((), f32), sds((), i32),
-            sds((), f32), sds((MAX_EOS_IDS,), i32), sds((), jnp.bool_), *slot(),
+            sds((), jnp.bool_), sds((), f32), sds((MAX_EOS_IDS,), i32),
+            sds((), jnp.bool_), *slot(),
         )
         return jax.jit(
             functools.partial(ModelRunner._mixed_impl, cfg, None, None)
@@ -461,15 +467,15 @@ def lowered(cfg, program: str) -> str:
         ).lower(
             params, k_cache, v_cache, sds((P,), i32), sds((), i32),
             sds((MAX_BLOCKS,), i32), sds((2,), jnp.uint32), sds((), f32),
-            sds((), f32), sds((), i32), sds((), f32), sds((MAX_EOS_IDS,), i32),
-            sds((), jnp.bool_), *slot(),
+            sds((), f32), sds((), i32), sds((), jnp.bool_), sds((), f32),
+            sds((MAX_EOS_IDS,), i32), sds((), jnp.bool_), *slot(),
         ).as_text()
     return jax.jit(
         functools.partial(ModelRunner._prefill_packed_impl, cfg, None)
     ).lower(
         params, k_cache, v_cache, sds((P,), i32), sds((P,), i32), sds((P,), i32),
         sds((P,), i32), sds((2,), i32), sds((2, 2), jnp.uint32), sds((2,), f32),
-        sds((2,), f32), sds((2,), i32), sds((2,), f32),
+        sds((2,), f32), sds((2,), i32), sds((2,), jnp.bool_), sds((2,), f32),
         sds((2, MAX_EOS_IDS), i32), sds((2,), jnp.bool_), *slot(2),
     ).as_text()
 
@@ -497,35 +503,42 @@ def operations(text: str) -> dict[str, int]:
 # `live` compare now stands before the scatter, not behind it, so the texts'
 # digests are new (04f787b092f94f4f, 4b44078fd9ec224b, ec18c988b51032e1,
 # 74e83189d87bd651 before).
+# Re-read in PR 53 for all twenty-two: every program's sampler takes the
+# lanes' `want_logprobs` and holds the log-prob surface under a second
+# `cond`, twelve operations a sampler call more (thirteen where the flag is a
+# scalar widened to a lane: a prefill's and a chunk's tail); nothing else of
+# a text moved. PR 50's: 1581/36b61c79679131e3, 1687, 839, 1576, 1678, 833,
+# 2052, 2664, 1319, 1239, 1666, 2136, 1235, 1155, 2173, 2805, 1498, 1446,
+# 1878, 2437, 1553, 1470 in the order below.
 PARENT_PROGRAMS = {
-    ("mistral", "decode_multi"): (1581, "36b61c79679131e3"),
-    ("mistral", "mixed_step"): (1687, "e7270e453bfea6cb"),
-    ("mistral", "prefill_packed"): (839, "192b9eac1aa901b3"),
-    ("qwen", "decode_multi"): (1576, "cd14ee6e89bfa824"),
-    ("qwen", "mixed_step"): (1678, "3b82c67f1415889d"),
-    ("qwen", "prefill_packed"): (833, "5aad89bbf6ac4b7f"),
+    ("mistral", "decode_multi"): (1629, "76e6d0321d223665"),
+    ("mistral", "mixed_step"): (1712, "759ce9a772993302"),
+    ("mistral", "prefill_packed"): (851, "18fb0ec552df6d17"),
+    ("qwen", "decode_multi"): (1624, "f9f71943f858df2e"),
+    ("qwen", "mixed_step"): (1703, "197c502ee8d9db16"),
+    ("qwen", "prefill_packed"): (845, "aeaaf274988453f1"),
     # The four newer families at their test files' toy configs, read at
     # commit cc32b34 (PR 49) before PR 50 wrote their four step programs once
     # (`models/programs.py`): the text a family's own copy of the programs
     # lowered. `prefill` beside the three, because one family gives the whole
     # prompt bodies of its own. A change to a family's layer bodies, to the
     # shared programs or to the sampler moves them; say so and re-read.
-    ("latent", "decode_multi"): (2052, "9a53cdff0c087bd9"),
-    ("latent", "mixed_step"): (2664, "4869490260e001a1"),
-    ("latent", "prefill"): (1319, "2ed32fd3f7d6366e"),
-    ("latent", "prefill_packed"): (1239, "db4b75864c05e30c"),
-    ("hybrid_ssm", "decode_multi"): (1666, "2bbabb37e08bc122"),
-    ("hybrid_ssm", "mixed_step"): (2136, "ba784010dda50f88"),
-    ("hybrid_ssm", "prefill"): (1235, "4853d45237f6d583"),
-    ("hybrid_ssm", "prefill_packed"): (1155, "513d6cc5151c89d7"),
-    ("conv_moe", "decode_multi"): (2173, "ce8b47edf4033158"),
-    ("conv_moe", "mixed_step"): (2805, "9df36a53b5b7cf7b"),
-    ("conv_moe", "prefill"): (1498, "24f44c6fc09c7e80"),
-    ("conv_moe", "prefill_packed"): (1446, "431d265525c7d60c"),
-    ("ssm2_moe", "decode_multi"): (1878, "3c62eebe0d8a83f8"),
-    ("ssm2_moe", "mixed_step"): (2437, "d73a07f1a112963d"),
-    ("ssm2_moe", "prefill"): (1553, "9622fb96fdb62b4f"),
-    ("ssm2_moe", "prefill_packed"): (1470, "fc84b364ac71331b"),
+    ("latent", "decode_multi"): (2100, "5a4e6fd2ade23217"),
+    ("latent", "mixed_step"): (2689, "76952028362eca78"),
+    ("latent", "prefill"): (1332, "501d4c0627cd4e03"),
+    ("latent", "prefill_packed"): (1251, "5cb0ce2a93d8e4ff"),
+    ("hybrid_ssm", "decode_multi"): (1714, "a9d1d341cc2a4e45"),
+    ("hybrid_ssm", "mixed_step"): (2161, "fe083eda98778878"),
+    ("hybrid_ssm", "prefill"): (1248, "53ac6a1a2d62bc79"),
+    ("hybrid_ssm", "prefill_packed"): (1167, "3ee57776a3bbeafa"),
+    ("conv_moe", "decode_multi"): (2221, "6c012ca30994a869"),
+    ("conv_moe", "mixed_step"): (2830, "be5e60bf6a9c64ed"),
+    ("conv_moe", "prefill"): (1511, "d7e43a1e5d5e107e"),
+    ("conv_moe", "prefill_packed"): (1458, "626867b07f50d96b"),
+    ("ssm2_moe", "decode_multi"): (1926, "03fbeb4d4d974f7f"),
+    ("ssm2_moe", "mixed_step"): (2462, "c9bfcce8917eafbf"),
+    ("ssm2_moe", "prefill"): (1566, "54543ed180567f5b"),
+    ("ssm2_moe", "prefill_packed"): (1482, "ec03fa7e84fd6a49"),
 }
 
 

@@ -272,17 +272,18 @@ def _bytes_by_the_code(label: str) -> set[int]:
     (4 lanes, 16 table columns, 8-token chunks, 4 EOS ids) without
     penalties: every lane array's elements, a bool as four bytes; a chunk
     of a mixed step brings 8 tokens, 16 table entries, a key, 4 EOS ids and
-    seven scalars beside the decode half's ten arrays."""
+    eight scalars beside the decode half's eleven arrays. One vector a
+    label and one scalar a chunk are PR 53's: who asked for log-probs."""
     B, W, C, E = 4, 16, 8, 4
     lanes = B * W + 2 * B  # block tables and keys
     if label.startswith("mixed_step@c"):
         k = int(label.rsplit("c", 1)[1])
-        return {4 * (k * (C + W + 2 + E + 7) + lanes + 7 * B + B * E)}
+        return {4 * (k * (C + W + 2 + E + 8) + lanes + 8 * B + B * E)}
     return {
-        # the ninth vector is `chain`, the lanes that go on from the carry
-        "decode_multi@H4B4": {4 * (lanes + 9 * B + B * E)},
-        "decode": {4 * (lanes + 6 * B), 4 * (lanes + 7 * B + B * E)},
-        "prefill_packed": {4 * (4 * C + 2 * B + 6 * B + B * E)},
+        # the tenth vector is `chain`, the lanes that go on from the carry
+        "decode_multi@H4B4": {4 * (lanes + 10 * B + B * E)},
+        "decode": {4 * (lanes + 7 * B), 4 * (lanes + 8 * B + B * E)},
+        "prefill_packed": {4 * (4 * C + 2 * B + 7 * B + B * E)},
     }[label]
 
 

@@ -95,7 +95,7 @@ def test_sample_tokens_full_logprob_surface():
         logits, jax.random.PRNGKey(0),
         jnp.zeros(3, jnp.float32),  # greedy
         jnp.ones(3, jnp.float32), jnp.zeros(3, jnp.int32),
-        num_top=4,
+        jnp.ones(3, bool), num_top=4,
     )
     toks, lps, tids, tlps = map(np.asarray, (toks, lps, tids, tlps))
     assert (lps <= 0).all()
@@ -212,11 +212,10 @@ def test_sample_tokens_is_the_two_draw_sampler_bit_for_bit(case, per_lane_keys):
         if not per_lane_keys:
             continue
         # the log-prob surface hangs on the token alone and is as it was
-        full = sample_tokens_full(logits, None, temp, top_p, top_k, keys=keys)
-        logz = jax.nn.log_softmax(logits, axis=-1)
-        top_lps, top_ids = jax.lax.top_k(logz, 20)
-        chosen = jnp.take_along_axis(logz, want[:, None], axis=-1)[:, 0]
-        for g, w in zip(full, (want, chosen, top_ids, top_lps)):
+        full = sample_tokens_full(
+            logits, None, temp, top_p, top_k, jnp.ones(_LANES, bool), keys=keys
+        )
+        for g, w in zip(full, (want, *_surface(logits, want))):
             np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 
 
@@ -236,6 +235,17 @@ def test_a_lanes_token_does_not_depend_on_its_neighbours(neighbour):
     beside = np.asarray(sample_tokens(logits, None, temp, top_p, top_k, keys=keys))
     others = np.arange(_LANES) != 3
     np.testing.assert_array_equal(beside[others], alone[others])
+
+
+def _surface(logits, tokens, num_top: int = 20):
+    """(chosen, top_ids, top_lps) as `sample_tokens_full` gave them for
+    every lane of every call until PR 53."""
+    import jax.numpy as jnp
+
+    logz = jax.nn.log_softmax(logits, axis=-1)
+    top_lps, top_ids = jax.lax.top_k(logz, num_top)
+    chosen = jnp.take_along_axis(logz, tokens[:, None], axis=-1)[:, 0]
+    return chosen, top_ids, top_lps
 
 
 def _sub_jaxprs(eqn):
@@ -294,6 +304,67 @@ def test_the_pool_is_inside_one_conditional(per_lane_keys):
     assert [e.params["k"] for e in pool_top_k] == [SAMPLE_CANDIDATES]
 
 
+ASKING = {"no_lane_asks": [], "one_of_several_asks": [2], "all_ask": range(8)}
+
+
+@pytest.mark.parametrize("per_lane_keys", [True, False], ids=["keys", "rng"])
+@pytest.mark.parametrize("asking", list(ASKING))
+def test_the_logprob_surface_is_computed_where_a_lane_asked(asking, per_lane_keys):
+    """The result: every lane's token is the one the sampler gave it when
+    the surface was computed for all (the pool's reference again), whoever
+    asks; the surface is the parent's for EVERY lane where one lane asks
+    and zeros where none does. The cost: the top 20, `log_softmax`'s
+    reductions and the chosen id's gather sit in one branch of one `cond`
+    and nowhere else, and the other branch computes nothing."""
+    import jax.numpy as jnp
+
+    want_lps = np.zeros(_LANES, bool)
+    want_lps[list(ASKING[asking])] = True
+    want_lps = jnp.asarray(want_lps)
+    # lanes of every kind in one batch: greedy, unrestricted, top_k, top_p
+    logits, temp, top_p, top_k, keys = _draw_inputs("all_unrestricted_at_0.7", 4)
+    temp = temp.at[0].set(0.0)
+    top_k, top_p = top_k.at[5].set(20), top_p.at[6].set(0.9)
+    rng = jax.random.PRNGKey(4)
+    kw = {"keys": keys} if per_lane_keys else {}
+    tokens = _two_draw_sample_tokens(logits, rng, temp, top_p, top_k, **kw)
+    got = jax.jit(sample_tokens_full)(logits, rng, temp, top_p, top_k, want_lps, **kw)
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(tokens))
+    surface = _surface(logits, tokens)
+    for g, w in zip(got[1:], surface):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        np.testing.assert_array_equal(
+            np.asarray(g), np.asarray(w) if ASKING[asking] else np.zeros(w.shape)
+        )
+    jaxpr = jax.make_jaxpr(
+        lambda *a: sample_tokens_full(a[0], rng, *a[1:], **kw)
+    )(logits, temp, top_p, top_k, want_lps).jaxpr
+    conds = [e for e in jaxpr.eqns if e.primitive.name == "cond"]
+    assert len(conds) == 2 and _primitives(jaxpr).count("cond") == 2  # the pool's too
+    top_20 = lambda eqns: [
+        e for e in eqns if e.primitive.name == "top_k" and e.params["k"] == 20
+    ]
+    holds = [
+        [len(top_20(b.jaxpr.eqns)) for b in c.params["branches"]] for c in conds
+    ]
+    assert sorted(map(sorted, holds)) == [[0, 0], [0, 1]], holds
+    (surface_cond,) = [c for c, h in zip(conds, holds) if sum(h)]
+    computes, nothing = sorted(
+        (_primitives(b.jaxpr) for b in surface_cond.params["branches"]),
+        key=lambda b: "top_k" not in b,
+    )
+    assert {"top_k", "reduce_max", "exp", "reduce_sum", "log", "gather"} <= set(computes)
+    assert set(nothing) <= {"broadcast_in_dim"}, nothing
+    outside = [e.primitive.name for e in jaxpr.eqns if e.primitive.name != "cond"]
+    for e in jaxpr.eqns:
+        if e.primitive.name != "cond":
+            for sub in _sub_jaxprs(e):
+                _primitives(sub, outside)
+    # beside the conditionals: the argmax, the draw and the predicates; no
+    # selection and no reduction of `log_softmax` over the vocabulary
+    assert not {"top_k", "reduce_max", "exp", "reduce_sum", "gather"} & set(outside), outside
+
+
 def test_draw_restrictions_is_one_rule_for_host_and_device():
     """The engine's counter asks numpy arrays what `sample_tokens` asks the
     device's: the same function, the same answers."""
@@ -315,6 +386,14 @@ def test_draw_restrictions_is_one_rule_for_host_and_device():
         "top_k_and_top_p_together": True, "wide_nucleus_above_1.25": True,
         "vocabulary_smaller_than_the_pool": True,
     }
+    # the log-prob surface's predicate likewise: one function for both
+    from dynamo_tpu.ops.sampling import surface_wanted
+
+    for asking, lanes in ASKING.items():
+        want_lps = np.zeros(_LANES, bool)
+        want_lps[list(lanes)] = True
+        on_host, on_device = surface_wanted(want_lps), surface_wanted(jnp.asarray(want_lps))
+        assert bool(on_host) == bool(on_device) == bool(len(lanes)), asking
 
 
 # ---------------------------------------------------------------- engine
@@ -404,6 +483,53 @@ async def test_logprobs_populated():
     await engine.close()
 
 
+async def test_logprobs_beside_streams_that_asked_for_none():
+    """A stream that asks for `top_logprobs` reads the same log-probs
+    beside streams that do not as when it runs alone (its first token from
+    a packed prefill among theirs, the others from horizons whose batch
+    holds it), and the others carry none; when the asking stream has ended,
+    the lanes left take the branch that computes nothing and their tokens
+    are what they are without it."""
+    engine = make_engine(max_batch=4)
+
+    async def items(req):
+        return [o async for o in engine.generate(req, Context()) if o.token_ids]
+
+    asks = lambda: PreprocessedRequest(
+        token_ids=[2, 4, 6],
+        sampling=SamplingOptions(temperature=0.7, seed=5, logprobs=True, top_logprobs=3),
+        stop=StopConditions(max_tokens=6, ignore_eos=True),
+    )
+    quiet = lambda: [
+        sampled_request([3, 1, 4, 1, 5], 14, temperature=0.7, seed=1),
+        greedy_request([9, 2, 6], 14),
+    ]
+    try:
+        alone = await items(asks())
+        others_alone = await asyncio.gather(*map(items, quiet()))
+        beside, *others = await asyncio.gather(items(asks()), *map(items, quiet()))
+        flat = lambda outs, field: [x for o in outs for x in getattr(o, field)]
+        assert flat(beside, "token_ids") == flat(alone, "token_ids")
+        assert len(flat(alone, "log_probs")) == 6
+        np.testing.assert_allclose(
+            flat(beside, "log_probs"), flat(alone, "log_probs"), rtol=1e-5
+        )
+        for got, want in zip(flat(beside, "top_logprobs"), flat(alone, "top_logprobs")):
+            assert [t for t, _ in got] == [t for t, _ in want] and len(got) == 3
+            np.testing.assert_allclose(
+                [lp for _, lp in got], [lp for _, lp in want], rtol=1e-5
+            )
+        assert any(lp < 0.0 for lp in flat(alone, "log_probs"))  # not the zeros
+        for outs, outs_alone in zip(others, others_alone):
+            assert all(o.log_probs is None and o.top_logprobs is None for o in outs)
+            assert flat(outs, "token_ids") == flat(outs_alone, "token_ids")
+            assert len(flat(outs, "token_ids")) == 14
+        counted = engine.stats.goodput.summary()["sampler"]
+        assert 0 < counted["logprob_dispatches"] < counted["dispatches"]
+    finally:
+        await engine.close()
+
+
 async def test_packed_prefill_parity_with_sequential():
     """Batched (packed) prefill admission must produce identical greedy
     outputs to one-at-a-time serving (segment masking = exact causal
@@ -452,11 +578,13 @@ async def test_min_tokens_suppresses_eos():
     await engine.close()
 
 
-@pytest.mark.parametrize("restricted", [True, False], ids=["one_top_p", "none"])
-async def test_ledger_counts_the_dispatches_that_needed_the_pool(restricted):
+@pytest.mark.parametrize("beside", ["one_top_p", "none", "one_logprobs"])
+async def test_ledger_counts_the_dispatches_that_needed_the_pool(beside):
     """`/debug/goodput`'s `sampler` slot (the ledger's summary) and its
     Prometheus twin: two lanes that draw over the whole vocabulary, and
-    for a few tokens one with `top_p` 0.9 beside them."""
+    for a few tokens one with `top_p` 0.9 beside them, or one that asks
+    for log-probs (`logprob_dispatches`: the dispatches whose steps
+    computed the log-prob surface)."""
     from prometheus_client import generate_latest
 
     from dynamo_tpu.http.metrics import ServiceMetrics
@@ -467,10 +595,11 @@ async def test_ledger_counts_the_dispatches_that_needed_the_pool(restricted):
             sampled_request([3, 1, 4, 1, 5], 24, temperature=0.7, seed=1),
             sampled_request([9, 2, 6, 5], 24, temperature=0.7, seed=2),
         ]
-        if restricted:
-            reqs.append(
-                sampled_request([2, 7, 1, 8], 4, temperature=0.7, top_p=0.9, seed=3)
-            )
+        extra = {"one_top_p": {"top_p": 0.9}, "one_logprobs": {"logprobs": True}}
+        if beside in extra:
+            reqs.append(sampled_request(
+                [2, 7, 1, 8], 4, temperature=0.7, seed=3, **extra[beside]
+            ))
         out = await asyncio.gather(*(collect(engine, r) for r in reqs))
         assert [len(t) for t, _ in out] == [24, 24, 4][: len(reqs)]
         summary = engine.stats.goodput.summary()
@@ -478,10 +607,13 @@ async def test_ledger_counts_the_dispatches_that_needed_the_pool(restricted):
         assert counted["dispatches"] == sum(
             v["count"] for k, v in by_label.items() if not k.startswith("prefill")
         ) > 0
-        if restricted:
-            assert 0 < counted["pool_dispatches"] < counted["dispatches"]
-        else:
-            assert counted["pool_dispatches"] == 0
+        for name, case in (
+            ("pool_dispatches", "one_top_p"), ("logprob_dispatches", "one_logprobs"),
+        ):
+            if beside == case:
+                assert 0 < counted[name] < counted["dispatches"]
+            else:
+                assert counted[name] == 0
         metrics = ServiceMetrics()
         metrics.attach_goodput({"goodput": engine.stats.goodput}, None)
         text = generate_latest(metrics.registry).decode()

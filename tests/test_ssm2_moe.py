@@ -265,7 +265,7 @@ def test_a_prompt_prefilled_in_three_chunks_equals_one_pass(attn_impl):
     )
     tok = np.zeros(LANES, np.int32)
     tok[[0, 2]] = np.asarray(jnp.argmax(logits, axis=-1), np.int32)[:2]
-    keys, temps, top_ps, top_ks = greedy(LANES)
+    keys, temps, top_ps, top_ks, want = greedy(LANES)
     mixed = jax.jit(functools.partial(ModelRunner._mixed_impl, cfg, None, None))
     sequences = {0: prompts[0] + [int(tok[0])], 2: prompts[1] + [int(tok[2])]}
     lane_ids, lane_lps = {0: [], 2: []}, {0: [], 2: []}
@@ -278,7 +278,7 @@ def test_a_prompt_prefilled_in_three_chunks_equals_one_pass(attn_impl):
         slots[1] = 0  # lane 1 does not decode: its write goes to the null block
         outs, kc, vc = mixed(
             params, kc, vc, (chunk,), jnp.asarray(tok), jnp.asarray(positions),
-            jnp.asarray(tables), jnp.asarray(slots), keys, temps, top_ps, top_ks,
+            jnp.asarray(tables), jnp.asarray(slots), keys, temps, top_ps, top_ks, want,
             jnp.full((LANES, MAX_EOS_IDS), -1, jnp.int32), jnp.zeros(LANES, bool),
         )
         chunk_out, (new, _, ids, lps) = outs[:4], outs[4:8]
@@ -356,7 +356,7 @@ def test_decode_multi_equals_single_steps_with_a_lane_that_ends_inside():
     )
     packed = np.asarray(packed)
     assert (packed[2:, 0, 0] == -1).all() and (packed[:2, 0, 0] >= 0).all()
-    keys, temps, top_ps, top_ks = greedy(LANES)
+    keys, temps, top_ps, top_ks, want = greedy(LANES)
     single = jax.jit(functools.partial(ModelRunner._decode_impl, cfg, None, None))
     tok = np.asarray([first[0], 0, first[1]], np.int32)
     pos = np.asarray([n, 0, n], np.int32)
@@ -367,7 +367,7 @@ def test_decode_multi_equals_single_steps_with_a_lane_that_ends_inside():
         step_keys = keys.at[:, 1].add(jnp.uint32(h))
         (t, lp, _, _), k1, v1 = single(
             params, k1, v1, jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(tables),
-            jnp.asarray(slots.astype(np.int32)), step_keys, temps, top_ps, top_ks,
+            jnp.asarray(slots.astype(np.int32)), step_keys, temps, top_ps, top_ks, want,
         )
         t, lp = np.asarray(t), np.asarray(lp)
         for lane in (0, 2):
