@@ -86,7 +86,9 @@ def unrolled_steps(step, init, H: int):
     ys = []
     carry = init
     for h in range(H):
-        carry, y = step(carry, jnp.int32(h))
+        # the step's place twice: traced, for what it computes from it, and
+        # as Python's, for what it decides while it is traced
+        carry, y = step(carry, jnp.int32(h), h)
         ys.append(y)
     return carry, jnp.stack(ys)
 
@@ -807,13 +809,14 @@ class ModelRunner:
         eos_valid = eos_ids >= 0
         model = forward_for(cfg)
         step_stats = getattr(model, "STEP_STATS", ())
+        settles = getattr(model, "DECODE_SETTLES", False)
         if pen is not None:
             hist, hist_len, prompt_len, freq, pres, rep = pen
             out_counts, seen = penalty_count_tables(
                 hist, hist_len, prompt_len, cfg.vocab_size
             )
 
-        def step(carry, h):
+        def step(carry, h, nth):
             if pen is None:
                 tokens, positions, k_cache, v_cache, done = carry
             else:
@@ -830,6 +833,9 @@ class ModelRunner:
                 block_tables, slot_idx,
                 mesh=attn_mesh, attn_head_axis=attn_head_axis,
                 **({"stats": stats} if step_stats else {}),
+                # the dispatch's last step writes what a layer's earlier
+                # steps left unwritten (`models.programs.decode`)
+                **({"settle": nth == H - 1} if settles else {}),
             )
             if pen is not None:
                 logits = apply_penalties_from_tables(

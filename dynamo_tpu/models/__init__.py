@@ -232,8 +232,10 @@ def forms_called() -> collections.Counter:
     inside the paged decode kernel (`kv_append_folded`) and how many by the
     row scatter before it (`kv_append_scattered`); how many grouped products
     of their experts run in the Pallas kernel (`grouped_product_kernel`) and
-    how many in XLA's (`grouped_product_xla`). A name nothing counted reads
-    0."""
+    how many in XLA's (`grouped_product_xla`); how many Mamba-2 state updates
+    run in the Pallas kernel (`ssd_step_kernel`: a layer once for each shape
+    of call a dispatch's steps make, `ops.pallas_ssm`) and how many in XLA's
+    (`ssd_step_xla`). A name nothing counted reads 0."""
     return collections.Counter(getattr(_watching, "forms", None))
 
 

@@ -266,16 +266,22 @@ def decode(
     mesh=None,
     attn_head_axis=None,
     stats: Optional[list] = None,
+    settle: bool = True,
 ) -> tuple[jax.Array, tuple, tuple]:
     """One decode step for a batch; lane b's recurrent state is row b of the
     slot arrays. A lane whose row goes to the null block holds no decoding
     sequence: it reads no page, is given to no expert, and its slot stays as
-    it is. Returns (logits [B, V], caches)."""
+    it is. `settle`: this step is the last of its dispatch, or the only one;
+    a dispatch of several steps says false for the others, and a layer whose
+    body lists it may then leave what it keeps unwritten and hand its next
+    step, in the array's place, what that step needs to write it
+    (`ops.pallas_ssm.Deferred`). Returns (logits [B, V], caches)."""
     live = live_decode_lanes(k_cache[_first(cfg, True)], slot_indices)
     values = dict(
         positions=positions, live=live, context=jnp.where(live, positions + 1, 0),
         block_tables=block_tables, slot_indices=slot_indices, mesh=mesh,
         head_axis=attn_head_axis, page_size=_page_size(cfg, k_cache),
+        settle=settle,
     )
     x, k_out, v_out = _walk(
         family, "decode", params, cfg, tokens, k_cache, v_cache, values, stats

@@ -95,7 +95,7 @@ from dynamo_tpu.models import (
 )
 from dynamo_tpu.models.programs import Body, Family
 from dynamo_tpu.models.programs import dense as _dense, normal as _normal
-from dynamo_tpu.ops import ssm
+from dynamo_tpu.ops import pallas_ssm, ssm
 from dynamo_tpu.ops.attention import (
     chunked_prefill_attention, decode_append_attention,
     packed_prefill_attention, write_decode_kv,
@@ -109,6 +109,10 @@ from dynamo_tpu.ops.moe import (
 )
 
 MODEL_TYPES = ("nemotron_h",)
+# `decode` takes `settle` (`models.programs.decode`): the runner's dispatch of
+# several steps says which is its last, as it hands `stats` to a module that
+# has `STEP_STATS`
+DECODE_SETTLES = True
 F32 = jnp.float32
 LAYER_KINDS = "M*E"
 # the published routing's normaliser: the chosen scores over (their sum + this)
@@ -598,12 +602,15 @@ def _attn_chunk_layer(x, layer, k_l, v_l, slots, block_table, chunk_start, *, cf
     return _attn_out(attn, x, layer, cfg), k_l, v_l
 
 
-@layer_body("cfg")
-def _mamba_decode_layer(x, layer, state, tail, live, *, cfg):
-    # every row of the slot arrays is updated under one mask, the null
-    # lane's with them (it is never live): no slice of the arrays, no
-    # update of a slice, so the step writes them where they lie
-    B, S = x.shape[0], state.shape[0]
+@layer_body("cfg", "settle")
+def _mamba_decode_layer(x, layer, state, tail, live, *, cfg, settle):
+    # the tail's every row is updated under one mask, the null lane's with
+    # them (it is never live), and so is the state's where the plain form
+    # runs: no slice of the arrays, no update of a slice, so the step writes
+    # them where they lie. Where the kernel runs it visits the live lanes'
+    # rows alone, and `state` is what the dispatch's step before this one
+    # deferred (`ops.pallas_ssm`)
+    B, S = x.shape[0], tail.shape[0]
     z, xbc, dt = _in_proj(x, layer, cfg)
     rows = lambda v: jnp.pad(v, ((0, S - B),) + ((0, 0),) * (v.ndim - 1))
     live_rows = rows(live)
@@ -612,10 +619,10 @@ def _mamba_decode_layer(x, layer, state, tail, live, *, cfg):
         tail = jnp.where(live_rows[:, None], new_tail, tail)
         xs, b, c, dt, a = _update_inputs(conv[:B], dt, layer, cfg)
         with jax.named_scope("ssm2.update"):
-            state, y = ssm.ssd_step(
-                state, rows(xs), rows(dt), a, rows(b), rows(c), live_rows
+            state, y = pallas_ssm.ssd_update(
+                state, xs, dt, a, b, c, live, settle=settle, impl=cfg.attn_impl
             )
-        return _mixer_out(y[:B], xs, z, x, layer, cfg), state, tail
+        return _mixer_out(y, xs, z, x, layer, cfg), state, tail
 
 
 @layer_body("cfg", "mesh", "head_axis")
@@ -643,12 +650,23 @@ def _packed_slots(cfg, *, segment_ids, last_idx, state_slots, null, **_):
     return {"seg_slots": seg_slots, "count": count}
 
 
+def _settles(cfg, *, settle, **_):
+    """Whether this decode step writes the Mamba-2 layers' state: the
+    dispatch's word where the kernel runs; always where the plain form does,
+    whose every step writes, so that its layers are one body as ever."""
+    kernel = pallas_ssm.tiling(
+        cfg.mamba_heads, cfg.mamba_head_dim, cfg.d_state, cfg.n_groups,
+        cfg.attn_impl,
+    )
+    return {"settle": settle or kernel is None}
+
+
 # A layer by its letter in the pattern. `M` keeps the state `[S, Hm, P, N]`
 # and the tail `[S, (K-1)*conv_dim]`, `*` pages of keys and values
 # `[Hkv, nb, bs, D]`; `E` keeps nothing and is told which tokens are real.
 FAMILY = Family(
     kind=Ssm2MoeConfig.kind,
-    prepare={"packed": _packed_slots},
+    prepare={"packed": _packed_slots, "decode": _settles},
     packed={
         "M": Body(_mamba_packed_layer, 2, (
             "positions", "valid", "last_idx", "seg_slots", "count")),
@@ -663,7 +681,7 @@ FAMILY = Family(
         "E": Body(_expert_layer, 0, ("valid",)),
     },
     decode={
-        "M": Body(_mamba_decode_layer, 2, ("live",)),
+        "M": Body(_mamba_decode_layer, 2, ("live",), static=("settle",)),
         "*": Body(
             _attn_decode_layer, 2, ("context", "block_tables", "slot_indices"),
             static=("mesh", "head_axis"),

@@ -211,7 +211,9 @@ def scan_packed(states, x, delta, b, c, a_neg, positions, valid, write_slots):
 #     y_t[j] = S_t[j] C_t[g]
 #
 # with `a = -exp(A_log)` a scalar a head and S[j] `[P, N]` float32, `d_state`
-# last (the TPU's tiled dimension). A lane's slot is `[H, P, N]`.
+# last (the TPU's tiled dimension). A lane's slot is `[H, P, N]`. On the chip
+# a decode step's update is `ops/pallas_ssm.py`'s kernel over the live lanes'
+# rows; `ssd_step` is the form it is held to, and what runs everywhere else.
 
 
 def ssd_step(s, x, dt, a, b, c, live):

@@ -205,10 +205,14 @@ def long_part(parts: dict[str, float]) -> str:
 # layers its programs called (`models.forms_called`), how many append a
 # decode token's keys and values to their cache inside the paged decode
 # kernel and how many by the row scatter before it, and how many grouped
-# products of their experts run in the Pallas kernel and how many in XLA's
+# products of their experts run in the Pallas kernel and how many in XLA's,
+# and how many Mamba-2 state updates run in the Pallas kernel that visits the
+# live lanes' rows alone and how many in XLA's over every row (a dispatch of
+# several steps calls a layer's kernel in one shape a step, each counted)
 LAYER_FORMS = (
     "kv_append_folded", "kv_append_scattered",
     "grouped_product_kernel", "grouped_product_xla",
+    "ssd_step_kernel", "ssd_step_xla",
 )
 FIRST_DISPATCH_FIELDS = (
     "trace_s", "lower_s", "backend_s", "layer_bodies",

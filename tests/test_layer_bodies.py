@@ -240,7 +240,7 @@ def test_a_nested_trace_counts_once():
 
 def test_the_split_merges_and_crosses_the_wire():
     a, b = GoodputLedger(enabled=True), GoodputLedger(enabled=True)
-    a.record_compile("decode", 12.0, {"trace_s": 1.0, "lower_s": 0.5, "backend_s": 9.0, "layer_bodies": 1, "kv_append_folded": 32, "grouped_product_kernel": 42})
+    a.record_compile("decode", 12.0, {"trace_s": 1.0, "lower_s": 0.5, "backend_s": 9.0, "layer_bodies": 1, "kv_append_folded": 32, "grouped_product_kernel": 42, "ssd_step_kernel": 20})
     b.record_compile("decode", 11.0, {"trace_s": 2.0, "lower_s": 0.25, "backend_s": 8.0, "layer_bodies": 1, "kv_append_scattered": 2, "grouped_product_xla": 3})
     b.record_compile("prefill_packed", 3.0)  # no split given: none kept
     merged = GoodputStats.from_dict(a.to_dict())
@@ -250,6 +250,7 @@ def test_the_split_merges_and_crosses_the_wire():
             "trace_s": 2.0, "lower_s": 0.5, "backend_s": 9.0, "layer_bodies": 1.0,
             "kv_append_folded": 32.0, "kv_append_scattered": 2.0,
             "grouped_product_kernel": 42.0, "grouped_product_xla": 3.0,
+            "ssd_step_kernel": 20.0, "ssd_step_xla": 0.0,
         },
     }
     assert merged.summary()["first_dispatch_by_label"]["decode"]["trace_s"] == 2.0

@@ -510,6 +510,12 @@ def operations(text: str) -> dict[str, int]:
 # a text moved. PR 50's: 1581/36b61c79679131e3, 1687, 839, 1576, 1678, 833,
 # 2052, 2664, 1319, 1239, 1666, 2136, 1235, 1155, 2173, 2805, 1498, 1446,
 # 1878, 2437, 1553, 1470 in the order below.
+# Re-read in PR 55 for `ssm2_moe`'s two programs that decode, and no other
+# entry: the Mamba-2 decode body's update goes through
+# `ops.pallas_ssm.ssd_update`, which, where the plain form runs (here), pads
+# the live mask to the slot rows itself beside the body's own pad for the
+# tail: one `pad` a body more (1926/03fbeb4d4d974f7f, 2462/c9bfcce8917eafbf
+# before), the same `ssd_step` on the same operands behind it.
 PARENT_PROGRAMS = {
     ("mistral", "decode_multi"): (1629, "76e6d0321d223665"),
     ("mistral", "mixed_step"): (1712, "759ce9a772993302"),
@@ -535,8 +541,8 @@ PARENT_PROGRAMS = {
     ("conv_moe", "mixed_step"): (2830, "be5e60bf6a9c64ed"),
     ("conv_moe", "prefill"): (1511, "d7e43a1e5d5e107e"),
     ("conv_moe", "prefill_packed"): (1458, "626867b07f50d96b"),
-    ("ssm2_moe", "decode_multi"): (1926, "03fbeb4d4d974f7f"),
-    ("ssm2_moe", "mixed_step"): (2462, "c9bfcce8917eafbf"),
+    ("ssm2_moe", "decode_multi"): (1927, "f498cd337f20eb50"),
+    ("ssm2_moe", "mixed_step"): (2463, "728220f1f353c081"),
     ("ssm2_moe", "prefill"): (1566, "54543ed180567f5b"),
     ("ssm2_moe", "prefill_packed"): (1482, "ec03fa7e84fd6a49"),
 }

@@ -381,6 +381,96 @@ def test_decode_multi_equals_single_steps_with_a_lane_that_ends_inside():
             np.testing.assert_allclose(np.asarray(vm[i][lane]), np.asarray(v1[i][lane]), atol=2e-5)
 
 
+# the toy with a Mamba-2 state the update kernel can tile (`ops/pallas_ssm.py`
+# `tiling`): 16 heads of 16 in 2 groups, so a row of 128 `dt x` is one group's
+# heads, and a state 128 wide
+KERNEL_HF = dict(HF, mamba_num_heads=16, ssm_state_size=128, expand=4)
+
+
+def test_decode_through_the_update_kernel_equals_the_plain_form():
+    """`decode` and `decode_multi@H4` with the Mamba-2 update in the Pallas
+    kernel (interpreted) against the same programs in the plain form, from
+    the same caches: lanes 0 and 2 decode (lane 0 may emit two tokens and then
+    stops), lane 1's slot is a chunked prefill's between two chunks. The same
+    tokens, logits and log-probs within the file's tolerance, the decoding
+    lanes' slots to float32 roundings; lane 1's slot and the null lane's are
+    bit for bit what they were; a dispatch's layers take the kernel once a
+    layer and step, the three calls that do not settle in a body of their
+    own each."""
+    from dynamo_tpu.models import forms_called, layer_bodies_called
+
+    cfgs = {
+        impl: dataclasses.replace(M.Ssm2MoeConfig.from_hf_dict(KERNEL_HF), attn_impl=impl)
+        for impl in ("xla", "pallas_interpret")
+    }
+    params = M.init_params(cfgs["xla"], jax.random.PRNGKey(3), jnp.float32)
+    n = 10
+    prompts = [prompt_tokens(n, 6), prompt_tokens(n, 7)]
+    tables = tables_for()
+    head, last = pack(prompts, tables[[0, 2]], 32)
+    kc, vc = caches(cfgs["xla"])
+    logits, kc, vc = jax.jit(functools.partial(M.prefill_packed, params, cfgs["xla"]))(
+        *head, kc, vc, last, state_slots=slots_of(LANES, [0, 2])
+    )
+    owned = np.random.default_rng(5)
+    kc = tuple(
+        k.at[1].set(jnp.asarray(owned.standard_normal(k.shape[1:]), jnp.float32))
+        if i in MAMBA_LAYERS else k for i, k in enumerate(kc)
+    )
+    first = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+    tok, pos = [first[0], 0, first[1]], [n, 0, n]
+    live = np.asarray([True, False, True])
+    slots = np.where(live, tables[np.arange(LANES), n // BS] * BS + n % BS, 0)
+    one, four, counted = {}, {}, {}
+    for impl, cfg in cfgs.items():
+        jax.clear_caches()  # a body traced for the other form would not be counted
+        with layer_bodies_called():
+            one[impl] = jax.jit(functools.partial(M.decode, params, cfg))(
+                jnp.asarray(tok, jnp.int32), jnp.asarray(pos, jnp.int32), kc, vc,
+                jnp.asarray(tables), jnp.asarray(slots.astype(np.int32)),
+            )
+        counted[impl, "decode"] = forms_called()
+        with layer_bodies_called() as bodies:
+            four[impl] = decode_multi(
+                cfg, params, 4, kc, vc, tok, pos, tables, list(live), [2, 1, 100]
+            )
+        counted[impl, "decode_multi"] = forms_called()
+        # a body a kind of layer; where the kernel runs, the Mamba-2 layer's
+        # three steps that do not settle are three more
+        assert len(bodies) == (3 if impl == "xla" else 6)
+    M_ = len(MAMBA_LAYERS)
+    forms = lambda impl, program: tuple(
+        counted[impl, program][f] for f in ("ssd_step_kernel", "ssd_step_xla")
+    )
+    assert forms("xla", "decode") == forms("xla", "decode_multi") == (0, M_)
+    assert forms("pallas_interpret", "decode") == (M_, 0)
+    assert forms("pallas_interpret", "decode_multi") == (4 * M_, 0)
+
+    (l_x, k_x, v_x), (l_k, k_k, v_k) = one["xla"], one["pallas_interpret"]
+    for lane in (0, 2):
+        assert rel(l_k[lane], l_x[lane]) < F32_TOL
+    (p_x, km_x, vm_x), (p_k, km_k, vm_k) = four["xla"], four["pallas_interpret"]
+    p_x, p_k = np.asarray(p_x), np.asarray(p_k)
+    assert (p_k[2:, 0, 0] == -1).all() and np.array_equal(p_k[:, :LANES, 0], p_x[:, :LANES, 0])
+    emitted = p_x[:, :LANES, 0] >= 0
+    assert np.abs(p_k[:, :LANES, 1] - p_x[:, :LANES, 1])[emitted].max() < 1e-5
+    for i in MAMBA_LAYERS:
+        for kept_k, kept_x in ((k_k, k_x), (km_k, km_x)):
+            for lane in (0, 2):
+                np.testing.assert_allclose(
+                    np.asarray(kept_k[i][lane]), np.asarray(kept_x[i][lane]), atol=2e-5
+                )
+            for row in (1, LANES):
+                assert np.array_equal(
+                    np.asarray(kept_k[i][row]).view(np.uint32),
+                    np.asarray(kc[i][row]).view(np.uint32),
+                )
+        for lane in (0, 2):
+            np.testing.assert_allclose(
+                np.asarray(vm_k[i][lane]), np.asarray(vm_x[i][lane]), atol=2e-5
+            )
+
+
 def test_a_reused_slot_gives_what_a_fresh_slot_gives():
     """Lane 1 serves one sequence (prefill and four decode steps), then a
     second one is prefilled into the same lane without any clearing: its

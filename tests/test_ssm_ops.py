@@ -248,3 +248,117 @@ def test_three_mamba2_forms_add_up_to_the_plain_loop(chunk):
         np.testing.assert_allclose(np.asarray(y1[0]), want_y[t], atol=TOL)
     np.testing.assert_allclose(np.asarray(s[0]), want_s[-1], atol=TOL)
     assert np.all(np.asarray(s[1]) == 3.0)
+
+
+# ------------------------------------ the decode update as a kernel (PR 55)
+#
+# `ops.pallas_ssm.ssd_update` interpreted, held to the plain `ssd_step` at a
+# shape the kernel can tile: 16 heads of 16 in 2 groups, a state 128 wide,
+# 6 lanes and the null lane's row behind them.
+
+HK, PK, NK, GK, BK = 16, 16, 128, 2, 6
+
+
+def update_inputs(seed: int):
+    rng = np.random.default_rng(seed)
+    normal = lambda *shape: jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    dt = jnp.asarray(np.log1p(np.exp(rng.standard_normal((BK, HK)) - 2)), jnp.float32)
+    return normal(BK, HK, PK), dt, normal(BK, GK, NK), normal(BK, GK, NK)
+
+
+def plain_step(s, x, dt, a, b, c, live):
+    """`ssd_step` over the slot array's every row, the null lane's behind the
+    batch's: what the kernel is held to."""
+    rows = lambda v: jnp.pad(v, ((0, 1),) + ((0, 0),) * (v.ndim - 1))
+    new, y = ssm.ssd_step(s, rows(x), rows(dt), a, rows(b), rows(c), rows(live))
+    return new, y[:BK]
+
+
+def bits(a):
+    return np.asarray(a).view(np.uint32)
+
+
+@pytest.mark.parametrize("live", [
+    [False] * 6, [True] * 6, [False, True, False, False, True, True],
+    [True, False, False, False, False, False],
+], ids=["none", "all", "scattered", "first"])
+def test_update_kernel_visits_the_live_rows_alone(live):
+    from dynamo_tpu.ops import pallas_ssm
+    from dynamo_tpu.ops.basics import forms_traced
+
+    rng = np.random.default_rng(7)
+    s = jnp.asarray(rng.standard_normal((BK + 1, HK, PK, NK)), jnp.float32)
+    a = -jnp.exp(jnp.asarray(rng.standard_normal(HK), jnp.float32))
+    x, dt, b, c = update_inputs(8)
+    live = jnp.asarray(live)
+    with forms_traced() as forms:
+        new, y = pallas_ssm.ssd_update(s, x, dt, a, b, c, live, impl="pallas_interpret")
+    assert dict(forms) == {"ssd_step_kernel": 1}
+    want_s, want_y = plain_step(s, x, dt, a, b, c, live)
+    visited = np.asarray(live)
+    np.testing.assert_allclose(np.asarray(y)[visited], np.asarray(want_y)[visited], rtol=TOL, atol=TOL)
+    np.testing.assert_allclose(np.asarray(new)[:BK][visited], np.asarray(want_s)[:BK][visited], rtol=TOL, atol=TOL)
+    # a row that is not visited, the null lane's among them, is bit for bit
+    # what it was, and its y is zero
+    kept = ~np.pad(visited, (0, 1))
+    assert np.array_equal(bits(new)[kept], bits(s)[kept])
+    assert np.all(np.asarray(y)[~visited] == 0.0)
+
+
+def test_update_kernel_writes_once_a_dispatch():
+    """Four steps, the last alone settling, lane 1 frozen behind the second:
+    every step's y and the written state are four plain steps', the three
+    calls that do not settle hand on the first state untouched, and a lane
+    that was never live keeps its row."""
+    from dynamo_tpu.ops import pallas_ssm
+
+    rng = np.random.default_rng(9)
+    s0 = jnp.asarray(rng.standard_normal((BK + 1, HK, PK, NK)), jnp.float32)
+    a = -jnp.exp(jnp.asarray(rng.standard_normal(HK), jnp.float32))
+    first = np.array([True, True, False, True, False, True])
+    kept, plain = s0, s0
+    for h in range(4):
+        live = jnp.asarray(first & ~((np.arange(BK) == 1) & (h >= 2)))
+        x, dt, b, c = update_inputs(10 + h)
+        kept, y = pallas_ssm.ssd_update(
+            kept, x, dt, a, b, c, live, settle=h == 3, impl="pallas_interpret"
+        )
+        plain, want_y = plain_step(plain, x, dt, a, b, c, live)
+        np.testing.assert_allclose(
+            np.asarray(y)[np.asarray(live)], np.asarray(want_y)[np.asarray(live)],
+            rtol=TOL, atol=TOL,
+        )
+        assert np.all(np.asarray(y)[~np.asarray(live)] == 0.0)
+        if h < 3:
+            assert isinstance(kept, pallas_ssm.Deferred) and kept.s0 is s0
+            assert len(kept.steps) == h + 1 and int(kept.count[0]) == 4
+    np.testing.assert_allclose(np.asarray(kept), np.asarray(plain), rtol=TOL, atol=TOL)
+    dead = ~np.pad(first, (0, 1))
+    assert np.array_equal(bits(kept)[dead], bits(s0)[dead])
+
+
+@pytest.mark.parametrize("shape", [
+    (8, 16, 16, 2),   # a state 16 wide: no tile of 128 lanes
+    (8, 16, 128, 2),  # a row of 128 `dt x` holds 8 heads, two groups'
+], ids=["narrow_state", "groups_share_a_row"])
+def test_update_of_a_shape_the_kernel_cannot_tile_is_the_plain_form(shape):
+    from dynamo_tpu.ops import pallas_ssm
+    from dynamo_tpu.ops.basics import forms_traced
+
+    H, P, N, G = shape
+    assert pallas_ssm.tiling(H, P, N, G, "pallas_interpret") is None
+    assert pallas_ssm.tiling(HK, PK, NK, GK, "pallas_interpret") == HK
+    assert pallas_ssm.tiling(HK, PK, NK, GK, "xla") is None
+    rng = np.random.default_rng(11)
+    normal = lambda *shape: jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    s, x, dt = normal(3, H, P, N), normal(2, H, P), jnp.abs(normal(2, H))
+    a, b, c = -jnp.abs(normal(H)), normal(2, G, N), normal(2, G, N)
+    live = jnp.asarray([True, False])
+    with forms_traced() as forms:
+        new, y = pallas_ssm.ssd_update(
+            s, x, dt, a, b, c, live, settle=False, impl="pallas_interpret"
+        )
+    assert dict(forms) == {"ssd_step_xla": 1}
+    rows = lambda v: jnp.pad(v, ((0, 1),) + ((0, 0),) * (v.ndim - 1))
+    want_s, want_y = ssm.ssd_step(s, rows(x), rows(dt), a, rows(b), rows(c), rows(live))
+    assert np.array_equal(bits(new), bits(want_s)) and np.array_equal(bits(y), bits(want_y[:2]))

@@ -1291,20 +1291,42 @@ def _ssm2_moe_setup(one_chip, num_blocks: int = 32832, pattern: str = "ME*"):
     return cfg, params, first, second
 
 
+def _state_movers(text: str, rows: int = B + 1) -> list[str]:
+    """Entry instructions other than the update kernel's calls whose result
+    holds a Mamba-2 layer's states, float32 `[65, 128, 64, 128]` or its heads
+    by group: a copy, a slice, an update or a fusion the size of a layer's
+    rows. The kernel visits the array where it lies, so there is none."""
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[: entry.index("\n}")]
+    found = []
+    for line in entry.splitlines()[1:]:
+        m = _HLO_INSTRUCTION.match(line)
+        if m and re.search(rf"f32\[{rows},(128|8,16),64,128\]", m["type"]) and m["op"] not in (
+            "parameter", "get-tuple-element", "tuple", "bitcast", "custom-call",
+        ):
+            found.append(f"{m['op']} {m['name']}")
+    return found
+
+
 @pytest.mark.parametrize("packed", [False, True], ids=["impl", "as_launched"])
-@pytest.mark.parametrize("program,bodies,kernels", [
-    # 1 attention layer x 4 steps, and 1 expert layer's 2 grouped products a pass
-    ("decode_multi@H4B64", 3, 1 * 4 + 2 * 4),
-    ("mixed_step@c1", 6, 1 + 2 * 2),  # the chunk's attention is XLA's
-    ("prefill_packed@512", 3, 2),
+@pytest.mark.parametrize("program,bodies,kernels,updates", [
+    # 1 attention layer x 4 steps, 1 expert layer's 2 grouped products a pass,
+    # and the Mamba-2 layer's update once a step, in a body of its own each
+    # where it does not settle (PR 55)
+    ("decode_multi@H4B64", 6, 1 * 4 + 2 * 4 + 1 * 4, 4),
+    ("mixed_step@c1", 6, 1 + 2 * 2 + 1, 1),  # the chunk's attention is XLA's
+    ("prefill_packed@512", 3, 2, 0),
 ])
-def test_ssm2_moe_step_programs_one_chip(one_chip, program, bodies, kernels, packed):
+def test_ssm2_moe_step_programs_one_chip(one_chip, program, bodies, kernels, updates, packed):
     """The family's step programs compile for the chip: three layer bodies a
     pass (Mamba-2, experts, attention; a mixed step has a chunk's pass and a
-    decode's), the paged decode kernel under its name at 2 KV heads, the
-    grouped products of the held experts in the Pallas kernel and none in
-    XLA's (PR 49), the slot arrays and the pages
-    written in place (aliased), and everything fits."""
+    decode's; a dispatch of four steps has the Mamba-2 layer's three steps
+    that do not settle beside the one that does), the paged decode kernel
+    under its name at 2 KV heads, the grouped products of the held experts in
+    the Pallas kernel and none in XLA's (PR 49), the Mamba-2 update in the
+    Pallas kernel once a layer and step with the states as they lie among its
+    operands, written by the settling call alone (PR 55), the slot arrays and
+    the pages written in place (aliased), and everything fits."""
     from dynamo_tpu.models import layer_bodies_called
 
     jax.clear_caches()  # a body traced by another test would not be counted
@@ -1313,14 +1335,27 @@ def test_ssm2_moe_step_programs_one_chip(one_chip, program, bodies, kernels, pac
     assert len(seen) == bodies, sorted(s[1] for s in seen)
     compiled = lowered.compile()
     text = compiled.as_text()
-    names = re.findall(
-        r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\""
-        r"[^\n]*op_name=\"[^\"\n]*pallas_call\"", text, re.M,
+    calls = re.findall(
+        r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = ([^\n]*?) custom-call\(([^\n]*"
+        r"custom_call_target=\"tpu_custom_call\"[^\n]*op_name=\"[^\"\n]*pallas_call\")",
+        text, re.M,
     )
-    assert len(names) == kernels
-    assert all(name.startswith("tpu_custom_call") for name in names), names
+    assert len(calls) == kernels
+    assert all(name.startswith("tpu_custom_call") for name, _, _ in calls), calls
     assert "ragged-dot" not in text and "ragged_dot" not in text
     assert _stack_relayouts(text, 128 * 1024 * 2688) == []
+    # what `ssm2_step_ms` reads: instructions that mention a layer's states,
+    # float32 [65, 128, 64, 128], and they are the update's calls and nothing
+    # else: the states go to the kernel unsliced, ungathered and uncopied, one
+    # call of four writes them (its result holds them, aliased to the operand)
+    # and the three others have no state among their results (this text
+    # names an operand's type among the call's layout constraints)
+    states = r"f32\[65,128,64,128\]"
+    touching = [(result, rest) for _, result, rest in calls if re.search(states, rest)]
+    assert len(touching) == updates
+    assert sum(bool(re.search(states, result)) for result, _ in touching) == min(updates, 1)
+    if program == "decode_multi@H4B64":  # a prefill writes a sequence's row
+        assert _state_movers(text) == []
     mem = compiled.memory_analysis()
     # one Mamba-2 layer's slot arrays (the tail's 65 rows are tiled to 72)
     # and one attention layer's two planes, at the published bytes a token
@@ -1329,10 +1364,18 @@ def test_ssm2_moe_step_programs_one_chip(one_chip, program, bodies, kernels, pac
     assert mem.alias_size_in_bytes == slots + pages
     assert mem.temp_size_in_bytes < 2 * 2**30
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16_909_336_064
-    if program == "decode_multi@H4B64":
-        # what `ssm2_step_ms` reads: instructions that mention a layer's
-        # states, float32 [65, 128, 64, 128] (or its heads by group)
-        assert re.search(r"f32\[65,(128|8,16),64,128\]", text)
+
+
+def test_state_mover_scan_finds_what_it_forbids(one_chip):
+    """The scan above on a program that does copy a layer's states: the plain
+    update under a `where` of two arrays, which XLA fuses into one pass over
+    all 65 rows."""
+    text = compile_text(
+        lambda s, t, live: jnp.where(live[:, None, None, None], s * 2.0, t),
+        one_chip((B + 1, 128, 64, 128), F32), one_chip((B + 1, 128, 64, 128), F32),
+        one_chip((B + 1,), jnp.bool_),
+    )
+    assert _state_movers(text) != []
 
 
 # -------- the window-and-full attention, sparse-expert family's programs (PR 52)
